@@ -62,6 +62,11 @@ class Filter:
     name: str
     fn: FilterFn
     init_state: Optional[Callable[[Sequence[int], Any], Any]] = None
+    # True when the state is read-only parameters (a neural filter's
+    # weights): ``fn`` returns it unchanged, so nothing one batch computes
+    # reaches the next and rows of different tenants may share a batch.
+    # False for temporal state (flow's previous frame) — see ``temporal``.
+    constant_state: bool = False
     compute_dtype: Any = jnp.float32
     uint8_ok: bool = False
     halo: Optional[int] = None
@@ -88,6 +93,12 @@ class Filter:
     @property
     def stateful(self) -> bool:
         return self.init_state is not None
+
+    @property
+    def temporal(self) -> bool:
+        """State that one batch writes and the next reads: what the
+        multi-tenant frontend must not thread across sessions."""
+        return self.stateful and not self.constant_state
 
     def __call__(self, batch: jnp.ndarray, state: Any = None) -> Tuple[jnp.ndarray, Any]:
         return self.fn(batch, state)
@@ -140,5 +151,6 @@ def FilterChain(*filters: Filter, name: Optional[str] = None) -> Filter:
         uint8_ok=all(f.uint8_ok for f in filters) if filters else False,
         halo=chain_halo,
         pad_safe=all(f.pad_safe for f in filters) if filters else True,
+        constant_state=all(f.constant_state for f in filters if f.stateful),
         members=tuple(filters),
     )

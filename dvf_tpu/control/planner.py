@@ -319,7 +319,7 @@ def plan_search(grid: Sequence[Plan],
 
     by_label = {p.label(): p for p in short}
     ab = _load_ab_comparison()
-    comp, _completed = ab(
+    comp = ab(
         [(p.label(), p) for p in short],
         lambda _label, p: measure(p),
         log=log,

@@ -436,14 +436,10 @@ def main(argv=None) -> int:
         try:
             import jax
 
+            # Explicitly the CPU platform: this flavor's collectives
+            # are gloo, and it has never run on a TPU (ROADMAP D8).
             jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception as e:  # noqa: BLE001 — old jax: no CPU
-                raise RuntimeError(
-                    f"no CPU collectives ({e}) — multihost replicas "
-                    f"need jax with gloo support") from e
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
             from dvf_tpu.fleet.multiproc import MultiHostEngine
             from dvf_tpu.parallel.distributed import init_distributed

@@ -2,8 +2,9 @@
 
 :class:`MultiHostReplica` is the fleet handle for a replica whose
 worker is a ``MultiHostEngine`` process group — ``hosts`` child
-processes joined by ``jax.distributed`` (gloo collectives on CPU, ICI
-on a real pod), compiling ONE pjit program across every member's
+processes joined by ``jax.distributed`` over gloo, which is the CPU
+platform only (``_mh_worker`` pins it explicitly; no member has ever
+held a TPU — ROADMAP D8), compiling ONE pjit program across every member's
 devices and serving it behind the standard replica RPC. The fleet
 router cannot tell it from a :class:`~dvf_tpu.fleet.replica.
 ProcessReplica`: same transport, same health/stats surface, same

@@ -311,6 +311,27 @@ class LocalReplica(ReplicaHandle):
         return self._fe().audit_probe(signature)
 
 
+def refuse_process_replicas_on_tpu(replica_env: Dict[str, str]) -> None:
+    """Process-mode replicas inherit this process's platform. A TPU chip
+    belongs to one process, so N children (and a front door that has
+    touched jax) cannot share it: unless the environment the children
+    will see names the CPU platform explicitly, refuse to start when this
+    host's default backend is a TPU. Local mode drives every chip from
+    one process (one replica per chip)."""
+    platforms = (replica_env.get("JAX_PLATFORMS")
+                 or os.environ.get("JAX_PLATFORMS") or "")
+    if platforms.split(",")[0].strip().lower() == "cpu":
+        return
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ServeError(
+            "fleet mode 'process' starts one child process per replica, "
+            "and a TPU chip belongs to one process: use mode 'local' "
+            "(--mode local: one process, one replica per chip), or set "
+            "JAX_PLATFORMS=cpu to run CPU replicas on purpose")
+
+
 class ProcessReplica(ReplicaHandle):
     """Replica in a child process, reached over the pickle RPC.
 
@@ -357,10 +378,12 @@ class ProcessReplica(ReplicaHandle):
         env = dict(os.environ)
         # The child defaults to ONE device and no test-harness device
         # forcing: a replica's parallelism is its own mesh's business
-        # (override via the env dict for multi-device replicas).
+        # (override via the env dict for multi-device replicas). The
+        # platform is inherited, never defaulted: FleetFrontend refuses
+        # process mode where that would be a TPU
+        # (refuse_process_replicas_on_tpu).
         env["XLA_FLAGS"] = ""
         env.pop("JAX_NUM_CPU_DEVICES", None)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         repo_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = repo_root + (

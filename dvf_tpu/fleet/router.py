@@ -58,6 +58,7 @@ from dvf_tpu.fleet.replica import (
     ProcessReplica,
     ReplicaHandle,
     ReplicaLostError,
+    refuse_process_replicas_on_tpu,
 )
 from dvf_tpu.fleet.stats import (
     merge_fault_summaries,
@@ -282,6 +283,8 @@ class FleetFrontend:
             raise ValueError(
                 "process mode needs filter_spec=(name, kwargs): a filter "
                 "object's closures cannot cross the process boundary")
+        if self.config.mode == "process":
+            refuse_process_replicas_on_tpu(self.config.replica_env)
         if filt is None:
             if self.config.filter_spec is None:
                 raise ValueError("need a filter or config.filter_spec")
@@ -296,6 +299,8 @@ class FleetFrontend:
         self.replica_losses = 0
         self.migrated_sessions = 0
         self.orphaned_sessions = 0
+        self.stacked_replicas = 0         # local replicas placed on a
+        #   device another replica already owns (more replicas than chips)
         self.order_violations = 0         # should stay 0: the affinity +
         #   migration protocol guarantees per-session index monotonicity
         self.scale_outs = 0               # applied spawn_replica calls
@@ -640,6 +645,17 @@ class FleetFrontend:
                 1, len(devs) // config.replicas)
             start = (index * per) % len(devs)
             chunk = devs[start:start + per] or devs[:1]
+            if (index + 1) * per > len(devs):
+                # More replicas than devices: this one wraps onto a
+                # device an earlier replica already owns. Kept working
+                # (elastic scale-out on a small host), never silent.
+                with self._lock:
+                    self.stacked_replicas += 1
+                print(f"[fleet] replica {rid} shares device(s) "
+                      f"{[d.id for d in chunk]} with an earlier replica "
+                      f"({len(devs)} device(s), {per} per replica): "
+                      f"stacked replicas contend for one chip",
+                      file=sys.stderr)
             chaos = None
             if config.chaos_spec:
                 from dvf_tpu.resilience import FaultPlan
@@ -2289,10 +2305,17 @@ class FleetFrontend:
             replica_rows[rid] = row
         session_rows = {}
         for sid, s in sessions.items():
+            # The owning replica's own accounting for this stream (same
+            # session id there): None while its export is unreachable.
+            rs = (((exports.get(s.replica_id) or {}).get("stats") or {})
+                  .get("sessions", {}).get(sid) or {})
             session_rows[sid] = {
                 "replica": s.replica_id,
                 "submitted": s.next_index,
                 "polled": s.polled,
+                "delivered": rs.get("delivered"),
+                "shed": rs.get("shed"),
+                "dropped_at_ingress": rs.get("dropped_at_ingress"),
                 "lost": s.lost,
                 "migrations": s.migrations,
                 "tier": s.tier,
@@ -2308,6 +2331,7 @@ class FleetFrontend:
             "migrated_sessions": self.migrated_sessions,
             "orphaned_sessions": self.orphaned_sessions,
             "order_violations": self.order_violations,
+            "stacked_replicas": self.stacked_replicas,
             # Per-replica warm-signature map (the placement input): what
             # each replica's pool serves without a compile.
             "warm_replicas": warm,

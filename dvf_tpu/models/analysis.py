@@ -23,7 +23,7 @@ lowering is leaving time on the table" -- the distinction the VERDICT
 asked the round to establish.
 
 The numbers are a MODEL (peaks from the public v5e datasheet, the same
-constants as dvf_tpu.benchmarks.V5E_PEAKS; efficiency factors are
+constants as dvf_tpu.benchmarks.DEVICE_PEAKS; efficiency factors are
 idealized tiling, not a simulator). The on-chip companion is
 benchmarks/neural_layers.py, which times the same per-layer blocks on
 the real chip; where the two disagree, the measured number wins.
@@ -38,7 +38,7 @@ import json
 import math
 from typing import List, Optional
 
-# Same public-datasheet constants as dvf_tpu.benchmarks.V5E_PEAKS
+# Same public-datasheet constants as dvf_tpu.benchmarks.DEVICE_PEAKS
 # (duplicated literals would drift; import lazily to stay jax-free).
 PEAK_BF16_TFLOPS = 197.0
 PEAK_HBM_GBPS = 819.0

@@ -38,7 +38,7 @@ the aggregation/exemplar machinery that makes it cheap at serving rates:
     controllers annotate their decisions with and a topology-aware
     planner seeds from.
 
-Serve-path components (in hop order; the glossary LATENCY.md documents):
+Serve-path components (in hop order):
 
 ==============  ============================================================
 queue_ingress   capture/submit → drained into the scheduler's pending

@@ -28,9 +28,10 @@ def sobel_bilateral(
     edge map), CPU 9.2 vs 3.3 fps (in interpret mode it lowers to
     ordinary fused XLA ops, a legitimate production path). "chain" (the
     two-op jnp chain) remains the default on backends whose A/B hasn't
-    been captured yet. Provenance: the sobel_bilateral_1080p
-    impl-comparison rows in benchmarks/BENCH_TABLE.md (TPU) and
-    benchmarks/cpu/ (CPU); both filters declare the same halo, so
+    been captured yet. Provenance: the sobel_bilateral_1080p impl
+    comparison — TPU figures captured 2026-07-31 through a shared chip
+    that no longer exists (table removed in PR 21), CPU rows in
+    benchmarks/cpu/BENCH_TABLE.json; both filters declare the same halo, so
     spatial sharding is unaffected by the choice.
     """
     if impl is None:

@@ -406,8 +406,9 @@ def flow_warp(
     ``None`` picks the measured per-backend winner: "pallas" on TPU
     (39.6 vs 17.4 fps at 720p batch 4 — TPU has no fast vector gather),
     "gather" on CPU (3.1 vs 3.0; and it imposes no displacement clip).
-    Provenance: the flow_warp_720p impl-comparison rows in
-    benchmarks/BENCH_TABLE.md (TPU) and benchmarks/cpu/ (CPU).
+    Provenance: the flow_warp_720p impl comparison — TPU figures
+    captured 2026-07-31 through a shared chip that no longer exists
+    (table removed in PR 21), CPU rows in benchmarks/cpu/BENCH_TABLE.json.
 
     NOTE the TPU default is an APPROXIMATION, unlike the other measured
     winners (which are numerics-identical): the Pallas warp clips

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from dvf_tpu.api.filter import Filter, stateless
 from dvf_tpu.ops.registry import register_filter
 from dvf_tpu.utils.image import rgb_to_gray, to_float, to_uint8
-from dvf_tpu.utils.compat import shard_map
 
 
 def _plane_cdf(flat_i32: jnp.ndarray) -> jnp.ndarray:
@@ -140,7 +139,7 @@ def equalize(on_gray: bool = False) -> Filter:
                         h_total=h)
 
         def sharded_fn(batch, state):
-            out = shard_map(
+            out = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=spec,
                 out_specs=spec,

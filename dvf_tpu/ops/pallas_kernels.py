@@ -125,6 +125,20 @@ def _resolve_tile_h(h: int, tile_h: Optional[int],
     return _pick_tile_h(h, target)
 
 
+def _pinned_tile_params(tile_h: Optional[int], interpret: bool):
+    """Compiler params for a stencil kernel whose caller pinned
+    ``tile_h`` (the run_table tile sweeps). Mosaic's default scoped-VMEM
+    limit is 16 MiB and the unrolled taps' temporaries grow with the
+    tile: bilateral at tile 40 over 1080p needs 16.55 MB on the v5e
+    (RESOURCE_EXHAUSTED in PR 21's chip run), so a pinned tile gets the
+    warp kernel's 64 MiB (the chip has 128 MiB of VMEM). The auto-picked
+    tile compiles under the default and keeps it — nothing the server
+    runs pins a tile."""
+    if interpret or tile_h is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+
+
 def _pad_rows(x: jnp.ndarray, extra: int) -> jnp.ndarray:
     """Append ``extra`` edge-value rows to NCHW ``x`` (dim 2) so the grid
     tiles exactly and every DMA slab is in-bounds; the values never reach
@@ -218,6 +232,7 @@ def bilateral_nhwc_pallas(
             pltpu.VMEM((c, _slab_rows(th, 2 * r), w_al), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=_pinned_tile_params(tile_h, interpret),
         interpret=interpret,
     )(x)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
@@ -396,6 +411,7 @@ def sep_blur_nhwc_pallas(
             pltpu.VMEM((c, _slab_rows(th, 2 * rh), w_al), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=_pinned_tile_params(tile_h, interpret),
         interpret=interpret,
     )(x)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
@@ -518,6 +534,7 @@ def sobel_bilateral_nhwc_pallas(
             pltpu.VMEM((c, _slab_rows(th, 2 * R), w_al), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=_pinned_tile_params(tile_h, interpret),
         interpret=interpret,
     )(x)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
@@ -624,12 +641,11 @@ def _tile_maxdiff_kernel(tile: int, row_px: int, ntx: int):
 
 def tile_maxdiff_pallas(a: jnp.ndarray, b: jnp.ndarray, tile: int = 32,
                         interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Pallas tile_maxdiff: one HBM pass per (batch row, tile row) pair,
-    the whole reduction held in VMEM/registers. Falls back to the jnp
-    golden when the geometry doesn't tile exactly (edge tiles) — the
-    kernel exists for the aligned common case (512², 1080p at tile 8/27…),
-    where it wins by never materializing the (B, H, W, C) diff array the
-    jnp version round-trips through HBM.
+    """Pallas tile_maxdiff: one pass per (batch row, tile row) pair, the
+    whole reduction held in VMEM/registers. Falls back to the jnp golden
+    when the geometry doesn't tile exactly (edge tiles). Interpret mode
+    only: it does not lower through Mosaic (``TILE_MAXDIFF_PALLAS_ON_TPU``
+    says why), so on a TPU call :func:`tile_maxdiff`.
     """
     interpret = _auto_interpret(interpret)
     squeeze = a.ndim == 3
@@ -654,12 +670,27 @@ def tile_maxdiff_pallas(a: jnp.ndarray, b: jnp.ndarray, tile: int = 32,
     return out[0] if squeeze else out
 
 
+# Whether the dispatcher compiles the Pallas kernel on a TPU. It does not:
+# on the v5e (PR 21's chip run, jax 0.9.0) the kernel does not lower — its
+# (1, 1, ntx) output block is neither a multiple of the (8, 128) tile nor
+# the whole axis, the body stacks scalars into a vector and slices lanes
+# at 96-lane offsets, and a uint8 input block wants 32-row tiles that 1080
+# rows do not divide. The golden loses nothing to it: XLA fuses |a - b|
+# into the tile reduce and reads each frame once. The kernel stays for
+# interpret mode (tier-1 pins its arithmetic) until a cell shows a TPU
+# kernel would win (ROADMAP D4).
+TILE_MAXDIFF_PALLAS_ON_TPU = False
+
+
 def tile_maxdiff(a: jnp.ndarray, b: jnp.ndarray, tile: int = 32,
                  interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Dispatch: the Pallas kernel on aligned geometries (compiled on
-    TPU, interpret elsewhere), the jnp golden otherwise."""
+    """Dispatch: the jnp golden on unaligned geometries and (see
+    ``TILE_MAXDIFF_PALLAS_ON_TPU``) on the TPU; the Pallas kernel in
+    interpret mode on aligned ones."""
     h, w = a.shape[-3], a.shape[-2]
-    if h % tile == 0 and w % tile == 0 and h % _SUBLANE == 0:
+    aligned = h % tile == 0 and w % tile == 0 and h % _SUBLANE == 0
+    if aligned and (_auto_interpret(interpret)
+                    or TILE_MAXDIFF_PALLAS_ON_TPU):
         return tile_maxdiff_pallas(a, b, tile, interpret=interpret)
     return tile_maxdiff_ref(a, b, tile)
 
@@ -739,20 +770,21 @@ def _qrecip_lanes(qtable, nbx: int):
 
 @functools.partial(jax.jit, static_argnums=(1,))
 def _dct8x8_quant_slab_jit(x, nbx, qrecip):
-    """The golden's execution of the shared slab math. Jitted on
-    purpose: eager per-op dispatch compiles each multiply-add as its own
-    XLA program and never forms FMAs, while the Pallas interpreter runs
-    the kernel body as one fused program (which does) — a 1-ulp
-    difference that flips round() on coefficient-boundary values. One
-    fused program on both sides restores bit-identity (pinned by
-    benchmarks/pallas_compile_check.py)."""
+    """The golden's execution of the slab math. Jitted on purpose: eager
+    per-op dispatch compiles each multiply-add as its own XLA program and
+    never forms FMAs, while the Pallas interpreter runs the kernel body
+    as one fused program (which does) — a 1-ulp difference that flips
+    round() on coefficient-boundary values. One fused program on both
+    sides restores bit-identity (pinned by tests/test_delta_wire.py in
+    interpret mode and by chip_smoke.py on the TPU)."""
     return _dct8x8_quant_slab(x, nbx, qrecip)
 
 
 def _dct8x8_quant_slab(x: jnp.ndarray, nbx: int,
                        qrecip: jnp.ndarray) -> jnp.ndarray:
-    """Shared arithmetic of the golden AND the Pallas kernel — one op
-    sequence so the two paths are bit-identical in interpret mode.
+    """The golden's arithmetic; the Pallas kernel
+    (:func:`_dct8x8_quant_kernel`) performs the same products and sums in
+    the same order on a layout Mosaic can lower.
 
     ``x`` is a (…, 8, 8·nbx) float32 slab of 8-pixel-tall block rows in
     INTERLEAVED lane order (lane = x_in_block · nbx + block_idx): every
@@ -830,21 +862,45 @@ def dct8x8_quant_ref(plane: jnp.ndarray, qtable) -> jnp.ndarray:
     return out[0] if squeeze else out
 
 
-def _dct8x8_quant_kernel(nbx: int):
-    def kernel(in_ref, q_ref, out_ref):
-        out_ref[0, 0, :, :] = _dct8x8_quant_slab(in_ref[0, 0], nbx,
-                                                 q_ref[...])
+def _dct8x8_quant_kernel(nofma):
+    """One grid step transforms one block row, laid out
+    ``[y, x_in_block, block]`` (see :func:`dct8x8_quant_pallas`): every
+    operand is an aligned ``(8, nbx)`` tile (x-in-block on sublanes, block
+    index on lanes), a ``(8, 1)`` / ``(1, nbx)`` broadcast of one, or a
+    Python scalar — no lane slicing, concatenation, stacking or reshape in
+    the body, which is what Mosaic lowers. The per-element arithmetic is
+    :func:`_dct8x8_quant_slab`'s, product by product and in its order, so
+    the two agree bit for bit wherever neither compiler contracts a
+    multiply-add. ``nofma`` guards each product in interpret mode only:
+    Mosaic has no lowering for ``optimization_barrier`` (PR 21's chip
+    run), and XLA:CPU is the compiler that contracts."""
+
+    def kernel(x_ref, d_ref, q_ref, out_ref):
+        rows = [x_ref[0, 0, y] - 128.0 for y in range(8)]  # JPEG level shift
+        for u in range(8):          # vertical frequency
+            vert = nofma(float(_DCT8[u, 0]) * rows[0])
+            for y in range(1, 8):
+                vert = vert + nofma(float(_DCT8[u, y]) * rows[y])
+            # Horizontal pass: x-in-block is the sublane axis, so mixing it
+            # is an outer-product accumulate, d_ref[k] holding column k of
+            # the DCT matrix as an (8, 1) vector over horizontal frequency.
+            t = nofma(d_ref[0] * vert[0:1, :])
+            for k in range(1, 8):
+                t = t + nofma(d_ref[k] * vert[k:k + 1, :])
+            out_ref[0, 0, u] = jnp.round(t * q_ref[u])
+
     return kernel
 
 
 def dct8x8_quant_pallas(plane: jnp.ndarray, qtable,
                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """Pallas DCT+quant: grid = (batch, block rows); each step transforms
-    one (8, W) block row entirely in VMEM/registers. The slab arrives in
-    interleaved lane order (see :func:`_dct8x8_quant_slab`) so both DCT
-    passes are static chunk slices + scalar multiply-adds — pure VPU
-    work, no gather, no in-kernel reshape. Requires H and W to be block
-    multiples (the dispatcher sends everything else to the golden)."""
+    one (8, W) block row entirely in VMEM/registers. The plane arrives as
+    ``(B, nby, 8, 8, nbx)`` = ``[.., y, x_in_block, block]`` (one XLA
+    transpose outside the kernel) and the coefficients leave as
+    ``[.., v_freq, h_freq, block]``, so both DCT passes are aligned
+    whole-tile VPU work. Requires H and W to be block multiples (the
+    dispatcher sends everything else to the golden)."""
     interpret = _auto_interpret(interpret)
     squeeze = plane.ndim == 2
     if squeeze:
@@ -854,21 +910,25 @@ def dct8x8_quant_pallas(plane: jnp.ndarray, qtable,
         raise ValueError(f"dct8x8_quant_pallas needs H, W multiples of 8; "
                          f"got {h}x{w}")
     nby, nbx = h // 8, w // 8
-    lanes = 8 * nbx
-    qrecip = jnp.asarray(_qrecip_lanes(qtable, nbx))
+    import numpy as np
+
+    x = (plane.astype(jnp.float32).reshape(b, nby, 8, nbx, 8)
+         .transpose(0, 1, 2, 4, 3))                    # [b, by, y, x, bx]
+    dcols = jnp.asarray(_DCT8.T[:, :, None])           # [k][h_freq, 0]
+    qrecip = jnp.asarray((1.0 / np.asarray(qtable, np.float64))
+                         .astype(np.float32)[:, :, None])  # [v][h, 0]
+    block = pl.BlockSpec((1, 1, 8, 8, nbx), lambda bb, ii: (bb, ii, 0, 0, 0))
+    table = pl.BlockSpec((8, 8, 1), lambda bb, ii: (0, 0, 0))
     out = pl.pallas_call(
-        _dct8x8_quant_kernel(nbx),
+        _dct8x8_quant_kernel(jax.lax.optimization_barrier if interpret
+                             else (lambda v: v)),
         grid=(b, nby),
-        in_specs=[
-            pl.BlockSpec((1, 1, 8, lanes), lambda bb, ii: (bb, ii, 0, 0)),
-            pl.BlockSpec((8, lanes), lambda bb, ii: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 8, lanes),
-                               lambda bb, ii: (bb, ii, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nby, 8, lanes), jnp.float32),
+        in_specs=[block, table, table],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((b, nby, 8, 8, nbx), jnp.float32),
         interpret=interpret,
-    )(_to_slab(plane, nby, nbx), qrecip)
-    out = _from_slab(out, nby, nbx)
+    )(x, dcols, qrecip)
+    out = out.transpose(0, 1, 4, 2, 3).astype(jnp.int16)  # (b,by,bx,8,8)
     return out[0] if squeeze else out
 
 

@@ -30,7 +30,6 @@ from dvf_tpu.models.espcn import (
     tp_inner_apply,
 )
 from dvf_tpu.ops.registry import measured_default_for, register_filter
-from dvf_tpu.utils.compat import shard_map
 
 
 @register_filter("upscale")
@@ -130,7 +129,7 @@ def super_resolution(
             batch_spec = P(None)
 
         def sharded_fn(batch: jnp.ndarray, state: Any) -> Tuple[jnp.ndarray, Any]:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 inner,
                 mesh=mesh,
                 in_specs=(specs, batch_spec),
@@ -143,6 +142,7 @@ def super_resolution(
             name=f"tp({name})",
             fn=sharded_fn,
             init_state=init_state,
+        constant_state=True,  # the state is the weights
             compute_dtype=jnp.float32,
             state_pspecs=lambda: specs,
         )
@@ -151,6 +151,7 @@ def super_resolution(
         name=name,
         fn=fn,
         init_state=init_state,
+        constant_state=True,  # the state is the weights
         compute_dtype=jnp.float32,
         state_pspecs=lambda: param_pspecs(config),
         specialize=specialize,

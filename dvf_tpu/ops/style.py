@@ -31,7 +31,6 @@ from dvf_tpu.models.style_transfer import (
     tp_inner_apply,
 )
 from dvf_tpu.ops.registry import measured_default_for, register_filter
-from dvf_tpu.utils.compat import shard_map
 
 
 @register_filter("style_transfer")
@@ -140,7 +139,7 @@ def style_transfer(
             batch_spec = P(None)
 
         def sharded_fn(batch: jnp.ndarray, state: Any) -> Tuple[jnp.ndarray, Any]:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 inner,
                 mesh=mesh,
                 in_specs=(specs, batch_spec),
@@ -153,6 +152,7 @@ def style_transfer(
             name=f"{parallel}({name})",
             fn=sharded_fn,
             init_state=init_state,
+        constant_state=True,  # the state is the weights
             compute_dtype=jnp.float32,
             state_pspecs=lambda: specs,
         )
@@ -161,6 +161,7 @@ def style_transfer(
         name=name,
         fn=fn,
         init_state=init_state,
+        constant_state=True,  # the state is the weights
         compute_dtype=jnp.float32,
         # TP specs are safe on any mesh (a size-1 model axis replicates);
         # PP's trunk specs are NOT — an indivisible model axis must fall

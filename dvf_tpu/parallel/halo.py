@@ -40,7 +40,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dvf_tpu.api.filter import Filter
-from dvf_tpu.utils.compat import axis_size, shard_map
 
 
 def halo_exchange_rows(x: jnp.ndarray, r: int, axis_name: str = "space") -> jnp.ndarray:
@@ -50,7 +49,7 @@ def halo_exchange_rows(x: jnp.ndarray, r: int, axis_name: str = "space") -> jnp.
     shards use reflect-101 of their own edge instead of the ring wrap, so
     the assembled result matches reflect-padded single-device semantics.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[1] <= r:
         raise ValueError(
             f"local slab has {x.shape[1]} rows but the stencil radius is {r}; "
@@ -154,7 +153,7 @@ def spatial_filter(
     spec = P("data" if data_sharded else None, "space")
 
     def fn(batch: jnp.ndarray, state):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=spec,

@@ -88,7 +88,9 @@ def batch_pspec(mesh: Mesh, batch_shape: Optional[Sequence[int]] = None) -> P:
     ``model`` axis only shards filter-internal tensors (style net weights).
     If ``batch_shape`` is given, an axis is only sharded when its dimension
     divides evenly (a 4-frame batch on an 8-way data mesh replicates rather
-    than erroring — correctness first, the engine logs the inefficiency).
+    than erroring — correct, but every device then computes the whole
+    batch; Engine.compile says so on stderr and counts each such batch in
+    ``EngineStats.replicated_batches``).
     """
     dims = dict(zip(mesh.axis_names, mesh.devices.shape))
     b_ax = dims.get("data", 1)

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dvf_tpu.utils.compat import axis_size
 
 
 def stack_layer_params(params_list) -> Any:
@@ -72,7 +71,7 @@ def pipeline_apply(
     ``n_microbatches``: 0/1 → auto: min(B, S) (enough to fill the
     pipeline); otherwise must divide B.
     """
-    s = axis_size(axis)
+    s = jax.lax.axis_size(axis)
     stage = lax.axis_index(axis)
     b = x.shape[0]
     if n_microbatches and n_microbatches > 1:

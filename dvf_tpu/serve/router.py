@@ -56,6 +56,7 @@ class ResultRouter:
             #   route (the plan was still in the supervisor window) must
             #   become a no-op, not a second release of the same claims
         touched = []
+        delivered = 0
         marks = plan.lin_marks
         for row, slot in enumerate(plan.slots[: plan.valid]):
             s = slot.session
@@ -67,9 +68,16 @@ class ResultRouter:
             s.complete(slot, out[row].copy())
             if s.state == "closed":
                 self.late_after_close += 1
-            elif s not in touched:
+                continue
+            if s not in touched:
                 touched.append(s)
-        delivered = 0
+            if len(s.reorder) >= s.config.reorder_capacity:
+                # One batch can hold more of a stream's rows than its
+                # reorder buffer holds frames (batch 64, one tenant,
+                # capacity 50): drain now, or the capacity cap evicts
+                # frames nobody has been offered yet — lost to every
+                # counter (PR 21's four-chip smoke: 100 of 128 delivered).
+                delivered += s.deliver_ready()
         for s in touched:
             delivered += s.deliver_ready()
         self.batches += 1

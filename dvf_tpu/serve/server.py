@@ -34,10 +34,15 @@ semaphore). Overload beyond that is absorbed by the per-session
 drop-oldest bounds and the batcher's SLO shedding, never by blocking a
 client.
 
-Only stateless filters are served: a stateful filter's temporal state
+Temporal filters (``Filter.temporal``: state one batch writes and the
+next reads, e.g. flow's previous frame) are served only by a
+single-tenant frontend (``max_sessions == 1``): with more, the state
 would thread *across* batches whose rows belong to different tenants —
-cross-session state leakage by construction — so the frontend refuses
-them at build time.
+cross-session leakage by construction — so the frontend refuses them at
+build time, and a single-tenant frontend resets the state at every
+admission. Filters whose state is read-only weights
+(``Filter.constant_state``: style transfer, super resolution) carry
+nothing from batch to batch and are multiplexed like stateless ones.
 
 ``ZmqStreamBridge`` binds one session to the reference app's socket pair
 using the exact READY-credit framing of ``transport.zmq_ingress`` — a
@@ -520,14 +525,14 @@ class ServeFrontend:
         config: Optional[ServeConfig] = None,
         engine: Optional[Engine] = None,
     ):
-        if filt.stateful:
-            raise ValueError(
-                f"filter {filt.name!r} is stateful; a shared batch "
-                f"interleaves rows from different sessions, so temporal "
-                f"state would leak across tenants — the serving frontend "
-                f"only multiplexes stateless filters")
         self.filter = filt
         self.config = config or ServeConfig()
+        if filt.temporal and self.config.max_sessions != 1:
+            raise ValueError(
+                f"filter {filt.name!r} carries temporal state; a shared "
+                f"batch interleaves rows from different sessions, so the "
+                f"state would leak across tenants — a temporal filter "
+                f"needs a single-tenant frontend (max_sessions=1)")
         if self.config.ingest not in INGEST_MODES:
             raise ValueError(
                 f"ingest must be one of {INGEST_MODES}, got "
@@ -1501,6 +1506,7 @@ class ServeFrontend:
                 sid_out = self._register_session_locked(
                     bucket, session_id, cfg, sink)
         if bucket is not None:
+            self._reset_temporal_state(bucket)
             self._warm_quality_async(bucket)
             if publish:
                 self.publish_stream(sid_out, publish, publish_tiers)
@@ -1536,10 +1542,21 @@ class ServeFrontend:
                 # admission failed after the lease: the program stays
                 # WARM in the pool either way.
                 self.pool.release(create_key)
+        self._reset_temporal_state(bucket)
         self._warm_quality_async(bucket)
         if publish:
             self.publish_stream(sid_out, publish, publish_tiers)
         return sid_out
+
+    @staticmethod
+    def _reset_temporal_state(bucket: "_Bucket") -> None:
+        """A temporal filter is only ever served single-tenant
+        (max_sessions == 1), and that cap admits the next session only
+        once the previous one has drained and retired — nothing is in
+        flight here, so the new tenant starts from pristine state
+        instead of the last tenant's final frame."""
+        if bucket.filter.temporal:
+            bucket.engine.reset_state()
 
     # -- broadcast plane (publish / subscribe) ---------------------------
 
@@ -1669,7 +1686,12 @@ class ServeFrontend:
                 f"({len(matches)} live buckets serve it); warm "
                 f"signatures: {self._warm_signatures()}")
         shape, dtype = declared
-        key = make_key(chain, shape, dtype)
+        # ``chain`` is already canonical (open_stream parsed it) or the
+        # default bucket's spelling, which may be a display name no
+        # parser accepts ("style_transfer(c=32,r=5,tp)") — build the key
+        # from it as it stands rather than re-parsing it through make_key.
+        key = SignatureKey(chain, canonical_geometry(shape),
+                           canonical_dtype(dtype).name)
         b = self._bucket_by_key.get(key)
         if b is not None:
             return b, None
@@ -1799,12 +1821,12 @@ class ServeFrontend:
                 filt = self._filters_by_chain.get(key.op_chain)
             if filt is None:
                 filt = build_filter(key.op_chain)
-                if filt.stateful:
+                if filt.temporal and self.config.max_sessions != 1:
                     raise AdmissionError(
-                        f"op_chain {key.op_chain!r} is stateful; a "
-                        f"shared batch interleaves tenants, so temporal "
-                        f"state would leak across sessions — stateless "
-                        f"chains only")
+                        f"op_chain {key.op_chain!r} carries temporal "
+                        f"state; a shared batch interleaves tenants, so "
+                        f"the state would leak across sessions — "
+                        f"temporal chains need max_sessions=1")
                 with self._lock:
                     self._filters_by_chain.setdefault(key.op_chain, filt)
             seed = None
@@ -3666,6 +3688,11 @@ class ServeFrontend:
             "morphs": self.morphs,
             "engine_batches": sum(b.engine.stats.batches for b in buckets),
             "engine_frames": sum(b.engine.stats.frames for b in buckets),
+            # Batches a data>1 mesh computed whole on every device (the
+            # batch size did not divide the data axis — e.g. a ladder
+            # downshift to 2 on four chips): correct, and a waste.
+            "replicated_batches": sum(b.engine.stats.replicated_batches
+                                      for b in buckets),
             # Multi-signature plane: one row per live bucket (keyed by
             # canonical signature) + the compiled-program pool counters.
             "open_buckets": len(buckets),

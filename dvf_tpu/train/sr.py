@@ -25,7 +25,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dvf_tpu.utils.compat import shard_map
 
 from dvf_tpu.models.espcn import (
     EspcnConfig,
@@ -202,7 +201,7 @@ def make_train_step(
             metrics,
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(specs, P(dp_axes)),

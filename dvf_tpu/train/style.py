@@ -29,7 +29,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dvf_tpu.utils.compat import shard_map
 
 from dvf_tpu.models.layers import gram_matrix
 from dvf_tpu.models.style_transfer import (
@@ -253,7 +252,7 @@ def make_train_step(
         return new_state, metrics
 
     batch_spec = P(dp_axes)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(specs, batch_spec),

@@ -581,7 +581,7 @@ def jpeg_wire_budget(height: int, width: int, quality: int = 90,
     ``wire_mode`` is the recommendation given the numbers: ``"delta"``
     when an expected dirty ratio was supplied and its ceiling clearly
     beats full-frame JPEG (>1.2×), else ``"jpeg"``. The full break-even
-    analysis lives in benchmarks/TPU_RESULTS.md.
+    analysis: ARCHITECTURE.md, "Wire-mode budget".
     """
     enc_fps, dec_fps = measure_codec_fps(height, width, quality=quality,
                                          mode="cycle")
@@ -1560,7 +1560,7 @@ class DeltaCodec:
     def stats(self) -> dict:
         """Wire-side accounting: the dirty ratio is the fraction of tiles
         actually re-encoded across delta frames (keyframes excluded) —
-        the number LATENCY.md's delta reading guide starts from."""
+        the number a delta-wire latency reading starts from."""
         return {
             "frames": self.frames,
             "keyframes": self.keyframes,

@@ -3,7 +3,10 @@
 # PLUS the audit smoke (scripts/audit_smoke.py: one shadow-replay round
 # + one injected-corruption detection, nonzero on a miss) PLUS the
 # broadcast smoke (scripts/broadcast_smoke.py: encode-once fan-out,
-# relay-hop audit, serve publish tee) PLUS the continuity soak smoke
+# relay-hop audit, serve publish tee) PLUS the chip smoke's dry run
+# (chip_smoke.py --cpu-tiny: the eight configs through ServeFrontend and
+# every Pallas kernel at toy sizes, labelled cpu — the real run needs
+# the chip) PLUS the continuity soak smoke
 # (benchmarks/continuity_bench.py --smoke: seeded chaos with
 # byte-identical reassembly + front-door kill -9 recovery, ~10 s)
 # PLUS the auto-plan gate (benchmarks/plan_bench.py --check: the
@@ -46,6 +49,14 @@ brc=$?
 if [ "$brc" -ne 0 ]; then
     echo "ci_tier1: BROADCAST MISS (broadcast_smoke rc=$brc)" >&2
     exit "$brc"
+fi
+
+echo "== chip smoke, CPU dry run (chip_smoke.py --cpu-tiny) =="
+JAX_PLATFORMS=cpu python chip_smoke.py --cpu-tiny
+ksrc=$?
+if [ "$ksrc" -ne 0 ]; then
+    echo "ci_tier1: CHIP SMOKE DRY RUN FAILED (chip_smoke rc=$ksrc)" >&2
+    exit "$ksrc"
 fi
 
 echo "== continuity soak smoke (seeded chaos + front-door crash recovery) =="

@@ -15,7 +15,6 @@ from dvf_tpu.models import (
 from dvf_tpu.models.layers import gram_matrix, upsample_nearest
 from dvf_tpu.models.vgg import VGGConfig, init_vgg, vgg_features, vgg_param_pspecs
 from dvf_tpu.parallel.mesh import MeshConfig, make_mesh
-from dvf_tpu.utils.compat import shard_map
 
 SMALL = StyleNetConfig(base_channels=8, n_residual=2)
 
@@ -229,7 +228,7 @@ def test_espcn_pspecs_cover_params_and_tp_matches_replicated():
         lambda p, s: jax.device_put(p, NamedSharding(mesh, s)),
         params, specs, is_leaf=lambda s: isinstance(s, P),
     )
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         tp_inner_apply(cfg), mesh=mesh,
         in_specs=(specs, P(None)),
         out_specs=P(None), check_vma=False,
@@ -397,7 +396,7 @@ def test_tp_shard_map_forward_with_fast_convs():
     mesh = make_mesh(MeshConfig(model=2))
     specs = param_pspecs(SMALL)
     inner = tp_inner_apply(fast)
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda p, b: inner(p, b),
         mesh=mesh,
         in_specs=(specs, P()),
@@ -425,7 +424,7 @@ def test_espcn_tp_shard_map_forward_with_fast_convs():
 
     mesh = make_mesh(MeshConfig(model=2))
     inner = e_tp(fast)
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda p, b: inner(p, b),
         mesh=mesh,
         in_specs=(e_pspecs(cfg), P()),

@@ -797,7 +797,7 @@ class TestServeFlightTriggers:
             # Wired through the chained hook (burn check + control
             # plane; the plane leg is a no-op when control is off).
             assert fe.telemetry.on_sample == fe._on_telemetry_sample
-            # Healthy window: 10 deliveries, 1 miss → 0.1 < 0.5: no dump.
+            # Healthy sample: 10 deliveries, 1 miss → 0.1 < 0.5: no dump.
             fe._check_slo_burn({"delivered_total": 0, "slo_miss_total": 0},
                                {"delivered_total": 10, "slo_miss_total": 1})
             assert fe.flight.stats()["dumps"] == 0
@@ -1061,7 +1061,9 @@ class TestExportSchemas:
                                   batch_size=2, height=16, width=16)
         self._assert_clean("bench_device_resident", r)
         self._assert_clean("roofline",
-                           roofline_fields(dict(r, fps=100.0), "tpu"))
+                           roofline_fields(dict(
+                               r, fps=100.0, platform="tpu",
+                               device_kind="TPU v5 lite")))
         self._assert_clean(
             "bench_stage_decomposition",
             bench_stage_decomposition(get_filter("invert"), (1,), 16, 16,

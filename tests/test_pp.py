@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from dvf_tpu.parallel.mesh import MeshConfig, make_mesh
-from dvf_tpu.utils.compat import shard_map
 from dvf_tpu.parallel.pp import (
     pipeline_apply,
     pipeline_stage_specs,
@@ -42,7 +41,7 @@ def _run_pp(layers, x, mesh, n_microbatches=0):
     stacked = stack_layer_params(layers)
     inner = lambda sp, xx: pipeline_apply(  # noqa: E731
         _layer_fn, sp, xx, axis="model", n_microbatches=n_microbatches)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         inner, mesh=mesh,
         in_specs=(pipeline_stage_specs("model", stacked), P("data")),
         out_specs=P("data"), check_vma=False,

@@ -199,12 +199,100 @@ class TestAdmissionControl:
         with pytest.raises(ServeError, match="already exists"):
             fe.open_stream(session_id="cam0")
 
-    def test_stateful_filter_rejected(self):
+    def test_temporal_filter_rejected_when_multi_tenant(self):
         """Temporal state would thread across tenants' batch rows."""
         filt = get_filter("flow_warp", levels=1, win_size=7, n_iters=1,
                           flow_scale=1)
-        with pytest.raises(ValueError, match="stateful"):
+        with pytest.raises(ValueError, match="temporal state"):
             ServeFrontend(filt)
+
+    def test_temporal_filter_served_single_tenant(self):
+        """max_sessions=1 has no second tenant to leak to: the frontend
+        serves flow_warp exactly as an Engine fed the same frames in
+        order would, and every admission starts from pristine state (a
+        fresh stream's first frame passes through, as at engine start)."""
+        from dvf_tpu.runtime.engine import Engine
+
+        kw = dict(levels=1, win_size=7, n_iters=1, flow_scale=1)
+        rng = np.random.default_rng(3)
+        frames = [rng.integers(0, 255, (H, W, 3), np.uint8)
+                  for _ in range(6)]
+        ref = Engine(get_filter("flow_warp", **kw))
+        want = [np.asarray(ref.submit(np.stack(frames[i:i + 2])))
+                for i in (0, 2, 4)]
+        want = [row for out in want for row in out]
+        fe = ServeFrontend(get_filter("flow_warp", **kw),
+                           ServeConfig(batch_size=2, max_sessions=1,
+                                       queue_size=16, slo_ms=60_000))
+        with fe:
+            for _ in range(2):   # second tenant: state reset, same output
+                sid = fe.open_stream()
+                for fr in frames:
+                    fe.submit(sid, fr)
+                    # one frame in flight at a time keeps the batch
+                    # composition (pairs) out of the comparison: flow's
+                    # output depends only on the previous frame
+                got = []
+                deadline = time.time() + 60.0
+                while len(got) < len(frames) and time.time() < deadline:
+                    got += fe.poll(sid)
+                    time.sleep(0.01)
+                assert [d.index for d in got] == list(range(len(frames)))
+                np.testing.assert_array_equal(got[0].frame, frames[0])
+                for d in got:
+                    assert np.abs(d.frame.astype(int)
+                                  - want[d.index].astype(int)).max() <= 1
+                fe.close(sid, drain=True)
+                deadline = time.time() + 10.0
+                while fe.open_count() and time.time() < deadline:
+                    time.sleep(0.01)
+            assert fe.stats()["errors"] == 0
+
+    def test_batch_larger_than_reorder_capacity_loses_nothing(self):
+        """One tenant filling a batch larger than its reorder buffer
+        (batch 64 against the default capacity of 50 — invert_1080p's
+        own batch) must still get every frame: the router drains the
+        buffer as it fills instead of letting the cap evict undelivered
+        frames."""
+        n = 96
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=64, queue_size=n,
+                                       out_queue_size=n, slo_ms=60_000))
+        with fe:
+            sid = fe.open_stream()
+            for i in range(n):
+                fe.submit(sid, tagged_frame(0, i))
+            got = []
+            deadline = time.time() + 60.0
+            while len(got) < n and time.time() < deadline:
+                got += fe.poll(sid)
+                time.sleep(0.01)
+            row = fe.stats()["sessions"][sid]
+        assert [d.index for d in got] == list(range(n))
+        assert row["delivered"] == row["submitted"] == n
+
+    def test_constant_state_filter_is_multiplexed(self):
+        """A neural filter's state is its weights (Filter.constant_state):
+        nothing flows from batch to batch, so two tenants share batches."""
+        filt = get_filter("super_resolution", scale=2)
+        assert filt.stateful and filt.constant_state and not filt.temporal
+        fe = ServeFrontend(filt, ServeConfig(batch_size=2, queue_size=16,
+                                             slo_ms=60_000))
+        with fe:
+            sids = [fe.open_stream(), fe.open_stream()]
+            for sid in sids:
+                for i in range(3):
+                    fe.submit(sid, tagged_frame(sids.index(sid), i))
+            got = {sid: [] for sid in sids}
+            deadline = time.time() + 60.0
+            while (any(len(v) < 3 for v in got.values())
+                   and time.time() < deadline):
+                for sid in sids:
+                    got[sid] += fe.poll(sid)
+                time.sleep(0.01)
+        for sid in sids:
+            assert [d.index for d in got[sid]] == [0, 1, 2]
+            assert got[sid][0].frame.shape == (2 * H, 2 * W, 3)
 
     def test_geometry_mismatch_rejected(self):
         fe = ServeFrontend(get_filter("invert"),
