@@ -210,8 +210,9 @@ def analytic_frame_ms(plan: Plan, cal: Optional[dict],
         # No calibration at all: fall back to the stage profile's
         # device component, else a 1 ms placeholder (ranking then
         # reduces to the tick/depth structure, which is still honest).
-        step = _profile_mean_ms(stage_profile, "device",
-                                default=1.0) * cal_batch
+        step = (_profile_mean_ms(stage_profile, "inflight_wait")
+                + _profile_mean_ms(stage_profile, "device",
+                                   default=1.0)) * cal_batch
     step_ms = float(step) * (_DISPATCH_FRAC + (1.0 - _DISPATCH_FRAC) * scale)
 
     h2d = cal.get("h2d_block_ms")
@@ -359,7 +360,7 @@ def predicted_tick_cost_ms(stage_profile: Optional[dict],
         return float(t)
     per_frame = sum(
         _profile_mean_ms(stage_profile, c)
-        for c in ("assemble_h2d", "device", "d2h"))
+        for c in ("assemble_h2d", "inflight_wait", "device", "d2h"))
     if per_frame > 0:
         return per_frame * max(1, int(batch_size))
     return None

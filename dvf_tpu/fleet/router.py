@@ -504,10 +504,15 @@ class FleetFrontend:
                 for e in entries:
                     prof = load_stage_profile(
                         self.config.serve.profile_dir, e["key"].render())
-                    comp = ((prof or {}).get("components_ms")
-                            or {}).get("device") or {}
+                    comps = (prof or {}).get("components_ms") or {}
+                    comp = comps.get("device") or {}
                     if comp.get("mean_ms") is not None:
-                        device_ms.append(float(comp["mean_ms"]))
+                        # submit → result ready: since the stage clock
+                        # split it, inflight_wait + device
+                        device_ms.append(
+                            float(comp["mean_ms"]) + float(
+                                (comps.get("inflight_wait") or {})
+                                .get("mean_ms") or 0.0))
                 if device_ms:
                     self._profile_device_ms = max(device_ms)
 

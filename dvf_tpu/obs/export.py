@@ -649,8 +649,16 @@ class FlightRecorder:
                 import jax
 
                 trace_dir = os.path.join(dump_dir, "device_trace")
+                # The device trace's clock starts where start_trace was
+                # called: its host-clock time goes into the dump, so
+                # trace.pftrace (wall-clock epochs) and device_trace/
+                # can be laid on one clock — the serve-path counterpart
+                # of the epoch runtime/pipeline.py hands to
+                # merge_with_device_trace.
+                epoch = time.time()
                 jax.profiler.start_trace(trace_dir)
                 try:
+                    self._note_device_epoch(dump_dir, epoch)
                     time.sleep(self.jax_profile_s)
                 finally:
                     jax.profiler.stop_trace()
@@ -673,6 +681,18 @@ class FlightRecorder:
 
         threading.Thread(target=capture, name="dvf-flight-profile",
                          daemon=True).start()
+
+    def _note_device_epoch(self, dump_dir: str, epoch: float) -> None:
+        """Add ``device_trace_epoch`` to the dump's meta.json (written
+        before the capture window opened)."""
+        path = os.path.join(dump_dir, "meta.json")
+        try:
+            with open(path) as f:
+                meta = json.load(f)
+        except (OSError, json.JSONDecodeError):
+            meta = {}
+        meta["device_trace_epoch"] = epoch
+        self._json(dump_dir, "meta.json", meta)
 
     def stats(self) -> dict:
         with self._lock:

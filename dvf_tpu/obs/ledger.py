@@ -106,6 +106,59 @@ CAUSE_AUTOPLAN = "autoplan"  # auto-plan plane decision (search/cache hit)
 TRACK_LEDGER = 6
 
 
+class XlaCompileWatch:
+    """Process-wide count of XLA backend compilations, whatever program
+    compiled (an engine's ``jit_step``, the ``jit_concatenate`` that joins
+    a batch's chunks, a retrace nobody asked for). jax emits
+    ``/jax/core/compile/backend_compile_duration`` around
+    compile-or-load-from-the-persistent-cache, so a cache hit counts too;
+    its seconds are summed beside the count. One listener for the
+    process, registered while at least one frontend runs
+    (:meth:`acquire` at ``start()``, :meth:`release` at ``stop()``); the
+    totals are monotone across registrations."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        self._refs = 0
+        self._lock = threading.Lock()
+
+    def _on_duration(self, event: str, duration_secs: float, **_kw) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self.count += 1
+                self.seconds += duration_secs
+
+    def acquire(self) -> None:
+        from jax import monitoring
+
+        with self._lock:
+            self._refs += 1
+            if self._refs == 1:
+                monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+
+    def release(self) -> None:
+        from jax import monitoring
+
+        with self._lock:
+            if self._refs == 0:
+                return
+            self._refs -= 1
+            if self._refs == 0:
+                monitoring.unregister_event_duration_listener(
+                    self._on_duration)
+
+    def totals(self) -> tuple:
+        with self._lock:
+            return self.count, round(self.seconds, 6)
+
+
+XLA_COMPILES = XlaCompileWatch()
+
+
 class ReconfigLedger:
     """Bounded ring of reconfiguration events + open stall windows.
 

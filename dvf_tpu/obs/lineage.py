@@ -38,22 +38,33 @@ the aggregation/exemplar machinery that makes it cheap at serving rates:
     controllers annotate their decisions with and a topology-aware
     planner seeds from.
 
-Serve-path components (in hop order):
+Serve-path components (in hop order). Every boundary is one wall-clock
+stamp taken once inside the serve path, whether or not lineage is armed
+(``obs.metrics.BatchStamps`` per batch, ``Slot.t_pending`` per frame);
+a lineage is a per-frame VIEW of those stamps, the always-on
+``obs.metrics.StageStats`` counters are the per-bucket sums of the same
+intervals, and the Tracer's dispatch/collect spans are drawn from them:
 
 ==============  ============================================================
 queue_ingress   capture/submit → drained into the scheduler's pending
                 staging (session ingress queue wait, incl. the client's
                 capture→submit gap)
-queue_bucket    pending → chosen for a device batch (bucket queue wait —
-                the EDF/cost scheduling delay, where an overloaded
-                bucket's p99 usually went)
-assemble_h2d    staging start → ``Engine.submit`` returned (batch
-                assembly + host-to-device transfer)
-device          submit → device result ready (device queue + compute —
-                the per-bucket tick)
-d2h             device ready → materialized into host memory
-deliver         materialized → handed to the client (router demux +
-                reorder wait + emit)
+queue_bucket    pending → chosen into a batch (``select_bucket``
+                returned): waiting to be picked by the EDF/cost scheduler
+permit_wait     chosen → in-flight permit acquired: the batch is FROZEN
+                (later arrivals cannot join it) and waits for a device
+                slot — where padded batches come from
+assemble_h2d    permit → ``Engine.submit`` returned (batch assembly +
+                host-to-device transfer)
+inflight_wait   submit returned → the collect thread took the batch off
+                the in-flight queue (device compute overlapped with the
+                collect thread's work on EARLIER batches)
+device          taken → ``block_until_ready`` returned: what the collect
+                thread still had to wait for the device (0 when the
+                device finished long before the batch was taken)
+d2h             device ready → fetched into host memory
+deliver         fetched → handed to the client's out queue / sink (row
+                copy + reorder wait + emit)
 ==============  ============================================================
 
 Extended components appended past delivery: ``encode``/``send`` (the
@@ -75,8 +86,11 @@ import numpy as np
 
 # Canonical hop order for rendering (components not listed sort last, in
 # first-seen order). One place owns the strings; consumers match on them.
-SERVE_COMPONENTS = ("queue_ingress", "queue_bucket", "assemble_h2d",
-                    "device", "d2h", "deliver")
+SERVE_COMPONENTS = ("queue_ingress", "queue_bucket", "permit_wait",
+                    "assemble_h2d", "inflight_wait", "device", "d2h",
+                    "deliver")
+# The batch-level subset (one interval per batch, shared by its frames).
+BATCH_COMPONENTS = SERVE_COMPONENTS[2:7]
 WIRE_COMPONENTS = ("encode", "send")
 RPC_COMPONENT = "rpc"
 # Broadcast fan-out hops (dvf_tpu.broadcast): the tier encode reuses

@@ -7,12 +7,15 @@ arithmetic and adds percentiles, which the north-star metric requires
 
 from __future__ import annotations
 
+import math
 import os
+import threading
 import time
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from dvf_tpu.obs.lineage import BATCH_COMPONENTS, SERVE_COMPONENTS
 from dvf_tpu.resilience.faults import FaultStats  # noqa: F401 — re-export:
 #   the per-kind fault counters are part of the metrics surface (embedded
 #   in pipeline/serve/worker stats and the bench JSON) even though the
@@ -214,15 +217,13 @@ class IngestStats:
         self.stage_ms_total = 0.0
         self.put_ms_total = 0.0
         self.wait_ms_total = 0.0
-        self.span_ms_total = 0.0
 
-    def record_batch(self, stage_ms: float, put_ms: float, wait_ms: float,
-                     span_ms: float) -> None:
+    def record_batch(self, stage_ms: float, put_ms: float,
+                     wait_ms: float) -> None:
         self.batches += 1
         self.stage_ms_total += stage_ms
         self.put_ms_total += put_ms
         self.wait_ms_total += wait_ms
-        self.span_ms_total += span_ms
 
     def overlap_efficiency(self) -> Optional[float]:
         if (self.effective_mode != "streamed" or self.batches == 0
@@ -244,6 +245,11 @@ class IngestStats:
             "stage_ms": round(self.stage_ms_total / n, 4),
             "h2d_put_ms": round(self.put_ms_total / n, 4),
             "h2d_wait_ms": round(self.wait_ms_total / n, 4),
+            # Cumulative totals beside the lifetime means: a window delta
+            # between two reads needs no multiplying back by ``batches``.
+            "stage_ms_total": round(self.stage_ms_total, 4),
+            "h2d_put_ms_total": round(self.put_ms_total, 4),
+            "h2d_wait_ms_total": round(self.wait_ms_total, 4),
             "h2d_block_ms": (round(self.h2d_block_ms, 4)
                              if self.h2d_block_ms else None),
             "overlap_efficiency": (round(eff, 4)
@@ -293,7 +299,6 @@ class EgressStats:
         #   across a steady-state run — the allocation-regression tests)
         self.d2h_wait_ms_total = 0.0     # blocked on shard host copies
         self.copy_ms_total = 0.0         # scatter into the output slab
-        self.span_ms_total = 0.0
         self.encode_batches = 0
         self.encode_ms_total = 0.0       # in-pool wall span per batch
         self.encode_wait_ms_total = 0.0  # exposed drain wait per batch
@@ -304,12 +309,10 @@ class EgressStats:
         self.send_batches = 0
         self.send_ms_total = 0.0
 
-    def record_fetch(self, wait_ms: float, copy_ms: float,
-                     span_ms: float) -> None:
+    def record_fetch(self, wait_ms: float, copy_ms: float) -> None:
         self.batches += 1
         self.d2h_wait_ms_total += wait_ms
         self.copy_ms_total += copy_ms
-        self.span_ms_total += span_ms
 
     def record_encode(self, encode_ms: float, wait_ms: float) -> None:
         self.encode_batches += 1
@@ -348,6 +351,13 @@ class EgressStats:
             "batches": self.batches,
             "d2h_wait_ms": round(self.d2h_wait_ms_total / n, 4),
             "copy_ms": round(self.copy_ms_total / n, 4),
+            # Cumulative totals beside the lifetime means (window deltas).
+            "d2h_wait_ms_total": round(self.d2h_wait_ms_total, 4),
+            "copy_ms_total": round(self.copy_ms_total, 4),
+            "encode_ms_total": round(self.encode_ms_total, 4),
+            "encode_wait_ms_total": round(self.encode_wait_ms_total, 4),
+            "entropy_ms_total": round(self.entropy_ms_total, 4),
+            "send_ms_total": round(self.send_ms_total, 4),
             "d2h_block_ms": (round(self.d2h_block_ms, 4)
                              if self.d2h_block_ms else None),
             "overlap_efficiency": (round(eff, 4)
@@ -361,6 +371,259 @@ class EgressStats:
                              / max(1, self.send_batches), 4),
             "pool_allocs": self.pool_allocs,
         }
+
+
+# ---------------------------------------------------------------------------
+# The serve path's stage clock: one set of stamps, always on
+# ---------------------------------------------------------------------------
+
+# One histogram geometry for every component, stated once: log-spaced
+# edges from 0.1 ms to 100 s, 16 bins a decade (an edge ratio of 1.155),
+# plus one bin below and one above. Bin 0 also takes zero and negative
+# intervals (a client ``ts`` ahead of the drain).
+HIST_LO_MS = 0.1
+HIST_DECADES = 6
+HIST_PER_DECADE = 16
+HIST_BINS = HIST_DECADES * HIST_PER_DECADE + 2
+
+
+def hist_bin(ms: float) -> int:
+    """The cumulative histogram's bin for one interval in ms."""
+    if ms < HIST_LO_MS:
+        return 0
+    i = int(math.log10(ms / HIST_LO_MS) * HIST_PER_DECADE) + 1
+    return i if i < HIST_BINS else HIST_BINS - 1
+
+
+class BatchStamps:
+    """One device batch's wall-clock stamps (``time.time()``, the clock
+    the Tracer's epoch and ``jax.profiler``'s ``start_trace`` are read
+    on), each taken ONCE where the batch crosses the boundary:
+
+    ``t_chosen`` (dispatch: ``select_bucket`` returned) → ``t_permit``
+    (in-flight permit acquired) → ``t_submit`` (``Engine.submit``
+    returned) → ``t_taken`` (collect: popped off the in-flight queue) →
+    ``t_ready`` (``block_until_ready`` returned) → ``t_fetched``
+    (``fetcher.fetch`` returned) → ``t_routed`` (``router.route``
+    returned). Everything that times a batch is a view of these: the
+    bucket's :class:`StageStats`, ``FrameLineage`` marks, the Tracer's
+    dispatch/collect spans, the tick-cost sample. ``stages`` is the
+    bucket's StageStats (None on ad-hoc plans: nothing is folded).
+    """
+
+    __slots__ = ("stages", "t_chosen", "t_permit", "t_submit", "t_taken",
+                 "t_ready", "t_fetched", "t_routed", "ms", "bins")
+
+    def __init__(self, stages: "Optional[StageStats]" = None,
+                 t_chosen: float = 0.0):
+        self.stages = stages
+        self.t_chosen = t_chosen
+        self.t_permit = self.t_submit = self.t_taken = 0.0
+        self.t_ready = self.t_fetched = self.t_routed = 0.0
+        self.ms: Optional[tuple] = None    # the five batch-level intervals,
+        self.bins: Optional[tuple] = None  # closed once by close_batch()
+
+    def close_batch(self) -> None:
+        """Collect thread, after the fetch and before ``route``: the five
+        batch-level intervals (``BATCH_COMPONENTS`` order) and their
+        histogram bins, computed once for every frame of the batch."""
+        ms = ((self.t_permit - self.t_chosen) * 1e3,
+              (self.t_submit - self.t_permit) * 1e3,
+              (self.t_taken - self.t_submit) * 1e3,
+              (self.t_ready - self.t_taken) * 1e3,
+              (self.t_fetched - self.t_ready) * 1e3)
+        self.ms = ms
+        self.bins = tuple(hist_bin(v) for v in ms)
+
+    def marks(self) -> List[tuple]:
+        """The batch-level ``(component, wall_ts)`` lineage marks."""
+        return list(zip(BATCH_COMPONENTS,
+                        (self.t_permit, self.t_submit, self.t_taken,
+                         self.t_ready, self.t_fetched)))
+
+
+class _StageCell:
+    """One component's cumulative cell: sum, max, histogram."""
+
+    __slots__ = ("ms_total", "max_ms", "hist", "batches", "batch_ms_total")
+
+    def __init__(self):
+        self.ms_total = 0.0
+        self.max_ms = 0.0
+        self.hist = [0] * HIST_BINS
+        self.batches = 0
+        self.batch_ms_total = 0.0
+
+    def add(self, ms: float) -> None:
+        self.ms_total += ms
+        if ms > self.max_ms:
+            self.max_ms = ms
+        self.hist[hist_bin(ms)] += 1
+
+    def add_batch(self, ms: float) -> None:
+        self.batches += 1
+        self.batch_ms_total += ms
+        if ms > self.max_ms:
+            self.max_ms = ms
+
+    def summary(self, frames: Optional[int], batch_level: bool) -> dict:
+        row = {"max_ms": round(self.max_ms, 4),
+               # sparse: [bin, count] for the occupied bins only
+               "hist": [[i, n] for i, n in enumerate(self.hist) if n]}
+        if frames is not None:     # a frame component (route is not)
+            row["frames"] = frames
+            row["ms_total"] = round(self.ms_total, 4)
+        if batch_level:
+            row["batches"] = self.batches
+            row["batch_ms_total"] = round(self.batch_ms_total, 4)
+        return row
+
+
+class StageStats:
+    """Always-on per-bucket stage counters: where a delivered frame's
+    latency went, and what the two pacing threads did for this bucket.
+
+    Eight frame components in hop order (``obs.lineage.SERVE_COMPONENTS``),
+    every one cumulative: ``frames``, ``ms_total`` (frame-weighted: a
+    batch-level interval counts once per delivered frame of its batch),
+    ``max_ms`` and a histogram on the fixed edges above, so a window
+    delta between two reads yields a mean AND percentiles. Beside them
+    ``delivered`` and ``latency_ms_total`` (the sum of ``now − ts`` over
+    the same frames): the eight ``ms_total`` add up to it, because every
+    interval telescopes between the same stamps. Only delivered frames
+    are folded, each exactly once, at ``StreamSession.deliver_ready``.
+
+    The batch-level components also carry ``batches`` and
+    ``batch_ms_total`` (once per batch, whatever its fill): the pacing
+    threads' states for this bucket — dispatch ``{permit_wait,
+    assemble_h2d}``, collect ``{device, d2h, route}`` (``route``:
+    fetched → ``router.route`` returned; a thread state, no frame
+    component, since ``deliver`` already covers the frames' share of it).
+
+    Writers: the dispatch thread writes its two batch cells, the collect
+    thread the other four, so those take no lock; the frame fold runs on
+    whichever thread delivers (collect, or a finalize) and takes one
+    lock per delivery round. Readers see monotone values.
+    """
+
+    def __init__(self):
+        self.delivered = 0
+        self.latency_ms_total = 0.0
+        self.cells: Dict[str, _StageCell] = {
+            c: _StageCell() for c in SERVE_COMPONENTS}
+        self.route = _StageCell()
+        self._lock = threading.Lock()
+        c = self.cells
+        self._frame_cells = (c["queue_ingress"], c["queue_bucket"],
+                             c["deliver"])
+        self._batch_cells = tuple(c[n] for n in BATCH_COMPONENTS)
+
+    # -- writers ---------------------------------------------------------
+
+    def note_dispatched(self, st: BatchStamps) -> None:
+        """Dispatch thread, once ``Engine.submit`` returned."""
+        permit, asm = self._batch_cells[0], self._batch_cells[1]
+        permit.add_batch((st.t_permit - st.t_chosen) * 1e3)
+        asm.add_batch((st.t_submit - st.t_permit) * 1e3)
+
+    def note_collected(self, st: BatchStamps) -> None:
+        """Collect thread, once ``router.route`` returned (``st`` closed)."""
+        ms = st.ms
+        cells = self._batch_cells
+        cells[2].add_batch(ms[2])
+        cells[3].add_batch(ms[3])
+        cells[4].add_batch(ms[4])
+        route_ms = (st.t_routed - st.t_fetched) * 1e3
+        self.route.add_batch(route_ms)
+        self.route.hist[hist_bin(route_ms)] += 1
+
+    def fold_delivered(self, rows) -> None:
+        """Fold one delivery round: ``rows`` = ``[(slot, now), ...]`` of
+        frames this bucket served, ``now`` the clock read their latency
+        was computed from. One lock round; consecutive frames of one
+        batch share its closed intervals."""
+        qi, qb, dl = self._frame_cells
+        with self._lock:
+            cur, n, lat = None, 0, 0.0
+            for slot, now in rows:
+                st = slot.stamps
+                ts = slot.ts
+                tp = slot.t_pending
+                qi.add((tp - ts) * 1e3)
+                qb.add((st.t_chosen - tp) * 1e3)
+                dl.add((now - st.t_fetched) * 1e3)
+                lat += (now - ts) * 1e3
+                if st is not cur:
+                    if cur is not None:
+                        self._fold_batch_rows(cur, n)
+                    cur, n = st, 0
+                n += 1
+            if cur is not None:
+                self._fold_batch_rows(cur, n)
+            self.latency_ms_total += lat
+            self.delivered += len(rows)
+
+    def _fold_batch_rows(self, st: BatchStamps, n: int) -> None:
+        for cell, ms, b in zip(self._batch_cells, st.ms, st.bins):
+            cell.ms_total += ms * n
+            cell.hist[b] += n
+
+    # -- export ----------------------------------------------------------
+
+    def summary(self) -> dict:
+        """``stats()["buckets"][label]["stages"]``. ``t`` is the read's
+        own wall time, so two reads carry the window between them."""
+        with self._lock:
+            frames = self.delivered
+            doc = {
+                "t": time.time(),
+                "delivered": frames,
+                "latency_ms_total": round(self.latency_ms_total, 4),
+                "hist_lo_ms": HIST_LO_MS,
+                "hist_bins_per_decade": HIST_PER_DECADE,
+                "hist_bins": HIST_BINS,
+                "components": {
+                    name: cell.summary(frames, name in BATCH_COMPONENTS)
+                    for name, cell in self.cells.items()},
+            }
+        doc["route"] = self.route.summary(None, True)
+        return doc
+
+
+class ThreadClock:
+    """One pacing thread's wall-time ledger: every interval of the
+    thread's life lands in exactly one state, so the states sum to
+    ``accounted_to − started``. ``idle`` is whatever belongs to no
+    bucket (no plan and sleeping a tick, control actions, an empty
+    in-flight queue, a batch that was shed); the named states are the
+    batch intervals the bucket's :class:`StageStats` also holds, summed
+    here over every bucket the frontend ever had. Single writer (the
+    thread itself); a replacement thread (supervised recovery) adopts
+    its predecessor's clock, so the ledger spans the frontend's life."""
+
+    def __init__(self, states, now: Optional[float] = None):
+        self.started = time.time() if now is None else now
+        self.mark = self.started      # everything before it is accounted
+        self.ms: Dict[str, float] = {"idle": 0.0, **{s: 0.0 for s in states}}
+
+    def spend(self, state: str, until: float) -> None:
+        """Everything since the last accounted instant, up to ``until``
+        (a stamp the caller already took), was ``state``."""
+        self.ms[state] += (until - self.mark) * 1e3
+        self.mark = until
+
+    def successor(self) -> "ThreadClock":
+        """The ledger a replacement thread carries on from."""
+        nxt = ThreadClock((), self.started)
+        nxt.ms = dict(self.ms)
+        nxt.mark = self.mark
+        return nxt
+
+    def summary(self) -> dict:
+        mark = self.mark
+        return {"started": self.started, "accounted_to": mark,
+                "wall_ms": round((mark - self.started) * 1e3, 4),
+                **{f"{k}_ms": round(v, 4) for k, v in self.ms.items()}}
 
 
 class RateLogger:

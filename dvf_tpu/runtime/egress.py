@@ -132,6 +132,8 @@ class ShardedBatchFetcher:
         self.slots = max(1, slots)
         self.tracer = tracer
         self.track = track
+        self.wall_offset_s = time.time() - time.perf_counter()  # monotonic
+        #   → wall, once per fetcher (not per shard span)
         self.chaos = chaos  # resilience.chaos.FaultPlan — the "d2h"
         #   injection site fires per shard fetch when armed
         self.stats = stats if stats is not None else EgressStats(
@@ -210,16 +212,13 @@ class ShardedBatchFetcher:
         """Materialize one batch; blocks until the device is done (like
         the ``np.asarray`` it replaces) but scatters shard host copies
         into the slot's preallocated slab as each one lands."""
-        t_begin = time.perf_counter()
         if not self._streamable(result):
             # A mid-stream geometry change can hand this fetcher a batch
             # compiled at another signature — fall back per batch rather
             # than corrupt the slab. (Intentional monolithic mode and
             # non-jax results land here too: the classic fetch.)
             out = np.asarray(result)
-            self.stats.record_fetch(
-                wait_ms=0.0, copy_ms=0.0,
-                span_ms=(time.perf_counter() - t_begin) * 1e3)
+            self.stats.record_fetch(wait_ms=0.0, copy_ms=0.0)
             return out
         # Compute wait is not D2H: exclude it from the exposed-transfer
         # clock so overlap_efficiency judges the fetch, not the device.
@@ -252,14 +251,12 @@ class ShardedBatchFetcher:
             wait_s += t1 - t0
             copy_s += t2 - t1
             if tracer is not None and tracer.enabled:
-                off = time.time() - time.perf_counter()  # monotonic → wall
+                off = self.wall_offset_s
                 b0 = sh.index[0]
                 tracer.complete(
                     EGRESS_D2H, t0 + off, t2 + off, self.track,
                     rows=f"{b0.start or 0}:{b0.stop}", bytes=host.nbytes)
-        self.stats.record_fetch(
-            wait_ms=wait_s * 1e3, copy_ms=copy_s * 1e3,
-            span_ms=(time.perf_counter() - t_begin) * 1e3)
+        self.stats.record_fetch(wait_ms=wait_s * 1e3, copy_ms=copy_s * 1e3)
         return slab
 
     def owns(self, out: np.ndarray) -> bool:
@@ -349,6 +346,7 @@ class AsyncCodecPlane:
         self.stats = stats
         self.tracer = tracer
         self.track = track
+        self.wall_offset_s = time.time() - time.perf_counter()
         self._pending: "deque[_EncodeEntry]" = deque()
 
     def __len__(self) -> int:
@@ -430,7 +428,7 @@ class AsyncCodecPlane:
                         self.stats.record_entropy(ms)
             tracer = self.tracer
             if tracer is not None and tracer.enabled and entry.futures:
-                off = time.time() - time.perf_counter()
+                off = self.wall_offset_s
                 tracer.complete(EGRESS_ENCODE, entry.t_submit + off,
                                 max(entry.t_done, entry.t_submit) + off,
                                 self.track, rows=len(entry.metas))

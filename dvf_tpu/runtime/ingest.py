@@ -161,6 +161,9 @@ class ShardedBatchAssembler:
         self.slots = max(1, slots)
         self.tracer = tracer
         self.track = track
+        self.wall_offset_s = time.time() - time.perf_counter()  # monotonic
+        #   → wall, computed once per assembler: every traced span of this
+        #   object lands on the wall clock by the same offset
         self.chaos = chaos  # resilience.chaos.FaultPlan — the "h2d"
         #   injection site fires per shard put when armed (None = zero
         #   overhead)
@@ -420,7 +423,7 @@ class BatchBuilder:
         tracer = self.asm.tracer
         if tracer is not None and tracer.enabled:
             nbytes = sum(slabs[key].nbytes for _, key in c.targets)
-            off = time.time() - time.perf_counter()  # monotonic → wall
+            off = self.asm.wall_offset_s
             tracer.complete(INGEST_H2D, t0 + off, t1 + off, self.asm.track,
                             rows=f"{c.start}:{c.stop}", bytes=nbytes)
         self._inflight.append(arrs)
@@ -455,7 +458,7 @@ class BatchBuilder:
             for row in range(valid, b):
                 np.copyto(buf[row], buf[valid - 1])
             self._stage_s += time.perf_counter() - t0
-            self._record(time.perf_counter())
+            self._record()
             return buf, False
         # Pad from the already-staged slabs: the source row's chunk may
         # be launched (its slab is only read), the destination rows are
@@ -489,19 +492,18 @@ class BatchBuilder:
         t_end = time.perf_counter()
         tracer = self.asm.tracer
         if tracer is not None and tracer.enabled and self._first_put_t:
-            off = time.time() - time.perf_counter()  # monotonic → wall
+            off = self.asm.wall_offset_s
             tracer.complete(INGEST_OVERLAP, self._first_put_t + off,
                             t_end + off, self.asm.track, valid=valid)
             tracer.complete(INGEST_STAGE, self._t_begin + off, t_end + off,
                             self.asm.track,
                             stage_ms=round(self._stage_s * 1e3, 3))
-        self._record(t_end)
+        self._record()
         return batch, True
 
-    def _record(self, t_end: float) -> None:
+    def _record(self) -> None:
         self.asm.stats.record_batch(
             stage_ms=self._stage_s * 1e3,
             put_ms=self._put_s * 1e3,
             wait_ms=self._wait_s * 1e3,
-            span_ms=(t_end - self._t_begin) * 1e3,
         )

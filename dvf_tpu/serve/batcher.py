@@ -47,6 +47,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from dvf_tpu.obs.metrics import BatchStamps
 from dvf_tpu.serve.session import Slot, StreamSession
 
 
@@ -73,11 +74,13 @@ class BatchPlan:
     #   behind THEIR device time, which would contaminate the bucket's
     #   per-program tick-cost EWMA (the EDF/cost denominator) toward the
     #   shared pipeline latency instead of this program's cost
-    lin_marks: Any = None  # lineage-armed frontends: the BATCH-level
-    #   (component, wall_ts) marks shared by every slot in this batch —
-    #   assemble_h2d at dispatch, device/d2h at collect; the router
-    #   extends each slot's FrameLineage with them before demux (one
-    #   stamp per batch, not per frame). None = lineage off.
+    stamps: BatchStamps = dataclasses.field(default_factory=BatchStamps)
+    #   the batch's wall-clock stamps, always carried and each taken
+    #   once (obs.metrics.BatchStamps): chosen / permit / submit on the
+    #   dispatch thread, taken / ready / fetched / routed on the collect
+    #   thread. The bucket's always-on stage counters, the lineage marks
+    #   the router fans out, the Tracer's dispatch/collect spans and the
+    #   tick-cost sample are all views of these.
     audit_rows: Any = None  # audit-armed frontends (obs.audit): rows
     #   the shadow-replay sampler picked this tick — [(row, input-copy,
     #   session_id, frame_index, lineage), ...]; the collect side pairs

@@ -57,14 +57,22 @@ class ResultRouter:
             #   become a no-op, not a second release of the same claims
         touched = []
         delivered = 0
-        marks = plan.lin_marks
+        st = plan.stamps
+        marks = None
         for row, slot in enumerate(plan.slots[: plan.valid]):
             s = slot.session
-            if slot.lin is not None and marks:
-                # Batch-level hop stamps (assemble_h2d / device / d2h)
-                # fan out to every slot's lineage here — the one place
-                # each routed row already passes.
-                slot.lin.marks.extend(marks)
+            # The batch's stamps ride each slot to its delivery — the one
+            # place every routed row already passes: deliver_ready folds
+            # the always-on stage counters from them.
+            slot.stamps = st
+            if slot.lin is not None and st.t_fetched:
+                # Lineage view (armed frontends): the frame's marks are
+                # the slot's own t_pending, then the batch's stamps; no
+                # clock is read for them. deliver_ready closes the trail.
+                if marks is None:
+                    marks = st.marks()
+                slot.lin.marks = [("queue_ingress", slot.t_pending),
+                                  ("queue_bucket", st.t_chosen), *marks]
             s.complete(slot, out[row].copy())
             if s.state == "closed":
                 self.late_after_close += 1

@@ -80,7 +80,14 @@ from dvf_tpu.obs.memory import (
     LeakTrendWatch,
     attach_memory_provider,
 )
-from dvf_tpu.obs.metrics import EgressStats, IngestStats, LatencyStats
+from dvf_tpu.obs.metrics import (
+    BatchStamps,
+    EgressStats,
+    IngestStats,
+    LatencyStats,
+    StageStats,
+    ThreadClock,
+)
 from dvf_tpu.obs.registry import (
     COUNTER,
     GAUGE,
@@ -125,10 +132,17 @@ from dvf_tpu.serve.session import (
 )
 
 # Trace track ids (one lane per stage, the pipeline's convention):
-# dispatch staging, device span, per-shard H2D / D2H transfer lanes.
-# The reconfiguration ledger stamps its events on its own lane
-# (obs.ledger.TRACK_LEDGER = 6), clear of all of these.
-TRACK_DISPATCH, TRACK_DEVICE, TRACK_H2D, TRACK_D2H = 0, 1, 3, 4
+# dispatch thread, device span, collect thread, per-shard H2D / D2H
+# transfer lanes. The reconfiguration ledger stamps its events on its
+# own lane (obs.ledger.TRACK_LEDGER = 6), clear of all of these.
+TRACK_DISPATCH, TRACK_DEVICE, TRACK_COLLECT, TRACK_H2D, TRACK_D2H = (
+    0, 1, 2, 3, 4)
+
+# The two pacing threads' per-bucket states (obs.metrics.ThreadClock;
+# ``idle`` is whatever belongs to no bucket). With ``trace`` on each is
+# one span per batch on the thread's lane, ``<thread>:<state>``.
+DISPATCH_STATES = ("permit_wait", "assemble_h2d")
+COLLECT_STATES = ("device", "d2h", "route")
 
 # dvf_compile_ms histogram bounds: serving compiles span sub-ms pool
 # hits through multi-second cold XLA runs.
@@ -373,6 +387,11 @@ class _Bucket:
         #   them were still in flight (those fetch through plan.fetcher);
         #   released by collect once the bucket's window drains to zero
         self.egress_stats: Optional[EgressStats] = None
+        self.stages = StageStats()  # always-on stage counters: where a
+        #   delivered frame's latency went (eight components that sum to
+        #   it) and what the two pacing threads did for this bucket —
+        #   every interval read off the batch's one set of stamps
+        #   (BatchPlan.stamps), reported as stats_row()["stages"]
         self._tick_cost_ms: Optional[float] = None  # live EWMA
         self.last_dispatch_t: Optional[float] = None  # wall clock of
         #   this bucket's most recent batch submit — the reconfiguration
@@ -409,7 +428,8 @@ class _Bucket:
 
     def observe_tick(self, wall_ms: float, sample: bool = True,
                      valid: Optional[int] = None) -> None:
-        """Collect-side cost sample (submit → materialized, wall).
+        """Collect-side cost sample (submit returned → fetched, wall —
+        read off the batch's stamps, not a clock of its own).
         ``sample=False`` counts the batch without feeding the cost EWMA —
         the wall time of a batch that queued behind other in-flight
         work measures the pipeline, not this bucket's program.
@@ -507,7 +527,13 @@ class _Bucket:
             "fault_budget": self.budget.summary(),
             "engine_batches": self.engine.stats.batches,
             "engine_compile_count": self.engine.stats.compile_count,
+            "stages": self.stages.summary(),
         }
+        # Process-wide XLA backend compilations (obs.ledger
+        # XlaCompileWatch), the same two numbers on every row: a window
+        # delta says whether ANYTHING compiled while it was open.
+        (row["xla_compiles_total"],
+         row["xla_compile_s_total"]) = ledger_mod.XLA_COMPILES.totals()
         if self.ingest_stats is not None:
             row["ingest"] = self.ingest_stats.summary()
         if self.egress_stats is not None:
@@ -763,6 +789,11 @@ class ServeFrontend:
         self._recover_lock = threading.Lock()
         self._collect_gen = 0  # bumped by recovery; a stale collect thread
         #   exits at its next loop check (and a wedged one, when it wakes)
+        # The pacing threads' wall-time ledgers (stats()["threads"]):
+        # each thread's states sum to its wall time. Built at start().
+        self._dispatch_clock: Optional[ThreadClock] = None
+        self._collect_clock: Optional[ThreadClock] = None
+        self._xla_watch_held = False
         # Plain unbounded FIFO: depth is already bounded by the semaphore,
         # and drop-oldest semantics here would silently leak a permit and
         # the dropped batch's inflight claims.
@@ -790,6 +821,13 @@ class ServeFrontend:
     def start(self) -> "ServeFrontend":
         if self._threads:
             raise ServeError("frontend already started")
+        # Count every XLA backend compile while this frontend runs,
+        # whether or not the reconfiguration ledger is armed.
+        ledger_mod.XLA_COMPILES.acquire()
+        self._xla_watch_held = True
+        now = time.time()
+        self._dispatch_clock = ThreadClock(DISPATCH_STATES, now)
+        self._collect_clock = ThreadClock(COLLECT_STATES, now)
         self._threads = [
             threading.Thread(target=self._dispatch, name="dvf-serve-dispatch",
                              daemon=True),
@@ -830,6 +868,9 @@ class ServeFrontend:
         for t in self._threads:
             if t is not threading.current_thread():
                 t.join(timeout=timeout)
+        if self._xla_watch_held:
+            self._xla_watch_held = False
+            ledger_mod.XLA_COMPILES.release()
         with self._lock:
             sessions = list(self._sessions.items())
             for sid, s in sessions:
@@ -3174,7 +3215,7 @@ class ServeFrontend:
                 old_q = self._inflight
                 while True:  # shed everything queued for collection
                     try:
-                        seq, plan, _result, _t0 = old_q.get_nowait()
+                        seq, plan, _result = old_q.get_nowait()
                     except queue.Empty:
                         break
                     if plan.bucket is not None:
@@ -3308,7 +3349,7 @@ class ServeFrontend:
                 # watchdog (whose next trip would otherwise catch it) off.
                 while True:
                     try:
-                        seq, plan, _result, _t0 = old_q.get_nowait()
+                        seq, plan, _result = old_q.get_nowait()
                     except queue.Empty:
                         break
                     self.router.discard(plan, kind=kind)
@@ -3339,6 +3380,8 @@ class ServeFrontend:
 
     def _dispatch(self) -> None:
         seq = 0
+        clock = self._dispatch_clock
+        tracer = self.tracer
         try:
             while not self._stop.is_set():
                 if self._recovering.is_set():
@@ -3366,6 +3409,10 @@ class ServeFrontend:
                     # pointer swing per swap — the only serving time a
                     # reconfiguration consumes on this thread.
                     self._apply_commits_dispatch()
+                # The tick's clock read: the scheduler's ``now``, and the
+                # point up to which this thread's time is accounted idle.
+                now = time.time()
+                clock.spend("idle", now)
                 with self._lock:
                     # Buckets with an aside-prepare in flight keep
                     # dispatching at the OLD size/program — a hot swap
@@ -3385,10 +3432,15 @@ class ServeFrontend:
                     # staging-slab reuse safe) — one staging
                     # implementation for both ingest modes.
                     pick, chosen = self.batcher.select_bucket(
-                        bucket_sessions, time.time())
+                        bucket_sessions, now)
                     if chosen:
-                        plan = BatchPlan(batch=None, valid=len(chosen),
-                                         slots=chosen, bucket=pick)
+                        # Stamp: the batch is chosen — and frozen. What
+                        # follows until the permit is ``permit_wait``,
+                        # not ``queue_bucket``.
+                        plan = BatchPlan(
+                            batch=None, valid=len(chosen), slots=chosen,
+                            bucket=pick,
+                            stamps=BatchStamps(pick.stages, time.time()))
                 self._finalize_drained()
                 if plan is None:
                     time.sleep(self._tick_s)
@@ -3422,18 +3474,9 @@ class ServeFrontend:
                     self.router.discard(plan, kind=FaultKind.STALL)
                     continue
                 q = self._inflight
-                t0 = time.time()
+                st = plan.stamps
+                st.t_permit = t0 = time.time()  # stamp: permit acquired
                 bucket = plan.bucket
-                if self.attribution is not None:
-                    # Lineage hop: bucket queue wait ends as staging
-                    # begins (one stamp per batch, fanned to the chosen
-                    # slots); the batch-level marks list then collects
-                    # assemble_h2d here and device/d2h on the collect
-                    # side — the router extends each slot's lineage.
-                    for slot in plan.slots:
-                        if slot.lin is not None:
-                            slot.lin.mark("queue_bucket", t0)
-                    plan.lin_marks = []
                 # A tick-cost sample is trustworthy only when nothing
                 # else is in flight at submit: otherwise submit→
                 # materialize includes queue wait behind OTHER batches'
@@ -3465,10 +3508,9 @@ class ServeFrontend:
                     engine = bucket.engine
                     result = (engine.submit_resident(batch)
                               if resident else engine.submit(batch))
-                    if plan.lin_marks is not None:
-                        # Batch assembly + H2D ends at submit return
-                        # (async dispatch: the device now owns the batch).
-                        plan.lin_marks.append(("assemble_h2d", time.time()))
+                    # Stamp: batch assembly + H2D ends at submit return
+                    # (async dispatch: the device now owns the batch).
+                    st.t_submit = time.time()
                     # Start the D2H now — per output shard on the streamed
                     # egress path — so the collect side only waits, never
                     # initiates (runtime/egress.py).
@@ -3479,16 +3521,29 @@ class ServeFrontend:
                     #   re-derive bucket.fetcher (new output signature)
                     #   while this batch is in flight — collect must
                     #   fetch from the one the D2H was issued on
-                    self.tracer.complete("serve_dispatch", t0, time.time(),
-                                         TRACK_DISPATCH, seq=seq,
-                                         frames=plan.valid,
-                                         bucket=bucket.label())
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     sem.release()
                     self.router.discard(plan, kind=classify(e, "dispatch"))
                     if not self._contain(e, "dispatch", bucket=bucket):
                         return
                     continue
+                # This thread's ledger and the bucket's counters, from
+                # the stamps (a shed or failed plan stays under idle).
+                clock.spend("idle", st.t_chosen)
+                clock.spend("permit_wait", t0)
+                clock.spend("assemble_h2d", st.t_submit)
+                bucket.stages.note_dispatched(st)
+                if tracer.enabled:
+                    # Trace view of the same stamps: the legacy
+                    # serve_dispatch span and one span per thread state.
+                    tracer.complete("serve_dispatch", t0, st.t_submit,
+                                    TRACK_DISPATCH, seq=seq,
+                                    frames=plan.valid,
+                                    bucket=bucket.label())
+                    tracer.complete("dispatch:permit_wait", st.t_chosen,
+                                    t0, TRACK_DISPATCH, seq=seq)
+                    tracer.complete("dispatch:assemble_h2d", t0,
+                                    st.t_submit, TRACK_DISPATCH, seq=seq)
                 # In-flight window: registered from now until the collect
                 # side materializes (or discards) it; carries the plan so
                 # a recovery can shed the sessions' claims even for a
@@ -3496,7 +3551,7 @@ class ServeFrontend:
                 # (when armed) trips on this window's oldest age.
                 self._window.add(seq, plan)
                 bucket.adjust_inflight(1)
-                q.put((seq, plan, result, t0))
+                q.put((seq, plan, result))
                 # Ledger stall accounting: this tick is the bucket's
                 # dispatch heartbeat — it closes any reconfiguration
                 # stall window open on the bucket (gap measured from
@@ -3514,18 +3569,18 @@ class ServeFrontend:
 
     def _collect(self, gen: int = 0) -> None:
         chaos = self.config.chaos
-        block_until_ready = None
-        if self.attribution is not None:
-            # Lineage needs the device/D2H split: block_until_ready
-            # marks "device compute done, data still on device"; the
-            # fetch that follows is then pure D2H+scatter. Without
-            # lineage the fetch blocks on both at once (no extra sync).
-            try:
-                import jax
+        # The device/D2H split, for every batch: block_until_ready marks
+        # "device compute done, data still on device"; the fetch that
+        # follows is then pure D2H+scatter. No new synchronisation: the
+        # streamed fetch begins with the same wait and the monolithic
+        # np.asarray waits anyway.
+        import jax
 
-                block_until_ready = jax.block_until_ready
-            except ImportError:  # pragma: no cover — jax is a hard dep
-                pass
+        block_until_ready = jax.block_until_ready
+        tracer = self.tracer
+        # A replacement thread (recovery) carries its predecessor's
+        # ledger on; the superseded thread keeps writing to the old one.
+        clock = self._collect_clock = self._collect_clock.successor()
         q = self._inflight  # generation-pinned: recovery installs a fresh
         #   queue before starting the replacement thread, so a superseded
         #   thread can never pop (and then wrongly discard) a
@@ -3543,24 +3598,26 @@ class ServeFrontend:
                 if self._supervisor is not None:
                     self._supervisor.beat("collect")
                 try:
-                    seq, plan, result, _t0 = q.get(timeout=0.05)
+                    seq, plan, result = q.get(timeout=0.05)
                 except queue.Empty:
+                    clock.spend("idle", time.time())
                     if self._dispatch_done.is_set() and q.empty():
                         break
                     continue
+                st = plan.stamps
+                st.t_taken = time.time()  # stamp: off the in-flight queue
                 bucket = plan.bucket
                 fetcher = (plan.fetcher if plan.fetcher is not None
                            else (bucket.fetcher if bucket is not None
                                  else None))  # plan-pinned first: the
                 #   bucket's fetcher may already belong to a hot-swapped
                 #   successor program with a different output signature
-                if plan.lin_marks is not None and block_until_ready is not None:
-                    try:
-                        block_until_ready(result)
-                        plan.lin_marks.append(("device", time.time()))
-                    except Exception:  # noqa: BLE001 — a poisoned batch
-                        pass  # raises again in fetch below, where the
-                        #   containment ladder owns it
+                try:
+                    block_until_ready(result)
+                except Exception:  # noqa: BLE001 — a poisoned batch
+                    pass  # raises again in fetch below, where the
+                    #   containment ladder owns it
+                st.t_ready = time.time()  # stamp: device result ready
                 try:
                     # Streamed egress: shard host copies into the slot's
                     # preallocated slab (D2H issued at submit); fallback:
@@ -3571,8 +3628,8 @@ class ServeFrontend:
                     # later.
                     out = (fetcher.fetch(result, seq) if fetcher is not None
                            else np.asarray(result))
-                    if plan.lin_marks is not None:
-                        plan.lin_marks.append(("d2h", time.time()))
+                    st.t_fetched = time.time()  # stamp: in host memory
+                    st.close_batch()
                     if chaos is not None:
                         # Chaos site "corrupt_device": one element of
                         # row 0 perturbed in an otherwise-valid batch —
@@ -3607,16 +3664,13 @@ class ServeFrontend:
                 sem.release()
                 if bucket is not None:
                     # Live tick-cost sample for the EDF/cost bucket score
-                    # (submit → materialized wall time, EWMA-smoothed;
-                    # contended ticks are counted but not sampled — see
-                    # the dispatch-side cost_sample comment).
-                    bucket.observe_tick((time.time() - _t0) * 1e3,
+                    # (submit returned → fetched, off the stamps,
+                    # EWMA-smoothed; contended ticks are counted but not
+                    # sampled — see the dispatch-side cost_sample comment).
+                    bucket.observe_tick((st.t_fetched - st.t_submit) * 1e3,
                                         sample=plan.cost_sample,
                                         valid=plan.valid)
                     bucket.adjust_inflight(-1)
-                self.tracer.complete("batch_complete", _t0, time.time(),
-                                     TRACK_DEVICE, seq=seq,
-                                     frames=plan.valid)
                 if plan.audit_rows and self.audit is not None \
                         and bucket is not None:
                     # Pair each sampled input with its DELIVERED output
@@ -3632,6 +3686,28 @@ class ServeFrontend:
                                 bucket=bucket.label(), lineage=lin,
                                 out_uint8=bucket.engine.out_uint8)
                 self.router.route(plan, out)
+                st.t_routed = time.time()  # stamp: demuxed and delivered
+                # This thread's ledger and the bucket's counters, from
+                # the stamps (a failed or superseded batch stays idle).
+                clock.spend("idle", st.t_taken)
+                clock.spend("device", st.t_ready)
+                clock.spend("d2h", st.t_fetched)
+                clock.spend("route", st.t_routed)
+                if bucket is not None:
+                    bucket.stages.note_collected(st)
+                if tracer.enabled:
+                    # Trace view of the same stamps: the legacy
+                    # batch_complete span (permit → fetched) on the
+                    # device lane, one span per state on the collect lane.
+                    tracer.complete("batch_complete", st.t_permit,
+                                    st.t_fetched, TRACK_DEVICE, seq=seq,
+                                    frames=plan.valid)
+                    tracer.complete("collect:device", st.t_taken,
+                                    st.t_ready, TRACK_COLLECT, seq=seq)
+                    tracer.complete("collect:d2h", st.t_ready,
+                                    st.t_fetched, TRACK_COLLECT, seq=seq)
+                    tracer.complete("collect:route", st.t_fetched,
+                                    st.t_routed, TRACK_COLLECT, seq=seq)
                 if bucket is not None and bucket.draining_fetchers \
                         and bucket.inflight_batches == 0:
                     # The last pre-swap batch just routed (route copies
@@ -3697,6 +3773,12 @@ class ServeFrontend:
             # canonical signature) + the compiled-program pool counters.
             "open_buckets": len(buckets),
             "buckets": {b.label(): b.stats_row() for b in buckets},
+            # The two pacing threads' wall-time ledgers: each thread's
+            # states (idle + the per-bucket ones) sum to accounted_to −
+            # started (obs.metrics.ThreadClock).
+            **({"threads": {"dispatch": self._dispatch_clock.summary(),
+                            "collect": self._collect_clock.summary()}}
+               if self._dispatch_clock is not None else {}),
             "pool": self.pool.stats(),
             # Auto-plan plane: the Plan doc driving this frontend (None
             # = hand-set defaults) — provenance says cache/measured.

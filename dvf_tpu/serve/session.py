@@ -89,9 +89,15 @@ class Slot:
     tag: Any = None     # opaque client cookie (e.g. the ZMQ bridge's
     #   remote frame index), threaded through to the Delivery
     lin: Any = None     # obs.lineage.FrameLineage when the frontend's
-    #   attribution plane is armed: the frame's hop trail, marked at
-    #   each queue/stage boundary and closed at delivery — None (zero
+    #   attribution plane is armed: the frame's hop trail, filled from
+    #   the stamps below at route and closed at delivery — None (zero
     #   cost) otherwise
+    t_pending: float = 0.0  # wall clock: drained from the session's
+    #   ingress into the scheduler's pending set (drain_ingress) — the
+    #   one per-frame stamp that is not a batch's, always taken
+    stamps: Any = None  # obs.metrics.BatchStamps of the batch that
+    #   served this frame, set by the router: the stage counters'
+    #   delivery fold and the lineage view both read it
 
 
 class Delivery(NamedTuple):
@@ -266,13 +272,13 @@ class StreamSession:
                     self.shed += n
             return
         got = self.ingress.pop_up_to(len(self.ingress))
-        if got and self.attribution is not None:
+        if got:
             # One stamp per drain, shared across the drained slots: the
-            # end of each frame's session-ingress-queue component.
+            # end of each frame's ``queue_ingress`` component (always on:
+            # the stage counters and the lineage view both read it).
             now = time.time()
             for slot in got:
-                if slot.lin is not None:
-                    slot.lin.mark("queue_ingress", now)
+                slot.t_pending = now
         self.pending.extend(got)
 
     def flush_queued(self, count_shed: bool = True) -> int:
@@ -326,8 +332,7 @@ class StreamSession:
         with self._lock:
             self.inflight -= 1
             if self.state != CLOSED:  # late result after hard close: dropped
-                self.reorder.complete(
-                    slot.index, (frame, slot.ts, slot.tag, slot.lin))
+                self.reorder.complete(slot.index, (frame, slot))
 
     def discard_inflight(self, n: int = 1, kind: str = None) -> None:
         """A device batch failed; its slots never produced results.
@@ -348,11 +353,22 @@ class StreamSession:
         out of index order."""
         n = 0
         closed = None
+        folds = None
         with self._deliver_lock:
             self.reorder.advance()
-            for idx, (frame, ts, tag, lin) in self.reorder.pop_ready():
+            for idx, (frame, slot) in self.reorder.pop_ready():
+                ts, tag, lin = slot.ts, slot.tag, slot.lin
                 now = time.time()
                 lat_s = now - ts
+                st = slot.stamps
+                if st is not None and st.stages is not None:
+                    # Stage counters (always on): this frame's intervals
+                    # fold into the bucket that ran its batch, on the
+                    # same clock read the latency is computed from, once
+                    # per delivery round below.
+                    if folds is None:
+                        folds = {}
+                    folds.setdefault(st.stages, []).append((slot, now))
                 self.latency.record(lat_s)
                 with self._lock:
                     self.delivered += 1
@@ -398,6 +414,9 @@ class StreamSession:
                         print(f"[serve:tap:{self.id}] error (continuing): "
                               f"{e!r}", file=sys.stderr, flush=True)
                 n += 1
+            if folds is not None:
+                for stages, rows in folds.items():
+                    stages.fold_delivered(rows)
             if closed is not None:
                 bucket = self.bucket
                 self.attribution.observe_batch(

@@ -156,15 +156,15 @@ class TestFetcherEquivalence:
 def test_overlap_efficiency_formula():
     s = EgressStats(requested_mode="streamed", d2h_block_ms=10.0)
     s.effective_mode = "streamed"
-    s.record_fetch(wait_ms=1.5, copy_ms=0.5, span_ms=3.0)
+    s.record_fetch(wait_ms=1.5, copy_ms=0.5)
     # exposed = 2.0 of a 10.0 blocking baseline → 80% hidden.
     assert s.overlap_efficiency() == pytest.approx(0.8)
     s2 = EgressStats(d2h_block_ms=1.0)
-    s2.record_fetch(wait_ms=5.0, copy_ms=0.0, span_ms=5.0)
+    s2.record_fetch(wait_ms=5.0, copy_ms=0.0)
     assert s2.overlap_efficiency() == 0.0  # clamped, never negative
     s3 = EgressStats(requested_mode="monolithic", d2h_block_ms=10.0)
     s3.effective_mode = "monolithic"
-    s3.record_fetch(1, 1, 1)
+    s3.record_fetch(1, 1)
     assert s3.overlap_efficiency() is None
     assert EgressStats(d2h_block_ms=None).overlap_efficiency() is None
     # Encode accounting lands in the summary.

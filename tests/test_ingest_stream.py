@@ -379,17 +379,17 @@ def test_ingest_trace_spans_emitted(tmp_path, monkeypatch):
 def test_overlap_efficiency_formula():
     s = IngestStats(requested_mode="streamed", depth=4, h2d_block_ms=10.0)
     s.effective_mode = "streamed"
-    s.record_batch(stage_ms=1.0, put_ms=1.5, wait_ms=0.5, span_ms=3.0)
+    s.record_batch(stage_ms=1.0, put_ms=1.5, wait_ms=0.5)
     # exposed = 2.0 of a 10.0 blocking baseline → 80% hidden.
     assert s.overlap_efficiency() == pytest.approx(0.8)
     # Exposed beyond the baseline clamps to 0, never negative.
     s2 = IngestStats(h2d_block_ms=1.0)
-    s2.record_batch(stage_ms=0, put_ms=5.0, wait_ms=0, span_ms=5.0)
+    s2.record_batch(stage_ms=0, put_ms=5.0, wait_ms=0)
     assert s2.overlap_efficiency() == 0.0
     # Monolithic / uncalibrated → None (no overlap claim).
     s3 = IngestStats(requested_mode="monolithic", h2d_block_ms=10.0)
     s3.effective_mode = "monolithic"
-    s3.record_batch(1, 1, 1, 1)
+    s3.record_batch(1, 1, 1)
     assert s3.overlap_efficiency() is None
     assert IngestStats(h2d_block_ms=None).overlap_efficiency() is None
 

@@ -1,0 +1,483 @@
+"""The serve path's one stage clock (PR 25).
+
+One set of wall-clock stamps per batch (``BatchPlan.stamps``) and one per
+frame (``Slot.t_pending``), always on; the per-bucket ``StageStats``
+counters, the ``FrameLineage`` marks, the Tracer's dispatch/collect spans
+and the tick-cost sample are all views of them. Pinned here:
+
+- the eight frame components of a bucket sum, frame-weighted, to its
+  delivered latency total;
+- each pacing thread's states sum to its wall time;
+- a histogram delta between two reads holds what was recorded between them;
+- ``permit_wait`` is its own interval (not ``queue_bucket``), and
+  ``inflight_wait`` (not ``device``) takes a held collect thread's time;
+- lineage and trace are views: same numbers, no clock of their own, and
+  nothing is allocated for them when they are off;
+- XLA compilations are counted process-wide; a flight dump's device
+  capture records its host-clock epoch.
+"""
+
+import json
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from dvf_tpu.obs import metrics as M
+from dvf_tpu.obs.lineage import BATCH_COMPONENTS, SERVE_COMPONENTS
+from dvf_tpu.ops import get_filter
+from dvf_tpu.resilience import FaultPlan
+from dvf_tpu.serve import ServeConfig, ServeFrontend
+
+H, W = 16, 24
+
+
+def frame_u8(k, j):
+    f = np.full((H, W, 3), 7, np.uint8)
+    f[0] = k
+    f[1] = j % 251
+    return f
+
+
+def drain(fe, sid, want, deadline_s=30.0):
+    got = []
+    deadline = time.time() + deadline_s
+    while len(got) < want and time.time() < deadline:
+        got += fe.poll(sid)
+        time.sleep(0.002)
+    return got
+
+
+def serve(n_sessions=2, n_frames=12, pace_s=0.0, **cfg):
+    """One served run; returns (deliveries per session, stats, frontend)."""
+    kw = dict(batch_size=4, queue_size=500, slo_ms=60_000.0,
+              telemetry_sample_s=0.0)
+    kw.update(cfg)
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(**kw))
+    got = {}
+    with fe:
+        sids = [fe.open_stream() for _ in range(n_sessions)]
+        for j in range(n_frames):
+            for k, sid in enumerate(sids):
+                fe.submit(sid, frame_u8(k, j))
+            if pace_s:
+                time.sleep(pace_s)
+        for sid in sids:
+            got[sid] = drain(fe, sid, n_frames)
+            assert len(got[sid]) == n_frames
+        stats = fe.stats()
+    return got, stats, fe
+
+
+def stages_of(stats):
+    (row,) = [r for r in stats["buckets"].values() if r["batches"]]
+    return row["stages"], row
+
+
+# ---------------------------------------------------------------------------
+# Closure 1: the components of a bucket sum to its delivered latency
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("cfg", [
+    dict(batch_size=4, max_inflight=4),
+    dict(batch_size=4, max_inflight=1),
+    dict(batch_size=3, max_inflight=2, pace_s=0.002),   # padded batches
+], ids=["depth4", "depth1", "padded"])
+def test_components_sum_to_delivered_latency(cfg):
+    got, stats, _ = serve(**cfg)
+    st, row = stages_of(stats)
+    n = sum(len(v) for v in got.values())
+    assert st["delivered"] == n
+    assert set(st["components"]) == set(SERVE_COMPONENTS)
+    total = sum(c["ms_total"] for c in st["components"].values())
+    assert total == pytest.approx(st["latency_ms_total"], rel=1e-6, abs=1e-3)
+    # ... and that latency total is the deliveries' own latency_ms
+    assert st["latency_ms_total"] == pytest.approx(
+        sum(d.latency_ms for v in got.values() for d in v), rel=1e-6, abs=1e-2)
+    for name, c in st["components"].items():
+        assert c["frames"] == n, name
+        assert sum(k for _, k in c["hist"]) == n, name        # frame-weighted
+        assert ("batches" in c) == (name in BATCH_COMPONENTS), name
+        if "batches" in c:
+            assert c["batches"] == row["batches"], name
+    assert st["route"]["batches"] == row["batches"]
+    assert sum(k for _, k in st["route"]["hist"]) == row["batches"]
+
+
+# ---------------------------------------------------------------------------
+# Closure 2: each pacing thread's states sum to its wall time
+# ---------------------------------------------------------------------------
+
+
+def _read_just_after_an_accrual(fe, thread):
+    """A thread's ledger lags the wall by the time since it last accrued
+    (a 2 ms tick for dispatch, a 50 ms queue poll for an idle collect
+    thread). Spin until ``accounted_to`` moves, then read: the lag is the
+    cost of one ``stats()`` call."""
+    last = fe.stats()["threads"][thread]["accounted_to"]
+    deadline = time.time() + 5.0
+    while time.time() < deadline:
+        stats = fe.stats()
+        now = time.time()
+        if stats["threads"][thread]["accounted_to"] != last:
+            return now, stats
+    raise AssertionError(f"{thread} thread's ledger stopped moving")
+
+
+@pytest.mark.parametrize("thread,states", [
+    ("dispatch", ("idle", "permit_wait", "assemble_h2d")),
+    ("collect", ("idle", "device", "d2h", "route")),
+])
+def test_thread_states_sum_to_wall_time(thread, states):
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, max_inflight=2,
+        telemetry_sample_s=0.0))
+    with fe:
+        sid = fe.open_stream()
+        for burst in range(2):
+            for j in range(16):
+                fe.submit(sid, frame_u8(0, burst * 16 + j))
+            assert len(drain(fe, sid, 16)) == 16
+            time.sleep(0.6)
+        now, stats = _read_just_after_an_accrual(fe, thread)
+    row = stats["threads"][thread]
+    assert {k[:-3] for k in row if k.endswith("_ms")} == set(states) | {"wall"}
+    total = sum(row[f"{s}_ms"] for s in states)
+    # the ledger never loses or double-counts an interval ...
+    assert total == pytest.approx(
+        (row["accounted_to"] - row["started"]) * 1e3, rel=1e-6, abs=0.01)
+    # ... and it is the thread's wall time, to 1%
+    wall_ms = (now - row["started"]) * 1e3
+    assert wall_ms > 1200.0
+    assert total == pytest.approx(wall_ms, rel=0.01)
+    # the bucket rows hold the same per-bucket states (one bucket here)
+    st, _ = stages_of(stats)
+    for s in states[1:]:
+        cell = st["route"] if s == "route" else st["components"][s]
+        assert cell["batch_ms_total"] == pytest.approx(row[f"{s}_ms"], abs=0.01)
+    assert row[f"{states[-1]}_ms"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# Histogram: a delta between two reads holds what was recorded between them
+# ---------------------------------------------------------------------------
+
+
+def _fake_slot(ts, t_pending, st):
+    class S:
+        pass
+    s = S()
+    s.ts, s.t_pending, s.stamps = ts, t_pending, st
+    return s
+
+
+def _fold(stages, ms_values, base=1000.0):
+    """Deliver frames whose queue_ingress is the given ms; every other
+    component 1 ms."""
+    for ms in ms_values:
+        st = M.BatchStamps(stages, base + ms / 1e3 + 0.001)
+        (st.t_permit, st.t_submit, st.t_taken, st.t_ready,
+         st.t_fetched) = (st.t_chosen + 0.001 * i for i in range(1, 6))
+        st.close_batch()
+        slot = _fake_slot(base, base + ms / 1e3, st)
+        stages.fold_delivered([(slot, st.t_fetched + 0.001)])
+
+
+@pytest.mark.parametrize("before,between", [
+    ([5.0] * 50, [200.0] * 100),
+    ([0.01] * 10, [1.0 + i for i in range(100)]),
+    ([], [0.02, 3.0, 3.0, 3.0, 90_000.0]),
+], ids=["shifted", "spread", "edges"])
+def test_histogram_delta_returns_the_windows_percentiles(before, between):
+    stages = M.StageStats()
+    _fold(stages, before)
+    a = stages.summary()["components"]["queue_ingress"]
+    _fold(stages, between)
+    b = stages.summary()["components"]["queue_ingress"]
+    # read the way the benchmark's readers do (chipbench/stagelib.py)
+    from chipbench import stagelib
+
+    doc = stages.summary()
+    win = {"lo_ms": doc["hist_lo_ms"], "per_decade": doc["hist_bins_per_decade"],
+           "bins": doc["hist_bins"]}
+    delta = stagelib._cell_delta(b, a, win["bins"])["hist"]
+    assert sum(delta) == len(between)
+    assert b["frames"] - a["frames"] == len(between)
+    assert b["ms_total"] - a["ms_total"] == pytest.approx(sum(between), rel=1e-6)
+    ratio = 10.0 ** (1.0 / M.HIST_PER_DECADE)     # one bin's width
+    for q in (0.5, 0.95):
+        want = float(np.quantile(between, q, method="inverted_cdf"))
+        est = stagelib.quantile(win, delta, q)
+        if want < M.HIST_LO_MS:
+            assert est < M.HIST_LO_MS
+        else:
+            assert want / ratio <= est <= want * ratio, (q, want, est)
+    assert b["max_ms"] == pytest.approx(max(before + between), rel=1e-6)
+    assert M.hist_bin(0.0) == 0 and M.hist_bin(-1.0) == 0
+    assert M.hist_bin(1e9) == M.HIST_BINS - 1
+
+
+def test_delivery_fold_loses_no_update_under_contention():
+    """Deliveries fold from whichever thread delivers (the collect thread, or
+    a finalize on the dispatch thread): more threads than cores, a tiny
+    switch interval, and the closure still holds to the frame."""
+    import sys
+
+    stages = M.StageStats()
+    threads, rounds = 12, 400
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(target=_fold, args=(stages, [2.0] * rounds))
+                   for _ in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60.0)
+        assert not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(old)
+    doc = stages.summary()
+    assert doc["delivered"] == threads * rounds
+    assert doc["components"]["queue_ingress"]["ms_total"] == pytest.approx(
+        2.0 * threads * rounds, rel=1e-6)
+    assert sum(c["ms_total"] for c in doc["components"].values()) == pytest.approx(
+        doc["latency_ms_total"], rel=1e-6)
+    for c in doc["components"].values():
+        assert sum(n for _, n in c["hist"]) == threads * rounds
+
+
+# ---------------------------------------------------------------------------
+# permit_wait is not queue_bucket; inflight_wait is not device
+# ---------------------------------------------------------------------------
+
+
+def test_permit_wait_is_split_from_queue_bucket():
+    """max_inflight 1, the collect thread held 60 ms a round, four batches
+    ready at once: each next one is chosen as soon as its predecessor is
+    submitted and then waits a held round for the permit. That wait is
+    ``permit_wait``; a frame's ``queue_bucket`` ends where its batch was
+    chosen, so a batch's frames hold only their PREDECESSORS' permit waits
+    there, never their own."""
+    chaos = FaultPlan().add("freeze", every=1, delay_s=0.06)
+    got, stats, _ = serve(n_sessions=1, n_frames=16, batch_size=4,
+                          max_inflight=1, chaos=chaos)
+    st, row = stages_of(stats)
+    comp = st["components"]
+    assert row["batches"] == 4
+    # batches 3 and 4 each waited a whole held round for their permit
+    assert comp["permit_wait"]["batch_ms_total"] > 100.0
+    assert comp["permit_wait"]["max_ms"] > 50.0
+    # the last batch's frames waited in the bucket through three rounds, and
+    # its own permit wait is on top of that, not inside it: the two
+    # components and the four after them still sum to the latency
+    assert comp["queue_bucket"]["max_ms"] > comp["permit_wait"]["max_ms"]
+    lat = st["latency_ms_total"]
+    assert sum(c["ms_total"] for c in comp.values()) == pytest.approx(lat, rel=1e-6)
+    # frozen-batch time is a real share of these frames' latency
+    assert comp["permit_wait"]["ms_total"] > 0.1 * lat
+
+
+def test_inflight_wait_takes_a_held_collect_thread():
+    """The ``freeze`` chaos site holds the collect thread before it takes
+    the next batch: the batch is long done on the device when it is taken,
+    so the time is ``inflight_wait`` and ``device`` stays near zero."""
+    chaos = FaultPlan().add("freeze", every=1, delay_s=0.08)
+    got, stats, _ = serve(n_sessions=1, n_frames=16, batch_size=4,
+                          max_inflight=4, chaos=chaos)
+    comp = stages_of(stats)[0]["components"]
+    # four batches in flight at once, taken one per held round
+    assert comp["inflight_wait"]["batch_ms_total"] > 150.0
+    assert comp["device"]["batch_ms_total"] < \
+        comp["inflight_wait"]["batch_ms_total"] / 4
+
+
+# ---------------------------------------------------------------------------
+# Views: lineage and trace read the same stamps
+# ---------------------------------------------------------------------------
+
+
+def test_lineage_is_a_view_of_the_stamps():
+    got, stats, _ = serve(n_sessions=2, n_frames=8, lineage=True)
+    st, _ = stages_of(stats)
+    sums = dict.fromkeys(SERVE_COMPONENTS, 0.0)
+    for deliveries in got.values():
+        for d in deliveries:
+            marks = d.lineage.marks
+            assert tuple(name for name, _ in marks) == SERVE_COMPONENTS
+            comps = d.lineage.components_ms()
+            assert sum(comps.values()) == pytest.approx(d.latency_ms, abs=1e-6)
+            for k, v in comps.items():
+                sums[k] += v
+    # frame for frame the same intervals the always-on counters folded
+    for name in SERVE_COMPONENTS:
+        assert st["components"][name]["ms_total"] == pytest.approx(
+            sums[name], rel=1e-6, abs=1e-3), name
+    assert set(stats["attribution"]["components"]) == set(SERVE_COMPONENTS)
+
+
+@pytest.fixture(scope="module")
+def traced_run():
+    t_begin = time.time()
+    got, stats, fe = serve(n_sessions=2, n_frames=12, trace=True)
+    return t_begin, time.time(), stats, fe.tracer.snapshot()
+
+
+@pytest.mark.parametrize("span,lane,state", [
+    ("dispatch:permit_wait", 0, ("dispatch", "permit_wait")),
+    ("dispatch:assemble_h2d", 0, ("dispatch", "assemble_h2d")),
+    ("collect:device", 2, ("collect", "device")),
+    ("collect:d2h", 2, ("collect", "d2h")),
+    ("collect:route", 2, ("collect", "route")),
+    ("serve_dispatch", 0, ("dispatch", "assemble_h2d")),
+    ("batch_complete", 1, None),
+])
+def test_trace_lanes_carry_the_state_spans_on_the_wall_clock(
+        traced_run, span, lane, state):
+    t_begin, t_end, stats, snap = traced_run
+    _, row = stages_of(stats)
+    events = [e for e in snap["events"] if e["name"] == span]
+    assert len(events) == row["batches"]
+    assert {e["pid"] for e in events} == {lane}
+    for e in events:        # µs from the tracer's wall-clock epoch
+        t0 = snap["start_time"] + e["ts"] / 1e6
+        assert t_begin - 0.001 <= t0 <= t_end
+        assert t0 + e["dur"] / 1e6 <= t_end + 0.001
+    if state is not None:   # the spans ARE the thread's ledger (µs rounding)
+        want = stats["threads"][state[0]][f"{state[1]}_ms"]
+        assert sum(e["dur"] for e in events) / 1e3 == pytest.approx(
+            want, abs=0.002 * len(events) + 0.01)
+
+
+def test_views_off_build_nothing(monkeypatch):
+    """lineage and trace off: no FrameLineage is allocated and no Tracer
+    event is built, per frame or per batch — the stamps are the cost."""
+    from dvf_tpu.obs import lineage as lineage_mod
+    from dvf_tpu.obs import trace as trace_mod
+
+    made = []
+    real_init = lineage_mod.FrameLineage.__init__
+
+    def counting_init(self, *a, **kw):
+        made.append(1)
+        real_init(self, *a, **kw)
+
+    calls = []
+    monkeypatch.setattr(lineage_mod.FrameLineage, "__init__", counting_init)
+    monkeypatch.setattr(trace_mod.Tracer, "complete",
+                        lambda self, *a, **kw: calls.append(a[0]))
+    monkeypatch.setattr(trace_mod.Tracer, "instant",
+                        lambda self, *a, **kw: calls.append(a[0]))
+    got, stats, fe = serve(n_sessions=2, n_frames=8, ledger=False)
+    assert made == [] and calls == []
+    assert all(d.lineage is None for v in got.values() for d in v)
+    assert stages_of(stats)[0]["delivered"] == 16      # ... and still counted
+    assert "attribution" not in stats and "trace" not in stats
+
+
+def test_tick_cost_sample_comes_from_the_stamps(monkeypatch):
+    """One batch: the bucket's tick-cost sample is submit returned → fetched,
+    i.e. inflight_wait + device + d2h of the counters, to the float."""
+    from dvf_tpu.serve import server as server_mod
+
+    seen = []
+    real = server_mod._Bucket.observe_tick
+
+    def spy(self, wall_ms, **kw):
+        seen.append(wall_ms)
+        real(self, wall_ms, **kw)
+
+    monkeypatch.setattr(server_mod._Bucket, "observe_tick", spy)
+    got, stats, _ = serve(n_sessions=1, n_frames=4, batch_size=4)
+    comp = stages_of(stats)[0]["components"]
+    assert len(seen) == 1
+    assert seen[0] == pytest.approx(sum(
+        comp[c]["batch_ms_total"] for c in ("inflight_wait", "device", "d2h")),
+        abs=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# Counters beside the stamps
+# ---------------------------------------------------------------------------
+
+
+def test_xla_compiles_total_rises_when_a_second_signature_compiles():
+    from dvf_tpu.obs.ledger import XLA_COMPILES
+
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=2, queue_size=100, slo_ms=60_000.0, telemetry_sample_s=0.0))
+    with fe:
+        a = fe.open_stream(op_chain="invert", frame_shape=(H, W, 3))
+        for j in range(2):
+            fe.submit(a, frame_u8(0, j))
+        assert len(drain(fe, a, 2)) == 2
+        rows = fe.stats()["buckets"]
+        before = {r["xla_compiles_total"] for r in rows.values()}
+        assert len(before) == 1 and min(before) >= 1
+        engines = sum(r["engine_compile_count"] for r in rows.values())
+        # a geometry nothing else in this process compiles
+        b = fe.open_stream(op_chain="invert", frame_shape=(H + 3, W + 5, 3))
+        for j in range(2):
+            fe.submit(b, np.zeros((H + 3, W + 5, 3), np.uint8))
+        assert len(drain(fe, b, 2)) == 2
+        rows = fe.stats()["buckets"]
+        after = {r["xla_compiles_total"] for r in rows.values()}
+        assert len(after) == 1                      # the same number on every row
+        assert sum(r["engine_compile_count"] for r in rows.values()) == engines + 1
+        assert min(after) >= min(before) + 1
+        assert all(r["xla_compile_s_total"] > 0 for r in rows.values())
+    # the listener goes with the last frontend; the totals stay
+    count = XLA_COMPILES.totals()[0]
+    assert XLA_COMPILES._refs == 0 or count >= min(after)
+
+
+def test_flight_dump_records_the_device_trace_epoch(tmp_path, monkeypatch):
+    import jax
+
+    from dvf_tpu.obs.export import FlightRecorder
+
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: started.append(time.time()))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    rec = FlightRecorder(str(tmp_path), stats_fn=lambda: {"ok": 1},
+                         min_interval_s=0.0, jax_profile_s=0.05)
+    t0 = time.time()
+    dump = rec.trigger("test")
+    assert dump is not None
+    deadline = time.time() + 10.0
+    meta = {}
+    while "device_trace_epoch" not in meta and time.time() < deadline:
+        time.sleep(0.02)
+        with open(f"{dump}/meta.json") as f:
+            try:
+                meta = json.load(f)
+            except json.JSONDecodeError:   # mid-rewrite
+                meta = {}
+    assert meta["reason"] == "test"
+    assert t0 <= meta["device_trace_epoch"] <= started[0]
+    for t in threading.enumerate():
+        if t.name == "dvf-flight-profile":
+            t.join(timeout=5.0)
+
+
+@pytest.mark.parametrize("cls,record,totals", [
+    (M.IngestStats, lambda s: s.record_batch(stage_ms=1.0, put_ms=2.0, wait_ms=3.0),
+     {"stage_ms_total": 2.0, "h2d_put_ms_total": 4.0, "h2d_wait_ms_total": 6.0}),
+    (M.EgressStats, lambda s: s.record_fetch(wait_ms=1.5, copy_ms=0.5),
+     {"d2h_wait_ms_total": 3.0, "copy_ms_total": 1.0, "encode_ms_total": 0.0,
+      "send_ms_total": 0.0}),
+], ids=["ingest", "egress"])
+def test_summaries_carry_totals_beside_the_means(cls, record, totals):
+    s = cls()
+    record(s)
+    record(s)
+    doc = s.summary()
+    for k, v in totals.items():
+        assert doc[k] == pytest.approx(v)
+        mean_key = k[:-len("_total")]
+        if doc["batches"] and mean_key in doc and v:
+            assert doc[mean_key] * doc["batches"] == pytest.approx(v)
+    assert not hasattr(s, "span_ms_total")      # written every batch, read by nothing
