@@ -294,9 +294,14 @@ class EgressStats:
         #   "d2h_fault_budget")
         self.depth = depth               # encode-plane in-flight window
         self.d2h_block_ms = d2h_block_ms
+        self.transfer_layout = "plain"   # "u32rows" where the fetcher
+        #   packs results into 32-bit words on the device
+        #   (runtime.egress.egress_pack) and hands out the landed buffer
         self.batches = 0
+        self.packed_batches = 0          # fetched in the packed layout
         self.pool_allocs = 0             # slab-pool constructions (stays 1
-        #   across a steady-state run — the allocation-regression tests)
+        #   across a steady-state run — the allocation-regression tests;
+        #   0 on the packed layout, which has no pool)
         self.d2h_wait_ms_total = 0.0     # blocked on shard host copies
         self.copy_ms_total = 0.0         # scatter into the output slab
         self.encode_batches = 0
@@ -309,8 +314,10 @@ class EgressStats:
         self.send_batches = 0
         self.send_ms_total = 0.0
 
-    def record_fetch(self, wait_ms: float, copy_ms: float) -> None:
+    def record_fetch(self, wait_ms: float, copy_ms: float,
+                     packed: bool = False) -> None:
         self.batches += 1
+        self.packed_batches += packed
         self.d2h_wait_ms_total += wait_ms
         self.copy_ms_total += copy_ms
 
@@ -348,7 +355,9 @@ class EgressStats:
             "requested_mode": self.requested_mode,
             "fallback_reason": self.fallback_reason,
             "depth": self.depth,
+            "transfer_layout": self.transfer_layout,
             "batches": self.batches,
+            "packed_batches": self.packed_batches,
             "d2h_wait_ms": round(self.d2h_wait_ms_total / n, 4),
             "copy_ms": round(self.copy_ms_total / n, 4),
             # Cumulative totals beside the lifetime means (window deltas).

@@ -15,7 +15,9 @@ operation-overlap discipline, applied at delivery:
   of compute and the next batch's staging), materialized shard-by-shard
   into a *preallocated* host slab at collect time (no per-batch
   allocation; the copy of shard *i* overlaps the in-flight transfer of
-  shard *i+1*);
+  shard *i+1*). Where the result is one shard on one device (the
+  one-chip replica) the fetcher owns the **transfer layout** instead,
+  see below;
 - :class:`AsyncCodecPlane` — a bounded-window, order-preserving encoder
   over the existing ``JpegCodec``/``NativeJpegCodec`` thread pools
   (``encode_batch_async`` futures): the delivery loop submits a batch's
@@ -28,6 +30,36 @@ Timeline, monolithic vs streamed (worker-style decode→compute→encode):
     streamed     decode ████ compute ████ fetch ▒█          (prefetch hid
                  encode        ░░ batch k−1 ░░  ██████       most of it)
                  send                            batch k−1 █
+
+The transfer layout (PR 26). A TPU holds a ``uint8[B, H, W, C]`` result
+channel-planar (``{2,1,3,0:T(8,128)(4,1)}``) and ``np.asarray`` of it
+hands back a numpy array in that order, not C-contiguous: the slab copy
+that followed was a byte-granular transposing gather at 0.4–0.5 GB/s on
+the collect thread (393 of invert_1080p's 435 ms a batch), and the
+transfer itself ran at 1 GB/s. A 32-bit array lands C-contiguous at
+3 GB/s. So on the streamed path, for a uint8 NHWC result whose rows are
+whole words (``W·C % 4 == 0``) held as one shard on one device:
+
+- the fetcher compiles :func:`egress_pack` (module ``jit_egress_pack``)
+  when it is built: the interleave as a permutation on the MXU,
+  ``uint8[B,H,W,C]`` → ``uint32[B,H,W·C/4]``, the same bytes in
+  row-major order (3–5 ms of device time for 199 MB, 1.1 for 44 MB);
+- ``prefetch(result)`` runs it, starts the words' ``copy_to_host_async``
+  and returns a :class:`PackedBatch`, which rides the in-flight queue in
+  the result's place (the device frees the result once the pack has
+  read it);
+- ``fetch(packed, slot)`` waits for that transfer and returns the buffer
+  it landed in, viewed as ``uint8[B,H,W,C]``: read-only, one pass over
+  the bytes (the runtime's), no slab, no copy (``copy_ms`` is 0), no
+  pool allocated. Rows that outlive the batch are copied by whoever
+  keeps them, as on the monolithic path (the serve router always does).
+
+``EgressStats.transfer_layout`` (``"u32rows"`` / ``"plain"``) and
+``packed_batches`` say that it engaged; the ``egress_d2h`` and
+``collect:d2h`` spans carry ``layout=``. No flag: a result sharded over
+several devices, another dtype or rank, rows that are no whole words, a
+batch of another geometry, monolithic mode and a released fetcher keep
+the paths below byte for byte.
 
 Fallbacks mirror the ingest assembler, recorded in the stats either way:
 
@@ -52,6 +84,7 @@ after its rows have been copied onward or sent.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 import weakref
@@ -84,6 +117,126 @@ STREAM_ON_CPU = False
 _LIVE_FETCHERS: "weakref.WeakSet" = weakref.WeakSet()
 
 
+# -- the transfer layout --------------------------------------------------
+
+# Pixels per MXU chunk of the pack program. 512 pixels of C channels are
+# 128·C whole 32-bit words, so every chunk boundary is lane-aligned
+# (multiples of 128) on the input side (pixels) and the output side
+# (words) alike; only a row's last chunk may be shorter.
+PACK_CHUNK_PX = 512
+
+TRANSFER_PLAIN = "plain"        # the result as the step program left it
+TRANSFER_PACKED = "u32rows"     # egress_pack's words, one row of W·C/4
+
+
+def pack_table(width: int, channels: int) -> np.ndarray:
+    """The pack program's permutation for one chunk, ``float32[C, P,
+    2·Pw]`` with ``P = min(width, PACK_CHUNK_PX)`` pixels and ``Pw =
+    P·C/4`` words: byte ``k`` of word ``j`` of the interleaved stream is
+    channel ``c`` of pixel ``p`` where ``p·C + c == 4·j + k``; column
+    ``j`` (low half-words) or ``Pw + j`` (high half-words) of row ``p``
+    of plane ``c`` holds 1 for an even ``k`` and 256 for an odd one. The
+    pattern repeats every four pixels, so one table serves every chunk
+    and a short last chunk reads the prefix of each half."""
+    p = min(width, PACK_CHUNK_PX)
+    pw = p * channels // 4
+    byte = np.arange(p * channels)
+    word, k = byte // 4, byte % 4
+    table = np.zeros((channels, p, 2 * pw), np.float32)
+    table[byte % channels, byte // channels, (k // 2) * pw + word] = \
+        np.where(k % 2, 256.0, 1.0)
+    return table
+
+
+def egress_pack(y, table):
+    """``uint8[B, H, W, C]`` → ``uint32[B, H, W·C/4]`` holding the same
+    bytes in row-major (interleaved) order, computed on the device (why:
+    the module docstring).
+
+    XLA's own reshape of the channel-planar bytes goes through a 42×
+    padded intermediate (C on the lanes) and does not compile at 1080p,
+    so the interleave is a permutation on the MXU: each plane's pixels,
+    exact in bfloat16, times :func:`pack_table` give the low and the
+    high 16 bits of every word (two terms per column, exact in the
+    float32 accumulator)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    _, _, width, channels = y.shape
+    p = min(width, PACK_CHUNK_PX)
+    pw = p * channels // 4
+    words = []
+    for w0 in range(0, width, p):
+        n = min(p, width - w0)
+        nw = n * channels // 4
+        acc = None
+        for c in range(channels):
+            t = table[c] if n == p else jnp.concatenate(
+                [table[c, :n, :nw], table[c, :n, pw:pw + nw]], axis=1)
+            d = lax.dot_general(
+                y[:, :, w0:w0 + n, c].astype(jnp.bfloat16), t,
+                (((2,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = d if acc is None else acc + d
+        words.append(acc[..., :nw].astype(jnp.uint32)
+                     | (acc[..., nw:].astype(jnp.uint32) << 16))
+    return words[0] if len(words) == 1 else jnp.concatenate(words, axis=-1)
+
+
+@functools.lru_cache(maxsize=16)
+def _compiled_pack(out_shape: Tuple[int, ...], device):
+    """(executable, device-resident table) for one output signature on
+    one device — lowered and compiled here, when a fetcher is built,
+    never on the first batch; cached so a hot swap back to a known
+    signature and a rebuilt fetcher compile nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    where = jax.sharding.SingleDeviceSharding(device)
+    table = jax.device_put(
+        jnp.asarray(pack_table(out_shape[2], out_shape[3]), jnp.bfloat16),
+        where)
+    spec = jax.ShapeDtypeStruct(out_shape, jnp.uint8, sharding=where)
+    return jax.jit(egress_pack).lower(spec, table).compile(), table
+
+
+class PackedBatch:
+    """One batch in the packed transfer layout, in flight: the pack
+    program's words and the shape they unpack to. What ``prefetch``
+    returns and the in-flight queue carries in place of the result; it
+    answers the two calls the collect paths make on a result before the
+    fetch, and any fetcher unpacks it, whatever became of the one that
+    packed it (released, degraded, rebuilt at another geometry)."""
+
+    __slots__ = ("words", "out_shape")
+
+    def __init__(self, words: Any, out_shape: Tuple[int, ...]):
+        self.words = words
+        self.out_shape = out_shape
+
+    def block_until_ready(self) -> "PackedBatch":
+        self.words.block_until_ready()
+        return self
+
+    def is_ready(self) -> bool:
+        return self.words.is_ready()
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        """The frames: the buffer the transfer landed in, viewed as
+        ``uint8[out_shape]`` (read-only; no pass over the bytes). So a
+        collect path's ``np.asarray(result)`` fallback holds for a
+        packed batch as for a result."""
+        out = np.asarray(self.words).view(np.uint8).reshape(self.out_shape)
+        return out if dtype is None else out.astype(dtype)
+
+
+def transfer_layout_of(handle: Any) -> str:
+    """Which layout an in-flight batch (what ``prefetch`` returned)
+    crosses to the host in — the ``layout=`` of its trace spans."""
+    return (TRANSFER_PACKED if isinstance(handle, PackedBatch)
+            else TRANSFER_PLAIN)
+
+
 def live_fetchers() -> List["ShardedBatchFetcher"]:
     return list(_LIVE_FETCHERS)
 
@@ -95,19 +248,21 @@ def occupied_slab_bytes() -> int:
 
 
 class ShardedBatchFetcher:
-    """Fetches engine results into preallocated host slabs, per shard.
+    """Fetches engine results into preallocated host slabs, per shard,
+    or, where it owns the transfer layout, as the landed buffer itself.
 
-    One fetcher per (output signature, sharding); ``prefetch(result)``
-    belongs right after ``Engine.submit`` (it issues the per-shard
-    ``copy_to_host_async`` so the transfer overlaps the tail of compute),
-    ``fetch(result, slot)`` belongs in the collect path (it materializes
-    into the slot's slab and only *waits*, never initiates).
+    One fetcher per (output signature, sharding); ``handle =
+    prefetch(result)`` belongs right after ``Engine.submit`` (it issues
+    the per-shard ``copy_to_host_async``, or the pack and its transfer,
+    so the transfer overlaps the tail of compute), ``fetch(handle,
+    slot)`` belongs in the collect path (it materializes into the
+    slot's slab and only *waits*, never initiates).
 
-    The returned array is the slab itself on the streamed path — valid
-    until the slot is revisited (the caller's in-flight bound), so
-    consumers that hold rows longer (reorder buffers) must copy them.
-    ``effective_mode`` tells the caller which contract applies; the
-    monolithic path returns a fresh per-batch array exactly as before.
+    The returned array is the slab itself on the streamed slab path —
+    valid until the slot is revisited (the caller's in-flight bound), so
+    consumers that hold rows longer (reorder buffers) must copy them;
+    ``owns(out)`` says so. The packed layout and the monolithic path
+    return a per-batch array that lives as long as a view of it does.
     """
 
     def __init__(
@@ -139,6 +294,8 @@ class ShardedBatchFetcher:
         self.stats = stats if stats is not None else EgressStats(
             requested_mode=mode)
         self._pool: Optional[List[np.ndarray]] = None
+        self._pack = None  # (executable, table) while the transfer
+        #   layout is TRANSFER_PACKED; no slab pool exists then
         self.effective_mode = self._plan()
         self.stats.effective_mode = self.effective_mode
         _LIVE_FETCHERS.add(self)
@@ -166,18 +323,52 @@ class ShardedBatchFetcher:
         if cal is not None and cal < MIN_STREAM_D2H_MS:
             self.stats.fallback_reason = "cheap_transfer"
             return "monolithic"
-        self._pool = [np.empty(self.out_shape, self.dtype)
-                      for _ in range(self.slots)]
-        self.stats.pool_allocs += 1
+        self._pack = self._plan_pack()
+        if self._pack is not None:
+            self.stats.transfer_layout = TRANSFER_PACKED
+        else:
+            self._pool = [np.empty(self.out_shape, self.dtype)
+                          for _ in range(self.slots)]
+            self.stats.pool_allocs += 1
         return "streamed"
+
+    def _plan_pack(self):
+        """The pack program where the transfer layout can help and the
+        landed buffer can be handed out whole: a uint8 NHWC result whose
+        rows are whole 32-bit words, held as one shard on one device
+        (the one-chip replica). Anything else keeps the slab path."""
+        shape = self.out_shape
+        if (self.dtype != np.uint8 or len(shape) != 4
+                or (shape[2] * shape[3]) % 4
+                or len(self.sharding.device_set) != 1):
+            return None
+        import jax
+
+        try:
+            return _compiled_pack(shape, next(iter(self.sharding.device_set)))
+        except jax.errors.JaxRuntimeError:  # a program the device cannot
+            return None  # build or hold: stay correct on the slab path
 
     # -- submit side ----------------------------------------------------
 
-    def prefetch(self, result: Any) -> None:
+    def prefetch(self, result: Any) -> Any:
         """Start the D2H now, overlapped with the next batch's staging and
         the tail of this batch's compute; ``fetch`` then only waits for
-        completion instead of initiating the copy. Per shard on the
-        streamed path so each shard's copy is independently in flight."""
+        completion instead of initiating the copy. Returns what to hand
+        ``fetch``: on the packed layout the pack program's output (the
+        caller drops ``result``, which the device frees once the pack
+        has read it), otherwise ``result`` itself, its transfer started
+        per shard on the streamed path so each shard's copy is
+        independently in flight."""
+        pack = self._pack  # read once: release() may clear it
+        if (pack is not None
+                and getattr(result, "dtype", None) == self.dtype
+                and tuple(result.shape) == self.out_shape
+                and result.sharding.device_set == self.sharding.device_set):
+            run, table = pack
+            words = run(result, table)
+            words.copy_to_host_async()
+            return PackedBatch(words, self.out_shape)
         try:
             if self.effective_mode == "streamed":
                 seen = set()
@@ -196,6 +387,7 @@ class ShardedBatchFetcher:
                 result.copy_to_host_async()
         except AttributeError:
             pass  # non-jax results (tests/fakes) have nothing to prefetch
+        return result
 
     # -- collect side ---------------------------------------------------
 
@@ -212,6 +404,8 @@ class ShardedBatchFetcher:
         """Materialize one batch; blocks until the device is done (like
         the ``np.asarray`` it replaces) but scatters shard host copies
         into the slot's preallocated slab as each one lands."""
+        if isinstance(result, PackedBatch):
+            return self._fetch_packed(result)
         if not self._streamable(result):
             # A mid-stream geometry change can hand this fetcher a batch
             # compiled at another signature — fall back per batch rather
@@ -255,9 +449,36 @@ class ShardedBatchFetcher:
                 b0 = sh.index[0]
                 tracer.complete(
                     EGRESS_D2H, t0 + off, t2 + off, self.track,
-                    rows=f"{b0.start or 0}:{b0.stop}", bytes=host.nbytes)
+                    rows=f"{b0.start or 0}:{b0.stop}", bytes=host.nbytes,
+                    layout=TRANSFER_PLAIN)
         self.stats.record_fetch(wait_ms=wait_s * 1e3, copy_ms=copy_s * 1e3)
         return slab
+
+    def _fetch_packed(self, packed: PackedBatch) -> np.ndarray:
+        """The packed layout's fetch: wait for the transfer ``prefetch``
+        started and hand out the buffer it landed in, viewed as frames.
+        One pass over the bytes, the runtime's; no slab, no copy
+        (``copy_ms`` is 0 by construction). The view is read-only and
+        lives as long as a row of it is referenced."""
+        packed.block_until_ready()  # the step's and the pack's device
+        #   time are not D2H (see fetch)
+        if self.chaos is not None and self.effective_mode == "streamed":
+            self.chaos.fire("d2h")  # one shard, one firing; a fetcher
+            #   degraded to monolithic has left the site, as its own
+            #   fetch has, also for a batch packed before the degrade
+        t0 = time.perf_counter()
+        out = np.asarray(packed)
+        t1 = time.perf_counter()
+        tracer = self.tracer
+        if tracer is not None and tracer.enabled:
+            off = self.wall_offset_s
+            tracer.complete(
+                EGRESS_D2H, t0 + off, t1 + off, self.track,
+                rows=f"0:{out.shape[0]}", bytes=out.nbytes,
+                layout=TRANSFER_PACKED)
+        self.stats.record_fetch(wait_ms=(t1 - t0) * 1e3, copy_ms=0.0,
+                                packed=True)
+        return out
 
     def owns(self, out: np.ndarray) -> bool:
         """True when ``out`` is one of this fetcher's pooled slabs — i.e.
@@ -270,6 +491,7 @@ class ShardedBatchFetcher:
         """Drop the slab pool eagerly (geometry re-probe / degradation:
         same rationale as ``ShardedBatchAssembler.release``)."""
         self._pool = None
+        self._pack = None  # a PackedBatch in flight unpacks regardless
 
 
 class _EncodeEntry:

@@ -596,7 +596,7 @@ class Pipeline:
                     # the copy (runtime/egress.py).
                     fetcher = self._fetcher_for()
                     if fetcher is not None:
-                        fetcher.prefetch(result)
+                        result = fetcher.prefetch(result)
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     if not inline:
                         self._inflight_sem.release()

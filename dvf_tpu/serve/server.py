@@ -107,6 +107,7 @@ from dvf_tpu.runtime.egress import (
     EGRESS_MODES,
     AsyncCodecPlane,
     ShardedBatchFetcher,
+    transfer_layout_of,
 )
 from dvf_tpu.runtime.engine import Engine, ProgramPool
 from dvf_tpu.runtime.ingest import INGEST_MODES, ShardedBatchAssembler
@@ -3516,7 +3517,10 @@ class ServeFrontend:
                     # initiates (runtime/egress.py).
                     fetcher = self._fetcher_for(bucket)
                     if fetcher is not None:
-                        fetcher.prefetch(result)
+                        # What rides the in-flight queue is what fetch
+                        # takes: the packed words where the fetcher
+                        # packs (the result itself is dropped here).
+                        result = fetcher.prefetch(result)
                     plan.fetcher = fetcher  # pinned: a hot swap may
                     #   re-derive bucket.fetcher (new output signature)
                     #   while this batch is in flight — collect must
@@ -3620,12 +3624,14 @@ class ServeFrontend:
                 st.t_ready = time.time()  # stamp: device result ready
                 try:
                     # Streamed egress: shard host copies into the slot's
-                    # preallocated slab (D2H issued at submit); fallback:
-                    # the classic whole-batch np.asarray. Either way this
-                    # waits for the device. The router copies rows out
-                    # during route(), so handing it the pooled slab is
-                    # safe — the slot only cycles max_inflight+1 batches
-                    # later.
+                    # preallocated slab (D2H issued at submit), or, on
+                    # the packed layout, the buffer the transfer landed
+                    # in viewed as frames; fallback: the classic
+                    # whole-batch np.asarray. Either way this waits for
+                    # the device. The router copies rows out during
+                    # route(), so handing it the pooled slab is safe —
+                    # the slot only cycles max_inflight+1 batches later —
+                    # and the landed buffer dies with the batch.
                     out = (fetcher.fetch(result, seq) if fetcher is not None
                            else np.asarray(result))
                     st.t_fetched = time.time()  # stamp: in host memory
@@ -3705,7 +3711,8 @@ class ServeFrontend:
                     tracer.complete("collect:device", st.t_taken,
                                     st.t_ready, TRACK_COLLECT, seq=seq)
                     tracer.complete("collect:d2h", st.t_ready,
-                                    st.t_fetched, TRACK_COLLECT, seq=seq)
+                                    st.t_fetched, TRACK_COLLECT, seq=seq,
+                                    layout=transfer_layout_of(result))
                     tracer.complete("collect:route", st.t_fetched,
                                     st.t_routed, TRACK_COLLECT, seq=seq)
                 if bucket is not None and bucket.draining_fetchers \

@@ -715,7 +715,7 @@ class TpuZmqWorker:
         else:
             fetcher = self._fetcher_for()
         if fetcher is not None:
-            fetcher.prefetch(result)
+            result = fetcher.prefetch(result)
         t_ready = None
         try:
             # Device/D2H attribution split: the fetch below blocks on

@@ -68,18 +68,21 @@ class TestFetcherEquivalence:
         fetcher = ShardedBatchFetcher(
             eng.out_shape, eng.out_dtype, eng.output_sharding, slots=3)
         assert fetcher.effective_mode == "streamed"
+        packed = cfg.data == 1  # one shard on one device: the packed
+        #   transfer layout, the landed buffer handed out, no pool
         # Several batches across aliasing pool slots.
         for slot in range(5):
             frames = np.stack(_rng_frames(batch, h, w, seed=slot))
             result = eng.submit(frames.copy())
             ref = np.asarray(result)
-            fetcher.prefetch(result)
-            out = fetcher.fetch(result, slot)
+            out = fetcher.fetch(fetcher.prefetch(result), slot)
             np.testing.assert_array_equal(out, ref)
-            assert fetcher.owns(out)
+            assert fetcher.owns(out) == (not packed)
         s = fetcher.stats.summary()
         assert s["batches"] == 5
-        assert s["pool_allocs"] == 1
+        assert s["pool_allocs"] == (0 if packed else 1)
+        assert s["packed_batches"] == (5 if packed else 0)
+        assert s["transfer_layout"] == ("u32rows" if packed else "plain")
 
     def test_monolithic_mode_is_classic_fetch(self):
         eng = Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=1)))
@@ -151,6 +154,205 @@ class TestFetcherEquivalence:
         eng2.ensure_compiled((4, 16, 16, 3), np.uint8)
         assert eng2.d2h_block_ms is None
         assert eng2.out_shape == (4, 16, 16, 3)
+
+
+# ---------------------------------------------------------------------------
+# The packed transfer layout (PR 26): results leave the device as 32-bit
+# words, the landed buffer is handed out as frames
+# ---------------------------------------------------------------------------
+
+
+def _packing_fetcher(shape, **kw):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    dev = jax.devices()[0]
+    f = ShardedBatchFetcher(shape, np.uint8, SingleDeviceSharding(dev), **kw)
+    return f, dev
+
+
+class TestPackedTransferLayout:
+
+    @pytest.mark.parametrize("shape", [
+        (8, 32, 48, 3),     # the toy: 36864 bytes = 9 x 4096
+        (1, 32, 48, 3),     # one frame: 4608 bytes, no multiple of 4096
+        (3, 30, 52, 3),     # three frames of an odd geometry, 39-word rows
+        (2, 8, 1028, 3),    # two full 512-pixel chunks and a 4-pixel tail
+        (2, 8, 516, 1),     # one channel
+        (2, 8, 640, 4),     # four channels: a pixel is a word
+    ])
+    def test_pack_then_fetch_is_byte_identical(self, shape):
+        import jax
+
+        f, dev = _packing_fetcher(shape)
+        assert f.effective_mode == "streamed"
+        assert f.stats.transfer_layout == "u32rows"
+        assert f.slab_bytes() == 0 and f.stats.pool_allocs == 0
+        rng = np.random.default_rng(shape[2])
+        for slot in range(3):
+            frames = rng.integers(0, 256, shape, dtype=np.uint8)
+            result = jax.device_put(frames, dev)
+            handle = f.prefetch(result)
+            assert isinstance(handle, egress_mod.PackedBatch)
+            assert handle.words.dtype == np.uint32
+            assert handle.words.shape == (
+                shape[0], shape[1], shape[2] * shape[3] // 4)
+            out = f.fetch(handle, slot)
+            assert out.dtype == np.uint8 and out.shape == shape
+            np.testing.assert_array_equal(out, frames)
+            np.testing.assert_array_equal(out, np.asarray(result))
+            assert not out.flags.writeable  # the landed buffer, viewed
+            assert not f.owns(out)
+            # Any collect path's np.asarray fallback unpacks it too.
+            np.testing.assert_array_equal(np.asarray(handle), frames)
+        s = f.stats.summary()
+        assert s["batches"] == 3 and s["packed_batches"] == 3
+        assert s["copy_ms_total"] == 0.0
+        assert s["transfer_layout"] == "u32rows"
+
+    @pytest.mark.parametrize("shape,dtype,why", [
+        ((2, 8, 9, 3), np.uint8, "27-byte rows are no whole words"),
+        ((2, 8, 8, 3), np.float32, "not uint8"),
+        ((2, 8, 24), np.uint8, "not NHWC"),
+    ])
+    def test_results_that_cannot_pack_keep_the_slab_path(self, shape, dtype,
+                                                         why):
+        import jax
+        from jax.sharding import SingleDeviceSharding
+
+        dev = jax.devices()[0]
+        f = ShardedBatchFetcher(shape, dtype, SingleDeviceSharding(dev),
+                                slots=2)
+        assert f.effective_mode == "streamed", why
+        assert f.stats.transfer_layout == "plain", why
+        assert f.stats.pool_allocs == 1 and f.slab_bytes() > 0
+        x = (np.arange(np.prod(shape)) % 251).astype(dtype).reshape(shape)
+        result = jax.device_put(x, dev)
+        handle = f.prefetch(result)
+        assert handle is result
+        out = f.fetch(handle, 0)
+        np.testing.assert_array_equal(out, x)
+        assert f.owns(out)
+        assert f.stats.summary()["packed_batches"] == 0
+
+    def test_several_distinct_shards_keep_the_slab_path(self):
+        eng = Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=4)))
+        eng.ensure_compiled((8, 16, 24, 3), np.uint8)
+        f = ShardedBatchFetcher(eng.out_shape, eng.out_dtype,
+                                eng.output_sharding, slots=2)
+        assert f.stats.transfer_layout == "plain"
+        assert f.stats.pool_allocs == 1
+        frames = np.stack(_rng_frames(8, 16, 24, seed=5))
+        result = eng.submit(frames.copy())
+        handle = f.prefetch(result)
+        assert handle is result
+        out = f.fetch(handle, 0)
+        np.testing.assert_array_equal(out, 255 - frames)
+        assert f.owns(out) and f.stats.packed_batches == 0
+
+    def test_monolithic_mode_never_packs(self):
+        f, dev = _packing_fetcher((4, 8, 8, 3), mode="monolithic")
+        import jax
+
+        assert f.effective_mode == "monolithic"
+        assert f.stats.transfer_layout == "plain"
+        result = jax.device_put(np.full((4, 8, 8, 3), 7, np.uint8), dev)
+        assert f.prefetch(result) is result
+        np.testing.assert_array_equal(f.fetch(result, 0), 7)
+        assert f.stats.packed_batches == 0 and f.stats.pool_allocs == 0
+
+    def test_geometry_mismatch_is_not_packed(self):
+        """A batch compiled at another signature goes back as it came:
+        no pack at the wrong shape, the classic per-batch fetch."""
+        import jax
+
+        f, dev = _packing_fetcher((4, 16, 16, 3))
+        other = np.full((4, 8, 8, 3), 9, np.uint8)
+        result = jax.device_put(other, dev)
+        handle = f.prefetch(result)
+        assert handle is result
+        out = f.fetch(handle, 0)
+        np.testing.assert_array_equal(out, other)
+        assert f.stats.packed_batches == 0 and f.stats.batches == 1
+
+    def test_release_mid_flight(self):
+        """A batch packed before release() still unpacks (its buffer is
+        its own: there is no pool to free under it); the next batch goes
+        back plain. Any fetcher unpacks a packed batch, whatever became
+        of the one that packed it."""
+        import jax
+
+        shape = (4, 8, 16, 3)
+        f, dev = _packing_fetcher(shape)
+        x = np.stack(_rng_frames(4, 8, 16, seed=9))
+        handle = f.prefetch(jax.device_put(x, dev))
+        f.release()
+        np.testing.assert_array_equal(f.fetch(handle, 0), x)
+        after = jax.device_put(x, dev)
+        assert f.prefetch(after) is after
+        np.testing.assert_array_equal(f.fetch(after, 1), x)
+        assert f.stats.packed_batches == 1 and f.stats.batches == 2
+        other, _ = _packing_fetcher((2, 4, 4, 3), mode="monolithic")
+        handle2 = _packing_fetcher(shape)[0].prefetch(jax.device_put(x, dev))
+        np.testing.assert_array_equal(other.fetch(handle2, 0), x)
+
+    def test_pack_is_compiled_when_the_fetcher_is_built(self):
+        """Never on a batch: the first prefetch finds the executable, and
+        a second fetcher of the same signature compiles nothing."""
+        shape = (2, 8, 20, 3)
+        egress_mod._compiled_pack.cache_clear()
+        f, dev = _packing_fetcher(shape)
+        info = egress_mod._compiled_pack.cache_info()
+        assert (info.misses, info.hits) == (1, 0)
+        assert f._pack is not None
+        _packing_fetcher(shape)
+        info = egress_mod._compiled_pack.cache_info()
+        assert (info.misses, info.hits) == (1, 1)
+
+    def test_d2h_chaos_fires_once_per_packed_batch(self):
+        import jax
+
+        from dvf_tpu.resilience import FaultPlan
+        from dvf_tpu.resilience.chaos import ChaosFault
+
+        chaos = FaultPlan().add("d2h", at=(1,))
+        f, dev = _packing_fetcher((2, 8, 8, 3), chaos=chaos)
+        x = np.full((2, 8, 8, 3), 3, np.uint8)
+        np.testing.assert_array_equal(
+            f.fetch(f.prefetch(jax.device_put(x, dev)), 0), x)
+        with pytest.raises(ChaosFault):
+            f.fetch(f.prefetch(jax.device_put(x, dev)), 1)
+        np.testing.assert_array_equal(
+            f.fetch(f.prefetch(jax.device_put(x, dev)), 2), x)
+
+    def test_trace_span_names_the_layout(self):
+        import jax
+
+        from dvf_tpu.obs.trace import Tracer
+
+        tracer = Tracer(enabled=True)
+        f, dev = _packing_fetcher((2, 8, 8, 3), tracer=tracer)
+        f.fetch(f.prefetch(jax.device_put(
+            np.zeros((2, 8, 8, 3), np.uint8), dev)), 0)
+        spans = [e for e in tracer._events if e["name"] == "egress_d2h"]
+        assert len(spans) == 1
+        assert spans[0]["args"]["layout"] == "u32rows"
+        assert spans[0]["args"]["bytes"] == 2 * 8 * 8 * 3
+
+
+def test_pack_table_is_a_permutation():
+    """Every byte of the interleaved stream comes from exactly one
+    (plane, pixel), with weight 1 or 256 by its place in the half-word."""
+    for width, channels in [(48, 3), (512, 3), (640, 3), (8, 4), (12, 1)]:
+        t = egress_mod.pack_table(width, channels)
+        p = min(width, egress_mod.PACK_CHUNK_PX)
+        pw = p * channels // 4
+        assert t.shape == (channels, p, 2 * pw)
+        assert np.count_nonzero(t) == p * channels
+        assert set(np.unique(t)) == {0.0, 1.0, 256.0}
+        # each output column takes exactly two bytes: one of each weight
+        assert (np.count_nonzero(t == 1.0, axis=(0, 1)) == 1).all()
+        assert (np.count_nonzero(t == 256.0, axis=(0, 1)) == 1).all()
 
 
 def test_overlap_efficiency_formula():
@@ -416,6 +618,106 @@ def test_serve_streamed_matches_monolithic():
     assert stats_s["faults"]["by_kind"] == {}
 
 
+def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
+                  trace=False):
+    """One tenant through a one-device frontend (the one-chip replica's
+    shape): the fetcher packs. Returns (frames, deliveries, stats, fe)."""
+    from dvf_tpu.serve import ServeConfig, ServeFrontend
+
+    filt = get_filter("invert")
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    config = ServeConfig(batch_size=batch, max_inflight=2, queue_size=64,
+                         slo_ms=60_000.0, chaos=chaos, trace=trace)
+    frames = _rng_frames(n_frames, 16, 24, seed=11)
+    got = []
+    fe = ServeFrontend(filt, config, engine=engine)
+    with fe:
+        sid = fe.open_stream()
+        for i, f in enumerate(frames):
+            fe.submit(sid, f)
+            if resize_to is not None and i == n_frames // 3:
+                label = next(iter(fe.stats()["buckets"]))
+                assert fe.request_batch_size(label, resize_to)
+            if resize_to is not None:
+                time.sleep(0.002)
+        fe.close(sid, drain=True)
+        deadline = time.time() + 30.0
+        while time.time() < deadline and len(got) < n_frames:
+            got.extend(fe.poll(sid))
+            time.sleep(0.005)
+        stats = fe.stats()
+    assert len(got) == n_frames, len(got)
+    assert [d.index for d in got] == list(range(n_frames))
+    return frames, got, stats, fe
+
+
+def test_serve_packed_layout_delivers_and_reports():
+    frames, got, stats, _ = _serve_packed()
+    for d, src in zip(got, frames):
+        np.testing.assert_array_equal(d.frame, 255 - src)
+        assert d.frame.flags.owndata  # a row copy, not a view that pins
+        #   the whole landed batch
+    assert stats["faults"]["by_kind"] == {}
+    (row,) = stats["buckets"].values()
+    eg = row["egress"]
+    assert eg["mode"] == "streamed" and eg["transfer_layout"] == "u32rows"
+    assert eg["packed_batches"] == eg["batches"] >= 6
+    assert eg["copy_ms_total"] == 0.0
+    assert eg["pool_allocs"] == 0  # no slab pool on the packed path
+    assert stats["egress"]["transfer_layout"] == "u32rows"
+    assert row["stages"]["components"]["d2h"]["frames"] == len(frames)
+
+
+def test_serve_packed_layout_under_corrupt_device_chaos():
+    """The corrupt_device site writes into a copy: the landed view is
+    read-only. Perturbed batches deliver (one element of row 0 off by
+    the site's xor), every other frame is exact, nothing is lost."""
+    from dvf_tpu.resilience import FaultPlan
+
+    chaos = FaultPlan(seed=3).add("corrupt_device", every=2)
+    frames, got, stats, _ = _serve_packed(chaos=chaos)
+    touched = 0
+    for d, src in zip(got, frames):
+        want = 255 - src
+        diff = np.argwhere(d.frame != want)
+        if len(diff):
+            touched += 1
+            assert len(diff) == 1 and tuple(diff[0]) == (0, 0, 0)
+            assert d.frame[0, 0, 0] == want[0, 0, 0] ^ 0x40
+    assert touched >= 2
+    assert sum(n for k, n in stats["chaos"]["fired"].items()
+               if k.startswith("corrupt_device")) == touched
+    assert stats["errors"] == 0
+    (row,) = stats["buckets"].values()
+    assert row["egress"]["packed_batches"] == row["egress"]["batches"]
+
+
+def test_serve_packed_layout_across_a_hot_swap():
+    """A batch resize swaps the output signature under batches in
+    flight: the old fetcher has no pool to release late, its packed
+    batches unpack on their own, the successor packs at the new shape;
+    delivery stays ordered and bit-exact."""
+    frames, got, stats, fe = _serve_packed(n_frames=48, resize_to=2)
+    for d, src in zip(got, frames):
+        np.testing.assert_array_equal(d.frame, 255 - src)
+    assert stats["swaps"] >= 1 and stats["swap_aborts"] == 0
+    (row,) = stats["buckets"].values()
+    assert row["batch_size"] == 2
+    eg = row["egress"]  # the successor's stats
+    assert eg["transfer_layout"] == "u32rows" and eg["pool_allocs"] == 0
+    assert eg["packed_batches"] == eg["batches"] >= 1
+    assert all(f.slab_bytes() == 0 for b in fe._buckets
+               for f in [b.fetcher, *b.draining_fetchers] if f is not None)
+
+
+def test_serve_trace_spans_name_the_layout():
+    _, _, _, fe = _serve_packed(n_frames=8, trace=True)
+    spans = [e for e in fe.tracer._events if e["name"] == "collect:d2h"]
+    assert spans and all(e["args"]["layout"] == "u32rows" for e in spans)
+    d2h = [e for e in fe.tracer._events if e["name"] == "egress_d2h"]
+    assert d2h and all(e["args"]["layout"] == "u32rows" for e in d2h)
+
+
 def test_serve_bad_egress_rejected():
     from dvf_tpu.serve import ServeConfig, ServeFrontend
 
@@ -570,12 +872,12 @@ class _EmptyCounter:
         return arr
 
 
-def _count_delivery_allocs(monkeypatch, n_frames):
+def _count_delivery_allocs(monkeypatch, n_frames, data=2):
     counter = _EmptyCounter()
     monkeypatch.setattr(np, "empty", counter)
     try:
         filt = get_filter("invert")
-        engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+        engine = Engine(filt, mesh=make_mesh(MeshConfig(data=data)))
         pipe = Pipeline(
             SyntheticSource(height=256, width=256, n_frames=n_frames),
             filt, NullSink(),
@@ -592,20 +894,28 @@ def _count_delivery_allocs(monkeypatch, n_frames):
         monkeypatch.setattr(np, "empty", counter.real)
     assert stats["delivered"] == n_frames
     assert stats["egress"]["mode"] == "streamed"
-    assert stats["egress"]["pool_allocs"] == 1  # one slab pool, reused
+    # One slab pool, reused, where results come back in shards; none at
+    # all on the packed layout (one device), whose fetch hands out the
+    # buffer the transfer landed in.
+    assert stats["egress"]["pool_allocs"] == (0 if data == 1 else 1)
+    assert stats["egress"]["packed_batches"] == (
+        stats["egress"]["batches"] if data == 1 else 0)
     return len(counter.big)
 
 
-def test_delivery_path_steady_state_allocates_nothing(monkeypatch):
+@pytest.mark.parametrize("data", [2, 1])
+def test_delivery_path_steady_state_allocates_nothing(monkeypatch, data):
     """Tripling the stream length must not change the number of big host
-    allocations: the egress slab pool is built once and reused, so the
-    delivery hot loop is allocation-free per batch. An uncounted warmup
-    run first: the process's first compile at this signature performs
-    one-time big host allocations that would skew whichever counted run
-    went first."""
-    _count_delivery_allocs(monkeypatch, n_frames=16)
-    short = _count_delivery_allocs(monkeypatch, n_frames=24)
-    long = _count_delivery_allocs(monkeypatch, n_frames=72)
+    allocations numpy is asked for: the egress slab pool is built once
+    and reused (sharded results), or never built (the packed layout:
+    the only per-batch buffer is the one the runtime lands the transfer
+    in), so the delivery hot loop allocates nothing of its own per
+    batch. An uncounted warmup run first: the process's first compile at
+    this signature performs one-time big host allocations that would
+    skew whichever counted run went first."""
+    _count_delivery_allocs(monkeypatch, n_frames=16, data=data)
+    short = _count_delivery_allocs(monkeypatch, n_frames=24, data=data)
+    long = _count_delivery_allocs(monkeypatch, n_frames=72, data=data)
     assert long == short, (short, long)
 
 
@@ -641,7 +951,11 @@ class TestEgressChaos:
         chaos = FaultPlan().add("d2h", every=1, count=64)
         filt = get_filter("invert")
         pipe = Pipeline(
-            SyntheticSource(height=16, width=16, n_frames=48),
+            # 12 batches: more than the in-flight window holds beyond
+            # the third fault, so at least one is dispatched after the
+            # degrade and rebuilds the fetcher (at 6 it was a race the
+            # first batch's compiles decided).
+            SyntheticSource(height=16, width=16, n_frames=96),
             filt, NullSink(),
             PipelineConfig(batch_size=8, frame_delay=0, queue_size=64,
                            resilient=True, chaos=chaos, fault_budget=2),
