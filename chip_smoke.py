@@ -50,6 +50,7 @@ SERVE_ORDER = ("invert_1080p", "sobel_bilateral_1080p", "style_720p",
 
 BATCHES_PER_CONFIG = 3   # frames submitted = this many device batches
 N_UNIQUE = 8             # distinct seeded frames per config (cycled)
+SERVE_SESSIONS = 2       # sessions sharing each served config's batches
 
 # Bounds on |served - reference| in uint8 steps, with the reason for each.
 TOL_EXACT = 0   # invert is integer arithmetic: 255 - x, bit for bit
@@ -226,12 +227,20 @@ class Smoke:
                     within(TOL_STEP))
         if fname == "flow_warp":
             # TPU default = the Pallas bounded warp; reference = the XLA
-            # gather warp over the same sequence (temporal state threads
-            # through the reference engine exactly as through the server).
+            # gather warp, each served session's frames (k, k+n, ...: two
+            # pixels of slide a frame) alone through a one-stream engine:
+            # what a session gets depends on that session's frames only.
             ref = get_filter(fname, warp_impl="gather", **kwargs)
             n = BATCHES_PER_CONFIG * batch
-            return (self._through_engine(ref, self.frames(name, n), batch),
-                    within(TOL_STEP))
+            frames = self.frames(name, n)
+            want = [None] * n
+            for k in range(SERVE_SESSIONS):
+                mine = range(k, n, SERVE_SESSIONS)
+                outs = self._through_engine(
+                    ref, [frames[j] for j in mine], batch)
+                for j, out in zip(mine, outs):
+                    want[j] = out
+            return want, within(TOL_STEP)
         # style_transfer / super_resolution: the same weights (same seed),
         # float32 compute, matmul precision "highest".
         ref = get_filter(fname, dtype="float32", **kwargs)
@@ -388,11 +397,7 @@ def _bucket_modes(row: dict) -> dict:
 def _has_mosaic_call(engine) -> bool:
     """Does the engine's served program contain a Mosaic kernel? (A Pallas
     kernel that quietly gave way to jnp, or to interpret mode, does not.)"""
-    import jax
-
-    shape, dtype = engine.signature
-    text = engine._step.lower(jax.ShapeDtypeStruct(shape, dtype),
-                              engine._state).as_text()
+    text = engine._step.lower(*engine.step_operands()).as_text()
     return "tpu_custom_call" in text
 
 
@@ -402,18 +407,16 @@ def serve_config(s: Smoke, name: str) -> None:
 
     h, w, batch, fname, kwargs = s.geometry(name)
     filt = get_filter(fname, **kwargs)
-    # flow carries temporal state, which only a single-tenant frontend
-    # serves (serve/server.py); every other config gets two sessions
-    # sharing batches.
-    n_sessions = 1 if filt.temporal else 2
+    # Sessions sharing batches, flow included: its temporal state is per
+    # session (the engine's session table).
+    n_sessions = SERVE_SESSIONS
     total = BATCHES_PER_CONFIG * batch
     per = total // n_sessions
     want, check = s.reference(name)
     frames = s.frames(name, total)
     config = ServeConfig(
         batch_size=batch, resilient=False, slo_ms=3_600_000.0,
-        queue_size=per, out_queue_size=per,
-        **({"max_sessions": 1} if filt.temporal else {}))
+        queue_size=per, out_queue_size=per)
     fe = ServeFrontend(filt, config)
     with fe:
         sids = [fe.open_stream(frame_shape=(h, w, 3))

@@ -14,6 +14,12 @@ Reference counterpart: the abstract ``Worker.__call__(frame_bytes) -> bytes``
   pytree threaded through the call, instead of mutable attributes on a worker
   object. State stays on device across batches — no host round trip and no
   re-trace.
+- **temporal state is one session's**: the pytree ``init_state`` builds is
+  the state of ONE stream. ``fn(batch, state)`` reads a batch as that
+  stream's consecutive frames; ``rows(batch, prev, pred)`` is the same body
+  over a batch whose rows belong to many streams, each row naming its own
+  predecessor. The Engine holds one state per session in a device table
+  and runs ``rows``, so which sessions share a batch is data, never shape.
 """
 
 from __future__ import annotations
@@ -48,15 +54,33 @@ class Filter:
         None = unknown/unbounded). Spatial sharding (parallel.halo) uses
         this to size the ring halo exchange.
       pad_safe: whether repeat-last-frame batch padding preserves this
-        filter's semantics. The runtime pads short batches by repeating the
-        last valid frame (static shapes → one compilation). For stateless
-        filters padded outputs are simply dropped (always safe). For
-        stateful filters the padded rows also flow through the state
-        update, so ``pad_safe`` asserts: *the post-batch state depends only
-        on the most recent valid frame* — true for the temporal-window flow
-        family (state = last frame; the padded duplicate IS the last valid
-        frame), false for e.g. a running average, which would double-count.
-        Executors refuse short batches for ``pad_safe=False`` filters.
+        filter's semantics. The single-stream executors (runtime.pipeline,
+        the ZMQ worker) pad short batches by repeating the last valid frame
+        (static shapes → one compilation) and hand the Engine no row map.
+        For stateless filters padded outputs are simply dropped (always
+        safe). For stateful filters the padded rows then flow through the
+        state update, so ``pad_safe`` asserts: *the post-batch state
+        depends only on the most recent valid frame* — true for the
+        temporal-window flow family (state = last frame; the padded
+        duplicate IS the last valid frame), false for e.g. a running
+        average, which would double-count. Those executors refuse short
+        batches for ``pad_safe=False`` filters. The serve path does not
+        depend on it: its row map marks pad rows, and a pad row neither
+        reads nor writes any session's state.
+      rows: temporal filters only — the body over rows of many sessions,
+        ``rows(batch, prev, pred) -> (out, row_states)``. ``prev`` is a
+        pytree of T session states (every leaf of ``init_state``'s tree
+        with a leading T); ``pred`` is int32 ``[B]``: row i's predecessor
+        is ``prev`` entry ``pred[i]`` when ``pred[i] < T``, else batch row
+        ``pred[i] - T`` (always an earlier row of the same session).
+        ``pred=None`` is the one-session case ``fn`` is made of: T == 1,
+        row 0 follows ``prev`` entry 0 and row i follows row i - 1.
+        ``row_states`` has every leaf with a leading B: the session state
+        after each row (the caller keeps the one after a session's last
+        row). Build such filters with :func:`temporal_filter`. In a
+        chain only the temporal members' leaves are per-session
+        (:func:`session_leaves`): a member whose state is read-only
+        weights gets and returns its single state, stored once.
     """
 
     name: str
@@ -89,6 +113,7 @@ class Filter:
     # (e.g. style transfer returns a shard_map'd Megatron-TP forward when
     # the mesh has a model axis). None = keep the generic body.
     specialize: Optional[Callable[[Any, Tuple[int, ...]], Optional["Filter"]]] = None
+    rows: Optional[Callable[[jnp.ndarray, Any, Any], Tuple[jnp.ndarray, Any]]] = None
 
     @property
     def stateful(self) -> bool:
@@ -96,9 +121,15 @@ class Filter:
 
     @property
     def temporal(self) -> bool:
-        """State that one batch writes and the next reads: what the
-        multi-tenant frontend must not thread across sessions."""
+        """State that one batch writes and the next reads: one session's
+        (see ``rows``), never threaded across sessions."""
         return self.stateful and not self.constant_state
+
+    @property
+    def session_state(self) -> bool:
+        """Temporal, with the many-session body: the Engine keeps a table
+        of session states and any number of tenants may share a batch."""
+        return self.temporal and self.rows is not None
 
     def __call__(self, batch: jnp.ndarray, state: Any = None) -> Tuple[jnp.ndarray, Any]:
         return self.fn(batch, state)
@@ -111,6 +142,41 @@ def stateless(name: str, fn: Callable[[jnp.ndarray], jnp.ndarray], **kw) -> Filt
         return fn(batch), state
 
     return Filter(name=name, fn=wrapped, **kw)
+
+
+def temporal_filter(name: str, rows: Callable, init_state: Callable,
+                    **kw) -> Filter:
+    """A temporal Filter from its many-session body (``Filter.rows``):
+    ``fn`` is the one-session case of it — one previous state, rows that
+    follow one another — so both spell the same mathematics once."""
+    import jax
+
+    def fn(batch: jnp.ndarray, state: Any) -> Tuple[jnp.ndarray, Any]:
+        out, row_states = rows(
+            batch, jax.tree.map(lambda a: a[None], state), None)
+        return out, jax.tree.map(lambda a: a[-1], row_states)
+
+    return Filter(name=name, fn=fn, rows=rows, init_state=init_state, **kw)
+
+
+def take_pred(seq: jnp.ndarray, pred: Any) -> jnp.ndarray:
+    """Each row's predecessor out of ``seq = [prev entries | batch rows]``
+    (see ``Filter.rows``): a slice in the one-session case, a row gather
+    otherwise."""
+    return seq[:-1] if pred is None else jnp.take(seq, pred, axis=0)
+
+
+def session_leaves(filt: Filter, state: Any) -> Any:
+    """Which leaves of ``filt``'s state tree are per-session (a pytree of
+    bools shaped like ``state``): all of a temporal filter's, none of a
+    constant-state one's, member by member in a chain. The Engine gives
+    the marked leaves a row per session; the others are stored once."""
+    import jax
+
+    if filt.members is not None:
+        return tuple(session_leaves(f, s)
+                     for f, s in zip(filt.members, state))
+    return jax.tree.map(lambda _: filt.temporal, state)
 
 
 def FilterChain(*filters: Filter, name: Optional[str] = None) -> Filter:
@@ -143,6 +209,22 @@ def FilterChain(*filters: Filter, name: Optional[str] = None) -> Filter:
                 for f in filters
             )
 
+    rows = None
+    if all(f.rows is not None for f in filters if f.temporal) \
+            and any(f.temporal for f in filters):
+        def rows(batch, prev, pred):  # noqa: F811
+            # Temporal members run their own many-session body; a member
+            # whose state is read-only weights keeps its single state
+            # (session_leaves marks it as no session's).
+            out_states = []
+            for f, p in zip(filters, prev):
+                if f.rows is not None:
+                    batch, p = f.rows(batch, p, pred)
+                else:
+                    batch, p = f.fn(batch, p)
+                out_states.append(p)
+            return batch, tuple(out_states)
+
     return Filter(
         name=chain_name,
         fn=fn,
@@ -153,4 +235,5 @@ def FilterChain(*filters: Filter, name: Optional[str] = None) -> Filter:
         pad_safe=all(f.pad_safe for f in filters) if filters else True,
         constant_state=all(f.constant_state for f in filters if f.stateful),
         members=tuple(filters),
+        rows=rows,
     )

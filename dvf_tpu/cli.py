@@ -32,6 +32,9 @@ BENCH_CONFIGS = {
     "gauss3_1080p": dict(filter=("gaussian_blur", {"ksize": 3}), h=1080, w=1920, batch=16),
     "gauss9_1080p": dict(filter=("gaussian_blur", {"ksize": 9}), h=1080, w=1920, batch=16),
     "sobel_bilateral_1080p": dict(filter=("sobel_bilateral", {}), h=1080, w=1920, batch=16),
+    # flow_warp's defaults: on a TPU the bounded kernel for the final and
+    # the inner warps (ops/registry.py MEASURED_DEFAULTS), the program
+    # chipbench/configs/flow_720p.json spells out kwarg for kwarg.
     "flow_720p": dict(filter=("flow_warp", {}), h=720, w=1280, batch=8),
     "style_720p": dict(
         filter=("style_transfer", {"base_channels": 32, "n_residual": 5}),

@@ -178,12 +178,14 @@ def main(argv=None) -> int:
                 elif kind == "open":
                     # 6-tuple since the multi-signature frontend (the
                     # trailing op_chain), 7-tuple since the control
-                    # plane (trailing tier); shorter tuples from an
-                    # older parent still open on the default bucket at
-                    # the default tier.
+                    # plane (trailing tier), 8-tuple since per-session
+                    # temporal state (trailing state_cause); shorter
+                    # tuples from an older parent still open on the
+                    # default bucket at the default tier.
                     _, sid, slo_ms, frame_shape, frame_dtype = op[:5]
                     op_chain = op[5] if len(op) > 5 else None
                     tier = op[6] if len(op) > 6 else None
+                    cause = op[7] if len(op) > 7 else "admission"
                     # The dtype crosses the wire as its original
                     # SPELLING; the frontend canonicalizes (np.dtype
                     # here would read "u8" as uint64).
@@ -191,7 +193,7 @@ def main(argv=None) -> int:
                         session_id=sid, slo_ms=slo_ms,
                         frame_shape=frame_shape,
                         frame_dtype=frame_dtype or None,
-                        op_chain=op_chain, tier=tier)
+                        op_chain=op_chain, tier=tier, state_cause=cause)
                 elif kind == "poll":
                     _, sid, max_items, meta_only = op
                     got = frontend.poll(sid, max_items)

@@ -158,7 +158,8 @@ class ReplicaHandle:
 
     # serving ops (any may raise ReplicaLostError)
     def open_stream(self, session_id, slo_ms=None, frame_shape=None,
-                    frame_dtype=None, op_chain=None, tier=None) -> str:
+                    frame_dtype=None, op_chain=None, tier=None,
+                    state_cause="admission") -> str:
         raise NotImplementedError
 
     def submit(self, session_id, frame, ts=None, tag=None) -> None:
@@ -268,11 +269,12 @@ class LocalReplica(ReplicaHandle):
         return self.frontend
 
     def open_stream(self, session_id, slo_ms=None, frame_shape=None,
-                    frame_dtype=None, op_chain=None, tier=None) -> str:
+                    frame_dtype=None, op_chain=None, tier=None,
+                    state_cause="admission") -> str:
         return self._fe().open_stream(
             session_id=session_id, slo_ms=slo_ms,
             frame_shape=frame_shape, frame_dtype=frame_dtype,
-            op_chain=op_chain, tier=tier)
+            op_chain=op_chain, tier=tier, state_cause=state_cause)
 
     def submit(self, session_id, frame, ts=None, tag=None) -> int:
         return self._fe().submit(session_id, frame, ts=ts, tag=tag)
@@ -649,12 +651,14 @@ class ProcessReplica(ReplicaHandle):
                     f"replica {self.id}: send {op[0]!r} failed: {e!r}")
 
     def open_stream(self, session_id, slo_ms=None, frame_shape=None,
-                    frame_dtype=None, op_chain=None, tier=None) -> str:
-        # 7-tuple since the control plane (trailing tier); a 6-tuple
-        # from an older parent still opens at the worker's default tier.
+                    frame_dtype=None, op_chain=None, tier=None,
+                    state_cause="admission") -> str:
+        # 7-tuple since the control plane (trailing tier), 8-tuple since
+        # per-session temporal state (trailing state_cause); a shorter
+        # one from an older parent still opens at the worker's defaults.
         return self._rpc(("open", session_id, slo_ms, frame_shape,
                           str(frame_dtype) if frame_dtype is not None
-                          else None, op_chain, tier))
+                          else None, op_chain, tier, state_cause))
 
     def submit(self, session_id, frame, ts=None, tag=None) -> None:
         self._send_only(("submit1", session_id, frame, ts, tag))

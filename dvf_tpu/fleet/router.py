@@ -1544,7 +1544,14 @@ class FleetFrontend:
         on ``s.lock`` for the drain window (backpressure, not loss); a
         replica that dies mid-drain degrades to the loss path's
         at-most-once salvage (the SIGKILL-during-scale-in chaos test
-        pins exactly this)."""
+        pins exactly this).
+
+        A temporal filter's per-session state does not travel: the
+        old replica's table row dies with the binding, and the survivor
+        binds the re-opened session a fresh row, counted on its bucket
+        row as ``state.resets_total.migrate`` (``open_stream``'s
+        ``state_cause``). The session's first frame
+        there is a first frame again (flow passes it through)."""
         with s.lock:
             if s.closed or s.orphaned or s.replica_id != old.id:
                 return
@@ -1606,7 +1613,8 @@ class FleetFrontend:
                                            frame_shape=s.frame_shape,
                                            frame_dtype=s.frame_dtype,
                                            op_chain=s.op_chain,
-                                           tier=s.tier)
+                                           tier=s.tier,
+                                           state_cause="migrate")
                     except (AdmissionError, ReplicaLostError):
                         continue
                     self._uncount_load(s)

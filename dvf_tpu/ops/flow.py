@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dvf_tpu.api.filter import Filter
+from dvf_tpu.api.filter import Filter, take_pred, temporal_filter
 from dvf_tpu.ops.conv import box_filter, sep_conv2d, gaussian_kernel_1d
 from dvf_tpu.ops.registry import measured_default_for, register_filter
 from dvf_tpu.utils.image import rgb_to_gray
@@ -174,14 +174,21 @@ def poly_expansion(gray: jnp.ndarray, n: int = 5, sigma: float = 1.1):
     vy = horiz(c1, k0)
     vxy = horiz(c1, k1)
     vyy = horiz(c2, k0)
-    v = jnp.stack([v1, vx, vy, vxx, vyy, vxy], axis=-1)  # (B,H,W,1,6)
-    r = jnp.einsum("...i,ji->...j", v, Ginv)  # coeffs [c, bx, by, axx, ayy, axy]
-    b1 = r[..., 1]
-    b2 = r[..., 2]
-    A11 = r[..., 3]
-    A22 = r[..., 4]
-    A12 = r[..., 5] * 0.5
-    return A11, A12, A22, b1, b2
+    moments = (v1, vx, vy, vxx, vyy, vxy)
+
+    def coeff(j):
+        # Row j of the normal-equation solve, coeffs [c, bx, by, axx, ayy,
+        # axy], as float32 multiply-adds over the moment planes (the
+        # matrix is a trace-time constant, zero off its few couplings
+        # but for the inverse's rounding noise). A 6-wide dot
+        # would run on the MXU at its default precision — bfloat16 passes
+        # on a TPU, three digits of a solve that cancels — and read the
+        # moments through a 6-element minor dimension.
+        terms = [float(Ginv[j, i]) * moments[i] for i in range(6)
+                 if abs(Ginv[j, i]) > 1e-9 * np.abs(Ginv).max()]
+        return functools.reduce(lambda acc, t: acc + t, terms)
+
+    return coeff(3), 0.5 * coeff(5), coeff(4), coeff(1), coeff(2)
 
 
 # ---------------------------------------------------------------------------
@@ -279,6 +286,7 @@ def farneback_flow_seq(
     win_type: str = "gaussian",
     inner_warp: str = "gather",
     inner_max_disp: int = 4,
+    pred: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Flow for every CONSECUTIVE pair of a frame sequence.
 
@@ -293,16 +301,22 @@ def farneback_flow_seq(
     (tests/test_flow.py::test_farneback_seq_matches_pairwise).
 
     Returns (B, H, W, 2) flows mapping gray_seq[i] -> gray_seq[i+1].
+
+    With ``pred`` (int32 ``[B]``, the many-session form of
+    ``Filter.rows``) ``gray_seq`` is ``[T previous frames | B current
+    frames]`` and pair i is ``gray_seq[pred[i]] -> gray_seq[T + i]``:
+    still one expansion per unique frame, the pair views gathered by row.
     """
-    bp1 = gray_seq.shape[0]
+    n_seq = gray_seq.shape[0]
+    n_pairs = n_seq - 1 if pred is None else pred.shape[0]
 
     def polys_at(lvl, lh, lw):
-        g = jax.image.resize(gray_seq, (bp1, lh, lw, 1), method="linear")
+        g = jax.image.resize(gray_seq, (n_seq, lh, lw, 1), method="linear")
         poly_all = jnp.concatenate(poly_expansion(g, poly_n, poly_sigma),
                                    axis=-1)
-        return poly_all[:-1], poly_all[1:]
+        return take_pred(poly_all, pred), poly_all[n_seq - n_pairs:]
 
-    return _coarse_to_fine(polys_at, bp1 - 1, gray_seq.shape[1],
+    return _coarse_to_fine(polys_at, n_pairs, gray_seq.shape[1],
                            gray_seq.shape[2], gray_seq.dtype,
                            levels, pyr_scale, win_size, n_iters, win_type,
                            _inner_warp_fn(inner_warp, inner_max_disp))
@@ -311,7 +325,7 @@ def farneback_flow_seq(
 def _inner_warp_fn(inner_warp: str, max_disp: int):
     """Resolve the per-iteration poly-warp implementation.
 
-    "gather" — exact XLA dynamic-gather bilinear sample (the default; no
+    "gather" — exact XLA dynamic-gather bilinear sample (no
     displacement bound). "pallas" — the bounded shift warp
     (:func:`dvf_tpu.ops.pallas_kernels.warp_bounded_pallas`): the same
     kernel the on-chip A/B measured 2.3× faster than gather for the
@@ -325,10 +339,13 @@ def _inner_warp_fn(inner_warp: str, max_disp: int):
     warp is therefore only faithful while the TRUE motion at the
     estimation grid stays within ±``max_disp``; beyond it the candidate
     polynomials are sampled short of the real displacement and the
-    estimate degrades, where "gather" keeps tracking. An APPROXIMATION —
-    opt-in until the on-chip A/B (flow_inner_720p) lands a verdict, and
+    estimate degrades, where "gather" keeps tracking. An APPROXIMATION,
     sized by the caller so the bound matches the final warp's contract
-    (see flow_warp: inner bound = ceil(max_disp / flow_scale))."""
+    (see flow_warp: inner bound = ceil(max_disp / flow_scale)), and
+    flow_warp's measured default on a TPU wherever its final warp is the
+    bounded kernel: the on-chip A/B (flow_inner_720p; PR 27) read the
+    step 17× shorter at 720p batch 64, XLA's gathers of the 5-channel
+    stacks being 3.5 s of a 3.78 s step."""
     if inner_warp == "gather":
         return warp_by_flow
     if inner_warp == "pallas":
@@ -385,13 +402,14 @@ def flow_warp(
     warp_impl: Optional[str] = None,
     max_disp: int = 4,
     win_type: str = "gaussian",
-    inner_warp: str = "gather",
+    inner_warp: Optional[str] = None,
 ) -> Filter:
     """Motion-compensate each previous frame onto the current one.
 
     Output = prev warped by the prev→curr flow — visually "ghost-free onion
-    skin". State = (last frame of previous batch, initialized flag); the
-    2-frame temporal window of BASELINE.json configs[3] lives on-device.
+    skin". State = (a session's last frame, initialized flag), one per
+    session (``Filter.rows``); the 2-frame temporal window of
+    BASELINE.json configs[3] lives on-device.
     ``flow_scale``: flow is estimated at 1/flow_scale resolution and
     upsampled (cost dominated by poly expansion at full res otherwise).
     ``win_type``: "gaussian" (default; OPTFLOW_FARNEBACK_GAUSSIAN
@@ -417,9 +435,22 @@ def flow_warp(
     the clip is invisible; for fast motion beyond ±max_disp, pin
     ``warp_impl="gather"`` (full displacement, 2.3× slower on TPU) or
     raise ``max_disp`` (taps grow as (2·max_disp+2)²).
+    ``inner_warp``: the nine warps of the polynomial stacks inside the
+    iteration (:func:`_inner_warp_fn`): "gather", or "pallas" = the same
+    bounded kernel, which also clips the iteration's accumulated flow to
+    ±``max_disp`` full-resolution px. ``None`` follows the final warp:
+    with ``warp_impl="gather"`` (no displacement bound anywhere) it is
+    "gather"; with the bounded final warp, whose contract already is
+    |motion| ≤ ``max_disp``, it is the measured per-backend winner —
+    "pallas" on TPU (220 vs 3780 ms a step at 720p batch 64, PR 27's
+    chip runs), "gather" elsewhere. So on a TPU ``flow_warp()`` is the
+    program ``chipbench/configs/flow_720p.json`` spells out.
     """
     if warp_impl is None:
         warp_impl = measured_default_for("flow_warp")
+    if inner_warp is None:
+        inner_warp = (measured_default_for("flow_inner")
+                      if warp_impl == "pallas" else "gather")
     if warp_impl not in ("gather", "pallas"):
         raise ValueError(f"warp_impl must be 'gather' or 'pallas', got {warp_impl!r}")
     if win_type not in ("gaussian", "box"):
@@ -441,18 +472,21 @@ def flow_warp(
             "initialized": jnp.zeros((), dtype=jnp.bool_),
         }
 
-    def fn(batch: jnp.ndarray, state) -> Tuple[jnp.ndarray, Any]:
+    def rows(batch: jnp.ndarray, prev_states, pred) -> Tuple[jnp.ndarray, Any]:
         bsz, h, w, c = batch.shape
-        # Sequence form: frame i is curr of pair i and prev of pair i+1,
-        # so gray conversion, downscale, pyramid, and poly expansion run
-        # once per unique frame (B+1) instead of once per role (2B); the
-        # per-pair prev stack is a view of the same concat.
-        seq = jnp.concatenate([state["prev"][None], batch], axis=0)
-        prev = seq[:-1]
+        # Sequence form: a frame may be curr of one pair and prev of the
+        # next, so gray conversion, downscale, pyramid, and poly expansion
+        # run once per unique frame (the T carried frames + B) instead of
+        # once per role (2B); each row's prev is picked out of the same
+        # concat by ``pred`` (its session's previous row in this batch,
+        # else the frame its session carried in).
+        seq = jnp.concatenate([prev_states["prev"], batch], axis=0)
+        prev = take_pred(seq, pred)
         sg = rgb_to_gray(seq)
         if flow_scale > 1:
             sh, sw = h // flow_scale, w // flow_scale
-            sg = jax.image.resize(sg, (bsz + 1, sh, sw, 1), method="linear")
+            sg = jax.image.resize(sg, (seq.shape[0], sh, sw, 1),
+                                  method="linear")
         # The inner warp runs at the 1/flow_scale estimation grid, so
         # ±max_disp full-res px = ±max_disp/flow_scale grid px — scale
         # the bound so pallas-inner carries the SAME |motion| ≤ max_disp
@@ -460,7 +494,8 @@ def flow_warp(
         flow = farneback_flow_seq(
             sg, levels=levels, win_size=win_size, n_iters=n_iters,
             win_type=win_type, inner_warp=inner_warp,
-            inner_max_disp=max(1, -(-max_disp // max(1, flow_scale))))
+            inner_max_disp=max(1, -(-max_disp // max(1, flow_scale))),
+            pred=pred)
         if flow_scale > 1:
             flow = jax.image.resize(flow, (bsz, h, w, 2), method="linear") * float(flow_scale)
         if warp_impl == "pallas":
@@ -471,21 +506,32 @@ def flow_warp(
             warped = warp_bounded_pallas(prev, flow, max_disp=max_disp)
         else:
             warped = warp_by_flow(prev, flow)
-        # Until the first real previous frame exists, pass the input through.
-        out = jnp.where(state["initialized"], warped, batch)
-        new_state = {
-            "prev": batch[-1],
-            "initialized": jnp.ones((), dtype=jnp.bool_),
-        }
-        return out.astype(batch.dtype), new_state
+        # Until a session's first real previous frame exists, pass the
+        # input through.
+        out = jnp.where(_started(prev_states, pred, bsz), warped, batch)
+        return out.astype(batch.dtype), _last_frame_states(batch)
 
-    return Filter(
-        name=(f"flow_warp(levels={levels},win={win_size},warp={warp_impl}"
-              f"{',box' if win_type == 'box' else ''}"
-              f"{',pallas-inner' if inner_warp == 'pallas' else ''})"),
-        fn=fn,
-        init_state=init_state,
-    )
+    return temporal_filter(
+        f"flow_warp(levels={levels},win={win_size},warp={warp_impl}"
+        f"{',box' if win_type == 'box' else ''}"
+        f"{',pallas-inner' if inner_warp == 'pallas' else ''})",
+        rows, init_state)
+
+
+def _started(prev_states, pred, bsz: int) -> jnp.ndarray:
+    """(B,1,1,1) bool: does row i have a real previous frame? Every row
+    that follows a row of this batch does; one that follows a carried
+    state does iff that state is initialized."""
+    seq = jnp.concatenate([prev_states["initialized"],
+                           jnp.ones((bsz,), jnp.bool_)])
+    return take_pred(seq, pred)[:, None, None, None]
+
+
+def _last_frame_states(batch: jnp.ndarray):
+    """Per-row states of the two-frame-window filters: after row i its
+    session carries frame i."""
+    return {"prev": batch,
+            "initialized": jnp.ones((batch.shape[0],), jnp.bool_)}
 
 
 @register_filter("flow_vis")
@@ -499,11 +545,11 @@ def flow_vis(levels: int = 3, win_size: int = 15, n_iters: int = 3, max_mag: flo
             "initialized": jnp.zeros((), dtype=jnp.bool_),
         }
 
-    def fn(batch: jnp.ndarray, state) -> Tuple[jnp.ndarray, Any]:
-        seq = jnp.concatenate([state["prev"][None], batch], axis=0)
+    def rows(batch: jnp.ndarray, prev_states, pred) -> Tuple[jnp.ndarray, Any]:
+        seq = jnp.concatenate([prev_states["prev"], batch], axis=0)
         flow = farneback_flow_seq(rgb_to_gray(seq),
                                   levels=levels, win_size=win_size,
-                                  n_iters=n_iters)
+                                  n_iters=n_iters, pred=pred)
         mag = jnp.sqrt(jnp.sum(flow * flow, axis=-1))
         ang = jnp.arctan2(flow[..., 1], flow[..., 0])  # [-pi, pi]
         hue = (ang + jnp.pi) / (2.0 * jnp.pi)          # [0, 1]
@@ -522,10 +568,9 @@ def flow_vis(levels: int = 3, win_size: int = 15, n_iters: int = 3, max_mag: flo
         b_ = jnp.select([i == 0, i == 1, i == 2, i == 3, i == 4, i == 5],
                         [p, p, t, val, val, q])
         out = jnp.stack([r, g, b_], axis=-1)
-        new_state = {"prev": batch[-1], "initialized": jnp.ones((), dtype=jnp.bool_)}
-        return out.astype(batch.dtype), new_state
+        return out.astype(batch.dtype), _last_frame_states(batch)
 
-    return Filter(name="flow_vis", fn=fn, init_state=init_state)
+    return temporal_filter("flow_vis", rows, init_state)
 
 
 @register_filter("ema_smooth")
@@ -533,7 +578,7 @@ def ema_smooth(alpha: float = 0.35) -> Filter:
     """Temporal exponential smoothing — motion-trail / denoise.
 
     y_i = alpha·x_i + (1-alpha)·y_{i-1}, chained across batches through
-    device-resident state (the second temporal-window filter after
+    a session's device-resident state (the second temporal-window filter after
     flow_warp; being pointwise (halo=0) AND stateful it exercises the
     engine's GSPMD H-sharding path for stateful filters).
 
@@ -546,11 +591,9 @@ def ema_smooth(alpha: float = 0.35) -> Filter:
       the carried state is literally independent of the pad count — the
       Filter.pad_safe contract ('state depends only on the most recent
       valid frame') holds as an identity, not an approximation.
-    - The recurrence runs as a ``lax.associative_scan`` over the batch
-      dim (first-order linear recurrences compose associatively:
-      ``(A,B)∘(A',B') = (A·A', A'·B + B')``), so the batch dimension
-      stays parallel — a sequential ``lax.scan`` carry would serialize
-      across the data-sharded mesh axis and idle every shard but one.
+    - The recurrence is resolved by pointer jumping over each row's
+      predecessor link (see the body), so the batch dimension stays
+      parallel and a batch may interleave any number of sessions.
     """
     if not 0.0 < alpha <= 1.0:
         raise ValueError("alpha must be in (0, 1]")
@@ -563,52 +606,50 @@ def ema_smooth(alpha: float = 0.35) -> Filter:
             "initialized": jnp.zeros((), dtype=jnp.bool_),
         }
 
-    def fn(batch: jnp.ndarray, state) -> Tuple[jnp.ndarray, Any]:
+    def rows(batch: jnp.ndarray, prev_states, pred) -> Tuple[jnp.ndarray, Any]:
+        bsz = batch.shape[0]
+        t = prev_states["prev"].shape[0]
         a = jnp.asarray(alpha, batch.dtype)
-        # First-ever frame: seed the EMA with it instead of fading in
-        # from black.
-        seed = jnp.where(state["initialized"], state["ema"], batch[0])
-        # Per-frame transform y_i = A_i·y_{i-1} + B_i, with repeats
-        # (x_i == x_{i-1} bit-exact) as identity transforms. The carried
-        # "prev" frame extends repeat detection across the batch boundary,
-        # so the semantics are independent of how the stream was
-        # partitioned into batches.
-        same0 = jnp.logical_and(
-            state["initialized"],
-            jnp.all(batch[0] == state["prev"]),
-        )[None]
-        same = jnp.concatenate([
-            same0,
-            jnp.all(batch[1:] == batch[:-1], axis=(1, 2, 3)),
-        ])[:, None, None, None]
-        # A is broadcast to the FULL batch shape before the scan: jax
-        # 0.4.x GSPMD miscompiles associative_scan over operands of mixed
-        # shape when the batch axis is sharded (a (B,1,1,1) A beside a
-        # (B,H,W,C) B returns wrong Ac on a data/space mesh — isolated on
-        # jax 0.4.37, CPU, data=2·space=4; exact with either operand
-        # layout on a single device). Shape-matched operands partition
-        # correctly on every toolchain, at the cost of materializing A.
-        A = jnp.broadcast_to(
-            jnp.where(same, 1.0, 1.0 - a).astype(batch.dtype), batch.shape)
+        started = _started(prev_states, pred, bsz)
+        # Per-frame transform y_i = A_i·y_pred(i) + B_i, with repeats
+        # (x_i == its session's previous frame, bit-exact) as identity
+        # transforms. The carried "prev" frame extends repeat detection
+        # across the batch boundary, so the semantics are independent of
+        # how a stream was partitioned into batches.
+        before = take_pred(
+            jnp.concatenate([prev_states["prev"], batch], axis=0), pred)
+        same = jnp.logical_and(
+            started, jnp.all(batch == before, axis=(1, 2, 3), keepdims=True))
+        A = jnp.where(same, 1.0, 1.0 - a).astype(batch.dtype)
         B = jnp.where(same, 0.0, a * batch).astype(batch.dtype)
+        ptr = (jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                t + jnp.arange(bsz - 1, dtype=jnp.int32)])
+               if pred is None else pred)
+        # Rows that follow a carried state resolve at once (a session's
+        # first-ever frame seeds the EMA with itself instead of fading in
+        # from black) and become constants: A = 0, B = y.
+        root = (ptr < t)[:, None, None, None]
+        carried = jnp.take(prev_states["ema"], jnp.minimum(ptr, t - 1), axis=0)
+        seed = jnp.where(started, carried, batch)
+        B = jnp.where(root, A * seed + B, B)
+        A = jnp.where(root, 0.0, A).astype(batch.dtype)
+        # The rest by pointer jumping: first-order linear recurrences
+        # compose associatively, ``(A,B)∘(A',B') = (A·A', A·B' + B)``, so
+        # each round splices a row onto its predecessor's predecessor and
+        # a chain of L rows resolves in log2(L) rounds with the batch
+        # dimension parallel throughout (a sequential scan would
+        # serialize across the data-sharded mesh axis), whatever the
+        # order in which sessions' rows sit in the batch.
+        for _ in range((bsz - 1).bit_length()):
+            j = jnp.maximum(ptr - t, 0)
+            live = ptr >= t
+            live4 = live[:, None, None, None]
+            B = jnp.where(live4, A * jnp.take(B, j, axis=0) + B, B)
+            A = jnp.where(live4, A * jnp.take(A, j, axis=0), A)
+            ptr = jnp.where(live, jnp.take(ptr, j), ptr)
+        states = _last_frame_states(batch)
+        states["ema"] = B
+        return B.astype(batch.dtype), states
 
-        def combine(left, right):
-            al, bl = left
-            ar, br = right
-            return al * ar, ar * bl + br
-
-        Ac, Bc = lax.associative_scan(combine, (A, B), axis=0)
-        ys = Ac * seed[None] + Bc
-        new_state = {
-            "ema": ys[-1],
-            "prev": batch[-1],
-            "initialized": jnp.ones((), dtype=jnp.bool_),
-        }
-        return ys.astype(batch.dtype), new_state
-
-    return Filter(
-        name=f"ema_smooth(a={alpha})",
-        fn=fn,
-        init_state=init_state,
-        halo=0,
-    )
+    return temporal_filter(f"ema_smooth(a={alpha})", rows, init_state,
+                           halo=0)

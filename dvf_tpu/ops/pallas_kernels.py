@@ -339,6 +339,7 @@ def warp_bounded_pallas(
         compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="warp_bounded",
     )(x, fl)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
 

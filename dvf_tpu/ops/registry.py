@@ -76,7 +76,8 @@ def measured_default(winners: Dict[str, str], fallback: str) -> str:
 # 2026-07-31 through a shared chip that no longer exists (the table and
 # the test that compared it with this map were removed in PR 21); the CPU
 # winners from benchmarks/cpu/BENCH_TABLE.json. They stay as they are
-# until ROADMAP D4 replaces them with a ledgered A/B on the chip.
+# until ROADMAP D4 replaces them with a ledgered A/B on the chip
+# ("flow_inner" is the first that was: PR 27's chip runs).
 #
 # Schema per key:
 #   comparison    — the impl_comparisons key benchmarks/run_table.py writes
@@ -111,6 +112,23 @@ MEASURED_DEFAULTS = {
         "winners": {"tpu": "pallas", "cpu": "gather"},
         "fallback": "gather",
         "label_to_impl": {"gather": "gather", "pallas_warp": "pallas"},
+    },
+    # flow_warp's inner_warp, where the final warp is the bounded kernel
+    # (the same +-max_disp contract; with a gather final warp the inner
+    # warps stay gathers whatever this says). TPU: PR 27's chip runs, the
+    # served table step at 720p batch 64 / 32 sessions: gather_inner
+    # 3780.7-3789.7 ms, pallas_inner 220.4-220.7 ms (17x; nine XLA
+    # gathers of the 5-channel polynomial stacks were 3.5 s of the step;
+    # PERF.md section 6). NOT numerics-identical: the iteration's flow is
+    # clipped to the bound (ops/flow.py::_inner_warp_fn). No CPU A/B (the
+    # kernel runs in interpret mode there): the fallback.
+    "flow_inner": {
+        "comparison": "flow_inner_720p",
+        "as_of": {"tpu": "2026-09-28T09:12:00+00:00"},
+        "winners": {"tpu": "pallas"},
+        "fallback": "gather",
+        "label_to_impl": {"gather_inner": "gather",
+                          "pallas_inner": "pallas"},
     },
     # ksize >= 9 branch of gaussian_blur. TPU winner is SHIFT per the
     # 2026-07-31 A/B (shift 1022.4 vs pallas_fused 186.3 fps at 1080p

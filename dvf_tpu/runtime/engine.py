@@ -20,6 +20,12 @@ Key TPU-first choices:
   threads+queues falls out of the runtime.
 - **Static shapes.** One (batch, H, W, C) signature = one compilation;
   the assembler pads short batches (`valid` mask) rather than re-tracing.
+- **Temporal state is a table of sessions.** A filter with per-session
+  state (``Filter.session_state``) gets one state row per session,
+  ``[state_rows, …]`` per leaf, donated through every step like any
+  state. The step takes the frames and a small int32 row map saying
+  which table row each batch row continues; which sessions share a
+  batch, and with how many rows each, is data and never shape.
 """
 
 from __future__ import annotations
@@ -37,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dvf_tpu.api.filter import Filter
+from dvf_tpu.api.filter import Filter, session_leaves
 from dvf_tpu.parallel.halo import spatial_filter
 from dvf_tpu.parallel.mesh import batch_pspec, batch_sharding, make_mesh, replicated
 from dvf_tpu.utils.image import to_float, to_uint8
@@ -57,6 +63,60 @@ def _body_dtype(filt: Filter, in_dtype):
     if np.dtype(in_dtype) == np.uint8 and not filt.uint8_ok:
         return filt.compute_dtype
     return in_dtype
+
+
+def session_rows(batch: int, state_rows: int) -> int:
+    """How many session states one step gathers out of the table (and
+    writes back): a batch cannot hold more sessions than rows, nor than
+    the table has."""
+    return min(int(batch), int(state_rows))
+
+
+def device_row_map(rows: Optional[np.ndarray], batch: int,
+                   state_rows: int) -> np.ndarray:
+    """The step's row map, from the serve path's per-batch-row form.
+
+    ``rows`` is int32 ``[2, batch]``: ``rows[0, i]`` the state row (the
+    session) batch row i belongs to, -1 for a pad row; ``rows[1, i]`` 1
+    on a session's first frame since its row was bound (the row restarts
+    from ``init_state`` there). None is one stream's consecutive frames
+    in state row 0, every row valid — what the single-stream executors
+    submit.
+
+    Returned: int32 ``[batch + 3 T]``, T = :func:`session_rows`:
+    ``pred[batch]`` as ``Filter.rows`` reads it, then per gathered
+    session ``t``: its table row (-1 = entry unused: read row 0, write
+    nothing), its fresh mark, and the batch row after which its state is
+    stored. A pad row reads entry 0 and is nobody's predecessor.
+    """
+    t_n = session_rows(batch, state_rows)
+    m = np.zeros(batch + 3 * t_n, np.int32)
+    pred, trow = m[:batch], m[batch:batch + t_n]
+    tfresh, tlast = m[batch + t_n:batch + 2 * t_n], m[batch + 2 * t_n:]
+    trow[:] = -1
+    if rows is None:
+        pred[1:] = np.arange(t_n, t_n + batch - 1)
+        trow[0], tlast[0] = 0, batch - 1
+        return m
+    entry: Dict[int, int] = {}
+    last: Dict[int, int] = {}
+    for i in range(batch):
+        r = int(rows[0, i])
+        if r < 0:
+            continue
+        if r >= state_rows:   # the device would drop the write in silence
+            raise ValueError(f"state row {r} of batch row {i} is outside "
+                             f"the table's {state_rows} rows")
+        j = last.get(r)
+        if j is None:
+            t = entry[r] = len(entry)
+            trow[t], tfresh[t], pred[i] = r, rows[1, i], t
+        else:
+            pred[i] = t_n + j
+        last[r] = i
+    for r, t in entry.items():
+        tlast[t] = last[r]
+    return m
 
 
 @dataclasses.dataclass
@@ -80,8 +140,15 @@ class Engine:
         chaos=None,
         op_chain: Optional[str] = None,
         calibration_seed: Optional[dict] = None,
+        state_rows: int = 1,
     ):
         self.filter = filt
+        if state_rows < 1:
+            raise ValueError("state_rows must be >= 1")
+        self.state_rows = int(state_rows)  # sessions whose temporal state
+        #   the device table holds (Filter.session_state filters only;
+        #   the serve frontend sizes it with max_sessions). 1 = the
+        #   single-stream executors: one stream, table row 0.
         self.mesh = mesh if mesh is not None else make_mesh()
         self.out_uint8 = out_uint8
         self.op_chain = op_chain if op_chain is not None else filt.name
@@ -97,6 +164,8 @@ class Engine:
         self.stats = EngineStats()
         self._exec_filter = filt   # possibly halo-wrapped in compile()
         self._step = None
+        self._tabled = False  # compile() found a session-state filter:
+        #   _state is the session table and the step takes a row map
         self._signature: Optional[Tuple] = None
         self._state: Any = None
         self._sharding = None  # chosen per batch signature in compile()
@@ -233,6 +302,12 @@ class Engine:
             spec = P()
             fn = jax.shard_map(filt.fn, mesh=self.mesh, in_specs=(spec, spec),
                                out_specs=(spec, spec), check_vma=False)
+            if filt.rows is not None:
+                return dataclasses.replace(
+                    filt, name=f"manual({filt.name})", fn=fn, specialize=None,
+                    rows=jax.shard_map(
+                        filt.rows, mesh=self.mesh, in_specs=(spec,) * 3,
+                        out_specs=(spec, spec), check_vma=False))
         else:
             spec = self._sharding.spec
             rows = jax.shard_map(lambda batch: filt.fn(batch, None)[0],
@@ -247,13 +322,17 @@ class Engine:
     def _build_step(self, batch_shape, in_dtype):
         filt = self._exec_filter
         out_uint8 = self.out_uint8
+        # A session-state filter's body also takes the batch's row map.
+        body, map_avals = ((self._table_body(batch_shape, in_dtype),
+                            (self._row_map_aval(batch_shape[0]),))
+                           if self._tabled else (filt.fn, ()))
 
-        def step(batch, state):
+        def step(batch, state, *row_map):
             if batch.dtype == jnp.uint8 and not filt.uint8_ok:
                 x = to_float(batch, filt.compute_dtype)
             else:
                 x = batch
-            y, new_state = filt.fn(x, state)
+            y, new_state = body(x, state, *row_map)
             if out_uint8 and y.dtype != jnp.uint8:
                 y = to_uint8(y)
             return y, new_state
@@ -269,6 +348,7 @@ class Engine:
             step,
             jax.ShapeDtypeStruct(tuple(batch_shape), np.dtype(in_dtype)),
             self._state,  # built just before _build_step in compile()
+            *map_avals,
         )[0]
         donate = ((0, 1)
                   if (out_aval.shape == tuple(batch_shape)
@@ -276,10 +356,89 @@ class Engine:
                   else (1,))
         return jax.jit(
             step,
-            in_shardings=(self._sharding, state_shardings),
+            in_shardings=(self._sharding, state_shardings)
+            + (self._replicated,) * len(map_avals),
             out_shardings=(self._sharding, state_shardings),
             donate_argnums=donate,
         )
+
+    def _row_map_aval(self, batch: int):
+        return jax.ShapeDtypeStruct(
+            (batch + 3 * session_rows(batch, self.state_rows),), np.int32)
+
+    def _table_body(self, batch_shape, in_dtype):
+        """The body of a session-state filter's step: gather the batch's
+        sessions out of the table, run ``Filter.rows``, store each
+        session's state after its last row. Everything about who is in
+        the batch arrives in ``row_map`` (:func:`device_row_map`)."""
+        filt = self._exec_filter
+        bsz = int(batch_shape[0])
+        t_n = session_rows(bsz, self.state_rows)
+        n_rows = self.state_rows
+        state_dtype = _body_dtype(filt, in_dtype)
+
+        def body(x, table, row_map):
+            pred = row_map[:bsz]
+            trow = row_map[bsz:bsz + t_n]
+            fresh = row_map[bsz + t_n:bsz + 2 * t_n] > 0
+            tlast = row_map[bsz + 2 * t_n:]
+
+            def gathered(leaf, init, per_session):
+                if not per_session:   # stored once (a chain's weights)
+                    return leaf
+                got = jnp.take(leaf, jnp.maximum(trow, 0), axis=0)
+                mark = fresh.reshape((t_n,) + (1,) * (got.ndim - 1))
+                return jnp.where(mark, jnp.asarray(init)[None], got)
+
+            init = filt.init_state(batch_shape, state_dtype)
+            tabled = session_leaves(filt, init)
+            prev = jax.tree.map(gathered, table, init, tabled)
+            y, row_states = filt.rows(x, prev, pred)
+            # Unused entries point past the table, each at an index of
+            # its own (the scatter is told they are unique): dropped.
+            dst = jnp.where(trow >= 0, trow,
+                            n_rows + jnp.arange(t_n, dtype=trow.dtype))
+            new_table = jax.tree.map(
+                lambda leaf, rs, per_session: leaf.at[dst].set(
+                    jnp.take(rs, tlast, axis=0).astype(leaf.dtype),
+                    mode="drop", unique_indices=True)
+                if per_session else leaf,
+                table, row_states, tabled)
+            return y, new_table
+
+        return body
+
+    def _run_step(self, batch, rows: Optional[np.ndarray] = None):
+        """One step on a device-resident batch, the state threaded."""
+        if self._tabled:
+            y, self._state = self._step(
+                batch, self._state,
+                device_row_map(rows, batch.shape[0], self.state_rows))
+        else:
+            y, self._state = self._step(batch, self._state)
+        return y
+
+    def step_operands(self) -> Tuple:
+        """Abstract operands of the compiled step, for ``lower()``."""
+        shape, dtype = self._signature
+        ops = (jax.ShapeDtypeStruct(shape, dtype), self._state)
+        return ops + ((self._row_map_aval(shape[0]),) if self._tabled else ())
+
+    def _fresh_state(self, batch_shape, dtype):
+        """The filter's initial state on the device; for a session-state
+        filter, its per-session leaves (``session_leaves``) stacked
+        ``state_rows`` times into the table."""
+        ef = self._exec_filter
+        if not ef.stateful:
+            return None
+        state = ef.init_state(batch_shape, _body_dtype(ef, dtype))
+        if self._tabled:
+            n = self.state_rows
+            state = jax.tree.map(
+                lambda a, per_session: jnp.broadcast_to(
+                    jnp.asarray(a)[None], (n,) + jnp.shape(a))
+                if per_session else a, state, session_leaves(ef, state))
+        return jax.device_put(state, self._state_shardings())
 
     def _state_shardings(self):
         """Sharding (tree or single) for the state pytree; also valid as a
@@ -316,15 +475,16 @@ class Engine:
                 base = specialized
         # … then the H-axis halo routing — see _pick_exec_filter.
         self._exec_filter = self._pick_exec_filter(base, batch_shape, dtype)
+        self._tabled = self._exec_filter.session_state
+        if self._exec_filter.temporal and not self._tabled \
+                and self.state_rows > 1:
+            raise ValueError(
+                f"filter {self._exec_filter.name!r} carries temporal state "
+                f"but defines no many-session body (Filter.rows): it can "
+                f"only run with state_rows=1")
 
         def fresh_state():
-            ef = self._exec_filter
-            if not ef.stateful:
-                return None
-            return jax.device_put(
-                ef.init_state(batch_shape, _body_dtype(ef, dtype)),
-                self._state_shardings()
-            )
+            return self._fresh_state(batch_shape, dtype)
 
         self._state = fresh_state()
         self._step = self._build_step(batch_shape, dtype)
@@ -364,7 +524,7 @@ class Engine:
             dummy = jax.device_put(zeros, self._sharding)
             jax.block_until_ready(dummy)
             self.h2d_block_ms = (time.perf_counter() - t0) * 1e3
-        out, _ = self._step(dummy, self._state)
+        out = self._run_step(dummy)
         out.block_until_ready()
         # Output signature + sharding: what the egress fetcher lays its
         # per-shard host slabs out from (the mirror of input_sharding).
@@ -406,7 +566,7 @@ class Engine:
         elif zeros.nbytes <= _D2H_CALIBRATION_CAP_BYTES:
             cal = jax.device_put(zeros, self._sharding)
             t0 = time.perf_counter()
-            out2, _ = self._step(cal, self._state)
+            out2 = self._run_step(cal)
             out2.block_until_ready()
             self.step_block_ms = (time.perf_counter() - t0) * 1e3
             del cal, out2
@@ -458,11 +618,15 @@ class Engine:
         layout from. None before the first compile."""
         return self._out_sharding
 
-    def submit(self, batch: np.ndarray) -> jax.Array:
+    def submit(self, batch: np.ndarray,
+               rows: Optional[np.ndarray] = None) -> jax.Array:
         """Dispatch one host batch; returns the (async) on-device result.
 
         The filter state (if any) is threaded internally across calls —
         device-resident, never copied to host (SURVEY.md §7 hard part 4).
+        ``rows`` (session-state filters; ignored otherwise) says which
+        session each batch row belongs to — see :func:`device_row_map`;
+        None reads the batch as one stream's consecutive frames.
         """
         if self.freed:
             raise RuntimeError(
@@ -474,11 +638,12 @@ class Engine:
             self.chaos.fire("oom")
             self.chaos.fire("compute")
         x = jax.device_put(batch, self._sharding)
-        y, self._state = self._step(x, self._state)
+        y = self._run_step(x, rows)
         self._count_batch(batch.shape[0])
         return y
 
-    def submit_resident(self, batch: jax.Array) -> jax.Array:
+    def submit_resident(self, batch: jax.Array,
+                        rows: Optional[np.ndarray] = None) -> jax.Array:
         """Serving entry for an already-device-resident batch: the
         streamed ingest path (runtime/ingest.py) shipped the shards while
         they decoded and assembled the mesh array itself, so the internal
@@ -496,7 +661,7 @@ class Engine:
         if self.chaos is not None:
             self.chaos.fire("oom")
             self.chaos.fire("compute")
-        y, self._state = self._step(batch, self._state)
+        y = self._run_step(batch, rows)
         self._count_batch(batch.shape[0])
         return y
 
@@ -559,10 +724,8 @@ class Engine:
         (deserialize, not recompile)."""
         if self._step is None or self._signature is None:
             return None
-        shape, dtype = self._signature
         try:
-            lowered = self._step.lower(
-                jax.ShapeDtypeStruct(shape, dtype), self._state)
+            lowered = self._step.lower(*self.step_operands())
             ca = lowered.compile().cost_analysis()
             flops = float(ca.get("flops", 0.0))
             byts = float(ca.get("bytes accessed", 0.0))
@@ -577,11 +740,13 @@ class Engine:
         same filter/mesh/options, recompiled at the old signature — the
         full compile() path, so the replacement is re-warmed and its
         ``h2d_block_ms`` re-calibrated before it takes traffic. A
-        stateful filter's temporal state restarts fresh (the wedged
-        engine's device-resident state is unrecoverable by definition).
+        stateful filter's temporal state restarts fresh, every session's
+        row of it (the wedged engine's device-resident state is
+        unrecoverable by definition).
         """
         fresh = Engine(self.filter, mesh=self.mesh, out_uint8=self.out_uint8,
-                       chaos=self.chaos, op_chain=self.op_chain)
+                       chaos=self.chaos, op_chain=self.op_chain,
+                       state_rows=self.state_rows)
         if self._signature is not None:
             shape, dtype = self._signature
             fresh.compile(shape, dtype)
@@ -639,7 +804,8 @@ class Engine:
                 #   failure — the old program must keep serving
             succ = Engine(self.filter, mesh=self.mesh,
                           out_uint8=self.out_uint8, chaos=self.chaos,
-                          op_chain=self.op_chain)
+                          op_chain=self.op_chain,
+                          state_rows=self.state_rows)
             succ.compile(tuple(batch_shape), dtype)
         except BaseException:
             with self._swap_lock:
@@ -670,8 +836,9 @@ class Engine:
         normally; the old program's handles drop here and its buffers
         free once they do.
 
-        Device-resident filter state migrates device-to-device when the
-        successor's state tree matches shape-for-shape
+        Device-resident filter state (a session-state filter's whole
+        table, which no batch size shapes) migrates device-to-device when
+        the successor's state tree matches shape-for-shape
         (``migrate_state=True``); a geometry-changing swap (or
         ``migrate_state=False`` — supervised recovery, whose old state
         is poisoned by definition) keeps the successor's fresh state.
@@ -710,8 +877,8 @@ class Engine:
             # paths read. In place — the engine OBJECT survives, so
             # pool leases, bucket bindings, and probe callers keep one
             # stable identity across any number of swaps.
-            for name in ("_step", "_signature", "_state", "_sharding",
-                         "_batch_replicated",
+            for name in ("_step", "_tabled", "_signature", "_state",
+                         "_sharding", "_batch_replicated",
                          "_exec_filter", "out_shape", "out_dtype",
                          "_out_sharding", "h2d_block_ms", "d2h_block_ms",
                          "step_block_ms", "last_compile_ms",
@@ -788,13 +955,9 @@ class Engine:
         _unregister_pool_engine(self)
 
     def reset_state(self) -> None:
+        """Restart the filter's state (every session's row of it)."""
         if self._exec_filter.stateful and self._signature is not None:
-            shape, dtype = self._signature
-            ef = self._exec_filter
-            self._state = jax.device_put(
-                ef.init_state(shape, _body_dtype(ef, dtype)),
-                self._state_shardings()
-            )
+            self._state = self._fresh_state(*self._signature)
 
 
 # ---------------------------------------------------------------------------

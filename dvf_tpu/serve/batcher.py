@@ -38,6 +38,17 @@ Scheduling policy (the genuinely new multi-tenant part):
   starved small bucket's headroom shrinks every tick while the big
   bucket's stays refreshed, so the small bucket always wins before its
   deadline passes (fairness pinned in tests/test_multitenant.py).
+- **Temporal state follows the session, not the batch.** For a filter
+  with per-session state each plan carries a row map (``BatchPlan.rows``):
+  the state row of the session each batch row belongs to, -1 on pad
+  rows, and a fresh mark on a session's first frame since its row was
+  bound. EDF picks per-session prefixes in index order, so within a
+  batch a session's rows are in stream order and a row's predecessor is
+  the same session's previous row in that batch, else the state its
+  session carried in. A frame that was shed, dropped or discarded
+  before submit never reached the device: it is skipped, and the next
+  frame's predecessor is the last frame of that session that DID reach
+  the device (tests/test_session_state.py).
 """
 
 from __future__ import annotations
@@ -86,6 +97,10 @@ class BatchPlan:
     #   session_id, frame_index, lineage), ...]; the collect side pairs
     #   each with its DELIVERED output and hands the pair to the replay
     #   worker. None = audit off or nothing sampled (zero cost).
+    rows: Optional[np.ndarray] = None  # session-state filters: the
+    #   int32 [2, batch] row map Engine.submit takes (runtime.engine.
+    #   device_row_map) — state row per batch row (-1 = pad), fresh
+    #   mark per row. None for every other filter.
     fetcher: Any = None  # the egress fetcher THIS batch was prefetched
     #   into, pinned at dispatch: a hot program swap may replace
     #   ``bucket.fetcher`` (new output signature) while this batch is
@@ -213,6 +228,33 @@ class ContinuousBatcher:
         return best, self.select(best_sessions, now, pre_drained=True,
                                  limit=limit)
 
+    @staticmethod
+    def row_map(slots: Sequence[Slot], batch_size: int
+                ) -> Optional[np.ndarray]:
+        """The plan's session-state row map (``BatchPlan.rows``), or
+        None when the chosen sessions hold no state row. Reads each
+        session's fresh mark without clearing it: the caller clears the
+        marks (:meth:`mark_reached_device`) only once the batch was
+        submitted, so a plan discarded before submit leaves them set."""
+        if not slots or slots[0].session.state_row is None:
+            return None
+        rows = np.zeros((2, batch_size), np.int32)
+        rows[0] = -1
+        marked = set()
+        for i, slot in enumerate(slots):
+            s = slot.session
+            rows[0, i] = s.state_row
+            if s.state_fresh and s not in marked:
+                rows[1, i] = 1
+                marked.add(s)
+        return rows
+
+    @staticmethod
+    def mark_reached_device(slots: Sequence[Slot]) -> None:
+        """The batch was submitted: its sessions' rows now hold state."""
+        for slot in slots:
+            slot.session.state_fresh = False
+
     def _pool_staging(self, frame: np.ndarray) -> np.ndarray:
         shape = (self.batch_size, *frame.shape)
         if self._staging is None or self._staging[0].shape != shape \
@@ -246,4 +288,5 @@ class ContinuousBatcher:
             slot.frame = None  # drop the client's buffer reference
         for row in range(valid, self.batch_size):
             np.copyto(staging[row], staging[valid - 1])
-        return BatchPlan(batch=staging, valid=valid, slots=chosen)
+        return BatchPlan(batch=staging, valid=valid, slots=chosen,
+                         rows=self.row_map(chosen, self.batch_size))

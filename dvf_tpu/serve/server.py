@@ -35,14 +35,19 @@ drop-oldest bounds and the batcher's SLO shedding, never by blocking a
 client.
 
 Temporal filters (``Filter.temporal``: state one batch writes and the
-next reads, e.g. flow's previous frame) are served only by a
-single-tenant frontend (``max_sessions == 1``): with more, the state
-would thread *across* batches whose rows belong to different tenants —
-cross-session leakage by construction — so the frontend refuses them at
-build time, and a single-tenant frontend resets the state at every
-admission. Filters whose state is read-only weights
-(``Filter.constant_state``: style transfer, super resolution) carry
-nothing from batch to batch and are multiplexed like stateless ones.
+next reads, e.g. flow's previous frame) are multiplexed like any other:
+the state is one SESSION's. The bucket's engine holds a device table of
+``max_sessions`` state rows; ``open_stream`` binds the session a row and
+marks it fresh (its first frame restarts the row from the filter's
+initial state), retirement frees the row, a morph or quality rebind to
+another bucket starts fresh there. Every batch carries a row map
+(``BatchPlan.rows``) naming each row's session, so a session's output
+depends on that session's frames only, whatever else shares its batches.
+A supervised engine rebuild restarts every row, and a session a fleet
+migrates to another replica restarts there: both are counted on the
+bucket row's ``state`` block (``resets_total``). Filters whose state is
+read-only weights (``Filter.constant_state``: style transfer, super
+resolution) carry nothing from batch to batch and need no table.
 
 ``ZmqStreamBridge`` binds one session to the reference app's socket pair
 using the exact READY-credit framing of ``transport.zmq_ingress`` — a
@@ -407,6 +412,55 @@ class _Bucket:
         #   estimate before the first live sample and annotates
         #   control-plane decisions
         self._pooled = False  # engine leased/adopted in the ProgramPool
+        # Session-state table bookkeeping (Filter.session_state filters;
+        # zero rows otherwise). Rows are bound and freed under the
+        # frontend lock; the counters are the dispatch thread's.
+        self.state_rows = (engine.state_rows if filt.session_state else 0)
+        self._state_free = list(range(self.state_rows - 1, -1, -1))
+        self.state_counts = {"table_rows_total": 0, "chain_rows_total": 0,
+                             "fresh_rows_total": 0}
+        self.state_resets = {"admission": 0, "rebuild": 0, "migrate": 0}
+
+    # -- session state ---------------------------------------------------
+
+    def bind_state(self, s: StreamSession, cause: str = "admission") -> None:
+        """Give ``s`` a row of this bucket's session-state table, marked
+        fresh: its next frame to reach the device restarts the row."""
+        s.state_row, s.state_fresh = None, False
+        if not self.state_rows:
+            return
+        if not self._state_free:
+            raise AdmissionError(
+                f"no free state row in bucket {self.label()!r} "
+                f"({self.state_rows} rows = max_sessions)")
+        s.state_row, s.state_fresh = self._state_free.pop(), True
+        self.state_resets[cause] += 1
+
+    def release_state(self, s: StreamSession) -> None:
+        if s.state_row is not None:
+            self._state_free.append(s.state_row)
+        s.state_row, s.state_fresh = None, False
+
+    def restart_state(self) -> int:
+        """The engine was rebuilt: its table is new, every bound session
+        restarts. Returns how many."""
+        bound = [s for s in self.sessions.values()
+                 if s.state_row is not None]
+        for s in bound:
+            s.state_fresh = True
+        self.state_resets["rebuild"] += len(bound)
+        return len(bound)
+
+    def note_state_rows(self, plan: BatchPlan) -> None:
+        """Dispatch thread, after the submit: the plan's frames reached
+        the device. A session's first row in the batch took its
+        predecessor from the table, its later rows from the batch."""
+        ContinuousBatcher.mark_reached_device(plan.slots)
+        sessions = len(set(plan.rows[0, :plan.valid].tolist()))
+        c = self.state_counts
+        c["table_rows_total"] += sessions
+        c["chain_rows_total"] += plan.valid - sessions
+        c["fresh_rows_total"] += int(plan.rows[1].sum())
 
     # -- scheduling ------------------------------------------------------
 
@@ -539,6 +593,12 @@ class _Bucket:
             row["ingest"] = self.ingest_stats.summary()
         if self.egress_stats is not None:
             row["egress"] = self.egress_stats.summary()
+        if self.state_rows:
+            row["state"] = dict(
+                self.state_counts, rows=self.state_rows,
+                bound=self.state_rows - len(self._state_free),
+                bytes=getattr(self.engine, "state_bytes", 0),
+                resets_total=dict(self.state_resets))
         return row
 
 
@@ -554,12 +614,6 @@ class ServeFrontend:
     ):
         self.filter = filt
         self.config = config or ServeConfig()
-        if filt.temporal and self.config.max_sessions != 1:
-            raise ValueError(
-                f"filter {filt.name!r} carries temporal state; a shared "
-                f"batch interleaves rows from different sessions, so the "
-                f"state would leak across tenants — a temporal filter "
-                f"needs a single-tenant frontend (max_sessions=1)")
         if self.config.ingest not in INGEST_MODES:
             raise ValueError(
                 f"ingest must be one of {INGEST_MODES}, got "
@@ -568,7 +622,17 @@ class ServeFrontend:
             raise ValueError(
                 f"egress must be one of {EGRESS_MODES}, got "
                 f"{self.config.egress!r}")
-        engine = engine or Engine(filt, chaos=self.config.chaos)
+        engine = engine or Engine(filt, chaos=self.config.chaos,
+                                  state_rows=self.config.max_sessions)
+        if filt.temporal and engine.state_rows < self.config.max_sessions:
+            # A caller-built engine: its session-state table has to hold
+            # every tenant this frontend admits.
+            if engine.signature is not None:
+                raise ValueError(
+                    f"engine for temporal filter {filt.name!r} was "
+                    f"compiled with {engine.state_rows} state row(s); "
+                    f"max_sessions={self.config.max_sessions} needs as many")
+            engine.state_rows = self.config.max_sessions
         if self.config.chaos is not None and engine.chaos is None:
             engine.chaos = self.config.chaos  # arm caller-built engine
         # Signature buckets: the DEFAULT bucket (index 0) carries the
@@ -1476,8 +1540,15 @@ class ServeFrontend:
         tier: Optional[int] = None,
         publish: Optional[str] = None,
         publish_tiers: Optional[Sequence] = None,
+        state_cause: str = "admission",
     ) -> str:
         """Admit one new stream; returns its session id.
+
+        ``state_cause`` says why the session's temporal state starts
+        fresh here, for the bucket row's ``state.resets_total``:
+        ``"admission"`` (a new stream) or ``"migrate"`` (a fleet re-opens
+        on this replica a session it served elsewhere; the state did not
+        travel). It changes the count, nothing else.
 
         ``publish`` registers the session's delivered output as a named
         broadcast channel (dvf_tpu.broadcast): subscribers attach with
@@ -1515,6 +1586,9 @@ class ServeFrontend:
         t = self.config.default_tier if tier is None else int(tier)
         if t < 0:
             raise ValueError(f"tier must be >= 0, got {tier!r}")
+        if state_cause not in ("admission", "migrate"):
+            raise ValueError(f"state_cause must be 'admission' or "
+                             f"'migrate', got {state_cause!r}")
         cfg = SessionConfig(
             queue_size=self.config.queue_size,
             slo_ms=slo_ms if slo_ms is not None else self.config.slo_ms,
@@ -1546,9 +1620,8 @@ class ServeFrontend:
             if bucket is not None:
                 self._price_admission_locked(bucket, t, cfg.slo_ms)
                 sid_out = self._register_session_locked(
-                    bucket, session_id, cfg, sink)
+                    bucket, session_id, cfg, sink, state_cause)
         if bucket is not None:
-            self._reset_temporal_state(bucket)
             self._warm_quality_async(bucket)
             if publish:
                 self.publish_stream(sid_out, publish, publish_tiers)
@@ -1576,7 +1649,7 @@ class ServeFrontend:
                     bucket = self._create_bucket_locked(create_key, engine)
                     owned = True
                 sid_out = self._register_session_locked(
-                    bucket, session_id, cfg, sink)
+                    bucket, session_id, cfg, sink, state_cause)
         finally:
             if not owned:
                 # Either the signature raced into existence (join — our
@@ -1584,21 +1657,10 @@ class ServeFrontend:
                 # admission failed after the lease: the program stays
                 # WARM in the pool either way.
                 self.pool.release(create_key)
-        self._reset_temporal_state(bucket)
         self._warm_quality_async(bucket)
         if publish:
             self.publish_stream(sid_out, publish, publish_tiers)
         return sid_out
-
-    @staticmethod
-    def _reset_temporal_state(bucket: "_Bucket") -> None:
-        """A temporal filter is only ever served single-tenant
-        (max_sessions == 1), and that cap admits the next session only
-        once the previous one has drained and retired — nothing is in
-        flight here, so the new tenant starts from pristine state
-        instead of the last tenant's final frame."""
-        if bucket.filter.temporal:
-            bucket.engine.reset_state()
 
     # -- broadcast plane (publish / subscribe) ---------------------------
 
@@ -1761,11 +1823,13 @@ class ServeFrontend:
 
     def _register_session_locked(self, bucket: "_Bucket",
                                  session_id: Optional[str],
-                                 cfg: SessionConfig, sink: Any) -> str:
+                                 cfg: SessionConfig, sink: Any,
+                                 state_cause: str = "admission") -> str:
         sid = session_id if session_id is not None else f"s{next(self._ids)}"
         if sid in self._sessions or sid in self._retired:
             raise ServeError(f"session id {sid!r} already exists")
         s = StreamSession(sid, cfg, sink=sink)
+        bucket.bind_state(s, state_cause)
         s.bucket = bucket
         s.attribution = self.attribution  # None when lineage is off
         self._sessions[sid] = s
@@ -1863,12 +1927,6 @@ class ServeFrontend:
                 filt = self._filters_by_chain.get(key.op_chain)
             if filt is None:
                 filt = build_filter(key.op_chain)
-                if filt.temporal and self.config.max_sessions != 1:
-                    raise AdmissionError(
-                        f"op_chain {key.op_chain!r} carries temporal "
-                        f"state; a shared batch interleaves tenants, so "
-                        f"the state would leak across sessions — "
-                        f"temporal chains need max_sessions=1")
                 with self._lock:
                     self._filters_by_chain.setdefault(key.op_chain, filt)
             seed = None
@@ -1886,7 +1944,8 @@ class ServeFrontend:
                     self._topology_fingerprint(), cal_sig)
             eng = Engine(filt, mesh=self.engine.mesh,
                          chaos=self.config.chaos, op_chain=key.op_chain,
-                         calibration_seed=seed)
+                         calibration_seed=seed,
+                         state_rows=self.config.max_sessions)
             eng.compile((self.config.batch_size, *key.geometry),
                         key.np_dtype)
             if self.config.plan_cache_dir and not eng.calibration_seeded:
@@ -2597,6 +2656,8 @@ class ServeFrontend:
                     flushed = s.flush_queued(count_shed=False)
                     self.quality_flushed_frames += flushed
                     old.sessions.pop(sid, None)
+                    old.release_state(s)
+                    target.bind_state(s)  # another program: starts fresh
                     target.sessions[sid] = s
                     s.bucket = target
                 if morph_chain is not None:
@@ -2965,6 +3026,8 @@ class ServeFrontend:
     def _retire_locked(self, sid: str, session: StreamSession) -> None:
         """Move one session to the retired map, evicting oldest beyond
         the retention bound (dicts iterate in insertion order)."""
+        if session.bucket is not None:
+            session.bucket.release_state(session)
         self._retired[sid] = session
         while len(self._retired) > self.config.max_retired:
             self._absorb_totals_locked(
@@ -3312,6 +3375,10 @@ class ServeFrontend:
                         f.release()
                     b.release_drained_fetchers()  # window fully shed:
                     #   nothing in flight can still pin them
+                    # The rebuilt engine's session-state table is new:
+                    # every bound session restarts (counted, ledgered).
+                    with self._lock:
+                        restarted = b.restart_state()
                     if self.ledger is not None:
                         label = b.label()
                         compile_ms = b.engine.last_compile_ms
@@ -3320,6 +3387,7 @@ class ServeFrontend:
                             cause=ledger_mod.CAUSE_RECOVERY,
                             signature=label, bucket=label,
                             fault_kind=kind, reason=reason,
+                            state_rows_restarted=restarted or None,
                             wall_ms=(time.time() - t_rb) * 1e3,
                             compile_ms=(round(float(compile_ms), 3)
                                         if compile_ms is not None
@@ -3441,6 +3509,8 @@ class ServeFrontend:
                         plan = BatchPlan(
                             batch=None, valid=len(chosen), slots=chosen,
                             bucket=pick,
+                            rows=self.batcher.row_map(chosen,
+                                                      pick.batch_size),
                             stamps=BatchStamps(pick.stages, time.time()))
                 self._finalize_drained()
                 if plan is None:
@@ -3507,8 +3577,13 @@ class ServeFrontend:
                         slot.frame = None  # drop the client's buffer
                     batch, resident = builder.finish(plan.valid)
                     engine = bucket.engine
-                    result = (engine.submit_resident(batch)
-                              if resident else engine.submit(batch))
+                    submit = (engine.submit_resident if resident
+                              else engine.submit)
+                    if plan.rows is None:
+                        result = submit(batch)
+                    else:  # session-state filter: who is in the batch
+                        result = submit(batch, plan.rows)
+                        bucket.note_state_rows(plan)
                     # Stamp: batch assembly + H2D ends at submit return
                     # (async dispatch: the device now owns the batch).
                     st.t_submit = time.time()
@@ -3544,10 +3619,13 @@ class ServeFrontend:
                                     TRACK_DISPATCH, seq=seq,
                                     frames=plan.valid,
                                     bucket=bucket.label())
+                    n_sess = len({slot.session.id for slot in plan.slots})
                     tracer.complete("dispatch:permit_wait", st.t_chosen,
-                                    t0, TRACK_DISPATCH, seq=seq)
+                                    t0, TRACK_DISPATCH, seq=seq,
+                                    sessions=n_sess)
                     tracer.complete("dispatch:assemble_h2d", t0,
-                                    st.t_submit, TRACK_DISPATCH, seq=seq)
+                                    st.t_submit, TRACK_DISPATCH, seq=seq,
+                                    sessions=n_sess)
                 # In-flight window: registered from now until the collect
                 # side materializes (or discards) it; carries the plan so
                 # a recovery can shed the sessions' claims even for a

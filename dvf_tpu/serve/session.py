@@ -142,6 +142,15 @@ class StreamSession:
         #   bound to (serve.server._Bucket, set at admission): which
         #   compiled program serves it, which geometry its frames must
         #   match, and where its faults/budget overflow attribute
+        self.state_row: Optional[int] = None  # this session's row of
+        #   its bucket's session-state table (temporal filters; bound
+        #   at admission or rebind, freed at retirement) — what the
+        #   batch's row map names for each of its frames. None: the
+        #   bucket's filter keeps no per-session state.
+        self.state_fresh = False  # the row restarts from the filter's
+        #   initial state at this session's NEXT frame to reach the
+        #   device (set at bind, cleared by the dispatch thread once a
+        #   batch carrying the mark was submitted)
         # -- load-adaptive quality state (dvf_tpu.control) --------------
         self.quality_level = 0   # 0 = full quality; level L frames are
         #   decimated ×2^L per axis at submit and served by a bucket

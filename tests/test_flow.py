@@ -2,6 +2,7 @@
 
 import cv2
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from dvf_tpu.ops import get_filter
@@ -80,7 +81,18 @@ class TestFlowWarpFilter:
         filt = get_filter("flow_warp", levels=2, win_size=11, n_iters=2, flow_scale=1)
         state = filt.init_state(batch.shape, jnp.float32)
         out, state = filt(jnp.asarray(batch), state)
-        np.testing.assert_allclose(np.asarray(out), batch, atol=1e-6)
+        # A stream's first FRAME has no predecessor and passes through;
+        # the first batch's later rows follow a real frame and are warped
+        # exactly as the pairwise form warps them (what a frame gets does
+        # not depend on which batch it rode in).
+        np.testing.assert_allclose(np.asarray(out[0]), batch[0], atol=1e-6)
+        one = get_filter("flow_warp", levels=2, win_size=11, n_iters=2,
+                         flow_scale=1)
+        s1 = one.init_state(batch[:1].shape, jnp.float32)
+        for i in range(3):
+            o1, s1 = one(jnp.asarray(batch[i:i + 1]), s1)
+            np.testing.assert_allclose(np.asarray(out[i]), np.asarray(o1[0]),
+                                       atol=1e-5)
         assert bool(state["initialized"])
         np.testing.assert_allclose(np.asarray(state["prev"]), batch[-1], atol=1e-6)
 
@@ -343,3 +355,24 @@ def test_inner_warp_validated_at_construction():
 
     with pytest.raises(ValueError, match="inner_warp"):
         get_filter("flow_warp", inner_warp="scatter")
+
+
+@pytest.mark.parametrize("backend, warp_impl, inner", [
+    ("tpu", None, True),        # flow_warp() on the chip: the cell's program
+    ("tpu", "pallas", True),
+    ("tpu", "gather", False),   # no displacement bound anywhere
+    ("cpu", None, False),
+    ("cpu", "pallas", False),   # no A/B off the chip: the exact gathers
+])
+def test_inner_warp_default_follows_the_final_warp(monkeypatch, backend,
+                                                   warp_impl, inner):
+    """``inner_warp=None``: the bounded kernel inside the iteration only
+    where the final warp is bounded already, and there the measured
+    per-backend winner (MEASURED_DEFAULTS["flow_inner"])."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    name = get_filter("flow_warp", warp_impl=warp_impl).name
+    assert ("pallas-inner" in name) == inner
+    assert "pallas-inner" in get_filter(
+        "flow_warp", warp_impl="gather", inner_warp="pallas").name

@@ -230,7 +230,9 @@ class TestEngineMesh:
         rng = np.random.default_rng(0)
         b1 = rng.integers(0, 255, size=(2, 32, 32, 3), dtype=np.uint8)
         out1 = np.asarray(eng.submit(b1))
-        np.testing.assert_array_equal(out1, b1)  # first batch passes through
+        np.testing.assert_array_equal(out1[0], b1[0])  # a stream's first
+        #   frame passes through; row 1 already follows row 0
+        assert not np.array_equal(out1[1], b1[1])
         out2 = np.asarray(eng.submit(b1))
         assert out2.shape == b1.shape  # second batch uses carried state
 

@@ -199,18 +199,57 @@ class TestAdmissionControl:
         with pytest.raises(ServeError, match="already exists"):
             fe.open_stream(session_id="cam0")
 
-    def test_temporal_filter_rejected_when_multi_tenant(self):
-        """Temporal state would thread across tenants' batch rows."""
-        filt = get_filter("flow_warp", levels=1, win_size=7, n_iters=1,
-                          flow_scale=1)
-        with pytest.raises(ValueError, match="temporal state"):
-            ServeFrontend(filt)
+    def test_temporal_filter_admitted_and_isolated_when_multi_tenant(self):
+        """Temporal state is one session's: a default (multi-tenant)
+        frontend admits flow_warp, two tenants share its batches, and
+        each gets what an Engine fed that tenant's frames alone gives."""
+        from dvf_tpu.runtime.engine import Engine
+
+        kw = dict(levels=1, win_size=7, n_iters=1, flow_scale=1)
+        rng = np.random.default_rng(5)
+        streams = [[rng.integers(0, 255, (H, W, 3), np.uint8)
+                    for _ in range(n)] for n in (5, 3)]
+        want = []
+        for frames in streams:
+            ref = Engine(get_filter("flow_warp", **kw))
+            want.append([np.asarray(ref.submit(fr[None]))[0]
+                         for fr in frames])
+        fe = ServeFrontend(get_filter("flow_warp", **kw),
+                           ServeConfig(batch_size=4, queue_size=16,
+                                       slo_ms=60_000))
+        with fe:
+            sids = [fe.open_stream(), fe.open_stream()]
+            for i in range(5):
+                for sid, frames in zip(sids, streams):
+                    if i < len(frames):
+                        fe.submit(sid, frames[i])
+            got = {sid: [] for sid in sids}
+            deadline = time.time() + 60.0
+            while (any(len(got[sid]) < len(fr)
+                       for sid, fr in zip(sids, streams))
+                   and time.time() < deadline):
+                for sid in sids:
+                    got[sid] += fe.poll(sid)
+                time.sleep(0.01)
+            stats = fe.stats()
+        for sid, frames, w in zip(sids, streams, want):
+            assert [d.index for d in got[sid]] == list(range(len(frames)))
+            np.testing.assert_array_equal(got[sid][0].frame, frames[0])
+            for d in got[sid]:
+                np.testing.assert_array_equal(d.frame, w[d.index])
+        assert stats["errors"] == 0
+        state = next(iter(stats["buckets"].values()))["state"]
+        assert state["rows"] == ServeConfig().max_sessions
+        assert state["fresh_rows_total"] == 2
+        assert (state["table_rows_total"] + state["chain_rows_total"]
+                == sum(len(fr) for fr in streams))
 
     def test_temporal_filter_served_single_tenant(self):
-        """max_sessions=1 has no second tenant to leak to: the frontend
-        serves flow_warp exactly as an Engine fed the same frames in
-        order would, and every admission starts from pristine state (a
-        fresh stream's first frame passes through, as at engine start)."""
+        """max_sessions=1 is the one-row table: the frontend serves
+        flow_warp exactly as an Engine fed the same frames in order
+        would, and the row, re-bound at every admission, starts from
+        pristine state (a fresh stream's first frame passes through,
+        as at engine start)."""
         from dvf_tpu.runtime.engine import Engine
 
         kw = dict(levels=1, win_size=7, n_iters=1, flow_scale=1)
@@ -229,9 +268,9 @@ class TestAdmissionControl:
                 sid = fe.open_stream()
                 for fr in frames:
                     fe.submit(sid, fr)
-                    # one frame in flight at a time keeps the batch
-                    # composition (pairs) out of the comparison: flow's
-                    # output depends only on the previous frame
+                    # flow's output depends only on the session's
+                    # previous frame, so how the batcher happens to pair
+                    # the frames is not part of the comparison
                 got = []
                 deadline = time.time() + 60.0
                 while len(got) < len(frames) and time.time() < deadline:
@@ -246,7 +285,11 @@ class TestAdmissionControl:
                 deadline = time.time() + 10.0
                 while fe.open_count() and time.time() < deadline:
                     time.sleep(0.01)
-            assert fe.stats()["errors"] == 0
+            stats = fe.stats()
+            assert stats["errors"] == 0
+            resets = next(iter(stats["buckets"].values()))["state"][
+                "resets_total"]
+            assert resets == {"admission": 2, "rebuild": 0, "migrate": 0}
 
     def test_batch_larger_than_reorder_capacity_loses_nothing(self):
         """One tenant filling a batch larger than its reorder buffer
