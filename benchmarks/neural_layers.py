@@ -2,7 +2,9 @@
 
 The measured companion to the static model in ``dvf_tpu.models.analysis``:
 times each layer block of the style net / ESPCN separately on the real
-chip — reference lowering AND the exact fast-conv rewrites side by side —
+chip (for ESPCN the reference lowering AND the exact space-to-depth
+rewrite side by side; the style net's blocks in the plain composition, its
+served form being scripts/style_step_probe.py's to time, PERF.md §5) —
 so the 3.7x gap between style_720p's measured ms/frame and its per-layer
 roofline sum can be attributed to specific layers instead of guessed at.
 
@@ -56,8 +58,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from dvf_tpu.models.layers import (
-        conv2d_nb, conv2d_s2d, instance_norm, upsample2_conv,
-        upsample_nearest)
+        conv2d_nb, conv2d_s2d, instance_norm, upsample_nearest)
     from dvf_tpu.models.style_transfer import (
         StyleNetConfig, apply_style_net, init_style_net)
     from dvf_tpu.models.espcn import EspcnConfig, apply_espcn, init_espcn
@@ -102,12 +103,12 @@ def main(argv=None) -> int:
     def norm_relu(p, y):
         return jax.nn.relu(instance_norm(p, y))
 
+    # The plain composition, block by block (what stage_forms' "plain"
+    # runs); the served net's own per-stage times on the chip are
+    # scripts/style_step_probe.py's (PERF.md §5).
     timed("style/stem_ref", lambda x: norm_relu(
         sp["stem_norm"], conv2d_nb(sp["stem"], x, compute_dtype=cd,
                                    reflect=True)), x_full)
-    timed("style/stem_fast", lambda x: norm_relu(
-        sp["stem_norm"], conv2d_s2d(sp["stem"], x, compute_dtype=cd,
-                                    reflect=True)), x_full)
     timed("style/down1", lambda x: norm_relu(
         sp["down1_norm"], conv2d_nb(sp["down1"], x, stride=2,
                                     compute_dtype=cd, reflect=True)), x_c1)
@@ -128,27 +129,17 @@ def main(argv=None) -> int:
     timed("style/up1_ref", lambda x: norm_relu(
         sp["up1_norm"], conv2d_nb(sp["up1"], upsample_nearest(x, 2),
                                   compute_dtype=cd, reflect=True)), x_h4)
-    timed("style/up1_fast", lambda x: norm_relu(
-        sp["up1_norm"], upsample2_conv(sp["up1"], x, compute_dtype=cd)),
-        x_h4)
     timed("style/up2_ref", lambda x: norm_relu(
         sp["up2_norm"], conv2d_nb(sp["up2"], upsample_nearest(x, 2),
                                   compute_dtype=cd, reflect=True)), x_h2)
-    timed("style/up2_fast", lambda x: norm_relu(
-        sp["up2_norm"], upsample2_conv(sp["up2"], x, compute_dtype=cd)),
-        x_h2)
     timed("style/out_ref", lambda x: conv2d_nb(
-        sp["out"], x, compute_dtype=cd, reflect=True), x_c1)
-    timed("style/out_fast", lambda x: conv2d_s2d(
         sp["out"], x, compute_dtype=cd, reflect=True), x_c1)
 
     xs = jnp.asarray(rng.rand(b, sh, sw, 3).astype(np.float32))
-    timed("style/full_ref", lambda x: apply_style_net(sp, x, scfg), xs)
-    timed("style/full_fast", lambda x: apply_style_net(
-        sp, x, StyleNetConfig(fast_convs=True)), xs)
+    timed("style/full", lambda x: apply_style_net(sp, x, scfg), xs)
 
-    # Sum of standalone ref blocks vs the fused full net (res block x
-    # n_residual): positive gain = fusion wins that much back.
+    # Sum of standalone plain blocks vs the full net as served (res block
+    # x n_residual): the gain is fusion plus the phase-domain stages.
     ref_sum = (results["style/stem_ref"] + results["style/down1"]
                + results["style/down2"]
                + results["style/res_block_x1"] * scfg.n_residual
@@ -156,7 +147,7 @@ def main(argv=None) -> int:
                + results["style/out_ref"])
     results["style/sum_of_blocks_ref"] = round(ref_sum, 4)
     results["style/fusion_gain_ms"] = round(
-        ref_sum - results["style/full_ref"], 4)
+        ref_sum - results["style/full"], 4)
 
     ecfg = EspcnConfig()
     ep = init_espcn(jax.random.PRNGKey(0), ecfg)
@@ -197,8 +188,7 @@ def main(argv=None) -> int:
     os.replace(tmp, args.out)
     print(json.dumps({
         "written": args.out, "backend": backend,
-        "style_full_ref": results.get("style/full_ref"),
-        "style_full_fast": results.get("style/full_fast"),
+        "style_full": results.get("style/full"),
         "espcn_full_ref": results.get("espcn/full_ref"),
         "espcn_full_fast": results.get("espcn/full_fast"),
     }), flush=True)

@@ -148,16 +148,10 @@ COMPARISONS = {
         ("tile40", "gaussian_blur_pallas", {"ksize": 9, "tile_h": 40}),
         ("tile120", "gaussian_blur_pallas", {"ksize": 9, "tile_h": 120}),
     ]),
-    # Exact conv rewrites for the neural configs (VERDICT r4 item 5):
-    # space-to-depth phase decomposition on the lane-starved stem/out 9x9
-    # convs + phase-collapsed subpixel decoder (models.layers.conv2d_s2d /
-    # upsample2_conv; static model in models.analysis projects ~1.8x on
-    # the style MXU floor, 2-3x per ESPCN layer). Winners wire into
-    # MEASURED_DEFAULTS["style_fast"/"espcn_fast"].
-    "style_fast_720p": (720, 1280, 8, [
-        ("ref", "style_transfer", {"fast_convs": False}),
-        ("fast", "style_transfer", {"fast_convs": True}),
-    ]),
+    # Exact space-to-depth conv rewrite for ESPCN (VERDICT r4 item 5;
+    # models.layers.conv2d_s2d; static model in models.analysis projects
+    # 2-3x per layer). The winner wires into MEASURED_DEFAULTS["espcn_fast"].
+    # (The style net's pair is gone with its flag: PERF.md §6, PR 28.)
     "sr_fast_540p": (540, 960, 8, [
         ("ref", "super_resolution", {"fast_convs": False}),
         ("fast", "super_resolution", {"fast_convs": True}),

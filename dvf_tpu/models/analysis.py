@@ -26,7 +26,9 @@ The numbers are a MODEL (peaks from the public v5e datasheet, the same
 constants as dvf_tpu.benchmarks.DEVICE_PEAKS; efficiency factors are
 idealized tiling, not a simulator). The on-chip companion is
 benchmarks/neural_layers.py, which times the same per-layer blocks on
-the real chip; where the two disagree, the measured number wins.
+the real chip; where the two disagree, the measured number wins. For the
+style net it has: PERF.md section 5 holds the measured table (the plain
+composition's 284 ms step against the phase-domain forward's).
 
 Usage: python -m dvf_tpu.models.analysis [--json] [--md-out PATH]
 """
@@ -277,7 +279,11 @@ def main(argv=None) -> int:
           "128x128 systolic tiling (lane = output channels, sublane = "
           "k**2*Cin contraction); HBM times are activation traffic at "
           "the compute dtype. The on-chip companion that measures the "
-          "same blocks is benchmarks/neural_layers.py.\n\n"
+          "same blocks is benchmarks/neural_layers.py. The style net's "
+          "measured table on the chip, op by op with each op's stage "
+          "(the plain composition beside the phase-domain forward that "
+          "is served), is PERF.md section 5; scripts/style_step_probe.py "
+          "re-measures it.\n\n"
           + render_md(style, style_sum) + "\n" + render_md(sr, sr_sum))
     if args.md_out:
         with open(args.md_out, "w") as f:

@@ -110,17 +110,19 @@ def gram_matrix(feats: jnp.ndarray) -> jnp.ndarray:
 # Exact MXU-utilization conv rewrites (see models.analysis for the numbers)
 # ---------------------------------------------------------------------------
 #
-# The style net's structural MXU floor is dominated by full-resolution convs
-# with tiny channel counts: the 9x9 out conv (Cout=3) can use 3/128 of the
-# systolic array's lanes, the stem (Cout=32) 32/128, and the decoder convs
-# run on 4x-upsampled activations at quarter lane use. Two classic, EXACT
-# rearrangements fix the utilization without changing the model's math:
+# Full-resolution convs with tiny channel counts starve the MXU: a 9x9 conv
+# with Cout=3 can use 3/128 of the systolic array's lanes, Cout=32 a quarter,
+# and a decoder conv on a nearest-x2-upsampled activation reads every source
+# pixel four times. Two classic, EXACT rearrangements fix the utilization
+# without changing the model's math:
 #
 # - conv2d_s2d: space-to-depth phase decomposition. A stride-1 kxk conv on
 #   (H, W, Cin) equals a ceil((k+1)/2)-sized conv on the space-to-depth
 #   transform (H/2, W/2, 4*Cin) producing all four output phases (4*Cout
 #   channels), followed by depth_to_space. Same multiply-adds (a few
-#   structurally-zero taps added), 4x the lane-dimension channels.
+#   structurally-zero taps added), 4x the lane-dimension channels. (ESPCN's
+#   opt-in; the style net keeps its tensors in that form instead: "The phase
+#   domain", below.)
 # - upsample2_conv: nearest-x2-upsample followed by a kxk conv collapses to
 #   a per-phase conv at LOW resolution whose taps are the sums of the
 #   original taps that landed on the same source pixel — the upsampled
@@ -214,24 +216,166 @@ def _upsample2_kernel(w: jnp.ndarray) -> jnp.ndarray:
     return kl_w, -e0
 
 
+def upsample2_conv_phase(
+    p: Params,
+    x: jnp.ndarray,
+    compute_dtype=jnp.bfloat16,
+) -> jnp.ndarray:
+    """nearest-×2 upsample + reflect-SAME 3×3 conv (without bias), computed
+    entirely at LOW resolution and LEFT there: returns
+    ``space_to_depth(y, 2)`` of the full-resolution result ``y`` (4·Cout
+    dense channels). Exact for k=3 only: edge padding of the low-res input
+    reproduces reflect-101 of the upsampled input when the pad radius is 1
+    (for r≥2 the reflected full-res rows map to DIFFERENT low-res pixels
+    than edge replication)."""
+    if p["w"].shape[0] != 3:
+        raise ValueError("upsample2_conv_phase is exact for 3x3 kernels only")
+    klw, pad = _upsample2_kernel(p["w"])
+    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="edge")
+    return lax.conv_general_dilated(
+        xp.astype(compute_dtype), klw.astype(compute_dtype),
+        window_strides=(1, 1), padding="VALID", dimension_numbers=_DN,
+    )
+
+
 def upsample2_conv(
     p: Params,
     x: jnp.ndarray,
     compute_dtype=jnp.bfloat16,
 ) -> jnp.ndarray:
-    """nearest-×2 upsample + reflect-SAME conv (without bias), computed
-    entirely at LOW resolution — exact for k=3: edge padding of the
-    low-res input reproduces reflect-101 of the upsampled input when the
-    pad radius is 1 (for r≥2 the reflected full-res rows map to DIFFERENT
-    low-res pixels than edge replication, so larger kernels fall back to
-    the materialized-upsample path)."""
+    """:func:`upsample2_conv_phase` brought back to full resolution;
+    kernels other than 3×3 take the materialized-upsample path."""
     if p["w"].shape[0] != 3:
         return conv2d_nb(p, upsample_nearest(x, 2),
                          compute_dtype=compute_dtype, reflect=True)
-    klw, pad = _upsample2_kernel(p["w"])
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="edge")
-    y2 = lax.conv_general_dilated(
-        xp.astype(compute_dtype), klw.astype(compute_dtype),
-        window_strides=(1, 1), padding="VALID", dimension_numbers=_DN,
+    return depth_to_space(upsample2_conv_phase(p, x, compute_dtype), 2)
+
+
+# ---------------------------------------------------------------------------
+# The phase domain: a full-resolution tensor kept as its space_to_depth
+# ---------------------------------------------------------------------------
+#
+# A (B, H, W, c) activation with c under the 128 lanes is stored and moved
+# 128/c times padded on the TPU. Its space_to_depth(2) image (B, H/2, W/2,
+# 4c) holds the same numbers densely. The functions below let such a tensor
+# stay in that form from the conv that makes it, through instance norm,
+# into the conv that reads it: the conv's taps are re-indexed between
+# phases, the norm's statistics sum over a channel's phases, and the
+# reflect border is built from neighbouring phases.
+
+
+def phase_kernel(w: jnp.ndarray, fi: int, fo: int,
+                 stride: int = 1) -> Tuple[jnp.ndarray, int, int]:
+    """Re-index a (k, k, Cin, Cout) kernel of a reflect-SAME conv with the
+    given stride into the kernel of the same conv between phase tensors:
+    input ``space_to_depth(x, fi)``, output ``space_to_depth(y, fo)``,
+    ``fi == stride·fo``. Output row ``fo·J + β`` reads input row
+    ``fi·J + (stride·β + dy − r)``, i.e. phase ``α`` of low-res row
+    ``J + e`` with ``fi·e + α = stride·β + dy − r``.
+
+    Returns ``(kernel, lo, hi)``: the (kl, kl, fi²·Cin, fo²·Cout) kernel of
+    a VALID stride-1 conv, and the low-res rows/cols of border it needs
+    before and after. One static gather, as :func:`_s2d_kernel`."""
+    if fi != stride * fo:
+        raise ValueError(f"phase factors {fi} -> {fo} do not fit stride {stride}")
+    k = w.shape[0]
+    r = k // 2
+    lo = -((-r) // fi)                                   # -floor(-r / fi)
+    hi = (stride * (fo - 1) + k - 1 - r) // fi
+    kl = lo + hi + 1
+    idy = np.full((kl, fi, fo), k, dtype=np.int32)       # k: the zero tap
+    for e in range(kl):
+        for a in range(fi):
+            for b in range(fo):
+                dy = fi * (e - lo) + a - stride * b + r
+                if 0 <= dy < k:
+                    idy[e, a, b] = dy
+    wpad = jnp.pad(w, ((0, 1), (0, 1), (0, 0), (0, 0)))
+    g = wpad[idy[:, :, :, None, None, None], idy[None, None, None, :, :, :]]
+    # g[e, a, b, e', a', b', ci, co] → (e, e', a, a', ci, b, b', co)
+    g = g.transpose(0, 3, 1, 4, 6, 2, 5, 7)
+    cin, cout = w.shape[2], w.shape[3]
+    return g.reshape(kl, kl, fi * fi * cin, fo * fo * cout), lo, hi
+
+
+def phase_reflect_pad(x: jnp.ndarray, top: int, bottom: int,
+                      left: int, right: int) -> jnp.ndarray:
+    """Reflect-101 border of a full-resolution tensor, built on its
+    ``space_to_depth(·, 2)`` image ``x``: equals ``space_to_depth(jnp.pad(X,
+    2·(top, bottom), 2·(left, right), mode="reflect"), 2)`` without ever
+    forming ``X``. Full-res row ``−t`` is row ``t``: low-res border row
+    ``−m`` holds, in phase 0, phase 0 of row ``m`` and, in phase 1,
+    phase 1 of row ``m − 1`` (mirrored at the far edge)."""
+    c = x.shape[-1] // 4
+    lane = jnp.arange(4 * c)
+
+    def along(x, axis, before, after, second_phase):
+        n = x.shape[axis]
+
+        def border(start0, start1, count):
+            take = lambda s: jnp.flip(
+                lax.slice_in_dim(x, s, s + count, axis=axis), axis)
+            return jnp.where(second_phase, take(start1), take(start0))
+
+        parts = []
+        if before:
+            parts.append(border(1, 0, before))
+        parts.append(x)
+        if after:
+            parts.append(border(n - after, n - after - 1, after))
+        return jnp.concatenate(parts, axis=axis) if len(parts) > 1 else x
+
+    x = along(x, 1, top, bottom, lane // (2 * c) == 1)
+    return along(x, 2, left, right, (lane // c) % 2 == 1)
+
+
+def conv2d_phase(
+    p: Params,
+    x: jnp.ndarray,
+    stride: int = 1,
+    fold: int = 1,
+    compute_dtype=jnp.bfloat16,
+) -> jnp.ndarray:
+    """Reflect-SAME k×k conv (without bias) of the full-resolution tensor
+    whose ``space_to_depth(·, 2)`` image is ``x``, computed between phase
+    tensors. The input factor is ``fi = 2·fold``; the result is
+    ``space_to_depth(y, fi // stride)`` of the conv's output ``y`` (factor
+    1: ``y`` itself). ``fold > 1`` serves a very small Cout, which fills
+    more MXU columns with more output phases: the conv then strides
+    ``fold`` over ``x``'s rows and columns with the factor-``fi`` kernel's
+    rows laid out over them — the deeper space_to_depth is never formed
+    (on a v5e the stride costs nothing, the relayout 7 ms of 16 720p
+    frames; PERF.md §6, PR 28)."""
+    fi = 2 * fold
+    kern, lo, hi = phase_kernel(p["w"], fi, fi // stride, stride)
+    xp = phase_reflect_pad(x, lo * fold, hi * fold, lo * fold, hi * fold)
+    # Input channels (α, α', c), α = 2·a2 + a1 → kernel rows 2-row-of-x
+    # major: (e, a2) over x's rows, (a1, b1, c) over x's channels.
+    kl, _, _, n = kern.shape
+    kern = kern.reshape(kl, kl, fold, 2, fold, 2, -1, n)
+    kern = kern.transpose(0, 2, 1, 4, 3, 5, 6, 7)
+    kern = kern.reshape(fold * kl, fold * kl, -1, n)
+    return lax.conv_general_dilated(
+        xp.astype(compute_dtype), kern.astype(compute_dtype),
+        window_strides=(fold, fold), padding="VALID", dimension_numbers=_DN,
     )
-    return depth_to_space(y2, 2)
+
+
+def instance_norm_phase(p: Params, x: jnp.ndarray,
+                        eps: float = 1e-5) -> jnp.ndarray:
+    """:func:`instance_norm` of the full-resolution tensor whose
+    ``space_to_depth(·, 2)`` image is ``x``: a channel's statistics are the
+    float32 mean over positions AND over its four phases; scale and bias
+    are tiled across the phases."""
+    b, _, _, c4 = x.shape
+
+    def per_channel(stat):            # (B, 4c) → per channel, tiled back
+        m = jnp.mean(stat.reshape(b, 4, c4 // 4), axis=1)
+        return jnp.tile(m, (1, 4))[:, None, None, :]
+
+    xf = x.astype(jnp.float32)
+    d = xf - per_channel(jnp.mean(xf, axis=(1, 2)))
+    var = per_channel(jnp.mean(d * d, axis=(1, 2)))
+    y = d * lax.rsqrt(var + eps)
+    y = y * jnp.tile(p["scale"], 4) + jnp.tile(p["bias"], 4)
+    return y.astype(x.dtype)

@@ -14,7 +14,16 @@ TPU-first choices:
   deliberately avoided (it miscompiles spatial×feature sharded convs on
   this toolchain; see train.style.make_train_step);
 - resize-conv (nearest upsample + conv) instead of transposed conv: fewer
-  artifacts, and the upsample is a free reshape/broadcast on TPU.
+  artifacts;
+- the sub-128-channel stages live in the **phase domain**: a (B, H, W, c)
+  activation with c under the 128 lanes is made, normalized and read as its
+  space_to_depth image (B, H/2, W/2, 4c) — dense on the lanes, never stored
+  4x padded — from ``stem`` into ``down1`` and from ``up2`` into ``out``
+  (whose 3-channel result alone is brought back to the frame), and the
+  decoder's upsample+conv pairs run as low-res convs emitting the phases.
+  Which form a stage takes is a function of its shape
+  (:func:`stage_forms`); every stage carries a ``jax.named_scope`` so a
+  device trace's ``op_name`` says which layer an op is.
 
 The net is exposed as a registered filter (``style_transfer``) whose params
 ride in the filter *state* pytree, so weights live on device across batches
@@ -34,13 +43,18 @@ from jax.sharding import PartitionSpec as P
 from dvf_tpu.models.layers import (
     Params,
     conv2d_nb,
-    conv2d_s2d,
+    conv2d_phase,
     conv_init,
+    depth_to_space,
     instance_norm,
     instance_norm_init,
-    upsample2_conv,
+    instance_norm_phase,
+    space_to_depth,
+    upsample2_conv_phase,
     upsample_nearest,
 )
+
+LANES = 128     # the TPU's lane width: a tensor with fewer channels is stored padded to it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,13 +62,6 @@ class StyleNetConfig:
     base_channels: int = 32          # stem width; doubles at each downsample
     n_residual: int = 5
     compute_dtype: Any = jnp.bfloat16
-    # Exact MXU-utilization conv rewrites (models.layers.conv2d_s2d /
-    # upsample2_conv; numbers in models.analysis): the 9x9 stem/out convs
-    # run space-to-depth at half res with 4x the lane channels, and the
-    # decoder's upsample+conv pairs phase-collapse to low-res convs.
-    # Same arithmetic, parity-tested; opt-in pending the on-chip A/B
-    # (run_table comparison style_fast_720p).
-    fast_convs: bool = False
 
     @property
     def widths(self):
@@ -109,58 +116,117 @@ def _conv_modes(config: StyleNetConfig) -> Dict[str, str]:
     return modes
 
 
+def stage_forms(config: StyleNetConfig, batch_shape) -> Dict[str, str]:
+    """The form each stage of :func:`_forward` runs in for an NHWC batch of
+    this shape — ``"phase"``: on the ``space_to_depth(·, 2)`` image of its
+    full-resolution tensor (models.layers, "The phase domain"), ``"plain"``:
+    on the tensor itself. A function of the shapes alone: the full-
+    resolution stages (stem → down1, up2 → out) hold ``base_channels``
+    channels at H×W and up1's output twice that at H/2×W/2; each takes the
+    phase form when its channels are under the 128 lanes and H, W are even
+    (odd geometry keeps the plain path throughout). down1's and the
+    trunk's convs already emit 128 channels. ``_forward`` branches on this
+    and nothing else. What each form costs on a v5e: PERF.md §5."""
+    _, h, w, _ = batch_shape
+    c1, c2, _ = config.widths
+    even = h % 2 == 0 and w % 2 == 0
+    full = "phase" if even and c1 < LANES else "plain"
+    half = "phase" if even and c2 < LANES else "plain"
+    return {"stem": full, "down1": full, "down2": "plain", "trunk": "plain",
+            "up1": half, "up2": full, "out": full}
+
+
 def _forward(params: Params, batch: jnp.ndarray, config: StyleNetConfig,
              row_reduce, trunk_fn=None) -> jnp.ndarray:
     """Shared forward body for ALL schedules. ``row_reduce`` runs on each
     row-parallel conv's pre-bias output (identity when unsharded,
     psum('model') under TP). ``trunk_fn(params, x)`` replaces the default
     flat residual loop (the PP grouping passes its scan/pipeline here) —
-    one copy of the stem/decoder wiring, however the trunk executes."""
+    one copy of the stem/decoder wiring, however the trunk executes.
+
+    Between ``stem`` and ``down1`` and between ``up2`` and ``out`` the
+    activation is a phase tensor where :func:`stage_forms` says so: it is
+    made, normalized and read as (B, H/2, W/2, 4·c) and never exists at
+    (B, H, W, c)."""
     cd = config.compute_dtype
     modes = _conv_modes(config)
+    phase = {k: v == "phase" for k, v in stage_forms(config, batch.shape).items()}
 
-    def cv(name, x, stride=1, upsampled=False):
-        p = params[name]
-        if upsampled:
-            # Decoder pair: nearest-x2 then conv. The fast path never
-            # materializes the upsampled activation (exact for k=3).
-            if config.fast_convs:
-                y = upsample2_conv(p, x, compute_dtype=cd)
-            else:
-                y = conv2d_nb(p, upsample_nearest(x, 2), compute_dtype=cd,
-                              reflect=True)
-        elif (config.fast_convs and stride == 1
-              and p["w"].shape[0] >= 5):
-            # Full-res large-kernel convs (stem 9x9, out 9x9): the lane-
-            # starved layers where the phase decomposition pays. The 3x3
-            # trunk convs already run full-lane (Cout=128) and would only
-            # inflate taps.
-            y = conv2d_s2d(p, x, compute_dtype=cd, reflect=True)
-        else:
-            y = conv2d_nb(p, x, stride=stride, compute_dtype=cd, reflect=True)
+    def finish(name, y, phases=1):
+        """Row-parallel reduce, then the bias (once per output phase)."""
         if modes.get(name) == "row":
             y = row_reduce(y)
-        return y + p["b"].astype(cd)
+        return y + jnp.tile(params[name]["b"], phases).astype(cd)
 
-    def norm_relu(name, y):
-        return jax.nn.relu(instance_norm(params[name], y))
+    def cv(name, x, stride=1):
+        return finish(name, conv2d_nb(params[name], x, stride=stride,
+                                      compute_dtype=cd, reflect=True))
+
+    def norm_relu(name, y, phased=False):
+        norm = instance_norm_phase if phased else instance_norm
+        return jax.nn.relu(norm(params[name], y))
 
     x = batch.astype(cd)
-    x = norm_relu("stem_norm", cv("stem", x))
-    x = norm_relu("down1_norm", cv("down1", x, stride=2))
-    x = norm_relu("down2_norm", cv("down2", x, stride=2))
-    if trunk_fn is not None:
-        x = trunk_fn(params, x)
-    else:
-        for i in range(config.n_residual):
-            h = norm_relu(f"res{i}_an", cv(f"res{i}_a", x))
-            h = instance_norm(params[f"res{i}_bn"], cv(f"res{i}_b", h))
-            x = x + h
-    x = norm_relu("up1_norm", cv("up1", x, upsampled=True))
-    x = norm_relu("up2_norm", cv("up2", x, upsampled=True))
-    x = cv("out", x)
-    y = 0.5 * (jnp.tanh(x.astype(jnp.float32)) + 1.0)
+    with jax.named_scope("stem"):
+        if phase["stem"]:
+            y = conv2d_phase(params["stem"], space_to_depth(x, 2),
+                             compute_dtype=cd)
+            x = norm_relu("stem_norm", finish("stem", y, 4), phased=True)
+        else:
+            x = norm_relu("stem_norm", cv("stem", x))
+    with jax.named_scope("down1"):
+        if phase["down1"]:
+            # 3x3 stride 2 on a phase tensor: a 2x2 conv, plain output.
+            y = conv2d_phase(params["down1"], x, stride=2, compute_dtype=cd)
+            x = norm_relu("down1_norm", finish("down1", y))
+        else:
+            x = norm_relu("down1_norm", cv("down1", x, stride=2))
+    with jax.named_scope("down2"):
+        x = norm_relu("down2_norm", cv("down2", x, stride=2))
+    with jax.named_scope("trunk"):
+        if trunk_fn is not None:
+            x = trunk_fn(params, x)
+        else:
+            for i in range(config.n_residual):
+                h = norm_relu(f"res{i}_an", cv(f"res{i}_a", x))
+                h = instance_norm(params[f"res{i}_bn"], cv(f"res{i}_b", h))
+                x = x + h
+    with jax.named_scope("up1"):
+        if phase["up1"]:
+            # Made and normalized as phases (dense), then brought to the
+            # half-resolution tensor up2's low-res conv reads.
+            y = upsample2_conv_phase(params["up1"], x, compute_dtype=cd)
+            x = depth_to_space(
+                norm_relu("up1_norm", finish("up1", y, 4), phased=True), 2)
+        else:
+            x = norm_relu("up1_norm", cv("up1", upsample_nearest(x, 2)))
+    with jax.named_scope("up2"):
+        if phase["up2"]:
+            # nearest-x2 + 3x3 as one low-res conv emitting the 4 phases.
+            y = upsample2_conv_phase(params["up2"], x, compute_dtype=cd)
+            x = norm_relu("up2_norm", finish("up2", y, 4), phased=True)
+        else:
+            x = norm_relu("up2_norm", cv("up2", upsample_nearest(x, 2)))
+    with jax.named_scope("out"):
+        if phase["out"]:
+            fold = _out_fold(x.shape)
+            y = finish("out", conv2d_phase(params["out"], x, fold=fold,
+                                           compute_dtype=cd), 4 * fold * fold)
+            y = 0.5 * (jnp.tanh(y.astype(jnp.float32)) + 1.0)
+            y = depth_to_space(y, 2 * fold)
+        else:
+            y = 0.5 * (jnp.tanh(cv("out", x).astype(jnp.float32)) + 1.0)
     return y.astype(batch.dtype)
+
+
+def _out_fold(phase_shape) -> int:
+    """Phase factor of the out conv, as a fold of its factor-2 input: Cout
+    is 3, so at factor 2 the conv fills 12 of the MXU's 128 columns; at
+    factor 4 (fold 2) 48, with 2.8x fewer padded multiply-adds — taken
+    where the phase tensor's own H and W are even (on a v5e: 98 ms a step
+    at fold 1, 84 at fold 2; PERF.md §6, PR 28)."""
+    _, h2, w2, _ = phase_shape
+    return 2 if h2 % 2 == 0 and w2 % 2 == 0 else 1
 
 
 def tp_inner_apply(config: StyleNetConfig) -> Any:

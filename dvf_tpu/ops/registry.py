@@ -153,20 +153,14 @@ MEASURED_DEFAULTS = {
         "fallback": "shift",
         "label_to_impl": {"shift": "shift", "pallas_fused": "pallas"},
     },
-    # Exact MXU-utilization conv rewrites for the neural configs
-    # (models.layers.conv2d_s2d / upsample2_conv; static case in
-    # models.analysis). CPU (full 720p/540p geometry, benchmarks/cpu/):
-    # "ref" wins both — the phase decomposition buys MXU lane
-    # utilization, which AVX has no analog of (style: 0.1 vs 0.1 tie; sr:
-    # 0.9 vs 0.4). TPU stays unpinned (the factories run the reference
-    # lowering) until an on-chip A/B exists (ROADMAP S4).
-    "style_fast": {
-        "comparison": "style_fast_720p",
-        "as_of": {"cpu": '2026-07-31T19:11:01.991899+00:00'},
-        "winners": {"cpu": "ref"},
-        "fallback": "ref",
-        "label_to_impl": {"ref": "ref", "fast": "fast"},
-    },
+    # Exact space-to-depth conv rewrite for ESPCN (models.layers.conv2d_s2d;
+    # static case in models.analysis). CPU (full 540p geometry,
+    # benchmarks/cpu/): "ref" wins (0.9 vs 0.4) — the phase decomposition
+    # buys MXU lane utilization, which AVX has no analog of. TPU stays
+    # unpinned until an on-chip A/B exists (no cell runs sr2x_540p, PERF.md
+    # §7e). The style net's counterpart is no option any more: its stages
+    # take the phase form from their shapes (models.style_transfer.
+    # stage_forms; the on-chip A/B is PERF.md §6, PR 28).
     "espcn_fast": {
         "comparison": "sr_fast_540p",
         "as_of": {"cpu": '2026-07-31T19:13:42.915897+00:00'},

@@ -30,7 +30,7 @@ from dvf_tpu.models.style_transfer import (
     to_pp_params,
     tp_inner_apply,
 )
-from dvf_tpu.ops.registry import measured_default_for, register_filter
+from dvf_tpu.ops.registry import register_filter
 
 
 @register_filter("style_transfer")
@@ -40,18 +40,15 @@ def style_transfer(
     n_residual: int = 5,
     seed: int = 0,
     parallel: str = "tp",
-    fast_convs: Optional[bool] = None,
     dtype: Optional[str] = None,
 ) -> Filter:
     """``params=None`` → seeded random init (demo/benchmark weights);
     pass a trained param pytree for real stylization.
 
-    ``fast_convs=None`` resolves the exact MXU-utilization conv rewrites
-    (models.layers.conv2d_s2d / upsample2_conv) from the measured
-    per-backend winner of the style_fast_720p A/B (MEASURED_DEFAULTS in
-    ops.registry; "ref" until a winner is committed). ``dtype`` pins the
-    model compute dtype ("bfloat16" default — MXU-native — or "float32"
-    for the A/B baseline).
+    ``dtype`` pins the model compute dtype ("bfloat16" default —
+    MXU-native — or "float32"). The form each stage runs in (the
+    full-resolution stages on phase tensors, dense on the 128 lanes) is a
+    function of the batch's shape: ``models.style_transfer.stage_forms``.
 
     ``parallel`` picks the model-axis strategy the ``specialize`` hook
     compiles when the mesh's model axis > 1:
@@ -70,8 +67,6 @@ def style_transfer(
     """
     if parallel not in ("tp", "pp"):
         raise ValueError(f"parallel must be 'tp' or 'pp', got {parallel!r}")
-    if fast_convs is None:
-        fast_convs = measured_default_for("style_fast") == "fast"
     if dtype is None:
         dtype = "bfloat16"
     if dtype not in ("bfloat16", "float32"):
@@ -79,7 +74,7 @@ def style_transfer(
             f"dtype must be 'bfloat16' or 'float32', got {dtype!r}")
     config = StyleNetConfig(
         base_channels=base_channels, n_residual=n_residual,
-        compute_dtype=jnp.dtype(dtype), fast_convs=bool(fast_convs))
+        compute_dtype=jnp.dtype(dtype))
 
     if parallel == "pp":
         _seq_apply = pp_sequential_apply(config)

@@ -1,0 +1,130 @@
+#!/usr/bin/env python3
+"""Where the style net's step goes, op by op, with each op's stage.
+
+ROADMAP S2's op map (PR 28). Compiles the step program of ``style_transfer(base_channels=32, n_residual=5)`` as the
+Engine builds it (uint8 batch in, uint8 batch out, the weights as state) at the cell's shape, times it, traces a few
+steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
+from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
+result shape, then the sum by stage. Run on the chip:
+
+    chiprun -- python scripts/style_step_probe.py            # writes chiprun_out/style_step_probe.json
+
+``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
+nothing.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+def op_table(hlo_text, stages):
+    """{op name: (stage, result type)} from a compiled module's text: each instruction with the first of
+    ``stages`` (the scopes of ``_forward``) in its ``op_name``, ``-`` for what the Engine adds around the net."""
+    table = {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ", line)
+        if not m:
+            continue
+        scope = re.search(r'op_name="([^"]*)"', line)
+        parts = scope.group(1).split("/") if scope else []
+        stage = next((p for p in parts if p in stages), "-")
+        table[m.group(1)] = (stage, m.group(2)[:72])
+    return table
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--toy", action="store_true", help="tiny shape, any backend: checks the script only")
+    ap.add_argument("--batch", type=int, default=16)
+    ap.add_argument("--steps", type=int, default=8, help="timed steps (three more are traced)")
+    ap.add_argument("--top", type=int, default=30, help="ops printed")
+    ap.add_argument("--out", default="chiprun_out/style_step_probe.json")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import reduce
+    from dvf_tpu.models.style_transfer import StyleNetConfig, stage_forms
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu" and not args.toy:
+        print("no accelerator: run through chiprun, or pass --toy", file=sys.stderr)
+        return 3
+    shape = (2, 64, 96, 3) if args.toy else (args.batch, 720, 1280, 3)
+    kwargs = {"base_channels": 8, "n_residual": 2} if args.toy else {"base_channels": 32, "n_residual": 5}
+    filt = get_filter("style_transfer", **kwargs)
+
+    def step(batch, state):            # the body of Engine._build_step
+        y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
+        return to_uint8(y), new_state
+
+    state = filt.init_state(shape, jnp.float32)
+    batch = jnp.asarray(np.random.default_rng(0).integers(0, 256, shape, dtype=np.uint8))
+    t = time.perf_counter()
+    compiled = jax.jit(step).lower(batch, state).compile()
+    compile_s = time.perf_counter() - t
+    forms = stage_forms(StyleNetConfig(**kwargs), shape)
+    table = op_table(compiled.as_text(), forms)
+    mem = compiled.memory_analysis()
+
+    for _ in range(2):
+        jax.block_until_ready(compiled(batch, state))
+    wall = []
+    for _ in range(args.steps):
+        t = time.perf_counter()
+        jax.block_until_ready(compiled(batch, state))
+        wall.append((time.perf_counter() - t) * 1e3)
+
+    traced = 3
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(traced):
+            jax.block_until_ready(compiled(batch, state))
+        jax.profiler.stop_trace()
+        planes = reduce.read_planes(reduce.find_xplane(trace_dir)) if dev.platform != "cpu" else {"devices": {}}
+
+    ops = {}
+    for plane in planes["devices"].values():
+        for name, _, dur in plane["ops"]:
+            key = reduce.short_name(name).lstrip("%")
+            ops[key] = ops.get(key, 0.0) + dur / 1e6 / traced
+        break
+    rows = sorted(((ms, name) + table.get(name, ("?", "")) for name, ms in ops.items()), reverse=True)
+    by_stage = {}
+    for ms, _, stage, _ in rows:
+        by_stage[stage] = by_stage.get(stage, 0.0) + ms
+
+    report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
+              "shape": list(shape), "stage_forms": forms, "compile_s": compile_s,
+              "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
+              "step_wall_ms": {"min": min(wall), "median": sorted(wall)[len(wall) // 2], "max": max(wall)},
+              "traced_ms_a_step": sum(ops.values()), "by_stage_ms": by_stage,
+              "ops": [{"ms": ms, "op": name, "stage": stage, "result": result} for ms, name, stage, result in rows]}
+    print(f"[probe {report['device']}{' toy' if args.toy else ''}] shape {shape}: compile {compile_s:.1f} s, "
+          f"scratch {report['temp_gib']:.2f} GiB, step wall min/median/max "
+          f"{min(wall):.2f}/{report['step_wall_ms']['median']:.2f}/{max(wall):.2f} ms, "
+          f"ops traced {report['traced_ms_a_step']:.2f} ms a step")
+    print(f"[probe] stage_forms {forms}")
+    for ms, name, stage, result in rows[:args.top]:
+        print(f"[probe] {ms:8.3f} ms  {stage:6s} {name:32s} {result}")
+    print("[probe] by stage: " + ", ".join(f"{k} {v:.2f}" for k, v in sorted(by_stage.items(), key=lambda kv: -kv[1])))
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(report, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -116,10 +116,13 @@ def test_style_engine_tp_matches_replicated():
     assert np.abs(got.astype(int) - want.astype(int)).max() <= 3
 
 
-def test_style_engine_tp_with_space_axis_and_odd_batch():
+@pytest.mark.parametrize("dtype,levels", [("bfloat16", 4), ("float32", 1)])
+def test_style_engine_tp_with_space_axis_and_odd_batch(dtype, levels):
     """The TP fold must degrade to whatever the batch divides: B=2 on a
     (data=1, space=4, model=2) mesh can't fold over data*space=4 — it must
-    still compile (batch replicated over the fold) and match."""
+    still compile (batch replicated over the fold) and match: to the uint8
+    rounding in float32, to bfloat16's rounding of the row convs' partial
+    sums before the psum otherwise."""
     import numpy as np
 
     from dvf_tpu.ops import get_filter
@@ -127,14 +130,14 @@ def test_style_engine_tp_with_space_axis_and_odd_batch():
 
     x = np.random.default_rng(1).integers(0, 255, (2, 32, 32, 3), np.uint8)
     mesh = make_mesh(MeshConfig(data=1, space=4, model=2))
-    eng = Engine(get_filter("style_transfer", base_channels=8, n_residual=2),
-                 mesh=mesh)
+    kwargs = dict(base_channels=8, n_residual=2, dtype=dtype)
+    eng = Engine(get_filter("style_transfer", **kwargs), mesh=mesh)
     got = np.asarray(eng.submit(x))
 
-    ref = Engine(get_filter("style_transfer", base_channels=8, n_residual=2),
+    ref = Engine(get_filter("style_transfer", **kwargs),
                  mesh=make_mesh(MeshConfig()))
     want = np.asarray(ref.submit(x))
-    assert np.abs(got.astype(int) - want.astype(int)).max() <= 3
+    assert np.abs(got.astype(int) - want.astype(int)).max() <= levels
 
 
 def test_upsample_nearest():
@@ -322,26 +325,185 @@ def test_fast_conv_rewrites_match_reference_lowering():
     assert float(jnp.abs(a - b).max()) == 0.0
 
 
-def test_style_net_fast_convs_parity():
-    """The whole style net with fast_convs on matches the reference
-    lowering (f32 pins the comparison to the rewrite, not rounding)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+def _plain_style_forward(params, x, config):
+    """The style net as the plain composition of the reference layers
+    (conv2d_nb + instance_norm + upsample_nearest at full resolution):
+    what every stage of ``_forward`` computes, whatever form it runs in."""
+    from dvf_tpu.models.layers import conv2d_nb, instance_norm
 
-    from dvf_tpu.models.style_transfer import (
-        StyleNetConfig, apply_style_net, init_style_net)
+    cd = config.compute_dtype
 
-    ref_cfg = StyleNetConfig(base_channels=8, n_residual=2,
-                             compute_dtype=jnp.float32)
-    fast_cfg = StyleNetConfig(base_channels=8, n_residual=2,
-                              compute_dtype=jnp.float32, fast_convs=True)
-    params = init_style_net(jax.random.PRNGKey(0), ref_cfg)
-    x = jnp.asarray(np.random.RandomState(1).rand(2, 24, 32, 3)
+    def cv(name, x, stride=1):
+        p = params[name]
+        return conv2d_nb(p, x, stride=stride, compute_dtype=cd,
+                         reflect=True) + p["b"].astype(cd)
+
+    def nr(name, y):
+        return jax.nn.relu(instance_norm(params[name], y))
+
+    x = nr("stem_norm", cv("stem", x.astype(cd)))
+    x = nr("down1_norm", cv("down1", x, 2))
+    x = nr("down2_norm", cv("down2", x, 2))
+    for i in range(config.n_residual):
+        h = nr(f"res{i}_an", cv(f"res{i}_a", x))
+        x = x + instance_norm(params[f"res{i}_bn"], cv(f"res{i}_b", h))
+    x = nr("up1_norm", cv("up1", upsample_nearest(x, 2)))
+    x = nr("up2_norm", cv("up2", upsample_nearest(x, 2)))
+    return 0.5 * (jnp.tanh(cv("out", x).astype(jnp.float32)) + 1.0)
+
+
+def _random_style_params(config, seed=0):
+    """init_style_net with the zero biases and unit norm scales perturbed,
+    so a dropped or mis-tiled bias/scale shows."""
+    params = init_style_net(jax.random.PRNGKey(seed), config)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    return jax.tree.unflatten(tree, [
+        a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)])
+
+
+F32_SMALL = StyleNetConfig(base_channels=8, n_residual=2,
+                           compute_dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("hw,form", [
+    ((64, 96), "phase"),     # out conv at phase factor 4
+    ((66, 98), "phase"),     # even, not a multiple of 4: out at factor 2
+    ((65, 97), "plain"),     # odd geometry keeps the plain path
+])
+def test_style_net_phase_forward_matches_plain_composition(hw, form):
+    """The forward with its full-resolution stages in the phase domain is
+    the plain composition of reference layers (f32 pins the comparison to
+    the re-indexing, not rounding), at each geometry class."""
+    from dvf_tpu.models.style_transfer import stage_forms
+
+    params = _random_style_params(F32_SMALL)
+    x = jnp.asarray(np.random.RandomState(1).rand(2, *hw, 3)
                     .astype(np.float32))
-    a = apply_style_net(params, x, ref_cfg)
-    b = apply_style_net(params, x, fast_cfg)
-    assert float(jnp.abs(a - b).max()) < 1e-4
+    forms = stage_forms(F32_SMALL, x.shape)
+    assert {forms[k] for k in ("stem", "down1", "up2", "out")} == {form}
+    want = _plain_style_forward(params, x, F32_SMALL)
+    got = apply_style_net(params, x, F32_SMALL)
+    assert got.shape == want.shape
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("k,cin,cout,stride,fold", [
+    (9, 3, 8, 1, 1),      # stem: 5x5 over 12 input phases
+    (3, 8, 16, 2, 1),     # down1: stride 2 on a phase tensor = 2x2, plain out
+    (9, 8, 3, 1, 1),      # out at phase factor 2
+    (9, 8, 3, 1, 2),      # out at phase factor 4 (nested input phases)
+    (5, 4, 6, 1, 2),
+])
+def test_conv2d_phase_matches_reference_conv(k, cin, cout, stride, fold):
+    from dvf_tpu.models.layers import (
+        conv2d_nb, conv2d_phase, depth_to_space, space_to_depth)
+
+    rng = np.random.RandomState(0)
+    p = {"w": jnp.asarray(rng.randn(k, k, cin, cout).astype(np.float32))}
+    x = jnp.asarray(rng.rand(2, 16, 24, cin).astype(np.float32))
+    want = conv2d_nb(p, x, stride=stride, compute_dtype=jnp.float32,
+                     reflect=True)
+    got = conv2d_phase(p, space_to_depth(x, 2), stride=stride, fold=fold,
+                       compute_dtype=jnp.float32)
+    fo = 2 * fold // stride
+    if fo > 1:
+        assert got.shape[-1] == fo * fo * cout
+        got = depth_to_space(got, fo)
+    assert float(jnp.abs(got - want).max()) < 1e-4
+
+
+@pytest.mark.parametrize("r", [1, 4])
+def test_phase_reflect_pad_matches_full_resolution_reflect(r):
+    """The reflect border built from neighbouring phases IS
+    jnp.pad(mode="reflect") of the full-resolution tensor — for down1's
+    radius (one low-res row, top/left only) and out's (two, all round)."""
+    from dvf_tpu.models.layers import phase_reflect_pad, space_to_depth
+
+    x = jnp.asarray(np.random.RandomState(0).rand(2, 12, 16, 5)
+                    .astype(np.float32))
+    lo = (r + 1) // 2                    # low-res rows a radius-r border needs
+    hi = 0 if r == 1 else lo
+    got = phase_reflect_pad(space_to_depth(x, 2), lo, hi, lo, hi)
+    want = space_to_depth(jnp.pad(
+        x, ((0, 0), (2 * lo, 2 * hi), (2 * lo, 2 * hi), (0, 0)),
+        mode="reflect"), 2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_instance_norm_phase_matches_full_resolution_norm():
+    from dvf_tpu.models.layers import (
+        depth_to_space, instance_norm, instance_norm_phase, space_to_depth)
+
+    rng = np.random.RandomState(0)
+    p = {"scale": jnp.asarray(rng.rand(6).astype(np.float32)),
+         "bias": jnp.asarray(rng.rand(6).astype(np.float32))}
+    x = jnp.asarray((3.0 * rng.randn(2, 8, 12, 6) + 1.0).astype(np.float32))
+    want = instance_norm(p, x)
+    got = depth_to_space(instance_norm_phase(p, space_to_depth(x, 2)), 2)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+def test_style_net_gradient_step_through_phase_forward():
+    """One gradient step through the phase-domain forward moves the
+    params as the plain composition's gradient does (train/style.py
+    differentiates the same ``_forward``)."""
+    params = _random_style_params(F32_SMALL)
+    x = jnp.asarray(np.random.RandomState(2).rand(1, 32, 48, 3)
+                    .astype(np.float32))
+    target = jnp.asarray(np.random.RandomState(3).rand(1, 32, 48, 3)
+                         .astype(np.float32))
+
+    def loss(fwd):
+        return lambda p: jnp.mean((fwd(p, x, F32_SMALL) - target) ** 2)
+
+    g_phase = jax.grad(loss(apply_style_net))(params)
+    g_plain = jax.grad(loss(_plain_style_forward))(params)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_phase),
+                            jax.tree.leaves(g_plain)):
+        scale = float(jnp.abs(b).max()) + 1e-8
+        assert float(jnp.abs(a - b).max()) < 1e-3 * scale + 1e-7, (
+            jax.tree_util.keystr(path))
+    stepped = jax.tree.map(lambda p, g: p - 0.1 * g, params, g_phase)
+    assert float(loss(apply_style_net)(stepped)) < float(
+        loss(apply_style_net)(params))
+
+
+def test_style_net_720p_jaxpr_holds_no_lane_starved_tensor():
+    """Structure at the cell's shape, no compile: between stem and down1
+    and between up2 and out the activation exists only as a 128-channel
+    phase tensor — no (16, 720, 1280, c) intermediate with 3 < c < 128 —
+    and no materialised nearest-x2 broadcast ahead of up2; stage_forms
+    says so, and says plain for an odd geometry."""
+    from dvf_tpu.models.style_transfer import stage_forms
+
+    cfg = StyleNetConfig()
+    shape = (16, 720, 1280, 3)
+    forms = stage_forms(cfg, shape)
+    assert [forms[k] for k in ("stem", "down1", "up1", "up2", "out")] == ["phase"] * 5
+    assert forms["down2"] == forms["trunk"] == "plain"
+    odd = stage_forms(cfg, (16, 721, 1280, 3))
+    assert set(odd.values()) == {"plain"}
+
+    params = jax.eval_shape(lambda: init_style_net(jax.random.PRNGKey(0), cfg))
+    jaxpr = jax.make_jaxpr(lambda p, x: apply_style_net(p, x, cfg))(
+        params, jax.ShapeDtypeStruct(shape, jnp.float32))
+
+    shapes = set()
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            shapes.update(tuple(v.aval.shape) for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    starved = [s for s in shapes
+               if len(s) == 4 and s[:3] == (16, 720, 1280) and 3 < s[3] < 128]
+    assert not starved, starved
+    assert (16, 360, 2, 640, 2, 64) not in shapes
+    assert (16, 180, 2, 320, 2, 128) not in shapes       # nor ahead of up1
+    assert (16, 360, 640, 128) in shapes         # the phase tensors themselves
 
 
 def test_espcn_fast_convs_parity():
@@ -361,52 +523,62 @@ def test_espcn_fast_convs_parity():
     assert float(jnp.abs(a - b).max()) < 1e-4
 
 
-def test_neural_filter_factory_knobs():
-    """fast_convs / dtype knobs resolve through the factories and the
-    measured-defaults table (no committed winner yet -> 'ref' lowering)."""
-    import pytest
-
+@pytest.mark.parametrize("name", ["style_transfer", "super_resolution"])
+def test_neural_filter_factory_knobs(name):
+    """The dtype knob resolves through the factories; ESPCN's fast_convs
+    resolves through the measured-defaults table (no committed winner yet
+    -> 'ref' lowering), and the style net has no such knob: the form of
+    each stage is a function of its shape (stage_forms)."""
     from dvf_tpu.ops import get_filter
 
-    for name in ("style_transfer", "super_resolution"):
-        f = get_filter(name)                      # defaults: ref + bf16
-        f_fast = get_filter(name, fast_convs=True)
-        f_f32 = get_filter(name, dtype="float32")
-        assert f.name and f_fast.name and f_f32.name
-        with pytest.raises(ValueError, match="dtype"):
-            get_filter(name, dtype="float16")
+    f = get_filter(name)                      # defaults: bf16
+    f_f32 = get_filter(name, dtype="float32")
+    assert f.name and f_f32.name
+    with pytest.raises(ValueError, match="dtype"):
+        get_filter(name, dtype="float16")
+    if name == "style_transfer":
+        with pytest.raises(TypeError, match="fast_convs"):
+            get_filter(name, fast_convs=True)
+    else:
+        assert get_filter(name, fast_convs=True).name
 
 
-def test_tp_shard_map_forward_with_fast_convs():
-    """The fast-conv rewrites must compose with Megatron TP: conv2d_s2d
-    regroups Cin/Cout into phase blocks PER SHARD (the gather is over the
-    shard's own slice) and upsample2_conv's tap collapse is linear in the
-    kernel, so the explicit-psum shard_map forward must match the
-    replicated fast forward AND the replicated reference forward."""
-    import dataclasses
+@pytest.mark.parametrize("schedule", ["tp", "pp"])
+def test_shard_map_forward_with_phase_stages(schedule):
+    """The phase-domain stages must compose with the model-axis
+    schedules. TP: a column conv's Cout shard is sliced INSIDE each phase
+    group (the kernel gather is over the shard's own slice), the norm's
+    phase statistics are per local channel, and a row conv's psum runs on
+    the phase tensor before the (tiled) bias. PP: stem/decoder run
+    replicated around the pipelined trunk. Either explicit shard_map
+    forward must match the replicated forward AND the plain composition."""
+    from dvf_tpu.models.style_transfer import (
+        pp_inner_apply, pp_param_pspecs, stage_forms, to_pp_params,
+        tp_inner_apply)
 
-    from dvf_tpu.models.style_transfer import tp_inner_apply
-
-    fast = dataclasses.replace(SMALL, fast_convs=True)
-    params = init_style_net(jax.random.PRNGKey(0), SMALL)
+    cfg = F32_SMALL       # f32: the comparison is the wiring, not rounding
+    params = _random_style_params(cfg)
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    want_ref = apply_style_net(params, x, SMALL)
-    want_fast = apply_style_net(params, x, fast)
+    assert stage_forms(cfg, x.shape)["out"] == "phase"
+    want_plain = _plain_style_forward(params, x, cfg)
+    want = apply_style_net(params, x, cfg)
 
     mesh = make_mesh(MeshConfig(model=2))
-    specs = param_pspecs(SMALL)
-    inner = tp_inner_apply(fast)
+    if schedule == "tp":
+        specs, inner, placed = param_pspecs(cfg), tp_inner_apply(cfg), params
+    else:
+        specs, inner = pp_param_pspecs(cfg), pp_inner_apply(cfg)
+        placed = to_pp_params(params, cfg)
     got = jax.jit(jax.shard_map(
         lambda p, b: inner(p, b),
         mesh=mesh,
         in_specs=(specs, P()),
         out_specs=P(),
         check_vma=False,
-    ))(params, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want_fast),
-                               atol=2e-2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want_ref),
-                               atol=2e-2)
+    ))(placed, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_plain),
+                               atol=1e-4)
 
 
 def test_espcn_tp_shard_map_forward_with_fast_convs():
