@@ -1,14 +1,9 @@
 """Streamed shard-level egress + asynchronous codec plane.
 
-The symmetric twin of :mod:`dvf_tpu.runtime.ingest`, on the D2H side.
-PR 3 streamed the ingest half (decode → per-shard H2D overlapped with
-compute), but every delivery path still blocked on a whole-batch
-``np.asarray(result)`` — one serializing fetch that allocates a fresh
-host batch — and only then encoded, serially. The measured head-to-head
-pins the cost: same-codec throughput is 1.27× the reference while
-raw-wire is 8.3× (benchmarks/REFERENCE_HEADTOHEAD.json) — the pipeline
-is egress/codec-bound. This module closes that gap with the same
-operation-overlap discipline, applied at delivery:
+The symmetric twin of :mod:`dvf_tpu.runtime.ingest`, on the D2H side: a
+whole-batch ``np.asarray(result)`` is one serializing fetch that
+allocates a fresh host batch, and an encode after it runs serially. The
+same operation-overlap discipline, applied at delivery:
 
 - :class:`ShardedBatchFetcher` — per-output-shard ``copy_to_host_async``
   issued the moment the batch is submitted (so D2H runs under the tail
@@ -23,6 +18,10 @@ operation-overlap discipline, applied at delivery:
   (``encode_batch_async`` futures): the delivery loop submits a batch's
   rows and returns to decoding/computing the NEXT batch while the pool
   encodes; completed batches drain in submission order.
+
+Who builds a fetcher, with which mode and how many slots, when it is
+rebuilt and when its slabs go is :class:`dvf_tpu.runtime.lane.DeviceLane`'s
+to decide; no caller constructs one.
 
 Timeline, monolithic vs streamed (worker-style decode→compute→encode):
 
@@ -45,9 +44,9 @@ whole words (``W·C % 4 == 0``) held as one shard on one device:
   ``uint8[B,H,W,C]`` → ``uint32[B,H,W·C/4]``, the same bytes in
   row-major order (3–5 ms of device time for 199 MB, 1.1 for 44 MB);
 - ``prefetch(result)`` runs it, starts the words' ``copy_to_host_async``
-  and returns a :class:`PackedBatch`, which rides the in-flight queue in
-  the result's place (the device frees the result once the pack has
-  read it);
+  and returns a :class:`PackedBatch`, which the lane's in-flight handle
+  carries in the result's place (the device frees the result once the
+  pack has read it);
 - ``fetch(packed, slot)`` waits for that transfer and returns the buffer
   it landed in, viewed as ``uint8[B,H,W,C]``: read-only, one pass over
   the bytes (the runtime's), no slab, no copy (``copy_ms`` is 0), no
@@ -61,11 +60,21 @@ several devices, another dtype or rank, rows that are no whole words, a
 batch of another geometry, monolithic mode and a released fetcher keep
 the paths below byte for byte.
 
+Which path runs where (ledger, PRs 26–28): all four benchmark cells
+(``invert_1080p.bulk``, ``style_720p.bulk`` / ``.live``,
+``flow_720p.bulk``) serve one chip and run the packed layout
+(``packed_batches == batches``); it took invert from 72.4 to 346.0
+frames/s and tied in the style and flow cells. The per-shard slab path
+is what a result sharded over several devices gets; its copy is the
+transposing gather above, and no cell runs it yet (the four-chip cell,
+PERF.md §7a, decides: pack per shard there, or delete: ROADMAP D3b).
+Monolithic is the CPU backend's path, the degrade target and the tests'
+reference.
+
 Fallbacks mirror the ingest assembler, recorded in the stats either way:
 
-- ``mode="monolithic"`` (the ``--egress monolithic`` escape hatch) and
-  results that are not shard-addressable keep the classic
-  ``np.asarray`` fetch — byte-for-byte the pre-streaming behavior;
+- ``mode="monolithic"`` and results that are not shard-addressable keep
+  the classic ``np.asarray`` fetch;
 - a CPU-backend result's ``np.asarray`` is already a zero-copy view of
   the runtime buffer, so any slab copy is pure added work
   (``fallback_reason="zero_copy_backend"``; tests monkeypatch
@@ -74,7 +83,7 @@ Fallbacks mirror the ingest assembler, recorded in the stats either way:
   streaming overhead stays monolithic (``"cheap_transfer"``, the mirror
   of ingest's ``MIN_STREAM_H2D_MS`` guard);
 - repeated d2h faults degrade streamed → monolithic through the error
-  budget (``"d2h_fault_budget"``, wired in pipeline/serve/worker).
+  budget (``"d2h_fault_budget"``: ``DeviceLane.degrade``).
 
 Slot discipline is the staging-pool contract unchanged: the caller
 provides a monotonically increasing slot id per batch and guarantees
@@ -203,10 +212,8 @@ def _compiled_pack(out_shape: Tuple[int, ...], device):
 class PackedBatch:
     """One batch in the packed transfer layout, in flight: the pack
     program's words and the shape they unpack to. What ``prefetch``
-    returns and the in-flight queue carries in place of the result; it
-    answers the two calls the collect paths make on a result before the
-    fetch, and any fetcher unpacks it, whatever became of the one that
-    packed it (released, degraded, rebuilt at another geometry)."""
+    returns in the result's place on that layout; any fetcher unpacks
+    it, whatever became of the one that packed it (released, degraded)."""
 
     __slots__ = ("words", "out_shape")
 
@@ -214,27 +221,14 @@ class PackedBatch:
         self.words = words
         self.out_shape = out_shape
 
-    def block_until_ready(self) -> "PackedBatch":
-        self.words.block_until_ready()
-        return self
 
-    def is_ready(self) -> bool:
-        return self.words.is_ready()
-
-    def __array__(self, dtype=None, copy=None) -> np.ndarray:
-        """The frames: the buffer the transfer landed in, viewed as
-        ``uint8[out_shape]`` (read-only; no pass over the bytes). So a
-        collect path's ``np.asarray(result)`` fallback holds for a
-        packed batch as for a result."""
-        out = np.asarray(self.words).view(np.uint8).reshape(self.out_shape)
-        return out if dtype is None else out.astype(dtype)
-
-
-def transfer_layout_of(handle: Any) -> str:
-    """Which layout an in-flight batch (what ``prefetch`` returned)
-    crosses to the host in — the ``layout=`` of its trace spans."""
-    return (TRANSFER_PACKED if isinstance(handle, PackedBatch)
-            else TRANSFER_PLAIN)
+def device_side(payload: Any) -> Tuple[Any, str]:
+    """(the device array whose readiness is the batch's, its transfer
+    layout) for what ``prefetch`` returned: what the lane's in-flight
+    handle waits on, and the ``layout=`` of the collect span."""
+    if isinstance(payload, PackedBatch):
+        return payload.words, TRANSFER_PACKED
+    return payload, TRANSFER_PLAIN
 
 
 def live_fetchers() -> List["ShardedBatchFetcher"]:
@@ -460,14 +454,16 @@ class ShardedBatchFetcher:
         One pass over the bytes, the runtime's; no slab, no copy
         (``copy_ms`` is 0 by construction). The view is read-only and
         lives as long as a row of it is referenced."""
-        packed.block_until_ready()  # the step's and the pack's device
-        #   time are not D2H (see fetch)
-        if self.chaos is not None and self.effective_mode == "streamed":
-            self.chaos.fire("d2h")  # one shard, one firing; a fetcher
-            #   degraded to monolithic has left the site, as its own
-            #   fetch has, also for a batch packed before the degrade
+        packed.words.block_until_ready()  # the step's and the pack's
+        #   device time are not D2H (see fetch)
+        if self.chaos is not None and self._pack is not None:
+            self.chaos.fire("d2h")  # one shard, one firing; a released
+            #   fetcher (degraded to monolithic, torn down) has left the
+            #   site, as its slab fetch has, also for a batch packed
+            #   before that
         t0 = time.perf_counter()
-        out = np.asarray(packed)
+        out = np.asarray(packed.words).view(np.uint8).reshape(
+            packed.out_shape)
         t1 = time.perf_counter()
         tracer = self.tracer
         if tracer is not None and tracer.enabled:

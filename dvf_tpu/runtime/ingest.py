@@ -1,16 +1,13 @@
 """Streamed shard-level batch ingest: overlap decode, H2D, and compute.
 
-The BENCH_r05 stage decomposition showed the hot path is ingest-bound,
-not compute-bound: the pipeline assembled the ENTIRE batch on the host
-(decode-all → stage-all) and then shipped it as one monolithic,
-serializing ``device_put`` before compute could start — host staging up
-to 3.5 ms/batch and H2D 3.7–7.0 ms against 0.6–1.2 ms of per-frame
-compute, with the link at 13% of its roofline. This module closes that
-gap with the classic decoupled access-execute / latency-hiding move
-(TVM, arXiv:1802.04799): frames decode directly into *per-device-shard*
-staging slabs, and each shard is ``device_put`` the moment its rows fill,
-so the H2D of shard *i* overlaps the decode of shard *i+1* and the device
-compute of batch *k−1*. The finished batch is assembled with
+Assembling the ENTIRE batch on the host (decode-all → stage-all) and
+then shipping it as one monolithic, serializing ``device_put`` keeps the
+device waiting for the whole transfer. This module applies the classic
+decoupled access-execute / latency-hiding move (TVM, arXiv:1802.04799):
+frames decode directly into *per-device-shard* staging slabs, and each
+shard is ``device_put`` the moment its rows fill, so the H2D of shard
+*i* overlaps the decode of shard *i+1* and the device compute of batch
+*k−1*. The finished batch is assembled with
 ``jax.make_array_from_single_device_arrays`` and handed to
 ``Engine.submit_resident`` — the engine's internal ``device_put`` is
 skipped entirely.
@@ -21,6 +18,16 @@ Timeline, monolithic vs streamed (one batch of 4 shards):
     streamed     decode ███░███░███░███░
                  H2D       ████ ████ ████ ████          (per shard,
                  compute ░░░░ batch k−1 ░░░░░░░         overlapped)
+
+Which path runs where (ledger, PRs 26–28): all four benchmark cells
+serve one chip streamed, ``ingest_depth`` row-chunks a batch. What the
+chip showed of it: ``assemble_h2d`` is 82.6 ms a batch of 32 1080p
+frames and paces ``invert_1080p.bulk`` (PERF.md §5), 49 ms of it exposed
+staging and transfer wait: ``uint8[B,H,W,3]`` is de-interleaved on the
+host inside ``device_put``. The H2D mirror of egress's packed transfer
+layout would live here and in :mod:`dvf_tpu.runtime.lane`, which builds
+the assembler (mode, depth, slots, when it is rebuilt, what a repeated
+fault degrades to); no caller constructs one.
 
 Shard granularity follows the engine's input sharding:
 
@@ -67,12 +74,10 @@ INGEST_MODES = ("streamed", "monolithic")
 # Below this calibrated blocking-put cost (Engine.h2d_block_ms, measured
 # at compile), the fixed per-batch streaming overhead — shard-put
 # dispatches, the on-device chunk concat, mesh-array assembly — exceeds
-# anything overlap can hide, so the assembler stays monolithic (measured
-# on the CPU backend: 128×128×8 streams at 480 fps vs 2507 monolithic
-# because the whole blocking put costs ~0.1 ms). The threshold has no
-# on-chip derivation yet (ROADMAP S3); chip_smoke.py prints the mode each
-# config's bucket actually took. Tests that exercise the streaming
-# machinery at tiny sizes monkeypatch this to 0.
+# anything overlap can hide, so the assembler stays monolithic. The
+# threshold has no on-chip derivation yet (ROADMAP S3); chip_smoke.py
+# prints the mode each config's bucket actually took. Tests that
+# exercise the streaming machinery at tiny sizes monkeypatch this to 0.
 MIN_STREAM_H2D_MS = 2.0
 
 # Host-slab accounting registry (obs.memory): every live assembler is
@@ -129,10 +134,9 @@ class ShardedBatchAssembler:
     """Stages batches into per-shard slabs and streams them to devices.
 
     One assembler per (batch signature, sharding); ``begin(slot)`` yields
-    a :class:`BatchBuilder` for one batch. ``mode="monolithic"`` is the
-    escape hatch (``--ingest=monolithic``): one whole-batch host buffer
-    per slot, handed back for the engine's classic ``submit`` path —
-    byte-for-byte the pre-streaming behavior.
+    a :class:`BatchBuilder` for one batch. ``mode="monolithic"``: one
+    whole-batch host buffer per slot, handed back for the engine's
+    classic ``submit`` path.
     """
 
     def __init__(

@@ -32,27 +32,23 @@ import time
 from collections import deque
 from typing import Any, Optional
 
-import numpy as np
-
 from dvf_tpu.api.filter import Filter
 from dvf_tpu.obs.export import attach_signal_provider
-from dvf_tpu.obs.metrics import EgressStats, IngestStats, LatencyStats, RateLogger
+from dvf_tpu.obs.metrics import LatencyStats, RateLogger
 from dvf_tpu.obs.registry import MetricsRegistry
 from dvf_tpu.obs.trace import Tracer
 from dvf_tpu.resilience.budget import ErrorBudget, escalate
 from dvf_tpu.resilience.faults import FaultError, FaultKind, FaultStats, classify
 from dvf_tpu.resilience.supervisor import Supervisor
-from dvf_tpu.runtime.egress import EGRESS_MODES, ShardedBatchFetcher
 from dvf_tpu.runtime.engine import Engine
-from dvf_tpu.runtime.ingest import INGEST_MODES, ShardedBatchAssembler
+from dvf_tpu.runtime.lane import DeviceLane
 from dvf_tpu.sched.queues import DropOldestQueue
 from dvf_tpu.sched.reorder import ReorderBuffer
 
 # Trace track ids (the reference maps worker pids to tracks,
-# distributor.py:129; our executors are stages, not processes).
-# TRACK_H2D is the streamed-ingest transfer lane (per-shard h2d spans);
-# TRACK_D2H is the streamed-egress mirror (per-shard egress_d2h spans).
-TRACK_INGEST, TRACK_DEVICE, TRACK_SINK, TRACK_H2D, TRACK_D2H = 0, 1, 2, 3, 4
+# distributor.py:129; our executors are stages, not processes). The
+# per-shard transfer spans land on the lane's own tracks (runtime/lane.py).
+TRACK_INGEST, TRACK_DEVICE, TRACK_SINK = 0, 1, 2
 
 
 @dataclasses.dataclass
@@ -140,18 +136,17 @@ class Pipeline:
             raise ValueError(
                 f"collect_mode must be 'thread' or 'inline', got "
                 f"{self.config.collect_mode!r}")
-        if self.config.ingest not in INGEST_MODES:
-            raise ValueError(
-                f"ingest must be one of {INGEST_MODES}, got "
-                f"{self.config.ingest!r}")
-        if self.config.egress not in EGRESS_MODES:
-            raise ValueError(
-                f"egress must be one of {EGRESS_MODES}, got "
-                f"{self.config.egress!r}")
         self.engine = engine or Engine(filt, chaos=self.config.chaos)
         if self.config.chaos is not None and self.engine.chaos is None:
             self.engine.chaos = self.config.chaos  # arm a caller-built engine
         self.tracer = Tracer(enabled=self.config.trace)
+        # The batches' way onto and off the chip (runtime/lane.py). The
+        # semaphore guarantees at most max_inflight batches outstanding,
+        # so a staging or delivery slot being rewritten belongs to a
+        # batch that has already been collected.
+        self._lane = DeviceLane(
+            self.engine, self.config, self.config.max_inflight,
+            tracer=self.tracer, chaos=self.config.chaos, name="pipeline")
         # Injectable ingest queue: default is the Python drop-oldest queue;
         # `--transport ring` passes a transport.ring_queue.RingFrameQueue,
         # putting the native C++ ring on the hot path (frames then cross
@@ -178,17 +173,9 @@ class Pipeline:
         # shed-rebuild at 0 fps forever.
         self._stalls_since_progress = 0
         self._stall_fail_after = max(2, self.config.fault_budget // 4)
-        self._ingest_mode = self.config.ingest  # may degrade to monolithic
-        #   after repeated h2d faults (budget escalation)
-        self._degrade_reason: Optional[str] = None
-        self._egress_mode = self.config.egress  # the d2h mirror of the
-        #   above: repeated d2h faults degrade streamed → monolithic fetch
-        self._egress_degrade_reason: Optional[str] = None
-        self._fetcher: Optional[ShardedBatchFetcher] = None
-        self._egress_stats: Optional[EgressStats] = None
         self._supervisor: Optional[Supervisor] = None
         self._recovering = threading.Event()  # dispatch parks while the
-        #   supervisor swaps the engine/assembler (see _on_stall)
+        #   supervisor swaps the engine under the lane (see _on_stall)
         # Metrics registry (obs.registry): the scrape endpoint's source
         # for this pipeline. The RateLoggers land their computed rates as
         # the rate_fps gauge ON THE SAME TICKS they print, so the every-5s
@@ -212,8 +199,6 @@ class Pipeline:
         self._deliver_rate = RateLogger("deliver", _ti if _ti > 0 else 5.0,
                                         quiet=_ti <= 0,
                                         registry=self.registry)
-        self._assembler: Optional[ShardedBatchAssembler] = None
-        self._ingest_stats: Optional[IngestStats] = None
         self._on_idle = None  # inline collect: drain-ready hook (_assemble)
         self._inflight: "DropOldestQueue" = DropOldestQueue(maxsize=1_000_000)
         self._inflight_sem = threading.Semaphore(self.config.max_inflight)
@@ -302,9 +287,10 @@ class Pipeline:
         """Resilient mode: drop, count, continue (the reference's
         per-iteration ``except: continue``, distributor.py:249-251,287-289)
         — but classified (resilience.faults) and bounded by the per-kind
-        error budget: the first overflow degrades (streamed→monolithic
-        ingest for h2d faults), the second fails hard, so a permanently
-        broken stage surfaces instead of shedding frames forever.
+        error budget: the first overflow degrades (a transfer fault puts
+        its side of the lane on the monolithic path), the second fails
+        hard, so a permanently broken stage surfaces instead of shedding
+        frames forever.
         Fail-fast mode: abort the pipeline. Returns True to continue."""
         kind = classify(e, site=where)
         self.faults.record(kind, e)
@@ -312,7 +298,8 @@ class Pipeline:
             self._fail(e)
             return False
         self.errors += 1
-        if escalate(self._budget, kind, self._degrade) == ErrorBudget.CONTAIN:
+        if escalate(self._budget, kind,
+                    self._lane.degrade) == ErrorBudget.CONTAIN:
             # stderr: stdout is a data channel (one-JSON-line contract in
             # the bench stack and CLI).
             print(f"[pipeline:{where}] {kind} fault (continuing): {e!r}",
@@ -324,32 +311,6 @@ class Pipeline:
             f"(> {self.config.fault_budget} in "
             f"{self.config.fault_window_s:g}s, no degradation left); "
             f"last: {e!r}"))
-        return False
-
-    def _degrade(self, kind: str) -> bool:
-        """Apply this kind's degradation, if one exists. h2d: fall back
-        from streamed to monolithic ingest (the same auto-degrade the
-        assembler does for replicated layouts, here forced by fault
-        pressure — reason recorded in the ingest stats). Returns True if
-        a degradation was applied."""
-        if kind == FaultKind.H2D and self._ingest_mode == "streamed":
-            self._ingest_mode = "monolithic"
-            self._degrade_reason = "h2d_fault_budget"
-            self._assembler = None  # rebuilt monolithic on the next batch
-            print("[pipeline] repeated h2d faults: degrading ingest "
-                  "streamed → monolithic", file=sys.stderr, flush=True)
-            return True
-        if kind == FaultKind.D2H and self._egress_mode == "streamed":
-            # The delivery-side mirror: repeated fetch faults fall back to
-            # the whole-batch np.asarray path (reason recorded in stats).
-            self._egress_mode = "monolithic"
-            self._egress_degrade_reason = "d2h_fault_budget"
-            old, self._fetcher = self._fetcher, None
-            if old is not None:
-                old.release()
-            print("[pipeline] repeated d2h faults: degrading egress "
-                  "streamed → monolithic", file=sys.stderr, flush=True)
-            return True
         return False
 
     def _on_stall(self, reason: str) -> None:
@@ -386,9 +347,8 @@ class Pipeline:
             # Rebuild BEFORE releasing the shed permits, so a dispatch
             # blocked on the semaphore wakes to the fresh engine.
             self.engine = self.engine.rebuild()
-            self._assembler = None
-            self._fetcher = None  # rebuilt against the fresh engine's
-            #   re-calibrated d2h_block_ms on the next collect
+            self._lane.retarget(self.engine)  # both sides re-derive from
+            #   the fresh engine's shardings and calibrations
             for _ in shed:
                 self._inflight_sem.release()
             # A batch already popped by collect and still materializing
@@ -435,69 +395,6 @@ class Pipeline:
             return None
         return items
 
-    def _builder_for(self, frame_shape, dtype, slot: int):
-        """One staged batch via the shared assembler (runtime/ingest.py).
-
-        The assembler owns the preallocated staging pool — per-shard
-        slabs (streamed) or whole-batch buffers (monolithic), one set per
-        in-flight slot. Pool size is max_inflight + 1: the semaphore
-        guarantees at most max_inflight batches outstanding, so the
-        buffers being rewritten belong to a batch that has already been
-        collected (the device consumed them long ago). Rebuilt only when
-        the frame signature changes, exactly like the engine's compile.
-        """
-        shape = (self.config.batch_size, *frame_shape)
-        dtype = np.dtype(dtype)
-        asm = self._assembler
-        if asm is None or asm.batch_shape != shape or asm.dtype != dtype:
-            # The engine's compiled input sharding defines the shard
-            # layout (and its warmup put calibrates the un-overlapped
-            # H2D cost the overlap_efficiency metric is judged against).
-            self.engine.ensure_compiled(shape, dtype)
-            self._ingest_stats = IngestStats(
-                requested_mode=self.config.ingest,
-                depth=self.config.ingest_depth,
-                h2d_block_ms=self.engine.h2d_block_ms)
-            self._assembler = asm = ShardedBatchAssembler(
-                shape, dtype, self.engine.input_sharding,
-                mode=self._ingest_mode, depth=self.config.ingest_depth,
-                slots=self.config.max_inflight + 1,
-                tracer=self.tracer, track=TRACK_H2D,
-                stats=self._ingest_stats, chaos=self.config.chaos)
-            if self._degrade_reason is not None:
-                # Budget-forced monolithic fallback: record why, like the
-                # assembler's own replicated_layout/cheap_transfer reasons.
-                self._ingest_stats.fallback_reason = self._degrade_reason
-        return asm.begin(slot)
-
-    def _fetcher_for(self):
-        """The streamed-egress fetcher for the engine's compiled output
-        signature (runtime/egress.py) — the delivery-side mirror of
-        ``_builder_for``. Slab pool is max_inflight + 1, same slot
-        discipline: the slab being rewritten belongs to a batch whose
-        rows were already copied onward by collect. Rebuilt when the
-        output signature changes (geometry change, engine rebuild)."""
-        shape = getattr(self.engine, "out_shape", None)
-        dtype = getattr(self.engine, "out_dtype", None)
-        if shape is None:
-            return None  # engine never compiled (shouldn't happen post-submit)
-        f = self._fetcher
-        if f is None or f.out_shape != tuple(shape) or f.dtype != dtype:
-            self._egress_stats = EgressStats(
-                requested_mode=self.config.egress,
-                d2h_block_ms=self.engine.d2h_block_ms)
-            self._fetcher = f = ShardedBatchFetcher(
-                shape, dtype, self.engine.output_sharding,
-                mode=self._egress_mode,
-                slots=self.config.max_inflight + 1,
-                stats=self._egress_stats,
-                tracer=self.tracer, track=TRACK_D2H,
-                chaos=self.config.chaos)
-            if self._egress_degrade_reason is not None:
-                self._egress_stats.fallback_reason = \
-                    self._egress_degrade_reason
-        return f
-
     def _drain_ready(self, pending: "deque") -> bool:
         """Inline collect: retire the oldest batch when the window is full,
         plus any already-completed results (oldest-first — retiring out of
@@ -508,10 +405,8 @@ class Pipeline:
             if len(pending) < self.config.max_inflight:
                 try:
                     ready = pending[0][3].is_ready()
-                except AttributeError:  # non-jax result (tests/fakes)
-                    break
                 except Exception:  # noqa: BLE001 — poisoned async result:
-                    # retire it NOW so _collect_one's np.asarray surfaces
+                    # retire it NOW so _collect_one's fetch surfaces
                     # the error through the normal containment path (a
                     # raise from here would bypass resilient mode and kill
                     # the stream on one bad batch).
@@ -556,7 +451,7 @@ class Pipeline:
                     # thread (which stops releasing permits) can't wedge
                     # dispatch. Acquired BEFORE touching the staging
                     # buffer — the permit is what makes buffer reuse safe
-                    # (see _staging_for).
+                    # (the lane keeps max_inflight + 1 slots).
                     while not self._inflight_sem.acquire(timeout=0.1):
                         if self._abort.is_set():
                             return
@@ -568,35 +463,33 @@ class Pipeline:
                         # codec) straight into the shard staging slabs,
                         # one window per shard chunk so the transfer of a
                         # decoded chunk overlaps the decode of the next.
-                        builder = self._builder_for(
-                            self.queue.frame_shape, self.queue.frame_dtype,
-                            seq)
+                        builder = self._lane.begin(
+                            (self.config.batch_size,
+                             *self.queue.frame_shape),
+                            self.queue.frame_dtype, seq)
                         for start, stop in builder.windows(valid):
                             decode(items[start:stop],
                                    builder.window_view(start, stop))
                             builder.commit_window(start, stop)
                     else:
                         f0 = items[0][1]
-                        builder = self._builder_for(f0.shape, f0.dtype, seq)
+                        builder = self._lane.begin(
+                            (self.config.batch_size, *f0.shape), f0.dtype,
+                            seq)
                         for row, (_, frame, _) in enumerate(items):
                             builder.write_row(row, frame)
-                    # finish() pads short batches by repeating the last
+                    # submit pads short batches by repeating the last
                     # frame — static shapes mean one compilation; padded
                     # outputs are dropped (and repeat-last keeps temporal
                     # state correct, see Filter.pad_safe) — and flushes
                     # the remaining shard transfers.
-                    batch, resident = builder.finish(valid)
+                    result = self._lane.submit(builder, valid)
                     t0 = time.time()
-                    result = (self.engine.submit_resident(batch) if resident
-                              else self.engine.submit(batch))
-                    # Start the D2H now — per output shard on the streamed
-                    # egress path — overlapped with the next batch's
+                    # Start the D2H now, overlapped with the next batch's
                     # staging + device compute; the collect side's fetch
                     # then only waits for completion instead of initiating
-                    # the copy (runtime/egress.py).
-                    fetcher = self._fetcher_for()
-                    if fetcher is not None:
-                        result = fetcher.prefetch(result)
+                    # the copy. What rides the window is the handle.
+                    result = self._lane.prefetch(result)
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     if not inline:
                         self._inflight_sem.release()
@@ -627,14 +520,10 @@ class Pipeline:
     def _collect_one(self, seq, meta, valid, result, t0, release=True) -> bool:
         """Materialize one batch into the reorder buffer + sink; returns
         False only when an error escaped containment."""
-        fetcher = self._fetcher
         try:
-            # Streamed egress: shard-by-shard host copies into the slot's
-            # preallocated slab (the D2H was issued at submit); monolithic
-            # or a non-streamable result: the classic np.asarray, blocking
-            # until the device is done.
-            out = (fetcher.fetch(result, seq) if fetcher is not None
-                   else np.asarray(result))
+            # Blocks until the device is done and the batch is in host
+            # memory (the D2H was issued at submit).
+            out = result.fetch(seq)
         except Exception as e:  # noqa: BLE001 — device error: drop batch
             if self._supervisor is not None:
                 self._supervisor.window.remove(seq)
@@ -651,12 +540,11 @@ class Pipeline:
             "batch_complete", t0, t1, TRACK_DEVICE,
             frames=[i for i, _ in meta],
         )
-        # Streamed fetch returns the slab itself, rewritten after
-        # max_inflight + 1 batches — rows that outlive this call (the
-        # reorder buffer holds them across the frame_delay window) must
-        # own their bytes. The monolithic path's fresh per-batch array
-        # keeps handing out views, exactly as before.
-        copy_rows = fetcher is not None and fetcher.owns(out)
+        # A pooled slab is rewritten after max_inflight + 1 batches —
+        # rows that outlive this call (the reorder buffer holds them
+        # across the frame_delay window) must own their bytes. A fresh
+        # per-batch array keeps handing out views.
+        copy_rows = result.owns(out)
         for row, (idx, ts) in enumerate(meta[:valid]):
             frame = out[row].copy() if copy_rows else out[row]
             self.reorder.complete(idx, (frame, ts))
@@ -821,11 +709,7 @@ class Pipeline:
             "engine_batches_total": float(self.engine.stats.batches),
             "trace_dropped_total": float(self.tracer.dropped),
         }
-        ing, egr = self._ingest_stats, self._egress_stats
-        if ing is not None:
-            out["ingest_overlap_efficiency"] = ing.overlap_efficiency()
-        if egr is not None:
-            out["egress_overlap_efficiency"] = egr.overlap_efficiency()
+        out.update(self._lane.signals())
         for kind, n in self.faults.summary()["by_kind"].items():
             out[f"fault_{kind}_total"] = float(n)
         return out
@@ -849,10 +733,7 @@ class Pipeline:
             "recoveries": self.recoveries,
             **self.latency.summary(),
         }
-        if self._ingest_stats is not None:
-            out["ingest"] = self._ingest_stats.summary()
-        if self._egress_stats is not None:
-            out["egress"] = self._egress_stats.summary()
+        out.update(self._lane.stats())
         if self.config.chaos is not None:
             out["chaos"] = self.config.chaos.summary()
         return out

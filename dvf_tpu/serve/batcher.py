@@ -77,8 +77,8 @@ class BatchPlan:
     #   the plan's claims were already released — a late result/second
     #   discard for a dead plan must not double-account the sessions
     bucket: Any = None  # the signature bucket this batch belongs to
-    #   (serve.server._Bucket): the collect side fetches through that
-    #   bucket's egress fetcher and attributes tick cost / faults to it;
+    #   (serve.server._Bucket): the collect side attributes tick cost /
+    #   faults to it;
     #   None on the legacy single-signature paths (tests, ad-hoc plans)
     cost_sample: bool = True  # False when other batches were in flight
     #   at submit: the submit→materialize wall then includes queue wait
@@ -101,12 +101,6 @@ class BatchPlan:
     #   int32 [2, batch] row map Engine.submit takes (runtime.engine.
     #   device_row_map) — state row per batch row (-1 = pad), fresh
     #   mark per row. None for every other filter.
-    fetcher: Any = None  # the egress fetcher THIS batch was prefetched
-    #   into, pinned at dispatch: a hot program swap may replace
-    #   ``bucket.fetcher`` (new output signature) while this batch is
-    #   still in flight, and the collect side must fetch from the one
-    #   the D2H was actually issued on. None = monolithic egress (the
-    #   collect side falls back to np.asarray).
 
 
 class ContinuousBatcher:

@@ -18,7 +18,7 @@ the single-stream pipeline's shape):
                       bucket.Engine.submit  (in-flight depth bounded
                           │  across buckets — one device queue)
                           │ collect thread: materialize via the
-                          ▼ bucket's fetcher → ResultRouter
+                          ▼ bucket's lane → ResultRouter
                       per-session reorder → out queue / sink ──poll──► clients
 
 Admission control is three-layered: ``max_sessions`` caps tenants at
@@ -58,6 +58,7 @@ frames share device batches with every other tenant.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import queue
 import sys
@@ -87,8 +88,6 @@ from dvf_tpu.obs.memory import (
 )
 from dvf_tpu.obs.metrics import (
     BatchStamps,
-    EgressStats,
-    IngestStats,
     LatencyStats,
     StageStats,
     ThreadClock,
@@ -108,14 +107,9 @@ from dvf_tpu.resilience.continuity import (
 )
 from dvf_tpu.resilience.faults import FaultError, FaultKind, FaultStats, classify
 from dvf_tpu.resilience.supervisor import InflightWindow, Supervisor
-from dvf_tpu.runtime.egress import (
-    EGRESS_MODES,
-    AsyncCodecPlane,
-    ShardedBatchFetcher,
-    transfer_layout_of,
-)
+from dvf_tpu.runtime.egress import AsyncCodecPlane
 from dvf_tpu.runtime.engine import Engine, ProgramPool
-from dvf_tpu.runtime.ingest import INGEST_MODES, ShardedBatchAssembler
+from dvf_tpu.runtime.lane import DeviceLane
 from dvf_tpu.runtime.signature import (
     SignatureKey,
     build_filter,
@@ -138,11 +132,11 @@ from dvf_tpu.serve.session import (
 )
 
 # Trace track ids (one lane per stage, the pipeline's convention):
-# dispatch thread, device span, collect thread, per-shard H2D / D2H
-# transfer lanes. The reconfiguration ledger stamps its events on its
-# own lane (obs.ledger.TRACK_LEDGER = 6), clear of all of these.
-TRACK_DISPATCH, TRACK_DEVICE, TRACK_COLLECT, TRACK_H2D, TRACK_D2H = (
-    0, 1, 2, 3, 4)
+# dispatch thread, device span, collect thread; the per-shard H2D / D2H
+# transfer spans land on the device lane's own tracks (runtime/lane.py:
+# 3, 4). The reconfiguration ledger stamps its events on its own lane
+# (obs.ledger.TRACK_LEDGER = 6), clear of all of these.
+TRACK_DISPATCH, TRACK_DEVICE, TRACK_COLLECT = 0, 1, 2
 
 # The two pacing threads' per-bucket states (obs.metrics.ThreadClock;
 # ``idle`` is whatever belongs to no bucket). With ``trace`` on each is
@@ -333,9 +327,9 @@ class _Bucket:
     """One serving signature's slice of the frontend.
 
     A bucket owns everything that is per-compiled-program: the leased
-    ``Engine`` (from the frontend's :class:`ProgramPool`), the pinned
-    frame geometry/dtype, its sessions, the streamed ingest assembler
-    and egress fetcher built against THAT engine's shardings, a
+    ``Engine`` (from the frontend's :class:`ProgramPool`) under the
+    device lane that carries its batches onto and off the chip
+    (runtime/lane.py), the pinned frame geometry/dtype, its sessions, a
     per-bucket :class:`ErrorBudget` (fault attribution is per bucket —
     one tenant mix's broken program must not spend another's budget),
     and the MEASURED tick-cost estimate the EDF/cost bucket scheduler
@@ -346,12 +340,20 @@ class _Bucket:
     _EWMA_ALPHA = 0.2
 
     def __init__(self, config: "ServeConfig", filt: Filter, op_chain: str,
-                 engine: Engine, key: Optional[SignatureKey] = None):
+                 engine: Engine, tracer: Tracer, pin,
+                 key: Optional[SignatureKey] = None):
         self.config = config
         self.filter = filt
         self.op_chain = op_chain        # canonical chain spelling
-        self.engine = engine
         self.key = key                  # SignatureKey once pinned
+        # The lane holds the engine pointer (``engine`` below is a view
+        # of it); max_inflight + 1 slots a side: the slot being
+        # rewritten always belongs to an already-collected batch.
+        # ``pin(bucket, shape, dtype)`` is the frontend's compile step.
+        self.lane = DeviceLane(
+            engine, config, config.max_inflight, tracer=tracer,
+            chaos=config.chaos, compile=functools.partial(pin, self),
+            name=lambda: f"serve bucket {self.label()}")
         self.sessions: Dict[str, StreamSession] = {}
         self.frame_shape: Optional[tuple] = (tuple(key.geometry)
                                              if key is not None else None)
@@ -376,23 +378,12 @@ class _Bucket:
         #   program compiles aside while this bucket keeps dispatching
         #   at the old size; the commit swings the program pointer
         #   between ticks, and in-flight batches drain on the old
-        #   program (their collect fetches through plan.fetcher)
+        #   program (each comes back through the fetcher it was
+        #   prefetched into: the lane's in-flight handle pins it)
         self.mean_valid_rows: Optional[float] = None  # EWMA of VALID
         #   rows per served batch — the occupancy signal batch sizing
         #   divides by (rows beyond it are padding the device computes
         #   and drops)
-        self.ingest_mode = config.ingest
-        self.degrade_reason: Optional[str] = None
-        self.egress_mode = config.egress
-        self.egress_degrade_reason: Optional[str] = None
-        self.assembler: Optional[ShardedBatchAssembler] = None
-        self.ingest_stats: Optional[IngestStats] = None
-        self.fetcher: Optional[ShardedBatchFetcher] = None
-        self.draining_fetchers: List[ShardedBatchFetcher] = []  # egress
-        #   fetchers retired by a hot swap while batches prefetched into
-        #   them were still in flight (those fetch through plan.fetcher);
-        #   released by collect once the bucket's window drains to zero
-        self.egress_stats: Optional[EgressStats] = None
         self.stages = StageStats()  # always-on stage counters: where a
         #   delivered frame's latency went (eight components that sum to
         #   it) and what the two pacing threads did for this bucket —
@@ -420,6 +411,14 @@ class _Bucket:
         self.state_counts = {"table_rows_total": 0, "chain_rows_total": 0,
                              "fresh_rows_total": 0}
         self.state_resets = {"admission": 0, "rebuild": 0, "migrate": 0}
+
+    @property
+    def engine(self) -> Engine:
+        return self.lane.engine
+
+    @engine.setter
+    def engine(self, value: Engine) -> None:
+        self.lane.retarget(value)
 
     # -- session state ---------------------------------------------------
 
@@ -516,14 +515,6 @@ class _Bucket:
         with self._count_lock:
             self.inflight_batches = 0
 
-    def release_drained_fetchers(self) -> None:
-        """Free swap-retired egress fetchers; call only when no batch
-        prefetched into them can still be in flight (window at zero, or
-        the bucket is being torn down)."""
-        drained, self.draining_fetchers = self.draining_fetchers, []
-        for f in drained:
-            f.release()
-
     # -- signature -------------------------------------------------------
 
     def pinned_signature(self) -> Optional[tuple]:
@@ -589,10 +580,7 @@ class _Bucket:
         # delta says whether ANYTHING compiled while it was open.
         (row["xla_compiles_total"],
          row["xla_compile_s_total"]) = ledger_mod.XLA_COMPILES.totals()
-        if self.ingest_stats is not None:
-            row["ingest"] = self.ingest_stats.summary()
-        if self.egress_stats is not None:
-            row["egress"] = self.egress_stats.summary()
+        row.update(self.lane.stats())
         if self.state_rows:
             row["state"] = dict(
                 self.state_counts, rows=self.state_rows,
@@ -614,14 +602,6 @@ class ServeFrontend:
     ):
         self.filter = filt
         self.config = config or ServeConfig()
-        if self.config.ingest not in INGEST_MODES:
-            raise ValueError(
-                f"ingest must be one of {INGEST_MODES}, got "
-                f"{self.config.ingest!r}")
-        if self.config.egress not in EGRESS_MODES:
-            raise ValueError(
-                f"egress must be one of {EGRESS_MODES}, got "
-                f"{self.config.egress!r}")
         engine = engine or Engine(filt, chaos=self.config.chaos,
                                   state_rows=self.config.max_sessions)
         if filt.temporal and engine.state_rows < self.config.max_sessions:
@@ -642,8 +622,13 @@ class ServeFrontend:
         # session declares a different (op_chain, geometry, dtype).
         default_chain = canonical_op_chain_or_verbatim(filt.name)
         self.pool = ProgramPool(capacity=self.config.pool_capacity)
+        label = self.config.replica_label
+        self.tracer = Tracer(
+            enabled=self.config.trace,
+            process_name=f"serve:{label}" if label else "serve")
         self._buckets: List[_Bucket] = [
-            _Bucket(self.config, filt, default_chain, engine)]
+            _Bucket(self.config, filt, default_chain, engine,
+                    self.tracer, self._pin_program)]
         self._bucket_by_key: Dict[SignatureKey, _Bucket] = {}
         # Live Filter objects by canonical chain. A filter's DISPLAY
         # name (e.g. "gaussian_blur(ksize=9)" resolved to its Pallas
@@ -680,10 +665,6 @@ class ServeFrontend:
         #   of its own; the session state IS this process)
         # -- telemetry plane (obs/): tracer lanes, metrics registry,
         # sliding signal window, flight recorder ---------------------------
-        label = self.config.replica_label
-        self.tracer = Tracer(
-            enabled=self.config.trace,
-            process_name=f"serve:{label}" if label else "serve")
         self.registry = MetricsRegistry()
         attach_signal_provider(
             self.registry, "serve", self.signals,
@@ -964,13 +945,7 @@ class ServeFrontend:
             # eagerly (the retirement path already does; live buckets
             # must too): the memory-accounting session-end guard pins
             # that a closed frontend leaves ZERO occupied host slabs.
-            a, b.assembler = b.assembler, None
-            f, b.fetcher = b.fetcher, None
-            if a is not None:
-                a.release()
-            if f is not None:
-                f.release()
-            b.release_drained_fetchers()
+            b.lane.release()
             if self.ledger is not None:
                 self.ledger.abandon_stalls(b.label())
         if self.config.profile_dir:
@@ -1216,19 +1191,15 @@ class ServeFrontend:
             out["downshifted_sessions"] = float(sum(
                 1 for s in live if s.quality_level > 0))
             out["dispatch_tick_s"] = float(self._tick_s)
-        ing = self._buckets[0].ingest_stats
-        egr = self._buckets[0].egress_stats
-        if ing is not None:
-            out["ingest_overlap_efficiency"] = ing.overlap_efficiency()
-        if egr is not None:
-            out["egress_overlap_efficiency"] = egr.overlap_efficiency()
+        out.update(self._buckets[0].lane.signals())
         if self.ledger is not None:
             out.update(self.ledger.signals())
             # Occupied host staging/delivery slabs (cheap per-bucket
             # sums) — also the leak-trend watch's input via the ring.
-            slab, state = self._slab_state_bytes(buckets)
-            out["mem_host_slab_bytes"] = float(slab)
-            out["mem_device_state_bytes"] = float(state)
+            out["mem_host_slab_bytes"] = float(sum(
+                b.lane.slab_bytes() for b in buckets))
+            out["mem_device_state_bytes"] = float(sum(
+                getattr(b.engine, "state_bytes", 0) or 0 for b in buckets))
         if self.attribution is not None:
             # Frame-lineage attribution: per-component p99 over the
             # window (attr_<component>_p99_ms) + lineage counters —
@@ -1446,25 +1417,6 @@ class ServeFrontend:
                 labels={"signature": signature or "unpinned",
                         "cause": cause or "unknown"})
 
-    def _record_inline_compile(self, bucket: "_Bucket", before: int,
-                               cause: str) -> None:
-        """Ledger a compile that ran OUTSIDE the pool (the default
-        bucket's lazy first pin in ``_builder_for``, a resize's
-        recompile): ``before`` is the engine's compile_count before the
-        ``ensure_compiled`` call — unchanged means no compile ran."""
-        led = self.ledger
-        eng = bucket.engine
-        if led is None or eng.stats.compile_count == before:
-            return
-        sig = bucket.label()
-        compile_ms = eng.last_compile_ms
-        led.record(ledger_mod.COMPILE, cause=cause, signature=sig,
-                   bucket=sig, cache="miss",
-                   wall_ms=compile_ms,
-                   compile_ms=(round(float(compile_ms), 3)
-                               if compile_ms is not None else None))
-        self._observe_compile(compile_ms, sig, cause)
-
     def _memory_bucket_rows(self) -> List[dict]:
         """Per-bucket memory attribution for the dvf_mem_* gauges:
         device-resident state (measured at compile) + occupied host
@@ -1473,39 +1425,12 @@ class ServeFrontend:
             buckets = list(self._buckets)
         rows = []
         for b in buckets:
-            a, f = b.assembler, b.fetcher
             rows.append({
                 "bucket": b.label(),
                 "device_state_bytes": getattr(b.engine, "state_bytes", 0),
-                "host_slab_bytes": ((a.slab_bytes() if a is not None else 0)
-                                    + (f.slab_bytes()
-                                       if f is not None else 0)),
+                "host_slab_bytes": b.lane.slab_bytes(),
             })
         return rows
-
-    @staticmethod
-    def _slab_state_bytes(buckets) -> tuple:
-        """(host slab bytes, device state bytes) over an
-        already-snapshotted bucket list — ONE copy of the sum shared by
-        signals() and _host_slab_bytes. Fields are captured once per
-        bucket: a concurrent resize/recovery nulls b.assembler under
-        the frontend lock, and a check-then-call would race it."""
-        slab = state = 0
-        for b in buckets:
-            a, f = b.assembler, b.fetcher
-            if a is not None:
-                slab += a.slab_bytes()
-            if f is not None:
-                slab += f.slab_bytes()
-            state += getattr(b.engine, "state_bytes", 0) or 0
-        return slab, state
-
-    def _host_slab_bytes(self) -> int:
-        """This frontend's occupied host staging memory (cheap sums —
-        a handful of buckets), the signals()/leak-watch input."""
-        with self._lock:
-            buckets = list(self._buckets)
-        return self._slab_state_bytes(buckets)[0]
 
     def _memory_stats(self) -> dict:
         """The ``stats()['memory']`` row: per-bucket attributed host
@@ -1859,7 +1784,7 @@ class ServeFrontend:
             victim = next((b for b in self._buckets[1:] if b.idle()), None)
             self._retire_bucket_locked(victim)
         b = _Bucket(self.config, engine.filter, key.op_chain, engine,
-                    key=key)
+                    self.tracer, self._pin_program, key=key)
         b._pooled = True  # leased through self.pool by _acquire_program
         if self.config.profile_dir:
             # One small JSON read at bucket creation (a path that just
@@ -1898,13 +1823,7 @@ class ServeFrontend:
                 self._retired_bucket_costs[bucket.label()] = tick
             if getattr(bucket, "_pooled", False):
                 self.pool.release(bucket.key)
-        a, bucket.assembler = bucket.assembler, None
-        f, bucket.fetcher = bucket.fetcher, None
-        if a is not None:
-            a.release()
-        if f is not None:
-            f.release()
-        bucket.release_drained_fetchers()
+        bucket.lane.release()
         if self.ledger is not None:
             label = bucket.label()
             # A retired bucket never dispatches again: close out any
@@ -2223,8 +2142,6 @@ class ServeFrontend:
                 unpinned = b.frame_shape is None
                 if unpinned:
                     b.batch_size = plan.batch_size
-                    b.ingest_mode = plan.ingest
-                    b.egress_mode = plan.egress
             if not unpinned and b.batch_size != plan.batch_size:
                 self.request_batch_size(b.label(), plan.batch_size,
                                         reason=reason or "autoplan")
@@ -2745,7 +2662,6 @@ class ServeFrontend:
                     # Nothing has flowed yet: no program at the old size
                     # to swap, the first batch compiles at the new one.
                     bucket.batch_size = n
-                    bucket.assembler = None
                     if self.ledger is not None:
                         self.ledger.record(
                             ledger_mod.BATCH_RESIZE,
@@ -2839,10 +2755,9 @@ class ServeFrontend:
         self._adopt_bucket_key(bucket)  # takes self._lock itself
         with self._lock:
             bucket.batch_size = n
-            bucket.assembler = None  # staging re-derives from the new
-            #   program's sharding in _builder_for; the egress fetcher
-            #   re-derives at the next dispatch (in-flight batches keep
-            #   fetching through the fetcher pinned on their plan)
+            bucket.lane.retarget(bucket.engine)  # both sides re-derive
+            #   from the new program at the next dispatch; in-flight
+            #   batches come back through the fetcher they went out on
             self.swaps += 1
         if self.ledger is not None:
             label = bucket.label()
@@ -3035,47 +2950,30 @@ class ServeFrontend:
 
     # -- service threads -------------------------------------------------
 
-    def _builder_for(self, bucket: "_Bucket", seq: int):
-        """One staged batch via the bucket's assembler (runtime/ingest.py)
-        — both ingest modes; the assembler owns the per-inflight-slot
-        staging pool (max_inflight + 1 buffers: the one being rewritten
-        always belongs to an already-collected batch, exactly like the
-        single-stream pipeline's). Per bucket because the slab layout
-        derives from THAT bucket's compiled input sharding AND its
-        (control-plane-resizable) batch size."""
-        shape = (bucket.batch_size, *bucket.frame_shape)
-        dtype = np.dtype(bucket.frame_dtype)
-        if (bucket.assembler is None
-                or bucket.assembler.batch_shape != shape
-                or bucket.assembler.depth != self.config.ingest_depth):
-            # The depth check is the auto-plan seam: a planned (or
-            # candidate) ingest depth lands in config and the next
-            # rebuild picks it up — exactly how a batch resize already
-            # re-derives the slab layout.
-            before = bucket.engine.stats.compile_count
-            self._seed_calibrations(bucket)
-            bucket.engine.ensure_compiled(shape, dtype)
-            self._save_calibrations(bucket, before)
-            # A compile that actually ran here is the legacy lazy pin
-            # (default bucket, first traffic) — ledger it as an
-            # admission-cause compile ON THE DISPATCH THREAD, which is
-            # exactly the JIT stall the AOT path exists to avoid.
-            self._record_inline_compile(bucket, before,
-                                        ledger_mod.CAUSE_ADMISSION)
-            self._adopt_bucket_key(bucket)
-            bucket.ingest_stats = IngestStats(
-                requested_mode=self.config.ingest,
-                depth=self.config.ingest_depth,
-                h2d_block_ms=bucket.engine.h2d_block_ms)
-            bucket.assembler = ShardedBatchAssembler(
-                shape, dtype, bucket.engine.input_sharding,
-                mode=bucket.ingest_mode, depth=self.config.ingest_depth,
-                slots=self.config.max_inflight + 1,
-                stats=bucket.ingest_stats, chaos=self.config.chaos,
-                tracer=self.tracer, track=TRACK_H2D)
-            if bucket.degrade_reason is not None:
-                bucket.ingest_stats.fallback_reason = bucket.degrade_reason
-        return bucket.assembler.begin(seq)
+    def _pin_program(self, bucket: "_Bucket", shape, dtype) -> None:
+        """The compile step of ``bucket``'s lane, run whenever its
+        staging side re-derives (first traffic, a batch resize, a
+        planned ingest depth): compile if this signature never was, and
+        book what a compile that actually ran here means."""
+        eng = bucket.engine
+        before = eng.stats.compile_count
+        self._seed_calibrations(bucket)
+        eng.ensure_compiled(shape, dtype)
+        self._save_calibrations(bucket, before)
+        if self.ledger is not None and eng.stats.compile_count != before:
+            # A compile that actually ran here, OUTSIDE the pool, is the
+            # legacy lazy pin (default bucket, first traffic) — ledger
+            # it as an admission-cause compile ON THE DISPATCH THREAD,
+            # which is exactly the JIT stall the AOT path exists to avoid.
+            sig, compile_ms = bucket.label(), eng.last_compile_ms
+            self.ledger.record(
+                ledger_mod.COMPILE, cause=ledger_mod.CAUSE_ADMISSION,
+                signature=sig, bucket=sig, cache="miss", wall_ms=compile_ms,
+                compile_ms=(round(float(compile_ms), 3)
+                            if compile_ms is not None else None))
+            self._observe_compile(compile_ms, sig,
+                                  ledger_mod.CAUSE_ADMISSION)
+        self._adopt_bucket_key(bucket)
 
     def _adopt_bucket_key(self, bucket: "_Bucket") -> None:
         """Once a bucket's engine has compiled, its canonical signature
@@ -3104,40 +3002,6 @@ class ServeFrontend:
             #   stop() frees it directly
         bucket._pooled = True
 
-    def _fetcher_for(self, bucket: "_Bucket"):
-        """The bucket's streamed-egress fetcher for its engine's
-        compiled output signature — the delivery-side mirror of
-        ``_builder_for``, same slot discipline (max_inflight + 1 slabs;
-        the router copies rows out during route(), so a slab is
-        quiescent before its slot cycles). Built by the dispatch thread;
-        the collect thread only reads it."""
-        shape = getattr(bucket.engine, "out_shape", None)
-        if shape is None:
-            return None
-        f = bucket.fetcher
-        if f is None or f.out_shape != tuple(shape):
-            if f is not None:
-                # Output signature changed under a hot swap: batches
-                # already prefetched into the old fetcher are still in
-                # flight (their plans pin it) — park it for release
-                # once the bucket's window drains instead of freeing
-                # slabs the collect side is about to read.
-                bucket.draining_fetchers.append(f)
-            bucket.egress_stats = EgressStats(
-                requested_mode=self.config.egress,
-                d2h_block_ms=bucket.engine.d2h_block_ms)
-            bucket.fetcher = f = ShardedBatchFetcher(
-                shape, bucket.engine.out_dtype,
-                bucket.engine.output_sharding,
-                mode=bucket.egress_mode,
-                slots=self.config.max_inflight + 1,
-                stats=bucket.egress_stats, chaos=self.config.chaos,
-                tracer=self.tracer, track=TRACK_D2H)
-            if bucket.egress_degrade_reason is not None:
-                bucket.egress_stats.fallback_reason = \
-                    bucket.egress_degrade_reason
-        return f
-
     def _fail(self, e: BaseException) -> None:
         first = self._error is None
         if first:
@@ -3153,8 +3017,8 @@ class ServeFrontend:
                  bucket: Optional["_Bucket"] = None) -> bool:
         """Bounded containment (resilience.budget): classify, count,
         continue while within the per-kind budget; the first overflow
-        degrades (h2d → monolithic ingest, compute/oom → supervised
-        engine rebuild), the second surfaces a hard ServeError — a
+        degrades (h2d / d2h → the lane's monolithic path, compute/oom →
+        supervised engine rebuild), the second surfaces a hard ServeError — a
         permanently broken engine must not serve 0 fps silently.
         Budgets attribute PER BUCKET: one signature's broken program
         spends its own budget, never another tenant mix's."""
@@ -3186,24 +3050,6 @@ class ServeFrontend:
         True if applied (the fault is then still contained; a second
         overflow fails)."""
         b = bucket if bucket is not None else self._buckets[0]
-        if kind == FaultKind.H2D and b.ingest_mode == "streamed":
-            b.ingest_mode = "monolithic"
-            b.degrade_reason = "h2d_fault_budget"
-            b.assembler = None
-            print(f"[serve] repeated h2d faults: degrading ingest "
-                  f"streamed → monolithic (bucket {b.label()})",
-                  file=sys.stderr, flush=True)
-            return True
-        if kind == FaultKind.D2H and b.egress_mode == "streamed":
-            b.egress_mode = "monolithic"
-            b.egress_degrade_reason = "d2h_fault_budget"
-            old, b.fetcher = b.fetcher, None
-            if old is not None:
-                old.release()
-            print(f"[serve] repeated d2h faults: degrading egress "
-                  f"streamed → monolithic (bucket {b.label()})",
-                  file=sys.stderr, flush=True)
-            return True
         if kind in (FaultKind.COMPUTE, FaultKind.OOM, FaultKind.INTERNAL):
             # The bucket's engine itself may be the broken thing
             # (poisoned compile cache, leaked device state): rebuild it
@@ -3213,7 +3059,7 @@ class ServeFrontend:
             self._recover(f"fault budget overflow ({kind})", kind=kind,
                           bucket=b)
             return True
-        return False
+        return b.lane.degrade(kind)  # h2d / d2h → the monolithic path
 
     def _on_stall(self, reason: str) -> None:
         """Watchdog callback (supervisor thread): a submitted batch aged
@@ -3364,17 +3210,11 @@ class ServeFrontend:
                                 # engine — the frontend is past serving
                                 # this bucket.
                                 pass
-                    a, b.assembler = b.assembler, None
-                    f, b.fetcher = b.fetcher, None  # re-derive from the
-                    #   fresh engine's re-calibrated d2h_block_ms; slabs
-                    #   released eagerly so the memory accounting never
-                    #   counts an abandoned pool as occupied
-                    if a is not None:
-                        a.release()
-                    if f is not None:
-                        f.release()
-                    b.release_drained_fetchers()  # window fully shed:
-                    #   nothing in flight can still pin them
+                    b.lane.release()  # both sides re-derive from the
+                    #   fresh program's calibrations; slabs (parked
+                    #   fetchers' too: the window was shed, nothing in
+                    #   flight pins them) go now, so the memory
+                    #   accounting never counts an abandoned pool
                     # The rebuilt engine's session-state table is new:
                     # every bound session restarts (counted, ledgered).
                     with self._lock:
@@ -3571,35 +3411,26 @@ class ServeFrontend:
                                 (row, np.array(slot.frame, copy=True),
                                  slot.session.id, slot.index, slot.lin))
                 try:
-                    builder = self._builder_for(bucket, seq)
+                    lane = bucket.lane
+                    builder = lane.begin(
+                        (bucket.batch_size, *bucket.frame_shape),
+                        bucket.frame_dtype, seq)
                     for row, slot in enumerate(plan.slots):
                         builder.write_row(row, slot.frame)
                         slot.frame = None  # drop the client's buffer
-                    batch, resident = builder.finish(plan.valid)
-                    engine = bucket.engine
-                    submit = (engine.submit_resident if resident
-                              else engine.submit)
-                    if plan.rows is None:
-                        result = submit(batch)
-                    else:  # session-state filter: who is in the batch
-                        result = submit(batch, plan.rows)
+                    # plan.rows: a session-state filter's row map (who
+                    # is in the batch); None for every other filter.
+                    result = lane.submit(builder, plan.valid, plan.rows)
+                    if plan.rows is not None:
                         bucket.note_state_rows(plan)
                     # Stamp: batch assembly + H2D ends at submit return
                     # (async dispatch: the device now owns the batch).
                     st.t_submit = time.time()
-                    # Start the D2H now — per output shard on the streamed
-                    # egress path — so the collect side only waits, never
-                    # initiates (runtime/egress.py).
-                    fetcher = self._fetcher_for(bucket)
-                    if fetcher is not None:
-                        # What rides the in-flight queue is what fetch
-                        # takes: the packed words where the fetcher
-                        # packs (the result itself is dropped here).
-                        result = fetcher.prefetch(result)
-                    plan.fetcher = fetcher  # pinned: a hot swap may
-                    #   re-derive bucket.fetcher (new output signature)
-                    #   while this batch is in flight — collect must
-                    #   fetch from the one the D2H was issued on
+                    # Start the D2H now, so the collect side only waits,
+                    # never initiates. What rides the in-flight queue is
+                    # the lane's handle (the result itself is dropped
+                    # here); it pins the fetcher the D2H was issued on.
+                    result = lane.prefetch(result)
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     sem.release()
                     self.router.discard(plan, kind=classify(e, "dispatch"))
@@ -3651,14 +3482,11 @@ class ServeFrontend:
 
     def _collect(self, gen: int = 0) -> None:
         chaos = self.config.chaos
-        # The device/D2H split, for every batch: block_until_ready marks
+        # The device/D2H split, for every batch: the handle's wait marks
         # "device compute done, data still on device"; the fetch that
         # follows is then pure D2H+scatter. No new synchronisation: the
         # streamed fetch begins with the same wait and the monolithic
         # np.asarray waits anyway.
-        import jax
-
-        block_until_ready = jax.block_until_ready
         tracer = self.tracer
         # A replacement thread (recovery) carries its predecessor's
         # ledger on; the superseded thread keeps writing to the old one.
@@ -3689,13 +3517,8 @@ class ServeFrontend:
                 st = plan.stamps
                 st.t_taken = time.time()  # stamp: off the in-flight queue
                 bucket = plan.bucket
-                fetcher = (plan.fetcher if plan.fetcher is not None
-                           else (bucket.fetcher if bucket is not None
-                                 else None))  # plan-pinned first: the
-                #   bucket's fetcher may already belong to a hot-swapped
-                #   successor program with a different output signature
                 try:
-                    block_until_ready(result)
+                    result.wait()
                 except Exception:  # noqa: BLE001 — a poisoned batch
                     pass  # raises again in fetch below, where the
                     #   containment ladder owns it
@@ -3710,8 +3533,7 @@ class ServeFrontend:
                     # route(), so handing it the pooled slab is safe —
                     # the slot only cycles max_inflight+1 batches later —
                     # and the landed buffer dies with the batch.
-                    out = (fetcher.fetch(result, seq) if fetcher is not None
-                           else np.asarray(result))
+                    out = result.fetch(seq)
                     st.t_fetched = time.time()  # stamp: in host memory
                     st.close_batch()
                     if chaos is not None:
@@ -3790,15 +3612,9 @@ class ServeFrontend:
                                     st.t_ready, TRACK_COLLECT, seq=seq)
                     tracer.complete("collect:d2h", st.t_ready,
                                     st.t_fetched, TRACK_COLLECT, seq=seq,
-                                    layout=transfer_layout_of(result))
+                                    layout=result.layout)
                     tracer.complete("collect:route", st.t_fetched,
                                     st.t_routed, TRACK_COLLECT, seq=seq)
-                if bucket is not None and bucket.draining_fetchers \
-                        and bucket.inflight_batches == 0:
-                    # The last pre-swap batch just routed (route copies
-                    # rows out of the slab, so it is quiescent now):
-                    # the old program's egress slabs can finally go.
-                    bucket.release_drained_fetchers()
                 # A materialized batch is proof of engine progress: the
                 # consecutive-stall escalation counter starts over.
                 self._stalls_since_progress = 0
@@ -3872,10 +3688,7 @@ class ServeFrontend:
             **self.router.stats(),
             "aggregate": LatencyStats.merged(
                 [s.latency for s in every.values()]),
-            **({"ingest": buckets[0].ingest_stats.summary()}
-               if buckets[0].ingest_stats is not None else {}),
-            **({"egress": buckets[0].egress_stats.summary()}
-               if buckets[0].egress_stats is not None else {}),
+            **buckets[0].lane.stats(),
             **({"supervisor": {
                     "stalls": self._supervisor.stalls,
                     "heartbeat_ages_s": self._supervisor.heartbeat_ages(),

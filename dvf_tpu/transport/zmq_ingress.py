@@ -31,18 +31,13 @@ import numpy as np
 
 from dvf_tpu.api.filter import Filter
 from dvf_tpu.obs.export import attach_signal_provider
-from dvf_tpu.obs.metrics import EgressStats, IngestStats
 from dvf_tpu.obs.registry import MetricsRegistry
 from dvf_tpu.obs.trace import EGRESS_SEND, Tracer
 from dvf_tpu.resilience.budget import ErrorBudget, escalate
 from dvf_tpu.resilience.faults import FaultError, FaultKind, FaultStats, classify
-from dvf_tpu.runtime.egress import (
-    EGRESS_MODES,
-    AsyncCodecPlane,
-    ShardedBatchFetcher,
-)
+from dvf_tpu.runtime.egress import AsyncCodecPlane
 from dvf_tpu.runtime.engine import Engine
-from dvf_tpu.runtime.ingest import INGEST_MODES, ShardedBatchAssembler
+from dvf_tpu.runtime.lane import DeviceLane
 from dvf_tpu.transport.codec import (
     WIRE_MODES,
     DeltaCodec,
@@ -124,12 +119,6 @@ class TpuZmqWorker:
     ):
         import zmq
 
-        if ingest not in INGEST_MODES:
-            raise ValueError(f"ingest must be one of {INGEST_MODES}, "
-                             f"got {ingest!r}")
-        if egress not in EGRESS_MODES:
-            raise ValueError(f"egress must be one of {EGRESS_MODES}, "
-                             f"got {egress!r}")
         if egress_depth < 1:
             raise ValueError("egress depth must be >= 1")
         if wire is None:
@@ -147,6 +136,26 @@ class TpuZmqWorker:
                 f"filter {filt.name!r} is stateful and not pad-safe; "
                 f"the ZMQ worker pads short batches and cannot serve it"
             )
+        self.chaos = chaos  # resilience.chaos.FaultPlan ("decode" and
+        #   "transport" injection sites live here; "h2d"/"d2h"/"compute"/
+        #   "oom" ride on the lane and the engine)
+        self.engine = engine or Engine(filt, chaos=chaos)
+        if chaos is not None and self.engine.chaos is None:
+            self.engine.chaos = chaos
+        self.ingest = ingest
+        self.ingest_depth = ingest_depth
+        self.egress = egress
+        self.egress_depth = egress_depth
+        # The batches' way onto and off the chip, built before any socket
+        # so that a bad mode raises with nothing to close. _process_batch
+        # is synchronous up to the fetch: one staging slot. The encode
+        # plane holds at most egress_depth batches' rows (encode futures,
+        # raw memoryviews): egress_depth + 1 delivery slots.
+        self._lane = DeviceLane(
+            self.engine, self, egress_depth, staging_inflight=0,
+            chaos=chaos, compile=self._compile, name="TpuZmqWorker")
+        self._geometry: Optional[tuple] = None  # (h, w) of the jpeg
+        #   stream as last probed; None = probe the next batch
         self.ctx = zmq.Context()
         self._dealer_endpoint = f"tcp://{host}:{distribute_port}"
         self.dealer = self.ctx.socket(zmq.DEALER)
@@ -159,12 +168,6 @@ class TpuZmqWorker:
         self.push.connect(f"tcp://{host}:{collect_port}")
         self._zmq = zmq
         self.filt = filt
-        self.chaos = chaos  # resilience.chaos.FaultPlan ("decode" and
-        #   "transport" injection sites live here; "h2d"/"compute"/"oom"
-        #   ride on the engine and assembler)
-        self.engine = engine or Engine(filt, chaos=chaos)
-        if chaos is not None and self.engine.chaos is None:
-            self.engine.chaos = chaos
         self.wire = wire
         self._wire_degrade_reason: Optional[str] = None
         if wire == "delta":
@@ -233,10 +236,6 @@ class TpuZmqWorker:
                       "drift is bounded by the keyframe cadence only",
                       file=sys.stderr)
             self._probe = DeviceDeltaProbe(tile=delta_tile)
-        self.ingest = ingest
-        self.ingest_depth = ingest_depth
-        self.egress = egress
-        self.egress_depth = egress_depth
         # The worker's own trace lane (bounded ring, obs.trace): batch
         # spans + egress_encode/egress_send land on track 0; the
         # snapshot merges into a fleet-wide Perfetto session like every
@@ -296,22 +295,11 @@ class TpuZmqWorker:
         self.continuity = ContinuityStats()
         self._reconnect = (ReconnectPolicy(self.heartbeat)
                            if self.heartbeat else None)
-        self._degrade_reason: Optional[str] = None
-        self._asm: Optional[ShardedBatchAssembler] = None  # per-geometry
-        #   staged-batch assembler (_process_batch); replaces the old raw
-        #   staging buffer — slabs are reused across batches identically
-        self._ingest_stats: Optional[IngestStats] = None
-        # Streamed egress (runtime/egress.py): per-output-shard fetch into
-        # preallocated slabs + the asynchronous codec plane — encode/send
-        # of batch k overlap the decode/H2D/compute of batch k+1, bounded
-        # by egress_depth batches in flight. Slab pool is egress_depth + 1
-        # so a pending batch's rows (referenced by encode futures / raw
-        # memoryviews) are never rewritten before their sends complete.
-        self._fetcher: Optional[ShardedBatchFetcher] = None
-        self._egress_stats: Optional[EgressStats] = None
+        # The asynchronous codec plane: encode/send of batch k overlap
+        # the decode/H2D/compute of batch k+1, bounded by egress_depth
+        # batches in flight.
         self._plane: Optional[AsyncCodecPlane] = None
         self._egress_seq = 0
-        self._egress_degrade_reason: Optional[str] = None
         self.batch_size = batch_size
         self.assemble_timeout_s = assemble_timeout_s
         self.use_jpeg = use_jpeg
@@ -347,72 +335,32 @@ class TpuZmqWorker:
     def stop(self) -> None:
         self._stop.set()
 
+    def _compile(self, shape, dtype) -> None:
+        """The lane's compile step, ledgered: the worker's only
+        reconfigurations are engine compiles on a geometry change."""
+        before = self.engine.stats.compile_count
+        self.engine.ensure_compiled(shape, dtype)
+        if (self.ledger is not None
+                and self.engine.stats.compile_count != before):
+            from dvf_tpu.obs import ledger as ledger_mod
+
+            compile_ms = self.engine.last_compile_ms
+            sig_key = self.engine.signature_key
+            self.ledger.record(
+                ledger_mod.COMPILE,
+                cause=ledger_mod.CAUSE_ADMISSION,
+                signature=(sig_key.render()
+                           if sig_key is not None else None),
+                wall_ms=compile_ms,
+                compile_ms=(round(float(compile_ms), 3)
+                            if compile_ms is not None else None),
+                cache="miss")
+
     def _builder(self, h: int, w: int):
-        """Per-geometry streamed assembler (runtime/ingest.py) — the same
-        ingest implementation the pipeline and serving frontend use.
-        _process_batch is fully synchronous (np.asarray fetches the
-        result before the next batch is assembled), so a single staging
-        slot is enough: the slabs handed to the engine are never still in
-        flight when rewritten. JPEG mode decodes each frame in place via
-        the C shim — zero per-batch allocations, exactly like the old
-        single staging buffer."""
-        shape = (self.batch_size, h, w, 3)
-        if self._asm is None or self._asm.batch_shape != shape:
-            before = self.engine.stats.compile_count
-            self.engine.ensure_compiled(shape, np.uint8)
-            if (self.ledger is not None
-                    and self.engine.stats.compile_count != before):
-                from dvf_tpu.obs import ledger as ledger_mod
-
-                compile_ms = self.engine.last_compile_ms
-                sig_key = self.engine.signature_key
-                self.ledger.record(
-                    ledger_mod.COMPILE,
-                    cause=ledger_mod.CAUSE_ADMISSION,
-                    signature=(sig_key.render()
-                               if sig_key is not None else None),
-                    wall_ms=compile_ms,
-                    compile_ms=(round(float(compile_ms), 3)
-                                if compile_ms is not None else None),
-                    cache="miss")
-            self._ingest_stats = IngestStats(
-                requested_mode=self.ingest, depth=self.ingest_depth,
-                h2d_block_ms=self.engine.h2d_block_ms)
-            self._asm = ShardedBatchAssembler(
-                shape, np.uint8, self.engine.input_sharding,
-                mode=self.ingest, depth=self.ingest_depth, slots=1,
-                stats=self._ingest_stats, chaos=self.chaos)
-            if self._degrade_reason is not None:
-                self._ingest_stats.fallback_reason = self._degrade_reason
-        return self._asm.begin(0)
-
-    def _fetcher_for(self):
-        """Per-output-signature streamed-egress fetcher + shared stats
-        (runtime/egress.py). Slab pool is egress_depth + 1: the encode
-        plane holds at most egress_depth batches' rows in flight, so the
-        slab being rewritten always belongs to a batch whose sends
-        completed. Rebuilt when the signature changes (geometry
-        re-probe), releasing the old pool eagerly."""
-        shape = getattr(self.engine, "out_shape", None)
-        if shape is None:
-            return None
-        f = self._fetcher
-        if f is None or f.out_shape != tuple(shape):
-            self._egress_stats = EgressStats(
-                requested_mode=self.egress, depth=self.egress_depth,
-                d2h_block_ms=self.engine.d2h_block_ms)
-            if f is not None:
-                f.release()
-            self._fetcher = f = ShardedBatchFetcher(
-                shape, self.engine.out_dtype, self.engine.output_sharding,
-                mode=self.egress, slots=self.egress_depth + 1,
-                stats=self._egress_stats, chaos=self.chaos)
-            if self._egress_degrade_reason is not None:
-                self._egress_stats.fallback_reason = \
-                    self._egress_degrade_reason
-            if self._plane is not None:
-                self._plane.stats = self._egress_stats
-        return f
+        """One staged batch at the stream's geometry. JPEG mode decodes
+        each frame in place into the lane's staging slabs via the C shim
+        — zero per-batch allocations."""
+        return self._lane.begin((self.batch_size, h, w, 3), np.uint8, 0)
 
     def _plane_for(self):
         """The asynchronous codec plane, shared across batches: encodes
@@ -421,7 +369,13 @@ class TpuZmqWorker:
         if self._plane is None:
             self._plane = AsyncCodecPlane(
                 self.codec, jpeg=self.use_jpeg, depth=self.egress_depth,
-                stats=self._egress_stats, tracer=self.tracer)
+                tracer=self.tracer)
+        stats = self._lane.egress_sink()
+        if self._plane.stats is not stats:
+            # A new fetcher brought a new stats block: the plane reports
+            # into the current one, which carries the plane's window.
+            stats.depth = self.egress_depth
+            self._plane.stats = stats
         return self._plane
 
     def _pump_egress(self, pid: bytes, block: bool = False) -> None:
@@ -441,7 +395,7 @@ class TpuZmqWorker:
                     self.errors += 1
                     self.faults.record(FaultKind.TRANSPORT, err)
                     if (escalate(self._budget, FaultKind.TRANSPORT,
-                                 self._degrade) == ErrorBudget.FAIL):
+                                 self._lane.degrade) == ErrorBudget.FAIL):
                         raise FaultError(
                             FaultKind.TRANSPORT,
                             f"transport fault budget exhausted "
@@ -463,7 +417,7 @@ class TpuZmqWorker:
                     self.errors += 1
                     self.faults.record(FaultKind.TRANSPORT, e)
                     if (escalate(self._budget, FaultKind.TRANSPORT,
-                                 self._degrade) == ErrorBudget.FAIL):
+                                 self._lane.degrade) == ErrorBudget.FAIL):
                         raise FaultError(
                             FaultKind.TRANSPORT,
                             f"transport fault budget exhausted "
@@ -474,8 +428,8 @@ class TpuZmqWorker:
                           f"remainder): {e!r}", file=sys.stderr)
                     break  # at-most-once: drop this batch's tail
             t_done = time.perf_counter()
-            if self._egress_stats is not None:
-                self._egress_stats.record_send((t_done - t_send) * 1e3)
+            if plane.stats is not None:
+                plane.stats.record_send((t_done - t_send) * 1e3)
             if self.tracer is not None and self.tracer.enabled:
                 off = time.time() - time.perf_counter()
                 self.tracer.complete(EGRESS_SEND, t_send + off,
@@ -492,19 +446,17 @@ class TpuZmqWorker:
             self._pump_egress(pid, block=True)
 
     def _decode_jpeg(self, blobs, valid):
-        """Decode a JPEG batch chunk-by-chunk into the assembler's shard
+        """Decode a JPEG batch chunk-by-chunk into the lane's shard
         slabs, so each decoded chunk's H2D streams out under the decode
-        of the next; returns the finished (batch, resident) pair."""
-        if self._asm is None:
-            h, w = self.codec.probe(blobs[0])
-        else:
-            h, w = self._asm.batch_shape[1:3]
-        builder = self._builder(h, w)
+        of the next; returns the staged builder."""
+        if self._geometry is None:
+            self._geometry = self.codec.probe(blobs[0])
+        builder = self._builder(*self._geometry)
         for start, stop in builder.windows(valid):
             self.codec.decode_batch(blobs[start:stop],
                                     out=builder.window_view(start, stop))
             builder.commit_window(start, stop)
-        return builder.finish(valid)
+        return builder
 
     def _decode_wire(self, blobs, indices, valid):
         """Decode one codec-wire batch with DELTA resync recovery.
@@ -522,13 +474,12 @@ class TpuZmqWorker:
         abandoned with the assembler, and its sequence numbers are
         already consumed so it cannot be replayed). Loops because the
         recovered suffix can itself contain another fault; every
-        iteration strictly shrinks the batch. Returns
-        ``(batch, resident, indices, valid)`` — batch None when the
-        faults consumed everything (drop, counted, not fatal)."""
+        iteration strictly shrinks the batch. Returns ``(builder,
+        indices, valid)`` — builder None when the faults consumed
+        everything (drop, counted, not fatal)."""
         while True:
             try:
-                batch, resident = self._decode_jpeg(blobs, valid)
-                return batch, resident, indices, valid
+                return self._decode_jpeg(blobs, valid), indices, valid
             except DeltaWireError as de:
                 self.faults.record(FaultKind.TRANSPORT, de)
                 if (escalate(self._budget, FaultKind.TRANSPORT,
@@ -540,13 +491,12 @@ class TpuZmqWorker:
                         f"{self.fault_window_s:g}s); last: {de!r}",
                         fatal=True) from de
                 self.errors += 1
-                # Release the abandoned half-staged assembler eagerly
-                # (same rationale as the geometry re-probe: the failed
-                # attempt may hold in-flight shard transfers against the
-                # slot's slabs).
-                old, self._asm = self._asm, None
-                if old is not None:
-                    old.release()
+                # Release the abandoned half-staged batch eagerly (same
+                # rationale as the geometry re-probe: the failed attempt
+                # may hold in-flight shard transfers against the slot's
+                # slabs), and probe again.
+                self._geometry = None
+                self._lane.restage()
                 # A gap can only heal at a keyframe AFTER the failing
                 # row: the decoder already consumed the sequence numbers
                 # before it (replaying those deltas would just raise a
@@ -563,7 +513,7 @@ class TpuZmqWorker:
                 if start == 0:
                     print(f"[TpuZmqWorker] delta wire fault (dropping "
                           f"batch): {de!r}", file=sys.stderr)
-                    return None, None, indices, 0
+                    return None, indices, 0
                 print(f"[TpuZmqWorker] delta wire fault: dropping {start} "
                       f"frame(s) to the next keyframe: {de!r}",
                       file=sys.stderr)
@@ -599,9 +549,9 @@ class TpuZmqWorker:
                 # rule mangles that blob so the codec rejects it.
                 blobs = [self.chaos.corrupt("decode", b) for b in blobs]
             try:
-                batch, resident, indices, valid = self._decode_wire(
+                builder, indices, valid = self._decode_wire(
                     blobs, indices, valid)
-                if batch is None:
+                if builder is None:
                     return  # delta wire faults consumed the whole batch
             except JpegGeometryError as ge:
                 # Stream geometry changed (the app restarted with a new
@@ -622,16 +572,15 @@ class TpuZmqWorker:
                         f"(> {self.fault_budget} re-probes in "
                         f"{self.fault_window_s:g}s): {ge!r}",
                         fatal=True) from ge
-                # Release the abandoned half-staged assembler's slabs
+                # Release the abandoned half-staged batch's slabs
                 # explicitly: the raising frame's traceback pins the
                 # builder (and through it every slab) for the whole
                 # retry, doubling peak staging memory until GC otherwise.
-                old, self._asm = self._asm, None
-                if old is not None:
-                    old.release()
-                batch, resident = self._decode_jpeg(blobs, valid)
+                self._geometry = None
+                self._lane.restage()
+                builder = self._decode_jpeg(blobs, valid)
             except FaultError:
-                raise  # already classified (h2d from the assembler, chaos)
+                raise  # already classified (h2d from the lane, chaos)
             except Exception as e:  # noqa: BLE001 — corrupt JPEG stream:
                 # carry the decode kind into run()'s containment so the
                 # fault counters attribute it correctly.
@@ -647,18 +596,16 @@ class TpuZmqWorker:
                     raise FaultError(FaultKind.DECODE,
                                      f"raw frame reshape failed: {e!r}") from e
                 builder.write_row(row, frame)
-            batch, resident = builder.finish(valid)
-        # finish() padded to the compiled batch signature (static shapes —
-        # one compilation for every batch size; repeat-last keeps stateful
-        # temporal windows correct, see Filter.pad_safe) and, on the
-        # streamed path, already shipped every shard to its device.
         if self.delay_s > 0:
             # Fault injection: simulate a slow worker to exercise the app's
             # drop/reorder logic, like the reference's --delay
             # (inverter.py:37-38,55-56).
             time.sleep(self.delay_s)
-        result = (self.engine.submit_resident(batch) if resident
-                  else self.engine.submit(batch))
+        # submit pads to the compiled batch signature (static shapes —
+        # one compilation for every batch size; repeat-last keeps stateful
+        # temporal windows correct, see Filter.pad_safe), ships what is
+        # left of the shards and runs the step.
+        result = self._lane.submit(builder, valid)
         t_sub = time.time()  # decode+assemble+H2D end / device start
         # Device-side change detection (delta wire): the per-tile
         # max-abs-diff reduction is queued right behind the filter
@@ -696,43 +643,28 @@ class TpuZmqWorker:
                 print(f"[TpuZmqWorker] device delta probe failed "
                       f"(host fallback): {e!r}", file=sys.stderr)
                 self._probe = None
-        # Streamed egress: issue the per-shard D2H immediately, fetch into
-        # the preallocated slab, and hand the rows to the asynchronous
-        # codec plane — encode/send of THIS batch overlap the decode/H2D/
-        # compute of the next one (bounded at egress_depth batches). On
-        # the full-assist path there is no pixel fetch at all: the codec
-        # gathers dirty coefficient blocks lazily at encode time.
-        if coeffs is not None:
-            # No pixel slab pool on the coefficient wire — but the plane
-            # still needs its stats sink (encode_ms/entropy_ms land there).
-            fetcher = None
-            if self._egress_stats is None:
-                self._egress_stats = EgressStats(
-                    requested_mode=self.egress, depth=self.egress_depth,
-                    d2h_block_ms=self.engine.d2h_block_ms)
-                if self._plane is not None:
-                    self._plane.stats = self._egress_stats
-        else:
-            fetcher = self._fetcher_for()
-        if fetcher is not None:
-            result = fetcher.prefetch(result)
+        # Issue the D2H immediately, fetch, and hand the rows to the
+        # asynchronous codec plane — encode/send of THIS batch overlap the
+        # decode/H2D/compute of the next one (bounded at egress_depth
+        # batches). On the full-assist path there is no pixel fetch at
+        # all: the codec gathers dirty coefficient blocks lazily at
+        # encode time, and the device result is only waited for.
+        if coeffs is None:
+            result = self._lane.prefetch(result)
         t_ready = None
         try:
             # Device/D2H attribution split: the fetch below blocks on
             # compute AND transfer at once; this sync (which the fetch
             # would pay anyway) marks where compute ended.
-            import jax as _jax
-
-            _jax.block_until_ready(result)
+            if coeffs is None:
+                result.wait()
+            else:
+                result.block_until_ready()
             t_ready = time.time()
         except Exception:  # noqa: BLE001 — attribution must never turn
             pass           # a poisoned batch into a new failure mode
-        if coeffs is not None:
-            out = None  # coefficient wire: no host pixel batch exists
-        elif fetcher is not None:
-            out = fetcher.fetch(result, self._egress_seq)
-        else:
-            out = np.asarray(result)
+        # coefficient wire: no host pixel batch exists
+        out = result.fetch(self._egress_seq) if coeffs is None else None
         self._egress_seq += 1
         t1 = time.time()
         comps = {"assemble_h2d": (t_sub - t0) * 1e3}
@@ -927,7 +859,7 @@ class TpuZmqWorker:
                 kind = classify(e, site="worker")
                 self.faults.record(kind, e)
                 if escalate(self._budget, kind,
-                            self._degrade) != ErrorBudget.CONTAIN:
+                            self._lane.degrade) != ErrorBudget.CONTAIN:
                     raise FaultError(
                         kind,
                         f"error budget exhausted for {kind!r} faults "
@@ -961,7 +893,7 @@ class TpuZmqWorker:
         window this degradation buys; a peer that stays corrupt through
         a second window still fails hard — the PR 4 ladder semantics
         (degrade = shrink OUR delta surface, not cure the peer).
-        Deliberately NOT part of ``_degrade``: the generic transport
+        Deliberately NOT part of the lane's ladder: the generic transport
         ladder also counts send and encode failures (dead collector),
         whose overflow must keep FAILING loudly — pessimizing a healthy
         delta wire would be the wrong remedy and would absorb that
@@ -972,32 +904,6 @@ class TpuZmqWorker:
             print("[TpuZmqWorker] repeated delta wire faults: degrading "
                   "to full-frame JPEG (keyframe-only)",
                   file=sys.stderr, flush=True)
-            return True
-        return False
-
-    def _degrade(self, kind: str) -> bool:
-        """First-overflow degradation: repeated h2d faults fall back from
-        streamed to monolithic ingest (reason recorded in the ingest
-        stats), mirroring the pipeline/serve ladder. Other kinds have no
-        degraded mode here — the budget fails them (delta wire faults
-        degrade through ``_degrade_delta``, not this ladder)."""
-        if kind == FaultKind.H2D and self.ingest == "streamed":
-            self.ingest = "monolithic"
-            self._degrade_reason = "h2d_fault_budget"
-            old, self._asm = self._asm, None
-            if old is not None:
-                old.release()
-            print("[TpuZmqWorker] repeated h2d faults: degrading ingest "
-                  "streamed → monolithic", file=sys.stderr, flush=True)
-            return True
-        if kind == FaultKind.D2H and self.egress == "streamed":
-            self.egress = "monolithic"
-            self._egress_degrade_reason = "d2h_fault_budget"
-            old, self._fetcher = self._fetcher, None
-            if old is not None:
-                old.release()
-            print("[TpuZmqWorker] repeated d2h faults: degrading egress "
-                  "streamed → monolithic", file=sys.stderr, flush=True)
             return True
         return False
 
@@ -1016,11 +922,7 @@ class TpuZmqWorker:
                             if self._ring is not None else None),
             "trace_dropped_total": float(self.tracer.dropped),
         }
-        ing, egr = self._ingest_stats, self._egress_stats
-        if ing is not None:
-            out["ingest_overlap_efficiency"] = ing.overlap_efficiency()
-        if egr is not None:
-            out["egress_overlap_efficiency"] = egr.overlap_efficiency()
+        out.update(self._lane.signals())
         attr = self.attribution.summary()
         for comp, row in (attr.get("components") or {}).items():
             out[f"attr_{comp}_p99_ms"] = row["p99_ms"]
@@ -1079,10 +981,7 @@ class TpuZmqWorker:
                 **({"explain": self.attribution.explain()}
                    if self.attribution.count else {}),
             },
-            **({"ingest": self._ingest_stats.summary()}
-               if self._ingest_stats is not None else {}),
-            **({"egress": self._egress_stats.summary()}
-               if self._egress_stats is not None else {}),
+            **self._lane.stats(),
             **({"audit": self.audit_document()}
                if self._wire_in is not None else {}),
             **({"ledger": self.ledger.summary()}

@@ -203,8 +203,10 @@ class TestPackedTransferLayout:
             np.testing.assert_array_equal(out, np.asarray(result))
             assert not out.flags.writeable  # the landed buffer, viewed
             assert not f.owns(out)
-            # Any collect path's np.asarray fallback unpacks it too.
-            np.testing.assert_array_equal(np.asarray(handle), frames)
+            # What the lane's in-flight handle waits on, and its span's
+            # layout: the words, not a disguise of the result.
+            assert egress_mod.device_side(handle) == (handle.words,
+                                                      "u32rows")
         s = f.stats.summary()
         assert s["batches"] == 3 and s["packed_batches"] == 3
         assert s["copy_ms_total"] == 0.0
@@ -230,6 +232,7 @@ class TestPackedTransferLayout:
         result = jax.device_put(x, dev)
         handle = f.prefetch(result)
         assert handle is result
+        assert egress_mod.device_side(handle) == (result, "plain")
         out = f.fetch(handle, 0)
         np.testing.assert_array_equal(out, x)
         assert f.owns(out)
@@ -621,7 +624,10 @@ def test_serve_streamed_matches_monolithic():
 def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
                   trace=False):
     """One tenant through a one-device frontend (the one-chip replica's
-    shape): the fetcher packs. Returns (frames, deliveries, stats, fe)."""
+    shape): the fetcher packs. Returns (frames, deliveries, stats, fe).
+    With ``resize_to`` the session stays busy until the resize has
+    committed and a few batches went through the successor, so
+    ``frames`` may come back longer than ``n_frames``."""
     from dvf_tpu.serve import ServeConfig, ServeFrontend
 
     filt = get_filter("invert")
@@ -640,6 +646,21 @@ def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
                 assert fe.request_batch_size(label, resize_to)
             if resize_to is not None:
                 time.sleep(0.002)
+        if resize_to is not None:
+            # The successor compiles aside on another thread, for longer
+            # than the frames above take: keep batches in flight until
+            # its commit has landed (so it swings under them), then push
+            # a few batches through the successor.
+            rng = np.random.default_rng(12)
+            after_swap = 4 * resize_to
+            deadline = time.time() + 120.0
+            while after_swap and time.time() < deadline:
+                after_swap -= fe.stats()["swaps"] >= 1
+                frames.append(rng.integers(0, 255, (16, 24, 3), np.uint8))
+                fe.submit(sid, frames[-1])
+                got.extend(fe.poll(sid))
+                time.sleep(0.002)
+            n_frames = len(frames)
         fe.close(sid, drain=True)
         deadline = time.time() + 30.0
         while time.time() < deadline and len(got) < n_frames:
@@ -698,6 +719,7 @@ def test_serve_packed_layout_across_a_hot_swap():
     batches unpack on their own, the successor packs at the new shape;
     delivery stays ordered and bit-exact."""
     frames, got, stats, fe = _serve_packed(n_frames=48, resize_to=2)
+    assert len(got) == len(frames) >= 48
     for d, src in zip(got, frames):
         np.testing.assert_array_equal(d.frame, 255 - src)
     assert stats["swaps"] >= 1 and stats["swap_aborts"] == 0
@@ -706,8 +728,8 @@ def test_serve_packed_layout_across_a_hot_swap():
     eg = row["egress"]  # the successor's stats
     assert eg["transfer_layout"] == "u32rows" and eg["pool_allocs"] == 0
     assert eg["packed_batches"] == eg["batches"] >= 1
-    assert all(f.slab_bytes() == 0 for b in fe._buckets
-               for f in [b.fetcher, *b.draining_fetchers] if f is not None)
+    assert all(b.lane.slab_bytes() == 0 for b in fe._buckets)
+    assert all(f.slab_bytes() == 0 for f in egress_mod.live_fetchers())
 
 
 def test_serve_trace_spans_name_the_layout():
@@ -989,3 +1011,256 @@ class TestEgressChaos:
         assert stats["faults"]["by_kind"].get("stall", 0) >= 1
         assert stats["delivered"] > 0
         assert stats["egress"]["mode"] == "streamed"
+
+
+# ---------------------------------------------------------------------------
+# The device lane (runtime/lane.py), egress side: who decides when a
+# fetcher is built, parked, degraded and freed
+# ---------------------------------------------------------------------------
+
+
+def _lane(cfg=MeshConfig(data=1), inflight=2, **options):
+    import types
+
+    from dvf_tpu.runtime.lane import DeviceLane
+
+    opts = types.SimpleNamespace(**{"ingest": "streamed", "ingest_depth": 4,
+                                    "egress": "streamed", **options})
+    eng = Engine(get_filter("invert"), mesh=make_mesh(cfg))
+    return DeviceLane(eng, opts, inflight), eng, opts
+
+
+def _through(lane, frames, seq):
+    """One batch through the lane: (the frames, its in-flight handle)."""
+    builder = lane.begin(frames.shape, frames.dtype, seq)
+    for row, f in enumerate(frames):
+        builder.write_row(row, f)
+    return lane.prefetch(lane.submit(builder, len(frames)))
+
+
+class TestDeviceLaneEgress:
+
+    def test_fetcher_is_rebuilt_on_a_new_output_signature_or_mode(self):
+        lane, eng, opts = _lane()
+        a = np.stack(_rng_frames(4, 16, 24, seed=1))
+        np.testing.assert_array_equal(_through(lane, a, 0).fetch(0), 255 - a)
+        f1, s1 = lane._fetcher, lane.egress_stats
+        assert f1.out_shape == (4, 16, 24, 3) and f1.slots == 3
+        assert s1.d2h_block_ms == eng.d2h_block_ms is not None
+        _through(lane, a, 1).fetch(1)
+        assert lane._fetcher is f1 and lane.egress_stats is s1  # kept
+        b = np.stack(_rng_frames(2, 16, 24, seed=2))  # a new signature
+        np.testing.assert_array_equal(_through(lane, b, 2).fetch(2), 255 - b)
+        f2 = lane._fetcher
+        assert f2 is not f1 and f2.out_shape == (2, 16, 24, 3)
+        assert lane.egress_stats is not s1 and lane.egress_stats.batches == 1
+        opts.egress = "monolithic"  # a planned mode reaches the lane
+        np.testing.assert_array_equal(_through(lane, b, 3).fetch(3), 255 - b)
+        assert lane._fetcher is not f2
+        assert lane.egress_stats.summary()["mode"] == "monolithic"
+        assert lane.egress_stats.requested_mode == "monolithic"
+
+    def test_handle_pins_its_fetcher_across_retarget(self):
+        """A hot swap retargets the lane with batches in flight: each
+        comes back through the fetcher its transfer was issued on, and
+        that fetcher's slabs go with the last of them, not before."""
+        lane, eng, _ = _lane(MeshConfig(data=2))  # two shards: slabs
+        x = [np.stack(_rng_frames(4, 16, 24, seed=s)) for s in range(3)]
+        h0, h1 = _through(lane, x[0], 0), _through(lane, x[1], 1)
+        old = lane._fetcher
+        assert old.slab_bytes() > 0 and h0.layout == "plain"
+        lane.retarget(eng)
+        assert lane._fetcher is None and lane._assembler is None
+        assert old.slab_bytes() > 0  # parked: two batches pin it
+        assert lane.slab_bytes() == old.slab_bytes()
+        h2 = _through(lane, x[2], 2)
+        new = lane._fetcher
+        assert new is not old and new.slab_bytes() > 0
+        out0 = h0.fetch(0)
+        assert h0.owns(out0) and old.owns(out0)
+        np.testing.assert_array_equal(out0, 255 - x[0])
+        assert old.slab_bytes() > 0  # one batch still pins it
+        np.testing.assert_array_equal(h1.fetch(1), 255 - x[1])
+        assert old.slab_bytes() == 0  # freed with its last batch
+        np.testing.assert_array_equal(out0, 255 - x[0])  # rows stay valid
+        np.testing.assert_array_equal(h2.fetch(2), 255 - x[2])
+        assert new.slab_bytes() > 0  # the live fetcher keeps its pool
+        assert lane.slab_bytes() == (new.slab_bytes()
+                                     + lane._assembler.slab_bytes())
+
+    def test_an_idle_fetcher_is_freed_at_retarget(self):
+        lane, eng, _ = _lane(MeshConfig(data=2))
+        x = np.stack(_rng_frames(4, 16, 24))
+        _through(lane, x, 0).fetch(0)
+        old = lane._fetcher
+        lane.retarget(eng)
+        assert old.slab_bytes() == 0 and lane.slab_bytes() == 0
+
+    def test_shed_batches_do_not_pin_a_parked_fetcher(self):
+        """A recovery drops in-flight handles unfetched: the parked
+        fetcher must die with them, not live on in the lane."""
+        import gc
+        import weakref
+
+        lane, eng, _ = _lane(MeshConfig(data=2))
+        shed = _through(lane, np.stack(_rng_frames(4, 16, 24)), 0)
+        ref = weakref.ref(lane._fetcher)
+        lane.retarget(eng)
+        assert ref() is not None and ref().slab_bytes() > 0
+        del shed
+        gc.collect()
+        assert ref() is None and lane.slab_bytes() == 0
+
+    def test_degrade_d2h_applies_once_and_is_recorded(self, capsys):
+        lane, eng, _ = _lane(MeshConfig(data=2))
+        x = np.stack(_rng_frames(4, 16, 24, seed=4))
+        inflight = _through(lane, x, 0)
+        streamed = lane._fetcher
+        assert lane.egress_stats.summary()["mode"] == "streamed"
+        assert lane.degrade("compute") is False  # not a transfer fault
+        assert lane.degrade("d2h") is True
+        assert "repeated d2h faults" in capsys.readouterr().err
+        assert streamed.slab_bytes() == 0  # freed at once
+        assert lane.degrade("d2h") is False  # once
+        np.testing.assert_array_equal(inflight.fetch(0), 255 - x)
+        np.testing.assert_array_equal(_through(lane, x, 1).fetch(1), 255 - x)
+        s = lane.egress_stats.summary()
+        assert s["mode"] == "monolithic"
+        assert s["requested_mode"] == "streamed"
+        assert s["fallback_reason"] == "d2h_fault_budget"
+        assert lane.degrade("h2d") is True  # the other side's, untouched
+
+    def test_degrade_needs_a_streamed_request(self):
+        lane, _, _ = _lane(egress="monolithic")
+        assert lane.degrade("d2h") is False
+
+    def test_release_frees_both_sides_and_the_lane_rebuilds(self):
+        import gc
+
+        from dvf_tpu.runtime import ingest as ingest_mod
+
+        gc.collect()
+        base = (egress_mod.occupied_slab_bytes(),
+                ingest_mod.occupied_slab_bytes())
+        lane, eng, _ = _lane(MeshConfig(data=2))
+        x = np.stack(_rng_frames(4, 16, 24, seed=6))
+        parked = _through(lane, x, 0)
+        lane.retarget(eng)  # one parked fetcher, one live
+        live = _through(lane, x, 1)
+        assert egress_mod.occupied_slab_bytes() > base[0]
+        assert ingest_mod.occupied_slab_bytes() > base[1]
+        lane.release()
+        assert lane.slab_bytes() == 0
+        assert egress_mod.occupied_slab_bytes() <= base[0]
+        assert ingest_mod.occupied_slab_bytes() <= base[1]
+        # Batches in flight fall back to a per-batch fetch.
+        np.testing.assert_array_equal(parked.fetch(0), 255 - x)
+        np.testing.assert_array_equal(live.fetch(1), 255 - x)
+        np.testing.assert_array_equal(_through(lane, x, 2).fetch(2), 255 - x)
+        assert lane.slab_bytes() > 0
+        lane.release()
+
+    def test_bad_modes_are_rejected_when_the_lane_is_built(self):
+        with pytest.raises(ValueError, match="egress"):
+            _lane(egress="bogus")
+        with pytest.raises(ValueError, match="ingest"):
+            _lane(ingest="bogus")
+
+
+@pytest.mark.parametrize("host", ["serve", "pipeline", "worker"])
+def test_lane_contract_through_its_hosts(host, monkeypatch):
+    """Whoever hosts it, the lane is all that stands between frames and
+    the engine: the host builds no assembler or fetcher of its own, its
+    ``ingest`` / ``egress`` stats blocks are the lane's, a degradation
+    applied to the lane is what the host then runs and reports, and the
+    frames come back bit-exact throughout."""
+    from dvf_tpu.runtime import ingest as ingest_mod
+    from dvf_tpu.runtime import lane as lane_mod
+
+    monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    built = []
+    for name in ("ShardedBatchAssembler", "ShardedBatchFetcher"):
+        real = getattr(lane_mod, name)
+        monkeypatch.setattr(
+            lane_mod, name,
+            lambda *a, _real=real, **kw: built.append(_real(*a, **kw))
+            or built[-1])
+    filt = get_filter("invert")
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    frames = _rng_frames(16, 16, 16, seed=8)
+    got = {}
+    if host == "serve":
+        from dvf_tpu.serve import ServeConfig, ServeFrontend
+
+        fe = ServeFrontend(filt, ServeConfig(batch_size=4, max_inflight=2,
+                                             queue_size=64, slo_ms=60_000.0),
+                           engine=engine)
+        lane = fe._buckets[0].lane
+        assert lane.degrade("d2h")
+        with fe:
+            sid = fe.open_stream()
+            for f in frames:
+                fe.submit(sid, f)
+            fe.close(sid, drain=True)
+            deadline = time.time() + 30.0
+            while time.time() < deadline and len(got) < len(frames):
+                got.update((d.index, d.frame) for d in fe.poll(sid))
+                time.sleep(0.005)
+            stats = fe.stats()
+        assert lane.slab_bytes() == 0  # stop() released it
+    elif host == "pipeline":
+        class _Sink(NullSink):
+            def emit(self, idx, frame, ts):
+                got[idx] = np.array(frame)
+
+        pipe = Pipeline(
+            ((f, time.time()) for f in frames), filt, _Sink(),
+            PipelineConfig(batch_size=4, max_inflight=2, queue_size=64,
+                           frame_delay=0), engine=engine)
+        lane = pipe._lane
+        assert lane.degrade("d2h")
+        stats = pipe.run()
+    else:
+        pytest.importorskip("zmq")
+        from dvf_tpu.transport.zmq_ingress import TpuZmqWorker
+
+        worker = TpuZmqWorker(filt, engine=engine, batch_size=4,
+                              use_jpeg=False, raw_size=16, egress_depth=2)
+
+        class _StubPush:
+            def send_multipart(self, parts):
+                got[int(parts[0].decode())] = np.frombuffer(
+                    bytes(parts[4]), np.uint8).reshape(16, 16, 3)
+
+            def close(self, *a):
+                pass
+
+        worker.push.close(0)
+        worker.push = _StubPush()
+        lane = worker._lane
+        assert lane.degrade("d2h")
+        try:
+            for b in range(0, len(frames), 4):
+                worker._process_batch(
+                    [(b + i, frames[b + i].tobytes()) for i in range(4)],
+                    b"pid")
+            worker.drain_egress(b"pid")
+            stats = worker.stats()
+        finally:
+            worker.close()
+    assert sorted(got) == list(range(len(frames)))
+    for i, f in enumerate(frames):
+        np.testing.assert_array_equal(got[i], 255 - f)
+    # Built by the lane and nobody else: one assembler, one fetcher.
+    assert [type(o).__name__ for o in built] == [
+        "ShardedBatchAssembler", "ShardedBatchFetcher"]
+    assert stats["ingest"] == lane.ingest_stats.summary()
+    assert stats["egress"] == lane.egress_stats.summary()
+    assert stats["ingest"]["mode"] == "streamed"
+    assert stats["ingest"]["batches"] == len(frames) // 4
+    assert stats["egress"]["mode"] == "monolithic"
+    assert stats["egress"]["requested_mode"] == "streamed"
+    assert stats["egress"]["fallback_reason"] == "d2h_fault_budget"
+    assert stats["egress"]["batches"] == len(frames) // 4
+    lane.release()
+    assert lane.slab_bytes() == 0

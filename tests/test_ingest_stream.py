@@ -654,3 +654,125 @@ def test_batcher_default_staging_is_bounded(monkeypatch):
         monkeypatch.setattr(np, "empty", counter.real)
     assert len(set(seen)) <= 2          # bounded ring, cycled
     assert len(counter.big) <= 2, counter.big  # built once, reused
+
+
+# ---------------------------------------------------------------------------
+# The device lane (runtime/lane.py), ingest side: who decides when an
+# assembler is built, degraded and freed
+# ---------------------------------------------------------------------------
+
+
+def _lane(cfg=MeshConfig(data=1), inflight=2, **kw):
+    import types
+
+    from dvf_tpu.runtime.lane import DeviceLane
+
+    opts = types.SimpleNamespace(ingest="streamed", ingest_depth=4,
+                                 egress="streamed")
+    eng = Engine(get_filter("invert"), mesh=make_mesh(cfg))
+    return DeviceLane(eng, opts, inflight, **kw), eng, opts
+
+
+def _run(lane, frames, seq, valid=None):
+    builder = lane.begin(frames.shape, frames.dtype, seq)
+    for row, f in enumerate(frames[:valid]):
+        builder.write_row(row, f)
+    return lane.prefetch(
+        lane.submit(builder, len(frames) if valid is None else valid)
+    ).fetch(seq)
+
+
+class TestDeviceLaneIngest:
+
+    def test_assembler_is_rebuilt_on_signature_depth_or_mode(self):
+        compiled = []
+        lane, eng, opts = _lane(
+            compile=lambda shape, dtype: (compiled.append(shape),
+                                          eng.ensure_compiled(shape, dtype)))
+        a = np.stack(_rng_frames(4, 16, 24, seed=1))
+        np.testing.assert_array_equal(_run(lane, a, 0), 255 - a)
+        a1, s1 = lane._assembler, lane.ingest_stats
+        assert a1.batch_shape == (4, 16, 24, 3) and a1.slots == 3
+        assert (a1.depth, s1.depth) == (4, 4)
+        assert s1.h2d_block_ms == eng.h2d_block_ms is not None
+        assert compiled == [(4, 16, 24, 3)]  # the caller's compile step
+        _run(lane, a, 1)
+        assert lane._assembler is a1 and lane.ingest_stats is s1  # kept
+        assert len(compiled) == 1
+        b = np.stack(_rng_frames(2, 16, 24, seed=2))  # a new signature
+        np.testing.assert_array_equal(_run(lane, b, 2), 255 - b)
+        a2 = lane._assembler
+        assert a2 is not a1 and a2.batch_shape == (2, 16, 24, 3)
+        assert a1.slab_bytes() == 0  # the old one's slabs went
+        assert eng.signature[0] == (2, 16, 24, 3)
+        opts.ingest_depth = 2  # a planned depth reaches the lane
+        np.testing.assert_array_equal(_run(lane, b, 3), 255 - b)
+        a3 = lane._assembler
+        assert a3 is not a2 and (a3.depth, lane.ingest_stats.depth) == (2, 2)
+        assert lane.ingest_stats.batches == 1  # a fresh block
+        opts.ingest = "monolithic"  # and a planned mode
+        np.testing.assert_array_equal(_run(lane, b, 4), 255 - b)
+        assert lane._assembler is not a3
+        assert lane.ingest_stats.summary()["mode"] == "monolithic"
+        assert len(compiled) == 4  # asked at every rebuild, never between
+
+    def test_padded_batch_and_float_dtype_key(self):
+        lane, _, _ = _lane()
+        a = np.stack(_rng_frames(4, 16, 24, seed=3))
+        out = _run(lane, a, 0, valid=3)
+        np.testing.assert_array_equal(out[:3], 255 - a[:3])
+        np.testing.assert_array_equal(out[3], 255 - a[2])  # repeat-last
+        first = lane._assembler
+        lane.begin(a.shape, np.float32, 1)  # same shape, another dtype
+        assert lane._assembler is not first
+        assert lane._assembler.dtype == np.float32
+
+    def test_staging_slots_follow_the_callers_bound(self):
+        lane, _, _ = _lane(inflight=2, staging_inflight=0)  # the worker's
+        a = np.stack(_rng_frames(4, 16, 24))
+        _run(lane, a, 0)
+        assert lane._assembler.slots == 1 and lane._fetcher.slots == 3
+
+    def test_degrade_h2d_applies_once_and_is_recorded(self, capsys):
+        lane, _, _ = _lane(name=lambda: "host X")
+        a = np.stack(_rng_frames(4, 16, 24, seed=5))
+        _run(lane, a, 0)
+        streamed = lane._assembler
+        assert lane.ingest_stats.summary()["mode"] == "streamed"
+        assert lane.degrade("oom") is False  # not a transfer fault
+        assert lane.degrade("h2d") is True
+        assert "[host X] repeated h2d faults: degrading ingest" \
+            in capsys.readouterr().err
+        assert lane._assembler is None and streamed.slab_bytes() == 0
+        assert lane.degrade("h2d") is False  # once
+        np.testing.assert_array_equal(_run(lane, a, 1), 255 - a)
+        s = lane.ingest_stats.summary()
+        assert s["mode"] == "monolithic"
+        assert s["requested_mode"] == "streamed"
+        assert s["fallback_reason"] == "h2d_fault_budget"
+        # A rebuild at another signature stays degraded.
+        b = np.stack(_rng_frames(2, 16, 24))
+        _run(lane, b, 2)
+        assert lane.ingest_stats.fallback_reason == "h2d_fault_budget"
+
+    def test_restage_and_release_free_the_slabs(self):
+        import gc
+
+        gc.collect()
+        base = ingest_mod.occupied_slab_bytes()
+        lane, _, _ = _lane()
+        a = np.stack(_rng_frames(4, 16, 24, seed=7))
+        half = lane.begin(a.shape, a.dtype, 0)
+        half.write_row(0, a[0])  # a batch abandoned mid-staging
+        asm = lane._assembler
+        assert asm.slab_bytes() > 0
+        assert ingest_mod.occupied_slab_bytes() > base
+        lane.restage()
+        assert asm.slab_bytes() == 0 and lane._assembler is None
+        np.testing.assert_array_equal(_run(lane, a, 1), 255 - a)
+        assert lane.slab_bytes() > 0
+        lane.release()
+        assert lane.slab_bytes() == 0
+        assert ingest_mod.occupied_slab_bytes() <= base
+        np.testing.assert_array_equal(_run(lane, a, 2), 255 - a)
+        lane.release()

@@ -397,7 +397,7 @@ class TestServeLedger:
                 or doc["device_live_bytes"] >= 0
         # Stop released every slab this frontend pinned.
         gc.collect()
-        assert fe._host_slab_bytes() == 0
+        assert sum(b.lane.slab_bytes() for b in fe._buckets) == 0
         # And nothing of this frontend's remains in the registries.
         assert all(a.slab_bytes() == 0 for a in ingest.live_assemblers())
         assert all(f.slab_bytes() == 0 for f in egress.live_fetchers())

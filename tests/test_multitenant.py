@@ -503,7 +503,8 @@ class TestPoolAndRetireHardening:
             fe.submit(a, np.zeros((H, W, 3), np.uint8))
             assert len(drain_session(fe, a, 1)) == 1
             bucket = fe._session(a).bucket
-            assert bucket.assembler is not None
+            assert bucket.lane._assembler is not None
+            assert bucket.lane.slab_bytes() > 0
             fe.close(a, drain=True)
             deadline = time.time() + 20
             while fe.open_count() > 0 and time.time() < deadline:
@@ -512,7 +513,9 @@ class TestPoolAndRetireHardening:
             b = fe.open_stream(op_chain="grayscale",
                                frame_shape=(H + 8, W, 3))
             assert fe._session(b).bucket is not bucket
-            assert bucket.assembler is None and bucket.fetcher is None
+            assert bucket.lane._assembler is None
+            assert bucket.lane._fetcher is None
+            assert bucket.lane.slab_bytes() == 0
             # The retired session still drains through its reference.
             assert fe.poll(a) == []
 

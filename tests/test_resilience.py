@@ -243,7 +243,7 @@ def test_geometry_reprobe_releases_slabs_and_counts_fault(rng):
     results: dict = {}
     # Phase 1: the 16x16 stream — pins the first assembler geometry.
     assert serve(0, 2) == 2
-    old_asm = worker._asm  # the 16x16-geometry assembler
+    old_asm = worker._lane._assembler  # the 16x16-geometry assembler
     # Phase 2: the stream switches to 24x24 → JpegGeometryError → re-probe.
     assert serve(2, 4) == 2
     worker.stop()
@@ -254,9 +254,9 @@ def test_geometry_reprobe_releases_slabs_and_counts_fault(rng):
     assert worker.faults.summary()["by_kind"] == {"geometry": 1}
     assert worker.errors == 0  # successful containment, not an error
     # … and the abandoned assembler's staging buffers were freed eagerly.
-    assert old_asm is not None and old_asm is not worker._asm
+    assert old_asm is not None and old_asm is not worker._lane._assembler
     assert old_asm._chunks == [] and old_asm._mono_pool is None
-    assert worker._asm.batch_shape == (2, 24, 24, 3)
+    assert worker._lane._assembler.batch_shape == (2, 24, 24, 3)
     # Numerics survive the re-probe: results decode to the inverted input.
     for i, frame in enumerate(small + large):
         h, w = codec.probe(results[i])
