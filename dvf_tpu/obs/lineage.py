@@ -50,10 +50,13 @@ queue_ingress   capture/submit → drained into the scheduler's pending
                 staging (session ingress queue wait, incl. the client's
                 capture→submit gap)
 queue_bucket    pending → chosen into a batch (``select_bucket``
-                returned): waiting to be picked by the EDF/cost scheduler
+                returned): waiting to be picked by the EDF/cost
+                scheduler, and, for fewer frames than a batch, for the
+                device's backlog to run out (serve/batcher.py)
 permit_wait     chosen → in-flight permit acquired: the batch is FROZEN
                 (later arrivals cannot join it) and waits for a device
-                slot — where padded batches come from
+                slot (full batches only: a short one is bound when the
+                device has nothing left to run)
 assemble_h2d    permit → ``Engine.submit`` returned (batch assembly +
                 host-to-device transfer)
 inflight_wait   submit returned → the collect thread took the batch off

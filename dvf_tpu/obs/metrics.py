@@ -605,8 +605,10 @@ class ThreadClock:
     ``accounted_to − started``. ``idle`` is whatever belongs to no
     bucket (no plan and sleeping a tick, control actions, an empty
     in-flight queue, a batch that was shed); the named states are the
-    batch intervals the bucket's :class:`StageStats` also holds, summed
-    here over every bucket the frontend ever had. Single writer (the
+    batch intervals the bucket's :class:`StageStats` also holds, and the
+    dispatch thread's ``hold`` (ticks on which a bucket's short batch
+    waited for the device's backlog: the bucket row's ``hold`` block),
+    summed here over every bucket the frontend ever had. Single writer (the
     thread itself); a replacement thread (supervised recovery) adopts
     its predecessor's clock, so the ledger spans the frontend's life."""
 
@@ -615,11 +617,13 @@ class ThreadClock:
         self.mark = self.started      # everything before it is accounted
         self.ms: Dict[str, float] = {"idle": 0.0, **{s: 0.0 for s in states}}
 
-    def spend(self, state: str, until: float) -> None:
+    def spend(self, state: str, until: float) -> float:
         """Everything since the last accounted instant, up to ``until``
-        (a stamp the caller already took), was ``state``."""
-        self.ms[state] += (until - self.mark) * 1e3
+        (a stamp the caller already took), was ``state``: the ms booked."""
+        ms = (until - self.mark) * 1e3
+        self.ms[state] += ms
         self.mark = until
+        return ms
 
     def successor(self) -> "ThreadClock":
         """The ledger a replacement thread carries on from."""

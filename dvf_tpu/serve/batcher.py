@@ -38,6 +38,23 @@ Scheduling policy (the genuinely new multi-tenant part):
   starved small bucket's headroom shrinks every tick while the big
   bucket's stays refreshed, so the small bucket always wins before its
   deadline passes (fairness pinned in tests/test_multitenant.py).
+- **A short batch waits for the device, not in it.** The program costs
+  the same step whether one row or all of them are real, and the chip
+  runs batches in submit order: dispatching a batch earlier never makes
+  its frames START earlier, it only freezes who rides together and puts
+  a whole padded step in front of every later frame. So while the
+  device still has submitted work to run (``may_go_short=False``: the
+  caller's newest in-flight batch is not ready), ``select_bucket`` binds
+  the picked bucket only if it fills a batch; fewer frames stay in
+  ``pending``, where they age and shed as ever and later arrivals join
+  them. Once the backlog has run out whatever is there goes at once (an
+  idle chip never waits for company), and a full batch always goes, as
+  deep as the in-flight window allows. A held bucket costs no device
+  time, so no other bucket is promoted past it. "Run out" is read one
+  staging ahead (:class:`DeviceBacklog`): the newest batch started when
+  the one before it was seen ready, its program's last batch says how
+  long it takes, so the held set is bound when the device is due to
+  fall idle by the time it is staged, not after.
 - **Temporal state follows the session, not the batch.** For a filter
   with per-session state each plan carries a row map (``BatchPlan.rows``):
   the state row of the session each batch row belongs to, -1 on pad
@@ -101,6 +118,72 @@ class BatchPlan:
     #   int32 [2, batch] row map Engine.submit takes (runtime.engine.
     #   device_row_map) — state row per batch row (-1 = pad), fresh
     #   mark per row. None for every other filter.
+
+
+def _seen_ready(handle) -> bool:
+    try:
+        return handle.is_ready()
+    except Exception:  # noqa: BLE001 — a poisoned batch holds nothing
+        return True    # up: the collect side's containment takes it
+
+
+class DeviceBacklog:
+    """What a dispatch thread can tell of the device's queue from the
+    batches it put there: ``select_bucket``'s ``may_go_short``.
+
+    The chip runs batches in submit order, so it has work left iff the
+    newest batch queued is not ready (``handle.is_ready()``, the lane's
+    ``InflightBatch``; one that raises counts as ready). That batch
+    started when the one before it was seen ready, or at its own submit
+    onto an idle device; with what its program's last batch took of the
+    device (``device_ms``: a measurement, or None while there is none)
+    that says when the backlog is due to run out, so a batch may be
+    bound one staging before that and be staged under the step's tail
+    instead of leaving the device idle meanwhile. Every start is an
+    observation, never a forecast built on a forecast: an error does
+    not outlive its batch, and binding a little early costs nothing but
+    the freeze. ``generation`` is whatever the caller's batches live and
+    die with (the frontend's permit semaphore: a supervised recovery
+    replaces it and writes the window off): batches of another one hold
+    nothing up. Single-threaded: the dispatch thread's.
+    """
+
+    def __init__(self):
+        self._staging_s = 0.0   # the last batch's, permit to submit
+        self._generation = None
+        self.clear()
+
+    def clear(self) -> None:
+        """Nothing of ours is on the device (or it was all written off)."""
+        self._newest = self._before = None
+        self._free_at = self._device_s = float("inf")
+
+    def queued(self, handle, generation, t_permit: float, t_submit: float,
+               device_ms: Optional[float]) -> None:
+        """``handle``'s batch was staged from ``t_permit`` and submitted
+        at ``t_submit``; its program's last batch took ``device_ms``."""
+        self._generation = generation
+        self._staging_s = t_submit - t_permit
+        self._before, self._newest = self._newest, handle
+        self._device_s = device_ms / 1e3 if device_ms else float("inf")
+        self._free_at = (t_submit + self._device_s
+                         if self._before is None else float("inf"))
+
+    def may_go_short(self, now: float, generation=None) -> bool:
+        """One poll a tick. True when the device has nothing left to
+        run, or will have nothing by the time a batch bound ``now`` is
+        staged (it takes what the last one took)."""
+        if generation is not self._generation:
+            self.clear()
+        if self._newest is None:
+            return True
+        if _seen_ready(self._newest):
+            self.clear()
+            return True
+        if self._before is not None and _seen_ready(self._before):
+            self._before = None     # the newest one runs from now
+            self._free_at = now + self._device_s
+        return now + self._staging_s >= self._free_at
 
 
 class ContinuousBatcher:
@@ -171,9 +254,12 @@ class ContinuousBatcher:
         self,
         bucket_sessions: Sequence[Tuple[Any, Sequence[StreamSession]]],
         now: float,
+        may_go_short: bool = True,
     ) -> Tuple[Any, Optional[List[Slot]]]:
         """EDF/cost-aware bucket pick for one tick; ``(None, None)`` =
-        nothing to do anywhere.
+        nothing to do anywhere, ``(bucket, None)`` = the pick's frames
+        are held: they do not fill a batch and ``may_go_short`` is False
+        (the device has a backlog to run first; module docstring).
 
         ``bucket_sessions``: ``[(bucket, sessions)]`` where ``bucket``
         exposes ``tick_cost_estimate() -> ms`` (a MEASURED per-batch
@@ -219,6 +305,10 @@ class ContinuousBatcher:
         # runs small batches instead of inheriting the frontend-wide
         # batch_size and padding the difference with repeated rows.
         limit = getattr(best, "batch_size", None)
+        if not may_go_short:
+            want = limit if limit is not None else self.batch_size
+            if sum(len(s.pending) for s in best_sessions) < want:
+                return best, None
         return best, self.select(best_sessions, now, pre_drained=True,
                                  limit=limit)
 

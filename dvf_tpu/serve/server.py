@@ -120,7 +120,11 @@ from dvf_tpu.runtime.signature import (
     make_key,
     parse_manifest,
 )
-from dvf_tpu.serve.batcher import BatchPlan, ContinuousBatcher
+from dvf_tpu.serve.batcher import (
+    BatchPlan,
+    ContinuousBatcher,
+    DeviceBacklog,
+)
 from dvf_tpu.serve.router import ResultRouter
 from dvf_tpu.serve.session import (
     CLOSED,
@@ -140,8 +144,9 @@ TRACK_DISPATCH, TRACK_DEVICE, TRACK_COLLECT = 0, 1, 2
 
 # The two pacing threads' per-bucket states (obs.metrics.ThreadClock;
 # ``idle`` is whatever belongs to no bucket). With ``trace`` on each is
-# one span per batch on the thread's lane, ``<thread>:<state>``.
-DISPATCH_STATES = ("permit_wait", "assemble_h2d")
+# one span per batch on the thread's lane, ``<thread>:<state>``
+# (``hold``: one span per hold, whatever batch ends it).
+DISPATCH_STATES = ("hold", "permit_wait", "assemble_h2d")
 COLLECT_STATES = ("device", "d2h", "route")
 
 # dvf_compile_ms histogram bounds: serving compiles span sub-ms pool
@@ -411,6 +416,15 @@ class _Bucket:
         self.state_counts = {"table_rows_total": 0, "chain_rows_total": 0,
                              "fresh_rows_total": 0}
         self.state_resets = {"admission": 0, "rebuild": 0, "migrate": 0}
+        # What the batcher's "a short batch waits for the device" rule
+        # did here (the dispatch thread's): batches by fill, those whose
+        # binding was put off at least one tick, and the ticks' time.
+        self.hold_counts = {"short_batches_total": 0,
+                            "full_batches_total": 0,
+                            "held_batches_total": 0, "hold_ms_total": 0.0}
+        self.device_ms: Optional[float] = None  # what this program's
+        #   last batch took of the device (observe_device): when the
+        #   dispatch thread expects a backlog of it to have run out
 
     @property
     def engine(self) -> Engine:
@@ -504,6 +518,21 @@ class _Bucket:
         else:
             self._tick_cost_ms = (1 - a) * self._tick_cost_ms + a * wall_ms
 
+    def observe_device(self, ms: float) -> None:
+        """Collect thread: a batch of this bucket was ready ``ms`` after
+        the later of its own submit and the batch before it being ready
+        (the step, the pack, and the H2D where nothing hid it). Read too
+        long when this thread was behind, never too short."""
+        self.device_ms = ms
+
+    def note_bound(self, valid: int, held: bool) -> None:
+        """Dispatch thread, once a batch was submitted: ``held`` when
+        its binding had been put off behind the device's backlog."""
+        c = self.hold_counts
+        c["full_batches_total" if valid >= self.batch_size
+          else "short_batches_total"] += 1
+        c["held_batches_total"] += held
+
     def record_fault(self, kind: str) -> None:
         self.faults[kind] = self.faults.get(kind, 0) + 1
 
@@ -574,6 +603,7 @@ class _Bucket:
             "engine_batches": self.engine.stats.batches,
             "engine_compile_count": self.engine.stats.compile_count,
             "stages": self.stages.summary(),
+            "hold": {k: round(v, 4) for k, v in self.hold_counts.items()},
         }
         # Process-wide XLA backend compilations (obs.ledger
         # XlaCompileWatch), the same two numbers on every row: a window
@@ -3291,12 +3321,20 @@ class ServeFrontend:
         seq = 0
         clock = self._dispatch_clock
         tracer = self.tracer
+        backlog = DeviceBacklog()  # what this thread put on the device
+        #   and has not seen ready
+        held = None    # (bucket, since): the pick whose short batch the
+        #   last tick did not bind (serve/batcher.py, "A short batch
+        #   waits for the device"); the ticks until it is are ``hold``
         try:
             while not self._stop.is_set():
                 if self._recovering.is_set():
                     # Supervised recovery in progress: park — the engine,
                     # queue, and semaphore are being replaced under us.
                     # _recover waits for this flag before touching them.
+                    # The window is being shed: nothing is held behind it.
+                    held = None
+                    backlog.clear()
                     self._dispatch_parked.set()
                     time.sleep(self._tick_s)
                     continue
@@ -3319,9 +3357,14 @@ class ServeFrontend:
                     # reconfiguration consumes on this thread.
                     self._apply_commits_dispatch()
                 # The tick's clock read: the scheduler's ``now``, and the
-                # point up to which this thread's time is accounted idle.
+                # point up to which this thread's time is accounted idle
+                # (or, with a bucket's binding put off, ``hold``).
                 now = time.time()
-                clock.spend("idle", now)
+                if held is None:
+                    clock.spend("idle", now)
+                else:
+                    held[0].hold_counts["hold_ms_total"] += clock.spend(
+                        "hold", now)
                 with self._lock:
                     # Buckets with an aside-prepare in flight keep
                     # dispatching at the OLD size/program — a hot swap
@@ -3330,18 +3373,21 @@ class ServeFrontend:
                         (b, [s for s in b.sessions.values()
                              if s.state != CLOSED])
                         for b in self._buckets if b.sessions]
-                plan = None
+                may_go_short = backlog.may_go_short(now, self._inflight_sem)
+                plan = pick = None
                 if bucket_sessions:
                     # One bucket per tick (one compiled program per
                     # batch): EDF-headroom ÷ measured tick cost picks
                     # the bucket, then the ordinary within-bucket EDF
-                    # picks the slots. Frames are staged through the
-                    # bucket's assembler below, after the in-flight
-                    # permit is acquired (the permit is what makes
-                    # staging-slab reuse safe) — one staging
-                    # implementation for both ingest modes.
+                    # picks the slots; fewer than a batch of them are
+                    # bound only once the device's backlog has run out
+                    # (or will have by the time they are staged).
+                    # Frames are staged through the bucket's assembler
+                    # below, after the in-flight permit is acquired (the
+                    # permit is what makes staging-slab reuse safe) —
+                    # one staging implementation for both ingest modes.
                     pick, chosen = self.batcher.select_bucket(
-                        bucket_sessions, now)
+                        bucket_sessions, now, may_go_short=may_go_short)
                     if chosen:
                         # Stamp: the batch is chosen — and frozen. What
                         # follows until the permit is ``permit_wait``,
@@ -3352,6 +3398,20 @@ class ServeFrontend:
                             rows=self.batcher.row_map(chosen,
                                                       pick.batch_size),
                             stamps=BatchStamps(pick.stages, time.time()))
+                deferred = pick if plan is None else None
+                after_hold = False
+                if held is not None and deferred is not held[0]:
+                    # The hold ended at this tick's clock read: its
+                    # frames are bound (or were shed, or another bucket
+                    # leads now).
+                    after_hold = plan is not None and pick is held[0]
+                    if tracer.enabled:
+                        tracer.complete("dispatch:hold", held[1], now,
+                                        TRACK_DISPATCH,
+                                        bucket=held[0].label())
+                    held = None
+                if deferred is not None and held is None:
+                    held = (deferred, now)
                 self._finalize_drained()
                 if plan is None:
                     time.sleep(self._tick_s)
@@ -3443,6 +3503,7 @@ class ServeFrontend:
                 clock.spend("permit_wait", t0)
                 clock.spend("assemble_h2d", st.t_submit)
                 bucket.stages.note_dispatched(st)
+                bucket.note_bound(plan.valid, after_hold)
                 if tracer.enabled:
                     # Trace view of the same stamps: the legacy
                     # serve_dispatch span and one span per thread state.
@@ -3465,6 +3526,8 @@ class ServeFrontend:
                 self._window.add(seq, plan)
                 bucket.adjust_inflight(1)
                 q.put((seq, plan, result))
+                backlog.queued(result, sem, t0, st.t_submit,
+                               bucket.device_ms)
                 # Ledger stall accounting: this tick is the bucket's
                 # dispatch heartbeat — it closes any reconfiguration
                 # stall window open on the bucket (gap measured from
@@ -3499,6 +3562,7 @@ class ServeFrontend:
         sem = self._inflight_sem  # pinned with the queue: a permit must be
         #   released into the semaphore it was acquired from — releasing
         #   the live attribute would over-credit a post-recovery window
+        last_ready = 0.0  # the previous batch's t_ready
         try:
             while self._collect_gen == gen:  # superseded by recovery → exit
                 if chaos is not None:
@@ -3523,6 +3587,10 @@ class ServeFrontend:
                     pass  # raises again in fetch below, where the
                     #   containment ladder owns it
                 st.t_ready = time.time()  # stamp: device result ready
+                if bucket is not None:
+                    bucket.observe_device(
+                        (st.t_ready - max(st.t_submit, last_ready)) * 1e3)
+                last_ready = st.t_ready
                 try:
                     # Streamed egress: shard host copies into the slot's
                     # preallocated slab (D2H issued at submit), or, on

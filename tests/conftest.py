@@ -315,3 +315,59 @@ def frame_u8(rng):
 def batch_f32(rng):
     """(4, 48, 64, 3) float batch in [0,1]."""
     return rng.random((4, 48, 64, 3), dtype=np.float32)
+
+
+class DeviceGate:
+    """A test's hand on what the serve dispatch thread reads of the
+    device (``runtime.lane.InflightBatch``): while ``busy`` every
+    in-flight batch reads not ready, whatever the CPU backend has done
+    with it (a backlog that lasts as long as the test says); ``fail``
+    makes the read raise (a poisoned handle); with ``collect`` cleared
+    the collect thread's ``wait`` blocks, so nothing comes back and no
+    permit is released. ``device_ms`` is what every bucket is told its
+    last batch took of the device, in place of the CPU's few ms: None
+    (no estimate, so no hold ends before the handle reads ready) unless
+    the test says otherwise."""
+
+    def __init__(self):
+        self.busy = False
+        self.fail = False
+        self.device_ms = None
+        self.collect = threading.Event()
+        self.collect.set()
+
+    @staticmethod
+    def until(cond, what="condition", deadline_s=30.0):
+        """Poll ``cond`` a tick at a time; fail loudly at the deadline."""
+        deadline = time.time() + deadline_s
+        while not cond():
+            assert time.time() < deadline, f"timed out waiting for {what}"
+            time.sleep(0.002)
+
+
+@pytest.fixture
+def device_gate(monkeypatch):
+    from dvf_tpu.runtime import lane
+
+    gate = DeviceGate()
+    real_ready, real_wait = lane.InflightBatch.is_ready, lane.InflightBatch.wait
+
+    def is_ready(self):
+        if gate.fail:
+            raise RuntimeError("device_gate: poisoned handle")
+        return not gate.busy and real_ready(self)
+
+    def wait(self):
+        assert gate.collect.wait(timeout=60.0), "device_gate never released"
+        real_wait(self)
+
+    from dvf_tpu.serve import server
+
+    monkeypatch.setattr(
+        server._Bucket, "observe_device",
+        lambda self, ms: setattr(self, "device_ms", gate.device_ms))
+    monkeypatch.setattr(lane.InflightBatch, "is_ready", is_ready)
+    monkeypatch.setattr(lane.InflightBatch, "wait", wait)
+    yield gate
+    gate.busy = gate.fail = False
+    gate.collect.set()

@@ -526,3 +526,197 @@ def _compiled_engine():
     e = Engine(get_filter("invert"))
     e.compile((2, H, W, 3), np.uint8)
     return e
+
+
+# ------------------------------------- a short batch waits for the device
+
+
+class _Bucket:
+    """What ``select_bucket`` asks of a bucket."""
+
+    def __init__(self, batch_size, cost_ms=1.0):
+        self.batch_size = batch_size
+        self.cost_ms = cost_ms
+
+    def tick_cost_estimate(self):
+        return self.cost_ms
+
+
+def _queued(name, n, slo_ms, ts):
+    from dvf_tpu.serve.session import SessionConfig, StreamSession
+
+    s = StreamSession(name, SessionConfig(queue_size=64, slo_ms=slo_ms))
+    for j in range(n):
+        s.submit(np.zeros((2, 2, 3), np.uint8), ts=ts + j * 1e-4)
+    return s
+
+
+class TestShortBatchWaitsAcrossBuckets:
+    """serve/batcher.py, "A short batch waits for the device, not in
+    it", at the bucket pick: the rule applies to the bucket the EDF/cost
+    score leads with."""
+
+    @pytest.mark.parametrize("have,may_go_short,bound", [
+        (1, False, 0), (3, False, 0), (4, False, 4), (6, False, 4),
+        (1, True, 1), (3, True, 3), (4, True, 4), (6, True, 4),
+    ])
+    def test_fill_and_backlog_decide_the_binding(self, have, may_go_short,
+                                                 bound):
+        from dvf_tpu.serve.batcher import ContinuousBatcher
+
+        now = time.time()
+        bucket = _Bucket(4)
+        s = _queued("s", have, 60_000.0, now)
+        pick, chosen = ContinuousBatcher(8).select_bucket(
+            [(bucket, [s])], now, may_go_short=may_go_short)
+        assert pick is bucket
+        assert len(chosen or ()) == bound
+        assert (chosen is None) == (bound == 0)
+        # what was not bound waits where later arrivals join it
+        assert len(s.pending) == have - bound and s.inflight == bound
+
+    def test_a_held_pick_promotes_no_other_bucket_and_still_sheds(self):
+        from dvf_tpu.serve.batcher import ContinuousBatcher
+
+        batcher = ContinuousBatcher(4)
+        now = time.time()
+        tight, loose = _Bucket(4), _Bucket(4)
+        a = _queued("a", 2, 50.0, now)          # leads: least headroom
+        b = _queued("b", 4, 60_000.0, now)      # a full batch, behind it
+        both = [(loose, [b]), (tight, [a])]
+        assert batcher.select_bucket(both, now, may_go_short=False) == (
+            tight, None)
+        assert (len(a.pending), len(b.pending)) == (2, 4)
+        assert a.inflight == b.inflight == 0
+        # two more arrive while the device works: the four leave together
+        for j in (2, 3):
+            a.submit(np.zeros((2, 2, 3), np.uint8), ts=now + j * 1e-4)
+        pick, chosen = batcher.select_bucket(both, now, may_go_short=False)
+        assert pick is tight
+        assert [(sl.session.id, sl.index) for sl in chosen] == [
+            ("a", j) for j in range(4)]
+        # held frames age like any other: past the deadline they are shed
+        c = _queued("c", 3, 50.0, now)
+        pick, chosen = batcher.select_bucket(
+            [(tight, [c])], now + 1.0, may_go_short=False)
+        assert (pick, chosen) == (None, None) and c.shed == 3
+
+    def test_two_signatures_through_holds_every_frame_accounted(
+            self, device_gate):
+        """Two buckets on one frontend while the device's backlog comes
+        and goes: each session's frames come back once, in order,
+        bit-identical to its own filter."""
+        fe = ServeFrontend(get_filter("invert"), cfg(batch_size=4))
+        n = 12
+        frames = frames_for((H, W, 3), np.uint8, n, seed=5)
+        with fe:
+            inv = fe.open_stream(frame_shape=(H, W, 3))
+            gray = fe.open_stream(op_chain="grayscale",
+                                  frame_shape=(H, W, 3))
+            for sid in (inv, gray):             # compile both programs
+                fe.submit(sid, frames[0])
+            want_gray = drain_session(fe, gray, 1)[0].frame
+            assert len(drain_session(fe, inv, 1)) == 1
+            for j in range(1, n):
+                device_gate.busy = j % 3 != 0   # held two rounds in three
+                fe.submit(inv, frames[j])
+                fe.submit(gray, frames[j])
+                time.sleep(0.004)
+            device_gate.busy = False
+            got_inv = drain_session(fe, inv, n - 1)
+            got_gray = drain_session(fe, gray, n - 1)
+            st = fe.stats()
+        assert [d.index for d in got_inv] == list(range(1, n))
+        assert [d.index for d in got_gray] == list(range(1, n))
+        for d in got_inv:
+            np.testing.assert_array_equal(d.frame, 255 - frames[d.index])
+        assert want_gray.shape == got_gray[0].frame.shape
+        for sid in (inv, gray):
+            row = st["sessions"][sid]
+            assert row["submitted"] == row["delivered"] == n
+            assert row["shed"] == row["failed"] == 0
+        holds = [r["hold"] for r in st["buckets"].values()]
+        assert sum(h["held_batches_total"] for h in holds) >= 1
+        assert sum(h["hold_ms_total"] for h in holds) == pytest.approx(
+            st["threads"]["dispatch"]["hold_ms"], abs=0.01)
+        for r in st["buckets"].values():
+            assert (r["hold"]["short_batches_total"]
+                    + r["hold"]["full_batches_total"]) == r["batches"]
+
+
+class _Handle:
+    def __init__(self, ready=False, poisoned=False):
+        self.ready, self.poisoned = ready, poisoned
+
+    def is_ready(self):
+        if self.poisoned:
+            raise RuntimeError("poisoned")
+        return self.ready
+
+
+class TestDeviceBacklog:
+    """serve/batcher.py::DeviceBacklog: what ``may_go_short`` is read
+    from. Times in seconds; one step "took" 100 ms, staging 10 ms."""
+
+    def _one_onto_an_idle_device(self, staging_s=0.010):
+        from dvf_tpu.serve.batcher import DeviceBacklog
+
+        b, h = DeviceBacklog(), _Handle()
+        assert b.may_go_short(0.0)                  # nothing of ours there
+        b.queued(h, None, -staging_s, 0.0, 100.0)
+        return b, h
+
+    @pytest.mark.parametrize("now,staging_s,go", [
+        (0.050, 0.010, False),      # the device still has half a step
+        (0.089, 0.010, False),
+        (0.091, 0.010, True),       # staged by the time it runs out
+        (0.050, 0.060, True),       # a long staging starts sooner
+        (5.000, 0.000, True),       # overdue: not held on a stale handle
+    ])
+    def test_released_one_staging_before_the_backlog_is_due(
+            self, now, staging_s, go):
+        b, _ = self._one_onto_an_idle_device(staging_s)
+        assert b.may_go_short(now) is go
+
+    @pytest.mark.parametrize("how", ["ready", "poisoned"])
+    def test_a_ready_or_raising_handle_ends_the_backlog(self, how):
+        b, h = self._one_onto_an_idle_device()
+        setattr(h, how, True)
+        assert b.may_go_short(0.001)
+        h.ready = h.poisoned = False                # forgotten: not asked again
+        assert b.may_go_short(0.002)
+
+    def test_without_a_measurement_only_readiness_counts(self):
+        from dvf_tpu.serve.batcher import DeviceBacklog
+
+        b, h = DeviceBacklog(), _Handle()
+        b.queued(h, None, -1.0, 0.0, None)
+        assert not b.may_go_short(3600.0)
+        h.ready = True
+        assert b.may_go_short(3600.0)
+
+    def test_a_queued_batch_starts_when_the_one_before_is_seen_ready(self):
+        """No forecast is built on a forecast: behind an unready batch
+        nothing is due until that one has been SEEN ready."""
+        b, first = self._one_onto_an_idle_device()
+        second = _Handle()
+        b.queued(second, None, 0.085, 0.095, 100.0)  # bound a staging early
+        assert not b.may_go_short(0.150)
+        assert not b.may_go_short(9.000)            # whatever the clock says
+        first.ready = True                          # seen at 9.002: the
+        assert not b.may_go_short(9.002)            # second runs from there
+        assert not b.may_go_short(9.090)
+        assert b.may_go_short(9.093)
+        second.ready = True
+        assert b.may_go_short(9.094)
+
+    def test_batches_of_another_generation_hold_nothing_up(self):
+        """A supervised recovery wrote the window off and replaced the
+        permits: what was queued under the old ones is not waited for."""
+        b, _ = self._one_onto_an_idle_device()
+        old, new = object(), object()
+        b.queued(_Handle(), old, 0.0, 0.010, 100.0)
+        assert not b.may_go_short(0.020, old)
+        assert b.may_go_short(0.020, new)
+        b.queued(_Handle(), new, 0.020, 0.030, 100.0)
+        assert not b.may_go_short(0.040, new)

@@ -732,3 +732,281 @@ class TestReplicaLifecycleHooks:
         merged = LatencyStats.merge_snapshots([snap])
         assert merged["count"] == agg["count"] == 6
         assert merged["p50_ms"] == pytest.approx(agg["p50_ms"])
+
+
+def _bucket_row(fe):
+    return next(iter(fe.stats()["buckets"].values()))
+
+
+def _hold(fe):
+    return _bucket_row(fe)["hold"]
+
+
+def _poll_n(fe, sid, n, gate):
+    got = []
+
+    def more():
+        got.extend(fe.poll(sid))
+        return len(got) >= n
+
+    gate.until(more, f"{n} deliveries of {sid}")
+    return got
+
+
+def _whole_tick(fe, gate):
+    """Returns once a dispatch tick that began after this call has run to
+    its end (its clock read is ``accounted_to``; a second one follows)."""
+    def accounted():
+        return fe.stats()["threads"]["dispatch"]["accounted_to"]
+
+    for _ in range(2):
+        mark = max(time.time(), accounted())
+        gate.until(lambda: accounted() > mark, "a dispatch tick")
+
+
+class TestShortBatchWaitsForTheDevice:
+    """serve/batcher.py, "A short batch waits for the device, not in it":
+    fewer frames than a batch are bound only once the device's backlog
+    has run out. ``device_gate`` (conftest) stands in for the device's
+    readiness as the dispatch thread reads it."""
+
+    def test_short_set_held_behind_a_backlog_leaves_with_later_arrivals(
+            self, device_gate):
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0))
+        with fe:
+            sid = fe.open_stream()
+            device_gate.busy = True
+            fe.submit(sid, tagged_frame(0, 0))     # idle device: goes alone
+            device_gate.until(
+                lambda: _hold(fe)["short_batches_total"] == 1, "frame 0")
+            tick_ms = fe._tick_s * 1e3
+
+            def held_ms():
+                return fe.stats()["threads"]["dispatch"]["hold_ms"]
+
+            for j in (1, 2):                        # backlog: both wait
+                before = held_ms()
+                fe.submit(sid, tagged_frame(0, j))
+                device_gate.until(lambda: held_ms() > before + 5 * tick_ms,
+                                  "five held ticks")
+                row = _bucket_row(fe)
+                assert row["queue_depth"] == j
+                assert row["hold"]["short_batches_total"] == 1
+                assert row["hold"]["held_batches_total"] == 0
+            device_gate.busy = False                # the backlog ran out
+            got = _poll_n(fe, sid, 3, device_gate)
+            hold = _hold(fe)
+        assert [d.index for d in got] == [0, 1, 2]
+        for d in got:
+            np.testing.assert_array_equal(d.frame,
+                                          255 - tagged_frame(0, d.index))
+        # frames 1 and 2 rode together: two batches for three frames
+        assert hold["short_batches_total"] == 2
+        assert hold["full_batches_total"] == 0
+        assert hold["held_batches_total"] == 1
+        assert hold["hold_ms_total"] > 10 * tick_ms
+
+    def test_full_set_goes_behind_a_backlog_up_to_the_permits(
+            self, device_gate):
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=2, max_inflight=2,
+                                       slo_ms=60_000.0))
+        sid = fe.open_stream()
+        for j in range(6):          # three full batches wait at the start
+            fe.submit(sid, tagged_frame(0, j))
+        device_gate.busy = True
+        device_gate.collect.clear()                 # nothing comes back
+        with fe:
+            # two go at depth, the third is bound and waits for a permit
+            device_gate.until(
+                lambda: (_bucket_row(fe)["inflight_batches"] == 2
+                         and _bucket_row(fe)["queue_depth"] == 0),
+                "two in flight, the third bound")
+            assert _hold(fe)["full_batches_total"] == 2
+            time.sleep(0.01)
+            assert _hold(fe)["full_batches_total"] == 2    # still blocked
+            device_gate.collect.set()
+            got = _poll_n(fe, sid, 6, device_gate)
+            row = _bucket_row(fe)
+            threads = fe.stats()["threads"]["dispatch"]
+        assert [d.index for d in got] == list(range(6))
+        assert row["hold"] == {"short_batches_total": 0,
+                               "full_batches_total": 3,
+                               "held_batches_total": 0, "hold_ms_total": 0.0}
+        assert threads["hold_ms"] == 0.0
+        permit = row["stages"]["components"]["permit_wait"]
+        assert permit["batches"] == 3 and permit["max_ms"] >= 10.0
+
+    def test_idle_device_never_holds_a_frame(self, device_gate):
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0))
+        with fe:
+            sid = fe.open_stream()
+            for j in range(3):      # each alone, the one before it back
+                fe.submit(sid, tagged_frame(0, j))
+                _poll_n(fe, sid, 1, device_gate)
+            hold = _hold(fe)
+            threads = fe.stats()["threads"]["dispatch"]
+        # bound by the first tick that met it: no tick was ever a hold
+        assert hold == {"short_batches_total": 3, "full_batches_total": 0,
+                        "held_batches_total": 0, "hold_ms_total": 0.0}
+        assert threads["hold_ms"] == 0.0
+
+    def test_hold_ends_one_staging_before_the_backlog_should(
+            self, device_gate):
+        """With a measured device time to go by, a held set is bound when
+        the backlog is due to run out, not when the handle says it has:
+        here the handle never does."""
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0))
+        with fe:
+            sid = fe.open_stream()
+            device_gate.device_ms = 60.0    # what every batch "took"
+            fe.submit(sid, tagged_frame(0, 0))      # leaves the estimate
+            _poll_n(fe, sid, 1, device_gate)
+            _whole_tick(fe, device_gate)            # ... and was seen ready
+            device_gate.busy = True                 # for good
+            t_first = time.time()
+            for j in (1, 2):        # onto an idle device; then behind it
+                fe.submit(sid, tagged_frame(0, j))
+                assert [d.index for d in
+                        _poll_n(fe, sid, 1, device_gate)] == [j]
+            waited_ms = (time.time() - t_first) * 1e3
+            hold = _hold(fe)
+        assert hold["short_batches_total"] == 3
+        assert hold["held_batches_total"] == 1      # frame 2
+        # one device time from frame 1's submit, less a staging
+        assert 30.0 <= hold["hold_ms_total"] <= waited_ms
+
+    def test_held_frame_past_its_deadline_is_shed_not_dispatched(
+            self, device_gate):
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0))
+        with fe:
+            a = fe.open_stream()
+            b = fe.open_stream(slo_ms=30.0)
+            device_gate.busy = True
+            fe.submit(a, tagged_frame(0, 0))
+            device_gate.until(
+                lambda: _hold(fe)["short_batches_total"] == 1, "frame a0")
+            fe.submit(b, tagged_frame(1, 0))        # held, then too late
+            device_gate.until(
+                lambda: fe.stats()["sessions"][b]["shed"] == 1, "the shed")
+            device_gate.busy = False
+            assert [d.index for d in _poll_n(fe, a, 1, device_gate)] == [0]
+            fe.submit(b, tagged_frame(1, 1))        # the service goes on
+            assert [d.index for d in _poll_n(fe, b, 1, device_gate)] == [1]
+            stats = fe.stats()
+        srow = stats["sessions"][b]
+        assert (srow["submitted"], srow["delivered"], srow["shed"]) == (2, 1, 1)
+        hold = next(iter(stats["buckets"].values()))["hold"]
+        assert hold["short_batches_total"] == 2     # a0, b1: b0 never ran
+        assert hold["held_batches_total"] == 0
+        assert hold["hold_ms_total"] > 0.0
+        assert stats["threads"]["dispatch"]["hold_ms"] == pytest.approx(
+            hold["hold_ms_total"], abs=0.01)
+
+    def test_order_and_accounting_hold_across_holds(self, device_gate):
+        """Three paced sessions while the device's readiness flaps: every
+        frame comes back once, in order, bit-exact, whichever batch it
+        was held for."""
+        n_sessions, n_frames = 3, 30
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0,
+                                       queue_size=64))   # the first batch
+        #   compiles on the dispatch thread: ingress holds the rest
+        stop = threading.Event()
+
+        def flap():
+            while not stop.is_set():
+                device_gate.busy = not device_gate.busy
+                time.sleep(0.004)
+
+        flapper = threading.Thread(target=flap)
+        deliveries = {}
+        with fe:
+            sids = [fe.open_stream() for _ in range(n_sessions)]
+            for k, sid in enumerate(sids):      # round 0 compiles
+                fe.submit(sid, tagged_frame(k, 0))
+            device_gate.until(
+                lambda: _bucket_row(fe)["routed_frames_total"] == n_sessions,
+                "the warm round")
+            flapper.start()
+            try:
+                for j in range(1, n_frames):
+                    for k, sid in enumerate(sids):
+                        fe.submit(sid, tagged_frame(k, j))
+                    time.sleep(0.003)
+            finally:
+                stop.set()
+                flapper.join(timeout=10.0)
+            assert not flapper.is_alive()
+            device_gate.busy = False
+            drain(fe, sids, deliveries)
+            stats = fe.stats()
+        for k, sid in enumerate(sids):
+            assert [d.index for d in deliveries[sid]] == list(range(n_frames))
+            for d in deliveries[sid]:
+                np.testing.assert_array_equal(d.frame,
+                                              255 - tagged_frame(k, d.index))
+            srow = stats["sessions"][sid]
+            assert srow["submitted"] == srow["delivered"] == n_frames
+            assert srow["shed"] == srow["failed"] == srow["inflight"] == 0
+        row = next(iter(stats["buckets"].values()))
+        hold = row["hold"]
+        assert (hold["short_batches_total"] + hold["full_batches_total"]
+                == row["batches"])
+        assert hold["held_batches_total"] >= 1
+        assert row["routed_frames_total"] == n_sessions * n_frames
+
+    def test_handle_that_raises_holds_nothing_up(self, device_gate):
+        fe = ServeFrontend(get_filter("invert"),
+                           ServeConfig(batch_size=4, slo_ms=60_000.0))
+        with fe:
+            sid = fe.open_stream()
+            device_gate.busy = device_gate.fail = True
+            for j in range(4):
+                fe.submit(sid, tagged_frame(0, j))
+                device_gate.until(
+                    lambda: _bucket_row(fe)["queue_depth"] == 0, "dispatch")
+            got = _poll_n(fe, sid, 4, device_gate)
+            hold = _hold(fe)
+        assert [d.index for d in got] == [0, 1, 2, 3]
+        assert hold["held_batches_total"] == 0
+        assert hold["hold_ms_total"] == 0.0
+
+    def test_supervised_recovery_releases_a_hold(self, device_gate):
+        """The window is shed with a frame held behind it: the handle the
+        hold was reading belongs to the old generation, so the frame
+        goes out on the rebuilt engine though that handle never reads
+        ready."""
+        from dvf_tpu.resilience import FaultPlan
+
+        chaos = FaultPlan().add("freeze", at=(3,), delay_s=1.5)
+        fe = ServeFrontend(
+            get_filter("invert"),
+            ServeConfig(batch_size=4, queue_size=1000, slo_ms=60_000.0,
+                        stall_timeout_s=0.35, chaos=chaos))
+        with fe:
+            sid = fe.open_stream()
+            s = fe._session(sid)
+            device_gate.busy = True                 # for good
+            i = 0
+            deadline = time.time() + 20.0
+            while fe.recoveries < 1:
+                assert time.time() < deadline, "watchdog never tripped"
+                fe.submit(sid, tagged_frame(0, i))
+                i += 1
+                time.sleep(0.01)
+            device_gate.until(lambda: s.delivered >= 1,
+                              "a held frame's delivery after the recovery")
+            device_gate.busy = False
+            device_gate.until(
+                lambda: s.delivered + s.failed + s.shed == i, "the rest")
+            got = fe.poll(sid)
+            stats = fe.stats()
+        idx = [d.index for d in got]
+        assert idx == sorted(set(idx)) and len(idx) == s.delivered
+        assert stats["recoveries"] >= 1 and s.failed >= 1
+        assert fe._error is None

@@ -184,6 +184,62 @@ def test_batch_composition_is_not_part_of_the_answer(name, batch, order, alone):
         assert _worst(got[k], alone[name][k]) <= 1
 
 
+@pytest.mark.parametrize("name", sorted(FILTERS))
+def test_frames_held_behind_a_backlog_keep_their_predecessors(
+        name, alone, device_gate):
+    """A short candidate set waits in ``pending`` while the device has a
+    backlog (serve/batcher.py) and later rounds join it: the row map is
+    built when the batch is bound, so each row's predecessor is still
+    its own session's previous frame, whichever round it came in with."""
+    fname, kw = FILTERS[name]
+    fe = ServeFrontend(get_filter(fname, **kw),
+                       ServeConfig(batch_size=4, max_sessions=4, queue_size=64,
+                                   out_queue_size=64, slo_ms=600_000))
+    rounds = max(map(len, STREAMS))
+    got = {k: [] for k in range(len(STREAMS))}
+
+    def row():
+        return next(iter(fe.stats()["buckets"].values()))
+
+    with fe:
+        sids = [fe.open_stream() for _ in STREAMS]
+        for i in range(rounds):
+            live = [k for k in range(len(STREAMS)) if i < len(STREAMS[k])]
+            # round 0 goes at once (an idle device) and reads busy until
+            # round 3: rounds 1 and 2 wait behind it, a full batch of them
+            # leaves at depth, the rest with round 3. Rows of one session
+            # in one batch chain through the batch, the others through
+            # the table.
+            device_gate.busy = i < 3
+            for k in live:
+                fe.submit(sids[k], STREAMS[k][i])
+            if i in (1, 2):
+                before = row()["hold"]["hold_ms_total"]
+                device_gate.until(
+                    lambda: row()["hold"]["hold_ms_total"] > before + 5.0,
+                    "held ticks")
+            else:
+                device_gate.until(
+                    lambda: i == 0 or (row()["queue_depth"] == 0
+                                       and row()["inflight_batches"] == 0),
+                    f"round {i} through")
+        for k, sid in enumerate(sids):
+            device_gate.until(
+                lambda: got[k].extend(fe.poll(sid))
+                or len(got[k]) >= len(STREAMS[k]), f"session {k}")
+        stats = fe.stats()
+    assert stats["errors"] == 0
+    for k in got:
+        assert [d.index for d in got[k]] == list(range(len(STREAMS[k])))
+        assert _worst([d.frame for d in got[k]], alone[name][k]) <= 1
+    bucket = next(iter(stats["buckets"].values()))
+    assert bucket["hold"]["held_batches_total"] >= 1
+    state = bucket["state"]
+    assert state["table_rows_total"] + state["chain_rows_total"] == 16
+    assert state["chain_rows_total"] >= 1      # held rounds shared a batch
+    assert bucket["engine_compile_count"] == 1
+
+
 def _sessions(n):
     out = []
     for k in range(n):

@@ -127,7 +127,7 @@ def _read_just_after_an_accrual(fe, thread):
 
 
 @pytest.mark.parametrize("thread,states", [
-    ("dispatch", ("idle", "permit_wait", "assemble_h2d")),
+    ("dispatch", ("idle", "hold", "permit_wait", "assemble_h2d")),
     ("collect", ("idle", "device", "d2h", "route")),
 ])
 def test_thread_states_sum_to_wall_time(thread, states):
@@ -153,8 +153,12 @@ def test_thread_states_sum_to_wall_time(thread, states):
     assert wall_ms > 1200.0
     assert total == pytest.approx(wall_ms, rel=0.01)
     # the bucket rows hold the same per-bucket states (one bucket here)
-    st, _ = stages_of(stats)
+    st, bucket_row = stages_of(stats)
     for s in states[1:]:
+        if s == "hold":     # no batch interval: the bucket row's hold block
+            assert bucket_row["hold"]["hold_ms_total"] == pytest.approx(
+                row["hold_ms"], abs=0.01)
+            continue
         cell = st["route"] if s == "route" else st["components"][s]
         assert cell["batch_ms_total"] == pytest.approx(row[f"{s}_ms"], abs=0.01)
     assert row[f"{states[-1]}_ms"] > 0.0
@@ -292,6 +296,63 @@ def test_inflight_wait_takes_a_held_collect_thread():
     assert comp["inflight_wait"]["batch_ms_total"] > 150.0
     assert comp["device"]["batch_ms_total"] < \
         comp["inflight_wait"]["batch_ms_total"] / 4
+
+
+def test_hold_is_a_thread_state_a_bucket_block_and_queue_bucket(device_gate):
+    """Ticks on which a short batch waits for the device's backlog
+    (serve/batcher.py): the dispatch thread's ``hold`` state, the bucket
+    row's ``hold`` block and one ``dispatch:hold`` span a hold are the
+    same clock reads; per frame the wait is ``queue_bucket``, so the
+    eight components still sum to the delivered latency."""
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, trace=True,
+        telemetry_sample_s=0.0))
+
+    def bucket_row():
+        return next(iter(fe.stats()["buckets"].values()))
+
+    with fe:
+        sid = fe.open_stream()
+        n = 0
+        for hold_ms in (40.0, 80.0):
+            device_gate.busy = True
+            fe.submit(sid, frame_u8(0, n))          # idle device: at once
+            device_gate.until(lambda: bucket_row()["batches"] == n + 1,
+                              "the batch that makes the backlog")
+            before = bucket_row()["hold"]["hold_ms_total"]
+            fe.submit(sid, frame_u8(0, n + 1))      # held
+            device_gate.until(
+                lambda: bucket_row()["hold"]["hold_ms_total"]
+                > before + hold_ms, "the hold")
+            device_gate.busy = False
+            device_gate.until(lambda: bucket_row()["batches"] == n + 2,
+                              "the held batch")
+            for _ in range(2):      # ... and a whole tick saw it ready
+                _read_just_after_an_accrual(fe, "dispatch")
+            n += 2
+        now, stats = _read_just_after_an_accrual(fe, "dispatch")
+        snap = fe.tracer.snapshot()
+    st, row = stages_of(stats)
+    hold, thread = row["hold"], stats["threads"]["dispatch"]
+    assert hold["short_batches_total"] == row["batches"] == 4
+    assert hold["full_batches_total"] == 0
+    assert hold["held_batches_total"] == 2
+    assert hold["hold_ms_total"] > 120.0
+    assert thread["hold_ms"] == pytest.approx(hold["hold_ms_total"], abs=0.01)
+    states = ("idle", "hold", "permit_wait", "assemble_h2d")
+    assert sum(thread[f"{s}_ms"] for s in states) == pytest.approx(
+        (thread["accounted_to"] - thread["started"]) * 1e3, rel=1e-6, abs=0.01)
+    spans = [e for e in snap["events"] if e["name"] == "dispatch:hold"]
+    assert len(spans) == 2 and {e["pid"] for e in spans} == {0}
+    assert sum(e["dur"] for e in spans) / 1e3 == pytest.approx(
+        thread["hold_ms"], abs=0.02)
+    # per frame: no ninth component; the held frames' wait is queue_bucket
+    assert set(st["components"]) == set(SERVE_COMPONENTS)
+    assert sum(c["ms_total"] for c in st["components"].values()) == \
+        pytest.approx(st["latency_ms_total"], rel=1e-6, abs=1e-3)
+    assert st["components"]["queue_bucket"]["ms_total"] == pytest.approx(
+        hold["hold_ms_total"], rel=0.1, abs=5.0)
+    assert st["components"]["permit_wait"]["max_ms"] < 40.0
 
 
 # ---------------------------------------------------------------------------
