@@ -52,5 +52,26 @@ def batch_fill_pct(ctx):
     return 100.0 * rows / (batches * size) if batches > 0 else None
 
 
+def hold_window(ctx):
+    """Window deltas of the bucket rows' ``hold`` block ("a short batch
+    waits for the device", serve/server.py::_Bucket), summed over buckets
+    and replicas: {"batches", "held", "hold_ms"}. ``batches`` is the
+    block's own short + full count, taken where ``held`` is (at the
+    submit, on the dispatch thread; the row's ``batches`` counts on the
+    collect thread, a batch in flight later). None where the window was
+    not watched or no bucket reports the block."""
+    out = None
+    for prev, row in _bucket_pairs(ctx):
+        if "hold" not in row:
+            continue
+        was = (prev or {}).get("hold", {})
+        out = out or {"batches": 0, "held": 0, "hold_ms": 0.0}
+        out["batches"] += sum(row["hold"][k] - was.get(k, 0)
+                              for k in ("short_batches_total", "full_batches_total"))
+        out["held"] += row["hold"]["held_batches_total"] - was.get("held_batches_total", 0)
+        out["hold_ms"] += row["hold"]["hold_ms_total"] - was.get("hold_ms_total", 0.0)
+    return out
+
+
 def trace_value(ctx, key):
     return ctx["trace"][key] if ctx["trace"] is not None else None
