@@ -64,14 +64,75 @@ def instance_norm_init(c: int, dtype=jnp.float32) -> Params:
     return {"scale": jnp.ones((c,), dtype), "bias": jnp.zeros((c,), dtype)}
 
 
-def instance_norm(p: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
-    """Per-(sample, channel) normalization over H,W; stats in float32."""
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=(1, 2), keepdims=True)
-    var = jnp.var(xf, axis=(1, 2), keepdims=True)
-    y = (xf - mean) * lax.rsqrt(var + eps)
-    y = y * p["scale"] + p["bias"]
-    return y.astype(x.dtype)
+CORNER = 8      # rows and columns of input a norm's pivot is computed from
+
+
+def corner_pivot(conv, x: jnp.ndarray) -> jnp.ndarray:
+    """A pivot for the norm after ``conv`` (any conv of this module with its
+    bias, as a function of its input) that waits for no pass over the conv's
+    output: the float32 mean, per (sample, channel), of the same conv on the
+    input's top-left CORNER×CORNER positions — values of the output's own
+    distribution (those near the crop's far edges see the crop's border in
+    place of their neighbours; a pivot only has to lie near the mean), for
+    a few thousand multiply-adds a channel. Because it does not depend on
+    the conv's output, both of the norm's sums can ride in that conv's pass.
+    ``(B, 1, 1, C)``; the norms stop the gradient at it (their result does
+    not depend on the pivot)."""
+    y = conv(x[:, :CORNER, :CORNER, :]).astype(jnp.float32)
+    return jnp.mean(y, axis=(1, 2), keepdims=True)
+
+
+def _norm_stats(x: jnp.ndarray, pivot: jnp.ndarray):
+    """A norm's one pass over its activation for the statistics: the float32
+    mean and mean square over H, W of ``x - pivot``, ``(B, 1, 1, C)`` each.
+    Two sibling reductions over one operand, which XLA emits as one read
+    (on a TPU inside the fusion of the conv that makes ``x``, when ``pivot``
+    does not depend on ``x``)."""
+    with jax.named_scope("norm_stats"):
+        d = x.astype(jnp.float32) - pivot
+        return (jnp.mean(d, axis=(1, 2), keepdims=True),
+                jnp.mean(d * d, axis=(1, 2), keepdims=True))
+
+
+def _norm_apply(scale, bias, x, pivot, m1, m2, eps):
+    """``m1``, ``m2``: first and second moments of ``x - pivot``. The
+    variance is ``m2 - m1**2`` whatever the pivot, and the norm is applied
+    to ``x - pivot`` too: no term is larger than the pivot's distance from
+    the mean, so nothing cancels that a pivot near the mean does not keep
+    small. One elementwise pass, which the caller's relu and residual add
+    join."""
+    a = scale * lax.rsqrt(jnp.maximum(m2 - m1 * m1, 0.0) + eps)
+    b = bias - m1 * a
+    with jax.named_scope("norm_apply"):
+        return ((x.astype(jnp.float32) - pivot) * a + b).astype(x.dtype)
+
+
+def instance_norm(p: Params, x: jnp.ndarray, pivot=None,
+                  eps: float = 1e-5) -> jnp.ndarray:
+    """Per-(sample, channel) normalization over H,W; stats in float32, taken
+    in ONE pass over ``x`` as the mean and mean square of ``x - pivot``
+    (``E[d^2] - E[d]^2`` is the variance about any pivot; the rounding
+    error grows with the square of the pivot's distance from the mean in
+    spreads, so the pivot has to lie near the mean). ``pivot``: broadcasts
+    against ``x`` with H, W of 1 (:func:`corner_pivot`, or a ``(C,)``
+    vector); None takes each channel's first position, which costs a pass
+    of its own after the conv that makes ``x``."""
+    if pivot is None:
+        pivot = x[:, :1, :1, :]
+    pivot = lax.stop_gradient(pivot.astype(jnp.float32))
+    m1, m2 = _norm_stats(x, pivot)
+    return _norm_apply(p["scale"], p["bias"], x, pivot, m1, m2, eps)
+
+
+def conv_norm(p: Params, conv, x: jnp.ndarray,
+              phased: bool = False) -> jnp.ndarray:
+    """``conv`` (with its bias, as a function of its input) of ``x``, then
+    the instance norm ``p`` of the result (:func:`instance_norm_phase` of
+    a conv emitting phases): its sums are taken about
+    :func:`corner_pivot` of the same conv, which does not wait for the
+    conv's output, so they ride in the conv's own pass."""
+    norm = instance_norm_phase if phased else instance_norm
+    return norm(p, conv(x), corner_pivot(conv, x))
 
 
 def upsample_nearest(x: jnp.ndarray, factor: int = 2) -> jnp.ndarray:
@@ -202,17 +263,23 @@ def _upsample2_kernel(w: jnp.ndarray) -> jnp.ndarray:
     # Low-res tap offset e = floor((i + dy - r) / 2) for dy in [0, k).
     offs = sorted({(i + dy - r) // 2 for dy in range(k) for i in range(2)})
     e0, kl = offs[0], offs[-1] - offs[0] + 1
+    # idy[e, i, :] lists the dy landing on low-res tap e of phase i, padded
+    # with k (wpad's zero row): one static gather and a sum over the
+    # lists, as :func:`_s2d_kernel` (a tap at a time it is k²·4 traced
+    # scatter-adds a call, a second of every process's set-up).
+    hits = [[[dy for dy in range(k) if (i + dy - r) // 2 - e0 == e]
+             for i in range(2)] for e in range(kl)]
+    n = max(len(h) for row in hits for h in row)
+    idy = np.full((kl, 2, n), k, dtype=np.int32)
+    for e in range(kl):
+        for i in range(2):
+            idy[e, i, :len(hits[e][i])] = hits[e][i]
+    wpad = jnp.pad(w, ((0, 1), (0, 1), (0, 0), (0, 0)))
+    g = wpad[idy[:, :, :, None, None, None], idy[None, None, None, :, :, :]]
+    kl_w = g.sum(axis=(2, 5))                  # (e, i, f, j, ci, co)
     cin, cout = w.shape[2], w.shape[3]
-    kl_w = jnp.zeros((kl, kl, 2, 2, cin, cout), dtype=w.dtype)
-    for i in range(2):
-        for j in range(2):
-            for dy in range(k):
-                for dx in range(k):
-                    e = (i + dy - r) // 2 - e0
-                    f = (j + dx - r) // 2 - e0
-                    kl_w = kl_w.at[e, f, i, j].add(w[dy, dx])
-    # (e, f, i, j, ci, co) → (e, f, ci, (i·2+j)·Cout + co)
-    kl_w = kl_w.transpose(0, 1, 4, 2, 3, 5).reshape(kl, kl, cin, 4 * cout)
+    # (e, i, f, j, ci, co) → (e, f, ci, (i·2+j)·Cout + co)
+    kl_w = kl_w.transpose(0, 2, 4, 1, 3, 5).reshape(kl, kl, cin, 4 * cout)
     return kl_w, -e0
 
 
@@ -361,21 +428,23 @@ def conv2d_phase(
     )
 
 
-def instance_norm_phase(p: Params, x: jnp.ndarray,
+def instance_norm_phase(p: Params, x: jnp.ndarray, pivot=None,
                         eps: float = 1e-5) -> jnp.ndarray:
     """:func:`instance_norm` of the full-resolution tensor whose
-    ``space_to_depth(·, 2)`` image is ``x``: a channel's statistics are the
-    float32 mean over positions AND over its four phases; scale and bias
-    are tiled across the phases."""
-    b, _, _, c4 = x.shape
+    ``space_to_depth(·, 2)`` image is ``x``: one pass for each lane's
+    moments about its channel's pivot, a channel's moments the mean of its
+    four phases' (one pivot a channel, so they add); scale and bias are
+    tiled across the phases. ``pivot`` is a phase image too (4·c lanes, as
+    the conv before emits it) and is averaged over a channel's phases."""
+    c4 = x.shape[-1]
 
-    def per_channel(stat):            # (B, 4c) → per channel, tiled back
-        m = jnp.mean(stat.reshape(b, 4, c4 // 4), axis=1)
-        return jnp.tile(m, (1, 4))[:, None, None, :]
+    def per_channel(stat):            # (..., 4c) → per channel, tiled back
+        m = jnp.mean(stat.reshape(*stat.shape[:-1], 4, c4 // 4), axis=-2)
+        return jnp.tile(m, 4)
 
-    xf = x.astype(jnp.float32)
-    d = xf - per_channel(jnp.mean(xf, axis=(1, 2)))
-    var = per_channel(jnp.mean(d * d, axis=(1, 2)))
-    y = d * lax.rsqrt(var + eps)
-    y = y * jnp.tile(p["scale"], 4) + jnp.tile(p["bias"], 4)
-    return y.astype(x.dtype)
+    if pivot is None:
+        pivot = x[:, :1, :1, :]
+    pivot = lax.stop_gradient(per_channel(pivot.astype(jnp.float32)))
+    m1, m2 = _norm_stats(x, pivot)
+    return _norm_apply(jnp.tile(p["scale"], 4), jnp.tile(p["bias"], 4), x,
+                       pivot, per_channel(m1), per_channel(m2), eps)

@@ -33,6 +33,7 @@ instead of being baked into the jitted program as constants.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict
 
 import jax
@@ -45,16 +46,17 @@ from dvf_tpu.models.layers import (
     conv2d_nb,
     conv2d_phase,
     conv_init,
+    conv_norm,
     depth_to_space,
-    instance_norm,
     instance_norm_init,
-    instance_norm_phase,
     space_to_depth,
     upsample2_conv_phase,
     upsample_nearest,
 )
 
 LANES = 128     # the TPU's lane width: a tensor with fewer channels is stored padded to it
+
+_reflect_conv = functools.partial(conv2d_nb, reflect=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,64 +160,61 @@ def _forward(params: Params, batch: jnp.ndarray, config: StyleNetConfig,
             y = row_reduce(y)
         return y + jnp.tile(params[name]["b"], phases).astype(cd)
 
-    def cv(name, x, stride=1):
-        return finish(name, conv2d_nb(params[name], x, stride=stride,
-                                      compute_dtype=cd, reflect=True))
+    def conv(name, fn=_reflect_conv, phases=1, **kw):
+        """Conv ``name`` with its bias, as a function of its input."""
+        return lambda x: finish(
+            name, fn(params[name], x, compute_dtype=cd, **kw), phases)
 
-    def norm_relu(name, y, phased=False):
-        norm = instance_norm_phase if phased else instance_norm
-        return jax.nn.relu(norm(params[name], y))
+    def norm(name, conv, x, phased=False, relu=True):
+        y = conv_norm(params[name], conv, x, phased)
+        return jax.nn.relu(y) if relu else y
 
     x = batch.astype(cd)
     with jax.named_scope("stem"):
         if phase["stem"]:
-            y = conv2d_phase(params["stem"], space_to_depth(x, 2),
-                             compute_dtype=cd)
-            x = norm_relu("stem_norm", finish("stem", y, 4), phased=True)
+            x = norm("stem_norm", conv("stem", conv2d_phase, 4),
+                     space_to_depth(x, 2), phased=True)
         else:
-            x = norm_relu("stem_norm", cv("stem", x))
+            x = norm("stem_norm", conv("stem"), x)
     with jax.named_scope("down1"):
         if phase["down1"]:
             # 3x3 stride 2 on a phase tensor: a 2x2 conv, plain output.
-            y = conv2d_phase(params["down1"], x, stride=2, compute_dtype=cd)
-            x = norm_relu("down1_norm", finish("down1", y))
+            x = norm("down1_norm", conv("down1", conv2d_phase, stride=2), x)
         else:
-            x = norm_relu("down1_norm", cv("down1", x, stride=2))
+            x = norm("down1_norm", conv("down1", stride=2), x)
     with jax.named_scope("down2"):
-        x = norm_relu("down2_norm", cv("down2", x, stride=2))
+        x = norm("down2_norm", conv("down2", stride=2), x)
     with jax.named_scope("trunk"):
         if trunk_fn is not None:
             x = trunk_fn(params, x)
         else:
             for i in range(config.n_residual):
-                h = norm_relu(f"res{i}_an", cv(f"res{i}_a", x))
-                h = instance_norm(params[f"res{i}_bn"], cv(f"res{i}_b", h))
-                x = x + h
+                h = norm(f"res{i}_an", conv(f"res{i}_a"), x)
+                x = x + norm(f"res{i}_bn", conv(f"res{i}_b"), h, relu=False)
     with jax.named_scope("up1"):
         if phase["up1"]:
             # Made and normalized as phases (dense), then brought to the
             # half-resolution tensor up2's low-res conv reads.
-            y = upsample2_conv_phase(params["up1"], x, compute_dtype=cd)
-            x = depth_to_space(
-                norm_relu("up1_norm", finish("up1", y, 4), phased=True), 2)
+            x = depth_to_space(norm(
+                "up1_norm", conv("up1", upsample2_conv_phase, 4), x,
+                phased=True), 2)
         else:
-            x = norm_relu("up1_norm", cv("up1", upsample_nearest(x, 2)))
+            x = norm("up1_norm", conv("up1"), upsample_nearest(x, 2))
     with jax.named_scope("up2"):
         if phase["up2"]:
             # nearest-x2 + 3x3 as one low-res conv emitting the 4 phases.
-            y = upsample2_conv_phase(params["up2"], x, compute_dtype=cd)
-            x = norm_relu("up2_norm", finish("up2", y, 4), phased=True)
+            x = norm("up2_norm", conv("up2", upsample2_conv_phase, 4), x,
+                     phased=True)
         else:
-            x = norm_relu("up2_norm", cv("up2", upsample_nearest(x, 2)))
+            x = norm("up2_norm", conv("up2"), upsample_nearest(x, 2))
     with jax.named_scope("out"):
         if phase["out"]:
             fold = _out_fold(x.shape)
-            y = finish("out", conv2d_phase(params["out"], x, fold=fold,
-                                           compute_dtype=cd), 4 * fold * fold)
+            y = conv("out", conv2d_phase, 4 * fold * fold, fold=fold)(x)
             y = 0.5 * (jnp.tanh(y.astype(jnp.float32)) + 1.0)
             y = depth_to_space(y, 2 * fold)
         else:
-            y = 0.5 * (jnp.tanh(cv("out", x).astype(jnp.float32)) + 1.0)
+            y = 0.5 * (jnp.tanh(conv("out")(x).astype(jnp.float32)) + 1.0)
     return y.astype(batch.dtype)
 
 
@@ -282,13 +281,13 @@ def pp_param_pspecs(config: StyleNetConfig = StyleNetConfig()) -> Dict[str, Any]
 def _pp_res_block(config: StyleNetConfig):
     cd = config.compute_dtype
 
-    def cv(p, x):
-        return conv2d_nb(p, x, compute_dtype=cd, reflect=True) + p["b"].astype(cd)
+    def norm(pn, pc, x):
+        return conv_norm(pn, lambda x: _reflect_conv(
+            pc, x, compute_dtype=cd) + pc["b"].astype(cd), x)
 
     def res_block(p, x):
-        h = jax.nn.relu(instance_norm(p["an"], cv(p["a"], x)))
-        h = instance_norm(p["bn"], cv(p["b"], h))
-        return x + h
+        h = jax.nn.relu(norm(p["an"], p["a"], x))
+        return x + norm(p["bn"], p["b"], h)
 
     return res_block
 

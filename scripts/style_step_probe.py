@@ -5,7 +5,10 @@ ROADMAP S2's op map (PR 28). Compiles the step program of ``style_transfer(base_
 Engine builds it (uint8 batch in, uint8 batch out, the weights as state) at the cell's shape, times it, traces a few
 steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
-result shape, then the sum by stage. Run on the chip:
+result shape, then the sum by stage and, by stage, the time of each norm's two passes: ``norm_stats`` (the one
+reduction pass, ``models/layers.py::_norm_stats``; ``conv+norm_stats`` where XLA put it into the fusion of the conv
+that makes the activation, whose time it then shares) and ``norm_apply`` (the elementwise pass that also carries the
+relu and the residual add). Run on the chip:
 
     chiprun -- python scripts/style_step_probe.py            # writes chiprun_out/style_step_probe.json
 
@@ -25,9 +28,26 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
+def norm_part(body):
+    """Which pass of an instance norm a fused computation's lines hold, from the scopes of ``models/layers.py``: a
+    reduce compiled under ``norm_stats`` (with the convolution, where they share the fusion), else anything compiled
+    under ``norm_apply``, else nothing."""
+    if any(" reduce(" in line and "/norm_stats/" in line for line in body):
+        return "conv+norm_stats" if any(" convolution(" in line for line in body) else "norm_stats"
+    return "norm_apply" if any("/norm_apply/" in line for line in body) else ""
+
+
 def op_table(hlo_text, stages):
-    """{op name: (stage, result type)} from a compiled module's text: each instruction with the first of
-    ``stages`` (the scopes of ``_forward``) in its ``op_name``, ``-`` for what the Engine adds around the net."""
+    """{op name: (stage, result type, norm part)} from a compiled module's text: each instruction with the first of
+    ``stages`` (the scopes of ``_forward``) in its ``op_name``, ``-`` for what the Engine adds around the net, and
+    :func:`norm_part` of the computation it calls (of its own line, for an op outside any fusion)."""
+    bodies, body = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif body is not None:
+            body.append(line)
     table = {}
     for line in hlo_text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ", line)
@@ -36,7 +56,8 @@ def op_table(hlo_text, stages):
         scope = re.search(r'op_name="([^"]*)"', line)
         parts = scope.group(1).split("/") if scope else []
         stage = next((p for p in parts if p in stages), "-")
-        table[m.group(1)] = (stage, m.group(2)[:72])
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        table[m.group(1)] = (stage, m.group(2)[:72], norm_part(bodies.get(called.group(1), []) if called else [line]))
     return table
 
 
@@ -101,25 +122,32 @@ def main() -> int:
             key = reduce.short_name(name).lstrip("%")
             ops[key] = ops.get(key, 0.0) + dur / 1e6 / traced
         break
-    rows = sorted(((ms, name) + table.get(name, ("?", "")) for name, ms in ops.items()), reverse=True)
-    by_stage = {}
-    for ms, _, stage, _ in rows:
+    rows = sorted(((ms, name) + table.get(name, ("?", "", "")) for name, ms in ops.items()), reverse=True)
+    by_stage, by_part = {}, {}
+    for ms, _, stage, _, part in rows:
         by_stage[stage] = by_stage.get(stage, 0.0) + ms
+        if part:
+            stages = by_part.setdefault(part, {})
+            stages[stage] = stages.get(stage, 0.0) + ms
 
     report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
               "shape": list(shape), "stage_forms": forms, "compile_s": compile_s,
               "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
               "step_wall_ms": {"min": min(wall), "median": sorted(wall)[len(wall) // 2], "max": max(wall)},
-              "traced_ms_a_step": sum(ops.values()), "by_stage_ms": by_stage,
-              "ops": [{"ms": ms, "op": name, "stage": stage, "result": result} for ms, name, stage, result in rows]}
+              "traced_ms_a_step": sum(ops.values()), "by_stage_ms": by_stage, "norm_ms": by_part,
+              "ops": [{"ms": ms, "op": name, "stage": stage, "norm": part, "result": result}
+                      for ms, name, stage, result, part in rows]}
     print(f"[probe {report['device']}{' toy' if args.toy else ''}] shape {shape}: compile {compile_s:.1f} s, "
           f"scratch {report['temp_gib']:.2f} GiB, step wall min/median/max "
           f"{min(wall):.2f}/{report['step_wall_ms']['median']:.2f}/{max(wall):.2f} ms, "
           f"ops traced {report['traced_ms_a_step']:.2f} ms a step")
     print(f"[probe] stage_forms {forms}")
-    for ms, name, stage, result in rows[:args.top]:
-        print(f"[probe] {ms:8.3f} ms  {stage:6s} {name:32s} {result}")
+    for ms, name, stage, result, part in rows[:args.top]:
+        print(f"[probe] {ms:8.3f} ms  {stage:6s} {name:32s} {part:15s} {result}")
     print("[probe] by stage: " + ", ".join(f"{k} {v:.2f}" for k, v in sorted(by_stage.items(), key=lambda kv: -kv[1])))
+    for part, stages in sorted(by_part.items()):
+        print(f"[probe] {part} {sum(stages.values()):.2f}: "
+              + ", ".join(f"{k} {v:.2f}" for k, v in sorted(stages.items(), key=lambda kv: -kv[1])))
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)

@@ -325,31 +325,49 @@ def test_fast_conv_rewrites_match_reference_lowering():
     assert float(jnp.abs(a - b).max()) == 0.0
 
 
-def _plain_style_forward(params, x, config):
+def _two_pass_norm(p, x, eps=1e-5):
+    """The independent reference of the one-pass norms of models.layers:
+    the textbook two dependent float32 passes (mean, then the mean of the
+    squared centred values), as layers.py had them before PR 36."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=(1, 2), keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=(1, 2), keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+    return y.astype(x.dtype)
+
+
+def _plain_style_forward(params, x, config, two_pass=False):
     """The style net as the plain composition of the reference layers
     (conv2d_nb + instance_norm + upsample_nearest at full resolution):
-    what every stage of ``_forward`` computes, whatever form it runs in."""
-    from dvf_tpu.models.layers import conv2d_nb, instance_norm
+    what every stage of ``_forward`` computes, whatever form it runs in.
+    ``two_pass``: with the independent two-pass norm in place of
+    ``layers.instance_norm`` about its corner pivot."""
+    from dvf_tpu.models.layers import conv2d_nb, corner_pivot, instance_norm
 
     cd = config.compute_dtype
 
-    def cv(name, x, stride=1):
+    def cv(name, stride=1):
         p = params[name]
-        return conv2d_nb(p, x, stride=stride, compute_dtype=cd,
-                         reflect=True) + p["b"].astype(cd)
+        return lambda x: conv2d_nb(p, x, stride=stride, compute_dtype=cd,
+                                   reflect=True) + p["b"].astype(cd)
 
-    def nr(name, y):
-        return jax.nn.relu(instance_norm(params[name], y))
+    def norm(name, conv, x):
+        if two_pass:
+            return _two_pass_norm(params[name], conv(x))
+        return instance_norm(params[name], conv(x), corner_pivot(conv, x))
 
-    x = nr("stem_norm", cv("stem", x.astype(cd)))
-    x = nr("down1_norm", cv("down1", x, 2))
-    x = nr("down2_norm", cv("down2", x, 2))
+    def nr(name, conv, x):
+        return jax.nn.relu(norm(name, conv, x))
+
+    x = nr("stem_norm", cv("stem"), x.astype(cd))
+    x = nr("down1_norm", cv("down1", 2), x)
+    x = nr("down2_norm", cv("down2", 2), x)
     for i in range(config.n_residual):
-        h = nr(f"res{i}_an", cv(f"res{i}_a", x))
-        x = x + instance_norm(params[f"res{i}_bn"], cv(f"res{i}_b", h))
-    x = nr("up1_norm", cv("up1", upsample_nearest(x, 2)))
-    x = nr("up2_norm", cv("up2", upsample_nearest(x, 2)))
-    return 0.5 * (jnp.tanh(cv("out", x).astype(jnp.float32)) + 1.0)
+        h = nr(f"res{i}_an", cv(f"res{i}_a"), x)
+        x = x + norm(f"res{i}_bn", cv(f"res{i}_b"), h)
+    x = nr("up1_norm", cv("up1"), upsample_nearest(x, 2))
+    x = nr("up2_norm", cv("up2"), upsample_nearest(x, 2))
+    return 0.5 * (jnp.tanh(cv("out")(x).astype(jnp.float32)) + 1.0)
 
 
 def _random_style_params(config, seed=0):
@@ -366,15 +384,21 @@ F32_SMALL = StyleNetConfig(base_channels=8, n_residual=2,
                            compute_dtype=jnp.float32)
 
 
+@pytest.mark.parametrize("two_pass,tol", [(False, 1e-5), (True, 2e-5)])
 @pytest.mark.parametrize("hw,form", [
     ((64, 96), "phase"),     # out conv at phase factor 4
     ((66, 98), "phase"),     # even, not a multiple of 4: out at factor 2
     ((65, 97), "plain"),     # odd geometry keeps the plain path
 ])
-def test_style_net_phase_forward_matches_plain_composition(hw, form):
+def test_style_net_phase_forward_matches_plain_composition(hw, form, two_pass,
+                                                           tol):
     """The forward with its full-resolution stages in the phase domain is
     the plain composition of reference layers (f32 pins the comparison to
-    the re-indexing, not rounding), at each geometry class."""
+    the re-indexing, not rounding), at each geometry class — and the same
+    composition over the independent two-pass norm, to what two float32
+    roundings of nine norms and ten convs differ by (either is 0.7-1.5e-5
+    from the same composition in float64, over three geometries and three
+    seeds; the two are 0.5-1.1e-5 apart)."""
     from dvf_tpu.models.style_transfer import stage_forms
 
     params = _random_style_params(F32_SMALL)
@@ -382,10 +406,10 @@ def test_style_net_phase_forward_matches_plain_composition(hw, form):
                     .astype(np.float32))
     forms = stage_forms(F32_SMALL, x.shape)
     assert {forms[k] for k in ("stem", "down1", "up2", "out")} == {form}
-    want = _plain_style_forward(params, x, F32_SMALL)
+    want = _plain_style_forward(params, x, F32_SMALL, two_pass)
     got = apply_style_net(params, x, F32_SMALL)
     assert got.shape == want.shape
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(got - want).max()) < tol
 
 
 @pytest.mark.parametrize("k,cin,cout,stride,fold", [
@@ -431,23 +455,92 @@ def test_phase_reflect_pad_matches_full_resolution_reflect(r):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_instance_norm_phase_matches_full_resolution_norm():
+def _norm_case(hw, dtype, mean=1.0, spread=3.0, c=6, seed=0):
+    rng = np.random.RandomState(seed)
+    p = {"scale": jnp.asarray(rng.rand(c).astype(np.float32) + 0.5),
+         "bias": jnp.asarray(rng.rand(c).astype(np.float32))}
+    x = (spread * rng.randn(2, *hw, c) + mean).astype(np.float32)
+    return p, jnp.asarray(x).astype(dtype)
+
+
+def _one_pass_norm(p, x, form, pivot):
+    """``layers.instance_norm`` of ``x``, or ``instance_norm_phase`` of
+    its phase image brought back, sums about ``pivot`` (None: each
+    channel's first position)."""
     from dvf_tpu.models.layers import (
         depth_to_space, instance_norm, instance_norm_phase, space_to_depth)
 
+    if form == "plain":
+        return instance_norm(p, x, pivot)
+    if pivot is not None:
+        pivot = jnp.tile(pivot, 4)       # the pivot's phase image
+    return depth_to_space(instance_norm_phase(p, space_to_depth(x, 2), pivot), 2)
+
+
+@pytest.mark.parametrize("pivot", ["first_position", "bias"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("form,hw", [
+    ("plain", (8, 12)), ("plain", (9, 13)),
+    ("phase", (8, 12)), ("phase", (10, 14)),   # the phase image 4x6, 5x7
+])
+def test_one_pass_norm_matches_two_pass_reference(form, hw, dtype, pivot):
+    """The norms' one pass (sum and sum of squares about a pivot) gives
+    the two dependent float32 passes' result: to 1e-5 on float32 input,
+    to a bfloat16 rounding of the same float32 values on bfloat16 input."""
+    p, x = _norm_case(hw, dtype)
+    piv = None if pivot == "first_position" else jnp.full((6,), 0.3)
+    got = _one_pass_norm(p, x, form, piv)
+    want = _two_pass_norm(p, x)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    err = float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max())
+    assert err < (1e-5 if dtype == "float32" else 2.0 ** -5)
+
+
+def _ill_conditioned(hw=(32, 48)):
+    """Channel 0: mean 100, spread 1 (a bare E[x^2] - E[x]^2 loses five
+    of float32's seven digits there); channel 1: mean 0, spread 1e-3."""
     rng = np.random.RandomState(0)
-    p = {"scale": jnp.asarray(rng.rand(6).astype(np.float32)),
-         "bias": jnp.asarray(rng.rand(6).astype(np.float32))}
-    x = jnp.asarray((3.0 * rng.randn(2, 8, 12, 6) + 1.0).astype(np.float32))
-    want = instance_norm(p, x)
-    got = depth_to_space(instance_norm_phase(p, space_to_depth(x, 2)), 2)
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    x = rng.randn(2, *hw, 2).astype(np.float32) * np.float32([1.0, 1e-3])
+    p = {"scale": jnp.ones((2,)), "bias": jnp.zeros((2,))}
+    return p, jnp.asarray(x + np.float32([100.0, 0.0]))
 
 
-def test_style_net_gradient_step_through_phase_forward():
+def _two_pass_norm64(x, eps=1e-5):
+    """Two passes in float64 numpy (unit scale, zero bias): where the mean
+    is large the float32 two-pass form is itself 6e-5 off (its mean is
+    rounded at the mean's size), the one pass about a pivot is not."""
+    x = np.asarray(x, np.float64)
+    mean = x.mean(axis=(1, 2), keepdims=True)
+    var = np.square(x - mean).mean(axis=(1, 2), keepdims=True)
+    return (x - mean) / np.sqrt(var + eps)
+
+
+def test_bare_one_pass_variance_fails_where_the_mean_is_large():
+    """What the pivot is for: the same one pass about 0."""
+    from dvf_tpu.models.layers import instance_norm
+
+    p, x = _ill_conditioned()
+    bare = instance_norm(p, x, jnp.zeros((2,)))
+    assert np.abs(np.asarray(bare) - _two_pass_norm64(x)).max() > 1e-3
+
+
+@pytest.mark.parametrize("form", ["plain", "phase"])
+@pytest.mark.parametrize("pivot", ["first_position", "bias"])
+def test_one_pass_norm_is_well_conditioned_about_its_pivot(form, pivot):
+    """About the first position, or about the bias the conv before added
+    (here what makes the mean large), the difference does not cancel."""
+    p, x = _ill_conditioned()
+    piv = None if pivot == "first_position" else jnp.asarray([100.0, 0.0])
+    got = _one_pass_norm(p, x, form, piv)
+    assert np.abs(np.asarray(got) - _two_pass_norm64(x)).max() < 1e-5
+
+
+@pytest.mark.parametrize("two_pass,tol", [(False, 1e-3), (True, 1e-4)])
+def test_style_net_gradient_step_through_phase_forward(two_pass, tol):
     """One gradient step through the phase-domain forward moves the
     params as the plain composition's gradient does (train/style.py
-    differentiates the same ``_forward``)."""
+    differentiates the same ``_forward``) — and as the gradient through
+    the two-pass norms does, to 1e-4 of each leaf's largest entry."""
     params = _random_style_params(F32_SMALL)
     x = jnp.asarray(np.random.RandomState(2).rand(1, 32, 48, 3)
                     .astype(np.float32))
@@ -458,11 +551,12 @@ def test_style_net_gradient_step_through_phase_forward():
         return lambda p: jnp.mean((fwd(p, x, F32_SMALL) - target) ** 2)
 
     g_phase = jax.grad(loss(apply_style_net))(params)
-    g_plain = jax.grad(loss(_plain_style_forward))(params)
+    g_plain = jax.grad(loss(
+        lambda p, x, cfg: _plain_style_forward(p, x, cfg, two_pass)))(params)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_phase),
                             jax.tree.leaves(g_plain)):
         scale = float(jnp.abs(b).max()) + 1e-8
-        assert float(jnp.abs(a - b).max()) < 1e-3 * scale + 1e-7, (
+        assert float(jnp.abs(a - b).max()) < tol * scale + 1e-7, (
             jax.tree_util.keystr(path))
     stepped = jax.tree.map(lambda p, g: p - 0.1 * g, params, g_phase)
     assert float(loss(apply_style_net)(stepped)) < float(
@@ -504,6 +598,68 @@ def test_style_net_720p_jaxpr_holds_no_lane_starved_tensor():
     assert (16, 360, 2, 640, 2, 64) not in shapes
     assert (16, 180, 2, 320, 2, 128) not in shapes       # nor ahead of up1
     assert (16, 360, 640, 128) in shapes         # the phase tensors themselves
+
+
+def _big_reduces(jaxpr, in_taint, scope, found):
+    """Walk a jaxpr in order. A variable's taint is the set of full-tensor
+    reductions (rank-4 operand of more positions than a norm's pivot is
+    taken from) it depends on without a convolution in between (a conv's
+    output starts clean: the next norm's activation). ``found`` collects,
+    per such reduction, (scope, operand's taint)."""
+    from dvf_tpu.models.layers import CORNER
+
+    def full_tensor(aval):
+        return aval.ndim == 4 and aval.shape[1] * aval.shape[2] > CORNER ** 2
+
+    taint = dict(zip(jaxpr.invars, in_taint))
+    get = lambda v: frozenset() if type(v).__name__ == "Literal" else taint.get(v, frozenset())
+    for eqn in jaxpr.eqns:
+        ins = [get(v) for v in eqn.invars]
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "conv_general_dilated":
+            outs = [frozenset()] * len(eqn.outvars)
+        elif eqn.primitive.name.startswith("reduce_") and full_tensor(eqn.invars[0].aval):
+            found.append((here, ins[0]))
+            outs = [frozenset([len(found)])]
+        elif len(subs) == 1 and len(subs[0].invars) == len(ins):
+            outs = _big_reduces(subs[0], ins, here, found)
+        else:
+            outs = [frozenset().union(*ins)] * len(eqn.outvars)
+        taint.update(zip(eqn.outvars, outs))
+    return [get(v) for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("hw", [(64, 96), (65, 97)])
+def test_style_step_norms_take_one_reduction_pass_each(hw):
+    """Structure of the lowered step at a toy shape, no compile: every
+    norm has ONE reduction pass over its activation — the sum and the sum
+    of squares, siblings over the conv's output about a pivot that is no
+    reduction's result (so no reduce reads a centred ``x - mean`` of the
+    full tensor, which would be a second, dependent pass) — and every such
+    pass sits under a ``norm_stats`` scope inside its stage's."""
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    filt = get_filter("style_transfer", base_channels=8, n_residual=2)
+    shape = (2, *hw, 3)
+    state = jax.eval_shape(lambda: filt.init_state(shape, jnp.float32))
+
+    def step(batch, state):            # the body of Engine._build_step
+        y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
+        return to_uint8(y), new_state
+
+    jaxpr = jax.make_jaxpr(step)(jax.ShapeDtypeStruct(shape, jnp.uint8), state)
+    found = []
+    _big_reduces(jaxpr.jaxpr, [frozenset()] * len(jaxpr.jaxpr.invars), "", found)
+    n_norms = 5 + 2 * 2
+    assert len(found) == 2 * n_norms, [s for s, _ in found]
+    stages = ("stem", "down1", "down2", "trunk", "up1", "up2")
+    for scope, operand_taint in found:
+        parts = scope.split("/")
+        assert "norm_stats" in parts, scope
+        assert any(st in parts[:parts.index("norm_stats")] for st in stages), scope
+        assert not operand_taint, f"{scope}: a reduce over another reduce's result"
 
 
 def test_espcn_fast_convs_parity():

@@ -1,0 +1,60 @@
+"""What the TPU's compiler makes of the style net's norms, with no chip: the
+compiler is installed here and compiles for a v5e that is described, not
+attached (on-chip-measurement guide, section 2). Nothing runs, so nothing
+here is a time. All such compiles live in this one file, behind a fixture:
+one worker loads the TPU library, and only once a test of this file runs."""
+
+import importlib.util
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _probe():
+    path = os.path.join(os.path.dirname(__file__), "..", "scripts", "style_step_probe.py")
+    spec = importlib.util.spec_from_file_location("style_step_probe", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_trunk_block_norm_sums_ride_in_their_convs_fusion(one_chip):
+    """A residual block of the style trunk at the cell's size (16 frames'
+    180x320x128, bfloat16): each of its two convs carries both of its
+    norm's sums in its own fusion (``conv+norm_stats``), each norm has one
+    apply pass, and no reduction over the activation is an op of its own —
+    what PR 36's 13 ms of the 84 ms step rest on (PERF.md §5)."""
+    from dvf_tpu.models.style_transfer import StyleNetConfig, _pp_res_block
+
+    c = StyleNetConfig().widths[2]
+    struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    conv = {"w": struct(3, 3, c, c), "b": struct(c)}
+    norm = {"scale": struct(c), "bias": struct(c)}
+    x = jax.ShapeDtypeStruct((16, 180, 320, c), jnp.bfloat16, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache    # a described device's entry cannot be read back
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(_pp_res_block(StyleNetConfig())).lower(
+            {"a": conv, "an": norm, "b": conv, "bn": norm}, x).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    text = compiled.as_text()
+    table = _probe().op_table(text, ())
+    entry = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = ", text[text.index("\nENTRY"):], re.M)
+    parts = [table[name][2] for name in entry if table[name][2]]
+    assert sorted(parts) == ["conv+norm_stats"] * 2 + ["norm_apply"] * 2, parts
