@@ -76,12 +76,19 @@ def _forward(params: Params, batch: jnp.ndarray, config: EspcnConfig,
             y = reduce(y)
         return y + p["b"].astype(cd)
 
+    # Every stage carries a ``jax.named_scope``, as the style net's do, so
+    # the compiled HLO's ``op_name`` says whose each fusion is
+    # (scripts/style_step_probe.py --model espcn).
     x = batch.astype(cd)
-    x = jax.nn.relu(cv("feat", x))
-    x = jax.nn.relu(cv("map", x, reduce=row_reduce))
-    x = cv("head", x)
-    y = depth_to_space(x.astype(jnp.float32), config.scale)
-    return jnp.clip(y, 0.0, 1.0).astype(batch.dtype)
+    with jax.named_scope("feat"):
+        x = jax.nn.relu(cv("feat", x))
+    with jax.named_scope("map"):
+        x = jax.nn.relu(cv("map", x, reduce=row_reduce))
+    with jax.named_scope("head"):
+        x = cv("head", x)
+    with jax.named_scope("shuffle"):
+        y = depth_to_space(x.astype(jnp.float32), config.scale)
+        return jnp.clip(y, 0.0, 1.0).astype(batch.dtype)
 
 
 def apply_espcn(params: Params, batch: jnp.ndarray,

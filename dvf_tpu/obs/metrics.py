@@ -217,10 +217,13 @@ class IngestStats:
         self.stage_ms_total = 0.0
         self.put_ms_total = 0.0
         self.wait_ms_total = 0.0
+        self.bytes_total = 0       # bytes staged to the device (whole
+        #   padded batches: what crossed the link, not the valid rows)
 
     def record_batch(self, stage_ms: float, put_ms: float,
-                     wait_ms: float) -> None:
+                     wait_ms: float, nbytes: int = 0) -> None:
         self.batches += 1
+        self.bytes_total += nbytes
         self.stage_ms_total += stage_ms
         self.put_ms_total += put_ms
         self.wait_ms_total += wait_ms
@@ -250,6 +253,7 @@ class IngestStats:
             "stage_ms_total": round(self.stage_ms_total, 4),
             "h2d_put_ms_total": round(self.put_ms_total, 4),
             "h2d_wait_ms_total": round(self.wait_ms_total, 4),
+            "bytes_total": self.bytes_total,
             "h2d_block_ms": (round(self.h2d_block_ms, 4)
                              if self.h2d_block_ms else None),
             "overlap_efficiency": (round(eff, 4)
@@ -304,6 +308,8 @@ class EgressStats:
         #   0 on the packed layout, which has no pool)
         self.d2h_wait_ms_total = 0.0     # blocked on shard host copies
         self.copy_ms_total = 0.0         # scatter into the output slab
+        self.bytes_total = 0             # bytes landed on the host, at the
+        #   OUTPUT geometry (four for each one staged under a x2 upscale)
         self.encode_batches = 0
         self.encode_ms_total = 0.0       # in-pool wall span per batch
         self.encode_wait_ms_total = 0.0  # exposed drain wait per batch
@@ -315,9 +321,10 @@ class EgressStats:
         self.send_ms_total = 0.0
 
     def record_fetch(self, wait_ms: float, copy_ms: float,
-                     packed: bool = False) -> None:
+                     packed: bool = False, nbytes: int = 0) -> None:
         self.batches += 1
         self.packed_batches += packed
+        self.bytes_total += nbytes
         self.d2h_wait_ms_total += wait_ms
         self.copy_ms_total += copy_ms
 
@@ -363,6 +370,7 @@ class EgressStats:
             # Cumulative totals beside the lifetime means (window deltas).
             "d2h_wait_ms_total": round(self.d2h_wait_ms_total, 4),
             "copy_ms_total": round(self.copy_ms_total, 4),
+            "bytes_total": self.bytes_total,
             "encode_ms_total": round(self.encode_ms_total, 4),
             "encode_wait_ms_total": round(self.encode_wait_ms_total, 4),
             "entropy_ms_total": round(self.entropy_ms_total, 4),

@@ -157,8 +157,9 @@ MEASURED_DEFAULTS = {
     # static case in models.analysis). CPU (full 540p geometry,
     # benchmarks/cpu/): "ref" wins (0.9 vs 0.4) — the phase decomposition
     # buys MXU lane utilization, which AVX has no analog of. TPU stays
-    # unpinned until an on-chip A/B exists (no cell runs sr2x_540p, PERF.md
-    # §7e). The style net's counterpart is no option any more: its stages
+    # unpinned and falls back to "ref", which is what the cell
+    # sr2x_540p.bulk runs; one on-chip probe at its shape read fast 78.3
+    # ms a step against ref 34.6 (PERF.md §7, PR 37). The style net's counterpart is no option any more: its stages
     # take the phase form from their shapes (models.style_transfer.
     # stage_forms; the on-chip A/B is PERF.md §6, PR 28).
     "espcn_fast": {

@@ -406,7 +406,8 @@ class ShardedBatchFetcher:
             # than corrupt the slab. (Intentional monolithic mode and
             # non-jax results land here too: the classic fetch.)
             out = np.asarray(result)
-            self.stats.record_fetch(wait_ms=0.0, copy_ms=0.0)
+            self.stats.record_fetch(wait_ms=0.0, copy_ms=0.0,
+                                    nbytes=out.nbytes)
             return out
         # Compute wait is not D2H: exclude it from the exposed-transfer
         # clock so overlap_efficiency judges the fetch, not the device.
@@ -445,7 +446,8 @@ class ShardedBatchFetcher:
                     EGRESS_D2H, t0 + off, t2 + off, self.track,
                     rows=f"{b0.start or 0}:{b0.stop}", bytes=host.nbytes,
                     layout=TRANSFER_PLAIN)
-        self.stats.record_fetch(wait_ms=wait_s * 1e3, copy_ms=copy_s * 1e3)
+        self.stats.record_fetch(wait_ms=wait_s * 1e3, copy_ms=copy_s * 1e3,
+                                nbytes=slab.nbytes)
         return slab
 
     def _fetch_packed(self, packed: PackedBatch) -> np.ndarray:
@@ -473,7 +475,7 @@ class ShardedBatchFetcher:
                 rows=f"0:{out.shape[0]}", bytes=out.nbytes,
                 layout=TRANSFER_PACKED)
         self.stats.record_fetch(wait_ms=(t1 - t0) * 1e3, copy_ms=0.0,
-                                packed=True)
+                                packed=True, nbytes=out.nbytes)
         return out
 
     def owns(self, out: np.ndarray) -> bool:

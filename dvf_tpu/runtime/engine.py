@@ -203,6 +203,9 @@ class Engine:
         self.out_shape: Optional[Tuple[int, ...]] = None  # compiled output
         self.out_dtype = None                             # signature — what
         #   the egress fetcher sizes its host slabs from (set by compile())
+        self.step_donates_input: Optional[bool] = None  # whether the
+        #   compiled step aliases its input batch: False where the result
+        #   has another geometry or dtype (set by _build_step)
         self._out_sharding = None
         self.last_compile_ms: Optional[float] = None  # wall duration of
         #   the most recent compile() (trace + XLA compile + warmup +
@@ -350,10 +353,10 @@ class Engine:
             self._state,  # built just before _build_step in compile()
             *map_avals,
         )[0]
-        donate = ((0, 1)
-                  if (out_aval.shape == tuple(batch_shape)
-                      and out_aval.dtype == np.dtype(in_dtype))
-                  else (1,))
+        self.step_donates_input = (
+            out_aval.shape == tuple(batch_shape)
+            and out_aval.dtype == np.dtype(in_dtype))
+        donate = (0, 1) if self.step_donates_input else (1,)
         return jax.jit(
             step,
             in_shardings=(self._sharding, state_shardings)
@@ -880,6 +883,7 @@ class Engine:
             for name in ("_step", "_tabled", "_signature", "_state",
                          "_sharding", "_batch_replicated",
                          "_exec_filter", "out_shape", "out_dtype",
+                         "step_donates_input",
                          "_out_sharding", "h2d_block_ms", "d2h_block_ms",
                          "step_block_ms", "last_compile_ms",
                          "state_bytes"):

@@ -159,6 +159,8 @@ class ShardedBatchAssembler:
             raise ValueError("ingest depth must be >= 1")
         self.batch_shape = tuple(batch_shape)
         self.dtype = np.dtype(dtype)
+        self.batch_nbytes = (int(np.prod(self.batch_shape))
+                             * self.dtype.itemsize)
         self.sharding = sharding
         self.mode = mode
         self.depth = depth
@@ -510,4 +512,5 @@ class BatchBuilder:
             stage_ms=self._stage_s * 1e3,
             put_ms=self._put_s * 1e3,
             wait_ms=self._wait_s * 1e3,
+            nbytes=self.asm.batch_nbytes,
         )

@@ -577,13 +577,29 @@ class _Bucket:
 
     # -- observability ---------------------------------------------------
 
+    def out_bytes(self) -> Optional[int]:
+        """Bytes of one batch's result as the engine compiled it (None
+        before the first compile): what the collect side will land."""
+        shape = getattr(self.engine, "out_shape", None)
+        if not shape:
+            return None
+        return int(np.prod(shape)) * np.dtype(self.engine.out_dtype).itemsize
+
     def stats_row(self) -> dict:
         live = list(self.sessions.values())
         agg = LatencyStats.merged([s.latency for s in live])
+        out_shape = getattr(self.engine, "out_shape", None)
         row = {
             "signature": self.label(),
             "op_chain": self.op_chain,
             "batch_size": self.batch_size,
+            # What the compiled step hands back, as the engine has it
+            # (None before the first compile): a geometry-changing filter
+            # delivers other frames than it was sent, and cannot alias
+            # its input batch.
+            "out_geometry": list(out_shape[1:]) if out_shape else None,
+            "step_donates_input": getattr(self.engine,
+                                          "step_donates_input", None),
             "mean_valid_rows": self.mean_valid_rows,
             "open_sessions": len(live),
             "queue_depth": sum(len(s.ingress) + len(s.pending)
@@ -3512,12 +3528,13 @@ class ServeFrontend:
                                     frames=plan.valid,
                                     bucket=bucket.label())
                     n_sess = len({slot.session.id for slot in plan.slots})
+                    out_bytes = bucket.out_bytes()
                     tracer.complete("dispatch:permit_wait", st.t_chosen,
                                     t0, TRACK_DISPATCH, seq=seq,
-                                    sessions=n_sess)
+                                    sessions=n_sess, out_bytes=out_bytes)
                     tracer.complete("dispatch:assemble_h2d", t0,
                                     st.t_submit, TRACK_DISPATCH, seq=seq,
-                                    sessions=n_sess)
+                                    sessions=n_sess, out_bytes=out_bytes)
                 # In-flight window: registered from now until the collect
                 # side materializes (or discards) it; carries the plan so
                 # a recovery can shed the sessions' claims even for a

@@ -1,9 +1,11 @@
 #!/usr/bin/env python3
-"""Where the style net's step goes, op by op, with each op's stage.
+"""Where a served net's step goes, op by op, with each op's stage.
 
-ROADMAP S2's op map (PR 28). Compiles the step program of ``style_transfer(base_channels=32, n_residual=5)`` as the
-Engine builds it (uint8 batch in, uint8 batch out, the weights as state) at the cell's shape, times it, traces a few
-steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
+ROADMAP S2's op map (PR 28), for the style net by default. ``--model espcn`` reads the upscaling service's step the
+same way (``super_resolution(scale=2)`` at 16 x 540 x 960 in, 1080 x 1920 out; the scopes of ``models/espcn.py``:
+``feat``, ``map``, ``head``, ``shuffle``; ``--fast-convs`` probes its space-to-depth form). Compiles the step program
+of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
+weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
 result shape, then the sum by stage and, by stage, the time of each norm's two passes: ``norm_stats`` (the one
 reduction pass, ``models/layers.py::_norm_stats``; ``conv+norm_stats`` where XLA put it into the fusion of the conv
@@ -11,6 +13,8 @@ that makes the activation, whose time it then shares) and ``norm_apply`` (the el
 relu and the residual add). Run on the chip:
 
     chiprun -- python scripts/style_step_probe.py            # writes chiprun_out/style_step_probe.json
+
+    chiprun -- python scripts/style_step_probe.py --model espcn   # chiprun_out/espcn_step_probe.json
 
 ``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
 nothing.
@@ -27,6 +31,26 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+def _style_stages(kwargs, shape):
+    from dvf_tpu.models.style_transfer import StyleNetConfig, stage_forms
+
+    return stage_forms(StyleNetConfig(**kwargs), shape)
+
+
+def _espcn_stages(kwargs, shape):
+    form = "s2d" if kwargs.get("fast_convs") else "plain"
+    return {"feat": form, "map": form, "head": form, "shuffle": "plain"}
+
+
+# model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))
+_ESPCN = {"scale": 2, "fast_convs": False, "dtype": "bfloat16"}          # as chipbench/configs/sr2x_540p.json
+MODELS = {
+    "style": ("style_transfer", (720, 1280), {"base_channels": 32, "n_residual": 5},
+              {"base_channels": 8, "n_residual": 2}, _style_stages),
+    "espcn": ("super_resolution", (540, 960), _ESPCN, _ESPCN, _espcn_stages),
+}
+
 
 def norm_part(body):
     """Which pass of an instance norm a fused computation's lines hold, from the scopes of ``models/layers.py``: a
@@ -67,15 +91,17 @@ def main() -> int:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--steps", type=int, default=8, help="timed steps (three more are traced)")
     ap.add_argument("--top", type=int, default=30, help="ops printed")
-    ap.add_argument("--out", default="chiprun_out/style_step_probe.json")
+    ap.add_argument("--model", choices=sorted(MODELS), default="style")
+    ap.add_argument("--fast-convs", action="store_true", help="the filter's fast_convs=True (espcn has it)")
+    ap.add_argument("--out", default=None, help="default chiprun_out/<model>_step_probe.json")
     args = ap.parse_args()
+    args.out = args.out or f"chiprun_out/{args.model}_step_probe.json"
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from chipbench import reduce
-    from dvf_tpu.models.style_transfer import StyleNetConfig, stage_forms
     from dvf_tpu.ops import get_filter
     from dvf_tpu.utils.image import to_float, to_uint8
 
@@ -83,9 +109,12 @@ def main() -> int:
     if dev.platform == "cpu" and not args.toy:
         print("no accelerator: run through chiprun, or pass --toy", file=sys.stderr)
         return 3
-    shape = (2, 64, 96, 3) if args.toy else (args.batch, 720, 1280, 3)
-    kwargs = {"base_channels": 8, "n_residual": 2} if args.toy else {"base_channels": 32, "n_residual": 5}
-    filt = get_filter("style_transfer", **kwargs)
+    name, (h, w), kwargs, toy_kwargs, stages_of = MODELS[args.model]
+    shape = (2, 64, 96, 3) if args.toy else (args.batch, h, w, 3)
+    kwargs = dict(toy_kwargs if args.toy else kwargs)
+    if args.fast_convs:
+        kwargs["fast_convs"] = True
+    filt = get_filter(name, **kwargs)
 
     def step(batch, state):            # the body of Engine._build_step
         y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
@@ -96,7 +125,7 @@ def main() -> int:
     t = time.perf_counter()
     compiled = jax.jit(step).lower(batch, state).compile()
     compile_s = time.perf_counter() - t
-    forms = stage_forms(StyleNetConfig(**kwargs), shape)
+    forms = stages_of(kwargs, shape)
     table = op_table(compiled.as_text(), forms)
     mem = compiled.memory_analysis()
 
@@ -131,7 +160,8 @@ def main() -> int:
             stages[stage] = stages.get(stage, 0.0) + ms
 
     report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
-              "shape": list(shape), "stage_forms": forms, "compile_s": compile_s,
+              "model": args.model, "filter": filt.name, "filter_kwargs": kwargs, "shape": list(shape),
+              "stage_forms": forms, "compile_s": compile_s,
               "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
               "step_wall_ms": {"min": min(wall), "median": sorted(wall)[len(wall) // 2], "max": max(wall)},
               "traced_ms_a_step": sum(ops.values()), "by_stage_ms": by_stage, "norm_ms": by_part,
