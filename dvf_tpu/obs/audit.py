@@ -315,20 +315,26 @@ def replay_tolerance(filt, in_dtype, default: float) -> float:
     return float(default)
 
 
-def maybe_corrupt_device(chaos, out: np.ndarray) -> np.ndarray:
+def maybe_corrupt_device(chaos, out):
     """The ``corrupt_device`` chaos site: when a rule fires, return a
     copy of ``out`` with ONE element of row 0 perturbed — the silent
     device corruption the shadow replay must catch (the perturbed
     frame still has valid geometry, still encodes, still delivers).
     Row 0 deterministically, so a test pinning "non-faulted sessions
-    stay bit-identical" can arrange its victim in slot 0."""
+    stay bit-identical" can arrange its victim in slot 0. Of rows that
+    landed in buffers of their own (``egress.LandedRows``) only row 0
+    is copied; the others stay the buffers they landed in."""
     if chaos is None or not chaos.perturb("corrupt_device"):
         return out
-    out = np.array(out)  # the fetch slab/view may be read-only
-    row = out[0]
+    if isinstance(out, np.ndarray):
+        out = np.array(out)  # the fetch slab/view may be read-only
+        row = out[0]
+    else:
+        row = np.array(out[0])
+        out = type(out)((row, *out[1:]))
     flat = row.reshape(-1)
-    if np.issubdtype(out.dtype, np.integer):
-        flat[0] = np.bitwise_xor(flat[0], np.array(0x40, out.dtype))
+    if np.issubdtype(row.dtype, np.integer):
+        flat[0] = np.bitwise_xor(flat[0], np.array(0x40, row.dtype))
     else:
         flat[0] = flat[0] + 1.0
     return out

@@ -303,13 +303,18 @@ class EgressStats:
         #   (runtime.egress.egress_pack) and hands out the landed buffer
         self.batches = 0
         self.packed_batches = 0          # fetched in the packed layout
+        self.row_landed_batches = 0      # ... as a host buffer a row
+        #   (runtime.egress.LandedRows): nobody copies a row to keep it
+        self.rows_landed_total = 0       # rows of those that crossed
+        self.rows_skipped_total = 0      # padding rows that never did
         self.pool_allocs = 0             # slab-pool constructions (stays 1
         #   across a steady-state run — the allocation-regression tests;
         #   0 on the packed layout, which has no pool)
         self.d2h_wait_ms_total = 0.0     # blocked on shard host copies
         self.copy_ms_total = 0.0         # scatter into the output slab
         self.bytes_total = 0             # bytes landed on the host, at the
-        #   OUTPUT geometry (four for each one staged under a x2 upscale)
+        #   OUTPUT geometry (four for each one staged under a x2 upscale);
+        #   a skipped padding row's are not among them
         self.encode_batches = 0
         self.encode_ms_total = 0.0       # in-pool wall span per batch
         self.encode_wait_ms_total = 0.0  # exposed drain wait per batch
@@ -321,9 +326,13 @@ class EgressStats:
         self.send_ms_total = 0.0
 
     def record_fetch(self, wait_ms: float, copy_ms: float,
-                     packed: bool = False, nbytes: int = 0) -> None:
+                     packed: bool = False, nbytes: int = 0,
+                     rows_landed: int = 0, rows_skipped: int = 0) -> None:
         self.batches += 1
         self.packed_batches += packed
+        self.row_landed_batches += rows_landed > 0
+        self.rows_landed_total += rows_landed
+        self.rows_skipped_total += rows_skipped
         self.bytes_total += nbytes
         self.d2h_wait_ms_total += wait_ms
         self.copy_ms_total += copy_ms
@@ -365,6 +374,9 @@ class EgressStats:
             "transfer_layout": self.transfer_layout,
             "batches": self.batches,
             "packed_batches": self.packed_batches,
+            "row_landed_batches": self.row_landed_batches,
+            "rows_landed_total": self.rows_landed_total,
+            "rows_skipped_total": self.rows_skipped_total,
             "d2h_wait_ms": round(self.d2h_wait_ms_total / n, 4),
             "copy_ms": round(self.copy_ms_total / n, 4),
             # Cumulative totals beside the lifetime means (window deltas).

@@ -41,33 +41,49 @@ whole words (``W·C % 4 == 0``) held as one shard on one device:
 
 - the fetcher compiles :func:`egress_pack` (module ``jit_egress_pack``)
   when it is built: the interleave as a permutation on the MXU,
-  ``uint8[B,H,W,C]`` → ``uint32[B,H,W·C/4]``, the same bytes in
-  row-major order (3–5 ms of device time for 199 MB, 1.1 for 44 MB);
-- ``prefetch(result)`` runs it, starts the words' ``copy_to_host_async``
-  and returns a :class:`PackedBatch`, which the lane's in-flight handle
-  carries in the result's place (the device frees the result once the
-  pack has read it);
-- ``fetch(packed, slot)`` waits for that transfer and returns the buffer
-  it landed in, viewed as ``uint8[B,H,W,C]``: read-only, one pass over
-  the bytes (the runtime's), no slab, no copy (``copy_ms`` is 0), no
-  pool allocated. Rows that outlive the batch are copied by whoever
-  keeps them, as on the monolithic path (the serve router always does).
+  ``uint8[B,H,W,C]`` → B arrays ``uint32[H,W·C/4]``, one a row, the same
+  bytes in row-major order (3–5 ms of device time for 199 MB, 1.1 for
+  44 MB);
+- ``prefetch(result, valid)`` runs it, starts ``copy_to_host_async`` on
+  each of the first ``valid`` rows and returns a :class:`PackedBatch`,
+  which the lane's in-flight handle carries in the result's place (the
+  device frees the result once the pack has read it). A padding row
+  (``row >= valid``) is dropped there: it never crosses the link;
+- ``fetch(packed, slot)`` waits for those transfers row by row and
+  returns :class:`LandedRows`: each row the buffer it landed in, viewed
+  as ``uint8[H,W,C]``: read-only, one pass over the bytes (the
+  runtime's), no slab, no copy (``copy_ms`` is 0), no pool allocated.
 
-``EgressStats.transfer_layout`` (``"u32rows"`` / ``"plain"``) and
-``packed_batches`` say that it engaged; the ``egress_d2h`` and
+**A delivered frame is the buffer it landed in (PR 39).** A row of
+:class:`LandedRows` shares memory with nothing: it may sit in a reorder
+buffer, an out queue or a replay ring for as long as it likes and keeps
+1/B of the batch alive on the host and nothing on the device (the
+device rows go with the :class:`PackedBatch`), so nobody copies it: as
+views of one landed ``uint32[B,H,W·C/4]`` buffer the rows had to be
+copied out by the serve router, 100–199 MB a batch on the collect
+thread, and B transfers land at 9–13 GB/s on this host where one lands
+at 3 (``scripts/d2h_probe.py``, PERF.md §6). Every other path hands out
+one ``ndarray`` a batch, whose rows are views of it; those are copied
+by whoever keeps them beyond the batch (``serve/router.py::route``
+tells the two apart by what ``fetch`` returned, nothing else).
+
+``EgressStats.transfer_layout`` (``"u32rows"`` / ``"plain"``),
+``packed_batches`` / ``row_landed_batches`` and ``rows_landed_total`` /
+``rows_skipped_total`` say that it engaged; the ``egress_d2h`` and
 ``collect:d2h`` spans carry ``layout=``. No flag: a result sharded over
 several devices, another dtype or rank, rows that are no whole words, a
 batch of another geometry, monolithic mode and a released fetcher keep
 the paths below byte for byte.
 
-Which path runs where (ledger, PRs 26–28): all four benchmark cells
+Which path runs where (ledger, PRs 26–37): all five benchmark cells
 (``invert_1080p.bulk``, ``style_720p.bulk`` / ``.live``,
-``flow_720p.bulk``) serve one chip and run the packed layout
-(``packed_batches == batches``); it took invert from 72.4 to 346.0
-frames/s and tied in the style and flow cells. The per-shard slab path
-is what a result sharded over several devices gets; its copy is the
-transposing gather above, and no cell runs it yet (the four-chip cell,
-PERF.md §7a, decides: pack per shard there, or delete: ROADMAP D3b).
+``flow_720p.bulk``, ``sr2x_540p.bulk``) serve one chip and run the
+packed layout (``packed_batches == row_landed_batches == batches``);
+the layout took invert from 72.4 to 346.0 frames/s and tied in the
+style and flow cells. The per-shard slab path is what a result sharded
+over several devices gets; its copy is the transposing gather above,
+and no cell runs it yet (the four-chip cell, PERF.md §7a, decides:
+pack per shard there, or delete: ROADMAP D3b).
 Monolithic is the CPU backend's path, the degrade target and the tests'
 reference.
 
@@ -157,10 +173,10 @@ def pack_table(width: int, channels: int) -> np.ndarray:
     return table
 
 
-def egress_pack(y, table):
-    """``uint8[B, H, W, C]`` → ``uint32[B, H, W·C/4]`` holding the same
-    bytes in row-major (interleaved) order, computed on the device (why:
-    the module docstring).
+def _pack_chunks(y, table):
+    """The words of ``uint8[B, H, W, C]`` in row-major (interleaved)
+    order, as one ``uint32[B, H, n·C/4]`` array per chunk of ``n <=
+    PACK_CHUNK_PX`` pixels along W.
 
     XLA's own reshape of the channel-planar bytes goes through a 42×
     padded intermediate (C on the lanes) and does not compile at 1080p,
@@ -189,7 +205,32 @@ def egress_pack(y, table):
             acc = d if acc is None else acc + d
         words.append(acc[..., :nw].astype(jnp.uint32)
                      | (acc[..., nw:].astype(jnp.uint32) << 16))
-    return words[0] if len(words) == 1 else jnp.concatenate(words, axis=-1)
+    return words
+
+
+def _join(chunks):
+    import jax.numpy as jnp
+
+    return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=-1)
+
+
+def pack_words(y, table):
+    """``uint8[B, H, W, C]`` → ``uint32[B, H, W·C/4]`` holding the same
+    bytes in row-major (interleaved) order, computed on the device (why:
+    the module docstring; how: :func:`_pack_chunks`)."""
+    return _join(_pack_chunks(y, table))
+
+
+def egress_pack(y, table):
+    """:func:`pack_words` as B arrays ``uint32[H, W·C/4]``, one a batch
+    row, each joined from its own slices of the chunks (no whole-batch
+    array is built on the way): each row gets a device buffer and, once
+    fetched, a host buffer of its own, so a frame that outlives its
+    batch keeps nothing else alive (``scripts/d2h_probe.py`` variant
+    ``R``: what the rows cost on the device and on the link against the
+    one array)."""
+    chunks = _pack_chunks(y, table)
+    return tuple(_join([c[i] for c in chunks]) for i in range(y.shape[0]))
 
 
 @functools.lru_cache(maxsize=16)
@@ -210,16 +251,28 @@ def _compiled_pack(out_shape: Tuple[int, ...], device):
 
 
 class PackedBatch:
-    """One batch in the packed transfer layout, in flight: the pack
-    program's words and the shape they unpack to. What ``prefetch``
-    returns in the result's place on that layout; any fetcher unpacks
-    it, whatever became of the one that packed it (released, degraded)."""
+    """One batch in the packed transfer layout, in flight: the rows of
+    the pack program's words that are on their way to the host (the
+    batch's valid rows; a padding row was dropped at the prefetch) and
+    the batch shape they belong to. What ``prefetch`` returns in the
+    result's place on that layout; any fetcher unpacks it, whatever
+    became of the one that packed it (released, degraded)."""
 
-    __slots__ = ("words", "out_shape")
+    __slots__ = ("rows", "out_shape")
 
-    def __init__(self, words: Any, out_shape: Tuple[int, ...]):
-        self.words = words
+    def __init__(self, rows: Tuple[Any, ...], out_shape: Tuple[int, ...]):
+        self.rows = rows
         self.out_shape = out_shape
+
+
+class LandedRows(tuple):
+    """A fetched batch whose rows each own the host buffer they landed
+    in (the packed layout's ``fetch``): ``out[row]`` for ``row < valid``
+    as an ``ndarray`` batch gives it, but no row shares memory with
+    another, so one kept beyond the batch pins that row alone and a
+    keeper need not copy it. Rows are read-only."""
+
+    __slots__ = ()
 
 
 def device_side(payload: Any) -> Tuple[Any, str]:
@@ -227,7 +280,9 @@ def device_side(payload: Any) -> Tuple[Any, str]:
     layout) for what ``prefetch`` returned: what the lane's in-flight
     handle waits on, and the ``layout=`` of the collect span."""
     if isinstance(payload, PackedBatch):
-        return payload.words, TRANSFER_PACKED
+        # One executable wrote every row: any of them is ready when
+        # the pack is done.
+        return payload.rows[0], TRANSFER_PACKED
     return payload, TRANSFER_PLAIN
 
 
@@ -255,8 +310,9 @@ class ShardedBatchFetcher:
     The returned array is the slab itself on the streamed slab path —
     valid until the slot is revisited (the caller's in-flight bound), so
     consumers that hold rows longer (reorder buffers) must copy them;
-    ``owns(out)`` says so. The packed layout and the monolithic path
-    return a per-batch array that lives as long as a view of it does.
+    ``owns(out)`` says so. The monolithic path and the per-batch
+    fallback return a fresh array that lives as long as a view of it
+    does; the packed layout returns :class:`LandedRows`, a buffer a row.
     """
 
     def __init__(
@@ -345,24 +401,27 @@ class ShardedBatchFetcher:
 
     # -- submit side ----------------------------------------------------
 
-    def prefetch(self, result: Any) -> Any:
+    def prefetch(self, result: Any, valid: Optional[int] = None) -> Any:
         """Start the D2H now, overlapped with the next batch's staging and
         the tail of this batch's compute; ``fetch`` then only waits for
         completion instead of initiating the copy. Returns what to hand
-        ``fetch``: on the packed layout the pack program's output (the
-        caller drops ``result``, which the device frees once the pack
-        has read it), otherwise ``result`` itself, its transfer started
-        per shard on the streamed path so each shard's copy is
-        independently in flight."""
+        ``fetch``: on the packed layout the pack program's first
+        ``valid`` rows, each with its own transfer started (the caller
+        drops ``result``, which the device frees once the pack has read
+        it; the padding rows past ``valid``, None = none, are freed here
+        and never transferred), otherwise ``result`` itself, its
+        transfer started per shard on the streamed path so each shard's
+        copy is independently in flight."""
         pack = self._pack  # read once: release() may clear it
         if (pack is not None
                 and getattr(result, "dtype", None) == self.dtype
                 and tuple(result.shape) == self.out_shape
                 and result.sharding.device_set == self.sharding.device_set):
             run, table = pack
-            words = run(result, table)
-            words.copy_to_host_async()
-            return PackedBatch(words, self.out_shape)
+            rows = tuple(run(result, table))[:valid]
+            for row in rows:
+                row.copy_to_host_async()
+            return PackedBatch(rows, self.out_shape)
         try:
             if self.effective_mode == "streamed":
                 seen = set()
@@ -394,10 +453,12 @@ class ShardedBatchFetcher:
                 and getattr(result, "is_fully_addressable", True)
                 and tuple(result.shape) == self.out_shape)
 
-    def fetch(self, result: Any, slot: int) -> np.ndarray:
+    def fetch(self, result: Any, slot: int):
         """Materialize one batch; blocks until the device is done (like
         the ``np.asarray`` it replaces) but scatters shard host copies
-        into the slot's preallocated slab as each one lands."""
+        into the slot's preallocated slab as each one lands. An
+        ``ndarray`` whose rows are views of it, or, on the packed
+        layout, :class:`LandedRows`."""
         if isinstance(result, PackedBatch):
             return self._fetch_packed(result)
         if not self._streamable(result):
@@ -450,39 +511,44 @@ class ShardedBatchFetcher:
                                 nbytes=slab.nbytes)
         return slab
 
-    def _fetch_packed(self, packed: PackedBatch) -> np.ndarray:
-        """The packed layout's fetch: wait for the transfer ``prefetch``
-        started and hand out the buffer it landed in, viewed as frames.
-        One pass over the bytes, the runtime's; no slab, no copy
-        (``copy_ms`` is 0 by construction). The view is read-only and
-        lives as long as a row of it is referenced."""
-        packed.words.block_until_ready()  # the step's and the pack's
+    def _fetch_packed(self, packed: PackedBatch) -> LandedRows:
+        """The packed layout's fetch: wait, row by row, for the
+        transfers ``prefetch`` started and hand out the buffers they
+        landed in, each viewed as a frame. One pass over the bytes, the
+        runtime's; no slab, no copy (``copy_ms`` is 0 by construction).
+        A row is read-only, shares memory with no other, and holds
+        nothing of the device: the device rows go with ``packed``."""
+        packed.rows[0].block_until_ready()  # the step's and the pack's
         #   device time are not D2H (see fetch)
         if self.chaos is not None and self._pack is not None:
-            self.chaos.fire("d2h")  # one shard, one firing; a released
+            self.chaos.fire("d2h")  # one batch, one firing; a released
             #   fetcher (degraded to monolithic, torn down) has left the
             #   site, as its slab fetch has, also for a batch packed
             #   before that
+        frame = packed.out_shape[1:]
         t0 = time.perf_counter()
-        out = np.asarray(packed.words).view(np.uint8).reshape(
-            packed.out_shape)
+        out = LandedRows(np.asarray(row).view(np.uint8).reshape(frame)
+                         for row in packed.rows)
         t1 = time.perf_counter()
+        nbytes = sum(row.nbytes for row in out)
         tracer = self.tracer
         if tracer is not None and tracer.enabled:
             off = self.wall_offset_s
             tracer.complete(
                 EGRESS_D2H, t0 + off, t1 + off, self.track,
-                rows=f"0:{out.shape[0]}", bytes=out.nbytes,
-                layout=TRANSFER_PACKED)
-        self.stats.record_fetch(wait_ms=(t1 - t0) * 1e3, copy_ms=0.0,
-                                packed=True, nbytes=out.nbytes)
+                rows=f"0:{len(out)}", bytes=nbytes, layout=TRANSFER_PACKED)
+        self.stats.record_fetch(
+            wait_ms=(t1 - t0) * 1e3, copy_ms=0.0, packed=True, nbytes=nbytes,
+            rows_landed=len(out),
+            rows_skipped=packed.out_shape[0] - len(out))
         return out
 
-    def owns(self, out: np.ndarray) -> bool:
+    def owns(self, out) -> bool:
         """True when ``out`` is one of this fetcher's pooled slabs — i.e.
         it will be rewritten once the slot cycles, so rows that outlive
         the caller's collect step must be copied. The monolithic and
-        per-batch-fallback paths return fresh arrays and stay False."""
+        per-batch-fallback paths return fresh arrays, the packed layout
+        rows of their own, and stay False."""
         return self._pool is not None and any(out is s for s in self._pool)
 
     def release(self) -> None:

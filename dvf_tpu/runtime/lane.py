@@ -52,7 +52,20 @@ class InflightBatch:
     """One batch between ``prefetch`` and ``fetch``, pinned to the
     fetcher its transfer was issued on: a hot swap or a degradation may
     give the lane another one while this batch is in flight. ``layout``:
-    its transfer layout, for the caller's d2h span."""
+    its transfer layout, for the caller's d2h span.
+
+    What ``fetch`` returns, and who may keep a row of it (``out[row]``,
+    ``row < valid``, either way):
+
+    - ``egress.LandedRows`` (the packed layout): every row is the host
+      buffer it landed in and shares memory with nothing. Keep it as
+      long as you like, read-only; it pins that row alone.
+    - an ``ndarray``: rows are views of the batch. ``owns(out)`` True:
+      a pooled slab, rewritten ``inflight + 1`` batches later, so copy
+      what outlives that. False: a fresh per-batch array (monolithic,
+      the per-batch fallback) that lives as long as any view of it, so
+      copy what may outlive the batch by long (the serve router does).
+    """
 
     __slots__ = ("_lane", "_fetcher", "_payload", "_device", "layout")
 
@@ -69,7 +82,7 @@ class InflightBatch:
     def is_ready(self) -> bool:
         return self._device.is_ready()
 
-    def fetch(self, seq: int) -> np.ndarray:
+    def fetch(self, seq: int):
         """The batch as host frames, once. ``seq`` is the caller's
         monotone batch number: the delivery slab's slot."""
         try:
@@ -77,7 +90,7 @@ class InflightBatch:
         finally:
             self._lane._fetched(self._fetcher)
 
-    def owns(self, out: np.ndarray) -> bool:
+    def owns(self, out) -> bool:
         """True when ``out`` is a pooled slab, rewritten ``inflight + 1``
         batches later: rows that outlive that are the caller's to copy."""
         return self._fetcher.owns(out)
@@ -175,10 +188,12 @@ class DeviceLane:
 
     # -- off the chip ------------------------------------------------------
 
-    def prefetch(self, result) -> InflightBatch:
+    def prefetch(self, result, valid: Optional[int] = None) -> InflightBatch:
         """Start ``result``'s way back now, under the tail of its compute
         and the next batch's staging; the caller keeps the handle in its
-        place. A new output signature or mode rebuilds the fetcher."""
+        place. ``valid``: the rows that carry a frame (None = all); on
+        the packed layout the padding behind them never crosses the
+        link. A new output signature or mode rebuilds the fetcher."""
         shape, dtype = self.engine.out_shape, self.engine.out_dtype
         mode, reason = self._mode(FaultKind.D2H)
         f = self._fetcher
@@ -194,7 +209,7 @@ class DeviceLane:
             self._swap_fetcher(f, park=True)
         with self._lock:
             self._pending[f] = self._pending.get(f, 0) + 1
-        return InflightBatch(self, f, f.prefetch(result))
+        return InflightBatch(self, f, f.prefetch(result, valid))
 
     def _swap_fetcher(self, new: Optional[ShardedBatchFetcher],
                       park: bool) -> None:

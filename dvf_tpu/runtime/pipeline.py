@@ -489,7 +489,7 @@ class Pipeline:
                     # staging + device compute; the collect side's fetch
                     # then only waits for completion instead of initiating
                     # the copy. What rides the window is the handle.
-                    result = self._lane.prefetch(result)
+                    result = self._lane.prefetch(result, valid)
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     if not inline:
                         self._inflight_sem.release()
@@ -543,7 +543,8 @@ class Pipeline:
         # A pooled slab is rewritten after max_inflight + 1 batches —
         # rows that outlive this call (the reorder buffer holds them
         # across the frame_delay window) must own their bytes. A fresh
-        # per-batch array keeps handing out views.
+        # per-batch array keeps handing out views, landed rows
+        # (egress.LandedRows) themselves.
         copy_rows = result.owns(out)
         for row, (idx, ts) in enumerate(meta[:valid]):
             frame = out[row].copy() if copy_rows else out[row]

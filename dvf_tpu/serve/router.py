@@ -18,8 +18,7 @@ from __future__ import annotations
 
 import threading
 
-import numpy as np
-
+from dvf_tpu.runtime.egress import LandedRows
 from dvf_tpu.serve.batcher import BatchPlan
 
 
@@ -29,6 +28,9 @@ class ResultRouter:
     def __init__(self):
         self.batches = 0
         self.frames = 0
+        self.rows_handed_total = 0  # rows delivered as the buffer they
+        #   landed in (egress.LandedRows)
+        self.rows_copied_total = 0  # rows copied out of a batch array
         self.late_after_close = 0  # results for hard-closed sessions
         self.late_after_recovery = 0  # results for plans the supervisor
         #   already wrote off (their sessions' claims were released at
@@ -39,14 +41,22 @@ class ResultRouter:
         #   concurrently, and a double discard_inflight would drive
         #   session.inflight negative
 
-    def route(self, plan: BatchPlan, out: np.ndarray) -> int:
+    def route(self, plan: BatchPlan, out) -> int:
         """Demux one completed batch; returns frames delivered.
 
-        Rows are copied out of the batch array: a view would keep the
-        whole (batch_size, H, W, C) result alive for as long as ONE
-        delivery sits unpolled — a slow-polling client could pin
-        out_queue_size full batches (batch_size× amplification) instead
-        of out_queue_size frames.
+        The invariant: a delivery that sits unpolled (out queue, replay
+        ring, a slow client) keeps ONE frame's bytes alive, never its
+        batch — else a slow-polling client could pin out_queue_size
+        full batches (batch_size× amplification) instead of
+        out_queue_size frames. How each shape of ``out`` keeps it:
+
+        - ``egress.LandedRows`` (the packed layout: one device, uint8
+          NHWC): every row is a host buffer of its own, handed to the
+          session as it is, read-only; nothing here touches its bytes;
+        - an ``ndarray`` (a pooled slab of a result sharded over several
+          devices, the monolithic fetch of the CPU backend or a degraded
+          lane, the per-batch fallback): rows are views of the batch,
+          so each is copied out.
         """
         with self._dead_lock:
             if plan.dead:
@@ -59,6 +69,11 @@ class ResultRouter:
         delivered = 0
         st = plan.stamps
         marks = None
+        own_rows = isinstance(out, LandedRows)
+        if own_rows:
+            self.rows_handed_total += plan.valid
+        else:
+            self.rows_copied_total += plan.valid
         for row, slot in enumerate(plan.slots[: plan.valid]):
             s = slot.session
             # The batch's stamps ride each slot to its delivery — the one
@@ -73,7 +88,7 @@ class ResultRouter:
                     marks = st.marks()
                 slot.lin.marks = [("queue_ingress", slot.t_pending),
                                   ("queue_bucket", st.t_chosen), *marks]
-            s.complete(slot, out[row].copy())
+            s.complete(slot, out[row] if own_rows else out[row].copy())
             if s.state == "closed":
                 self.late_after_close += 1
                 continue
@@ -119,6 +134,8 @@ class ResultRouter:
         return {
             "batches": self.batches,
             "frames": self.frames,
+            "rows_handed_total": self.rows_handed_total,
+            "rows_copied_total": self.rows_copied_total,
             "late_after_close": self.late_after_close,
             "late_after_recovery": self.late_after_recovery,
         }

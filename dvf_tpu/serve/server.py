@@ -3506,7 +3506,7 @@ class ServeFrontend:
                     # never initiates. What rides the in-flight queue is
                     # the lane's handle (the result itself is dropped
                     # here); it pins the fetcher the D2H was issued on.
-                    result = lane.prefetch(result)
+                    result = lane.prefetch(result, plan.valid)
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     sem.release()
                     self.router.discard(plan, kind=classify(e, "dispatch"))
@@ -3611,13 +3611,13 @@ class ServeFrontend:
                 try:
                     # Streamed egress: shard host copies into the slot's
                     # preallocated slab (D2H issued at submit), or, on
-                    # the packed layout, the buffer the transfer landed
-                    # in viewed as frames; fallback: the classic
-                    # whole-batch np.asarray. Either way this waits for
-                    # the device. The router copies rows out during
+                    # the packed layout, the buffer each valid row
+                    # landed in; fallback: the classic whole-batch
+                    # np.asarray. Either way this waits for the device.
+                    # The router copies an array's rows out during
                     # route(), so handing it the pooled slab is safe —
                     # the slot only cycles max_inflight+1 batches later —
-                    # and the landed buffer dies with the batch.
+                    # and hands landed rows on as they are.
                     out = result.fetch(seq)
                     st.t_fetched = time.time()  # stamp: in host memory
                     st.close_batch()

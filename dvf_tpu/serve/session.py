@@ -101,7 +101,17 @@ class Slot:
 
 
 class Delivery(NamedTuple):
-    """One processed frame handed back to the client."""
+    """One processed frame handed back to the client.
+
+    ``frame`` may be **read-only**: where the result came off the chip a
+    row at a time (``runtime.egress.LandedRows``: every one-chip
+    replica) it is the host buffer its row landed in, which nobody
+    copied on the way here. Nothing in ``dvf_tpu/`` (sinks, codecs, the
+    bridges, the replay ring, the audit, which takes its own copy)
+    writes into a delivered frame; a client that wants to takes
+    ``frame.copy()``. The frame keeps its own bytes alive and nothing
+    else, on the host or the device.
+    """
 
     index: int
     frame: np.ndarray

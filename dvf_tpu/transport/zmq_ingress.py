@@ -650,7 +650,7 @@ class TpuZmqWorker:
         # all: the codec gathers dirty coefficient blocks lazily at
         # encode time, and the device result is only waited for.
         if coeffs is None:
-            result = self._lane.prefetch(result)
+            result = self._lane.prefetch(result, valid)
         t_ready = None
         try:
             # Device/D2H attribution split: the fetch below blocks on

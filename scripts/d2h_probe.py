@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """What a result costs to bring back from the device, by shape of the same bytes.
 
-ROADMAP S1's ceiling probe (PR 26). For the invert result (uint8[32,1080,1920,3], 199 MB) and the style result
-(uint8[16,720,1280,3], 44 MB) it times one warm blocking fetch and a steady stream of fetches with
+ROADMAP S1's ceiling probe (PR 26). For the invert result (uint8[32,1080,1920,3], 199 MB), the style result
+(uint8[16,720,1280,3], 44 MB) and, since PR 39, the upscaler's (uint8[16,1080,1920,3], 100 MB) and the flow result
+(uint8[64,720,1280,3], 177 MB; ``--shapes`` picks) it times one warm blocking fetch and a steady stream of fetches with
 ``copy_to_host_async`` in flight, for several device arrays that hold the *same bytes*:
 
   A      uint8[B,H,W,3]           the step program's result, today's transfer
@@ -10,13 +11,20 @@ ROADMAP S1's ceiling probe (PR 26). For the invert result (uint8[32,1080,1920,3]
   B      uint8[B, H*W*3]          flat rows of bytes
   C      uint8[N,128]             lane-dense bytes
   Dn     uint32[N,128]            the issue's pack: XLA's own reshape + bitcast (may not compile: 42x padded temp)
-  D      uint32[B,H,W*3/4]        runtime.egress.egress_pack (the interleave as a permutation on the MXU)
+  D      uint32[B,H,W*3/4]        runtime.egress.pack_words (the interleave as a permutation on the MXU)
+  R      B x uint32[H,W*3/4]      runtime.egress.egress_pack: D as B results of the one program, a transfer a row,
+                                  fetched row by row on the calling thread (what the serve path lands since PR 39)
+  Rw     B x uint32[H,W*3/4]      the same B results, sliced inside the program from D whole (R joins each row from
+                                  the chunks and builds no whole-batch array)
+  Rs     B x uint32[H,W*3/4]      D, then B eager slices of it on the host's dispatch path (the other way to rows)
   D128   uint32[N,128]            D reshaped on the device, N % 8 == 0
   E      D128 in 4 row chunks     fetched on 4 threads
   F      D in pinned_host         placed there by the pack program's out_shardings
 
 Each line: GB/s of the blocking fetch and of the stream, the producing program's time on the device (wall of
-blocking calls, an upper bound), and whether the bytes equal A's. Then, for the arrays np.asarray hands back for A and
+blocking calls, an upper bound), and whether the bytes equal A's. R and Rs also say what starting the B transfers
+costs the thread that starts them (``start_ms``, a batch) and what one kept row holds on the device once the batch is
+dropped (``device_bytes_held_by_a_kept_row``: 0, or the router would have to copy after all). Then, for the arrays np.asarray hands back for A and
 D (A's is not C-contiguous on a TPU: it keeps the device's channel-planar order) and for a C-contiguous numpy copy of
 each, what the host's own passes cost (bringing it to C order, the slab copy, the router's row copies). Run on the chip:
 
@@ -40,8 +48,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-RESULTS = {"invert_1080p": (32, 1080, 1920, 3), "style_720p": (16, 720, 1280, 3)}
+RESULTS = {"invert_1080p": (32, 1080, 1920, 3), "style_720p": (16, 720, 1280, 3),
+           "sr2x_540p": (16, 1080, 1920, 3), "flow_720p": (64, 720, 1280, 3)}
 TOY = {"toy_a": (8, 32, 48, 3), "toy_b": (3, 16, 512, 3)}
+ROW_VARIANTS = ("R", "Rw", "Rs")  # a device array a batch row, fetched one after another on the calling thread
 
 
 def main() -> int:
@@ -50,6 +60,7 @@ def main() -> int:
     ap.add_argument("--stream", type=int, default=8, help="fetches in the steady stream")
     ap.add_argument("--depth", type=int, default=3, help="copy_to_host_async in flight")
     ap.add_argument("--only", default="", help="comma list of variants (A,D,...) to run")
+    ap.add_argument("--shapes", default="", help="comma list of result names (invert_1080p,...) to run")
     ap.add_argument("--out", default="chiprun_out/d2h_probe.json")
     args = ap.parse_args()
 
@@ -57,13 +68,14 @@ def main() -> int:
     import jax.numpy as jnp
     from jax import lax
 
-    from dvf_tpu.runtime.egress import egress_pack, pack_table
+    from dvf_tpu.runtime.egress import egress_pack, pack_table, pack_words
 
     dev = jax.devices()[0]
     if dev.platform == "cpu" and not args.toy:
         print("no accelerator: run through chiprun, or pass --toy", file=sys.stderr)
         return 3
     only = set(filter(None, args.only.split(",")))
+    shapes = set(filter(None, args.shapes.split(",")))
     pool = ThreadPoolExecutor(4)
     report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
               "stream": args.stream, "depth": args.depth, "results": {}}
@@ -72,10 +84,10 @@ def main() -> int:
         for x in (a if isinstance(a, (tuple, list)) else (a,)):
             x.copy_to_host_async()
 
-    def to_host(a):
-        """The fetch, as the collect thread would make it; parts on 4 threads."""
+    def to_host(a, threads=True):
+        """The fetch, as the collect thread would make it; parts on 4 threads, or one after another."""
         if isinstance(a, (tuple, list)):
-            return list(pool.map(np.asarray, a))
+            return list(pool.map(np.asarray, a)) if threads else [np.asarray(x) for x in a]
         return np.asarray(a)
 
     def as_bytes(host, shape):
@@ -85,6 +97,8 @@ def main() -> int:
         return np.ascontiguousarray(host).reshape(-1).view(np.uint8)[:n].reshape(shape)
 
     for name, shape in (TOY if args.toy else RESULTS).items():
+        if shapes and name not in shapes:
+            continue
         b, h, w, c = shape
         nbytes = int(np.prod(shape))
         rng = np.random.default_rng(26)
@@ -101,7 +115,7 @@ def main() -> int:
             return lax.bitcast_convert_type(f.reshape(-1, 128, 4), jnp.uint32)
 
         def d128(y, t):
-            wd = egress_pack(y, t).reshape(-1)
+            wd = pack_words(y, t).reshape(-1)
             k = (-wd.shape[0]) % 1024
             return (jnp.pad(wd, (0, k)) if k else wd).reshape(-1, 128)
 
@@ -121,10 +135,13 @@ def main() -> int:
             "B": (lambda y: y.reshape(b, -1), False),
             "C": (lambda y: jnp.pad(y.reshape(-1), (0, (-nbytes) % 128)).reshape(-1, 128), False),
             "Dn": (flat_words, False),
-            "D": (egress_pack, True),
+            "D": (pack_words, True),
+            "R": (egress_pack, True),
+            "Rw": (lambda y, t: tuple(pack_words(y, t)[i] for i in range(b)), True),
+            "Rs": (pack_words, True),
             "D128": (d128, True),
             "E": (chunks, True),
-            "F": (egress_pack, True),
+            "F": (pack_words, True),
         }
         rows = {}
         for v, (fn, takes_table) in variants.items():
@@ -145,8 +162,14 @@ def main() -> int:
                 compiled = jitted.lower(*make_args).compile()
                 row["compile_s"] = round(time.perf_counter() - t0, 2)
 
+                threads = v not in ROW_VARIANTS
+
+                def produce(y):
+                    out = compiled(y, *extra)
+                    return [out[i] for i in range(b)] if v == "Rs" else out
+
                 def make():
-                    return compiled(step(x), *extra)
+                    return produce(step(x))
 
                 jax.block_until_ready(make())  # warm
                 # the producing program's device time: the blocking call less the step's own
@@ -154,25 +177,45 @@ def main() -> int:
                 for _ in range(5):
                     y = jax.block_until_ready(step(x))
                     t0 = time.perf_counter()
-                    jax.block_until_ready(compiled(y, *extra))
+                    jax.block_until_ready(produce(y))
                     ts.append((time.perf_counter() - t0) * 1e3)
                 row["program_ms"] = round(min(ts), 3)
+                del y
+                in_use = (dev.memory_stats() or {}).get("bytes_in_use")  # with no result of this variant alive
                 # one warm blocking fetch (no async copy started before it)
                 a = jax.block_until_ready(make())
                 t0 = time.perf_counter()
-                host = to_host(a)
+                host = to_host(a, threads)
                 dt = time.perf_counter() - t0
                 row["block_ms"] = round(dt * 1e3, 2)
                 row["block_gbps"] = round(nbytes / dt / 1e9, 3)
                 row["bytes_equal_A"] = bool(np.array_equal(as_bytes(host, shape), ref))
-                del host, a
+                if v in ROW_VARIANTS:
+                    # what a kept row holds once its batch is gone: of the host one row, of the device nothing
+                    row["transfers"] = len(a)
+                    row["rows_share_memory"] = bool(any(np.shares_memory(host[0], o) for o in host[1:]))
+                    kept = host[0]
+                    del host, a
+                    row["device_bytes_held_by_a_kept_row"] = (
+                        None if in_use is None else int(dev.memory_stats()["bytes_in_use"] - in_use))
+                    row["kept_row_intact"] = bool(np.array_equal(kept.view(np.uint8).reshape(shape[1:]), ref[0]))
+                    del kept
+                else:
+                    del host, a
+                starts = []
                 # a steady stream: depth transfers in flight, fetch the oldest, issue the next
                 q = deque()
                 issued = 0
-                for _ in range(min(args.depth, args.stream)):
+
+                def issue():
                     a = make()
+                    t0 = time.perf_counter()
                     start(a)
+                    starts.append((time.perf_counter() - t0) * 1e3)
                     q.append(a)
+
+                for _ in range(min(args.depth, args.stream)):
+                    issue()
                     issued += 1
                 jax.block_until_ready(list(q))
                 per = []
@@ -180,18 +223,17 @@ def main() -> int:
                 for _ in range(args.stream):
                     a = q.popleft()
                     t0 = time.perf_counter()
-                    host = to_host(a)
+                    host = to_host(a, threads)
                     per.append((time.perf_counter() - t0) * 1e3)
                     del host, a
                     if issued < args.stream:
-                        a = make()
-                        start(a)
-                        q.append(a)
+                        issue()
                         issued += 1
                 dt = time.perf_counter() - t_all
                 row["stream_ms_per_fetch"] = round(dt * 1e3 / args.stream, 2)
                 row["stream_gbps"] = round(nbytes * args.stream / dt / 1e9, 3)
                 row["stream_fetch_ms"] = [round(p, 1) for p in per]
+                row["start_ms"] = round(float(np.median(starts)), 3)
             except Exception as e:  # noqa: BLE001 — a variant the compiler or the runtime refuses is a finding
                 row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
             print(f"[{name}] {v:5s} {json.dumps(row)}", flush=True)
@@ -221,7 +263,7 @@ def main() -> int:
             del frames
             return out
 
-        for v, fn, extra in (("A", lambda y: y, ()), ("D", egress_pack, (table,))):
+        for v, fn, extra in (("A", lambda y: y, ()), ("D", pack_words, (table,))):
             if only and v not in only:
                 continue
             a = jax.jit(fn)(step(x), *extra)

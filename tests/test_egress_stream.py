@@ -194,23 +194,79 @@ class TestPackedTransferLayout:
             result = jax.device_put(frames, dev)
             handle = f.prefetch(result)
             assert isinstance(handle, egress_mod.PackedBatch)
-            assert handle.words.dtype == np.uint32
-            assert handle.words.shape == (
-                shape[0], shape[1], shape[2] * shape[3] // 4)
+            assert len(handle.rows) == shape[0]  # one device array a row
+            assert all(r.dtype == np.uint32 and r.shape == (
+                shape[1], shape[2] * shape[3] // 4) for r in handle.rows)
             out = f.fetch(handle, slot)
-            assert out.dtype == np.uint8 and out.shape == shape
-            np.testing.assert_array_equal(out, frames)
-            np.testing.assert_array_equal(out, np.asarray(result))
-            assert not out.flags.writeable  # the landed buffer, viewed
+            assert isinstance(out, egress_mod.LandedRows)
+            assert len(out) == shape[0]
+            assert all(r.dtype == np.uint8 and r.shape == shape[1:]
+                       for r in out)
+            np.testing.assert_array_equal(np.stack(out), frames)
+            np.testing.assert_array_equal(np.stack(out), np.asarray(result))
+            # each row is the buffer it landed in, viewed: read-only,
+            # and no two of them are one buffer
+            assert not any(r.flags.writeable for r in out)
+            assert not any(np.shares_memory(a, b)
+                           for i, a in enumerate(out) for b in out[i + 1:])
             assert not f.owns(out)
             # What the lane's in-flight handle waits on, and its span's
-            # layout: the words, not a disguise of the result.
-            assert egress_mod.device_side(handle) == (handle.words,
+            # layout: a row of the words, not a disguise of the result.
+            assert egress_mod.device_side(handle) == (handle.rows[0],
                                                       "u32rows")
         s = f.stats.summary()
         assert s["batches"] == 3 and s["packed_batches"] == 3
+        assert s["row_landed_batches"] == 3
+        assert s["rows_landed_total"] == 3 * shape[0]
+        assert s["rows_skipped_total"] == 0
+        assert s["bytes_total"] == 3 * int(np.prod(shape))
         assert s["copy_ms_total"] == 0.0
         assert s["transfer_layout"] == "u32rows"
+
+    @pytest.mark.parametrize("valid", [1, 3, 8])
+    def test_padding_rows_never_land(self, valid):
+        """``prefetch(result, valid)``: the rows past ``valid`` are
+        dropped on the device and start no transfer; the counters say
+        how many crossed and how many did not, and ``bytes_total`` is
+        what landed."""
+        import jax
+
+        shape = (8, 16, 24, 3)
+        f, dev = _packing_fetcher(shape)
+        frames = np.stack(_rng_frames(8, 16, 24, seed=valid))
+        handle = f.prefetch(jax.device_put(frames, dev), valid)
+        assert len(handle.rows) == valid
+        out = f.fetch(handle, 0)
+        assert isinstance(out, egress_mod.LandedRows) and len(out) == valid
+        np.testing.assert_array_equal(np.stack(out), frames[:valid])
+        s = f.stats.summary()
+        assert s["row_landed_batches"] == s["packed_batches"] == 1
+        assert s["rows_landed_total"] == valid
+        assert s["rows_skipped_total"] == 8 - valid
+        assert s["bytes_total"] == valid * 16 * 24 * 3
+
+    def test_a_kept_row_keeps_one_row_alive(self):
+        """Holding one landed row after the batch, its handle and the
+        other rows are gone keeps that row's buffer and no other: weak
+        references to the other rows' landed words die."""
+        import gc
+        import weakref
+
+        import jax
+
+        shape = (4, 16, 24, 3)
+        f, dev = _packing_fetcher(shape)
+        frames = np.stack(_rng_frames(4, 16, 24, seed=2))
+        handle = f.prefetch(jax.device_put(frames, dev))
+        out = f.fetch(handle, 0)
+        landed = [weakref.ref(r.base if r.base is not None else r)
+                  for r in out]  # the uint32 words under each view
+        kept = out[2]
+        del out, handle
+        gc.collect()
+        assert [r() is not None for r in landed] == [False, False, True,
+                                                     False]
+        np.testing.assert_array_equal(kept, frames[2])
 
     @pytest.mark.parametrize("shape,dtype,why", [
         ((2, 8, 9, 3), np.uint8, "27-byte rows are no whole words"),
@@ -290,14 +346,22 @@ class TestPackedTransferLayout:
         x = np.stack(_rng_frames(4, 8, 16, seed=9))
         handle = f.prefetch(jax.device_put(x, dev))
         f.release()
-        np.testing.assert_array_equal(f.fetch(handle, 0), x)
+        out = f.fetch(handle, 0)
+        assert isinstance(out, egress_mod.LandedRows)
+        np.testing.assert_array_equal(np.stack(out), x)
         after = jax.device_put(x, dev)
         assert f.prefetch(after) is after
-        np.testing.assert_array_equal(f.fetch(after, 1), x)
+        plain = f.fetch(after, 1)
+        assert isinstance(plain, np.ndarray)  # rows are views again
+        np.testing.assert_array_equal(plain, x)
         assert f.stats.packed_batches == 1 and f.stats.batches == 2
+        assert f.stats.row_landed_batches == 1
         other, _ = _packing_fetcher((2, 4, 4, 3), mode="monolithic")
-        handle2 = _packing_fetcher(shape)[0].prefetch(jax.device_put(x, dev))
-        np.testing.assert_array_equal(other.fetch(handle2, 0), x)
+        handle2 = _packing_fetcher(shape)[0].prefetch(
+            jax.device_put(x, dev), 3)
+        out2 = other.fetch(handle2, 0)
+        assert isinstance(out2, egress_mod.LandedRows) and len(out2) == 3
+        np.testing.assert_array_equal(np.stack(out2), x[:3])
 
     def test_pack_is_compiled_when_the_fetcher_is_built(self):
         """Never on a batch: the first prefetch finds the executable, and
@@ -322,11 +386,12 @@ class TestPackedTransferLayout:
         f, dev = _packing_fetcher((2, 8, 8, 3), chaos=chaos)
         x = np.full((2, 8, 8, 3), 3, np.uint8)
         np.testing.assert_array_equal(
-            f.fetch(f.prefetch(jax.device_put(x, dev)), 0), x)
-        with pytest.raises(ChaosFault):
+            np.stack(f.fetch(f.prefetch(jax.device_put(x, dev)), 0)), x)
+        with pytest.raises(ChaosFault):  # two rows, one batch, one firing
             f.fetch(f.prefetch(jax.device_put(x, dev)), 1)
         np.testing.assert_array_equal(
-            f.fetch(f.prefetch(jax.device_put(x, dev)), 2), x)
+            np.stack(f.fetch(f.prefetch(jax.device_put(x, dev)), 2)), x)
+        assert chaos.summary()["events"]["d2h"] == 3
 
     def test_trace_span_names_the_layout(self):
         import jax
@@ -337,10 +402,14 @@ class TestPackedTransferLayout:
         f, dev = _packing_fetcher((2, 8, 8, 3), tracer=tracer)
         f.fetch(f.prefetch(jax.device_put(
             np.zeros((2, 8, 8, 3), np.uint8), dev)), 0)
+        f.fetch(f.prefetch(jax.device_put(
+            np.zeros((2, 8, 8, 3), np.uint8), dev), 1), 1)
         spans = [e for e in tracer._events if e["name"] == "egress_d2h"]
-        assert len(spans) == 1
-        assert spans[0]["args"]["layout"] == "u32rows"
-        assert spans[0]["args"]["bytes"] == 2 * 8 * 8 * 3
+        assert len(spans) == 2  # one a batch, carrying the rows that landed
+        assert all(e["args"]["layout"] == "u32rows" for e in spans)
+        assert [e["args"]["rows"] for e in spans] == ["0:2", "0:1"]
+        assert [e["args"]["bytes"] for e in spans] == [2 * 8 * 8 * 3,
+                                                        8 * 8 * 3]
 
 
 def test_pack_table_is_a_permutation():
@@ -622,7 +691,7 @@ def test_serve_streamed_matches_monolithic():
 
 
 def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
-                  trace=False):
+                  trace=False, **kw):
     """One tenant through a one-device frontend (the one-chip replica's
     shape): the fetcher packs. Returns (frames, deliveries, stats, fe).
     With ``resize_to`` the session stays busy until the resize has
@@ -633,7 +702,7 @@ def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
     filt = get_filter("invert")
     engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
     config = ServeConfig(batch_size=batch, max_inflight=2, queue_size=64,
-                         slo_ms=60_000.0, chaos=chaos, trace=trace)
+                         slo_ms=60_000.0, chaos=chaos, trace=trace, **kw)
     frames = _rng_frames(n_frames, 16, 24, seed=11)
     got = []
     fe = ServeFrontend(filt, config, engine=engine)
@@ -673,16 +742,27 @@ def _serve_packed(n_frames=24, batch=4, chaos=None, resize_to=None,
 
 
 def test_serve_packed_layout_delivers_and_reports():
-    frames, got, stats, _ = _serve_packed()
+    frames, got, stats, _ = _serve_packed(n_frames=26)
     for d, src in zip(got, frames):
         np.testing.assert_array_equal(d.frame, 255 - src)
-        assert d.frame.flags.owndata  # a row copy, not a view that pins
-        #   the whole landed batch
+        assert not d.frame.flags.writeable  # the buffer its row landed
+        #   in, as the runtime handed it over: nobody copied it
+    # ... and a buffer of its own: no delivery pins another's bytes
+    assert not any(np.shares_memory(a.frame, b.frame)
+                   for i, a in enumerate(got) for b in got[i + 1:])
     assert stats["faults"]["by_kind"] == {}
     (row,) = stats["buckets"].values()
     eg = row["egress"]
     assert eg["mode"] == "streamed" and eg["transfer_layout"] == "u32rows"
-    assert eg["packed_batches"] == eg["batches"] >= 6
+    assert eg["packed_batches"] == eg["batches"] >= 7
+    assert eg["row_landed_batches"] == eg["batches"]
+    # 26 frames in batches of 4: at least one batch is short, and its
+    # padding never crossed
+    assert eg["rows_landed_total"] == len(frames)
+    assert eg["rows_skipped_total"] == 4 * eg["batches"] - len(frames) > 0
+    assert eg["bytes_total"] == len(frames) * 16 * 24 * 3
+    assert stats["rows_handed_total"] == len(frames)
+    assert stats["rows_copied_total"] == 0
     assert eg["copy_ms_total"] == 0.0
     assert eg["pool_allocs"] == 0  # no slab pool on the packed path
     assert stats["egress"]["transfer_layout"] == "u32rows"
@@ -705,7 +785,12 @@ def test_serve_packed_layout_under_corrupt_device_chaos():
             touched += 1
             assert len(diff) == 1 and tuple(diff[0]) == (0, 0, 0)
             assert d.frame[0, 0, 0] == want[0, 0, 0] ^ 0x40
+            assert d.frame.flags.writeable  # row 0's perturbed copy
+        else:
+            assert not d.frame.flags.writeable  # as it landed
     assert touched >= 2
+    assert stats["rows_copied_total"] == 0  # the site copied row 0, the
+    #   router nothing
     assert sum(n for k, n in stats["chaos"]["fired"].items()
                if k.startswith("corrupt_device")) == touched
     assert stats["errors"] == 0
@@ -727,9 +812,65 @@ def test_serve_packed_layout_across_a_hot_swap():
     assert row["batch_size"] == 2
     eg = row["egress"]  # the successor's stats
     assert eg["transfer_layout"] == "u32rows" and eg["pool_allocs"] == 0
-    assert eg["packed_batches"] == eg["batches"] >= 1
+    assert eg["packed_batches"] == eg["row_landed_batches"] \
+        == eg["batches"] >= 1
+    assert stats["rows_handed_total"] == len(frames)
+    assert stats["rows_copied_total"] == 0
     assert all(b.lane.slab_bytes() == 0 for b in fe._buckets)
     assert all(f.slab_bytes() == 0 for f in egress_mod.live_fetchers())
+
+
+def test_serve_packed_layout_under_the_audit_replay():
+    """The shadow replay takes its own copy of a sampled row
+    (``np.array(out[row], copy=True)``) from the landed rows and judges
+    it clean; the delivery itself is still the landed buffer."""
+    _, got, stats, _ = _serve_packed(audit=True, audit_sample_every=1)
+    assert not any(d.frame.flags.writeable for d in got)
+    st = stats["audit"]
+    assert st["replays_sampled_total"] >= len(got) // 2
+    assert st["replay_mismatches_total"] == 0
+    assert st["replay_errors_total"] == 0
+    assert st["confirmed_corruptions_total"] == 0
+    assert stats["rows_copied_total"] == 0
+
+
+def test_serve_packed_layout_under_d2h_chaos():
+    """One firing a batch on the row-landed path: the faulted batch's
+    frames are lost to the fault counters, every other frame arrives
+    exact and in order, as the landed buffer."""
+    from dvf_tpu.resilience import FaultPlan
+    from dvf_tpu.serve import ServeConfig, ServeFrontend
+
+    chaos = FaultPlan().add("d2h", at=(2,))
+    filt = get_filter("invert")
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    fe = ServeFrontend(filt, ServeConfig(
+        batch_size=4, max_inflight=2, queue_size=64, slo_ms=60_000.0,
+        chaos=chaos), engine=engine)
+    frames = _rng_frames(24, 16, 24, seed=13)
+    got = []
+    with fe:
+        sid = fe.open_stream()
+        for f in frames:
+            fe.submit(sid, f)
+        fe.close(sid, drain=True)
+        deadline = time.time() + 30.0
+        while time.time() < deadline:
+            got.extend(fe.poll(sid))
+            st = fe.stats()["sessions"][sid]
+            if st["delivered"] + st["failed"] == len(frames):
+                got.extend(fe.poll(sid))
+                break
+            time.sleep(0.005)
+        stats = fe.stats()
+    lost = stats["sessions"][sid]["failed"]
+    assert 1 <= lost <= 4 and len(got) == len(frames) - lost
+    assert stats["faults"]["by_kind"] == {"d2h": 1}
+    assert [d.index for d in got] == sorted(d.index for d in got)
+    for d in got:
+        np.testing.assert_array_equal(d.frame, 255 - frames[d.index])
+        assert not d.frame.flags.writeable
+    assert stats["rows_copied_total"] == 0
 
 
 def test_serve_trace_spans_name_the_layout():
@@ -1264,3 +1405,59 @@ def test_lane_contract_through_its_hosts(host, monkeypatch):
     assert stats["egress"]["batches"] == len(frames) // 4
     lane.release()
     assert lane.slab_bytes() == 0
+
+
+@pytest.mark.parametrize("host", ["pipeline", "worker"])
+def test_rows_land_through_the_other_hosts(host):
+    """``Pipeline`` and ``TpuZmqWorker`` pass their batch's ``valid`` to
+    the lane as the serve frontend does: on one device every batch lands
+    a buffer a row, a short batch's padding stays on the chip, and the
+    frames come back bit-exact (the hosts index ``out[row]`` as ever)."""
+    filt = get_filter("invert")
+    if host == "pipeline":
+        sink, stats = _run_capture(filt, "streamed", MeshConfig(data=1),
+                                   batch=4, n_frames=30)
+        assert stats["delivered"] == 30
+        src = iter(SyntheticSource(height=24, width=32, n_frames=30))
+        for idx, (frame, _) in zip(range(30), src):
+            np.testing.assert_array_equal(sink.frames[idx], 255 - frame)
+        eg, frame_bytes, landed = stats["egress"], 24 * 32 * 3, 30
+    else:
+        pytest.importorskip("zmq")
+        from dvf_tpu.transport.zmq_ingress import TpuZmqWorker
+
+        frames = _rng_frames(7, 16, 16, seed=4)
+        got = {}
+        worker = TpuZmqWorker(
+            filt, engine=Engine(filt, mesh=make_mesh(MeshConfig(data=1))),
+            batch_size=4, use_jpeg=False, raw_size=16, egress_depth=2)
+
+        class _StubPush:
+            def send_multipart(self, parts):
+                got[int(parts[0].decode())] = np.frombuffer(
+                    bytes(parts[4]), np.uint8).reshape(16, 16, 3)
+
+            def close(self, *a):
+                pass
+
+        worker.push.close(0)
+        worker.push = _StubPush()
+        try:
+            for b in (0, 4):  # a full batch, then three frames of four
+                worker._process_batch(
+                    [(i, frames[i].tobytes())
+                     for i in range(b, min(b + 4, len(frames)))], b"pid")
+            worker.drain_egress(b"pid")
+            stats = worker.stats()
+        finally:
+            worker.close()
+        assert sorted(got) == list(range(7))
+        for i, f in enumerate(frames):
+            np.testing.assert_array_equal(got[i], 255 - f)
+        eg, frame_bytes, landed = stats["egress"], 16 * 16 * 3, 7
+    assert eg["transfer_layout"] == "u32rows"
+    assert eg["row_landed_batches"] == eg["packed_batches"] == eg["batches"]
+    assert eg["rows_landed_total"] == landed
+    assert eg["rows_skipped_total"] == 4 * eg["batches"] - landed > 0
+    assert eg["bytes_total"] == landed * frame_bytes
+    assert eg["copy_ms_total"] == 0.0 and eg["pool_allocs"] == 0

@@ -1010,3 +1010,116 @@ class TestShortBatchWaitsForTheDevice:
         assert idx == sorted(set(idx)) and len(idx) == s.delivered
         assert stats["recoveries"] >= 1 and s.failed >= 1
         assert fe._error is None
+
+
+# ---------------------------------------------------------------------------
+# The router's memory invariant: a kept delivery keeps one frame's bytes
+# ---------------------------------------------------------------------------
+
+
+def _fetched(path, frames, monkeypatch):
+    """(what ``fetch`` returns for one batch of ``frames``, the handle)
+    on one of the fetcher's four ends."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from dvf_tpu.parallel import MeshConfig, make_mesh
+    from dvf_tpu.runtime import Engine
+    from dvf_tpu.runtime import egress as egress_mod
+    from dvf_tpu.runtime.egress import ShardedBatchFetcher
+
+    monkeypatch.setattr(egress_mod, "STREAM_ON_CPU", True)
+    monkeypatch.setattr(egress_mod, "MIN_STREAM_D2H_MS", 0.0)
+    dev = jax.devices()[0]
+    one = SingleDeviceSharding(dev)
+    if path == "slab":  # a result sharded over two devices
+        eng = Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=2)))
+        eng.ensure_compiled(frames.shape, np.uint8)
+        f = ShardedBatchFetcher(eng.out_shape, eng.out_dtype,
+                                eng.output_sharding, slots=2)
+        result = eng.submit(255 - frames)
+    else:
+        shape = {"fallback": (4, 8, 8, 3)}.get(path, frames.shape)
+        f = ShardedBatchFetcher(
+            shape, np.uint8, one, slots=2,
+            mode="monolithic" if path == "monolithic" else "streamed")
+        result = jax.device_put(frames, dev)
+    handle = f.prefetch(result, len(frames))
+    return f, f.fetch(handle, 0)
+
+
+class TestRouterKeepsOneFrameAlive:
+
+    @pytest.mark.parametrize("path,handed", [
+        ("rows", True),         # one device, packed: a buffer a row
+        ("slab", False),        # sharded result: the pooled slab
+        ("monolithic", False),  # the CPU backend, a degraded lane
+        ("fallback", False),    # a batch of another geometry
+    ])
+    def test_rows_are_handed_on_or_copied(self, path, handed, monkeypatch):
+        from dvf_tpu.runtime.egress import LandedRows
+        from dvf_tpu.serve.batcher import ContinuousBatcher
+        from dvf_tpu.serve.router import ResultRouter
+        from dvf_tpu.serve.session import SessionConfig, StreamSession
+
+        rng = np.random.default_rng(5)
+        frames = rng.integers(0, 256, (4, H, W, 3), dtype=np.uint8)
+        f, out = _fetched(path, frames, monkeypatch)
+        assert isinstance(out, LandedRows) == handed
+        assert f.owns(out) == (path == "slab")
+        sessions = [StreamSession(n, SessionConfig(queue_size=8,
+                                                   slo_ms=60_000.0))
+                    for n in ("a", "b")]
+        for i in range(4):
+            sessions[i % 2].submit(frames[i])
+        plan = ContinuousBatcher(4).plan(sessions, time.time())
+        assert plan.valid == 4
+        router = ResultRouter()
+        assert router.route(plan, out) == 4
+        stats = router.stats()
+        assert stats["rows_handed_total"] == (4 if handed else 0)
+        assert stats["rows_copied_total"] == (0 if handed else 4)
+        got = {s.id: s.poll() for s in sessions}
+        for row, slot in enumerate(plan.slots):
+            d = got[slot.session.id][slot.index]
+            np.testing.assert_array_equal(d.frame, frames[row])
+            if handed:
+                assert d.frame is out[row]  # the landed buffer itself
+                assert not d.frame.flags.writeable
+            else:
+                assert not np.shares_memory(d.frame, out)
+                assert d.frame.flags.owndata and d.frame.flags.writeable
+
+    def test_a_kept_delivery_keeps_one_row_not_the_batch(self, monkeypatch):
+        """One delivery left in a session's out queue after the batch,
+        its handle and the other deliveries are gone keeps its own
+        row's buffer alive and no other row's."""
+        import gc
+        import weakref
+
+        from dvf_tpu.serve.batcher import ContinuousBatcher
+        from dvf_tpu.serve.router import ResultRouter
+        from dvf_tpu.serve.session import SessionConfig, StreamSession
+
+        rng = np.random.default_rng(6)
+        frames = rng.integers(0, 256, (4, H, W, 3), dtype=np.uint8)
+        f, out = _fetched("rows", frames, monkeypatch)
+        landed = [weakref.ref(r.base if r.base is not None else r)
+                  for r in out]
+        slow, quick = (StreamSession(n, SessionConfig(
+            queue_size=8, slo_ms=60_000.0, replay_window=0))  # a replay
+            #   ring would keep the polled frames too, each its one row
+            for n in ("slow", "quick"))
+        slow.submit(frames[0])
+        for i in (1, 2, 3):
+            quick.submit(frames[i])
+        plan = ContinuousBatcher(4).plan([slow, quick], time.time())
+        kept_row = [s.session for s in plan.slots].index(slow)
+        ResultRouter().route(plan, out)
+        assert len(quick.poll()) == 3  # polled and dropped
+        del out, plan, f
+        gc.collect()
+        assert [r() is not None for r in landed] == [
+            row == kept_row for row in range(4)]
+        (d,) = slow.poll()  # the slow client's frame is still whole
+        np.testing.assert_array_equal(d.frame, frames[0])

@@ -244,11 +244,17 @@ def test_pack_round_trip_at_the_output_geometry(packed_on_cpu):
         want = np.asarray(result)
         handle = fetcher.prefetch(result)
         assert isinstance(handle, egress_mod.PackedBatch) and handle.out_shape == eng.out_shape
+        assert len(handle.rows) == BATCH
         out = fetcher.fetch(handle, seq)
-        assert out.shape == eng.out_shape and out.dtype == np.uint8
-        np.testing.assert_array_equal(out, want)
+        # a buffer a row, at the OUTPUT geometry, none sharing memory with another
+        assert isinstance(out, egress_mod.LandedRows) and len(out) == BATCH
+        assert all(r.shape == eng.out_shape[1:] and r.dtype == np.uint8 and not r.flags.writeable
+                   for r in out)
+        assert not any(np.shares_memory(a, b) for i, a in enumerate(out) for b in out[i + 1:])
+        np.testing.assert_array_equal(np.stack(out), want)
     s = fetcher.stats.summary()
     assert s["transfer_layout"] == "u32rows" and s["packed_batches"] == s["batches"] == 4
+    assert s["row_landed_batches"] == 4 and s["rows_landed_total"] == 4 * BATCH
     assert s["bytes_total"] == 4 * BATCH * OUT_ROW
 
 
@@ -269,17 +275,47 @@ def test_bytes_counted_each_way(ref, config, packed, request):
         engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
     n_batches = 5
     streams = [_frames(7, 2 * n_batches), _frames(8, 2 * n_batches)]   # 20 rows: 5 full batches
-    _, stats, _ = _serve(filt, streams, engine=engine)
+    got, stats, _ = _serve(filt, streams, engine=engine)
     row = _bucket_row(stats)
     batches = row["ingest"]["batches"]
     assert batches == row["egress"]["batches"] == row["batches"] >= n_batches
-    # whole padded batches cross the link: rows x bytes a row, each way
+    # whole padded batches go up; whole padded batches come down the slab path, and on the packed
+    # layout the rows that carry a frame (a padding row is never transferred)
+    rows = BATCH * n_batches
+    padding = BATCH * batches - rows
     assert row["ingest"]["bytes_total"] == batches * BATCH * IN_ROW
-    assert row["egress"]["bytes_total"] == batches * BATCH * OUT_ROW
-    assert row["egress"]["bytes_total"] == SCALE * SCALE * row["ingest"]["bytes_total"]
+    assert row["egress"]["bytes_total"] == (rows if packed else batches * BATCH) * OUT_ROW
+    assert row["egress"]["rows_landed_total"] == (rows if packed else 0)
+    assert row["egress"]["rows_skipped_total"] == (padding if packed else 0)
+    assert row["egress"]["row_landed_batches"] == (batches if packed else 0)
+    if not padding:
+        assert row["egress"]["bytes_total"] == SCALE * SCALE * row["ingest"]["bytes_total"]
     assert row["out_geometry"] == [H * SCALE, W * SCALE, 3]
     assert row["step_donates_input"] is False
     assert row["egress"]["transfer_layout"] == ("u32rows" if packed else "plain")
+    # the packed layout's deliveries are the 2H x 2W buffers their rows landed in; a slab's are copies
+    assert stats["rows_handed_total"] == (rows if packed else 0)
+    assert stats["rows_copied_total"] == (0 if packed else rows)
+    assert all(d.frame.shape == (H * SCALE, W * SCALE, 3) and d.frame.flags.writeable != packed
+               for g in got for d in g)
+
+
+def test_a_short_batch_lands_its_valid_rows_at_the_output_geometry(ref, config, packed_on_cpu):
+    """3 frames in a batch of 4: three 2H x 2W rows cross, the padding row's four-fold bytes do not."""
+    filt = get_filter("super_resolution", params=_host(ref.make_params(9, config)),
+                      **config["filter"]["kwargs"])
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    streams = [_frames(9, 3)]
+    got, stats, _ = _serve(filt, streams, engine=engine)
+    row = _bucket_row(stats)
+    eg = row["egress"]
+    assert eg["rows_landed_total"] == 3 and eg["bytes_total"] == 3 * OUT_ROW
+    assert eg["rows_skipped_total"] == BATCH * eg["batches"] - 3 > 0
+    assert eg["row_landed_batches"] == eg["packed_batches"] == eg["batches"]
+    assert stats["rows_handed_total"] == 3 and stats["rows_copied_total"] == 0
+    want = ref.reference(streams[0], config, ref.make_params(9, config))
+    assert _within(_numbers([d.frame for d in got[0]], want),
+                   {"max_abs_steps": BF16_MAX_STEPS, "mean_abs_steps": BF16_MEAN_STEPS})
 
 
 def test_invert_row_keeps_its_geometry_and_donates():
