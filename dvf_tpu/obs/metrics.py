@@ -180,8 +180,33 @@ class LatencyStats:
 
 
 class IngestStats:
-    """Streamed-ingest accounting: how much H2D cost the pipeline actually
-    *exposed* vs how much it hid under decode/compute.
+    """Streamed-ingest accounting: what one batch's way onto the chip cost
+    the thread that staged it, split where the work happens, and how much
+    of the H2D the pipeline hid under decode/compute.
+
+    Five cumulative clocks, each taken on ``perf_counter`` inside the call
+    that does the work (the serve path's ``assemble_h2d`` interval is
+    their sum plus the dispatch loop's own code):
+
+    - ``stage_ms``: the host's copies of frames (and padding rows) into
+      the staging slabs (``BatchBuilder.write_row`` / ``finish``);
+    - ``put_ms``: time INSIDE the ``device_put`` calls (a host-side
+      relayout that ``device_put`` does synchronously shows here);
+    - ``wait_ms``: blocked on the depth window (``block_until_ready`` of
+      the oldest chunk: the link, or an asynchronous relayout);
+    - ``join_ms``: ``finish``, from the last pad copy to the assembled
+      array (the on-device chunk concat's dispatch and
+      ``make_array_from_single_device_arrays``);
+    - ``step_dispatch_ms``: ``DeviceLane.submit``'s ``Engine.submit`` /
+      ``submit_resident`` call, row map included (on the monolithic path
+      the engine's own blocking ``device_put`` is inside it).
+
+    Who sums which: ``overlap_efficiency`` below counts ``put + wait`` as
+    the exposed H2D; the benchmark's ``ingest_exposed_ms`` reads ``stage +
+    wait`` (the copy into the slabs ADDED to the wait for the link, the
+    puts left out); ``ingest_stage_ms`` / ``ingest_put_ms`` /
+    ``step_dispatch_ms`` read one each, and ``dispatch_thread_pct`` prints
+    all five beside what is left of ``assemble_h2d``.
 
     ``overlap_efficiency`` — the headline number (bench JSON, pipeline
     stats) — is the fraction of the batch's transfer cost hidden from the
@@ -217,16 +242,26 @@ class IngestStats:
         self.stage_ms_total = 0.0
         self.put_ms_total = 0.0
         self.wait_ms_total = 0.0
+        self.join_ms_total = 0.0
+        self.step_dispatch_ms_total = 0.0  # DeviceLane.submit's own
         self.bytes_total = 0       # bytes staged to the device (whole
         #   padded batches: what crossed the link, not the valid rows)
 
     def record_batch(self, stage_ms: float, put_ms: float,
-                     wait_ms: float, nbytes: int = 0) -> None:
+                     wait_ms: float, nbytes: int = 0,
+                     join_ms: float = 0.0) -> None:
         self.batches += 1
         self.bytes_total += nbytes
         self.stage_ms_total += stage_ms
         self.put_ms_total += put_ms
         self.wait_ms_total += wait_ms
+        self.join_ms_total += join_ms
+
+    def split_ms(self) -> tuple:
+        """The five cumulative clocks, in the docstring's order: a
+        caller's per-batch view is the difference of two reads."""
+        return (self.stage_ms_total, self.put_ms_total, self.wait_ms_total,
+                self.join_ms_total, self.step_dispatch_ms_total)
 
     def overlap_efficiency(self) -> Optional[float]:
         if (self.effective_mode != "streamed" or self.batches == 0
@@ -253,6 +288,8 @@ class IngestStats:
             "stage_ms_total": round(self.stage_ms_total, 4),
             "h2d_put_ms_total": round(self.put_ms_total, 4),
             "h2d_wait_ms_total": round(self.wait_ms_total, 4),
+            "join_ms_total": round(self.join_ms_total, 4),
+            "step_dispatch_ms_total": round(self.step_dispatch_ms_total, 4),
             "bytes_total": self.bytes_total,
             "h2d_block_ms": (round(self.h2d_block_ms, 4)
                              if self.h2d_block_ms else None),
@@ -310,6 +347,10 @@ class EgressStats:
         self.pool_allocs = 0             # slab-pool constructions (stays 1
         #   across a steady-state run — the allocation-regression tests;
         #   0 on the packed layout, which has no pool)
+        self.prefetch_rows_total = 0     # transfers started at the submit
+        self.prefetch_ms_total = 0.0     # ... and what starting them (the
+        #   pack's dispatch, the copy_to_host_async calls) cost the
+        #   dispatch thread, inside ShardedBatchFetcher.prefetch
         self.d2h_wait_ms_total = 0.0     # blocked on shard host copies
         self.copy_ms_total = 0.0         # scatter into the output slab
         self.bytes_total = 0             # bytes landed on the host, at the
@@ -336,6 +377,10 @@ class EgressStats:
         self.bytes_total += nbytes
         self.d2h_wait_ms_total += wait_ms
         self.copy_ms_total += copy_ms
+
+    def record_prefetch(self, start_ms: float, transfers: int) -> None:
+        self.prefetch_ms_total += start_ms
+        self.prefetch_rows_total += transfers
 
     def record_encode(self, encode_ms: float, wait_ms: float) -> None:
         self.encode_batches += 1
@@ -377,6 +422,8 @@ class EgressStats:
             "row_landed_batches": self.row_landed_batches,
             "rows_landed_total": self.rows_landed_total,
             "rows_skipped_total": self.rows_skipped_total,
+            "prefetch_rows_total": self.prefetch_rows_total,
+            "prefetch_ms_total": round(self.prefetch_ms_total, 4),
             "d2h_wait_ms": round(self.d2h_wait_ms_total / n, 4),
             "copy_ms": round(self.copy_ms_total / n, 4),
             # Cumulative totals beside the lifetime means (window deltas).
@@ -431,22 +478,28 @@ class BatchStamps:
 
     ``t_chosen`` (dispatch: ``select_bucket`` returned) → ``t_permit``
     (in-flight permit acquired) → ``t_submit`` (``Engine.submit``
-    returned) → ``t_taken`` (collect: popped off the in-flight queue) →
-    ``t_ready`` (``block_until_ready`` returned) → ``t_fetched``
-    (``fetcher.fetch`` returned) → ``t_routed`` (``router.route``
-    returned). Everything that times a batch is a view of these: the
-    bucket's :class:`StageStats`, ``FrameLineage`` marks, the Tracer's
-    dispatch/collect spans, the tick-cost sample. ``stages`` is the
-    bucket's StageStats (None on ad-hoc plans: nothing is folded).
+    returned) → ``t_prefetched`` (``lane.prefetch`` returned: the way
+    back is started) → ``t_taken`` (collect: popped off the in-flight
+    queue) → ``t_ready`` (``block_until_ready`` returned) →
+    ``t_fetched`` (``fetcher.fetch`` returned) → ``t_routed``
+    (``router.route`` returned). ``t_held``: where the hold that this
+    batch's binding ended had started (0.0: it was bound on the tick
+    that found its frames). Everything that times a batch is a view of
+    these: the bucket's :class:`StageStats` and :class:`StarvedStats`,
+    ``FrameLineage`` marks, the Tracer's dispatch/collect spans, the
+    tick-cost sample. ``stages`` is the bucket's StageStats (None on
+    ad-hoc plans: nothing is folded).
     """
 
-    __slots__ = ("stages", "t_chosen", "t_permit", "t_submit", "t_taken",
-                 "t_ready", "t_fetched", "t_routed", "ms", "bins")
+    __slots__ = ("stages", "t_held", "t_chosen", "t_permit", "t_submit",
+                 "t_prefetched", "t_taken", "t_ready", "t_fetched",
+                 "t_routed", "ms", "bins")
 
     def __init__(self, stages: "Optional[StageStats]" = None,
                  t_chosen: float = 0.0):
         self.stages = stages
         self.t_chosen = t_chosen
+        self.t_held = self.t_prefetched = 0.0
         self.t_permit = self.t_submit = self.t_taken = 0.0
         self.t_ready = self.t_fetched = self.t_routed = 0.0
         self.ms: Optional[tuple] = None    # the five batch-level intervals,
@@ -499,7 +552,8 @@ class _StageCell:
         row = {"max_ms": round(self.max_ms, 4),
                # sparse: [bin, count] for the occupied bins only
                "hist": [[i, n] for i, n in enumerate(self.hist) if n]}
-        if frames is not None:     # a frame component (route is not)
+        if frames is not None:     # a frame component (route, prefetch
+            #   are thread states only)
             row["frames"] = frames
             row["ms_total"] = round(self.ms_total, 4)
         if batch_level:
@@ -525,11 +579,13 @@ class StageStats:
     The batch-level components also carry ``batches`` and
     ``batch_ms_total`` (once per batch, whatever its fill): the pacing
     threads' states for this bucket — dispatch ``{permit_wait,
-    assemble_h2d}``, collect ``{device, d2h, route}`` (``route``:
-    fetched → ``router.route`` returned; a thread state, no frame
-    component, since ``deliver`` already covers the frames' share of it).
+    assemble_h2d, prefetch}``, collect ``{device, d2h, route}``
+    (``prefetch``: submit returned → ``lane.prefetch`` returned, and
+    ``route``: fetched → ``router.route`` returned, are thread states and
+    no frame components: a frame's ``inflight_wait`` runs from the submit
+    and already holds the one, ``deliver`` the frames' share of the other).
 
-    Writers: the dispatch thread writes its two batch cells, the collect
+    Writers: the dispatch thread writes its three batch cells, the collect
     thread the other four, so those take no lock; the frame fold runs on
     whichever thread delivers (collect, or a finalize) and takes one
     lock per delivery round. Readers see monotone values.
@@ -541,6 +597,7 @@ class StageStats:
         self.cells: Dict[str, _StageCell] = {
             c: _StageCell() for c in SERVE_COMPONENTS}
         self.route = _StageCell()
+        self.prefetch = _StageCell()
         self._lock = threading.Lock()
         c = self.cells
         self._frame_cells = (c["queue_ingress"], c["queue_bucket"],
@@ -550,10 +607,17 @@ class StageStats:
     # -- writers ---------------------------------------------------------
 
     def note_dispatched(self, st: BatchStamps) -> None:
-        """Dispatch thread, once ``Engine.submit`` returned."""
+        """Dispatch thread, once ``lane.prefetch`` returned."""
         permit, asm = self._batch_cells[0], self._batch_cells[1]
         permit.add_batch((st.t_permit - st.t_chosen) * 1e3)
         asm.add_batch((st.t_submit - st.t_permit) * 1e3)
+        self._add_state(self.prefetch, (st.t_prefetched - st.t_submit) * 1e3)
+
+    @staticmethod
+    def _add_state(cell: _StageCell, ms: float) -> None:
+        """A thread-state cell (no frame fold): its histogram is per batch."""
+        cell.add_batch(ms)
+        cell.hist[hist_bin(ms)] += 1
 
     def note_collected(self, st: BatchStamps) -> None:
         """Collect thread, once ``router.route`` returned (``st`` closed)."""
@@ -562,9 +626,7 @@ class StageStats:
         cells[2].add_batch(ms[2])
         cells[3].add_batch(ms[3])
         cells[4].add_batch(ms[4])
-        route_ms = (st.t_routed - st.t_fetched) * 1e3
-        self.route.add_batch(route_ms)
-        self.route.hist[hist_bin(route_ms)] += 1
+        self._add_state(self.route, (st.t_routed - st.t_fetched) * 1e3)
 
     def fold_delivered(self, rows) -> None:
         """Fold one delivery round: ``rows`` = ``[(slot, now), ...]`` of
@@ -616,7 +678,69 @@ class StageStats:
                     for name, cell in self.cells.items()},
             }
         doc["route"] = self.route.summary(None, True)
+        doc["prefetch"] = self.prefetch.summary(None, True)
         return doc
+
+
+# What the dispatch thread was doing, in a batch's own stamps, between
+# the moment the chip ran out of this frontend's work and the moment it
+# got more (StarvedStats); each ends at the stamp beside it.
+STARVED_STATES = ("idle", "hold", "permit_wait", "assemble_h2d")
+
+
+class StarvedStats:
+    """A bucket's starvation ledger: why the chip had nothing of ours to
+    run, asked of the program's own stamps (the bucket row's ``starved``
+    block, cumulative ms).
+
+    The chip ran out of work at ``t_ready`` of batch n−1 and got more at
+    ``t_submit`` of batch n. Where that interval is positive it is a
+    *gap*, cut at batch n's stamps and booked under what the dispatch
+    thread was doing in each part: ``idle`` (before the bucket had frames
+    to bind: up to ``t_held``, or ``t_chosen`` for a batch that was not
+    held), ``hold`` (``t_held`` → ``t_chosen``), ``permit_wait``
+    (``t_chosen`` → ``t_permit``), ``assemble_h2d`` (``t_permit`` →
+    ``t_submit``). Written by the collect thread, which keeps the
+    previous batch's ``t_ready``; the gap belongs to the bucket of batch
+    n. A collect thread's first batch (a generation's first, the first
+    after a supervised recovery) opens none.
+
+    Bias, as ``_Bucket.observe_device`` states its own: ``t_ready`` is
+    read by the collect thread, so when that thread is behind the device
+    the gap reads too SHORT, never too long. And the gap ends at the
+    submit: an H2D that lands after it (a padded batch's, whose last
+    chunks are still on the link when the step is dispatched) is the
+    device's idle time and not this ledger's. That transfer, and the
+    dispatch-to-start latency of the step itself, is the difference to
+    the device trace's idle share.
+    """
+
+    def __init__(self):
+        self.ms = dict.fromkeys(STARVED_STATES, 0.0)
+        self.gaps = 0
+        self.max_gap_ms = 0.0
+
+    def note(self, last_ready: float, st: BatchStamps) -> None:
+        """Collect thread, once batch n is ready: ``last_ready`` is batch
+        n−1's ``t_ready`` (0.0: there was none on this thread)."""
+        if not last_ready or st.t_submit <= last_ready:
+            return
+        cur = last_ready
+        ends = (st.t_held or st.t_chosen, st.t_chosen, st.t_permit,
+                st.t_submit)
+        for state, end in zip(STARVED_STATES, ends):
+            if end > cur:
+                self.ms[state] += (end - cur) * 1e3
+                cur = end
+        gap_ms = (st.t_submit - last_ready) * 1e3
+        self.gaps += 1
+        if gap_ms > self.max_gap_ms:
+            self.max_gap_ms = gap_ms
+
+    def summary(self) -> dict:
+        return {**{f"{k}_ms_total": round(v, 4) for k, v in self.ms.items()},
+                "gaps_total": self.gaps,
+                "max_gap_ms": round(self.max_gap_ms, 4)}
 
 
 class ThreadClock:
@@ -624,11 +748,13 @@ class ThreadClock:
     thread's life lands in exactly one state, so the states sum to
     ``accounted_to − started``. ``idle`` is whatever belongs to no
     bucket (no plan and sleeping a tick, control actions, an empty
-    in-flight queue, a batch that was shed); the named states are the
-    batch intervals the bucket's :class:`StageStats` also holds, and the
-    dispatch thread's ``hold`` (ticks on which a bucket's short batch
-    waited for the device's backlog: the bucket row's ``hold`` block),
-    summed here over every bucket the frontend ever had. Single writer (the
+    in-flight queue, a batch that was shed or whose submit or prefetch
+    raised); the named states are the batch intervals the bucket's
+    :class:`StageStats` also holds (dispatch ``permit_wait``,
+    ``assemble_h2d``, ``prefetch``; collect ``device``, ``d2h``,
+    ``route``), and the dispatch thread's ``hold`` (ticks on which a
+    bucket's short batch waited for the device's backlog: the bucket
+    row's ``hold`` block), summed here over every bucket the frontend ever had. Single writer (the
     thread itself); a replacement thread (supervised recovery) adopts
     its predecessor's clock, so the ledger spans the frontend's life."""
 

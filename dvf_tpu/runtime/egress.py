@@ -412,6 +412,13 @@ class ShardedBatchFetcher:
         and never transferred), otherwise ``result`` itself, its
         transfer started per shard on the streamed path so each shard's
         copy is independently in flight."""
+        t0 = time.perf_counter()
+        handle, started = self._start_transfers(result, valid)
+        self.stats.record_prefetch((time.perf_counter() - t0) * 1e3, started)
+        return handle
+
+    def _start_transfers(self, result: Any, valid: Optional[int]):
+        """``prefetch``'s work: (what to hand ``fetch``, transfers started)."""
         pack = self._pack  # read once: release() may clear it
         if (pack is not None
                 and getattr(result, "dtype", None) == self.dtype
@@ -421,7 +428,8 @@ class ShardedBatchFetcher:
             rows = tuple(run(result, table))[:valid]
             for row in rows:
                 row.copy_to_host_async()
-            return PackedBatch(rows, self.out_shape)
+            return PackedBatch(rows, self.out_shape), len(rows)
+        started = 0
         try:
             if self.effective_mode == "streamed":
                 seen = set()
@@ -436,11 +444,13 @@ class ShardedBatchFetcher:
                         continue
                     seen.add(key)
                     sh.data.copy_to_host_async()
+                    started += 1
             else:
                 result.copy_to_host_async()
+                started = 1
         except AttributeError:
             pass  # non-jax results (tests/fakes) have nothing to prefetch
-        return result
+        return result, started
 
     # -- collect side ---------------------------------------------------
 

@@ -324,6 +324,7 @@ class BatchBuilder:
         self._stage_s = 0.0
         self._put_s = 0.0
         self._wait_s = 0.0
+        self._join_s = 0.0
         self._first_put_t: Optional[float] = None
         self._t_begin = time.perf_counter()
 
@@ -484,7 +485,8 @@ class BatchBuilder:
                 self._stage_s += time.perf_counter() - t0
                 self._launch(ci)
                 t0 = time.perf_counter()
-        self._stage_s += time.perf_counter() - t0
+        t_join = time.perf_counter()
+        self._stage_s += t_join - t0
         import jax
         import jax.numpy as jnp
 
@@ -496,6 +498,7 @@ class BatchBuilder:
         batch = jax.make_array_from_single_device_arrays(
             self.asm.batch_shape, self.asm.sharding, arrs)
         t_end = time.perf_counter()
+        self._join_s = t_end - t_join
         tracer = self.asm.tracer
         if tracer is not None and tracer.enabled and self._first_put_t:
             off = self.asm.wall_offset_s
@@ -513,4 +516,5 @@ class BatchBuilder:
             put_ms=self._put_s * 1e3,
             wait_ms=self._wait_s * 1e3,
             nbytes=self.asm.batch_nbytes,
+            join_ms=self._join_s * 1e3,
         )

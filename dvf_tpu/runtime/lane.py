@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import sys
 import threading
+import time
 import weakref
 from typing import Any, Callable, Optional, Tuple, Union
 
@@ -180,11 +181,17 @@ class DeviceLane:
 
     def submit(self, builder: BatchBuilder, valid: int, rows=None):
         """Pad and flush the staged batch, run the step: the (async)
-        device result. ``rows``: a session-state filter's row map."""
+        device result. ``rows``: a session-state filter's row map. The
+        engine call's own time (the jitted step's dispatch on the host)
+        is the ingest block's ``step_dispatch_ms``."""
         batch, resident = builder.finish(valid)
         run = (self.engine.submit_resident if resident
                else self.engine.submit)
-        return run(batch) if rows is None else run(batch, rows)
+        t0 = time.perf_counter()
+        result = run(batch) if rows is None else run(batch, rows)
+        self.ingest_stats.step_dispatch_ms_total += (
+            time.perf_counter() - t0) * 1e3
+        return result
 
     # -- off the chip ------------------------------------------------------
 

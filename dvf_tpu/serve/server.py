@@ -90,6 +90,7 @@ from dvf_tpu.obs.metrics import (
     BatchStamps,
     LatencyStats,
     StageStats,
+    StarvedStats,
     ThreadClock,
 )
 from dvf_tpu.obs.registry import (
@@ -146,7 +147,7 @@ TRACK_DISPATCH, TRACK_DEVICE, TRACK_COLLECT = 0, 1, 2
 # ``idle`` is whatever belongs to no bucket). With ``trace`` on each is
 # one span per batch on the thread's lane, ``<thread>:<state>``
 # (``hold``: one span per hold, whatever batch ends it).
-DISPATCH_STATES = ("hold", "permit_wait", "assemble_h2d")
+DISPATCH_STATES = ("hold", "permit_wait", "assemble_h2d", "prefetch")
 COLLECT_STATES = ("device", "d2h", "route")
 
 # dvf_compile_ms histogram bounds: serving compiles span sub-ms pool
@@ -425,6 +426,9 @@ class _Bucket:
         self.device_ms: Optional[float] = None  # what this program's
         #   last batch took of the device (observe_device): when the
         #   dispatch thread expects a backlog of it to have run out
+        self.starved = StarvedStats()  # what the dispatch thread was
+        #   doing while the chip had nothing of ours to run (the collect
+        #   thread's, beside observe_device): stats_row()["starved"]
 
     @property
     def engine(self) -> Engine:
@@ -620,6 +624,7 @@ class _Bucket:
             "engine_compile_count": self.engine.stats.compile_count,
             "stages": self.stages.summary(),
             "hold": {k: round(v, 4) for k, v in self.hold_counts.items()},
+            "starved": self.starved.summary(),
         }
         # Process-wide XLA backend compilations (obs.ledger
         # XlaCompileWatch), the same two numbers on every row: a window
@@ -3421,6 +3426,8 @@ class ServeFrontend:
                     # frames are bound (or were shed, or another bucket
                     # leads now).
                     after_hold = plan is not None and pick is held[0]
+                    if after_hold:
+                        plan.stamps.t_held = held[1]
                     if tracer.enabled:
                         tracer.complete("dispatch:hold", held[1], now,
                                         TRACK_DISPATCH,
@@ -3473,6 +3480,7 @@ class ServeFrontend:
                 # program's cost. Contended ticks still count batches;
                 # they just don't feed the estimate.
                 plan.cost_sample = len(self._window) == 0
+                split = None  # trace only: the ingest clocks before it
                 if self.audit is not None:
                     # Shadow-replay sampling (obs.audit): the sampler
                     # decides per staged frame; a picked frame's INPUT
@@ -3491,6 +3499,8 @@ class ServeFrontend:
                     builder = lane.begin(
                         (bucket.batch_size, *bucket.frame_shape),
                         bucket.frame_dtype, seq)
+                    if tracer.enabled:
+                        split = lane.ingest_stats.split_ms()
                     for row, slot in enumerate(plan.slots):
                         builder.write_row(row, slot.frame)
                         slot.frame = None  # drop the client's buffer
@@ -3507,6 +3517,9 @@ class ServeFrontend:
                     # the lane's handle (the result itself is dropped
                     # here); it pins the fetcher the D2H was issued on.
                     result = lane.prefetch(result, plan.valid)
+                    # Stamp: the pack is dispatched and the rows' D2H
+                    # started; a thread state of its own, not idle.
+                    st.t_prefetched = time.time()
                 except Exception as e:  # noqa: BLE001 — drop this batch
                     sem.release()
                     self.router.discard(plan, kind=classify(e, "dispatch"))
@@ -3518,23 +3531,31 @@ class ServeFrontend:
                 clock.spend("idle", st.t_chosen)
                 clock.spend("permit_wait", t0)
                 clock.spend("assemble_h2d", st.t_submit)
+                clock.spend("prefetch", st.t_prefetched)
                 bucket.stages.note_dispatched(st)
                 bucket.note_bound(plan.valid, after_hold)
                 if tracer.enabled:
-                    # Trace view of the same stamps: the legacy
-                    # serve_dispatch span and one span per thread state.
-                    tracer.complete("serve_dispatch", t0, st.t_submit,
-                                    TRACK_DISPATCH, seq=seq,
-                                    frames=plan.valid,
-                                    bucket=bucket.label())
+                    # Trace view of the same stamps: one span per thread
+                    # state; assemble_h2d carries the ingest clocks'
+                    # split of this batch (stage / put / wait / join /
+                    # step dispatch, each taken inside its call).
                     n_sess = len({slot.session.id for slot in plan.slots})
                     out_bytes = bucket.out_bytes()
+                    stage_ms, put_ms, wait_ms, join_ms, step_ms = (
+                        round(b - a, 3) for a, b in
+                        zip(split, lane.ingest_stats.split_ms()))
                     tracer.complete("dispatch:permit_wait", st.t_chosen,
                                     t0, TRACK_DISPATCH, seq=seq,
                                     sessions=n_sess, out_bytes=out_bytes)
                     tracer.complete("dispatch:assemble_h2d", t0,
                                     st.t_submit, TRACK_DISPATCH, seq=seq,
-                                    sessions=n_sess, out_bytes=out_bytes)
+                                    sessions=n_sess, out_bytes=out_bytes,
+                                    stage_ms=stage_ms, put_ms=put_ms,
+                                    wait_ms=wait_ms, join_ms=join_ms,
+                                    step_dispatch_ms=step_ms)
+                    tracer.complete("dispatch:prefetch", st.t_submit,
+                                    st.t_prefetched, TRACK_DISPATCH,
+                                    seq=seq, rows=plan.valid)
                 # In-flight window: registered from now until the collect
                 # side materializes (or discards) it; carries the plan so
                 # a recovery can shed the sessions' claims even for a
@@ -3607,6 +3628,7 @@ class ServeFrontend:
                 if bucket is not None:
                     bucket.observe_device(
                         (st.t_ready - max(st.t_submit, last_ready)) * 1e3)
+                    bucket.starved.note(last_ready, st)
                 last_ready = st.t_ready
                 try:
                     # Streamed egress: shard host copies into the slot's

@@ -11,6 +11,10 @@ and the tick-cost sample are all views of them. Pinned here:
 - a histogram delta between two reads holds what was recorded between them;
 - ``permit_wait`` is its own interval (not ``queue_bucket``), and
   ``inflight_wait`` (not ``device``) takes a held collect thread's time;
+- the dispatch thread from inside (PR 40): ``prefetch`` is a state of its
+  own and not ``idle``; the ingest block's five clocks stay inside
+  ``assemble_h2d``; the bucket row's ``starved`` block books each device
+  gap under what the dispatch thread was doing, from the batch's stamps;
 - lineage and trace are views: same numbers, no clock of their own, and
   nothing is allocated for them when they are off;
 - XLA compilations are counted process-wide; a flight dump's device
@@ -49,12 +53,12 @@ def drain(fe, sid, want, deadline_s=30.0):
     return got
 
 
-def serve(n_sessions=2, n_frames=12, pace_s=0.0, **cfg):
+def serve(n_sessions=2, n_frames=12, pace_s=0.0, engine=None, **cfg):
     """One served run; returns (deliveries per session, stats, frontend)."""
     kw = dict(batch_size=4, queue_size=500, slo_ms=60_000.0,
               telemetry_sample_s=0.0)
     kw.update(cfg)
-    fe = ServeFrontend(get_filter("invert"), ServeConfig(**kw))
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(**kw), engine=engine)
     got = {}
     with fe:
         sids = [fe.open_stream() for _ in range(n_sessions)]
@@ -127,7 +131,7 @@ def _read_just_after_an_accrual(fe, thread):
 
 
 @pytest.mark.parametrize("thread,states", [
-    ("dispatch", ("idle", "hold", "permit_wait", "assemble_h2d")),
+    ("dispatch", ("idle", "hold", "permit_wait", "assemble_h2d", "prefetch")),
     ("collect", ("idle", "device", "d2h", "route")),
 ])
 def test_thread_states_sum_to_wall_time(thread, states):
@@ -159,7 +163,7 @@ def test_thread_states_sum_to_wall_time(thread, states):
             assert bucket_row["hold"]["hold_ms_total"] == pytest.approx(
                 row["hold_ms"], abs=0.01)
             continue
-        cell = st["route"] if s == "route" else st["components"][s]
+        cell = st[s] if s in ("route", "prefetch") else st["components"][s]
         assert cell["batch_ms_total"] == pytest.approx(row[f"{s}_ms"], abs=0.01)
     assert row[f"{states[-1]}_ms"] > 0.0
 
@@ -339,7 +343,7 @@ def test_hold_is_a_thread_state_a_bucket_block_and_queue_bucket(device_gate):
     assert hold["held_batches_total"] == 2
     assert hold["hold_ms_total"] > 120.0
     assert thread["hold_ms"] == pytest.approx(hold["hold_ms_total"], abs=0.01)
-    states = ("idle", "hold", "permit_wait", "assemble_h2d")
+    states = ("idle", "hold", "permit_wait", "assemble_h2d", "prefetch")
     assert sum(thread[f"{s}_ms"] for s in states) == pytest.approx(
         (thread["accounted_to"] - thread["started"]) * 1e3, rel=1e-6, abs=0.01)
     spans = [e for e in snap["events"] if e["name"] == "dispatch:hold"]
@@ -353,6 +357,351 @@ def test_hold_is_a_thread_state_a_bucket_block_and_queue_bucket(device_gate):
     assert st["components"]["queue_bucket"]["ms_total"] == pytest.approx(
         hold["hold_ms_total"], rel=0.1, abs=5.0)
     assert st["components"]["permit_wait"]["max_ms"] < 40.0
+
+
+# ---------------------------------------------------------------------------
+# The dispatch thread from inside: prefetch, the ingest split, starvation
+# ---------------------------------------------------------------------------
+
+
+def test_prefetch_is_a_dispatch_state_and_not_idle(monkeypatch):
+    """A lane whose ``prefetch`` takes 20 ms: the dispatch thread's ledger
+    shows 20 ms a batch under ``prefetch`` (booked as ``idle`` before PR
+    40), the bucket's ``prefetch`` cell counts every batch, a frame's
+    components do not change, and the egress block counts the transfers."""
+    from dvf_tpu.runtime import lane as lane_mod
+
+    real = lane_mod.DeviceLane.prefetch
+
+    def slow(self, result, valid=None):
+        time.sleep(0.02)
+        return real(self, result, valid)
+
+    monkeypatch.setattr(lane_mod.DeviceLane, "prefetch", slow)
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, trace=True,
+        telemetry_sample_s=0.0))
+    with fe:
+        sid = fe.open_stream()
+        for j in range(24):
+            fe.submit(sid, frame_u8(0, j))
+        assert len(drain(fe, sid, 24)) == 24
+        now, stats = _read_just_after_an_accrual(fe, "dispatch")
+        snap = fe.tracer.snapshot()
+    st, row = stages_of(stats)
+    thread = stats["threads"]["dispatch"]
+    n = row["batches"]
+    assert st["prefetch"]["batches"] == n >= 6
+    assert "frames" not in st["prefetch"]       # a thread state, no component
+    assert sum(k for _, k in st["prefetch"]["hist"]) == n
+    assert 20.0 * n <= thread["prefetch_ms"] < 20.0 * n + 15.0 * n
+    assert st["prefetch"]["batch_ms_total"] == pytest.approx(
+        thread["prefetch_ms"], abs=0.01)
+    states = ("idle", "hold", "permit_wait", "assemble_h2d", "prefetch")
+    assert sum(thread[f"{s}_ms"] for s in states) == pytest.approx(
+        (thread["accounted_to"] - thread["started"]) * 1e3, rel=1e-6, abs=0.01)
+    # ... so idle is what the wall leaves, the 20 ms a batch not in it
+    assert thread["idle_ms"] == pytest.approx(
+        thread["wall_ms"] - sum(thread[f"{s}_ms"] for s in states[1:]), abs=0.01)
+    assert set(st["components"]) == set(SERVE_COMPONENTS)
+    assert sum(c["ms_total"] for c in st["components"].values()) == \
+        pytest.approx(st["latency_ms_total"], rel=1e-6, abs=1e-3)
+    # inflight_wait still starts at the submit: the 20 ms are inside it
+    assert st["components"]["inflight_wait"]["batch_ms_total"] >= 20.0 * n
+    spans = [e for e in snap["events"] if e["name"] == "dispatch:prefetch"]
+    assert [e["args"]["seq"] for e in spans] == list(range(n))
+    assert sum(e["args"]["rows"] for e in spans) == 24
+    # the fetcher's own clock (inside the slowed call) and its transfers
+    assert row["egress"]["prefetch_rows_total"] >= n
+    assert 0.0 < row["egress"]["prefetch_ms_total"] < thread["prefetch_ms"]
+
+
+def test_a_raising_prefetch_stays_under_idle(monkeypatch):
+    from dvf_tpu.runtime import lane as lane_mod
+
+    real = lane_mod.DeviceLane.prefetch
+    calls = []
+
+    def flaky(self, result, valid=None):
+        calls.append(1)
+        if len(calls) == 2:
+            time.sleep(0.03)
+            raise RuntimeError("prefetch: injected")
+        return real(self, result, valid)
+
+    monkeypatch.setattr(lane_mod.DeviceLane, "prefetch", flaky)
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, telemetry_sample_s=0.0))
+    with fe:
+        sid = fe.open_stream()
+        for j in range(12):
+            fe.submit(sid, frame_u8(0, j))
+        assert len(drain(fe, sid, 8)) == 8          # one batch of three shed
+        now, stats = _read_just_after_an_accrual(fe, "dispatch")
+    st, row = stages_of(stats)
+    thread = stats["threads"]["dispatch"]
+    assert st["prefetch"]["batches"] == row["batches"] == 2
+    assert thread["prefetch_ms"] < 30.0 <= thread["idle_ms"]
+    assert thread["prefetch_ms"] == pytest.approx(
+        st["prefetch"]["batch_ms_total"], abs=0.01)
+
+
+SPLIT = ("stage_ms_total", "h2d_put_ms_total", "h2d_wait_ms_total",
+         "join_ms_total", "step_dispatch_ms_total")
+
+
+@pytest.mark.parametrize("mode", ["monolithic", "streamed"])
+def test_ingest_split_stays_inside_assemble_h2d(mode, monkeypatch):
+    """stage + put + wait + join + step dispatch, each taken inside the call
+    that does the work, are parts of ``assemble_h2d``: never more than it,
+    and what they leave (the loop's own code: begin, the row loop, the
+    stamps) is under 0.5 ms a batch + 25% at this size, warm."""
+    from dvf_tpu.parallel import MeshConfig, make_mesh
+    from dvf_tpu.runtime import ingest as ingest_mod
+    from dvf_tpu.runtime.engine import Engine
+
+    if mode == "streamed":
+        monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    engine = Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=1)))
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=8, queue_size=500, slo_ms=60_000.0, trace=True,
+        telemetry_sample_s=0.0), engine=engine)
+    reads = []
+    with fe:
+        sid = fe.open_stream()
+        for burst in range(2):      # the first takes the compiles in
+            for j in range(64):
+                fe.submit(sid, frame_u8(0, burst * 64 + j))
+            assert len(drain(fe, sid, 64)) == 64
+            reads.append(stages_of(fe.stats()))
+        snap = fe.tracer.snapshot()
+    (st0, row0), (st1, row1) = reads
+    assert row1["ingest"]["mode"] == mode
+    n = row1["batches"] - row0["batches"]
+    assert n == row1["ingest"]["batches"] - row0["ingest"]["batches"] >= 8
+    split = {k: row1["ingest"][k] - row0["ingest"][k] for k in SPLIT}
+    asm = (st1["components"]["assemble_h2d"]["batch_ms_total"]
+           - st0["components"]["assemble_h2d"]["batch_ms_total"])
+    assert all(v >= 0.0 for v in split.values())
+    assert split["stage_ms_total"] > 0.0 and split["step_dispatch_ms_total"] > 0.0
+    if mode == "streamed":
+        assert split["h2d_put_ms_total"] > 0.0 and split["join_ms_total"] > 0.0
+    else:       # one host buffer: nothing is put or joined outside the engine
+        assert split["h2d_put_ms_total"] == split["join_ms_total"] == 0.0
+    total = sum(split.values())
+    assert total <= asm + 0.01
+    assert asm - total <= 0.5 * n + 0.25 * asm
+    # the assemble_h2d spans carry the same split, batch by batch
+    spans = [e["args"] for e in snap["events"]
+             if e["name"] == "dispatch:assemble_h2d"]
+    assert len(spans) == row1["batches"]
+    args = ("stage_ms", "put_ms", "wait_ms", "join_ms", "step_dispatch_ms")
+    for ours, theirs in zip(args, SPLIT):
+        assert sum(a[ours] for a in spans) == pytest.approx(
+            row1["ingest"][theirs], abs=0.001 * len(spans) + 0.001)
+
+
+def _stamps(t_chosen, t_permit, t_submit, t_held=0.0):
+    st = M.BatchStamps(None, t_chosen)
+    st.t_held, st.t_permit, st.t_submit = t_held, t_permit, t_submit
+    return st
+
+
+@pytest.mark.parametrize("last_ready,stamps,want", [
+    # the collect thread's first batch: no predecessor, no gap
+    (0.0, (10.0, 10.1, 10.2), None),
+    # the device still had batch n-1 when batch n was submitted
+    (10.3, (10.0, 10.1, 10.2), None),
+    (10.2, (10.0, 10.1, 10.2), None),
+    # ran out before the bucket had frames: every state, cut at the stamps
+    (9.0, (10.0, 10.1, 10.4), dict(idle=1000.0, permit_wait=100.0, assemble_h2d=300.0)),
+    # ... held from 9.5: idle up to the hold's start, hold up to t_chosen
+    (9.0, (10.0, 10.1, 10.4, 9.5),
+     dict(idle=500.0, hold=500.0, permit_wait=100.0, assemble_h2d=300.0)),
+    # ran out during the hold
+    (9.8, (10.0, 10.1, 10.4, 9.5), dict(hold=200.0, permit_wait=100.0, assemble_h2d=300.0)),
+    # ran out while the batch waited for its permit
+    (10.05, (10.0, 10.1, 10.4, 9.5), dict(permit_wait=50.0, assemble_h2d=300.0)),
+    # ran out while it was being staged: the invert cell's case
+    (10.3, (10.0, 10.1, 10.4), dict(assemble_h2d=100.0)),
+], ids=["first", "busy", "touching", "idle", "held", "in_hold", "in_permit_wait",
+        "in_assemble"])
+def test_starved_block_cuts_a_gap_at_the_batchs_stamps(last_ready, stamps, want):
+    starved = M.StarvedStats()
+    starved.note(last_ready, _stamps(*stamps))
+    doc = starved.summary()
+    assert set(doc) == {f"{s}_ms_total" for s in M.STARVED_STATES} | {
+        "gaps_total", "max_gap_ms"}
+    if want is None:
+        assert doc["gaps_total"] == 0 and doc["max_gap_ms"] == 0.0
+        assert all(doc[f"{s}_ms_total"] == 0.0 for s in M.STARVED_STATES)
+        return
+    for s in M.STARVED_STATES:
+        assert doc[f"{s}_ms_total"] == pytest.approx(want.get(s, 0.0), abs=1e-6), s
+    assert doc["gaps_total"] == 1
+    assert doc["max_gap_ms"] == pytest.approx(sum(want.values()), abs=1e-6)
+    # cumulative: a second, shorter gap adds up and leaves the maximum
+    starved.note(20.0, _stamps(19.0, 19.5, 20.05))
+    doc = starved.summary()
+    assert doc["gaps_total"] == 2
+    assert doc["assemble_h2d_ms_total"] == pytest.approx(
+        want.get("assemble_h2d", 0.0) + 50.0, abs=1e-6)
+    assert doc["max_gap_ms"] == pytest.approx(sum(want.values()), abs=1e-6)
+
+
+def test_starved_block_through_a_served_run():
+    """Two bursts 0.4 s apart: the device ran out of work between them, the
+    dispatch thread had nothing to bind, so the wait is a gap under
+    ``idle``; the states sum to the gaps, and there is at most one gap a
+    batch after the first."""
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, telemetry_sample_s=0.0))
+    with fe:
+        sid = fe.open_stream()
+        for burst in range(2):
+            for j in range(8):
+                fe.submit(sid, frame_u8(0, burst * 8 + j))
+            assert len(drain(fe, sid, 8)) == 8
+            time.sleep(0.4)
+        _, row = stages_of(fe.stats())
+    doc = row["starved"]
+    assert 1 <= doc["gaps_total"] <= row["batches"] - 1
+    assert doc["idle_ms_total"] >= 400.0
+    assert doc["max_gap_ms"] >= 400.0
+    assert doc["hold_ms_total"] == 0.0          # an idle device takes a short batch at once
+    assert sum(doc[f"{s}_ms_total"] for s in M.STARVED_STATES) >= doc["max_gap_ms"]
+
+
+def test_starved_block_holds_the_hold(device_gate):
+    """A short batch held behind a device that reads busy (the gate) while
+    the collect thread has long seen its predecessor ready: the gap's
+    middle is ``hold``, from the stamp the dispatch thread kept."""
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, telemetry_sample_s=0.0))
+
+    def bucket_row():
+        return next(iter(fe.stats()["buckets"].values()))
+
+    with fe:
+        sid = fe.open_stream()
+        device_gate.busy = True
+        fe.submit(sid, frame_u8(0, 0))              # idle device: at once
+        device_gate.until(lambda: bucket_row()["batches"] == 1, "the first batch")
+        before = bucket_row()["hold"]["hold_ms_total"]
+        fe.submit(sid, frame_u8(0, 1))              # held
+        device_gate.until(
+            lambda: bucket_row()["hold"]["hold_ms_total"] > before + 60.0, "the hold")
+        device_gate.busy = False
+        device_gate.until(lambda: bucket_row()["batches"] == 2, "the held batch")
+        row = bucket_row()
+    doc = row["starved"]
+    assert doc["gaps_total"] == 1
+    assert doc["hold_ms_total"] > 60.0
+    assert doc["hold_ms_total"] == pytest.approx(row["hold"]["hold_ms_total"], abs=10.0)
+    assert doc["max_gap_ms"] == pytest.approx(
+        sum(doc[f"{s}_ms_total"] for s in M.STARVED_STATES), abs=0.01)
+
+
+def test_first_batch_after_a_recovery_opens_no_gap(monkeypatch):
+    """A supervised recovery starts a collect thread of its own: the batch
+    it takes first has no predecessor on that thread, so the time the
+    window was wedged is no gap of the dispatch thread's."""
+    seen = []
+    real = M.StarvedStats.note
+
+    def spy(self, last_ready, st):
+        seen.append((threading.get_ident(), last_ready))
+        real(self, last_ready, st)
+
+    monkeypatch.setattr(M.StarvedStats, "note", spy)
+    chaos = FaultPlan().add("freeze", at=(3,), delay_s=1.5)
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=1000, slo_ms=60_000.0, stall_timeout_s=0.35,
+        chaos=chaos, telemetry_sample_s=0.0))
+    with fe:
+        sid = fe.open_stream()
+        i = 0
+        deadline = time.time() + 20.0
+        while fe.recoveries < 1:
+            assert time.time() < deadline, "watchdog never tripped"
+            fe.submit(sid, frame_u8(0, i))
+            i += 1
+            time.sleep(0.01)
+        served = len(seen)
+        while len(seen) < served + 2:               # ... and on after it
+            assert time.time() < deadline, "nothing served after the recovery"
+            fe.submit(sid, frame_u8(0, i))
+            i += 1
+            time.sleep(0.01)
+    threads = list(dict.fromkeys(t for t, _ in seen))
+    assert len(threads) >= 2
+    for t in threads:
+        first, *rest = [ready for who, ready in seen if who == t]
+        assert first == 0.0 and all(r > 0.0 for r in rest)
+
+
+
+@pytest.fixture(scope="module")
+def two_reads():
+    """Bucket rows at two reads with a burst between them, as the
+    benchmark's window watch passes them to its readers."""
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, telemetry_sample_s=0.0))
+    reads = []
+    with fe:
+        sid = fe.open_stream()
+        for burst in range(2):
+            for j in range(24):
+                fe.submit(sid, frame_u8(0, burst * 24 + j))
+                if j % 4 == 3:
+                    time.sleep(0.01)        # the device runs dry between batches
+            assert len(drain(fe, sid, 24)) == 24
+            reads.append({"buckets": [r for r in fe.stats()["buckets"].values()
+                                      if r["batches"]]})
+    return reads
+
+
+@pytest.mark.parametrize("metric", [
+    "dispatch_thread_pct", "ingest_stage_ms", "ingest_put_ms", "step_dispatch_ms",
+    "prefetch_start_ms", "device_starved_pct"])
+def test_benchmark_readers_find_the_programs_blocks(two_reads, metric):
+    """The six readers of PR 40 (chipbench/layer_metrics) on the program's
+    own rows: every key they take is there, and the window's delta is the
+    rows' difference."""
+    from chipbench import spec
+
+    before, after = two_reads
+    logs = []
+    ctx = {"before": before, "after": after, "log": logs.append}
+    value = spec.load_module(f"layer_metrics/{metric}.py").read(ctx)
+    (b,), (a,) = before["buckets"], after["buckets"]
+    wall_ms = (a["stages"]["t"] - b["stages"]["t"]) * 1e3
+    n = a["ingest"]["batches"] - b["ingest"]["batches"]
+    assert n >= 6 and value is not None
+
+    def delta(block, key):
+        return a[block][key] - b[block][key]
+
+    want = {
+        "dispatch_thread_pct": 100.0 * sum(
+            a["stages"][c]["batch_ms_total"] - b["stages"][c]["batch_ms_total"]
+            if c == "prefetch" else
+            a["stages"]["components"][c]["batch_ms_total"]
+            - b["stages"]["components"][c]["batch_ms_total"]
+            for c in ("assemble_h2d", "prefetch")) / wall_ms,
+        "ingest_stage_ms": delta("ingest", "stage_ms_total") / n,
+        "ingest_put_ms": delta("ingest", "h2d_put_ms_total") / n,
+        "step_dispatch_ms": delta("ingest", "step_dispatch_ms_total") / n,
+        "prefetch_start_ms": delta("egress", "prefetch_ms_total") / (
+            a["stages"]["prefetch"]["batches"] - b["stages"]["prefetch"]["batches"]),
+        "device_starved_pct": 100.0 * sum(
+            delta("starved", f"{s}_ms_total") for s in M.STARVED_STATES) / wall_ms,
+    }[metric]
+    assert value == pytest.approx(want, rel=1e-9, abs=1e-9)
+    if metric == "device_starved_pct":
+        assert value > 0.0 and any("not traced" in line for line in logs)
+    if metric == "dispatch_thread_pct":
+        assert any("unattributed" in line and "= assemble_h2d" in line for line in logs)
 
 
 # ---------------------------------------------------------------------------
@@ -392,7 +741,7 @@ def traced_run():
     ("collect:device", 2, ("collect", "device")),
     ("collect:d2h", 2, ("collect", "d2h")),
     ("collect:route", 2, ("collect", "route")),
-    ("serve_dispatch", 0, ("dispatch", "assemble_h2d")),
+    ("dispatch:prefetch", 0, ("dispatch", "prefetch")),
     ("batch_complete", 1, None),
 ])
 def test_trace_lanes_carry_the_state_spans_on_the_wall_clock(
