@@ -183,11 +183,51 @@ def espcn_layer_costs(height: int, width: int, scale: int = 2,
                   dt, bf16, note="N=%d → %d/128 lanes" % (r2, r2)),
         LayerCost("depth_to_space", "upsample", height * scale,
                   width * scale, r2, 3, 0, 0.0,
-                  2.0 * height * width * r2 * 4,  # f32 in the current body
+                  2.0 * height * width * r2,      # uint8: XLA rounds before it
                   1.0, 1.0, 0.0,
-                  2.0 * height * width * r2 * 4 / (PEAK_HBM_GBPS * 1e9) * 1e3,
-                  note="pure reshape/transpose; f32 read+write"),
+                  2.0 * height * width * r2 / (PEAK_HBM_GBPS * 1e9) * 1e3,
+                  note="reshape/transpose of uint8; on a v5e layout copies at "
+                       "a tenth of this rate (PERF.md section 5)"),
     ]
+
+
+# One 128-wide row through one 128 x 128 weight tile is a pass; 197 TFLOP/s
+# is 6.0e9 of them a second.
+MXU_PASSES_PER_S = PEAK_BF16_TFLOPS * 1e12 / (2 * 128 * 128)
+
+
+def espcn_form_passes(height: int, width: int, phases=None, scale: int = 2,
+                      c1: int = 64, c2: int = 32) -> dict:
+    """MXU passes a frame of each ESPCN conv in a given form, structural
+    zeros included (what the MXU runs, not what the algorithm needs):
+    ``phases`` as ``models.espcn.stage_phases`` gives them, None = the
+    plain body. A conv reading phases ``fi`` and emitting ``fo`` has
+    M = H·W / (foh·fow) rows, K = taps_h·fih · taps_w·fiw · Cin with
+    taps = lo + hi + 1 of ``models.layers.zero_phase_taps``, N = foh·fow ·
+    Cout; passes = M · ceil(K/128) · ceil(N/128). At the cell's 16 x 540 x
+    960 the plain body is 8.3 + 41.5 + 24.9 M passes a batch (12.4 ms at
+    the MXU's limit), (2, 2) throughout 4.1 + 37.3 + 18.7 M (10.0 ms),
+    the served (1, 2) / (2, 2) / (2, 4) 4.1 + 24.9 + 12.4 M (6.9 ms);
+    the v5e reads map and head within 3% of that limit (PERF.md §5)."""
+    from dvf_tpu.models.layers import zero_phase_taps   # lazily: this module stays jax-free to import
+
+    phases = phases or {"feat": (1, 1), "map": (1, 1), "head": (1, 1)}
+
+    def extent(k, fi, fo):          # taps x phases read along one axis
+        return zero_phase_taps(k, fi, fo)[0].shape[0] * fi
+
+    out, fi = {}, (1, 1)
+    for name, k, cin, cout in (("feat", 5, 3, c1), ("map", 3, c1, c2),
+                               ("head", 3, c2, 3 * scale * scale)):
+        fo = phases[name]
+        rows = height * width // (fo[0] * fo[1])
+        k_dim = extent(k, fi[0], fo[0]) * extent(k, fi[1], fo[1]) * cin
+        n_dim = fo[0] * fo[1] * cout
+        passes = rows * math.ceil(k_dim / 128) * math.ceil(n_dim / 128)
+        out[name] = {"rows": rows, "k": k_dim, "n": n_dim, "passes": passes,
+                     "mxu_ms": passes / MXU_PASSES_PER_S * 1e3}
+        fi = fo
+    return out
 
 
 def summarize(layers: List[LayerCost], measured_ms: Optional[float] = None,

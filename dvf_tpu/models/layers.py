@@ -23,6 +23,11 @@ _DN = ("NHWC", "HWIO", "NHWC")
 Params = Dict[str, Any]
 
 
+def _pair(factor) -> Tuple[int, int]:
+    """A phase factor as (H, W): an int is that factor on both axes."""
+    return (factor, factor) if isinstance(factor, int) else tuple(factor)
+
+
 def conv_init(rng, ksize: int, cin: int, cout: int, dtype=jnp.float32) -> Params:
     """He-normal conv weight + zero bias."""
     wkey, _ = jax.random.split(rng)
@@ -143,21 +148,23 @@ def upsample_nearest(x: jnp.ndarray, factor: int = 2) -> jnp.ndarray:
     return x.reshape(b, h * factor, w * factor, c)
 
 
-def depth_to_space(x: jnp.ndarray, factor: int) -> jnp.ndarray:
+def depth_to_space(x: jnp.ndarray, factor) -> jnp.ndarray:
     """Subpixel rearrange (B, H, W, C·r²) → (B, H·r, W·r, C), DCR order:
-    ``y[b, h*r+i, w*r+j, c] = x[b, h, w, (i*r + j)*C + c]``.
+    ``y[b, h*r+i, w*r+j, c] = x[b, h, w, (i*r + j)*C + c]``. ``factor``:
+    ``r``, or an (H, W) pair (rh, rw) for a factor an axis.
 
     The ESPCN upscale head: the conv producing C·r² channels is a dense
-    MXU matmul; this rearrange is pure reshape/transpose — zero FLOPs, and
-    XLA folds it into the surrounding layout changes.
+    MXU matmul; this rearrange is pure reshape/transpose — zero FLOPs, but
+    not free: on a TPU it is layout copies (PERF.md §5).
     """
+    rh, rw = _pair(factor)
     b, h, w, crr = x.shape
-    c = crr // (factor * factor)
-    if c * factor * factor != crr:
-        raise ValueError(f"channels {crr} not divisible by r²={factor * factor}")
-    x = x.reshape(b, h, w, factor, factor, c)
+    c = crr // (rh * rw)
+    if c * rh * rw != crr:
+        raise ValueError(f"channels {crr} not divisible by r²={rh * rw}")
+    x = x.reshape(b, h, w, rh, rw, c)
     x = x.transpose(0, 1, 3, 2, 4, 5)  # b, h, i, w, j, c
-    return x.reshape(b, h * factor, w * factor, c)
+    return x.reshape(b, h * rh, w * rw, c)
 
 
 def gram_matrix(feats: jnp.ndarray) -> jnp.ndarray:
@@ -190,13 +197,15 @@ def gram_matrix(feats: jnp.ndarray) -> jnp.ndarray:
 #   activation is never materialized.
 
 
-def space_to_depth(x: jnp.ndarray, factor: int = 2) -> jnp.ndarray:
+def space_to_depth(x: jnp.ndarray, factor=2) -> jnp.ndarray:
     """(B, H, W, C) → (B, H/f, W/f, f²·C); inverse of depth_to_space
-    (phase-major channel order: out[..., (a*f + b)*C + c] = x[h*f+a, w*f+b, c])."""
+    (phase-major channel order: out[..., (a*f + b)*C + c] = x[h*f+a, w*f+b, c]).
+    ``factor``: ``f``, or an (H, W) pair for a factor an axis."""
+    fh, fw = _pair(factor)
     b, h, w, c = x.shape
-    x = x.reshape(b, h // factor, factor, w // factor, factor, c)
+    x = x.reshape(b, h // fh, fh, w // fw, fw, c)
     x = x.transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(b, h // factor, w // factor, factor * factor * c)
+    return x.reshape(b, h // fh, w // fw, fh * fw * c)
 
 
 def _s2d_kernel(w: jnp.ndarray) -> jnp.ndarray:
@@ -448,3 +457,104 @@ def instance_norm_phase(p: Params, x: jnp.ndarray, pivot=None,
     m1, m2 = _norm_stats(x, pivot)
     return _norm_apply(jnp.tile(p["scale"], 4), jnp.tile(p["bias"], 4), x,
                        pivot, per_channel(m1), per_channel(m2), eps)
+
+
+# ---------------------------------------------------------------------------
+# The phase domain under a zero-SAME border, a factor an axis (ESPCN)
+# ---------------------------------------------------------------------------
+#
+# A zero-SAME conv is exact between phase tensors with a plain ``jnp.pad``:
+# the full-resolution border is zeros, and what lies beyond it meets only
+# structurally zero taps. Here a phase tensor has a factor an axis,
+# ``space_to_depth(x, (fh, fw))``, and a conv may emit MORE phases than it
+# reads (``fo`` a multiple of ``fi`` on each axis) by striding ``fo // fi``
+# over its input: from a plain tensor (``fi = 1``) that is a (k+f−1)-tap
+# stride-f conv emitting f phases, 9/16 dense for k = 3, f = 2 where the
+# phase → phase kernel is 9/36. So each activation takes the factors that
+# fill its 128 lanes and no more, and no tensor goes back to plain.
+
+
+def zero_phase_taps(k: int, fi: int, fo: int):
+    """One axis of :func:`zero_phase_kernel`: ``(idy, lo, hi)`` with
+    ``idy[e, α, β]`` the tap ``dy`` of the k-tap kernel that output phase β
+    reads from input phase α of low-res position ``(fo // fi)·J + e − lo``
+    (output row ``fo·J + β`` reads input row ``fo·J + β + dy − r``), ``k``
+    where there is none: the zero tap. :func:`phase_kernel`'s table at
+    stride 1, with ``fo`` free of ``fi``."""
+    r = k // 2
+    lo = -((-r) // fi)
+    hi = (fo - 1 + k - 1 - r) // fi
+    idy = np.full((lo + hi + 1, fi, fo), k, dtype=np.int32)
+    for e in range(lo + hi + 1):
+        for a in range(fi):
+            for b in range(fo):
+                dy = fi * (e - lo) + a - b + r
+                if 0 <= dy < k:
+                    idy[e, a, b] = dy
+    return idy, lo, hi
+
+
+def zero_phase_kernel(w: jnp.ndarray, fi=1, fo=2):
+    """Re-index a (k, k, Cin, Cout) kernel of a zero-SAME stride-1 conv
+    into the kernel of the same conv from ``space_to_depth(x, fi)`` to
+    ``space_to_depth(y, fo)``; ``fi``, ``fo``: a factor or an (H, W) pair,
+    ``fo`` a multiple of ``fi`` on each axis. Returns ``(kernel, pads,
+    strides)``: the (klh, klw, fih·fiw·Cin, foh·fow·Cout) kernel of a VALID
+    conv with window strides ``fo // fi``, and the ``(lo, hi)`` low-res
+    rows / columns of zeros its input needs. At ``fi = 1`` output phase
+    (β, β′) holds ``w`` at rows β…β+k−1, columns β′…β′+k−1 of a (k+f−1)²
+    kernel. One static gather, as :func:`_s2d_kernel`."""
+    fi, fo = _pair(fi), _pair(fo)
+    if fo[0] % fi[0] or fo[1] % fi[1]:
+        raise ValueError(f"phase factors {fi} -> {fo}: a conv emits a multiple of what it reads")
+    k = w.shape[0]
+    (ih, loh, hih), (iw, low, hiw) = (zero_phase_taps(k, a, b) for a, b in zip(fi, fo))
+    wpad = jnp.pad(w, ((0, 1), (0, 1), (0, 0), (0, 0)))
+    g = wpad[ih[:, :, :, None, None, None], iw[None, None, None, :, :, :]]
+    # g[e, α, β, e', α', β', ci, co] → (e, e', α, α', ci, β, β', co)
+    g = g.transpose(0, 3, 1, 4, 6, 2, 5, 7)
+    cin, cout = w.shape[2], w.shape[3]
+    kern = g.reshape(ih.shape[0], iw.shape[0], fi[0] * fi[1] * cin, fo[0] * fo[1] * cout)
+    sh, sw = fo[0] // fi[0], fo[1] // fi[1]
+    # The last window starts a stride short of the end: it stops that far short of ``hi``.
+    return kern, ((loh, hih - (sh - 1)), (low, hiw - (sw - 1))), (sh, sw)
+
+
+def conv2d_zero_phase(
+    p: Params,
+    x: jnp.ndarray,
+    fi=1,
+    fo=2,
+    cols=None,
+    compute_dtype=jnp.bfloat16,
+) -> jnp.ndarray:
+    """Zero-SAME k×k stride-1 conv (without bias) of the full-resolution
+    tensor whose ``space_to_depth(·, fi)`` image is ``x`` (``fi = 1``: the
+    tensor itself), as ONE conv that emits the result's phases: returns
+    ``space_to_depth(conv2d_nb(p, X), fo)``. ``cols``: a static permutation
+    of the emitted columns (:func:`subpixel_order`), applied to the kernel.
+    On a v5e the stride costs nothing (PERF.md §6, PRs 28 and 41)."""
+    kern, (pad_h, pad_w), strides = zero_phase_kernel(p["w"], fi, fo)
+    if cols is not None:
+        kern = kern[..., cols]
+    xp = jnp.pad(x, ((0, 0), pad_h, pad_w, (0, 0)))
+    return lax.conv_general_dilated(
+        xp.astype(compute_dtype), kern.astype(compute_dtype),
+        window_strides=strides, padding="VALID", dimension_numbers=_DN,
+    )
+
+
+def subpixel_order(factor, scale: int, c: int) -> np.ndarray:
+    """The order of a sub-pixel head's emitted columns that makes shuffle
+    and un-phasing ONE rearrangement. The head's ``scale²·c`` channels are
+    (i, j, c) of the ×``scale`` shuffle; emitted at phase factors (fh, fw)
+    the columns are (β, β′, i, j, c) and the frame's row is ``fh·scale·J +
+    scale·β + i``. With ``perm`` = this function's result,
+    ``depth_to_space(y[..., perm], (fh·scale, fw·scale)) ==
+    depth_to_space(depth_to_space(y, (fh, fw)), scale)``: columns in
+    (β, i, β′, j, c) order. Static (numpy): index the kernel's and the
+    tiled bias's columns with it."""
+    fh, fw = _pair(factor)
+    idx = np.arange(fh * fw * scale * scale * c)
+    idx = idx.reshape(fh, fw, scale, scale, c)            # (β, β', i, j, c)
+    return idx.transpose(0, 2, 1, 3, 4).reshape(-1)       # (β, i, β', j, c)

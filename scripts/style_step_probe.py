@@ -3,7 +3,8 @@
 
 ROADMAP S2's op map (PR 28), for the style net by default. ``--model espcn`` reads the upscaling service's step the
 same way (``super_resolution(scale=2)`` at 16 x 540 x 960 in, 1080 x 1920 out; the scopes of ``models/espcn.py``:
-``feat``, ``map``, ``head``, ``shuffle``; ``--fast-convs`` probes its space-to-depth form). Compiles the step program
+``feat``, ``map``, ``head``, ``shuffle``, each in the form ``models/espcn.py::stage_forms`` gives for the shape;
+``--fast-convs`` probes the per-layer space-to-depth round trip). Compiles the step program
 of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
 weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
@@ -39,8 +40,14 @@ def _style_stages(kwargs, shape):
 
 
 def _espcn_stages(kwargs, shape):
-    form = "s2d" if kwargs.get("fast_convs") else "plain"
-    return {"feat": form, "map": form, "head": form, "shuffle": "plain"}
+    from dvf_tpu.models.espcn import EspcnConfig, stage_forms, stage_phases
+
+    if kwargs.get("fast_convs"):        # to phases and back around every conv: no stage carries a form
+        return {"feat": "s2d", "map": "s2d", "head": "s2d", "shuffle": "plain"}
+    config = EspcnConfig(scale=kwargs["scale"])
+    phases = stage_phases(config, shape)
+    return {stage: form if form == "plain" else "phase %dx%d" % phases.get(stage, phases["head"])
+            for stage, form in stage_forms(config, shape).items()}
 
 
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))

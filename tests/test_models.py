@@ -760,3 +760,248 @@ def test_espcn_tp_shard_map_forward_with_fast_convs():
         check_vma=False,
     ))(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
+
+
+# -- ESPCN in the carried phase form (zero-SAME phase convs) ------------------
+
+def _plain_espcn_forward(params, x, cfg):
+    """The plain composition the carried form re-indexes: three zero-SAME
+    convs at the input's resolution, then the sub-pixel shuffle."""
+    from dvf_tpu.models.layers import conv2d_nb, depth_to_space
+
+    cd = cfg.compute_dtype
+    h = x.astype(cd)
+    for name, relu in (("feat", True), ("map", True), ("head", False)):
+        h = conv2d_nb(params[name], h, compute_dtype=cd) + params[name]["b"].astype(cd)
+        h = jax.nn.relu(h) if relu else h
+    y = depth_to_space(h.astype(jnp.float32), cfg.scale)
+    return jnp.clip(y, 0.0, 1.0).astype(x.dtype)
+
+
+def _random_espcn_params(cfg, seed=0):
+    """Random biases too: a bias tiled over the wrong phases must show."""
+    from dvf_tpu.models.espcn import init_espcn
+
+    params = init_espcn(jax.random.PRNGKey(seed), cfg)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 3))
+    return {k: {"w": v["w"], "b": 0.1 * jax.random.normal(next(keys), v["b"].shape)}
+            for k, v in params.items()}
+
+
+@pytest.mark.parametrize("k,fi,fo", [
+    (3, 1, 2), (5, 1, 2), (3, 1, 4), (5, 1, 4),               # plain → phase: the strided emission
+    (3, 2, 2), (5, 2, 2),                                     # phase → phase
+    (5, 1, (1, 2)), (3, (1, 2), (2, 2)), (3, (2, 2), (2, 4)),  # the cell's three convs
+    (3, (1, 2), (1, 4)), (3, (2, 1), (4, 2)), (3, 2, 4), (3, 1, 1),
+])
+def test_conv2d_zero_phase_is_the_plain_conv_seen_through_space_to_depth(k, fi, fo):
+    from dvf_tpu.models.layers import (_pair, conv2d_nb, conv2d_zero_phase, conv_init,
+                                       space_to_depth, zero_phase_kernel)
+
+    p = conv_init(jax.random.PRNGKey(k), k, 5, 7)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16, 5))
+    want = space_to_depth(conv2d_nb(p, x, compute_dtype=jnp.float32), fo)
+    got = conv2d_zero_phase(p, space_to_depth(x, fi), fi, fo, compute_dtype=jnp.float32)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    kern, pads, strides = zero_phase_kernel(p["w"], fi, fo)
+    (fih, fiw), (foh, fow) = _pair(fi), _pair(fo)
+    assert strides == (foh // fih, fow // fiw)
+    assert kern.shape[2:] == (fih * fiw * 5, foh * fow * 7)
+    if fi == 1:        # a (k+f-1)-tap kernel, phase (β, β') holding w at rows β.., columns β'..
+        assert kern.shape[:2] == (k + foh - 1, k + fow - 1) and pads == ((k // 2,) * 2,) * 2
+        block = kern.reshape(*kern.shape[:3], foh, fow, 7)
+        np.testing.assert_array_equal(
+            np.asarray(block[foh - 1:foh - 1 + k, fow - 1:fow - 1 + k, :, -1, -1]), np.asarray(p["w"]))
+
+
+def test_zero_phase_kernel_refuses_a_tensor_going_back():
+    from dvf_tpu.models.layers import conv_init, zero_phase_kernel
+
+    w = conv_init(jax.random.PRNGKey(0), 3, 4, 4)["w"]
+    for fi, fo in ((2, 1), ((2, 2), (1, 2)), (4, 2), ((1, 4), (2, 2))):
+        with pytest.raises(ValueError, match="multiple"):
+            zero_phase_kernel(w, fi, fo)
+
+
+@pytest.mark.parametrize("factor", [(2, 3), (4, 8), 2])
+def test_space_to_depth_and_back_with_a_factor_an_axis(factor):
+    from dvf_tpu.models.layers import _pair, depth_to_space, space_to_depth
+
+    fh, fw = _pair(factor)
+    x = jnp.arange(2 * 8 * 24 * 3, dtype=jnp.float32).reshape(2, 8, 24, 3)
+    y = space_to_depth(x, factor)
+    assert y.shape == (2, 8 // fh, 24 // fw, fh * fw * 3)
+    assert float(y[1, 1, 2, (1 * fw + 1) * 3 + 2]) == float(x[1, fh + 1, 2 * fw + 1, 2])
+    np.testing.assert_array_equal(np.asarray(depth_to_space(y, factor)), np.asarray(x))
+
+
+@pytest.mark.parametrize("scale", [2, 3])
+@pytest.mark.parametrize("fi,fo", [(1, 2), (2, 2), ((2, 2), (2, 4)), (1, (2, 4))])
+def test_head_in_subpixel_order_leaves_one_depth_to_space(scale, fi, fo):
+    """head's columns permuted on the kernel + ONE depth_to_space equal the
+    shuffle (DCR order) of the plain head."""
+    from dvf_tpu.models.layers import (_pair, conv2d_nb, conv2d_zero_phase, conv_init,
+                                       depth_to_space, space_to_depth, subpixel_order)
+
+    p = conv_init(jax.random.PRNGKey(0), 3, 8, 3 * scale * scale)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 12, 8))
+    want = depth_to_space(conv2d_nb(p, x, compute_dtype=jnp.float32), scale)
+    foh, fow = _pair(fo)
+    cols = subpixel_order(fo, scale, 3)
+    assert sorted(cols) == list(range(foh * fow * 3 * scale * scale))
+    xi = space_to_depth(x, fi)
+    y = conv2d_zero_phase(p, xi, fi, fo, cols, compute_dtype=jnp.float32)
+    one = (foh * scale, fow * scale)
+    np.testing.assert_allclose(np.asarray(depth_to_space(y, one)), np.asarray(want), atol=1e-5)
+    # the unpermuted columns under the same rearrangement are another picture
+    y0 = conv2d_zero_phase(p, xi, fi, fo, compute_dtype=jnp.float32)
+    assert float(jnp.abs(depth_to_space(y0, one) - want).max()) > 0.1
+
+
+def test_espcn_stage_phases_is_a_function_of_the_shapes():
+    from dvf_tpu.models.espcn import EspcnConfig, stage_forms, stage_phases
+
+    cfg = EspcnConfig()
+    cell = {"feat": (1, 2), "map": (2, 2), "head": (2, 4)}
+    plain = dict.fromkeys(cell, (1, 1))
+    assert stage_phases(cfg, (16, 540, 960, 3)) == cell
+    assert stage_forms(cfg, (16, 540, 960, 3)) == {
+        "feat": "phase", "map": "phase", "head": "phase", "shuffle": "phase"}
+    # even H and W is all it needs; head's fourth column phase wants W a multiple of 4
+    assert stage_phases(cfg, (1, 2, 2, 3)) == {**cell, "head": (2, 2)}
+    assert stage_phases(cfg, (4, 10, 6, 3)) == {**cell, "head": (2, 2)}
+    assert stage_phases(cfg, (4, 6, 12, 3)) == cell
+    for odd in ((16, 541, 960, 3), (16, 540, 961, 3)):
+        assert stage_phases(cfg, odd) == plain
+        assert set(stage_forms(cfg, odd).values()) == {"plain"}
+    assert stage_phases(EspcnConfig(fast_convs=True), (16, 540, 960, 3)) == plain
+    # a conv emits a multiple of what it reads: a stage is capped by its reader
+    assert stage_phases(EspcnConfig(c2=128), (2, 8, 8, 3)) == {**plain, "head": (2, 4)}
+    assert stage_phases(EspcnConfig(c1=128), (2, 8, 8, 3)) == {**cell, "feat": (1, 1)}
+    assert stage_phases(EspcnConfig(c1=16), (2, 8, 8, 3)) == {**cell, "feat": (2, 2)}
+    assert stage_phases(EspcnConfig(scale=4), (2, 8, 8, 3)) == dict.fromkeys(cell, (1, 2))
+    assert stage_phases(EspcnConfig(scale=7), (2, 8, 8, 3)) == plain    # 147 columns
+
+
+@pytest.mark.parametrize("shape,cfg_kw,form", [
+    ((2, 16, 24, 3), {}, "phase"),
+    ((1, 2, 2, 3), {}, "phase"),              # the smallest geometry admitted
+    ((1, 10, 6, 3), {}, "phase"),             # a batch of one; W no multiple of 4: head at (2, 2)
+    ((2, 8, 12, 3), {"scale": 3}, "phase"),
+    ((2, 8, 12, 3), {"scale": 4}, "phase"),   # 48 sub-pixel channels: (1, 2) throughout
+    ((2, 8, 12, 3), {"c1": 16}, "phase"),
+    ((2, 8, 12, 3), {"c1": 128}, "mixed"),    # plain feat → emitted map
+    ((2, 8, 12, 3), {"c2": 128}, "mixed"),    # plain feat, map → emitted head
+    ((2, 15, 24, 3), {}, "plain"),
+    ((2, 16, 23, 3), {}, "plain"),
+], ids=["even", "smallest", "batch1", "scale3", "scale4", "narrow_feat", "wide_feat", "wide_map",
+        "odd_h", "odd_w"])
+def test_espcn_carried_form_matches_plain_body(shape, cfg_kw, form):
+    from dvf_tpu.models.espcn import EspcnConfig, apply_espcn, stage_forms
+
+    cfg = EspcnConfig(compute_dtype=jnp.float32, **cfg_kw)
+    forms = set(stage_forms(cfg, shape).values())
+    assert forms == ({"plain", "phase"} if form == "mixed" else {form})
+    params = _random_espcn_params(cfg)
+    x = jax.random.uniform(jax.random.PRNGKey(7), shape)
+    got = apply_espcn(params, x, cfg)
+    want = _plain_espcn_forward(params, x, cfg)
+    assert got.shape == want.shape == (shape[0], shape[1] * cfg.scale, shape[2] * cfg.scale, 3)
+    if form == "plain":        # an unadmitted geometry runs the plain body as it was
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        assert float(jnp.abs(want - 0.5).mean()) > 0.05       # not all clipped away
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_espcn_tp_shard_map_forward_in_the_carried_form():
+    """feat's Cout shard is sliced inside each emitted phase, map's psum
+    runs on the phase tensor's pre-bias partial sums and its bias is tiled
+    after it: the explicit shard_map forward equals the replicated one and
+    the plain composition."""
+    from dvf_tpu.models.espcn import (
+        EspcnConfig, apply_espcn, param_pspecs as e_pspecs, stage_forms,
+        tp_inner_apply as e_tp)
+
+    cfg = EspcnConfig(compute_dtype=jnp.float32)
+    params = _random_espcn_params(cfg)
+    x = jax.random.uniform(jax.random.PRNGKey(1), (2, 16, 24, 3))
+    assert set(stage_forms(cfg, x.shape).values()) == {"phase"}
+    mesh = make_mesh(MeshConfig(model=2))
+    inner = e_tp(cfg)
+    got = jax.jit(jax.shard_map(
+        lambda p, b: inner(p, b), mesh=mesh, in_specs=(e_pspecs(cfg), P()),
+        out_specs=P(), check_vma=False))(params, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(apply_espcn(params, x, cfg)),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_plain_espcn_forward(params, x, cfg)), atol=1e-5)
+
+
+def test_espcn_gradient_through_the_carried_form():
+    """train/sr.py differentiates through apply_espcn: the re-indexings are
+    gathers on the weights, and the gradient is the plain body's."""
+    from dvf_tpu.models.espcn import EspcnConfig, apply_espcn
+
+    cfg = EspcnConfig(compute_dtype=jnp.float32)
+    params = _random_espcn_params(cfg)
+    x = jax.random.uniform(jax.random.PRNGKey(3), (2, 12, 16, 3))
+    target = jax.random.uniform(jax.random.PRNGKey(4), (2, 24, 32, 3))
+
+    def loss(fn):
+        return lambda p: jnp.mean((fn(p, x, cfg) - target) ** 2)
+
+    g_carried = jax.grad(loss(apply_espcn))(params)
+    g_plain = jax.grad(loss(_plain_espcn_forward))(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(g_carried):
+        want = g_plain
+        for key in path:
+            want = want[key.key]
+        assert float(jnp.abs(want).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want), atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_espcn_540p_jaxpr_holds_no_lane_starved_tensor():
+    """Structure at the cell's shape, no compile: no (16, 540, 960, c)
+    activation with 3 < c < 128 exists (the frame's own 3 channels apart);
+    the three convs make the phase images (16, 540, 480, 128), (16, 270,
+    480, 128) and (16, 270, 240, 96), and the one rearrangement is
+    depth_to_space((4, 8))'s transpose."""
+    from dvf_tpu.models.espcn import EspcnConfig, apply_espcn, init_espcn
+
+    cfg = EspcnConfig()
+    shape = (16, 540, 960, 3)
+    params = jax.eval_shape(lambda: init_espcn(jax.random.PRNGKey(0), cfg))
+    jaxpr = jax.make_jaxpr(lambda p, x: apply_espcn(p, x, cfg))(
+        params, jax.ShapeDtypeStruct(shape, jnp.float32))
+    made = [(eqn.primitive.name, tuple(v.aval.shape))
+            for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars]
+    starved = [s for _, s in made if len(s) == 4 and s[:3] == shape[:3] and 3 < s[3] < 128]
+    assert not starved, starved
+    assert [s for name, s in made if name == "conv_general_dilated"] == [
+        (16, 540, 480, 128), (16, 270, 480, 128), (16, 270, 240, 96)]
+    assert [s for name, s in made if name == "transpose" and s[0] == 16] == [
+        (16, 270, 4, 240, 8, 3)]
+
+
+@pytest.mark.parametrize("phases,want_m", [
+    (None, (8.29, 41.47, 24.88)),
+    ({"feat": (2, 2), "map": (2, 2), "head": (2, 2)}, (4.15, 37.32, 18.66)),
+    ("served", (4.15, 24.88, 12.44)),
+], ids=["plain", "2x2", "served"])
+def test_espcn_form_passes_counts_what_the_mxu_runs(phases, want_m):
+    """The pass arithmetic PERF.md's candidate table rests on: M rows x
+    K tiles x N tiles, structural zeros included, for a batch of 16."""
+    from dvf_tpu.models.analysis import espcn_form_passes
+    from dvf_tpu.models.espcn import EspcnConfig, stage_phases
+
+    if phases == "served":
+        phases = stage_phases(EspcnConfig(), (16, 540, 960, 3))
+    got = espcn_form_passes(540, 960, phases)
+    for name, want in zip(("feat", "map", "head"), want_m):
+        assert round(16 * got[name]["passes"] / 1e6, 2) == want, (name, got[name])
+    if phases is not None and phases["map"] == (2, 2) and phases["feat"] == (1, 2):
+        assert (got["map"]["k"], got["map"]["n"]) == (1536, 128)
+        assert (got["head"]["k"], got["head"]["n"]) == (1536, 96)

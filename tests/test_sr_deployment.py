@@ -2,7 +2,9 @@
 serve path: ``ServeFrontend`` → ``DeviceLane`` → ``Engine`` →
 ``egress_pack`` → router, with a result that is NOT its input's geometry.
 
-Toy size on the CPU (32×48 in, 64×96 out, batch 4), seeded random weights
+Toy size on the CPU (32×48 in, 64×96 out, batch 4: an even geometry, so the
+served forward is the carried phase form of ``models/espcn.py::stage_forms``,
+as at the cell's 540×960), seeded random weights
 from the benchmark's plain reference (``chipbench/refs/sr2x_540p.py``,
 loaded by path as test_session_state.py loads flow's: it imports nothing
 of the program). What is held:
@@ -214,7 +216,12 @@ def test_a_transposed_shuffle_reads_not_correct(ref, config, monkeypatch):
     from dvf_tpu.models import espcn
 
     def transposed(x, factor):
-        return ref.shuffle(x, factor, order="ji")
+        # J for I within each block of sub-pixels, at the carried form's one rearrangement
+        # (a factor an axis) as at the plain body's: the columns read (j, i, c) for (i, j, c).
+        b, h, w, n = x.shape
+        fh, fw = factor
+        x = x.reshape(b, h, w, fw, fh, n // (fh * fw)).swapaxes(3, 4)
+        return x.transpose(0, 1, 3, 2, 4, 5).reshape(b, h * fh, w * fw, -1)
 
     monkeypatch.setattr(espcn, "depth_to_space", transposed)
     n = _served_numbers(ref, config, 6, "bfloat16")
@@ -374,17 +381,22 @@ def _scoped_primitives(jaxpr, outer, found):
                 _scoped_primitives(inner, scope, found)
 
 
-@pytest.mark.parametrize("fast", [False, True], ids=["ref", "fast_convs"])
-def test_stages_carry_named_scopes(fast):
+@pytest.mark.parametrize("fast,h", [(False, H), (True, H), (False, H + 1)],
+                         ids=["carried", "fast_convs", "plain"])
+def test_stages_carry_named_scopes(fast, h):
     """Every convolution sits under its stage's scope and the rearrangement
     under ``shuffle``: what the HLO's ``op_name`` then says of each fusion
-    (scripts/style_step_probe.py --model espcn sums the step by them)."""
-    from dvf_tpu.models.espcn import EspcnConfig, apply_espcn, init_espcn
+    (scripts/style_step_probe.py --model espcn sums the step by them). In
+    the carried form (an even geometry) as in the plain body (an odd one),
+    every op of the step but the cast of the batch is under a stage."""
+    from dvf_tpu.models.espcn import EspcnConfig, apply_espcn, init_espcn, stage_forms
 
     cfg = EspcnConfig(scale=SCALE, fast_convs=fast)
+    want_form = "phase" if (not fast and h % 2 == 0) else "plain"
+    assert set(stage_forms(cfg, (2, h, W, 3)).values()) == {want_form}
     params = init_espcn(jax.random.PRNGKey(0), cfg)
     jaxpr = jax.make_jaxpr(lambda p, b: apply_espcn(p, b, cfg))(
-        params, jax.ShapeDtypeStruct((2, H, W, 3), jnp.float32))
+        params, jax.ShapeDtypeStruct((2, h, W, 3), jnp.float32))
     found = []
     _scoped_primitives(jaxpr.jaxpr, "", found)
     stages = ("feat", "map", "head", "shuffle")
@@ -395,3 +407,10 @@ def test_stages_carry_named_scopes(fast):
     under_shuffle = {prim for scope, prim in found if "shuffle" in scope.split("/")}
     assert "transpose" in under_shuffle, under_shuffle
     assert under_shuffle & {"clamp", "max", "min"}, under_shuffle          # the clip to [0, 1]
+    outside = [prim for scope, prim in found if not set(stages) & set(scope.split("/"))]
+    assert outside == ["convert_element_type"], outside         # batch.astype(compute_dtype)
+    if want_form == "phase":
+        # the kernel re-indexings (gathers on the weights) belong to their stage too
+        gathers = [next(st for st in stages if st in scope.split("/"))
+                   for scope, prim in found if prim == "gather"]
+        assert {"feat", "map", "head"} <= set(gathers), gathers

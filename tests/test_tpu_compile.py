@@ -58,3 +58,48 @@ def test_trunk_block_norm_sums_ride_in_their_convs_fusion(one_chip):
     entry = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = ", text[text.index("\nENTRY"):], re.M)
     parts = [table[name][2] for name in entry if table[name][2]]
     assert sorted(parts) == ["conv+norm_stats"] * 2 + ["norm_apply"] * 2, parts
+
+
+def test_espcn_step_at_the_cells_shape_carries_its_activations(one_chip):
+    """The upscaling step as the Engine builds it (uint8 in, uint8 out) at
+    16 x 540 x 960, for the described v5e: three conv fusions whose results
+    are the phase images (16, 540, 480, 128), (16, 270, 480, 128) and
+    (16, 270, 240, 96) — head's already uint8, the rounding inside the
+    conv's own fusion — no tensor of the plain form's (16, 540, 960, c > 3),
+    and no float tensor after head, so that the rearrangement moves bytes
+    (PERF.md §5: what PR 41's 34.6 -> 14.9 ms rest on)."""
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    filt = get_filter("super_resolution", scale=2, fast_convs=False, dtype="bfloat16")
+    shape = (16, 540, 960, 3)
+
+    def step(batch, state):
+        y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
+        return to_uint8(y), new_state
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: filt.init_state(shape, jnp.float32)))
+    batch = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(batch, state).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    made = []           # (dtype, dims, op, line) of the entry computation's instructions
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* (\w+)\(", line)
+        if m:
+            made.append(m.groups() + (line,))
+    convs = [dims for _, dims, op, line in made
+             if op == "fusion" and "conv_general_dilated" in line and dims.startswith("16,")]
+    assert convs == ["16,540,480,128", "16,270,480,128", "16,270,240,96"], convs
+    assert [dtype for dtype, dims, _, _ in made if dims == "16,270,240,96"][0] == "u8"
+    plain_form = [dims for _, dims, _, _ in made
+                  if dims.startswith("16,540,960,") and dims != "16,540,960,3"]
+    assert not plain_form, plain_form
+    big_floats = [(dtype, dims) for dtype, dims, _, _ in made
+                  if dtype in ("f32", "bf16") and dims.startswith(("16,1080,", "16,270,4,", "16,270,240,"))]
+    assert not big_floats, big_floats
