@@ -114,6 +114,13 @@ class Filter:
     # the mesh has a model axis). None = keep the generic body.
     specialize: Optional[Callable[[Any, Tuple[int, ...]], Optional["Filter"]]] = None
     rows: Optional[Callable[[jnp.ndarray, Any, Any], Tuple[jnp.ndarray, Any]]] = None
+    # kernel_plan(batch_shape) -> dict, for a filter whose body is a kernel
+    # of the repo's own: the kernel's name as a trace lists it and the
+    # tiling it resolves to for that NHWC shape (ops/pallas_kernels.py
+    # ``sobel_bilateral_plan``). The Engine resolves it once per compile
+    # (``Engine.kernel_plan``) and the serve path's bucket row passes it on
+    # as its ``kernel`` block. None: no such kernel (XLA's own ops only).
+    kernel_plan: Optional[Callable[[Tuple[int, ...]], dict]] = None
 
     @property
     def stateful(self) -> bool:

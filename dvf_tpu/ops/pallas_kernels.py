@@ -125,18 +125,31 @@ def _resolve_tile_h(h: int, tile_h: Optional[int],
     return _pick_tile_h(h, target)
 
 
-def _pinned_tile_params(tile_h: Optional[int], interpret: bool):
-    """Compiler params for a stencil kernel whose caller pinned
-    ``tile_h`` (the run_table tile sweeps). Mosaic's default scoped-VMEM
-    limit is 16 MiB and the unrolled taps' temporaries grow with the
-    tile: bilateral at tile 40 over 1080p needs 16.55 MB on the v5e
-    (RESOURCE_EXHAUSTED in PR 21's chip run), so a pinned tile gets the
-    warp kernel's 64 MiB (the chip has 128 MiB of VMEM). The auto-picked
-    tile compiles under the default and keeps it — nothing the server
-    runs pins a tile."""
-    if interpret or tile_h is None:
+_VMEM_LIMIT_RAISED = 64 * 1024 * 1024
+_TAPS_UNDER_DEFAULT_VMEM = 25   # a 5x5 window: the largest served before PR 43
+
+
+def _stencil_vmem_limit(tile_h: Optional[int], interpret: bool,
+                        taps: int) -> Optional[int]:
+    """Scoped-VMEM limit for a stencil kernel, None = Mosaic's default
+    (16 MiB). The unrolled taps' temporaries grow with the tile and with
+    the window: bilateral at a pinned tile 40 over 1080p needs 16.55 MB on
+    the v5e (RESOURCE_EXHAUSTED in PR 21's chip run), and the fused
+    Sobel+bilateral at d = 9 (81 taps) needs 26.33 MB at the AUTO tile of
+    24 rows (Mosaic for a described v5e, PR 43: the unpinned kernel did
+    not compile). So a caller-pinned ``tile_h`` (the run_table tile
+    sweeps) or a window over 5x5 taps gets the warp kernel's 64 MiB (the
+    chip has 128 MiB of VMEM); the auto-picked tile at up to 25 taps
+    compiles under the default and keeps it."""
+    if interpret or (tile_h is None and taps <= _TAPS_UNDER_DEFAULT_VMEM):
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+    return _VMEM_LIMIT_RAISED
+
+
+def _vmem_params(limit: Optional[int]):
+    """Compiler params carrying a :func:`_stencil_vmem_limit`."""
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def _pad_rows(x: jnp.ndarray, extra: int) -> jnp.ndarray:
@@ -232,8 +245,10 @@ def bilateral_nhwc_pallas(
             pltpu.VMEM((c, _slab_rows(th, 2 * r), w_al), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_pinned_tile_params(tile_h, interpret),
+        compiler_params=_vmem_params(
+            _stencil_vmem_limit(tile_h, interpret, d * d)),
         interpret=interpret,
+        name="bilateral",
     )(x)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
 
@@ -412,8 +427,10 @@ def sep_blur_nhwc_pallas(
             pltpu.VMEM((c, _slab_rows(th, 2 * rh), w_al), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_pinned_tile_params(tile_h, interpret),
+        compiler_params=_vmem_params(_stencil_vmem_limit(
+            tile_h, interpret, len(kh_taps) + len(kw_taps))),
         interpret=interpret,
+        name="sep_blur",
     )(x)
     return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
 
@@ -499,6 +516,36 @@ def _sobel_bilateral_kernel(tile_h: int, r: int, w: int, c: int,
     return kernel
 
 
+def sobel_bilateral_plan(shape, d: int = 5, tile_h: Optional[int] = None,
+                         interpret: bool = False) -> dict:
+    """The tiling :func:`sobel_bilateral_nhwc_pallas` resolves to for an
+    NHWC batch of ``shape``, as data. The kernel's wrapper takes its own
+    numbers from this dict, so what a compiled step states about its
+    kernel (``Filter.kernel_plan`` → ``Engine.kernel_plan`` → the bucket
+    row's ``kernel`` block) is what ran, not a second copy of the
+    arithmetic."""
+    if d % 2 != 1:
+        raise ValueError(f"window d must be odd, got {d}")
+    b, h, w, c = (int(v) for v in shape)
+    R = d // 2 + 1  # bilateral halo + 1 row/col of Sobel support
+    th, h_pad = _resolve_tile_h(h, tile_h, compiled=not interpret)
+    slab, w_al = _slab_rows(th, 2 * R), _round_up(w + 2 * R, _LANE)
+    return {
+        "kernel": "sobel_bilateral",   # the pallas_call's name in a trace
+        "impl": "pallas",
+        "taps": d * d,
+        "tile_h": th,
+        "h_pad": h_pad,
+        "grid": [b, h_pad // th],
+        "slab_rows": slab,             # rows DMA'd a grid step (tile + halo, 8-aligned)
+        "w_aligned": w_al,             # columns DMA'd (W + halo, 128-aligned)
+        "vmem_scratch_bytes": c * slab * w_al * 4,
+        # None: Mosaic's default scoped-VMEM limit (16 MiB)
+        "vmem_limit_bytes": _stencil_vmem_limit(tile_h, interpret, d * d),
+        "compute_dtype": "float32",
+    }
+
+
 def sobel_bilateral_nhwc_pallas(
     batch: jnp.ndarray,
     d: int = 5,
@@ -509,36 +556,41 @@ def sobel_bilateral_nhwc_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused Sobel→bilateral over float NHWC in [0,1]; numerics match
-    FilterChain(sobel, bilateral) — ops.chains.sobel_bilateral."""
-    if d % 2 != 1:
-        raise ValueError(f"window d must be odd, got {d}")
+    FilterChain(sobel, bilateral) — ops.chains.sobel_bilateral. The three
+    parts carry ``jax.named_scope``s (``stencil_prep`` / ``stencil_kernel``
+    / ``stencil_finish``) so a compiled step's ``op_name``s say which part
+    an op belongs to (scripts/style_step_probe.py --model stencil)."""
+    plan = sobel_bilateral_plan(batch.shape, d, tile_h, interpret)
     r = d // 2
     R = r + 1
     b, h, w, c = batch.shape
-    th, h_pad = _resolve_tile_h(h, tile_h, compiled=not interpret)
-    w_al = _round_up(w + 2 * R, _LANE)
+    th, h_pad, w_al = plan["tile_h"], plan["h_pad"], plan["w_aligned"]
 
-    x = jnp.transpose(batch, (0, 3, 1, 2))  # NCHW: W on lanes
-    x = jnp.pad(x, ((0, 0), (0, 0), (R, R), (R, R)), mode="reflect")
-    x = _pad_rows(x, _extra_rows(h, h_pad, th, 2 * R))
-    x = _pad_cols(x, w_al - (w + 2 * R))
+    with jax.named_scope("stencil_prep"):
+        x = jnp.transpose(batch, (0, 3, 1, 2))  # NCHW: W on lanes
+        x = jnp.pad(x, ((0, 0), (0, 0), (R, R), (R, R)), mode="reflect")
+        x = _pad_rows(x, _extra_rows(h, h_pad, th, 2 * R))
+        x = _pad_cols(x, w_al - (w + 2 * R))
 
     kernel = _sobel_bilateral_kernel(th, r, w, c, sigma_color, sigma_space,
                                      magnitude_scale)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, h_pad // th),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, c, th, w), lambda bb, ii: (bb, 0, ii, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c, h_pad, w), batch.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((c, _slab_rows(th, 2 * R), w_al), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=_pinned_tile_params(tile_h, interpret),
-        interpret=interpret,
-    )(x)
-    return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
+    with jax.named_scope("stencil_kernel"):
+        out = pl.pallas_call(
+            kernel,
+            grid=tuple(plan["grid"]),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, c, th, w), lambda bb, ii: (bb, 0, ii, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, c, h_pad, w), batch.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((c, plan["slab_rows"], w_al), jnp.float32),
+                pltpu.SemaphoreType.DMA,
+            ],
+            compiler_params=_vmem_params(plan["vmem_limit_bytes"]),
+            interpret=interpret,
+            name=plan["kernel"],
+        )(x)
+    with jax.named_scope("stencil_finish"):
+        return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
 
 
 @register_filter("sobel_bilateral_pallas")
@@ -564,6 +616,8 @@ def sobel_bilateral_pallas(
         f"sobel_bilateral_pallas(d={d})",
         fn,
         halo=d // 2 + 1,
+        kernel_plan=lambda shape: sobel_bilateral_plan(
+            shape, d, tile_h, _auto_interpret(interpret)),
     )
 
 

@@ -206,6 +206,10 @@ class Engine:
         self.step_donates_input: Optional[bool] = None  # whether the
         #   compiled step aliases its input batch: False where the result
         #   has another geometry or dtype (set by _build_step)
+        self.kernel_plan: Optional[dict] = None  # which kernel of the
+        #   repo's own the compiled step runs and how it tiled it, as the
+        #   filter states it (Filter.kernel_plan) for the shape one device
+        #   sees; None for XLA's own ops (set by _build_step)
         self._out_sharding = None
         self.last_compile_ms: Optional[float] = None  # wall duration of
         #   the most recent compile() (trace + XLA compile + warmup +
@@ -357,6 +361,9 @@ class Engine:
             out_aval.shape == tuple(batch_shape)
             and out_aval.dtype == np.dtype(in_dtype))
         donate = (0, 1) if self.step_donates_input else (1,)
+        self.kernel_plan = (
+            filt.kernel_plan(self._sharding.shard_shape(tuple(batch_shape)))
+            if filt.kernel_plan is not None else None)
         return jax.jit(
             step,
             in_shardings=(self._sharding, state_shardings)
@@ -883,7 +890,7 @@ class Engine:
             for name in ("_step", "_tabled", "_signature", "_state",
                          "_sharding", "_batch_replicated",
                          "_exec_filter", "out_shape", "out_dtype",
-                         "step_donates_input",
+                         "step_donates_input", "kernel_plan",
                          "_out_sharding", "h2d_block_ms", "d2h_block_ms",
                          "step_block_ms", "last_compile_ms",
                          "state_bytes"):

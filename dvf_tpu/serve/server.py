@@ -604,6 +604,9 @@ class _Bucket:
             "out_geometry": list(out_shape[1:]) if out_shape else None,
             "step_donates_input": getattr(self.engine,
                                           "step_donates_input", None),
+            # Which kernel of the repo's own the step runs and how it
+            # tiled it (Engine.kernel_plan); None for XLA's own ops.
+            "kernel": getattr(self.engine, "kernel_plan", None),
             "mean_valid_rows": self.mean_valid_rows,
             "open_sessions": len(live),
             "queue_depth": sum(len(s.ingress) + len(s.pending)
@@ -3541,6 +3544,7 @@ class ServeFrontend:
                     # step dispatch, each taken inside its call).
                     n_sess = len({slot.session.id for slot in plan.slots})
                     out_bytes = bucket.out_bytes()
+                    plan_k = getattr(bucket.engine, "kernel_plan", None)
                     stage_ms, put_ms, wait_ms, join_ms, step_ms = (
                         round(b - a, 3) for a, b in
                         zip(split, lane.ingest_stats.split_ms()))
@@ -3550,6 +3554,7 @@ class ServeFrontend:
                     tracer.complete("dispatch:assemble_h2d", t0,
                                     st.t_submit, TRACK_DISPATCH, seq=seq,
                                     sessions=n_sess, out_bytes=out_bytes,
+                                    kernel=plan_k and plan_k["kernel"],
                                     stage_ms=stage_ms, put_ms=put_ms,
                                     wait_ms=wait_ms, join_ms=join_ms,
                                     step_dispatch_ms=step_ms)

@@ -4,7 +4,10 @@
 ROADMAP S2's op map (PR 28), for the style net by default. ``--model espcn`` reads the upscaling service's step the
 same way (``super_resolution(scale=2)`` at 16 x 540 x 960 in, 1080 x 1920 out; the scopes of ``models/espcn.py``:
 ``feat``, ``map``, ``head``, ``shuffle``, each in the form ``models/espcn.py::stage_forms`` gives for the shape;
-``--fast-convs`` probes the per-layer space-to-depth round trip). Compiles the step program
+``--fast-convs`` probes the per-layer space-to-depth round trip); ``--model stencil`` the fused Sobel -> bilateral chain of
+``chipbench/configs/sobel_bilateral_1080p.json`` (``sobel_bilateral(d=9, impl="pallas")`` at 1080 x 1920; the scopes of
+``ops/pallas_kernels.py::sobel_bilateral_nhwc_pallas``: ``stencil_prep``, ``stencil_kernel``, ``stencil_finish``; ``--d 5`` and
+``--impl chain`` probe the real-time window and the two-op jnp chain, which carries no scope). Compiles the step program
 of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
 weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
@@ -16,6 +19,8 @@ relu and the residual add). Run on the chip:
     chiprun -- python scripts/style_step_probe.py            # writes chiprun_out/style_step_probe.json
 
     chiprun -- python scripts/style_step_probe.py --model espcn   # chiprun_out/espcn_step_probe.json
+
+    chiprun -- python scripts/style_step_probe.py --model stencil --batch 64   # chiprun_out/stencil_step_probe.json
 
 ``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
 nothing.
@@ -50,12 +55,26 @@ def _espcn_stages(kwargs, shape):
             for stage, form in stage_forms(config, shape).items()}
 
 
+def _stencil_stages(kwargs, shape):
+    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_plan
+
+    if kwargs["impl"] != "pallas":      # the jnp chain: XLA's own fusions, no scope and no tiling
+        return {}
+    plan = sobel_bilateral_plan(shape, kwargs["d"])
+    return {"stencil_prep": "NCHW float32, reflect + %d x %d" % (plan["h_pad"], plan["w_aligned"]),
+            "stencil_kernel": "tile %d, grid %s, slab %d x %d" % (plan["tile_h"], plan["grid"], plan["slab_rows"],
+                                                                  plan["w_aligned"]),
+            "stencil_finish": "slice, NHWC"}
+
+
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))
+_STENCIL = {"d": 9, "sigma_color": 0.1, "sigma_space": 2.0, "magnitude_scale": 1.0, "impl": "pallas"}   # as the cell's file
 _ESPCN = {"scale": 2, "fast_convs": False, "dtype": "bfloat16"}          # as chipbench/configs/sr2x_540p.json
 MODELS = {
     "style": ("style_transfer", (720, 1280), {"base_channels": 32, "n_residual": 5},
               {"base_channels": 8, "n_residual": 2}, _style_stages),
     "espcn": ("super_resolution", (540, 960), _ESPCN, _ESPCN, _espcn_stages),
+    "stencil": ("sobel_bilateral", (1080, 1920), _STENCIL, _STENCIL, _stencil_stages),
 }
 
 
@@ -100,6 +119,8 @@ def main() -> int:
     ap.add_argument("--top", type=int, default=30, help="ops printed")
     ap.add_argument("--model", choices=sorted(MODELS), default="style")
     ap.add_argument("--fast-convs", action="store_true", help="the filter's fast_convs=True (espcn has it)")
+    ap.add_argument("--d", type=int, default=None, help="stencil: the bilateral's window (the cell serves 9)")
+    ap.add_argument("--impl", choices=("pallas", "chain"), default=None, help="stencil: the fused kernel or the jnp chain")
     ap.add_argument("--out", default=None, help="default chiprun_out/<model>_step_probe.json")
     args = ap.parse_args()
     args.out = args.out or f"chiprun_out/{args.model}_step_probe.json"
@@ -121,13 +142,17 @@ def main() -> int:
     kwargs = dict(toy_kwargs if args.toy else kwargs)
     if args.fast_convs:
         kwargs["fast_convs"] = True
+    if args.d is not None:
+        kwargs["d"] = args.d
+    if args.impl is not None:
+        kwargs["impl"] = args.impl
     filt = get_filter(name, **kwargs)
 
     def step(batch, state):            # the body of Engine._build_step
         y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
         return to_uint8(y), new_state
 
-    state = filt.init_state(shape, jnp.float32)
+    state = filt.init_state(shape, jnp.float32) if filt.init_state is not None else None
     batch = jnp.asarray(np.random.default_rng(0).integers(0, 256, shape, dtype=np.uint8))
     t = time.perf_counter()
     compiled = jax.jit(step).lower(batch, state).compile()

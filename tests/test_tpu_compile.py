@@ -103,3 +103,37 @@ def test_espcn_step_at_the_cells_shape_carries_its_activations(one_chip):
     big_floats = [(dtype, dims) for dtype, dims, _, _ in made
                   if dtype in ("f32", "bf16") and dims.startswith(("16,1080,", "16,270,4,", "16,270,240,"))]
     assert not big_floats, big_floats
+
+
+@pytest.mark.parametrize("d,tile_h", [(5, None), (9, None), (9, 24)], ids=["d5", "d9", "d9_pinned"])
+def test_stencil_kernel_compiles_through_mosaic_at_1080p(one_chip, d, tile_h):
+    """The fused Sobel -> bilateral kernel at 1080 x 1920 for the described
+    v5e, as the Engine's step wraps it. At d 9 (81 taps) the unrolled
+    temporaries need 26.33 MB of scoped VMEM at the auto tile of 24 rows:
+    under Mosaic's default 16 MiB the unpinned kernel did not compile
+    (RESOURCE_EXHAUSTED) before PR 43, which interpret mode on the CPU
+    never sees. The kernel is in the step under its own name, inside its
+    scope, with the limit ``sobel_bilateral_plan`` states."""
+    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas, sobel_bilateral_plan
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    shape = (2, 1080, 1920, 3)
+
+    def step(batch):
+        return to_uint8(sobel_bilateral_nhwc_pallas(to_float(batch), d=d, tile_h=tile_h))
+
+    batch = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(batch).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    (call,) = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert re.match(r"\s*%sobel_bilateral(\.\d+)? = f32\[2,3,1080,1920\]", call), call[:120]
+    assert 'op_name="jit(step)/stencil_kernel/sobel_bilateral/pallas_call"' in call
+    plan = sobel_bilateral_plan(shape, d, tile_h)
+    assert (plan["tile_h"], plan["slab_rows"], plan["w_aligned"]) == (24, 32 if d == 5 else 40, 2048)
+    raised = re.search(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', call)
+    assert (int(raised.group(1)) if raised else None) == plan["vmem_limit_bytes"]
+    assert plan["vmem_limit_bytes"] == (None if d == 5 else 64 * 1024 * 1024)
