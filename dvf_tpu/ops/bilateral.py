@@ -8,9 +8,12 @@ elementwise work (25 shifts for d=5) — pure VPU math that XLA fuses into a
 single pass over HBM; no gathers, no data-dependent shapes. The range kernel
 uses Euclidean color distance like cv2.bilateralFilter. Two Pallas
 counterparts live in :mod:`dvf_tpu.ops.pallas_kernels`: ``bilateral_pallas``
-(this op alone, tiled through VMEM) and ``sobel_bilateral_pallas`` (the whole
-configs[2] Sobel→bilateral chain fused into one kernel); this module is the
-jnp reference path and the numerics golden for both.
+(this op alone, tiled through VMEM: its range distance needs all three
+channels, so it carries three planes) and ``sobel_bilateral_pallas`` (the
+whole configs[2] Sobel→bilateral chain fused into one kernel over ONE plane,
+the luma: the chain's bilateral input is gray broadcast ×3, so nothing after
+the luma sees a channel); this module is the jnp reference path and the
+numerics golden for both.
 """
 
 from __future__ import annotations

@@ -23,14 +23,16 @@ def sobel_bilateral(
     """BASELINE configs[2]: Sobel edges then bilateral, one device program.
 
     ``impl=None`` picks the measured per-backend winner — the fused
-    Pallas program on BOTH measured backends. TPU (PR 43's chip runs on a
-    v5e, as_of 2026-10-01, ``scripts/style_step_probe.py --model
-    stencil``, the Engine's step at 1080p, d = 9): batch 64, "pallas"
-    106.1 ms a step (the kernel 75.3, its float32 NCHW preparation 26.8)
+    Pallas program on BOTH measured backends. TPU (chip runs on a v5e,
+    as_of 2026-10-01, ``scripts/style_step_probe.py --model stencil``,
+    the Engine's step at 1080p, d = 9): batch 64, "pallas" 79.05 ms a
+    step (PR 44: the kernel 70.7 over the one luma plane, the luma and
+    its padding 5.6, rounding and the broadcast to three channels 1.8)
     where "chain" does not compile (XLA wants 138 GB of HBM for the 81
     shifted views); batch 4, the largest at which the chain fits, 6.94
-    vs 53.25 ms (7.7x, on 0.20 vs 8.64 GiB of scratch); at d = 5 the
-    fused step of 64 is 58.6 ms (kernel 28.1).
+    vs 53.25 ms (PR 43, the kernel's three-plane form: 7.7x, on 0.20 vs
+    8.64 GiB of scratch); at d = 5 the fused step of 64 is 32.3 ms
+    (kernel 24.1).
     CPU 9.2 vs 3.3 fps (in interpret mode it lowers to ordinary fused XLA
     ops, a legitimate production path; benchmarks/cpu/BENCH_TABLE.json).
     "chain" (the two-op jnp chain) remains the default on backends whose

@@ -464,9 +464,12 @@ def gaussian_blur_pallas(
 _LUMA = (0.299, 0.587, 0.114)  # Rec.601, matches utils.image.rgb_to_gray
 
 
-def _sobel_bilateral_kernel(tile_h: int, r: int, w: int, c: int,
+def _sobel_bilateral_kernel(tile_h: int, r: int, w: int,
                             sigma_color: float, sigma_space: float,
                             magnitude_scale: float):
+    """The kernel over ONE float32 plane, the frame's luma: nothing after
+    the luma sees a channel, so the slab a grid step DMAs and the block it
+    stores are one plane each (``sobel_bilateral_plan``'s ``planes``)."""
     d = 2 * r + 1
     R = r + 1  # bilateral halo + 1 row/col of Sobel support
     # Range distance on a 3-channel broadcast-gray image is 3·Δ²gray.
@@ -483,14 +486,13 @@ def _sobel_bilateral_kernel(tile_h: int, r: int, w: int, c: int,
         b = pl.program_id(0)
         i = pl.program_id(1)
         copy = pltpu.make_async_copy(
-            in_ref.at[b, :, pl.ds(i * tile_h, slab), :],
+            in_ref.at[b, pl.ds(i * tile_h, slab), :],
             scratch,
             sem,
         )
         copy.start()
         copy.wait()
-        x = scratch[...].astype(jnp.float32)      # (c, slab, w_al)
-        gray = _LUMA[0] * x[0] + _LUMA[1] * x[1] + _LUMA[2] * x[2]
+        gray = scratch[...].astype(jnp.float32)   # (slab, w_al)
         # Sobel (ksize=3, conv taps [1,2,1]⊗[-1,0,1]) on the full slab:
         # valid region shrinks by 1 each side → (th+2r, w+2r).
         sx = gray[:-2, :] + 2.0 * gray[1:-1, :] + gray[2:, :]   # smooth V
@@ -509,9 +511,7 @@ def _sobel_bilateral_kernel(tile_h: int, r: int, w: int, c: int,
                 wgt = spatial[dy][dx] * jnp.exp(-(diff * diff) * inv2sc)
                 num = num + wgt * sh
                 den = den + wgt
-        res = num / den
-        out_ref[...] = jnp.broadcast_to(
-            res[None, None], (1, c, tile_h, w)).astype(out_ref.dtype)
+        out_ref[0] = (num / den).astype(out_ref.dtype)
 
     return kernel
 
@@ -523,10 +523,12 @@ def sobel_bilateral_plan(shape, d: int = 5, tile_h: Optional[int] = None,
     numbers from this dict, so what a compiled step states about its
     kernel (``Filter.kernel_plan`` → ``Engine.kernel_plan`` → the bucket
     row's ``kernel`` block) is what ran, not a second copy of the
-    arithmetic."""
+    arithmetic. ``planes`` is how many float32 planes a grid step DMAs and
+    how many it stores: 1, the luma in and the edge map out, whatever the
+    frame's channel count."""
     if d % 2 != 1:
         raise ValueError(f"window d must be odd, got {d}")
-    b, h, w, c = (int(v) for v in shape)
+    b, h, w, _ = (int(v) for v in shape)
     R = d // 2 + 1  # bilateral halo + 1 row/col of Sobel support
     th, h_pad = _resolve_tile_h(h, tile_h, compiled=not interpret)
     slab, w_al = _slab_rows(th, 2 * R), _round_up(w + 2 * R, _LANE)
@@ -534,16 +536,35 @@ def sobel_bilateral_plan(shape, d: int = 5, tile_h: Optional[int] = None,
         "kernel": "sobel_bilateral",   # the pallas_call's name in a trace
         "impl": "pallas",
         "taps": d * d,
+        "planes": 1,                   # planes DMA'd, and stored, a grid step
         "tile_h": th,
         "h_pad": h_pad,
         "grid": [b, h_pad // th],
         "slab_rows": slab,             # rows DMA'd a grid step (tile + halo, 8-aligned)
         "w_aligned": w_al,             # columns DMA'd (W + halo, 128-aligned)
-        "vmem_scratch_bytes": c * slab * w_al * 4,
+        "vmem_scratch_bytes": slab * w_al * 4,
         # None: Mosaic's default scoped-VMEM limit (16 MiB)
         "vmem_limit_bytes": _stencil_vmem_limit(tile_h, interpret, d * d),
         "compute_dtype": "float32",
     }
+
+
+def _reflect_fill(x: jnp.ndarray, axis: int, R: int, fill: int) -> jnp.ndarray:
+    """``[reflected strip, x, reflected strip, zeros]`` along ``axis`` as
+    ONE concatenate: the reflect-101 halo of ``R`` and ``fill`` filler
+    entries behind it. The filler keeps the grid's last slab in bounds and
+    the slab's width lane-aligned and never reaches a valid output
+    (``_pad_rows`` has the argument), so its value is free."""
+    n = x.shape[axis]
+    if n <= R:
+        raise ValueError(f"a reflected halo of {R} needs more than {R} "
+                         f"entries along axis {axis}, got {n}")
+    parts = [jnp.flip(jax.lax.slice_in_dim(x, 1, R + 1, axis=axis), axis), x,
+             jnp.flip(jax.lax.slice_in_dim(x, n - 1 - R, n - 1, axis=axis), axis)]
+    if fill:
+        shape = x.shape[:axis] + (fill,) + x.shape[axis + 1:]
+        parts.append(jnp.zeros(shape, x.dtype))
+    return jnp.concatenate(parts, axis=axis)
 
 
 def sobel_bilateral_nhwc_pallas(
@@ -556,10 +577,17 @@ def sobel_bilateral_nhwc_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused Sobel→bilateral over float NHWC in [0,1]; numerics match
-    FilterChain(sobel, bilateral) — ops.chains.sobel_bilateral. The three
-    parts carry ``jax.named_scope``s (``stencil_prep`` / ``stencil_kernel``
-    / ``stencil_finish``) so a compiled step's ``op_name``s say which part
-    an op belongs to (scripts/style_step_probe.py --model stencil)."""
+    FilterChain(sobel, bilateral) — ops.chains.sobel_bilateral.
+
+    The stencil's data is ONE plane from the first pass to the last (PR
+    44). ``stencil_prep`` takes the Rec.601 luma of the NHWC batch (luma is
+    pointwise, so it commutes with every pad) and pads that plane, an axis
+    a concatenate, to ``[b, h_pad + halo, w_aligned]``; ``stencil_kernel``
+    DMAs one-plane slabs and stores the filtered edge map once;
+    ``stencil_finish`` slices the map and broadcasts it to the frame's
+    channels, so behind it the Engine rounds one plane, not three equal
+    ones. The ``jax.named_scope``s put each part into a compiled step's
+    ``op_name``s (scripts/style_step_probe.py --model stencil)."""
     plan = sobel_bilateral_plan(batch.shape, d, tile_h, interpret)
     r = d // 2
     R = r + 1
@@ -567,22 +595,24 @@ def sobel_bilateral_nhwc_pallas(
     th, h_pad, w_al = plan["tile_h"], plan["h_pad"], plan["w_aligned"]
 
     with jax.named_scope("stencil_prep"):
-        x = jnp.transpose(batch, (0, 3, 1, 2))  # NCHW: W on lanes
-        x = jnp.pad(x, ((0, 0), (0, 0), (R, R), (R, R)), mode="reflect")
-        x = _pad_rows(x, _extra_rows(h, h_pad, th, 2 * R))
-        x = _pad_cols(x, w_al - (w + 2 * R))
+        # A reduction, not three channel slices: XLA then reads the uint8
+        # frame straight into the one float32 plane (sliced, it first
+        # writes all three).
+        x = (batch * jnp.asarray(_LUMA, batch.dtype)).sum(-1)
+        x = _reflect_fill(x, 1, R, _extra_rows(h, h_pad, th, 2 * R))
+        x = _reflect_fill(x, 2, R, w_al - (w + 2 * R))
 
-    kernel = _sobel_bilateral_kernel(th, r, w, c, sigma_color, sigma_space,
+    kernel = _sobel_bilateral_kernel(th, r, w, sigma_color, sigma_space,
                                      magnitude_scale)
     with jax.named_scope("stencil_kernel"):
         out = pl.pallas_call(
             kernel,
             grid=tuple(plan["grid"]),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, c, th, w), lambda bb, ii: (bb, 0, ii, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, c, h_pad, w), batch.dtype),
+            out_specs=pl.BlockSpec((1, th, w), lambda bb, ii: (bb, ii, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, h_pad, w), batch.dtype),
             scratch_shapes=[
-                pltpu.VMEM((c, plan["slab_rows"], w_al), jnp.float32),
+                pltpu.VMEM((plan["slab_rows"], w_al), jnp.float32),
                 pltpu.SemaphoreType.DMA,
             ],
             compiler_params=_vmem_params(plan["vmem_limit_bytes"]),
@@ -590,7 +620,7 @@ def sobel_bilateral_nhwc_pallas(
             name=plan["kernel"],
         )(x)
     with jax.named_scope("stencil_finish"):
-        return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
+        return jax.lax.broadcast_in_dim(out[:, :h], (b, h, w, c), (0, 1, 2))
 
 
 @register_filter("sobel_bilateral_pallas")

@@ -61,10 +61,11 @@ def _stencil_stages(kwargs, shape):
     if kwargs["impl"] != "pallas":      # the jnp chain: XLA's own fusions, no scope and no tiling
         return {}
     plan = sobel_bilateral_plan(shape, kwargs["d"])
-    return {"stencil_prep": "NCHW float32, reflect + %d x %d" % (plan["h_pad"], plan["w_aligned"]),
+    rows = plan["h_pad"] - plan["tile_h"] + plan["slab_rows"]           # the last slab's end: 1096 at the cell's shape
+    return {"stencil_prep": "%d float32 luma plane, reflect + %d x %d" % (plan["planes"], rows, plan["w_aligned"]),
             "stencil_kernel": "tile %d, grid %s, slab %d x %d" % (plan["tile_h"], plan["grid"], plan["slab_rows"],
                                                                   plan["w_aligned"]),
-            "stencil_finish": "slice, NHWC"}
+            "stencil_finish": "slice, broadcast to %d, NHWC" % shape[-1]}
 
 
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))

@@ -240,6 +240,32 @@ def test_pallas_fused_sobel_bilateral_padded_rows():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+@pytest.mark.parametrize("d", [5, 9])
+@pytest.mark.parametrize("geometry", ["padded_rows", "no_filler_cols"])
+def test_pallas_fused_sobel_bilateral_one_plane_form(d, geometry):
+    """The one-plane form (PR 44: luma first, then the reflected strips and
+    zero filler an axis a concatenate) against the jnp chain, where the
+    filler is on the path (36 rows: ``h_pad > h``, filler rows under the
+    last tile and filler columns beside every one) and where the column
+    filler has zero width (W + 2R a multiple of 128, so the column
+    concatenate has no fourth part). The map is made once: the three
+    channels of the result are bit-equal."""
+    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas, sobel_bilateral_plan
+
+    R = d // 2 + 1
+    h, w = (36, 48) if geometry == "padded_rows" else (32, 128 - 2 * R)
+    plan = sobel_bilateral_plan((2, h, w, 3), d, interpret=True)
+    assert (plan["h_pad"] > h) == (geometry == "padded_rows")
+    assert (plan["w_aligned"] == w + 2 * R) == (geometry == "no_filler_cols")
+    rng = np.random.default_rng(17 + d)
+    batch = jnp.asarray(rng.random((2, h, w, 3), dtype=np.float32))
+    want, _ = get_filter("sobel_bilateral", d=d, impl="chain").fn(batch, None)
+    got = np.asarray(sobel_bilateral_nhwc_pallas(batch, d=d, interpret=True))
+    assert got.shape == (2, h, w, 3)
+    np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
+    assert np.array_equal(got[..., 0], got[..., 1]) and np.array_equal(got[..., 0], got[..., 2])
+
+
 def test_pallas_filter_registered(batch):
     f = get_filter("bilateral_pallas", interpret=True)
     got, _ = f.fn(batch, None)

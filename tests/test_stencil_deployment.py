@@ -211,9 +211,9 @@ def test_bucket_row_states_the_kernel_and_its_tiling(d, geometry):
     th, h_pad = pk._resolve_tile_h(h, None, compiled=False)
     slab, w_al = pk._slab_rows(th, halo2), pk._round_up(w + halo2, pk._LANE)
     assert block == {
-        "kernel": "sobel_bilateral", "impl": "pallas", "taps": d * d, "tile_h": th,
+        "kernel": "sobel_bilateral", "impl": "pallas", "taps": d * d, "planes": 1, "tile_h": th,
         "h_pad": h_pad, "grid": [BATCH, h_pad // th], "slab_rows": slab, "w_aligned": w_al,
-        "vmem_scratch_bytes": 3 * slab * w_al * 4, "vmem_limit_bytes": None,
+        "vmem_scratch_bytes": slab * w_al * 4, "vmem_limit_bytes": None,
         "compute_dtype": "float32"}
     assert block == fe._buckets[0].engine.kernel_plan
     json.dumps(block)                                    # plain data: stats() is serialised
@@ -270,9 +270,10 @@ def test_step_carries_the_three_scopes_and_the_kernels_name(d):
     calls = [(stage(scope), name) for scope, prim, name in found if prim == "pallas_call"]
     assert calls == [("stencil_kernel", "sobel_bilateral")], calls
     assert by_stage["stencil_kernel"] == ["pallas_call"]
-    # NHWC -> NCHW, the reflect pad (jnp.pad's reversed slices), the edge rows and columns
-    assert {"transpose", "rev", "concatenate"} <= set(by_stage["stencil_prep"]), by_stage["stencil_prep"]
-    assert {"transpose", "slice"} <= set(by_stage["stencil_finish"]), by_stage["stencil_finish"]
+    # the luma as a reduction, then the reflected strips and the filler, an axis a concatenate
+    assert {"reduce_sum", "rev", "concatenate"} <= set(by_stage["stencil_prep"]), by_stage["stencil_prep"]
+    assert by_stage["stencil_prep"].count("concatenate") == 2 and "pad" not in by_stage["stencil_prep"]
+    assert {"slice", "broadcast_in_dim"} <= set(by_stage["stencil_finish"]), by_stage["stencil_finish"]
     # outside the filter: the engine's uint8 <-> float conversions only
     assert not {"pallas_call", "rev", "transpose"} & set(by_stage[None]), by_stage[None]
     text = jax.jit(step).lower(jax.ShapeDtypeStruct((BATCH, h, w, 3), jnp.uint8)).as_text(
@@ -322,7 +323,8 @@ def test_plan_at_the_cells_shape():
     plan = pk.sobel_bilateral_plan((64, 1080, 1920, 3), 9)
     assert (plan["tile_h"], plan["h_pad"], plan["grid"]) == (24, 1080, [64, 45])
     assert (plan["slab_rows"], plan["w_aligned"]) == (40, 2048)
-    assert plan["vmem_scratch_bytes"] == 3 * 40 * 2048 * 4
+    # one plane a grid step, in and out (PR 44): the luma's slab, the edge map's block
+    assert plan["planes"] == 1 and plan["vmem_scratch_bytes"] == 40 * 2048 * 4
     with pytest.raises(ValueError):
         pk.sobel_bilateral_plan((64, 1080, 1920, 3), 8)
 

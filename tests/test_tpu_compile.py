@@ -5,6 +5,7 @@ here is a time. All such compiles live in this one file, behind a fixture:
 one worker loads the TPU library, and only once a test of this file runs."""
 
 import importlib.util
+import math
 import os
 import re
 
@@ -105,6 +106,31 @@ def test_espcn_step_at_the_cells_shape_carries_its_activations(one_chip):
     assert not big_floats, big_floats
 
 
+_STENCIL_SHAPE = (2, 1080, 1920, 3)
+_stencil_texts = {}
+
+
+def _stencil_step_text(one_chip, d, tile_h):
+    """The compiled text of the fused Sobel -> bilateral step as the Engine
+    wraps it (uint8 in, uint8 out), for the described v5e; one compile a
+    (d, tile_h) for the tests below."""
+    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    if (d, tile_h) not in _stencil_texts:
+        def step(batch):
+            return to_uint8(sobel_bilateral_nhwc_pallas(to_float(batch), d=d, tile_h=tile_h))
+
+        batch = jax.ShapeDtypeStruct(_STENCIL_SHAPE, jnp.uint8, sharding=one_chip)
+        cache_was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            _stencil_texts[d, tile_h] = jax.jit(step).lower(batch).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+    return _stencil_texts[d, tile_h]
+
+
 @pytest.mark.parametrize("d,tile_h", [(5, None), (9, None), (9, 24)], ids=["d5", "d9", "d9_pinned"])
 def test_stencil_kernel_compiles_through_mosaic_at_1080p(one_chip, d, tile_h):
     """The fused Sobel -> bilateral kernel at 1080 x 1920 for the described
@@ -113,27 +139,48 @@ def test_stencil_kernel_compiles_through_mosaic_at_1080p(one_chip, d, tile_h):
     under Mosaic's default 16 MiB the unpinned kernel did not compile
     (RESOURCE_EXHAUSTED) before PR 43, which interpret mode on the CPU
     never sees. The kernel is in the step under its own name, inside its
-    scope, with the limit ``sobel_bilateral_plan`` states."""
-    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas, sobel_bilateral_plan
-    from dvf_tpu.utils.image import to_float, to_uint8
+    scope, with the limit ``sobel_bilateral_plan`` states, and its result
+    is the ONE plane of the edge map (PR 44; three equal ones before)."""
+    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_plan
 
-    shape = (2, 1080, 1920, 3)
-
-    def step(batch):
-        return to_uint8(sobel_bilateral_nhwc_pallas(to_float(batch), d=d, tile_h=tile_h))
-
-    batch = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        text = jax.jit(step).lower(batch).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
+    text = _stencil_step_text(one_chip, d, tile_h)
     (call,) = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
-    assert re.match(r"\s*%sobel_bilateral(\.\d+)? = f32\[2,3,1080,1920\]", call), call[:120]
+    assert re.match(r"\s*%sobel_bilateral(\.\d+)? = f32\[2,1080,1920\]", call), call[:120]
     assert 'op_name="jit(step)/stencil_kernel/sobel_bilateral/pallas_call"' in call
-    plan = sobel_bilateral_plan(shape, d, tile_h)
+    plan = sobel_bilateral_plan(_STENCIL_SHAPE, d, tile_h)
     assert (plan["tile_h"], plan["slab_rows"], plan["w_aligned"]) == (24, 32 if d == 5 else 40, 2048)
+    assert plan["planes"] == 1 and plan["vmem_scratch_bytes"] == plan["slab_rows"] * 2048 * 4
     raised = re.search(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', call)
     assert (int(raised.group(1)) if raised else None) == plan["vmem_limit_bytes"]
     assert plan["vmem_limit_bytes"] == (None if d == 5 else 64 * 1024 * 1024)
+
+
+@pytest.mark.parametrize("gone", ["f32[2,3,1096,2048]", "f32[2,3,1080,1920]", "f32[2,1080,1920,3]",
+                                  "a fourth prep pass"],
+                         ids=["padded_planes", "result_planes", "nhwc_float", "prep_passes"])
+def test_stencil_step_carries_one_plane(one_chip, gone):
+    """The step of the cell's filter (d 9, tile 24) for the described v5e
+    makes no float32 tensor of three planes: not the padded NCHW input the
+    kernel took before PR 44, not its threefold result, not the float NHWC
+    frame in front of the luma (which a luma written as three channel
+    slices leaves behind). And ``stencil_prep`` is three passes over a
+    plane: the luma straight from the uint8 frame, a concatenate an axis
+    (a ``jnp.pad`` for the filler would be a fourth)."""
+    text = _stencil_step_text(one_chip, 9, 24)
+    b, h, w, _ = _STENCIL_SHAPE
+    made = []           # (result types, op_name) of the entry computation's instructions
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\([^=]*?\)|\S+) ", line)
+        if m:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            made.append((re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)), scope.group(1) if scope else ""))
+    assert any("/stencil_prep/" in scope for _, scope in made)
+    if gone.startswith("f32["):
+        dims = gone[4:-1]
+        assert not [types for types, _ in made if ("f32", dims) in types]
+    else:
+        elements = lambda dims: math.prod(int(v) for v in dims.split(",") if v)
+        passes = [types for types, scope in made if "/stencil_prep/" in scope
+                  and any(elements(dims) >= b * h * w for _, dims in types)]
+        assert len(passes) <= 3, passes
+        assert [types[0][1] for types in passes][-1] == "2,1096,2048"      # what the kernel reads
