@@ -35,6 +35,10 @@ import threading
 from typing import Dict, Iterable, List, Optional, Sequence
 
 
+PLACEMENT_KEYS = ("placed_total", "warm_hits_total", "spillovers_total",
+                  "migrations_total")
+
+
 class SpilloverAdmission:
     """Candidate ordering + admission counters for the fleet router."""
 
@@ -50,6 +54,10 @@ class SpilloverAdmission:
         #   controller's key input was previously visible only in
         #   rejection STRINGS; these counters put it on the telemetry
         #   ring (fleet signals() flattens them per tier name)
+        self._placement: Dict[str, Dict[str, int]] = {}  # where sessions
+        #   landed, per replica (the ``placement`` block): opens placed,
+        #   those that met a pool already warm for their signature, the
+        #   hops they fell past before landing, migrations taken in
 
     def candidates(
         self,
@@ -94,6 +102,31 @@ class SpilloverAdmission:
             return (load.get(r.id, 0) - bias, cold, r.id)
 
         return sorted(ok, key=rank)
+
+    def record_placement(self, replica_id: str, warm: bool = False,
+                         hops: int = 0, migration: bool = False) -> None:
+        """One session bound to ``replica_id``: by an open (``placed``;
+        ``warm``: its signature was in the replica's warm set; ``hops``:
+        candidates that refused it first) or by a migration off a lost
+        or retiring replica. Cumulative, so a window delta reads them."""
+        with self._lock:
+            row = self._placement.setdefault(
+                replica_id, dict.fromkeys(PLACEMENT_KEYS, 0))
+            if migration:
+                row["migrations_total"] += 1
+                return
+            row["placed_total"] += 1
+            row["warm_hits_total"] += bool(warm)
+            row["spillovers_total"] += hops
+
+    def placement(self) -> dict:
+        """``stats()["placement"]``: the fleet's totals beside each
+        replica's row; the rows sum to the totals."""
+        with self._lock:
+            rows = {rid: dict(r) for rid, r in sorted(self._placement.items())}
+        return {**{k: sum(r[k] for r in rows.values())
+                   for k in PLACEMENT_KEYS},
+                "by_replica": rows}
 
     def record_tier_rejection(self) -> None:
         with self._lock:

@@ -137,6 +137,21 @@ class ReplicaHandle:
         #   the merge_tracer_snapshots epoch discipline, per frame).
         #   Exactly 0 for in-process replicas; process replicas estimate
         #   it from the health RPC's midpoint each monitor tick.
+        self.door = None           # () -> this replica's ``door`` block
+        #   (fleet.stats.DoorStats.row), set by the front door that owns
+        #   the handle: what its submit/poll calls cost, booked here
+
+    def _with_door(self, export: dict) -> dict:
+        """Lay this replica's ``door`` block on every bucket row of a
+        ``stats_full`` export: the front door's clock travels with the
+        rows of the replica it was spent on, so whoever reads a
+        replica's rows reads its door beside them."""
+        if self.door is not None:
+            block = self.door()
+            rows = (export.get("stats") or {}).get("buckets") or {}
+            for row in rows.values():
+                row["door"] = block
+        return export
 
     # lifecycle
     def start(self) -> "ReplicaHandle":
@@ -303,8 +318,9 @@ class LocalReplica(ReplicaHandle):
 
     def stats_full(self) -> dict:
         fe = self._fe()
-        return {"stats": fe.stats(), "latency": fe.latency_snapshot(),
-                "signals": fe.signals(), "health": fe.health()}
+        return self._with_door(
+            {"stats": fe.stats(), "latency": fe.latency_snapshot(),
+             "signals": fe.signals(), "health": fe.health()})
 
     def trace_snapshot(self) -> dict:
         return self._fe().tracer.snapshot()
@@ -712,8 +728,8 @@ class ProcessReplica(ReplicaHandle):
         # would answer the NEXT request), so it must keep meaning
         # replica loss — and a scrape must not be able to declare a
         # merely-slow replica dead.
-        return self._rpc(("stats",),
-                         lock_timeout=self._rpc_lock_timeout_s)
+        return self._with_door(
+            self._rpc(("stats",), lock_timeout=self._rpc_lock_timeout_s))
 
     def trace_snapshot(self) -> dict:
         # Same bound discipline as stats_full: busy channel → benign

@@ -39,6 +39,7 @@ fault summaries → one table with ``by_replica`` attribution
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import sys
 import threading
@@ -61,6 +62,7 @@ from dvf_tpu.fleet.replica import (
     refuse_process_replicas_on_tpu,
 )
 from dvf_tpu.fleet.stats import (
+    DoorStats,
     merge_fault_summaries,
     merge_latency_snapshots,
     replica_row,
@@ -90,6 +92,10 @@ from dvf_tpu.serve.session import (
 )
 
 FLEET_MODES = ("local", "process")
+
+# The front door's own lane in its tracer: ``fleet:submit`` / ``fleet:poll``
+# spans (``trace`` on), beside the lifecycle instants (0) and the ledger (1).
+TRACK_DOOR = 2
 
 
 @dataclasses.dataclass
@@ -296,6 +302,9 @@ class FleetFrontend:
         self.faults = FaultStats()        # fleet-observed faults (replica
         #   losses), attributed per replica via record(..., replica=)
         self.admission = SpilloverAdmission()
+        self.door = DoorStats()           # submit/poll, entry to return,
+        #   per bound replica: always on (stats()["door"], and each
+        #   replica's block on its bucket rows via stats_full)
         self.replica_losses = 0
         self.migrated_sessions = 0
         self.orphaned_sessions = 0
@@ -590,6 +599,11 @@ class FleetFrontend:
     # -- replica construction -------------------------------------------
 
     def _make_replica(self, rid: str, index: int) -> ReplicaHandle:
+        r = self._build_replica(rid, index)
+        r.door = functools.partial(self.door.row, rid)
+        return r
+
+    def _build_replica(self, rid: str, index: int) -> ReplicaHandle:
         if self.config.mode == "process":
             serve_fields = {
                 f.name: getattr(self.config.serve, f.name)
@@ -926,8 +940,12 @@ class FleetFrontend:
                     continue
                 if hops:
                     self.admission.record_spillover(hops)
+                was_warm = (key_render is not None
+                            and key_render in set(warm.get(r.id) or ()))
+                self.admission.record_placement(r.id, warm=was_warm,
+                                                hops=hops)
                 if key_render is not None:
-                    if key_render in set(warm.get(r.id) or ()):
+                    if was_warm:
                         self.admission.record_warm_placement()
                     with self._lock:
                         # Optimistic warm update: the replica compiled
@@ -993,11 +1011,24 @@ class FleetFrontend:
         (pre-migration window) is dropped and counted (``lost``):
         freshness-first at-most-once, the same contract as every other
         drop bound in the system."""
+        t_in = time.perf_counter()
         s = self._session(session_id)
+        try:
+            return self._submit(s, frame, ts, tag)
+        finally:
+            dt = time.perf_counter() - t_in
+            self.door.note_submit(s.replica_id, dt)
+            if self.tracer.enabled:
+                now = time.time()
+                self.tracer.complete("fleet:submit", now - dt, now,
+                                     track=TRACK_DOOR, replica=s.replica_id)
+
+    def _submit(self, s: _FleetSession, frame: np.ndarray,
+                ts: Optional[float], tag: Any) -> int:
         with s.lock:
             if s.closed or s.orphaned:
                 raise SessionClosedError(
-                    f"session {session_id!r} is closed"
+                    f"session {s.sid!r} is closed"
                     + (" (orphaned by replica loss)" if s.orphaned else ""))
             idx = s.next_index
             s.next_index += 1
@@ -1036,7 +1067,26 @@ class FleetFrontend:
         drops the frame payloads — the fleet bench's counting mode, so
         measuring N replicas doesn't serialize N replicas' pixels
         through the front door."""
+        t_in = time.perf_counter()
         s = self._session(session_id)
+        out: list = []
+        try:
+            out = self._poll(s, max_items, meta_only)
+            return out
+        finally:
+            dt = time.perf_counter() - t_in
+            self.door.note_poll(s.replica_id, dt, len(out))
+            if out and self.tracer.enabled:
+                # An empty poll leaves no span (a polling client makes
+                # tens of thousands a second, and the ring is bounded);
+                # the door's counters count it.
+                now = time.time()
+                self.tracer.complete("fleet:poll", now - dt, now,
+                                     track=TRACK_DOOR, replica=s.replica_id,
+                                     deliveries=len(out))
+
+    def _poll(self, s: _FleetSession, max_items: Optional[int],
+              meta_only: bool) -> list:
         # Continuity chaos sites model the CLIENT-facing wire, so they
         # wrap the fleet's bookkeeping: a net_partition costs this poll
         # its delivery opportunity (frames stay queued replica-side —
@@ -1054,7 +1104,7 @@ class FleetFrontend:
                     self.ledger.record(
                         ledger_mod.PARTITION,
                         cause=ledger_mod.CAUSE_RECOVERY,
-                        sid=session_id, plane="fleet")
+                        sid=s.sid, plane="fleet")
                 return []
             try:
                 chaos.fire("net_delay")   # delay_s rules sleep in fire()
@@ -1627,6 +1677,8 @@ class FleetFrontend:
                         self._load[target.id] = (
                             self._load.get(target.id, 0) + 1)
                     self.migrated_sessions += 1
+                    self.admission.record_placement(target.id,
+                                                    migration=True)
                     return
                 # Nobody could take it: it closes under the client.
                 orphan = True
@@ -1741,7 +1793,7 @@ class FleetFrontend:
                 "multihost flavor needs multihost_hosts >= 2 and a "
                 "--precompile manifest naming the signature the group "
                 "compiles")
-        return MultiHostReplica(
+        r = MultiHostReplica(
             rid,
             op_chain=key.op_chain,
             frame_shape=tuple(key.geometry),
@@ -1754,6 +1806,8 @@ class FleetFrontend:
             startup_timeout_s=self.config.startup_timeout_s,
             rpc_timeout_s=self.config.rpc_timeout_s,
         )
+        r.door = functools.partial(self.door.row, rid)
+        return r
 
     def retire_replica(self, rid: str,
                        cause: str = ledger_mod.CAUSE_MANUAL,
@@ -2372,6 +2426,11 @@ class FleetFrontend:
                           for ch, p in self._publish_pumps.items()},
             }} if self.broadcast is not None else {}),
             **self.admission.stats(),
+            # Where sessions landed, per replica (fleet.admission), and
+            # what the front door cost its callers (fleet.stats.DoorStats):
+            # cumulative, so a window delta reads both.
+            "placement": self.admission.placement(),
+            "door": self.door.summary(),
             "faults": merge_fault_summaries(
                 self.faults.summary(),
                 {rid: (e or {}).get("stats", {}).get("faults")

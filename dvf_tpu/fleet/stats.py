@@ -13,10 +13,76 @@ samples, never averaged percentiles) and a fleet fault table with
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Optional
 
 from dvf_tpu.obs.metrics import LatencyStats
 from dvf_tpu.resilience.faults import FaultStats
+
+
+DOOR_KEYS = ("submit_calls_total", "submit_us_total", "poll_calls_total",
+             "poll_us_total", "deliveries_total")
+_DOOR_ZERO = (0, 0.0, 0, 0.0, 0)
+
+
+class DoorStats:
+    """The front door's own clock (always on, as the stage clock is):
+    what ``FleetFrontend.submit`` and ``poll`` cost their caller, entry
+    to return on ``time.perf_counter``, cumulative and booked under the
+    replica the call's session is bound to when it returns.
+
+    ``deliveries_total`` counts the deliveries ``poll`` handed out, so
+    ``(submit_us_total + poll_us_total) / deliveries_total`` over a window
+    is the front door's cost of one served frame, empty polls included.
+    Writers are the clients' threads (one lock, two adds a call); a
+    replica that left the fleet keeps its row, so the total is monotone.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: Dict[str, list] = {}    # rid -> [DOOR_KEYS order]
+
+    def _row_locked(self, rid: str) -> list:
+        row = self._rows.get(rid)
+        if row is None:
+            row = self._rows[rid] = list(_DOOR_ZERO)
+        return row
+
+    def note_submit(self, rid: str, seconds: float) -> None:
+        with self._lock:
+            row = self._row_locked(rid)
+            row[0] += 1
+            row[1] += seconds * 1e6
+
+    def note_poll(self, rid: str, seconds: float, deliveries: int) -> None:
+        with self._lock:
+            row = self._row_locked(rid)
+            row[2] += 1
+            row[3] += seconds * 1e6
+            row[4] += deliveries
+
+    @staticmethod
+    def _block(vals) -> dict:
+        return {k: (round(v, 1) if isinstance(v, float) else v)
+                for k, v in zip(DOOR_KEYS, vals)}
+
+    def row(self, rid: str) -> dict:
+        """One replica's block: what rides its bucket rows (``replica``
+        says whose it is, so a reader that meets it on several rows
+        counts it once)."""
+        with self._lock:
+            vals = list(self._rows.get(rid) or _DOOR_ZERO)
+        return {"replica": rid, **self._block(vals)}
+
+    def summary(self) -> dict:
+        """``stats()["door"]``: the fleet's total beside every replica's
+        block."""
+        with self._lock:
+            rows = {rid: list(v) for rid, v in sorted(self._rows.items())}
+        total = [sum(col) for col in zip(*rows.values())] or _DOOR_ZERO
+        return {**self._block(total),
+                "by_replica": {rid: {"replica": rid, **self._block(v)}
+                               for rid, v in rows.items()}}
 
 
 def merge_fault_summaries(
@@ -65,6 +131,11 @@ def replica_row(handle, export: Optional[dict], sessions: int) -> dict:
             recoveries=st.get("recoveries"),
             faults=st.get("faults", {}).get("by_kind", {}),
             aggregate=st.get("aggregate"),
+            # The replica's bucket rows as its own stats() has them
+            # (stages, ingest, egress, starved, hold, ...): what a reader
+            # of the fleet needs of a replica without reaching past
+            # stats() into the handles.
+            buckets=st.get("buckets"),
         )
         attr = st.get("attribution")
         if attr is not None:
