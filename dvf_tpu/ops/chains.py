@@ -24,17 +24,20 @@ def sobel_bilateral(
 
     ``impl=None`` picks the measured per-backend winner — the fused
     Pallas program on BOTH measured backends. TPU (chip runs on a v5e,
-    as_of 2026-10-01, ``scripts/style_step_probe.py --model stencil``,
-    the Engine's step at 1080p, d = 9): batch 64, "pallas" 79.05 ms a
-    step (PR 44: the kernel 70.7 over the one luma plane, the luma and
-    its padding 5.6, rounding and the broadcast to three channels 1.8)
-    where "chain" does not compile (XLA wants 138 GB of HBM for the 81
-    shifted views); batch 4, the largest at which the chain fits, 6.94
-    vs 53.25 ms (PR 43, the kernel's three-plane form: 7.7x, on 0.20 vs
-    8.64 GiB of scratch); at d = 5 the fused step of 64 is 32.3 ms
-    (kernel 24.1).
+    as_of 2026-10-02, the Engine's step at 1080p, d = 9): batch 64,
+    "pallas" 34.3 ms a step (PR 46, ``step_ms.bulk`` of the served cell:
+    the kernel 27.5 with its taps in register-sized strips,
+    ``scripts/stencil_kernel_probe.py``; 79.05 with the kernel at 70.7
+    before, PR 44: the luma and its padding 5.6, rounding and the
+    broadcast to three channels 1.8) where "chain" does not compile (XLA
+    wants 138 GB of HBM for the 81 shifted views); batch 4, the largest
+    at which the chain fits, 6.94 vs 53.25 ms (PR 43, the kernel's
+    three-plane whole-tile form: 7.7x, on 0.20 vs 8.64 GiB of scratch);
+    at d = 5 the fused step of 64 was 32.3 ms (kernel 24.1) in PR 44 and
+    is not measured since.
     CPU 9.2 vs 3.3 fps (in interpret mode it lowers to ordinary fused XLA
-    ops, a legitimate production path; benchmarks/cpu/BENCH_TABLE.json).
+    ops, a legitimate production path, and keeps the whole-tile form;
+    benchmarks/cpu/BENCH_TABLE.json).
     "chain" (the two-op jnp chain) remains the default on backends whose
     A/B hasn't been captured yet. Both filters declare the same halo, so
     spatial sharding is unaffected by the choice.

@@ -46,6 +46,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -135,12 +136,14 @@ def _stencil_vmem_limit(tile_h: Optional[int], interpret: bool,
     (16 MiB). The unrolled taps' temporaries grow with the tile and with
     the window: bilateral at a pinned tile 40 over 1080p needs 16.55 MB on
     the v5e (RESOURCE_EXHAUSTED in PR 21's chip run), and the fused
-    Sobel+bilateral at d = 9 (81 taps) needs 26.33 MB at the AUTO tile of
+    Sobel+bilateral at d = 9 (81 taps) needed 26.33 MB at the AUTO tile of
     24 rows (Mosaic for a described v5e, PR 43: the unpinned kernel did
-    not compile). So a caller-pinned ``tile_h`` (the run_table tile
-    sweeps) or a window over 5x5 taps gets the warp kernel's 64 MiB (the
-    chip has 128 MiB of VMEM); the auto-picked tile at up to 25 taps
-    compiles under the default and keeps it."""
+    not compile) until PR 46 ran its taps in strips (3.25 MB now:
+    tests/test_tpu_compile.py compiles it under the default). So a
+    caller-pinned ``tile_h`` (the run_table tile sweeps) or a window over
+    5x5 taps gets the warp kernel's 64 MiB (the chip has 128 MiB of VMEM),
+    which costs nothing where it goes unused; the auto-picked tile at up
+    to 25 taps compiles under the default and keeps it."""
     if interpret or (tile_h is None and taps <= _TAPS_UNDER_DEFAULT_VMEM):
         return None
     return _VMEM_LIMIT_RAISED
@@ -464,16 +467,75 @@ def gaussian_blur_pallas(
 _LUMA = (0.299, 0.587, 0.114)  # Rec.601, matches utils.image.rgb_to_gray
 
 
+_STRIP = (_SUBLANE, 3 * _LANE)   # rows x lanes: three f32 vregs an array
+
+
+def _strip_shape(interpret: bool) -> Optional[tuple]:
+    """``(rows, lanes)`` of the piece of a tile whose taps the fused kernel
+    runs at a time, or None for the whole tile at once. Compiled: 8 x 384,
+    so center, both accumulators and a tap's temporaries stay in the
+    64-vreg file. Interpret mode has no register file: the whole-tile form
+    lowers to ONE vectorised XLA loop fusion over the tile, while the
+    strips' rolls and selects keep the CPU's fusion scalar (1.6 against
+    13.7 frames/s at 1080p, d 5, here on the CPU, PR 46), so CPU users
+    keep the whole-tile form. Tests pin the strips in interpret mode by
+    patching this function."""
+    return None if interpret else _STRIP
+
+
+def _loop(trips: int, body) -> None:
+    """``body(i)`` for i in [0, trips): a ``fori_loop`` (one copy of the body
+    whatever the trips), the body itself where there is one trip."""
+    if trips == 1:
+        body(0)
+    elif trips:
+        jax.lax.fori_loop(0, trips, lambda i, carry: body(i), None)
+
+
+def _aligned(i, step: int):
+    """``i * step``, stated as a multiple of ``step`` where ``i`` is traced."""
+    return i * step if isinstance(i, int) else pl.multiple_of(i * step, step)
+
+
+def _shifted_shape(tile_h: int, r: int, w: int) -> tuple:
+    """Shape of the column-shifted copies of the edge map the strips read:
+    ``d`` shifts; the rows every 8-row strip of the tile reaches (the 2r
+    under it, in whole sublane tiles); the columns the tile emits."""
+    return (2 * r + 1, _round_up(tile_h, _SUBLANE) + _round_up(2 * r, _SUBLANE),
+            _round_up(w, _LANE))
+
+
 def _sobel_bilateral_kernel(tile_h: int, r: int, w: int,
                             sigma_color: float, sigma_space: float,
-                            magnitude_scale: float):
+                            magnitude_scale: float,
+                            strip_shape: Optional[tuple] = None):
     """The kernel over ONE float32 plane, the frame's luma: nothing after
     the luma sees a channel, so the slab a grid step DMAs and the block it
-    stores are one plane each (``sobel_bilateral_plan``'s ``planes``)."""
+    stores are one plane each (``sobel_bilateral_plan``'s ``planes``).
+
+    ``strip_shape`` None: every expression is over the whole
+    ``(tile_h, w)`` tile, the form interpret mode runs
+    (:func:`_strip_shape`). Compiled, that tile is 45 vregs an array at
+    24 x 1920 where the file holds 64: center, num and den alone are 135,
+    so every tap went through VMEM (44K spill loads and stores a grid
+    step, 32.6K bundles; PR 46 has the count). Given ``(8, lanes)``, a
+    grid step works in register-sized pieces instead. Sobel runs a row
+    tile (8 slab rows) at a time and stores the edge map ``d`` times,
+    shifted left by 0..d-1 columns (the ``shifted`` scratch): a lane shift
+    is paid once a grid step, not once a tap. The taps then run strip by
+    strip, center / num / den in registers from the first tap to the
+    divide, on sublane-aligned loads only (``strip`` has how). Loops, not
+    unrolled strips: a live range ends with its strip. On the v5e, 64
+    frames of 1080p at d 9: 11.1K bundles a grid step and 27.5 ms a batch
+    where the whole-tile form took 71.6 (scripts/stencil_kernel_probe.py,
+    chip runs of PR 46)."""
     d = 2 * r + 1
     R = r + 1  # bilateral halo + 1 row/col of Sobel support
     # Range distance on a 3-channel broadcast-gray image is 3·Δ²gray.
     inv2sc = 3.0 / (2.0 * sigma_color * sigma_color)
+    # exp(-x·inv2sc) = exp2(x·k2): the strips fold the constants into one
+    # multiply (Mosaic's exp is 0 - x, two multiplies and the same vpow2).
+    k2 = -inv2sc * math.log2(math.e)
     spatial = [
         [math.exp(-(dy * dy + dx * dx) / (2.0 * sigma_space * sigma_space))
          for dx in range(-r, r + 1)]
@@ -482,16 +544,7 @@ def _sobel_bilateral_kernel(tile_h: int, r: int, w: int,
 
     slab = _slab_rows(tile_h, 2 * R)
 
-    def kernel(in_ref, out_ref, scratch, sem):
-        b = pl.program_id(0)
-        i = pl.program_id(1)
-        copy = pltpu.make_async_copy(
-            in_ref.at[b, pl.ds(i * tile_h, slab), :],
-            scratch,
-            sem,
-        )
-        copy.start()
-        copy.wait()
+    def whole_tile(scratch, out_ref):
         gray = scratch[...].astype(jnp.float32)   # (slab, w_al)
         # Sobel (ksize=3, conv taps [1,2,1]⊗[-1,0,1]) on the full slab:
         # valid region shrinks by 1 each side → (th+2r, w+2r).
@@ -513,6 +566,127 @@ def _sobel_bilateral_kernel(tile_h: int, r: int, w: int,
                 den = den + wgt
         out_ref[0] = (num / den).astype(out_ref.dtype)
 
+    def in_strips(scratch, shifted, out_ref):
+        strip_rows, strip_lanes = strip_shape
+        _, rows_sh, w_sh = shifted.shape
+
+        def left(x, k):
+            """``x[:, j + k]`` at column j (the last k columns wrap)."""
+            return pltpu.roll(x, x.shape[1] - k, 1)
+
+        def sobel_tile(t):
+            # Edge-map rows [8t, 8t+8) from slab rows [8t, 8t+10), in the
+            # whole-tile form's order of additions. The slab's last row
+            # tile has none under it: its own rows stand in, and the map's
+            # rows that read them (>= tile_h + 2r) are never used.
+            y0 = _aligned(t, _SUBLANE)
+            y1 = pl.multiple_of(jnp.minimum(y0 + _SUBLANE, slab - _SUBLANE),
+                                _SUBLANE)
+            win = jnp.concatenate([scratch[pl.ds(y0, _SUBLANE), :],
+                                   scratch[pl.ds(y1, _SUBLANE), :]], axis=0)
+            g0 = win[:_SUBLANE]
+            g1 = pltpu.roll(win, 2 * _SUBLANE - 1, 0)[:_SUBLANE]
+            g2 = pltpu.roll(win, 2 * _SUBLANE - 2, 0)[:_SUBLANE]
+            sx = g0 + 2.0 * g1 + g2                              # smooth V
+            gx = left(sx, 2) - sx                                # deriv H
+            sy0 = g0 + 2.0 * left(g0, 1) + left(g0, 2)           # smooth H
+            sy2 = g2 + 2.0 * left(g2, 1) + left(g2, 2)
+            gy = sy2 - sy0                                       # deriv V
+            mag = jnp.clip(jnp.sqrt(gx * gx + gy * gy) * magnitude_scale,
+                           0.0, 1.0)
+            for dx in range(d):
+                shifted[dx, pl.ds(y0, _SUBLANE), :] = \
+                    (left(mag, dx) if dx else mag)[:, :w_sh]
+
+        _loop(min(rows_sh, slab) // _SUBLANE, sobel_tile)
+
+        def strip(row0, n_rows, c0, n_cols):
+            """Output rows [row0, row0 + n_rows) x columns [c0, c0 + n_cols)
+            of the tile: all d*d taps with the accumulators in registers.
+            ``row0`` and ``c0`` are tile-aligned and may be traced; the
+            extents are static.
+
+            A tap's window, map rows [row0 + dy, +8), lies across two
+            sublane tiles unless dy is a multiple of 8. It is not moved:
+            the two tiles are merged by a sublane select (row i of the
+            window lands on sublane (i + dy) mod 8), the center is rolled
+            ONCE a dy to meet it, the d taps of that dy accumulate in the
+            rolled frame, and their two sums are rolled back once."""
+            # jax.lax, not jnp, from here to the store: a jnp operator traces
+            # through a jit of its own, and with 81 taps of them the step
+            # took 2.0 s to trace on the serving host where the whole-tile
+            # form takes 0.4, twice a set-up (the Engine's eval_shape, then
+            # its jit; chip runs of PR 46). The same primitives reach Mosaic.
+            lanes = pl.ds(c0, _round_up(n_cols, _LANE))
+            sublane = lax.broadcasted_iota(
+                jnp.int32, (strip_rows, lanes.size), 0)
+            tiles = [pl.ds(row0 + y, strip_rows)
+                     for y in range(0, 2 * r + strip_rows, strip_rows)]
+            wraps = {off: lax.ge(sublane, off) for off in range(1, strip_rows)}
+
+            @functools.cache
+            def rows(dx, tile):
+                # One load a (copy, row tile) in the trace; the compiler
+                # reloads where it likes (the schedule is the same as with
+                # a load a tap, and the trace a third shorter).
+                return shifted[dx, tiles[tile], lanes]
+
+            def merged(dx, dy):
+                """Map rows [row0 + dy, +8) at column shift dx, row i of
+                them on sublane (i + dy) mod 8."""
+                tile, off = divmod(dy, strip_rows)
+                if not off:
+                    return rows(dx, tile)
+                return lax.select(wraps[off], rows(dx, tile), rows(dx, tile + 1))
+
+            def to_frame(x, dy):
+                """Row i of ``x`` to sublane (i + dy) mod 8."""
+                return pltpu.roll(x, dy % strip_rows, 0) if dy % strip_rows else x
+
+            center = to_frame(merged(r, r), -r)
+            num = den = jnp.zeros_like(center)
+            for dy in range(d):
+                center_dy = to_frame(center, dy)
+                num_dy = den_dy = jnp.zeros_like(center)
+                for dx in range(d):
+                    sh = merged(dx, dy)
+                    diff = lax.sub(sh, center_dy)
+                    wgt = lax.mul(lax.exp2(lax.mul(lax.mul(diff, diff), k2)),
+                                  spatial[dy][dx])
+                    num_dy = lax.add(num_dy, lax.mul(wgt, sh))
+                    den_dy = lax.add(den_dy, wgt)
+                num = lax.add(num, to_frame(num_dy, -dy))
+                den = lax.add(den, to_frame(den_dy, -dy))
+            out_ref[0, pl.ds(row0, n_rows), pl.ds(c0, n_cols)] = \
+                lax.div(num, den)[:n_rows, :n_cols].astype(out_ref.dtype)
+
+        def row_strip(row0, n_rows):
+            _loop(w // strip_lanes, lambda c: strip(
+                row0, n_rows, _aligned(c, strip_lanes), strip_lanes))
+            if w % strip_lanes:
+                strip(row0, n_rows, w - w % strip_lanes, w % strip_lanes)
+
+        _loop(tile_h // strip_rows,
+              lambda s: row_strip(_aligned(s, strip_rows), strip_rows))
+        if tile_h % strip_rows:
+            row_strip(tile_h - tile_h % strip_rows, tile_h % strip_rows)
+
+    def kernel(in_ref, out_ref, scratch, *rest):
+        *shifted, sem = rest        # the copies' scratch, where there are strips
+        b = pl.program_id(0)
+        i = pl.program_id(1)
+        copy = pltpu.make_async_copy(
+            in_ref.at[b, pl.ds(i * tile_h, slab), :],
+            scratch,
+            sem,
+        )
+        copy.start()
+        copy.wait()
+        if strip_shape is None:
+            whole_tile(scratch, out_ref)
+        else:
+            in_strips(scratch, *shifted, out_ref)
+
     return kernel
 
 
@@ -532,6 +706,7 @@ def sobel_bilateral_plan(shape, d: int = 5, tile_h: Optional[int] = None,
     R = d // 2 + 1  # bilateral halo + 1 row/col of Sobel support
     th, h_pad = _resolve_tile_h(h, tile_h, compiled=not interpret)
     slab, w_al = _slab_rows(th, 2 * R), _round_up(w + 2 * R, _LANE)
+    strip = _strip_shape(interpret)
     return {
         "kernel": "sobel_bilateral",   # the pallas_call's name in a trace
         "impl": "pallas",
@@ -543,6 +718,11 @@ def sobel_bilateral_plan(shape, d: int = 5, tile_h: Optional[int] = None,
         "slab_rows": slab,             # rows DMA'd a grid step (tile + halo, 8-aligned)
         "w_aligned": w_al,             # columns DMA'd (W + halo, 128-aligned)
         "vmem_scratch_bytes": slab * w_al * 4,
+        # rows x lanes of the tile whose taps run at a time and the d
+        # column-shifted copies of the edge map they read (PR 46; None and
+        # 0 in interpret mode: the whole tile at once, no copies)
+        "strip": list(strip) if strip else None,
+        "vmem_shifted_bytes": math.prod(_shifted_shape(th, d // 2, w)) * 4 if strip else 0,
         # None: Mosaic's default scoped-VMEM limit (16 MiB)
         "vmem_limit_bytes": _stencil_vmem_limit(tile_h, interpret, d * d),
         "compute_dtype": "float32",
@@ -583,7 +763,9 @@ def sobel_bilateral_nhwc_pallas(
     44). ``stencil_prep`` takes the Rec.601 luma of the NHWC batch (luma is
     pointwise, so it commutes with every pad) and pads that plane, an axis
     a concatenate, to ``[b, h_pad + halo, w_aligned]``; ``stencil_kernel``
-    DMAs one-plane slabs and stores the filtered edge map once;
+    DMAs one-plane slabs and stores the filtered edge map once (compiled,
+    strip by strip of ``plan["strip"]``, the taps' accumulators in
+    registers: PR 46, :func:`_sobel_bilateral_kernel`);
     ``stencil_finish`` slices the map and broadcasts it to the frame's
     channels, so behind it the Engine rounds one plane, not three equal
     ones. The ``jax.named_scope``s put each part into a compiled step's
@@ -603,7 +785,7 @@ def sobel_bilateral_nhwc_pallas(
         x = _reflect_fill(x, 2, R, w_al - (w + 2 * R))
 
     kernel = _sobel_bilateral_kernel(th, r, w, sigma_color, sigma_space,
-                                     magnitude_scale)
+                                     magnitude_scale, plan["strip"])
     with jax.named_scope("stencil_kernel"):
         out = pl.pallas_call(
             kernel,
@@ -613,6 +795,8 @@ def sobel_bilateral_nhwc_pallas(
             out_shape=jax.ShapeDtypeStruct((b, h_pad, w), batch.dtype),
             scratch_shapes=[
                 pltpu.VMEM((plan["slab_rows"], w_al), jnp.float32),
+                *([pltpu.VMEM(_shifted_shape(th, r, w), jnp.float32)]
+                  if plan["strip"] else []),
                 pltpu.SemaphoreType.DMA,
             ],
             compiler_params=_vmem_params(plan["vmem_limit_bytes"]),

@@ -79,8 +79,9 @@ def measured_default(winners: Dict[str, str], fallback: str) -> str:
 # until ROADMAP D4 replaces them with a ledgered A/B on the chip
 # ("flow_inner" is the first that was: PR 27's chip runs; "sobel_bilateral"
 # the first stencil pair measured: PR 43's chip runs, 2026-10-01, whose
-# figures ops/chains.py::sobel_bilateral quotes — the winner stands, and
-# this map is not changed by a measurement that confirms it).
+# figures ops/chains.py::sobel_bilateral quotes beside the fused step's
+# since, 34.3 ms a batch of 64 on 2026-10-02, PR 46 — the winner stands,
+# and this map is not changed by a measurement that confirms it).
 #
 # Schema per key:
 #   comparison    — the impl_comparisons key benchmarks/run_table.py writes

@@ -240,28 +240,50 @@ def test_pallas_fused_sobel_bilateral_padded_rows():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("d", [5, 9])
-@pytest.mark.parametrize("geometry", ["padded_rows", "no_filler_cols"])
-def test_pallas_fused_sobel_bilateral_one_plane_form(d, geometry):
+# geometry -> (batch, h, w or None for "W + 2R a lane multiple", tile_h)
+_FUSED_GEOMETRIES = {
+    "padded_rows": (2, 36, 48, None),          # h_pad > h: filler rows under the last tile, filler columns
+    "no_filler_cols": (2, 32, None, None),     # the column concatenate has no fourth part
+    "w100_tile8": (2, 16, 100, 8),             # one lane tile, cut at column 100; a tile of one 8-row strip
+    "w1968_tile24": (1, 48, 1968, 24),         # the cell's tile; five whole 384-lane chunks and a 48-column rest
+    "whole_h_20": (2, 20, 130, None),          # one whole-H tile: two 8-row strips and a 4-row rest
+}
+
+
+@pytest.mark.parametrize("form", ["whole_tile", "strips"])
+@pytest.mark.parametrize("d", [3, 5, 9])
+@pytest.mark.parametrize("geometry", list(_FUSED_GEOMETRIES))
+def test_pallas_fused_sobel_bilateral_one_plane_form(d, geometry, form, monkeypatch):
     """The one-plane form (PR 44: luma first, then the reflected strips and
     zero filler an axis a concatenate) against the jnp chain, where the
     filler is on the path (36 rows: ``h_pad > h``, filler rows under the
     last tile and filler columns beside every one) and where the column
     filler has zero width (W + 2R a multiple of 128, so the column
     concatenate has no fourth part). The map is made once: the three
-    channels of the result are bit-equal."""
-    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas, sobel_bilateral_plan
+    channels of the result are bit-equal.
 
+    ``strips`` is the form the compiled kernel takes (PR 46: the taps run
+    over 8 x 384 pieces of the tile, on column-shifted copies of the edge
+    map), pinned here in interpret mode, which by itself keeps the whole
+    tile at once: at strip edges that do not divide evenly (a width under
+    one chunk, 1920 + 48, a last row strip of 4) and at every ``dy`` of a
+    3, 5 and 9 window (the rows' sublane offsets)."""
+    from dvf_tpu.ops import pallas_kernels as pk
+
+    if form == "strips":
+        monkeypatch.setattr(pk, "_strip_shape", lambda interpret: pk._STRIP)
     R = d // 2 + 1
-    h, w = (36, 48) if geometry == "padded_rows" else (32, 128 - 2 * R)
-    plan = sobel_bilateral_plan((2, h, w, 3), d, interpret=True)
+    b, h, w, tile_h = _FUSED_GEOMETRIES[geometry]
+    w = w or 128 - 2 * R
+    plan = pk.sobel_bilateral_plan((b, h, w, 3), d, tile_h, interpret=True)
     assert (plan["h_pad"] > h) == (geometry == "padded_rows")
     assert (plan["w_aligned"] == w + 2 * R) == (geometry == "no_filler_cols")
+    assert plan["strip"] == (list(pk._STRIP) if form == "strips" else None)
     rng = np.random.default_rng(17 + d)
-    batch = jnp.asarray(rng.random((2, h, w, 3), dtype=np.float32))
+    batch = jnp.asarray(rng.random((b, h, w, 3), dtype=np.float32))
     want, _ = get_filter("sobel_bilateral", d=d, impl="chain").fn(batch, None)
-    got = np.asarray(sobel_bilateral_nhwc_pallas(batch, d=d, interpret=True))
-    assert got.shape == (2, h, w, 3)
+    got = np.asarray(pk.sobel_bilateral_nhwc_pallas(batch, d=d, tile_h=tile_h, interpret=True))
+    assert got.shape == (b, h, w, 3)
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
     assert np.array_equal(got[..., 0], got[..., 1]) and np.array_equal(got[..., 0], got[..., 2])
 

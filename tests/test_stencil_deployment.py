@@ -214,7 +214,10 @@ def test_bucket_row_states_the_kernel_and_its_tiling(d, geometry):
         "kernel": "sobel_bilateral", "impl": "pallas", "taps": d * d, "planes": 1, "tile_h": th,
         "h_pad": h_pad, "grid": [BATCH, h_pad // th], "slab_rows": slab, "w_aligned": w_al,
         "vmem_scratch_bytes": slab * w_al * 4, "vmem_limit_bytes": None,
-        "compute_dtype": "float32"}
+        "compute_dtype": "float32",
+        # PR 46: compiled, the taps run in register-sized strips over d column-shifted copies of the
+        # edge map; interpret mode (this CPU) keeps the whole tile at once and holds no copies
+        "strip": None, "vmem_shifted_bytes": 0}
     assert block == fe._buckets[0].engine.kernel_plan
     json.dumps(block)                                    # plain data: stats() is serialised
     spans = [e for e in fe.tracer._events if e["name"] == "dispatch:assemble_h2d"]
@@ -325,6 +328,10 @@ def test_plan_at_the_cells_shape():
     assert (plan["slab_rows"], plan["w_aligned"]) == (40, 2048)
     # one plane a grid step, in and out (PR 44): the luma's slab, the edge map's block
     assert plan["planes"] == 1 and plan["vmem_scratch_bytes"] == 40 * 2048 * 4
+    # the taps' strips tile the 24 x 1920 block exactly: 3 x 5 of 8 x 384, three vregs an array (PR 46),
+    # over nine shifted copies of the 32 map rows a tile's taps reach
+    assert plan["strip"] == [8, 384]
+    assert plan["vmem_shifted_bytes"] == 9 * 32 * 1920 * 4
     with pytest.raises(ValueError):
         pk.sobel_bilateral_plan((64, 1080, 1920, 3), 8)
 
@@ -342,3 +349,34 @@ def test_costs_follow_the_configurations_window_and_geometry(costs):
     five = costs.kernel_cost(_config(toy=False, d=5), 64)
     assert five["bytes"] == kernel["bytes"] and five["flops"] == (19 + 8 * 25 + 1) * pixels
     assert costs.cost(cfg, 32)["flops"] * 2 == step["flops"]
+
+
+# -- the kernel probe's reading of a schedule (scripts/stencil_kernel_probe.py) ------------------------------------
+
+_BUNDLES = """\
+// kernel: sobel_bilateral.1
+     0x0   :  { %s1_s0 = smov 0 }
+     0x1 LB: > { %v1_v0 = vld [vmem:[%s0_s1] sm:$0xff]  ;;  %s2_s2 = sphi %s1_s0, %s3_s2 /* phi copy */ }
+     0x2 LB: >> { %v2_v1 = vld [vmem:[#allocation3_spill] sm:$0xff]  ;;  %4169 = vpow2.f32 %v2_v1 }
+     0x3   : >> { %v4_v2 = vpop.eup %4169  ;;  %p5_p1 = scmp.ge.s32.totalorder %s4_s3, 5 /* loop exit test */ }
+     0x4   : >> { %9 = vst [vmem:[#allocation3_spill] sm:$0xff] /*vst_source=*/%v4_v2  ;;  %7 = sbr.rel (!%p5_p1) target bundleno = 39 (0x27), region = 60 }
+     0x5   : > { %v8_v3 = vmul.f32 %v1_v0, %v1_v0  ;;  %10 = vst [vmem:[%s9_s4] sm:$0xff] /*vst_source=*/%v8_v3  ;;  %p9_p2 = scmp.ge.s32.totalorder %s2_s2, 2881 /* loop exit test */ }
+     0x6   :  { %13 = sbr.rel (!%p9_p2) target bundleno = 1 (0x1), region = 93 }
+"""
+
+
+def test_probe_counts_a_grid_steps_bundles_with_inner_loops_times_their_trips():
+    """A grid step of the listing: one bundle, a three-bundle inner loop of
+    five trips, two bundles after it. Spills are the loads and stores that
+    address ``#allocation*_spill``; a listing with no loop is refused."""
+    probe = _load("scripts/stencil_kernel_probe.py", "stencil_kernel_probe")
+    counts, loops = probe.read_bundles(_BUNDLES)
+    assert counts == {"bundles": 1 + 3 * 5 + 2, "vld": 1 + 5, "vld_spill": 5, "vpow2": 5, "vst": 5 + 1,
+                      "vst_spill": 5, "vmul": 1}
+    assert loops == [{"first": 2, "last": 4, "trips": 5, "bundles": 3}]
+    with pytest.raises(ValueError):
+        probe.read_bundles("     0x0   :  { %s1_s0 = smov 0 }\n")
+    vmem = probe.scoped_vmem("#allocation2 [shape = 'f32[40,2048]{1,0}', space=vmem, size = 0x50000, scoped]\n"
+                             "#allocation3_spill [shape = 'u8[4096]{0}', space=vmem, size = 0x1000, scoped]\n"
+                             "#allocation9 [shape = 's32[1]{0}', space=sflag, size = 0x4, scoped]\n")
+    assert vmem == {"scratch_bytes": 0x50000, "spill_bytes": 0x1000, "bytes": 0x51000}

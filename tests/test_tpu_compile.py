@@ -110,48 +110,60 @@ _STENCIL_SHAPE = (2, 1080, 1920, 3)
 _stencil_texts = {}
 
 
-def _stencil_step_text(one_chip, d, tile_h):
+def _stencil_step_text(one_chip, d, tile_h, vmem="plan"):
     """The compiled text of the fused Sobel -> bilateral step as the Engine
     wraps it (uint8 in, uint8 out), for the described v5e; one compile a
-    (d, tile_h) for the tests below."""
-    from dvf_tpu.ops.pallas_kernels import sobel_bilateral_nhwc_pallas
+    (d, tile_h, vmem) for the tests below. ``vmem="default"``: with
+    ``_stencil_vmem_limit`` answering None, Mosaic's own 16 MiB of scope."""
+    from dvf_tpu.ops import pallas_kernels as pk
     from dvf_tpu.utils.image import to_float, to_uint8
 
-    if (d, tile_h) not in _stencil_texts:
+    if (d, tile_h, vmem) not in _stencil_texts:
         def step(batch):
-            return to_uint8(sobel_bilateral_nhwc_pallas(to_float(batch), d=d, tile_h=tile_h))
+            return to_uint8(pk.sobel_bilateral_nhwc_pallas(to_float(batch), d=d, tile_h=tile_h))
 
         batch = jax.ShapeDtypeStruct(_STENCIL_SHAPE, jnp.uint8, sharding=one_chip)
         cache_was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            _stencil_texts[d, tile_h] = jax.jit(step).lower(batch).compile().as_text()
+            with pytest.MonkeyPatch.context() as patch:
+                if vmem == "default":
+                    patch.setattr(pk, "_stencil_vmem_limit", lambda tile_h, interpret, taps: None)
+                _stencil_texts[d, tile_h, vmem] = jax.jit(step).lower(batch).compile().as_text()
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was)
-    return _stencil_texts[d, tile_h]
+    return _stencil_texts[d, tile_h, vmem]
 
 
-@pytest.mark.parametrize("d,tile_h", [(5, None), (9, None), (9, 24)], ids=["d5", "d9", "d9_pinned"])
-def test_stencil_kernel_compiles_through_mosaic_at_1080p(one_chip, d, tile_h):
+@pytest.mark.parametrize("d,tile_h,vmem", [(5, None, "plan"), (9, None, "plan"), (9, 24, "plan"),
+                                           (9, None, "default"), (9, 24, "default")],
+                         ids=["d5", "d9", "d9_pinned", "d9_default_vmem", "d9_pinned_default_vmem"])
+def test_stencil_kernel_compiles_through_mosaic_at_1080p(one_chip, d, tile_h, vmem):
     """The fused Sobel -> bilateral kernel at 1080 x 1920 for the described
-    v5e, as the Engine's step wraps it. At d 9 (81 taps) the unrolled
-    temporaries need 26.33 MB of scoped VMEM at the auto tile of 24 rows:
-    under Mosaic's default 16 MiB the unpinned kernel did not compile
-    (RESOURCE_EXHAUSTED) before PR 43, which interpret mode on the CPU
-    never sees. The kernel is in the step under its own name, inside its
-    scope, with the limit ``sobel_bilateral_plan`` states, and its result
-    is the ONE plane of the edge map (PR 44; three equal ones before)."""
+    v5e, as the Engine's step wraps it. The kernel is in the step under its
+    own name, inside its scope, with the limit ``sobel_bilateral_plan``
+    states, and its result is the ONE plane of the edge map (PR 44; three
+    equal ones before).
+
+    The ``default_vmem`` cases: at d 9 (81 taps) the whole-tile form's
+    unrolled temporaries needed 26.33 MB of scoped VMEM at the tile of 24
+    rows, so under Mosaic's default 16 MiB the kernel did not compile
+    (RESOURCE_EXHAUSTED, before PR 43 raised the limit; interpret mode on
+    the CPU never sees it). In strips (PR 46) the call holds 3.25 MB, the
+    slab, the nine shifted copies of the map and 0.34 MB of spill slots:
+    it compiles with no limit raised."""
     from dvf_tpu.ops.pallas_kernels import sobel_bilateral_plan
 
-    text = _stencil_step_text(one_chip, d, tile_h)
+    text = _stencil_step_text(one_chip, d, tile_h, vmem)
     (call,) = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
     assert re.match(r"\s*%sobel_bilateral(\.\d+)? = f32\[2,1080,1920\]", call), call[:120]
     assert 'op_name="jit(step)/stencil_kernel/sobel_bilateral/pallas_call"' in call
     plan = sobel_bilateral_plan(_STENCIL_SHAPE, d, tile_h)
     assert (plan["tile_h"], plan["slab_rows"], plan["w_aligned"]) == (24, 32 if d == 5 else 40, 2048)
     assert plan["planes"] == 1 and plan["vmem_scratch_bytes"] == plan["slab_rows"] * 2048 * 4
+    assert plan["strip"] == [8, 384] and plan["vmem_shifted_bytes"] == d * 32 * 1920 * 4
     raised = re.search(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', call)
-    assert (int(raised.group(1)) if raised else None) == plan["vmem_limit_bytes"]
+    assert (int(raised.group(1)) if raised else None) == (plan["vmem_limit_bytes"] if vmem == "plan" else None)
     assert plan["vmem_limit_bytes"] == (None if d == 5 else 64 * 1024 * 1024)
 
 
