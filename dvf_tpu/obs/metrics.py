@@ -201,6 +201,19 @@ class IngestStats:
       ``submit_resident`` call, row map included (on the monolithic path
       the engine's own blocking ``device_put`` is inside it).
 
+    The row path (PR 47; ``runtime/ingest.py``): a batch that went up
+    as B rows from the clients' own arrays books 0 ms of ``stage`` (no
+    host copy), its B ``device_put``s under ``put_ms`` and
+    ``ingest_join``'s dispatch under ``join_ms`` (where the slab path
+    books its concatenate's); ``rows_direct_total`` /
+    ``direct_batches`` count its frames and batches, ``rows_staged_total``
+    / ``staged_batches`` those that went through the slabs (frames are
+    the batch's valid rows either way), and ``row_path`` says the row
+    path exists for this signature. ``bytes_total`` stays what
+    crossed the link: the whole padded batch of a slab, the valid rows
+    of a row-path batch (a padding row is the last valid row's device
+    array again).
+
     Who sums which: ``overlap_efficiency`` below counts ``put + wait`` as
     the exposed H2D; the benchmark's ``ingest_exposed_ms`` reads ``stage +
     wait`` (the copy into the slabs ADDED to the wait for the link, the
@@ -244,14 +257,27 @@ class IngestStats:
         self.wait_ms_total = 0.0
         self.join_ms_total = 0.0
         self.step_dispatch_ms_total = 0.0  # DeviceLane.submit's own
-        self.bytes_total = 0       # bytes staged to the device (whole
-        #   padded batches: what crossed the link, not the valid rows)
+        self.bytes_total = 0       # bytes staged to the device (what
+        #   crossed the link: a slab's whole padded batch, the valid
+        #   rows of a row-path batch)
+        self.row_path = False      # the row path's program exists
+        self.rows_direct_total = 0   # frames put from the client's array
+        self.rows_staged_total = 0   # frames copied into a slab
+        self.direct_batches = 0
+        self.staged_batches = 0
 
     def record_batch(self, stage_ms: float, put_ms: float,
                      wait_ms: float, nbytes: int = 0,
-                     join_ms: float = 0.0) -> None:
+                     join_ms: float = 0.0, rows: int = 0,
+                     direct: bool = False) -> None:
         self.batches += 1
         self.bytes_total += nbytes
+        if direct:
+            self.rows_direct_total += rows
+            self.direct_batches += 1
+        else:
+            self.rows_staged_total += rows
+            self.staged_batches += 1
         self.stage_ms_total += stage_ms
         self.put_ms_total += put_ms
         self.wait_ms_total += wait_ms
@@ -291,6 +317,11 @@ class IngestStats:
             "join_ms_total": round(self.join_ms_total, 4),
             "step_dispatch_ms_total": round(self.step_dispatch_ms_total, 4),
             "bytes_total": self.bytes_total,
+            "row_path": self.row_path,
+            "rows_direct_total": self.rows_direct_total,
+            "rows_staged_total": self.rows_staged_total,
+            "direct_batches": self.direct_batches,
+            "staged_batches": self.staged_batches,
             "h2d_block_ms": (round(self.h2d_block_ms, 4)
                              if self.h2d_block_ms else None),
             "overlap_efficiency": (round(eff, 4)

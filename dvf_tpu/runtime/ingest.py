@@ -19,15 +19,36 @@ Timeline, monolithic vs streamed (one batch of 4 shards):
                  H2D       ████ ████ ████ ████          (per shard,
                  compute ░░░░ batch k−1 ░░░░░░░         overlapped)
 
-Which path runs where (ledger, PRs 26–28): all four benchmark cells
-serve one chip streamed, ``ingest_depth`` row-chunks a batch. What the
-chip showed of it: ``assemble_h2d`` is 82.6 ms a batch of 32 1080p
-frames and paces ``invert_1080p.bulk`` (PERF.md §5), 49 ms of it exposed
-staging and transfer wait: ``uint8[B,H,W,3]`` is de-interleaved on the
-host inside ``device_put``. The H2D mirror of egress's packed transfer
-layout would live here and in :mod:`dvf_tpu.runtime.lane`, which builds
-the assembler (mode, depth, slots, when it is rebuilt, what a repeated
-fault degrades to); no caller constructs one.
+**The row path (PR 47): a submitted frame goes up from the buffer the
+client gave.** A ``uint8[B,H,W,3]`` slab costs the host twice: the
+``np.copyto`` of every frame into it on the dispatch thread (199 MB a
+batch of 32 1080p frames, 25 ms), and the runtime's own de-interleave
+behind ``device_put`` (the chip holds such an array channel-planar),
+which takes one thread a transfer: four slab chunks stream at 6.0–8.2
+GB/s where B frames, a transfer each, stream at 13.5–13.9 (PERF.md §7,
+``scripts/h2d_probe.py``: ``S4`` against ``R3``). So where a batch is
+one shard on one device, :meth:`BatchBuilder.put_rows` puts each frame
+of the plan on the chip *from the client's own array*, as the
+``uint8[H,W,C]`` it is: a transfer a frame and no copy of ours on the
+host (the runtime's own copy into its transfer buffers, 0.3–0.6 ms a
+1080p frame inside the call, is the one pass left), and ``finish``
+hands the B device frames to :func:`ingest_join` (module
+``jit_ingest_join``, compiled when the assembler is built), the slab
+path's concatenate with B operands where it has ``depth``: the
+``uint8[B,H,W,C]`` batch the step is compiled for, bit for bit the slab
+path's, at the same cost to the chip. A padding row of a short batch is
+the last valid row's device array again: padding never crosses the
+link. ``IngestStats`` counts ``rows_direct_total`` /
+``rows_staged_total`` and the batches of each. Eligible is read off the
+input and the plan (:meth:`ShardedBatchAssembler._plan_rows`,
+:meth:`BatchBuilder.put_rows`): uint8 frames of three dimensions, every
+one C-contiguous at the batch's geometry, the whole batch one shard on
+one device, streamed mode; anything else, and the decoders that fill
+``window_view`` (``runtime/pipeline.py``, ``transport/zmq_ingress.py``),
+keep ``write_row`` and the slabs below byte for byte. Who builds the
+assembler (mode, depth, slots, when it is rebuilt, what a repeated fault
+degrades to: monolithic, which has no row path) is
+:mod:`dvf_tpu.runtime.lane`; no caller constructs one.
 
 Shard granularity follows the engine's input sharding:
 
@@ -59,6 +80,7 @@ and the burstiness of the H2D queue.
 
 from __future__ import annotations
 
+import functools
 import time
 import weakref
 from typing import Any, Dict, List, Optional, Tuple
@@ -79,6 +101,34 @@ INGEST_MODES = ("streamed", "monolithic")
 # prints the mode each config's bucket actually took. Tests that
 # exercise the streaming machinery at tiny sizes monkeypatch this to 0.
 MIN_STREAM_H2D_MS = 2.0
+
+# -- the row path's program ------------------------------------------------
+
+def ingest_join(*frames):
+    """B device frames ``uint8[H,W,C]`` → the ``uint8[B,H,W,C]`` batch:
+    the slab path's ``jnp.concatenate`` with a frame an operand (1.3–1.9
+    ms of the chip for 199–398 MB, as the four-chunk join costs:
+    ``scripts/h2d_probe.py``, ``J4``)."""
+    import jax.numpy as jnp
+
+    return jnp.stack(frames)
+
+
+@functools.lru_cache(maxsize=16)
+def _compiled_join(batch_shape: Tuple[int, ...], device):
+    """:func:`ingest_join` for one batch signature on one device:
+    lowered and compiled here, when an assembler is built (through the
+    persistent cache), never on a first batch; cached so a hot swap back
+    to a known signature and a rebuilt assembler compile nothing. The
+    mirror of ``egress._compiled_pack``."""
+    import jax
+    import jax.numpy as jnp
+
+    frame = jax.ShapeDtypeStruct(
+        batch_shape[1:], jnp.uint8,
+        sharding=jax.sharding.SingleDeviceSharding(device))
+    return jax.jit(ingest_join).lower(*(frame,) * batch_shape[0]).compile()
+
 
 # Host-slab accounting registry (obs.memory): every live assembler is
 # weakly tracked so the scrape-time gauges — and the conftest
@@ -180,8 +230,13 @@ class ShardedBatchAssembler:
         self._device_order: List[Any] = []
         self._mono_pool: Optional[List[np.ndarray]] = None
         self._scratch: Optional[np.ndarray] = None  # general-path decode buf
+        self._join = None  # ingest_join's executable where the row path
+        #   can run; the slabs exist beside it either way
         self.effective_mode = self._plan()
         self.stats.effective_mode = self.effective_mode
+        if self.effective_mode == "streamed":
+            self._join = self._plan_rows()
+        self.stats.row_path = self._join is not None
         self.stats.pool_allocs += 1
         _LIVE_ASSEMBLERS.add(self)
 
@@ -268,6 +323,23 @@ class ShardedBatchAssembler:
                 self._chunk_of_row[r] = i
         return "streamed"
 
+    def _plan_rows(self):
+        """The row path's program where a frame can go up as it is and
+        the batch be made on the chip: a uint8 NHWC batch held whole as
+        one shard on one device (the one-chip replica). Anything else
+        keeps the slabs alone (the mirror of the fetcher's
+        ``_plan_pack``)."""
+        if (self.dtype != np.uint8 or len(self.batch_shape) != 4
+                or len(self.sharding.device_set) != 1):
+            return None
+        import jax
+
+        try:
+            return _compiled_join(self.batch_shape,
+                                  next(iter(self.sharding.device_set)))
+        except jax.errors.JaxRuntimeError:  # a program the device cannot
+            return None  # build or hold: stay correct on the slab path
+
     def _plan_monolithic(self, reason: Optional[str] = None) -> str:
         self.stats.fallback_reason = reason
         self._mono_pool = [
@@ -307,6 +379,7 @@ class ShardedBatchAssembler:
         self._device_order = []
         self._mono_pool = None
         self._scratch = None
+        self._join = None
 
 
 class BatchBuilder:
@@ -321,12 +394,95 @@ class BatchBuilder:
         self._filled = [0] * len(asm._chunks) if self._streamed else [0]
         self._parts: Dict[Any, List[Any]] = {d: [] for d in asm._device_order}
         self._inflight: List[List[Any]] = []
+        self._rows: Optional[List[Any]] = None  # the row path: the
+        #   plan's frames on the device, from put_rows to finish
+        self._join = None  # ... and the program that takes them
         self._stage_s = 0.0
         self._put_s = 0.0
         self._wait_s = 0.0
         self._join_s = 0.0
         self._first_put_t: Optional[float] = None
         self._t_begin = time.perf_counter()
+        self.direct = False  # put_rows took the batch: it goes up as rows
+
+    # -- the row path ----------------------------------------------------
+
+    def put_rows(self, frames) -> bool:
+        """Put the batch's frames (its valid rows, in order) on the chip
+        from the arrays they are in: a transfer a frame (one
+        ``device_put`` call for the list: 10–15% cheaper on this thread
+        than a call a frame, ``h2d_probe.py``'s ``Rl``), no copy of ours
+        on the host, nothing written to a frame. False, with nothing
+        done, where the row path cannot take this batch: the assembler
+        has none (see ``ShardedBatchAssembler._plan_rows``), or a frame
+        is not a C-contiguous uint8 array at the batch's geometry (a
+        strided view, another dtype); the caller then stages through
+        ``write_row``. A frame must stay as it is until its transfer has
+        landed: the caller's contract with its clients (``Session.submit``:
+        until the frame's result is delivered)."""
+        asm = self.asm
+        join = asm._join  # read once: release() may clear it
+        if join is None or self.direct or any(self._filled):
+            return False
+        frame_shape = asm.batch_shape[1:]
+        if not frames or len(frames) > asm.batch_shape[0] or not all(
+                isinstance(f, np.ndarray) and f.dtype == np.uint8
+                and f.shape == frame_shape and f.flags.c_contiguous
+                for f in frames):
+            return False
+        import jax
+
+        if asm.chaos is not None:
+            asm.chaos.fire("h2d")  # one batch, one firing (the slab path
+            #   fires a chunk)
+        dev = next(iter(asm.sharding.device_set))
+        t0 = time.perf_counter()
+        self._first_put_t = t0
+        try:
+            rows = jax.device_put(list(frames), dev)
+        except Exception as e:  # noqa: BLE001 — as _launch: containment
+            # classifies it as h2d and can degrade the lane
+            raise FaultError(
+                FaultKind.H2D,
+                f"row device_put failed for a batch of {len(frames)}: "
+                f"{e!r}") from e
+        t1 = time.perf_counter()
+        self._put_s += t1 - t0
+        self._rows, self._join, self.direct = rows, join, True
+        tracer = asm.tracer
+        if tracer is not None and tracer.enabled:
+            off = asm.wall_offset_s
+            tracer.complete(INGEST_H2D, t0 + off, t1 + off, asm.track,
+                            rows=f"0:{len(rows)}", direct=True,
+                            bytes=sum(f.nbytes for f in frames))
+        return True
+
+    def _finish_rows(self, valid: int):
+        """The row path's ``finish``: ``ingest_join`` over the device
+        frames, a padding row being the last valid one's again."""
+        import jax
+
+        asm = self.asm
+        rows, self._rows = self._rows, None  # the device frees a frame
+        #   once the join has read it, not when this builder goes
+        if len(rows) != valid:
+            raise ValueError(f"valid={valid} but {len(rows)} rows were put")
+        t_join = time.perf_counter()
+        pad = asm.batch_shape[0] - valid
+        # self._join is put_rows' own: a release of the assembler
+        # meanwhile takes nothing from a batch under way
+        batch = jax.make_array_from_single_device_arrays(
+            asm.batch_shape, asm.sharding,
+            [self._join(*rows, *(rows[-1],) * pad)])
+        t_end = time.perf_counter()
+        self._join_s = t_end - t_join
+        tracer = asm.tracer
+        if tracer is not None and tracer.enabled:
+            off = asm.wall_offset_s
+            tracer.complete(INGEST_OVERLAP, self._first_put_t + off,
+                            t_end + off, asm.track, valid=valid)
+        self._record(valid)
+        return batch, True
 
     # -- row staging -----------------------------------------------------
 
@@ -459,13 +615,15 @@ class BatchBuilder:
         b = self.asm.batch_shape[0]
         if not (0 < valid <= b):
             raise ValueError(f"valid={valid} out of range for batch {b}")
+        if self.direct:
+            return self._finish_rows(valid)
         if not self._streamed:
             t0 = time.perf_counter()
             buf = self._mono_buf()
             for row in range(valid, b):
                 np.copyto(buf[row], buf[valid - 1])
             self._stage_s += time.perf_counter() - t0
-            self._record()
+            self._record(valid)
             return buf, False
         # Pad from the already-staged slabs: the source row's chunk may
         # be launched (its slab is only read), the destination rows are
@@ -507,14 +665,19 @@ class BatchBuilder:
             tracer.complete(INGEST_STAGE, self._t_begin + off, t_end + off,
                             self.asm.track,
                             stage_ms=round(self._stage_s * 1e3, 3))
-        self._record()
+        self._record(valid)
         return batch, True
 
-    def _record(self) -> None:
-        self.asm.stats.record_batch(
+    def _record(self, valid: int) -> None:
+        asm, direct = self.asm, self.direct
+        asm.stats.record_batch(
             stage_ms=self._stage_s * 1e3,
             put_ms=self._put_s * 1e3,
             wait_ms=self._wait_s * 1e3,
-            nbytes=self.asm.batch_nbytes,
+            # What crossed the link: a slab's whole padded batch, the
+            # valid rows of a row-path batch.
+            nbytes=(asm.batch_nbytes // asm.batch_shape[0] * valid
+                    if direct else asm.batch_nbytes),
             join_ms=self._join_s * 1e3,
+            rows=valid, direct=direct,
         )

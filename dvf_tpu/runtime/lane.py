@@ -155,8 +155,11 @@ class DeviceLane:
               seq: int) -> BatchBuilder:
         """Start staging batch number ``seq`` (monotone; its slot). The
         assembler's own builder comes back: ``write_row`` runs once a
-        frame on the thread that paces the host-bound cells. A new batch
-        signature, ingest depth or mode rebuilds the assembler."""
+        frame on the thread that paces the host-bound cells, or
+        ``put_rows`` once a batch where the frames may go up from the
+        arrays they are in (the row path, ``runtime/ingest.py``: its
+        ``ingest_join`` is compiled here, with the assembler). A new
+        batch signature, ingest depth or mode rebuilds the assembler."""
         shape, dtype = tuple(batch_shape), np.dtype(dtype)
         mode, reason = self._mode(FaultKind.H2D)
         depth = self.options.ingest_depth

@@ -3504,8 +3504,16 @@ class ServeFrontend:
                         bucket.frame_dtype, seq)
                     if tracer.enabled:
                         split = lane.ingest_stats.split_ms()
-                    for row, slot in enumerate(plan.slots):
-                        builder.write_row(row, slot.frame)
+                    # Rows or slab (runtime/ingest.py, the row path):
+                    # the frames go up from the clients' own arrays, a
+                    # put a frame and no copy, where the lane and every
+                    # frame of the plan allow; else through the slabs.
+                    frames = [slot.frame for slot in plan.slots]
+                    if not builder.put_rows(frames):
+                        for row, frame in enumerate(frames):
+                            builder.write_row(row, frame)
+                    del frames
+                    for slot in plan.slots:
                         slot.frame = None  # drop the client's buffer
                     # plan.rows: a session-state filter's row map (who
                     # is in the batch); None for every other filter.
@@ -3555,6 +3563,7 @@ class ServeFrontend:
                                     st.t_submit, TRACK_DISPATCH, seq=seq,
                                     sessions=n_sess, out_bytes=out_bytes,
                                     kernel=plan_k and plan_k["kernel"],
+                                    direct=builder.direct,
                                     stage_ms=stage_ms, put_ms=put_ms,
                                     wait_ms=wait_ms, join_ms=join_ms,
                                     step_dispatch_ms=step_ms)

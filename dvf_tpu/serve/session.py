@@ -229,8 +229,10 @@ class StreamSession:
 
         Never blocks: a full ingress queue evicts the oldest frame
         (drop-oldest, distributor.py:193-203 semantics). The frame array
-        is referenced, not copied, until the batcher stages it — callers
-        that reuse their capture buffer must pass a copy.
+        is referenced, not copied, until the frame's result is delivered
+        (where the batch goes up as rows the chip reads the array itself:
+        ``runtime/ingest.py``, the row path) — callers that reuse their
+        capture buffer must pass a copy. It is never written to.
         """
         ts = time.time() if ts is None else ts
         lin = None

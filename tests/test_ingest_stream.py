@@ -12,6 +12,11 @@ Three properties guard the tentpole:
 3. **Allocation regression** — the steady-state hot loop performs ZERO
    per-batch multi-100KB host allocations (the staging pools are actually
    reused) across the pipeline, serve, and zmq paths.
+4. **The row path** (PR 47) — ``put_rows`` + ``ingest_join`` make the
+   slab path's batch byte for byte from the clients' own arrays;
+   ``put_rows`` takes a batch only where it may, writes to no frame, and
+   everything else stays on the slabs, counted under
+   ``rows_staged_total``.
 """
 
 import time
@@ -171,6 +176,147 @@ class TestAssemblerEquivalence:
                                     mode="monolithic")
         with pytest.raises(ValueError, match="valid"):
             asm.begin(0).finish(0)
+
+
+def _frames_c(n, h, w, c, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 256, size=(h, w, c), dtype=np.uint8)
+            for _ in range(n)]
+
+
+class TestRowPath:
+    """``put_rows`` / ``ingest_join``: the batch from the clients' own
+    arrays, byte for byte what ``np.stack`` of the frames is."""
+
+    @pytest.mark.parametrize("channels", [3, 4])
+    @pytest.mark.parametrize("width", [1920, 1280, 960, 48])
+    def test_matches_np_stack_byte_for_byte(self, width, channels):
+        h, b = 8, 3
+        shape = (b, h, width, channels)
+        frames = _frames_c(b, h, width, channels, seed=width + channels)
+        # every byte value, and the extremes side by side
+        frames[0].reshape(-1)[:256] = np.arange(256, dtype=np.uint8)
+        frames[1][0, :2] = [[0] * channels, [255] * channels]
+        asm = ShardedBatchAssembler(
+            shape, np.uint8,
+            batch_sharding(make_mesh(MeshConfig(data=1)), shape), slots=1)
+        builder = asm.begin(0)
+        assert builder.put_rows(frames)
+        out, resident = builder.finish(b)
+        assert resident and out.shape == shape and out.dtype == np.uint8
+        np.testing.assert_array_equal(np.asarray(out), np.stack(frames))
+
+    @pytest.mark.parametrize("valid", [4, 3, 1])
+    def test_put_rows_makes_the_slab_paths_batch(self, valid):
+        """Full and short batches: a padding row is the last valid row
+        again, and the result equals ``write_row``'s bit for bit."""
+        shape = (4, 16, 48, 3)
+        sharding = batch_sharding(make_mesh(MeshConfig(data=1)), shape)
+        asm = ShardedBatchAssembler(shape, np.uint8, sharding, slots=2)
+        assert asm.effective_mode == "streamed" and asm.stats.row_path
+        frames = _rng_frames(valid, 16, 48, seed=valid)
+        keep = [f.copy() for f in frames]
+        for f in frames:
+            f.flags.writeable = False       # nothing may write to them
+        direct = asm.begin(0)
+        assert direct.put_rows(frames) is True
+        got, resident = direct.finish(valid)
+        assert resident and direct.direct
+        slab = asm.begin(1)
+        for row, f in enumerate(frames):
+            slab.write_row(row, f)
+        want, _ = slab.finish(valid)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      _padded_ref(keep, 4))
+        assert got.sharding.is_equivalent_to(sharding, 4)
+        for f, k in zip(frames, keep):
+            np.testing.assert_array_equal(f, k)
+        s = asm.stats.summary()
+        assert (s["rows_direct_total"], s["direct_batches"]) == (valid, 1)
+        assert (s["rows_staged_total"], s["staged_batches"]) == (valid, 1)
+        # what crossed the link: the valid rows, and a whole padded slab
+        assert s["bytes_total"] == (valid + 4) * 16 * 48 * 3
+        assert direct._rows is None         # the device rows went at finish
+
+    @pytest.mark.parametrize("case", [
+        "strided_frame", "readonly_ok", "float_frame", "wrong_geometry",
+        "two_devices", "float_batch", "monolithic", "too_many", "empty",
+    ])
+    def test_eligibility_is_read_off_the_input_and_the_lane(self, case):
+        """Everything the row path cannot take goes through the slabs
+        as before, and is counted there."""
+        dtype = np.float32 if case == "float_batch" else np.uint8
+        cfg = MeshConfig(data=2) if case == "two_devices" \
+            else MeshConfig(data=1)
+        shape = (4, 16, 48, 3)
+        asm = ShardedBatchAssembler(
+            shape, dtype, batch_sharding(make_mesh(cfg), shape), slots=2,
+            mode="monolithic" if case == "monolithic" else "streamed")
+        frames = _rng_frames(4, 16, 48, seed=5)
+        if case == "strided_frame":     # the door's downscale view
+            big = _rng_frames(1, 32, 96, seed=6)[0]
+            frames[2] = big[::2, ::2]
+            assert not frames[2].flags.c_contiguous
+        elif case == "readonly_ok":
+            frames[1].flags.writeable = False
+        elif case == "float_frame":
+            frames[0] = frames[0].astype(np.float32)
+        elif case == "wrong_geometry":
+            frames[3] = _rng_frames(1, 16, 24, seed=7)[0]
+        elif case == "float_batch":
+            frames = [f.astype(np.float32) for f in frames]
+        elif case == "too_many":
+            frames = frames + frames[:1]
+        elif case == "empty":
+            frames = []
+        built = case not in ("two_devices", "float_batch", "monolithic")
+        assert asm.stats.summary()["row_path"] is built
+        b = asm.begin(0)
+        took = b.put_rows(frames)
+        assert took is (case == "readonly_ok")
+        if case in ("float_frame", "wrong_geometry", "too_many", "empty"):
+            return                      # no batch of this assembler's
+        if not took:
+            for row, f in enumerate(frames):
+                b.write_row(row, f)
+        arr, _ = b.finish(4)
+        np.testing.assert_array_equal(
+            np.asarray(arr), np.stack([np.asarray(f) for f in frames]))
+        s = asm.stats.summary()
+        assert s["rows_direct_total"] == (4 if took else 0)
+        assert s["rows_staged_total"] == (0 if took else 4)
+        assert s["batches"] == 1
+
+    def test_h2d_chaos_site_fires_once_a_row_batch(self):
+        from dvf_tpu.resilience import FaultPlan
+        from dvf_tpu.resilience.faults import FaultKind, classify
+
+        shape = (4, 16, 48, 3)
+        chaos = FaultPlan().add("h2d", at=(1,))
+        asm = ShardedBatchAssembler(
+            shape, np.uint8,
+            batch_sharding(make_mesh(MeshConfig(data=1)), shape),
+            slots=2, chaos=chaos)
+        frames = _rng_frames(4, 16, 48)
+        assert asm.begin(0).put_rows(frames)            # firing 0
+        with pytest.raises(Exception) as e:             # firing 1
+            asm.begin(1).put_rows(frames)
+        assert classify(e.value, "dispatch") == FaultKind.H2D
+        assert chaos.summary()["fired"] == {"h2d:h2d": 1}
+
+    def test_compiled_join_is_cached_a_signature(self):
+        shape = (2, 8, 48, 3)
+        sharding = batch_sharding(make_mesh(MeshConfig(data=1)), shape)
+        a = ShardedBatchAssembler(shape, np.uint8, sharding, slots=1)
+        b = ShardedBatchAssembler(shape, np.uint8, sharding, slots=1)
+        assert a._join is not None and a._join is b._join
+        builder = a.begin(0)
+        assert builder.put_rows(_rng_frames(2, 8, 48))
+        a.release()         # takes nothing from a batch under way
+        assert a._join is None and b._join is not None
+        assert not a.begin(0).put_rows(_rng_frames(2, 8, 48))
+        assert np.asarray(builder.finish(2)[0]).shape == shape
 
 
 def test_assembler_equivalence_property():

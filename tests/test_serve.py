@@ -1123,3 +1123,193 @@ class TestRouterKeepsOneFrameAlive:
             row == kept_row for row in range(4)]
         (d,) = slow.poll()  # the slow client's frame is still whole
         np.testing.assert_array_equal(d.frame, frames[0])
+
+
+# ---------------------------------------------------------------------------
+# Slab or rows (PR 47): a submitted frame goes up from the client's array
+# ---------------------------------------------------------------------------
+
+
+def _one_chip_frontend(monkeypatch, rows=True, **config):
+    """A frontend whose batch is one shard on one device, streamed on the
+    CPU at the tests' sizes; ``rows`` False: no row path, as on a lane
+    whose batch spans devices (the slabs alone)."""
+    from dvf_tpu.parallel import MeshConfig, make_mesh
+    from dvf_tpu.runtime import Engine
+    from dvf_tpu.runtime import ingest as ingest_mod
+
+    monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    if not rows:
+        monkeypatch.setattr(ingest_mod.ShardedBatchAssembler, "_plan_rows",
+                            lambda self: None)
+    filt = get_filter("invert")
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    config.setdefault("slo_ms", 60_000.0)
+    return ServeFrontend(filt, ServeConfig(**config), engine=engine)
+
+
+def _ingest(fe):
+    return _bucket_row(fe)["ingest"]
+
+
+def _deliveries(fe, sid, n, deadline_s=30.0):
+    got, deadline = [], time.time() + deadline_s
+    while len(got) < n:
+        assert time.time() < deadline, f"{len(got)} of {n} delivered"
+        got.extend(fe.poll(sid))
+        time.sleep(0.002)
+    return got
+
+
+class TestSlabOrRows:
+    """runtime/ingest.py, the row path, as the serve loop drives it: which
+    batches go up as rows, and that nothing a client can observe moves."""
+
+    @pytest.mark.parametrize("rows", [True, False], ids=["rows", "slab"])
+    def test_both_paths_deliver_identical_frames(self, monkeypatch, rows):
+        fe = _one_chip_frontend(monkeypatch, rows, batch_size=4,
+                                queue_size=64, trace=True)
+        n = 22                                  # five full batches, one of 2
+        frames = {s: [tagged_frame(s, j) for j in range(n)] for s in (0, 1)}
+        keep = {s: [f.copy() for f in fs] for s, fs in frames.items()}
+        for fs in frames.values():
+            for f in fs:
+                f.flags.writeable = False       # nobody may write to a
+                #                                 client's array
+        deliveries = {}
+        with fe:
+            sids = [fe.open_stream() for _ in frames]
+            for j in range(n):
+                for s, sid in enumerate(sids):
+                    fe.submit(sid, frames[s][j])
+            drain(fe, sids, deliveries)
+            ingest = _ingest(fe)
+            spans = [e for e in fe.tracer._events
+                     if e["name"] == "dispatch:assemble_h2d"]
+        for s, sid in enumerate(sids):
+            got = deliveries[sid]
+            assert [d.index for d in got] == list(range(n))
+            for d in got:
+                np.testing.assert_array_equal(d.frame,
+                                              255 - keep[s][d.index])
+            for f, k in zip(frames[s], keep[s]):
+                np.testing.assert_array_equal(f, k)
+        assert ingest["mode"] == "streamed"
+        assert ingest["row_path"] is rows
+        direct, staged = ((2 * n, 0) if rows else (0, 2 * n))
+        assert ingest["rows_direct_total"] == direct
+        assert ingest["rows_staged_total"] == staged
+        assert ingest["direct_batches"] + ingest["staged_batches"] \
+            == ingest["batches"]
+        assert (ingest["stage_ms_total"] == 0.0) is rows   # no host copy
+        assert spans and all(e["args"]["direct"] is rows for e in spans)
+
+    @pytest.mark.parametrize("case,direct", [
+        ("contiguous", True),
+        ("readonly", True),         # read, never written to
+        ("strided", False),         # the door's downscale view
+        ("fortran_order", False),
+    ])
+    def test_one_ineligible_frame_sends_its_batch_through_the_slab(
+            self, monkeypatch, case, direct):
+        fe = _one_chip_frontend(monkeypatch, batch_size=2)
+        frames = [tagged_frame(0, j) for j in range(2)]
+        if case == "readonly":
+            frames[1].flags.writeable = False
+        elif case == "strided":
+            big = np.repeat(np.repeat(frames[1], 2, axis=0), 2, axis=1)
+            frames[1] = big[::2, ::2]
+        elif case == "fortran_order":
+            frames[1] = np.asfortranarray(frames[1])
+        assert frames[1].flags.c_contiguous is direct
+        with fe:
+            sid = fe.open_stream()
+            for f in frames:
+                fe.submit(sid, f)
+            got = _deliveries(fe, sid, 2)
+            ingest = _ingest(fe)
+        for d in got:
+            np.testing.assert_array_equal(d.frame,
+                                          255 - tagged_frame(0, d.index))
+        assert ingest["row_path"] is True
+        assert ingest["rows_direct_total"] == (2 if direct else 0)
+        assert ingest["rows_staged_total"] == (0 if direct else 2)
+        assert ingest["batches"] == 1
+
+    def test_a_short_batchs_padding_never_crosses_the_link(self,
+                                                           monkeypatch):
+        fe = _one_chip_frontend(monkeypatch, batch_size=4)
+        with fe:
+            sid = fe.open_stream()
+            fe.submit(sid, tagged_frame(0, 0))
+            (d,) = _deliveries(fe, sid, 1)
+            ingest = _ingest(fe)
+        np.testing.assert_array_equal(d.frame, 255 - tagged_frame(0, 0))
+        assert (ingest["rows_direct_total"], ingest["batches"]) == (1, 1)
+        assert ingest["bytes_total"] == tagged_frame(0, 0).nbytes
+
+    def test_h2d_faults_degrade_rows_to_the_slab_and_the_run_goes_on(
+            self, monkeypatch):
+        from dvf_tpu.resilience import FaultPlan
+
+        chaos = FaultPlan().add("h2d", every=1, count=3)
+        fe = _one_chip_frontend(monkeypatch, batch_size=2,
+                                queue_size=64, chaos=chaos, fault_budget=2)
+        with fe:
+            sid = fe.open_stream()
+            s = fe._sessions[sid]
+            for j in range(16):
+                fe.submit(sid, tagged_frame(0, j))
+                time.sleep(0.005)
+            deadline = time.time() + 30.0
+            while s.delivered + s.failed + s.shed < 16:
+                assert time.time() < deadline, "the run did not go on"
+                time.sleep(0.005)
+            got = fe.poll(sid)
+            stats = fe.stats()
+            ingest = _ingest(fe)
+        assert stats["faults"]["by_kind"] == {"h2d": 3}
+        assert s.failed >= 1 and s.delivered >= 8
+        for d in got:
+            np.testing.assert_array_equal(d.frame,
+                                          255 - tagged_frame(0, d.index))
+        # the third fault overflowed the budget: the lane is monolithic
+        # now, which has no row path; frames go through the slab
+        assert ingest["mode"] == "monolithic"
+        assert ingest["fallback_reason"] == "h2d_fault_budget"
+        assert ingest["row_path"] is False
+        assert ingest["rows_direct_total"] == 0
+        assert ingest["staged_batches"] == ingest["batches"] > 0
+        assert ingest["rows_staged_total"] == s.delivered
+        assert fe._error is None
+
+    def test_a_delivered_frames_input_is_kept_by_nobody(self, monkeypatch):
+        """``slot.frame`` is dropped at the staging and the device frames
+        go with the join: once the result is delivered nothing of the
+        service holds the client's array."""
+        import gc
+        import weakref
+
+        fe = _one_chip_frontend(monkeypatch, batch_size=2,
+                                replay_window=0)
+        with fe:
+            sid = fe.open_stream()
+            frames = [tagged_frame(0, j) for j in range(4)]
+            refs = [weakref.ref(f) for f in frames]
+            for f in frames:
+                fe.submit(sid, f)
+            del f, frames
+            got = []
+            deadline = time.time() + 30.0
+            while len(got) < 4:
+                assert time.time() < deadline
+                got.extend(fe.poll(sid))
+                time.sleep(0.002)
+            assert _ingest(fe)["rows_direct_total"] == 4
+            # the dispatch loop's own locals go with its next batch
+            fe.submit(sid, tagged_frame(0, 4))
+            while not fe.poll(sid):
+                assert time.time() < deadline
+                time.sleep(0.002)
+            gc.collect()
+            assert [r() for r in refs] == [None] * 4

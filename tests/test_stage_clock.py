@@ -450,18 +450,24 @@ SPLIT = ("stage_ms_total", "h2d_put_ms_total", "h2d_wait_ms_total",
          "join_ms_total", "step_dispatch_ms_total")
 
 
-@pytest.mark.parametrize("mode", ["monolithic", "streamed"])
-def test_ingest_split_stays_inside_assemble_h2d(mode, monkeypatch):
+@pytest.mark.parametrize("mode,rows", [
+    ("monolithic", False), ("streamed", False), ("streamed", True)],
+    ids=["monolithic", "streamed", "streamed_rows"])
+def test_ingest_split_stays_inside_assemble_h2d(mode, rows, monkeypatch):
     """stage + put + wait + join + step dispatch, each taken inside the call
     that does the work, are parts of ``assemble_h2d``: never more than it,
     and what they leave (the loop's own code: begin, the row loop, the
-    stamps) is under 0.5 ms a batch + 25% at this size, warm."""
+    stamps) is under 0.5 ms a batch + 25% at this size, warm. ``rows``: the
+    frames go up from the client's arrays (the row path: no staging)."""
     from dvf_tpu.parallel import MeshConfig, make_mesh
     from dvf_tpu.runtime import ingest as ingest_mod
     from dvf_tpu.runtime.engine import Engine
 
     if mode == "streamed":
         monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    if not rows:
+        monkeypatch.setattr(ingest_mod.ShardedBatchAssembler, "_plan_rows",
+                            lambda self: None)
     engine = Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=1)))
     fe = ServeFrontend(get_filter("invert"), ServeConfig(
         batch_size=8, queue_size=500, slo_ms=60_000.0, trace=True,
@@ -483,7 +489,9 @@ def test_ingest_split_stays_inside_assemble_h2d(mode, monkeypatch):
     asm = (st1["components"]["assemble_h2d"]["batch_ms_total"]
            - st0["components"]["assemble_h2d"]["batch_ms_total"])
     assert all(v >= 0.0 for v in split.values())
-    assert split["stage_ms_total"] > 0.0 and split["step_dispatch_ms_total"] > 0.0
+    assert (split["stage_ms_total"] == 0.0) is rows
+    assert row1["ingest"]["rows_direct_total"] == (128 if rows else 0)
+    assert split["step_dispatch_ms_total"] > 0.0
     if mode == "streamed":
         assert split["h2d_put_ms_total"] > 0.0 and split["join_ms_total"] > 0.0
     else:       # one host buffer: nothing is put or joined outside the engine

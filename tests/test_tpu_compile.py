@@ -196,3 +196,29 @@ def test_stencil_step_carries_one_plane(one_chip, gone):
                   and any(elements(dims) >= b * h * w for _, dims in types)]
         assert len(passes) <= 3, passes
         assert [types[0][1] for types in passes][-1] == "2,1096,2048"      # what the kernel reads
+
+
+@pytest.mark.parametrize("shape", [(32, 1080, 1920, 3), (64, 1080, 1920, 3), (16, 720, 1280, 3)],
+                         ids=["invert_1080p", "sobel_bilateral_1080p", "style_720p"])
+def test_ingest_join_is_a_copy_with_no_temporary(one_chip, shape):
+    """The row path's program (``runtime/ingest.py::ingest_join``, PR 47) for
+    the described v5e at the cells' batches: B frames in the layout a TPU
+    holds a ``uint8[H,W,3]`` array in (channel-planar) go into the batch in
+    the layout the step takes (``{2,1,3,0}``), a frame an operand, with no
+    temporary and no relayout on the chip: the slab path's concatenate."""
+    from dvf_tpu.runtime.ingest import ingest_join
+
+    b, h, w, c = shape
+    frame = jax.ShapeDtypeStruct((h, w, c), jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(ingest_join).lower(*(frame,) * b).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    root = next(line for line in entry.splitlines() if "ROOT" in line)
+    assert f"u8[{b},{h},{w},{c}]{{2,1,3,0" in root, root
+    assert len(re.findall(rf"u8\[{h},{w},{c}\]{{1,0,2[^}}]*}} parameter\(", entry)) == b
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
