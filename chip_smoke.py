@@ -556,7 +556,7 @@ def kernel_cases(s: Smoke):
         tiles = ()
     else:
         hw1080, hw720, hw360 = (1080, 1920), (720, 1280), (360, 640)
-        tiles = (8, 40, 120)   # the run_table tile sweeps' pins
+        tiles = (8, 40, 120)   # pinned tiles (the auto pick is 24)
     # Float kernels are compared in float, to half a uint8 step: a
     # disagreement can then move a delivered pixel by at most one step.
     half_step = 0.5 / 255.0

@@ -1,4 +1,4 @@
-"""Benchmark harnesses shared by the repo-root ``bench.py`` and the CLI.
+"""Benchmark harnesses behind the CLI's ``bench`` command.
 
 Measurement modes:
 
@@ -152,7 +152,7 @@ def roofline_fields(r: dict) -> dict:
     out = {}
     if bytes_f > 0:
         ceil = n * peaks["hbm_gbps"] * 1e9 / bytes_f
-        # "hbm_" prefix: bench.py's e2e phase already reports a LINK-based
+        # "hbm_" prefix: the e2e phase already reports a LINK-based
         # `roofline_frac` (fraction of the host↔device ceiling); this one
         # is the fraction of the HBM-bandwidth ceiling for device-resident
         # throughput — different ceiling, different name.

@@ -949,8 +949,7 @@ def cmd_fleet(args) -> int:
     FleetFrontend — one front door, ``--replicas`` engine replicas with
     session affinity, spillover admission, and supervised replica
     replacement. ``--scaling`` runs the fleet scaling round instead
-    (aggregate throughput at 1..N replicas; benchmarks/fleet_bench.py
-    persists the same round)."""
+    (aggregate throughput at 1..N replicas)."""
     _force_platform()
 
     import threading
@@ -2196,8 +2195,7 @@ def main(argv=None) -> int:
     fl.add_argument("--scaling", action="store_true",
                     help="run the fleet scaling round instead of the "
                          "demo: aggregate throughput at 1 and "
-                         "--replicas replicas, core-pinned workers "
-                         "(benchmarks/fleet_bench.py persists this)")
+                         "--replicas replicas, core-pinned workers")
     fl.add_argument("--lineage", action="store_true",
                     help="arm frame-lineage latency attribution on every "
                          "replica (same spelling as serve --lineage); "
@@ -2415,9 +2413,8 @@ def main(argv=None) -> int:
     bp.add_argument("--e2e", action="store_true")
     bp.add_argument("--collect-mode", choices=("thread", "inline"),
                     default="inline",
-                    help="e2e pipeline collect mode — 'inline' matches the "
-                         "headline bench.py harness (both record it in "
-                         "their JSON so cross-harness numbers compare)")
+                    help="e2e pipeline collect mode (recorded in the "
+                         "JSON)")
     bp.add_argument("--transport", choices=("python", "ring"), default="python",
                     help="--e2e ingest transport (ring = native C++ ring)")
     bp.add_argument("--mesh", default=None,

@@ -16,9 +16,8 @@ spawn is a session-rebind, not a cold compile) and ``retire_replica()``
 Same discipline as `control.controllers`: ``step(row, prev)`` reads one
 telemetry row, no wall-clock, no randomness — replaying a recorded
 window through a fresh controller yields a byte-identical action list
-(pinned in tests/test_elastic.py, and asserted by the committed
-``ELASTIC_BENCH.json`` run), so a scale incident is reproducible from
-its flight dump.
+(pinned in tests/test_elastic.py), so a scale incident is reproducible
+from its flight dump.
 
 The decision inputs, in the order they matter:
 
@@ -426,7 +425,7 @@ class PredictiveElasticityController(FleetElasticityController):
     no later. Same determinism discipline as the base class — the
     slope history is rebuilt from the rows alone, no wall clock, so a
     recorded window replays byte-identically (pinned by
-    tests/test_planner.py and the committed PLAN_BENCH.json)."""
+    tests/test_planner.py)."""
 
     def __init__(self, config: Optional[ElasticConfig] = None):
         super().__init__(config)
@@ -478,8 +477,8 @@ def make_elasticity_controller(
         config: Optional[ElasticConfig] = None) -> FleetElasticityController:
     """The one construction seam: predictive when the config says so
     (``--autoplan`` arms it at the fleet tier), reactive otherwise —
-    so the elastic plane, the bench harness, and the replay tests can
-    never disagree about which controller a config builds."""
+    so the elastic plane and the replay tests can never disagree about
+    which controller a config builds."""
     config = config or ElasticConfig()
     if config.predictive:
         return PredictiveElasticityController(config)

@@ -13,9 +13,7 @@ topology) it
    (`analytic_frame_ms`) — cheap arithmetic, no device time,
 3. live-profiles only the analytic shortlist (≤ 1/3 of the grid, the
    acceptance bound) through the REAL frontend — each leg a short paced
-   burst, ranked by `benchtools.ab_comparison`, the same leg machinery
-   the bench table's A/B phase runs on (one paced-measurement path, not
-   a third copy),
+   burst, ranked by `ab_comparison`,
 4. returns the winning :class:`Plan`, which the caller persists in the
    on-disk plan cache (`dvf_tpu.control.plan_cache`) so repeat startups
    skip the search entirely.
@@ -52,6 +50,7 @@ __all__ = [
     "candidate_grid",
     "analytic_frame_ms",
     "shortlist",
+    "ab_comparison",
     "plan_search",
     "predicted_tick_cost_ms",
     "topology_fingerprint",
@@ -271,25 +270,35 @@ def shortlist(grid: Sequence[Plan], cal: Optional[dict],
     return scored[:budget]
 
 
-def _load_ab_comparison() -> Callable:
-    """The shared leg machinery lives in the repo-root ``benchtools``
-    (jax-free, shared with benchmarks/run_table.py). The package may be
-    imported without the repo root on sys.path — fall back to loading
-    it by file, never by copying it."""
-    try:
-        from benchtools import ab_comparison
-        return ab_comparison
-    except ImportError:
-        import importlib.util
-        import os
+def ab_comparison(legs, measure, *, prior=None, keep_leg=None, log=None):
+    """One incremental A/B comparison: measure legs in order, rank them.
 
-        root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        spec = importlib.util.spec_from_file_location(
-            "benchtools", os.path.join(root, "benchtools.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.ab_comparison
+    - ``legs``: ``[(label, payload), ...]`` measured in order by
+      ``measure(label, payload) -> dict`` (``{"fps": ...}`` on success,
+      ``{"error": ...}`` on failure — an error leg is recorded, not
+      raised).
+    - ``prior``: an earlier partial comparison dict; legs whose prior
+      entry passes ``keep_leg(entry)`` are seeded and not re-measured
+      (the caller decides whether the prior qualifies at all).
+
+    Returns the comparison; ``comp["winner"]`` is the label with the
+    highest ``fps`` (``"n/a"`` when every leg errored)."""
+    comp = {}
+    prior = prior or {}
+    for label, _ in legs:
+        entry = prior.get(label)
+        if keep_leg is not None and isinstance(entry, dict) \
+                and keep_leg(entry):
+            comp[label] = entry
+            if log:
+                log(f"{label}: kept from partial prior run")
+    for label, payload in legs:
+        if label not in comp:
+            comp[label] = measure(label, payload)
+    fps = {k: v.get("fps", 0) for k, v in comp.items()
+           if isinstance(v, dict) and "fps" in v}
+    comp["winner"] = max(fps, key=fps.get) if any(fps.values()) else "n/a"
+    return comp
 
 
 def plan_search(grid: Sequence[Plan],
@@ -304,10 +313,9 @@ def plan_search(grid: Sequence[Plan],
     """The search: analytic prune to the shortlist, then live-profile
     each shortlisted candidate with ``measure(plan) ->
     {"fps": ...} | {"error": ...}`` (a short paced burst through the
-    real frontend), ranked by the same `benchtools.ab_comparison` the
-    bench table's A/B phase uses. Returns ``(winning Plan, comparison
-    dict)`` — the comparison is what the caller ledgers (per-leg fps,
-    winner, search cost).
+    real frontend), ranked by `ab_comparison`. Returns ``(winning Plan,
+    comparison dict)`` — the comparison is what the caller ledgers
+    (per-leg fps, winner, search cost).
 
     With no ``measure`` (or when every leg errors) the analytic best
     wins with ``source="analytic"`` — degraded but deterministic; the
@@ -319,8 +327,7 @@ def plan_search(grid: Sequence[Plan],
                       "grid": len(grid), "analytic_only": True}
 
     by_label = {p.label(): p for p in short}
-    ab = _load_ab_comparison()
-    comp = ab(
+    comp = ab_comparison(
         [(p.label(), p) for p in short],
         lambda _label, p: measure(p),
         log=log,

@@ -11,8 +11,7 @@ when ``FleetConfig.autoscale`` is armed:
     ``warm_target`` replicas PRE-SPAWNED and AOT-PRECOMPILED (the
     ``--precompile`` manifest through the persistent compilation cache,
     PR 9) but not yet serving; adopting one into the fleet is a
-    dictionary insert plus session placement — the measured
-    spawn-to-first-served-frame gap in ``ELASTIC_BENCH.json``. A
+    dictionary insert plus session placement. A
     background refill thread replaces taken standbys, so the pool is
     warm again before the controller's cooldown expires.
 
@@ -27,7 +26,7 @@ when ``FleetConfig.autoscale`` is armed:
     never stall the sampling cadence the next decision reads. Keeps a
     bounded decision log AND the composed-row window, so the whole
     scaling episode replays deterministically from the recorded rows
-    (the bench's ``replay.match`` acceptance).
+    (``tests/test_elastic.py`` replays a live fleet's).
 
 Leak discipline: standby replicas are REAL worker processes (or live
 frontends in local mode) that exist before any session does, so a pool
@@ -257,8 +256,8 @@ class ElasticFleetPlane:
     def decide(self, row: dict) -> List[Action]:
         """One deterministic decision step over a composed row; records
         the row and any actions for replay. Safe to call directly with
-        recorded rows — the bench's replay harness does, through a
-        FRESH controller."""
+        recorded rows — the replay tests do, through a FRESH
+        controller."""
         prev = self._prev_row
         actions = self.controller.step(row, prev)
         self._prev_row = row
@@ -272,7 +271,7 @@ class ElasticFleetPlane:
 
     def replay_window(self) -> dict:
         """The recorded (composed rows, emitted actions) pair — what
-        the bench replays through a fresh controller to prove the run
+        the tests replay through a fresh controller to prove the run
         is reproducible from its telemetry window."""
         with self._lock:
             return {"rows": [dict(r) for r in self.window],

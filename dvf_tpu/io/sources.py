@@ -51,8 +51,7 @@ class SyntheticSource:
     - ``"block"`` — a small moving block over a STATIC background, the
       webcam-like low-motion workload (a subject moving against a fixed
       scene): per-frame change is ~2 block footprints, a few % of the
-      frame, which is the regime the delta wire's order-of-magnitude
-      codec saving is claimed for (benchmarks/DELTA_BENCH.json).
+      frame, which is the regime the delta wire is for.
     - ``False`` / ``"none"`` — a fully static stream (dirty ratio 0;
       the bit-identity equivalence tests).
     """

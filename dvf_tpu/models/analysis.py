@@ -16,16 +16,16 @@ geometry it derives
 - an HBM ideal time: bytes / 819 GB/s,
 
 and a per-layer verdict: which ceiling binds, and what the whole model's
-best-case serial time is. Comparing that bound to the measured
-ms_per_frame in benchmarks/BENCH_TABLE.json separates "the model is
-fundamentally transfer/arithmetic-bound at these shapes" from "the
-lowering is leaving time on the table" -- the distinction the VERDICT
-asked the round to establish.
+best-case serial time is. Comparing that bound to a step measured on the
+chip (PERF.md section 5) separates "the model is fundamentally
+transfer/arithmetic-bound at these shapes" from "the lowering is leaving
+time on the table" -- the distinction the VERDICT asked the round to
+establish.
 
 The numbers are a MODEL (peaks from the public v5e datasheet, the same
 constants as dvf_tpu.benchmarks.DEVICE_PEAKS; efficiency factors are
 idealized tiling, not a simulator). The on-chip companion is
-benchmarks/neural_layers.py, which times the same per-layer blocks on
+scripts/style_step_probe.py, which times the served step op by op on
 the real chip; where the two disagree, the measured number wins. For the
 style net it has: PERF.md section 5 holds the measured table (the plain
 composition's 284 ms step against the phase-domain forward's).
@@ -38,7 +38,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-from typing import List, Optional
+from typing import List
 
 # Same public-datasheet constants as dvf_tpu.benchmarks.DEVICE_PEAKS
 # (duplicated literals would drift; import lazily to stay jax-free).
@@ -230,8 +230,7 @@ def espcn_form_passes(height: int, width: int, phases=None, scale: int = 2,
     return out
 
 
-def summarize(layers: List[LayerCost], measured_ms: Optional[float] = None,
-              label: str = "") -> dict:
+def summarize(layers: List[LayerCost], label: str = "") -> dict:
     total_flops = sum(l.flops for l in layers)
     total_bytes = sum(l.hbm_bytes for l in layers)
     serial_ideal = sum(l.ideal_ms for l in layers)
@@ -249,16 +248,6 @@ def summarize(layers: List[LayerCost], measured_ms: Optional[float] = None,
             total_flops / (serial_ideal * 1e-3) / (PEAK_BF16_TFLOPS * 1e12),
             4) if serial_ideal else None,
     }
-    if measured_ms:
-        out["measured_ms_per_frame"] = measured_ms
-        out["lowering_gap_x"] = round(measured_ms / serial_ideal, 1)
-        out["mfu_measured"] = round(
-            total_flops / (measured_ms * 1e-3) / (PEAK_BF16_TFLOPS * 1e12), 4)
-        out["verdict"] = (
-            "transfer/arithmetic-bound" if measured_ms <= serial_ideal * 1.5
-            else "lowering-bound: measured %.1fx the per-layer roofline sum "
-                 "-- the gap is in XLA's lowering/fusion, not the model's "
-                 "arithmetic or traffic" % (measured_ms / serial_ideal))
     return out
 
 
@@ -280,21 +269,6 @@ def render_md(layers: List[LayerCost], summary: dict) -> str:
     return "\n".join(lines)
 
 
-def _measured_ms(config_name: str) -> Optional[float]:
-    """ms_per_frame from the committed TPU bench table, if present."""
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))),
-        "benchmarks", "BENCH_TABLE.json")
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        return doc["configs"][config_name]["device"]["ms_per_frame"]
-    except Exception:
-        return None
-
-
 def main(argv=None) -> int:
     import argparse
 
@@ -304,11 +278,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     style = style_layer_costs(720, 1280)
-    style_sum = summarize(style, _measured_ms("style_720p"),
+    style_sum = summarize(style,
                           "style_720p (batch-independent, per frame)")
     sr = espcn_layer_costs(540, 960)
-    sr_sum = summarize(sr, _measured_ms("sr2x_540p"),
-                       "sr2x_540p (batch-independent, per frame)")
+    sr_sum = summarize(sr, "sr2x_540p (batch-independent, per frame)")
 
     if args.json:
         print(json.dumps({"style_720p": style_sum, "sr2x_540p": sr_sum}))
@@ -318,8 +291,7 @@ def main(argv=None) -> int:
           "HBM (public v5e datasheet). Per-layer MXU times model the "
           "128x128 systolic tiling (lane = output channels, sublane = "
           "k**2*Cin contraction); HBM times are activation traffic at "
-          "the compute dtype. The on-chip companion that measures the "
-          "same blocks is benchmarks/neural_layers.py. The style net's "
+          "the compute dtype. The style net's "
           "measured table on the chip, op by op with each op's stage "
           "(the plain composition beside the phase-domain forward that "
           "is served), is PERF.md section 5; scripts/style_step_probe.py "

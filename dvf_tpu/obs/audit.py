@@ -10,9 +10,8 @@ kills replicas mid-stream, and substitutes freshly compiled programs on
 the live path (resize / quality rebind / recovery rebuild — and the
 ROADMAP item-1 hot swap will multiply that rate) needs online
 silent-corruption detection the way it needed latency attribution.
-Four detectors, each overhead-gated (benchmarks/AUDIT_BENCH.json) and
-chaos-proven (the ``corrupt_wire`` / ``corrupt_device`` injection
-sites):
+Four detectors, each chaos-proven (the ``corrupt_wire`` /
+``corrupt_device`` injection sites):
 
 1. **Wire integrity** — an 8-byte blake2b content digest stamped into
    a tiny framed envelope at every encode hop and verified at every

@@ -68,11 +68,10 @@ def bilateral(d: int = 5, sigma_color: float = 0.1, sigma_space: float = 2.0,
     ``impl=None`` picks the measured per-backend winner: on TPU the Pallas
     kernel ("pallas", 765 vs 256 fps at 1080p batch 8 — one HBM pass per
     tile, no spilled shifted views); on CPU the unrolled jnp lowering
-    ("jnp", 3.7 vs 2.0 fps — interpret mode pays per-tile overhead with
-    no VMEM to win back). Provenance: the bilateral_1080p impl
-    comparison — TPU figures captured 2026-07-31 through a shared chip
-    that no longer exists (table removed in PR 21), CPU rows in
-    benchmarks/cpu/BENCH_TABLE.json.
+    ("jnp" — interpret mode pays per-tile overhead with no VMEM to win
+    back). Provenance: the TPU figures were captured 2026-07-31 through
+    a shared chip that no longer exists (table removed in PR 21); not
+    measured on this chip.
     Both impls declare the same halo, so spatial sharding is unaffected.
     """
     if impl is None:

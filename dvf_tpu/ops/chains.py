@@ -35,9 +35,8 @@ def sobel_bilateral(
     three-plane whole-tile form: 7.7x, on 0.20 vs 8.64 GiB of scratch);
     at d = 5 the fused step of 64 was 32.3 ms (kernel 24.1) in PR 44 and
     is not measured since.
-    CPU 9.2 vs 3.3 fps (in interpret mode it lowers to ordinary fused XLA
-    ops, a legitimate production path, and keeps the whole-tile form;
-    benchmarks/cpu/BENCH_TABLE.json).
+    CPU: in interpret mode it lowers to ordinary fused XLA ops, a
+    legitimate production path, and keeps the whole-tile form.
     "chain" (the two-op jnp chain) remains the default on backends whose
     A/B hasn't been captured yet. Both filters declare the same halo, so
     spatial sharding is unaffected by the choice.

@@ -109,7 +109,7 @@ def sep_conv2d(
 
     ``impl``: "shift" (default — stencil-as-shifted-FMAs, the fast path
     for 3-channel images on TPU and CPU) or "depthwise" (XLA conv op,
-    kept for A/B benchmarking; see benchmarks/run_table.py).
+    kept for A/B comparison).
     """
     if impl == "shift":
         return _shifted_sep_conv(batch, kh, kw)
@@ -133,10 +133,9 @@ def gaussian_blur(ksize: int = 9, sigma: float = 0.0,
     a shared chip that no longer exists (table removed in PR 21), and the
     pallas leg's 0.043 HBM fraction makes that gauss9 capture suspect —
     ROADMAP D4 re-runs it on the ledger. **CPU = "pallas" at ksize≥9**
-    (15.3 vs 9.3 fps — interpret mode lowers to one fused XLA pass
-    instead of two), "shift" below. Explicit impl pins (the A/B harness
-    passes "shift"/"depthwise"). Halo is ksize//2 for every impl, so
-    spatial sharding is unaffected.
+    (interpret mode lowers to one fused XLA pass instead of two),
+    "shift" below. An explicit ``impl`` pins the choice. Halo is
+    ksize//2 for every impl, so spatial sharding is unaffected.
     """
     if impl is None:
         impl = measured_default_for(

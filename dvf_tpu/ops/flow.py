@@ -423,10 +423,9 @@ def flow_warp(
     flow to ±``max_disp`` px — the table benchmark compares the two.
     ``None`` picks the measured per-backend winner: "pallas" on TPU
     (39.6 vs 17.4 fps at 720p batch 4 — TPU has no fast vector gather),
-    "gather" on CPU (3.1 vs 3.0; and it imposes no displacement clip).
-    Provenance: the flow_warp_720p impl comparison — TPU figures
-    captured 2026-07-31 through a shared chip that no longer exists
-    (table removed in PR 21), CPU rows in benchmarks/cpu/BENCH_TABLE.json.
+    "gather" on CPU (it imposes no displacement clip). Provenance: the
+    TPU figures were captured 2026-07-31 through a shared chip that no
+    longer exists (table removed in PR 21); not measured on this chip.
 
     NOTE the TPU default is an APPROXIMATION, unlike the other measured
     winners (which are numerics-identical): the Pallas warp clips

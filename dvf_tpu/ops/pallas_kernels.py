@@ -140,7 +140,7 @@ def _stencil_vmem_limit(tile_h: Optional[int], interpret: bool,
     24 rows (Mosaic for a described v5e, PR 43: the unpinned kernel did
     not compile) until PR 46 ran its taps in strips (3.25 MB now:
     tests/test_tpu_compile.py compiles it under the default). So a
-    caller-pinned ``tile_h`` (the run_table tile sweeps) or a window over
+    caller-pinned ``tile_h`` (chip_smoke.py's tile pins) or a window over
     5x5 taps gets the warp kernel's 64 MiB (the chip has 128 MiB of VMEM),
     which costs nothing where it goes unused; the auto-picked tile at up
     to 25 taps compiles under the default and keeps it."""
@@ -446,7 +446,8 @@ def gaussian_blur_pallas(
     interpret: Optional[bool] = None,
 ) -> Filter:
     """Pallas-backed separable Gaussian (A/B partner of ``gaussian_blur``;
-    run_table records the per-backend winner). ``interpret=None`` → auto:
+    ops/registry.py MEASURED_DEFAULTS holds the per-backend winner).
+    ``interpret=None`` → auto:
     compiled on TPU, interpret mode elsewhere."""
     from dvf_tpu.ops.conv import gaussian_kernel_1d
 

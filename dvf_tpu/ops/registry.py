@@ -56,10 +56,9 @@ def measured_default(winners: Dict[str, str], fallback: str) -> str:
     """Pick a filter's default implementation from the MEASURED per-backend
     winners.
 
-    ``winners`` maps backend → impl label, populated only from A/B rows
-    of benchmarks/run_table.py — an unmeasured backend falls back to
-    ``fallback`` rather than guessing. Callers pin an explicit
-    ``impl=...`` to bypass this entirely (the A/B harness does).
+    ``winners`` maps backend → impl label — a backend with no entry
+    falls back to ``fallback`` rather than guessing. Callers pin an
+    explicit ``impl=...`` to bypass this entirely.
 
     Note this touches ``jax.default_backend()`` (initializes the backend):
     it runs at filter-construction time, which in every CLI/worker path is
@@ -72,50 +71,28 @@ def measured_default(winners: Dict[str, str], fallback: str) -> str:
 
 
 # The per-backend default of every op that has more than one
-# implementation. The TPU winners were transcribed from A/B rows captured
-# 2026-07-31 through a shared chip that no longer exists (the table and
-# the test that compared it with this map were removed in PR 21); the CPU
-# winners from benchmarks/cpu/BENCH_TABLE.json. They stay as they are
-# until ROADMAP D4 replaces them with a ledgered A/B on the chip
-# ("flow_inner" is the first that was: PR 27's chip runs; "sobel_bilateral"
-# the first stencil pair measured: PR 43's chip runs, 2026-10-01, whose
-# figures ops/chains.py::sobel_bilateral quotes beside the fused step's
-# since, 34.3 ms a batch of 64 on 2026-10-02, PR 46 — the winner stands,
-# and this map is not changed by a measurement that confirms it).
-#
-# Schema per key:
-#   comparison    — the impl_comparisons key benchmarks/run_table.py writes
-#   winners       — backend → impl argument the factory picks
-#   fallback      — impl for backends with no A/B
-#   label_to_impl — A/B harness impl labels (benchmarks/run_table.py
-#                   COMPARISONS) → the factory's impl argument values
-#   as_of         — backend → captured_utc of the A/B the declaration was
-#                   transcribed from (what benchmarks/fold_winners.py
-#                   compares a newer table against)
+# implementation: ``winners`` maps a backend to the impl argument the
+# factory picks, ``fallback`` is the impl for every other backend. Two
+# TPU winners have a run on this chip behind them: "flow_inner" (PR 27's
+# chip runs) and "sobel_bilateral" (PRs 43-46: the pair measured in PR
+# 43, whose figures ops/chains.py::sobel_bilateral quotes beside the
+# fused step's since, 34.3 ms a batch of 64 in PR 46). The other TPU
+# winners were transcribed from A/B rows captured 2026-07-31 through a
+# shared chip that no longer exists, and the CPU winners from a CPU
+# table; both records are gone: they are unmeasured on this chip and
+# stay as they are until ROADMAP D4 replaces them with a ledgered A/B.
 MEASURED_DEFAULTS = {
     "bilateral": {
-        "comparison": "bilateral_1080p",
-        "as_of": {"tpu": "2026-07-31T04:01:32.529568+00:00",
-                  "cpu": "2026-07-30T17:25:47.284731+00:00"},
         "winners": {"tpu": "pallas", "cpu": "jnp"},
         "fallback": "jnp",
-        "label_to_impl": {"jnp": "jnp", "pallas": "pallas"},
     },
     "sobel_bilateral": {
-        "comparison": "sobel_bilateral_1080p",
-        "as_of": {"tpu": "2026-07-31T04:02:11.015286+00:00",
-                  "cpu": "2026-07-30T17:26:32.012594+00:00"},
         "winners": {"tpu": "pallas", "cpu": "pallas"},
         "fallback": "chain",
-        "label_to_impl": {"jnp_chain": "chain", "pallas_fused": "pallas"},
     },
     "flow_warp": {
-        "comparison": "flow_warp_720p",
-        "as_of": {"tpu": "2026-07-31T04:05:28.041167+00:00",
-                  "cpu": "2026-07-30T17:27:19.651675+00:00"},
         "winners": {"tpu": "pallas", "cpu": "gather"},
         "fallback": "gather",
-        "label_to_impl": {"gather": "gather", "pallas_warp": "pallas"},
     },
     # flow_warp's inner_warp, where the final warp is the bounded kernel
     # (the same +-max_disp contract; with a gather final warp the inner
@@ -127,12 +104,8 @@ MEASURED_DEFAULTS = {
     # clipped to the bound (ops/flow.py::_inner_warp_fn). No CPU A/B (the
     # kernel runs in interpret mode there): the fallback.
     "flow_inner": {
-        "comparison": "flow_inner_720p",
-        "as_of": {"tpu": "2026-09-28T09:12:00+00:00"},
         "winners": {"tpu": "pallas"},
         "fallback": "gather",
-        "label_to_impl": {"gather_inner": "gather",
-                          "pallas_inner": "pallas"},
     },
     # ksize >= 9 branch of gaussian_blur. TPU winner is SHIFT per the
     # 2026-07-31 A/B (shift 1022.4 vs pallas_fused 186.3 fps at 1080p
@@ -140,38 +113,26 @@ MEASURED_DEFAULTS = {
     # made the Pallas kernels actually lower through Mosaic. pallas_fused's
     # 0.043 HBM fraction makes that capture suspect; ROADMAP D4 re-runs it.
     "gaussian_blur_k9": {
-        "comparison": "gauss9_1080p",
-        "as_of": {"tpu": "2026-07-31T04:07:56.417105+00:00",
-                  "cpu": "2026-07-30T17:29:24.105196+00:00"},
         "winners": {"tpu": "shift", "cpu": "pallas"},
         "fallback": "shift",
-        "label_to_impl": {"shift": "shift", "depthwise": "depthwise",
-                          "pallas_fused": "pallas"},
     },
-    # ksize < 9 branch: shift on both measured backends (gauss3_1080p).
+    # ksize < 9 branch: shift on both backends.
     "gaussian_blur_small": {
-        "comparison": "gauss3_1080p",
-        "as_of": {"tpu": "2026-07-31T04:08:23.317984+00:00",
-                  "cpu": "2026-07-31T04:59:07.526136+00:00"},
         "winners": {"tpu": "shift", "cpu": "shift"},
         "fallback": "shift",
-        "label_to_impl": {"shift": "shift", "pallas_fused": "pallas"},
     },
     # Exact space-to-depth conv rewrite for ESPCN (models.layers.conv2d_s2d;
-    # static case in models.analysis). CPU (full 540p geometry,
-    # benchmarks/cpu/): "ref" wins (0.9 vs 0.4) — the phase decomposition
-    # buys MXU lane utilization, which AVX has no analog of. TPU stays
+    # static case in models.analysis). CPU: "ref" (the phase decomposition
+    # buys MXU lane utilization, which AVX has no analog of). TPU stays
     # unpinned and falls back to "ref", which is what the cell
     # sr2x_540p.bulk runs; one on-chip probe at its shape read fast 78.3
-    # ms a step against ref 34.6 (PERF.md §7, PR 37). The style net's counterpart is no option any more: its stages
-    # take the phase form from their shapes (models.style_transfer.
-    # stage_forms; the on-chip A/B is PERF.md §6, PR 28).
+    # ms a step against ref 34.6 (PERF.md §7, PR 37). The style net's
+    # counterpart is no option any more: its stages take the phase form
+    # from their shapes (models.style_transfer.stage_forms; the on-chip
+    # A/B is PERF.md §6, PR 28).
     "espcn_fast": {
-        "comparison": "sr_fast_540p",
-        "as_of": {"cpu": '2026-07-31T19:13:42.915897+00:00'},
         "winners": {"cpu": "ref"},
         "fallback": "ref",
-        "label_to_impl": {"ref": "ref", "fast": "fast"},
     },
 }
 

@@ -41,7 +41,7 @@ Resume tokens (:func:`make_resume_token` / :func:`check_resume_token`)
     can resubmit them, and reassembles the stream in source order. Under
     ``net_dup``/``net_reorder``/``net_partition`` chaos plus replica
     SIGKILL, ``assembled()`` is byte-identical to a fault-free run —
-    that is the invariant ``benchmarks/continuity_bench.py`` soaks.
+    that is the invariant ``tests/test_continuity.py`` pins.
 
 Crash-consistent state (:func:`atomic_write_json` / :func:`load_json`)
     tmp-file + ``os.replace`` snapshot discipline for the fleet router's

@@ -1306,7 +1306,7 @@ DEFAULT_COMPILE_CACHE_BYTES = 512 * 1024 * 1024
 
 
 def resolve_compile_cache_dir() -> str:
-    """The compile-cache directory every entry point uses (CLI, bench.py,
+    """The compile-cache directory every entry point uses (CLI,
     chip_smoke.py, fleet replicas via the env): the environment's when
     set, the checkout-anchored default otherwise."""
     return (os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -276,8 +276,8 @@ class ServeConfig:
     #   quality rebind, or recovery rebuild ledgers a probe-digest
     #   verdict. Exports: stats()["audit"], audit_* signals,
     #   dvf_audit_* samples, /audit, flight-dump audit.json; the first
-    #   CONFIRMED corruption trips a flight dump. Overhead gated ≤3%
-    #   fps (benchmarks/AUDIT_BENCH.json). Off by default (--audit).
+    #   CONFIRMED corruption trips a flight dump. Its cost is not
+    #   measured on the chip. Off by default (--audit).
     audit_sample_every: int = 64  # shadow-replay sampling period K:
     #   every Kth staged frame is re-executed on the golden path
     audit_seed: int = 0           # sampler phase (deterministic replay)
@@ -306,8 +306,7 @@ class ServeConfig:
     #   bucket stall) in a bounded ring — stats()["ledger"], /ledger,
     #   the dvf_compile_ms histogram, dvf_mem_* gauges, a dedicated
     #   Perfetto lane, and flight-dump ledger.json. Default ON: events
-    #   are reconfiguration-rate, not frame-rate (overhead gated ≤2%
-    #   fps by benchmarks/LEDGER_BENCH.json). False = none of it.
+    #   are reconfiguration-rate, not frame-rate. False = none of it.
     autoplan: bool = False        # auto-plan plane (control.planner):
     #   at startup, resolve an operating plan for the primary signature
     #   — plan-cache hit (warm restart: < 50 ms, no search), else a
@@ -2117,8 +2116,7 @@ class ServeFrontend:
                     # Best-of-2: the first burst after a hot swap pays
                     # cold staging (fresh program, empty assembler
                     # ring) — the second burst is the steady state the
-                    # plan will actually run at. Same repeat discipline
-                    # as the bench table's A/B legs.
+                    # plan will actually run at.
                     a = self._measure_plan_candidate(sid, frame, p)
                     if "error" in a:
                         return a
@@ -2213,9 +2211,8 @@ class ServeFrontend:
         and the ingest-depth config the next assembler rebuild picks
         up), then push a paced burst of ``autoplan_burst_frames``
         frames through the measurement session and report sustained
-        fps. The row shape matches the bench table's A/B legs
-        (``fps`` or ``error``), so `benchtools.ab_comparison` ranks
-        the search — one shared paced-measurement path."""
+        fps. The row is what `planner.ab_comparison` ranks (``fps`` or
+        ``error``)."""
         with self._lock:
             s = self._sessions.get(sid)
             bucket = s.bucket if s is not None else None

@@ -23,7 +23,6 @@ throughput outpacing the device) is a thread-count knob. Use
 from __future__ import annotations
 
 import ctypes
-import json
 import math
 import os
 import struct
@@ -486,8 +485,8 @@ def measure_codec_fps(height: int, width: int, samples: int = 8,
       rate; on real cores it exceeds it.
 
     This is the measurement behind serve's wire-mode budget warning — the
-    decision must use THIS host's numbers, not the committed CODEC_BENCH
-    table from another machine (SURVEY §7 hard part 3: host JPEG
+    decision must use THIS host's numbers, not a table from another
+    machine (SURVEY §7 hard part 3: host JPEG
     throughput is the first bottleneck at high rates).
     """
     import time
@@ -836,24 +835,18 @@ class CoefficientFrame:
         return unfold(y), unfold(cb), unfold(cr)
 
 
+# The share of the classic full encode cycle that stays on the host once
+# the transform runs on the device: a CPU timing of the whole encode
+# cycle (PR 15), not measured on the chip; no benchmark cell runs a codec
+# wire.
+ENTROPY_SHARE = 0.629
+
+
 def entropy_pool_size(cores: Optional[int] = None) -> int:
-    """Entropy-pool width from MEASURED stage costs (the TVM discipline:
-    size from data, not guesses). benchmarks/CODEC_BENCH.json's
-    ``stage_costs.entropy_share`` records what fraction of the classic
-    full encode cycle survives on the host once the transform moved to
-    the device; the pool only needs that share of the cores the full
-    codec pool would have used. Falls back to half the cores when the
-    table hasn't been regenerated on this checkout."""
+    """Entropy-pool width: the pool only needs ``ENTROPY_SHARE`` of the
+    cores the full codec pool would have used."""
     cores = cores or os.cpu_count() or 1
-    share = 0.5
-    try:
-        path = os.path.join(os.path.dirname(os.path.dirname(_DIR)),
-                            "benchmarks", "CODEC_BENCH.json")
-        with open(path) as f:
-            share = float(json.load(f)["stage_costs"]["entropy_share"])
-    except (OSError, KeyError, ValueError, TypeError):
-        pass
-    return max(1, min(cores, math.ceil(cores * min(1.0, max(0.05, share)))))
+    return max(1, min(cores, math.ceil(cores * ENTROPY_SHARE)))
 
 
 class EntropyPool:

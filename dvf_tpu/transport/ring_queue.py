@@ -41,7 +41,7 @@ cross-process shm capability and byte-bounded freshness); ring/jpeg
 the codec-throughput wall SURVEY §7 hard part 3 predicts; JPEG pays off
 when the wire is a network, not shm, or at the reference's 512² geometry
 where encode is ~5-10 ms); ring/delta scales those codec costs by the
-stream's dirty ratio (benchmarks/DELTA_BENCH.json).
+stream's dirty ratio.
 
 Differences from the Python queue, by design:
 

@@ -6,19 +6,9 @@
 # relay-hop audit, serve publish tee) PLUS the chip smoke's dry run
 # (chip_smoke.py --cpu-tiny: the eight configs through ServeFrontend and
 # every Pallas kernel at toy sizes, labelled cpu — the real run needs
-# the chip) PLUS the continuity soak smoke
-# (benchmarks/continuity_bench.py --smoke: seeded chaos with
-# byte-identical reassembly + front-door kill -9 recovery, ~10 s)
-# PLUS the auto-plan gate (benchmarks/plan_bench.py --check: the
-# committed PLAN_BENCH.json must still clear every acceptance gate —
-# planned>=1.15x default, chosen within 5% of exhaustive best at <=1/3
-# live-profiled, warm plan step <50 ms, deterministic predictive
-# replay spawning before the first refusal)
-# PLUS the perf-regression sentinel (benchmarks/sentinel.py --quick).
-# Exit nonzero on a test failure, an audit/broadcast/continuity miss,
-# a stale plan artifact, OR a measured perf regression —
-# the same bar the GitHub Actions workflow (.github/workflows/ci.yml)
-# enforces on every push.
+# the chip). Exit nonzero on a test failure or an audit/broadcast/chip
+# smoke miss. No step times anything: speed is the chip's to say
+# (BENCHMARK.json, PERF_LEDGER.jsonl).
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
@@ -57,30 +47,6 @@ ksrc=$?
 if [ "$ksrc" -ne 0 ]; then
     echo "ci_tier1: CHIP SMOKE DRY RUN FAILED (chip_smoke rc=$ksrc)" >&2
     exit "$ksrc"
-fi
-
-echo "== continuity soak smoke (seeded chaos + front-door crash recovery) =="
-JAX_PLATFORMS=cpu python benchmarks/continuity_bench.py --smoke
-crc=$?
-if [ "$crc" -ne 0 ]; then
-    echo "ci_tier1: CONTINUITY MISS (continuity_bench rc=$crc)" >&2
-    exit "$crc"
-fi
-
-echo "== auto-plan gate (committed PLAN_BENCH.json acceptance) =="
-JAX_PLATFORMS=cpu python benchmarks/plan_bench.py --check
-prc=$?
-if [ "$prc" -ne 0 ]; then
-    echo "ci_tier1: PLAN GATE MISS (plan_bench --check rc=$prc)" >&2
-    exit "$prc"
-fi
-
-echo "== perf-regression sentinel =="
-JAX_PLATFORMS=cpu python benchmarks/sentinel.py --quick
-src=$?
-if [ "$src" -ne 0 ]; then
-    echo "ci_tier1: PERF REGRESSION (sentinel rc=$src)" >&2
-    exit "$src"
 fi
 
 echo "ci_tier1: clean"

@@ -9,7 +9,7 @@ import os
 
 # The suite runs on the CPU with 8 virtual devices: it exercises mesh
 # logic without hardware (the chip is reached through chip_smoke.py and
-# bench.py only). Set before jax initializes a backend; child processes
+# the benchmark only). Set before jax initializes a backend; child processes
 # (fleet replicas, CLI subprocesses) inherit the same platform. Override
 # with DVF_TEST_PLATFORM to run on an accelerator.
 _platform = os.environ.get("DVF_TEST_PLATFORM", "cpu")
@@ -98,12 +98,11 @@ def pytest_configure(config):
                    "bounded wall time; run in tier-1, select with "
                    "-m lineage)")
     config.addinivalue_line(
-        "markers", "ledger: compile/reconfiguration ledger, memory "
-                   "accounting, and perf-regression sentinel tests "
-                   "(bounded event ring, measured bucket stalls, "
-                   "dvf_mem_* gauges, sentinel exit codes — CPU "
-                   "backend, bounded wall time; run in tier-1, select "
-                   "with -m ledger)")
+        "markers", "ledger: compile/reconfiguration ledger and memory "
+                   "accounting tests (bounded event ring, measured "
+                   "bucket stalls, dvf_mem_* gauges — CPU backend, "
+                   "bounded wall time; run in tier-1, select with "
+                   "-m ledger)")
     config.addinivalue_line(
         "markers", "elastic: controller-driven fleet autoscaling tests "
                    "(deterministic scale-decision replay, warm standby "
@@ -128,8 +127,8 @@ def pytest_configure(config):
         "markers", "swap: live-reconfiguration tests (compile-aside "
                    "program double-buffering, atomic hot swap, "
                    "mid-stream filter morph, chaos-injected swap "
-                   "aborts, swap_bench schema — CPU backend, bounded "
-                   "wall time; run in tier-1, select with -m swap)")
+                   "aborts — CPU backend, bounded wall time; run in "
+                   "tier-1, select with -m swap)")
 
 
 @pytest.fixture(scope="session", autouse=True)

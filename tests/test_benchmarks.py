@@ -130,83 +130,6 @@ def test_roofline_fields_models():
         roofline_fields(dict(r, platform="tpu", device_kind="TPU v9"))
 
 
-def _load_run_table_module():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "run_table", os.path.join(os.path.dirname(__file__), "..",
-                                  "benchmarks", "run_table.py"))
-    rt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rt)
-    return rt
-
-
-def test_run_table_freshness_rules():
-    rt = _load_run_table_module()
-
-    good = {"device": {"value": 1.0}, "e2e": {"value": 1.0},
-            "captured_utc": "2026-07-30T10:00:00+00:00"}
-    errd = {"device": {"error": "rc=-9"}, "e2e": {"value": 1.0},
-            "captured_utc": "2026-07-30T10:00:00+00:00"}
-    assert rt.is_fresh(good, "")
-    assert rt.is_fresh(good, "2026-07-30T09:00")
-    assert not rt.is_fresh(good, "2026-07-30T11:00")   # older than horizon
-    assert not rt.is_fresh(errd, "")                   # errors always rerun
-    assert not rt.is_fresh(None, "")
-    assert not rt.is_fresh({"e2e": {"value": 1}}, "")  # device leg missing
-    # Killed between legs: device persisted, e2e never ran → stale.
-    assert not rt.is_fresh(
-        {"device": {"value": 1.0},
-         "captured_utc": "2026-07-30T10:00:00+00:00"}, "")
-    # Legacy pre-incremental rows carry no stamp → stale even with no
-    # --min-fresh (their e2e percentiles predate the rate-controlled
-    # methodology and must not be republished under the new caption).
-    assert not rt.is_fresh(
-        {"device": {"value": 1.0}, "e2e": {"value": 1.0}}, "")
-
-    comp = {"jnp": {"fps": 5.0}, "pallas": {"fps": 9.0}, "winner": "pallas",
-            "captured_utc": "2026-07-30T10:00:00+00:00"}
-    assert rt.comparison_fresh(comp, "2026-07-30T09:00")
-    assert not rt.comparison_fresh(comp, "2026-07-31T00:00")
-    assert not rt.comparison_fresh(
-        dict(comp, pallas={"error": "x"}), "")
-    # Killed between impl legs: finished legs persisted, winner never
-    # computed → stale, the rerun fills the remaining impls.
-    partial = {"jnp": {"fps": 5.0},
-               "captured_utc": "2026-07-30T10:00:00+00:00"}
-    assert not rt.comparison_fresh(partial, "")
-
-    # Run-mode mismatch: a --quick or --cpu session's rows must never be
-    # treated as fresh by a full/TPU run in the same out-dir (they'd be
-    # republished under the TPU header).
-    assert not rt.is_fresh(dict(good, quick=True), "")
-    assert not rt.is_fresh(dict(good, forced_cpu=True), "")
-    assert rt.is_fresh(dict(good, quick=True), "", quick=True)
-    assert rt.is_fresh(dict(good, forced_cpu=True), "", forced_cpu=True)
-    assert not rt.is_fresh(good, "", forced_cpu=True)  # and vice versa
-    assert not rt.comparison_fresh(dict(comp, forced_cpu=True), "")
-    assert rt.comparison_fresh(dict(comp, forced_cpu=True), "",
-                               forced_cpu=True)
-
-    # Leg-level schema (phased runner): each leg carries its own stamp and
-    # mode, so a device leg from one session stays fresh while the e2e leg
-    # is still owed — the window-triage property.
-    dev = {"value": 1.0, "captured_utc": "2026-07-30T18:00:00+00:00"}
-    e2e = {"value": 2.0, "captured_utc": "2026-07-30T19:00:00+00:00"}
-    legged = {"device": dev, "e2e": e2e}
-    assert rt.leg_fresh(legged, "device", "2026-07-30T17:00")
-    assert rt.leg_fresh(legged, "e2e", "2026-07-30T18:30")
-    assert not rt.leg_fresh(legged, "device", "2026-07-30T18:30")  # stale
-    assert rt.is_fresh(legged, "2026-07-30T17:00")
-    assert not rt.is_fresh({"device": dev}, "")          # e2e owed
-    assert rt.leg_fresh({"device": dev}, "device", "")   # but device banked
-    # Leg-level mode beats entry-level fallback.
-    cpu_leg = dict(dev, forced_cpu=True)
-    assert not rt.leg_fresh({"device": cpu_leg}, "device", "")
-    assert rt.leg_fresh({"device": cpu_leg}, "device", "", forced_cpu=True)
-
-
 def test_stream_congested_verdicts():
     from dvf_tpu.benchmarks import stream_congested
 
@@ -275,31 +198,6 @@ def test_latency_backoff_exhausted_flags_congested(monkeypatch):
     assert r["target_fps"] == 2.0  # the lowest rate actually tried
 
 
-def test_e2e_leg_freshness_requires_congestion_verdict():
-    """Methodology gate: e2e percentiles captured before the backoff-
-    verified harness (no lat_congested field) are stale regardless of
-    stamp — the next session re-measures them honestly."""
-    rt = _load_run_table_module()
-
-    pre = {"e2e": {"value": 1.0, "p50_ms": 5.0,
-                   "captured_utc": "2026-07-31T10:00:00+00:00"}}
-    assert not rt.leg_fresh(pre, "e2e", "")
-    # v2 legs (drops-only verdict, no steady-delivery-rate signal) are
-    # stale too: they could false-negative on a short stream over a
-    # slow link.
-    v2 = {"e2e": {"value": 1.0, "p50_ms": 5.0, "lat_congested": False,
-                  "captured_utc": "2026-07-31T10:00:00+00:00"}}
-    assert not rt.leg_fresh(v2, "e2e", "")
-    post = {"e2e": {"value": 1.0, "p50_ms": 5.0, "lat_congested": False,
-                    "lat_delivery_fps": 9.5,
-                    "captured_utc": "2026-07-31T10:00:00+00:00"}}
-    assert rt.leg_fresh(post, "e2e", "")
-    # A leg that never published percentiles (fps-only) needs no verdict.
-    bare = {"e2e": {"value": 1.0,
-                    "captured_utc": "2026-07-31T10:00:00+00:00"}}
-    assert rt.leg_fresh(bare, "e2e", "")
-
-
 def test_latency_backoff_never_inflates_frames(monkeypatch):
     """Large batch must not raise the retry's frame count above the
     original leg's (a batch-derived floor would multiply wall time on
@@ -318,50 +216,6 @@ def test_latency_backoff_never_inflates_frames(monkeypatch):
     B.bench_e2e_latency(object(), n_frames=48, batch_size=64, height=8,
                         width=8, target_fps=2.4, max_backoffs=2)
     assert frames_seen == [48, 24, 16]  # monotonically non-increasing
-
-
-def test_congested_e2e_leg_is_never_fresh():
-    """A lat_congested=True capture renders (with ‡) but must not satisfy
-    freshness — a later run replaces it with real transit."""
-    rt = _load_run_table_module()
-
-    cong = {"e2e": {"value": 1.0, "p50_ms": 5000.0, "lat_congested": True,
-                    "captured_utc": "2026-07-31T10:00:00+00:00"}}
-    assert not rt.leg_fresh(cong, "e2e", "")
-
-
-def test_render_marks_unverified_and_congested_percentiles():
-    """Percentiles may render under the 'VERIFIED uncongested' caption
-    only when a v3 verdict travels with them: congested legs get ‡,
-    pre-verification legs get §."""
-    rt = _load_run_table_module()
-
-    doc = {"configs": {
-        "invert_640x480": {
-            "device": {"value": 1.0, "captured_utc": "2026-07-31T01:00"},
-            "e2e": {"value": 1.0, "p50_ms": 10.0, "p99_ms": 20.0,
-                    "lat_delivery_fps": 5.0, "lat_congested": False,
-                    "captured_utc": "2026-07-31T01:00"}},
-        "invert_1080p": {
-            "device": {"value": 1.0, "captured_utc": "2026-07-31T01:00"},
-            "e2e": {"value": 1.0, "p50_ms": 99.0, "p99_ms": 100.0,
-                    "lat_congested": True, "lat_delivery_fps": 0.1,
-                    "captured_utc": "2026-07-31T01:00"}},
-        "gauss3_1080p": {
-            "device": {"value": 1.0, "captured_utc": "2026-07-31T01:00"},
-            "e2e": {"value": 1.0, "p50_ms": 55.0, "p99_ms": 60.0,
-                    "lat_congested": False,  # v2: verdict without rate
-                    "captured_utc": "2026-07-31T01:00"}},
-    }, "impl_comparisons": {}, "updated_utc": "2026-07-31T01:00"}
-
-    md = rt.render_md(doc, forced_cpu=False)
-    row = {ln.split("|")[1].strip(): ln for ln in md.splitlines()
-           if ln.startswith("| ")}
-    assert "§" not in row["invert_640x480"]          # clean: no mark
-    assert "‡" not in row["invert_640x480"]
-    assert "| 10.0 |" in row["invert_640x480"]
-    assert "99.0 ‡" in row["invert_1080p"]           # verified congested
-    assert "55.0 §" in row["gauss3_1080p"]           # pre-verification
 
 
 def test_latency_backoff_floor_never_exceeds_original(monkeypatch):
@@ -476,129 +330,3 @@ def test_latency_backoff_invariants_property(monkeypatch):
         assert r["congested"] is last_cong
 
     check()
-
-
-def _load_bench_module():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_root2", os.path.join(os.path.dirname(__file__), "..",
-                                    "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_bench_without_a_chip_fails_and_prints_no_number(capsys):
-    """bench.py runs on the chip or not at all: with no TPU it exits
-    non-zero, names what it found on stderr, and prints NOTHING on stdout
-    — no CPU fallback line, no provisional line, no wait loop."""
-    bench = _load_bench_module()
-    assert bench.main([]) != 0
-    cap = capsys.readouterr()
-    assert cap.out == ""
-    assert "'cpu'" in cap.err and "not a tpu" in cap.err
-
-
-def test_bench_on_the_chip_prints_one_line_naming_the_device(monkeypatch,
-                                                             capsys):
-    """On a TPU the measurement runs once and the single JSON line names
-    platform, device_kind and n_devices beside the value."""
-    import json
-    import types
-
-    import jax
-
-    bench = _load_bench_module()
-    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-    monkeypatch.setattr(jax, "devices", lambda: [chip])
-    calls = []
-
-    def fake_measure(args, mode):
-        calls.append((args.batch, mode))
-        return {"device_fps": 45000.0, "p50_ms": 3.0, "e2e_fps": 900.0}
-
-    monkeypatch.setattr(bench, "measure", fake_measure)
-    assert bench.main(["--batch", "32"]) == 0
-    assert calls == [(32, "headline")]
-    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.strip()]
-    assert len(lines) == 1
-    out = json.loads(lines[0])
-    assert out["metric"] == "1080p_invert_device_fps"
-    assert out["value"] == 45000.0 and out["vs_baseline"] == 22.5
-    assert (out["platform"], out["device_kind"], out["n_devices"]) == (
-        "tpu", "TPU v5 lite", 1)
-    assert "fallback" not in out and "provisional" not in out
-    # --e2e names the other metric and skips the device-resident leg.
-    assert bench.main(["--e2e"]) == 0
-    assert calls[-1][1] == "e2e"
-    out = json.loads(capsys.readouterr().out.strip())
-    assert out["metric"] == "1080p_invert_e2e_fps"
-
-
-def test_stale_code_device_mark_and_freshness():
-    """A device leg carrying stale_code renders with the ¶ mark + footnote
-    and is never considered fresh, so the next session re-measures it."""
-    rt = _load_run_table_module()
-
-    doc = {"configs": {
-        "gauss9_1080p": {
-            "device": {"value": 1685.5, "stale_code": "pre-Mosaic capture",
-                       "captured_utc": "2026-07-31T01:42"},
-            "e2e": {"value": 1.0, "p50_ms": 5.0, "lat_delivery_fps": 2.0,
-                    "lat_congested": False,
-                    "captured_utc": "2026-07-31T01:42"}},
-    }, "impl_comparisons": {}, "updated_utc": "2026-07-31T01:42"}
-    md = rt.render_md(doc, forced_cpu=False)
-    row = next(ln for ln in md.splitlines() if ln.startswith("| gauss9"))
-    assert "1685.5 ¶" in row
-    assert "pre-Mosaic capture" in md
-    assert not rt.leg_fresh(doc["configs"]["gauss9_1080p"], "device", "")
-
-
-def test_failed_remeasure_keeps_best_available_leg(tmp_path, monkeypatch):
-    """A stale_code-marked leg re-runs; if the re-measure ERRORS (the
-    child crashed or timed out), the kept best-available number and its
-    provenance must survive, with the failed attempt recorded beside
-    them, and the table run still completes (rc 0: an errored leg is a
-    row to retry, not a reason to stop)."""
-    import json
-
-    rt = _load_run_table_module()
-    json_path = tmp_path / "BENCH_TABLE.json"
-    # flow_720p: a TABLE config with no same-named COMPARISONS entry, so
-    # --only runs exactly one (mocked) device leg and no impl A/Bs.
-    json_path.write_text(json.dumps({"configs": {
-        "flow_720p": {"device": {
-            "value": 1685.5, "stale_code": "pre-Mosaic capture",
-            "captured_utc": "2026-07-31T01:42"}}},
-        "impl_comparisons": {}}))
-    monkeypatch.setattr(rt, "bench_config",
-                        lambda *a, **k: {"error": "rc=-9: child killed"})
-    rc = rt.main(["--out-dir", str(tmp_path), "--only", "flow_720p",
-                  "--legs", "device", "--min-fresh", "2026-07-31T15:45"])
-    assert rc == 0
-    doc = json.loads(json_path.read_text())
-    leg = doc["configs"]["flow_720p"]["device"]
-    assert leg["value"] == 1685.5                  # best-available kept
-    assert leg["stale_code"] == "pre-Mosaic capture"
-    assert "child killed" in leg["last_retry_error"]["error"]
-
-
-def test_e2e_stale_code_renders_marked():
-    rt = _load_run_table_module()
-    doc = {"configs": {
-        "flow_720p": {
-            "device": {"value": 37.9, "captured_utc": "2026-07-31T01:44"},
-            "e2e": {"value": 4.8, "p50_ms": 9.0, "lat_delivery_fps": 2.0,
-                    "lat_congested": False, "stale_code": "pre-dedup",
-                    "captured_utc": "2026-07-31T01:27"}},
-    }, "impl_comparisons": {}, "updated_utc": "2026-07-31T01:44"}
-    md = rt.render_md(doc, forced_cpu=False)
-    row = next(ln for ln in md.splitlines() if ln.startswith("| flow"))
-    assert "4.8 ¶" in row and "9.0 ¶" in row
-    assert "pre-dedup" in md
-    assert not rt.leg_fresh(doc["configs"]["flow_720p"], "e2e", "")
-
-

@@ -11,8 +11,7 @@ re-encodes), the subscribe CLI's dead-gate exit code, and the worker's
 graceful SIGTERM drain.
 
 Process-mode front-door crash + re-adopt is exercised end to end by
-``benchmarks/continuity_bench.py`` (the CI smoke runs it); the pytest
-variant here is ``slow``-marked.
+``test_fleet_process_crash_resume``, which is ``slow``-marked.
 """
 
 import json
@@ -289,7 +288,7 @@ def test_zmq_bridge_send_retry_reuses_encoded_payload():
     arrives bit-correct."""
     zmq = pytest.importorskip("zmq")
 
-    from benchtools import free_port
+    from _util import free_port
     from dvf_tpu.serve import ZmqStreamBridge
 
     class FlakyPush:
@@ -477,8 +476,7 @@ def test_fleet_process_crash_resume(tmp_path):
     """Front-door kill -9 (``crash()`` abandons live workers) followed
     by ``resume_state=True``: still-live process replicas are
     re-adopted, the open session survives with monotone indices, and
-    the pre-crash resume token still verifies. (The CI smoke runs the
-    timed variant in benchmarks/continuity_bench.py.)"""
+    the pre-crash resume token still verifies."""
     import dataclasses
 
     from dvf_tpu.fleet import FleetConfig, FleetFrontend
@@ -542,7 +540,7 @@ def test_subscribe_dead_gate_exits_3():
     after the full --timeout deadline."""
     zmq = pytest.importorskip("zmq")
 
-    from benchtools import free_port
+    from _util import free_port
     from dvf_tpu.cli import main as cli_main
 
     port = free_port()
@@ -585,7 +583,7 @@ def test_worker_sigterm_graceful_stats_line():
     supervisor's kill gets the same accounting as a max_frames exit."""
     pytest.importorskip("zmq")
 
-    from benchtools import free_port
+    from _util import free_port
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu")

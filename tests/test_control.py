@@ -12,7 +12,8 @@ batcher's tier-then-EDF slot pick shed batch-tier work before
 interactive; controller decisions are visible on ``/metrics`` and in
 ``stats()``. Satellites pinned here: ``TimeSeriesRing`` hook-exception
 containment, the ``FlightRecorder`` disk-byte cap, the mixed
-uint8+bf16 signature mix, and the soak bench's quick-mode schema.
+uint8+bf16 signature mix, and a controlled overload at toy size (no
+hard failures; the recorded window replays).
 """
 
 import time
@@ -666,33 +667,88 @@ class TestFlightRecorderByteCap:
         assert len(rec.dumps) == 3
 
 
-# ------------------------------------------------- soak bench schema
+# ------------------------------------------------- controlled overload
 
 
-class TestSoakBenchQuick:
-    def test_soak_bench_writer_schema(self):
-        """Satellite: the SOAK_BENCH.json writer is schema-conformant
-        in quick mode (seconds), like ADMIT_BENCH/DELTA_BENCH — a
-        renamed key breaks here, not on the committed artifact."""
-        import os
-        import sys
+class TestControlledOverload:
+    def test_no_hard_failures_and_the_recorded_window_replays(self):
+        """Two interactive tenants and a churning batch tenant flood a
+        controlled frontend past its queue: no live session errors (a
+        refused batch-tier open is graceful shed, not a failure), and
+        the rows the live plane decided on, replayed through a fresh
+        plane, give the recorded actions again, twice."""
+        import copy
 
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from benchmarks.soak_bench import run
+        from dvf_tpu.serve.session import ServeError
 
-        doc = run(quick=True)
-        assert not walk_export(doc), walk_export(doc)
-        for leg in ("uncontrolled_capacity", "uncontrolled_overload",
-                    "controlled_overload"):
-            row = doc[leg]
-            assert row["sessions_opened_total"] > 0, leg
-            assert row["delivered_total"] > 0, leg
-            assert set(row["tiers"]) == {"interactive", "standard",
-                                         "batch"}
-        assert doc["controlled_overload"]["control"] is True
-        assert "control_actions" in doc["controlled_overload"]
-        acc = doc["acceptance"]
-        assert "controlled_interactive_p99_over_baseline_ratio" in acc
-        # Quick mode only pins the harness, not the collapse ratios —
-        # but a controlled quick leg must still be failure-free.
-        assert doc["controlled_overload"]["hard_failures_total"] == 0
+        ccfg = ControlConfig(interval_s=0.05, down_after=2, up_after=8,
+                             min_dwell=4, overload_after=2,
+                             saturate_after=6, resize_hold=2,
+                             resize_cooldown=4)
+        fe = ServeFrontend(get_filter("invert"), ServeConfig(
+            batch_size=4, queue_size=16, out_queue_size=4096,
+            slo_ms=2_000.0, max_sessions=8, control=True,
+            control_config=ccfg))
+        plane = fe.control_plane
+        live_decide = plane.decide
+        rows, recorded = [], []
+
+        def recording_decide(row):
+            rows.append(copy.deepcopy(row))
+            actions = live_decide(row)
+            recorded.extend((a.kind, a.target, a.value, a.reason)
+                            for a in actions)
+            return actions
+
+        plane.decide = recording_decide
+        frame = np.random.default_rng(5).integers(
+            0, 255, (H, W, 3), dtype=np.uint8)
+        hard_failures = refusals = delivered = rounds = 0
+        churn = None
+        with fe:
+            fe.precompile([
+                {"op_chain": "invert", "frame_shape": [H, W, 3]},
+                {"op_chain": "invert|upscale(scale=2)",
+                 "frame_shape": [H // 2, W // 2, 3]}])
+            tenants = [fe.open_stream(op_chain="invert",
+                                      frame_shape=(H, W, 3),
+                                      tier=TIER_INTERACTIVE)
+                       for _ in range(2)]
+            t_end = time.time() + 1.5
+            while time.time() < t_end or len(rows) < 8:
+                rounds += 1
+                try:
+                    if churn is None:
+                        try:
+                            churn = fe.open_stream(
+                                op_chain="invert", frame_shape=(H, W, 3),
+                                tier=TIER_BATCH)
+                        except AdmissionError:
+                            refusals += 1
+                    for sid in tenants + [churn] * (churn is not None):
+                        for _ in range(8):
+                            fe.submit(sid, frame)
+                        delivered += len(fe.poll(sid))
+                    if churn is not None and rounds % 10 == 0:
+                        fe.close(churn, drain=False)
+                        churn = None
+                except (ServeError, ValueError):
+                    hard_failures += 1
+                time.sleep(0.005)
+            stats = fe.stats()
+        assert hard_failures == 0
+        assert delivered > 0
+        assert stats["errors"] == 0
+        assert stats["shed_total"] + sum(
+            s["dropped_at_ingress"]
+            for s in stats["sessions"].values()) > 0   # it WAS a flood
+
+        def replay():
+            fresh = ControlPlane(_FakeActuator(), plane.config)
+            return [(a.kind, a.target, a.value, a.reason)
+                    for row in rows
+                    for a in fresh.decide(copy.deepcopy(row))]
+
+        assert recorded, "the window recorded no action"
+        assert replay() == recorded
+        assert replay() == recorded

@@ -894,7 +894,7 @@ class TestServeBridgeDelta:
         import sys as _sys
 
         _sys.path.insert(0, ".")
-        from benchtools import free_port
+        from _util import free_port
         from dvf_tpu.serve import ZmqStreamBridge
         from dvf_tpu.serve.server import ServeConfig, ServeFrontend
 

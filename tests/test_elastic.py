@@ -25,6 +25,7 @@ from dvf_tpu.control.fleet_elastic import (
     FLAVOR_MULTIHOST,
     FleetElasticityController,
     fleet_pressure,
+    make_elasticity_controller,
 )
 from dvf_tpu.fleet import FleetConfig, FleetFrontend, StandbyPool
 from dvf_tpu.fleet.elastic import live_standby_handles
@@ -66,6 +67,16 @@ def _ecfg(**kw) -> ElasticConfig:
                 in_occupancy_frac=0.6, saturate_after=4, interval_s=0.1)
     base.update(kw)
     return ElasticConfig(**base)
+
+
+def _replay(config: ElasticConfig, rows) -> list:
+    """A fresh controller over recorded rows: the actions it emits."""
+    ctl, seq, prev = make_elasticity_controller(config), [], None
+    for row in rows:
+        for a in ctl.step(dict(row), prev):
+            seq.append((a.kind, a.target, a.value, a.reason))
+        prev = row
+    return seq
 
 
 def _row(desired=1, live=None, refusals=0.0, cap=8.0, bound=0.0,
@@ -117,16 +128,8 @@ class TestFleetElasticityController:
         return rows
 
     def test_same_window_replayed_twice_identical_actions(self):
-        def run_once():
-            ctl = FleetElasticityController(_ecfg())
-            seq, prev = [], None
-            for row in self._window():
-                for a in ctl.step(dict(row), prev):
-                    seq.append((a.kind, a.target, a.value, a.reason))
-                prev = row
-            return seq
-
-        first, second = run_once(), run_once()
+        first, second = (_replay(_ecfg(), self._window())
+                         for _ in range(2))
         assert first == second
         kinds = [a[0] for a in first]
         assert "scale_out" in kinds and "scale_in" in kinds
@@ -425,6 +428,41 @@ class TestElasticFleetLocal:
         assert st["elastic"]["decisions"], "decision log empty"
         assert st["rejections_by_tier"].get(1, 0) >= 1
 
+    def test_live_window_replays_to_the_recorded_actions(self):
+        """A fleet that went 1 -> 2 -> 1 under refusal pressure hands
+        back the window it decided on; a fresh controller over those
+        rows emits the recorded action list again, byte for byte (a
+        scaling incident is reproducible from its telemetry)."""
+        elastic = _ecfg(max_replicas=2)
+        fleet = self._fleet(autoscale=(1, 2), elastic=elastic)
+        with fleet:
+            keep = fleet.open_stream()
+            extras = [fleet.open_stream() for _ in range(3)]   # r0 full
+
+            def knock():
+                try:
+                    extras.append(fleet.open_stream())
+                except AdmissionError:
+                    pass
+                return fleet.signals()["replicas_live"] >= 2
+
+            assert wait_for(knock, deadline_s=60.0, period=0.05), \
+                fleet.stats()
+            for sid in extras:
+                fleet.close(sid, drain=True)
+            assert wait_for(
+                lambda: (fleet.signals()["replicas_live"] == 1
+                         and fleet.signals()["scale_in_total"] >= 1),
+                deadline_s=60.0), fleet.stats()
+            fleet.submit(keep, tagged_frame(0, 0))
+            assert wait_for(lambda: len(fleet.poll(keep)) == 1)
+            window = fleet.elastic.replay_window()
+        recorded = [tuple(a) for a in window["actions"]]
+        kinds = [a[0] for a in recorded]
+        assert kinds.index("scale_out") < kinds.index("scale_in")
+
+        assert _replay(elastic, window["rows"]) == recorded
+
     def test_metrics_endpoint_gauges(self):
         """Satellite: /metrics walks the elastic gauges + counters."""
         fleet = self._fleet(standby_warm=0, autoscale=None)
@@ -711,32 +749,3 @@ class TestScaleInChaos:
         assert st["order_violations"] == 0
         assert rb not in st["replicas"]  # the retire completed its
         #   bookkeeping even though the victim died under it
-
-
-# ------------------------------------------------------- bench quick mode
-
-
-class TestElasticBenchQuick:
-    def test_elastic_bench_writer_schema(self):
-        """benchmarks/elastic_bench.run(quick=True) emits the committed
-        document shape: spawn A/B with the warm/cold ratio, the
-        step-overload phases, scale accounting, and a PASSING
-        deterministic replay of the recorded telemetry window."""
-        from dvf_tpu.obs.registry import walk_export
-
-        from benchmarks.elastic_bench import run
-
-        doc = run(quick=True)
-        assert doc["schema"] == "dvf.elastic_bench.v1"
-        bad = walk_export(doc)
-        assert not bad, f"non-conformant keys: {bad}"
-        spawn = doc["spawn"]
-        for k in ("standby_spawn_to_first_frame_ms",
-                  "cold_spawn_to_first_frame_ms", "speedup_ratio"):
-            assert spawn[k] is not None
-        soak = doc["soak"]
-        assert soak["scale_out_total"] >= 1
-        assert soak["replicas_peak"] >= 2
-        assert soak["hard_failures_total"] == 0
-        assert doc["replay"]["match"] is True
-        assert doc["replay"]["actions"] >= 1

@@ -227,8 +227,9 @@ class TestServeLineage:
     def _frontend(self, tmp_path=None, **kw):
         from dvf_tpu.serve import ServeConfig, ServeFrontend
 
+        kw.setdefault("lineage", True)
         cfg = ServeConfig(batch_size=2, queue_size=100, slo_ms=60_000.0,
-                          lineage=True, telemetry_sample_s=0.0, **kw)
+                          telemetry_sample_s=0.0, **kw)
         return ServeFrontend(get_filter("invert"), cfg)
 
     def test_every_delivered_frame_is_additive(self):
@@ -277,6 +278,43 @@ class TestServeLineage:
                                ("snapshot", fe.attribution.snapshot())):
                 bad = walk_export(doc)
                 assert not bad, (label, bad)
+
+    def test_same_deliveries_with_lineage_on_and_off(self):
+        """Three sessions in a closed loop (a few batches in flight)
+        through a frontend with attribution armed and one without: each
+        session gets the same indices and bytes either way, and where it
+        is armed every frame's components still sum to its latency."""
+        n, window = 24, 6
+        runs = {}
+        for armed in (True, False):
+            fe = self._frontend(lineage=armed)
+            got = {}
+            with fe:
+                sids = [fe.open_stream() for _ in range(3)]
+                sent = 0
+                while sent < n:
+                    for k, sid in enumerate(sids):
+                        fe.submit(sid, frame_u8(k, sent))
+                    sent += 1
+                    for sid in sids:
+                        got.setdefault(sid, []).extend(fe.poll(sid))
+                    while sent - min(len(v) for v in got.values()) \
+                            >= window:
+                        for sid in sids:
+                            got[sid].extend(fe.poll(sid))
+                        time.sleep(0.001)
+                for sid in sids:
+                    got[sid] += drain(fe, sid, n - len(got[sid]))
+            runs[armed] = [got[sid] for sid in sids]
+        for on, off in zip(runs[True], runs[False]):
+            assert [d.index for d in on] == list(range(n))
+            assert [d.index for d in off] == list(range(n))
+            assert [d.frame.tobytes() for d in on] == \
+                [d.frame.tobytes() for d in off]
+            assert all(d.lineage is None for d in off)
+            for d in on:
+                assert sum(d.lineage.components_ms().values()) == \
+                    pytest.approx(d.latency_ms, abs=1e-6)
 
     def test_lineage_off_is_zero_cost_surface(self):
         from dvf_tpu.serve import ServeConfig, ServeFrontend

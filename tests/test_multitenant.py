@@ -250,6 +250,62 @@ class TestMixedSignatures:
         for x, y in zip(first, second):
             np.testing.assert_array_equal(x, y)
 
+    def test_returning_signature_is_a_pool_hit_and_a_join_compiles_nothing(
+            self):
+        """The admission ladder in counts: a second session of a live
+        signature routes to its bucket (no pool traffic, no XLA
+        compile), and a signature whose bucket retired while its
+        program stayed in the pool comes back as a pool hit, again with
+        no XLA compile, serving the same bytes."""
+        from dvf_tpu.obs.ledger import XLA_COMPILES
+
+        shape = (H + 2, W, 3)
+        frames = frames_for(shape, np.uint8, 4, seed=11)
+        fe = ServeFrontend(get_filter("invert"),
+                           cfg(batch_size=2, max_buckets=2,
+                               pool_capacity=8))
+
+        def serve(sid):
+            for f in frames:
+                fe.submit(sid, f)
+            got = drain_session(fe, sid, len(frames))
+            assert len(got) == len(frames)
+            return [d.frame.tobytes() for d in got]
+
+        def leave(*sids):
+            for sid in sids:
+                fe.close(sid, drain=True)
+            deadline = time.time() + 20
+            while fe.open_count() > 0 and time.time() < deadline:
+                time.sleep(0.005)
+
+        def counts():
+            pool = fe.stats()["pool"]
+            return (pool["hits"], pool["misses"],
+                    XLA_COMPILES.totals()[0])
+
+        with fe:
+            a = fe.open_stream(op_chain="grayscale|invert",
+                               frame_shape=shape)
+            first = serve(a)       # two batches: the step's own jit too
+            hits, misses, compiles = counts()
+            assert (hits, misses) == (0, 1)
+            joined = fe.open_stream(op_chain="grayscale|invert",
+                                    frame_shape=shape)
+            assert serve(joined) == first
+            assert counts() == (0, 1, compiles)
+            leave(a, joined)
+            # A second signature at the bucket cap retires the idle one.
+            b = fe.open_stream(op_chain="grayscale", frame_shape=shape)
+            serve(b)
+            leave(b)
+            hits, misses, compiles = counts()
+            assert (hits, misses) == (0, 2)
+            back = fe.open_stream(op_chain="grayscale|invert",
+                                  frame_shape=shape)
+            assert serve(back) == first
+            assert counts() == (1, 2, compiles)
+
     def test_edf_cost_scheduler_never_starves_small_bucket(self):
         """A big, continuously-loaded bucket on a slowed engine vs a
         small tight-SLO bucket: the EDF-headroom ÷ tick-cost score must

@@ -1073,51 +1073,3 @@ class TestExportSchemas:
             bench_e2e_streaming(get_filter("invert"), 16, 4, 16, 16))
         self._assert_clean("jpeg_wire_budget",
                            jpeg_wire_budget(32, 32, threads=1))
-
-    def test_attr_bench_writer(self):
-        """The ATTR_BENCH.json writer is schema-conformant in quick
-        mode, and the COMMITTED artifact pins the lineage overhead gate:
-        attribution-on serve throughput within the ≤3% budget of
-        attribution-off on the same paced harness (measured best-of
-        interleaved trials — quick mode on a noisy box is a smoke test,
-        not evidence, so the budget assert reads the committed run)."""
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from benchmarks.attr_bench import OVERHEAD_BUDGET_FRAC, run
-
-        doc = run(quick=True)
-        self._assert_clean("attr_bench", doc)
-        acc = doc["acceptance"]
-        assert acc["overhead_budget_frac"] == OVERHEAD_BUDGET_FRAC
-        assert acc["measured_overhead_frac"] is not None
-        assert doc["lineage_on"]["best_fps"] > 0
-        committed = os.path.join(os.path.dirname(__file__), "..",
-                                 "benchmarks", "ATTR_BENCH.json")
-        with open(committed) as f:
-            shipped = json.load(f)
-        self._assert_clean("attr_bench_committed", shipped)
-        acc = shipped["acceptance"]
-        assert acc["within_budget"] is True, acc
-        assert acc["measured_overhead_frac"] <= \
-            acc["overhead_budget_frac"], acc
-
-    def test_admit_bench_writer(self):
-        """The ADMIT_BENCH.json writer (benchmarks/admit_bench.run) is
-        schema-conformant in quick mode — a renamed key there breaks
-        here instead of silently shipping a non-scrapable bench doc."""
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from benchmarks.admit_bench import run
-
-        doc = run(quick=True)
-        self._assert_clean("admit_bench", doc)
-        acc = doc["acceptance"]
-        # Quick mode still demonstrates the acceptance inequality: a
-        # pool-hit admission beats a cold JIT admission ≥ 10×.
-        assert acc["warm_admit_speedup_measured"] >= \
-            acc["warm_admit_speedup_target"]
-        assert doc["mixed"]["mixed_over_solo_ratio"] is not None

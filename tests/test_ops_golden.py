@@ -260,10 +260,10 @@ def test_equalize_per_channel_flattens_histogram():
 
 
 def test_measured_per_backend_defaults():
-    """impl=None resolves to the MEASURED winner for this backend
-    (benchmarks/cpu/BENCH_TABLE.md impl comparisons: the fused Pallas
-    programs win on CPU for sobel_bilateral and gauss-k9); an explicit
-    impl always pins, and unmeasured cases keep the conservative default."""
+    """impl=None resolves to the declared winner for this backend (the
+    fused Pallas programs on the CPU for sobel_bilateral and gauss-k9);
+    an explicit impl always pins, and unmeasured cases keep the
+    conservative default."""
     import pytest
 
     from dvf_tpu.ops import get_filter
@@ -271,9 +271,9 @@ def test_measured_per_backend_defaults():
     # CPU winners (this suite forces the cpu backend in conftest).
     assert "pallas" in get_filter("sobel_bilateral").name
     assert "pallas" in get_filter("gaussian_blur").name          # k=9
-    # Small kernel: shift wins the committed gauss3 A/B on both backends.
+    # Small kernel: shift on both backends.
     assert "pallas" not in get_filter("gaussian_blur", ksize=3).name
-    # Explicit impl pins — the A/B harness depends on this.
+    # Explicit impl pins.
     assert "pallas" not in get_filter("sobel_bilateral", impl="chain").name
     assert "pallas" not in get_filter("gaussian_blur", impl="shift").name
     with pytest.raises(ValueError, match="impl"):
@@ -285,6 +285,63 @@ def test_measured_per_backend_defaults():
     assert measured_default({"tpu": "a"}, fallback="b") == "b"
     with pytest.raises(ValueError, match="pallas"):
         get_filter("gaussian_blur", impl="palas")
+
+
+# MEASURED_DEFAULTS key -> (factory, fixed kwargs, the argument the
+# entry's impl goes to, what impl=None resolves to on the CPU).
+_DEFAULT_SITES = {
+    "bilateral": ("bilateral", {}, "impl", "jnp"),
+    "sobel_bilateral": ("sobel_bilateral", {}, "impl", "pallas"),
+    "flow_warp": ("flow_warp", {}, "warp_impl", "gather"),
+    "flow_inner": ("flow_warp", {"warp_impl": "pallas"}, "inner_warp",
+                   "gather"),
+    "gaussian_blur_k9": ("gaussian_blur", {"ksize": 9}, "impl", "pallas"),
+    "gaussian_blur_small": ("gaussian_blur", {"ksize": 3}, "impl", "shift"),
+    "espcn_fast": ("super_resolution", {}, "fast_convs", "ref"),
+}
+
+
+def _built(key, impl=None):
+    """What the factory behind ``key`` builds with ``impl`` pinned (None =
+    the default), as something two builds can be compared by: the name,
+    and for ESPCN (one name for both forms) the traced program."""
+    import jax
+
+    name, kwargs, arg, _ = _DEFAULT_SITES[key]
+    if impl is not None:
+        kwargs = {**kwargs,
+                  arg: (impl == "fast") if key == "espcn_fast" else impl}
+    filt = get_filter(name, **kwargs)
+    if key != "espcn_fast":
+        return filt.name
+    state = filt.init_state((1, 8, 8, 3), jnp.float32)
+    return str(jax.make_jaxpr(filt.fn)(
+        jnp.zeros((1, 8, 8, 3), jnp.float32), state))
+
+
+def test_default_sites_cover_the_table():
+    from dvf_tpu.ops.registry import MEASURED_DEFAULTS
+
+    assert set(_DEFAULT_SITES) == set(MEASURED_DEFAULTS)
+
+
+@pytest.mark.parametrize("key", sorted(_DEFAULT_SITES))
+def test_measured_default_entry_holds_winners_its_factory_accepts(key):
+    """An entry is ``winners`` and ``fallback`` and nothing else, every
+    impl it names is one its factory builds, and ``impl=None`` on the CPU
+    builds what it built before the table lost its harness fields."""
+    from dvf_tpu.ops.registry import MEASURED_DEFAULTS
+
+    entry = MEASURED_DEFAULTS[key]
+    assert set(entry) == {"winners", "fallback"}
+    assert set(entry["winners"]) <= {"tpu", "cpu"}
+    impls = set(entry["winners"].values()) | {entry["fallback"]}
+    built = {impl: _built(key, impl) for impl in sorted(impls)}
+    if len(impls) > 1:
+        assert len(set(built.values())) == len(impls)   # pins tell apart
+    on_cpu = _DEFAULT_SITES[key][3]
+    assert entry["winners"].get("cpu", entry["fallback"]) == on_cpu
+    assert _built(key) == _built(key, on_cpu)
 
 
 def test_median_blur_matches_cv2():

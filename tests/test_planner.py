@@ -1,7 +1,8 @@
 """Auto-plan plane tests (PR 20): plan-cache keying/invalidation, the
 planner's analytic prune + measured search, calibration persistence,
-the feed-forward predictive elasticity controller, and the offline
-replay regression over the committed PLAN_BENCH.json window.
+the feed-forward predictive elasticity controller, a cold search and a
+warm restart through a live frontend, and a live fleet's recorded window
+replayed offline.
 
 Keying discipline pinned here: a plan searched under one (op chain,
 geometry, topology, planner version) must NEVER drive another — each
@@ -10,7 +11,6 @@ rather than crashes.
 """
 
 import dataclasses
-import importlib.util
 import json
 import os
 
@@ -182,6 +182,41 @@ def test_plan_search_all_legs_error_degrades_to_analytic():
     assert plan.source == pl.PLAN_SOURCE_ANALYTIC
     # And an analytic plan never persists as if measured.
     assert pl.plan_to_cache("/tmp/x", SIG, GEO, TOPO, plan) is None
+
+
+@pytest.mark.parametrize("case, measured, winner", [
+    ("ranking", "abc", "b"),
+    ("failed_leg", "abc", "c"),      # b errors: recorded, cannot win
+    ("kept_prior", "bc", "a"),       # a's prior entry is fresh: seeded
+])
+def test_ab_comparison(case, measured, winner):
+    """The leg machinery where it lives now: legs are measured in order
+    and the highest ``fps`` wins; an erroring leg is recorded, never
+    raised; a prior leg ``keep_leg`` accepts is seeded and not measured
+    again (a prior leg that is no leg of this comparison is left out)."""
+    fps = {"a": 10.0, "b": 30.0, "c": 20.0}
+    prior = {"a": {"fps": 99.0, "fresh": True},
+             "b": {"fps": 1.0, "fresh": False}, "zz": {"fps": 500.0}}
+    seen, said = [], []
+
+    def measure(label, payload):
+        seen.append((label, payload))
+        if case == "failed_leg" and label == "b":
+            return {"error": "boom"}
+        return {"fps": fps[label]}
+
+    comp = pl.ab_comparison(
+        [(k, k.upper()) for k in fps], measure,
+        prior=prior if case == "kept_prior" else None,
+        keep_leg=lambda entry: entry.get("fresh"), log=said.append)
+    assert seen == [(k, k.upper()) for k in measured]
+    assert comp.pop("winner") == winner
+    assert set(comp) == set(fps)
+    assert comp["b"] == ({"error": "boom"} if case == "failed_leg"
+                         else {"fps": 30.0})
+    assert (comp["a"] is prior["a"]) == (case == "kept_prior") == bool(said)
+    every = pl.ab_comparison([("x", None)], lambda *_: {"error": "x"})
+    assert every["winner"] == "n/a"
 
 
 def test_predicted_tick_cost_ms_feeds_forward():
@@ -361,83 +396,88 @@ def test_predictive_replay_is_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# The committed PLAN_BENCH.json: schema + offline replay regression
+# The plane end to end: a warm plan cache, and a live window replayed
 # ---------------------------------------------------------------------------
 
 
-def _load_plan_bench():
-    spec = importlib.util.spec_from_file_location(
-        "plan_bench", os.path.join(os.path.dirname(__file__), "..",
-                                   "benchmarks", "plan_bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def test_warm_plan_cache_yields_one_plan_event_read_from_the_cache(tmp_path):
+    """A cold boot searches (a miss: at most a third of the grid goes
+    live, the winner is measured and cached); a second frontend on the
+    same cache directory applies that plan from one read: one ``plan``
+    event, a hit, no legs, the same operating point."""
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.serve import ServeConfig, ServeFrontend
+
+    def boot():
+        fe = ServeFrontend(get_filter("invert"), ServeConfig(
+            batch_size=4, queue_size=32, out_queue_size=1024,
+            slo_ms=60_000.0, autoplan=True, plan_cache_dir=str(tmp_path),
+            autoplan_burst_frames=8))
+        with fe:
+            doc = fe.autoplan(GEO, "uint8")
+            events = [e for e in fe.ledger.document()["events"]
+                      if e["kind"] == "plan"]
+        return doc, events
+
+    cold, cold_events = boot()
+    assert [e["cache"] for e in cold_events] == ["miss"]
+    assert cold["source"] == pl.PLAN_SOURCE_MEASURED
+    assert 1 <= cold["searched"] <= cold["grid"] // 3
+    assert cold_events[0]["legs"] == cold["searched"]
+
+    warm, warm_events = boot()
+    assert [e["cache"] for e in warm_events] == ["hit"]
+    assert warm_events[0]["legs"] == 0
+    assert warm["source"] == pl.PLAN_SOURCE_CACHE
+    assert [warm[k] for k in ("batch_size", "tick_s", "ingest_depth")] == \
+        [cold[k] for k in ("batch_size", "tick_s", "ingest_depth")]
 
 
-def _committed_doc():
-    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "PLAN_BENCH.json")
-    with open(path) as f:
-        return json.load(f)
+def test_live_predictive_window_replays_byte_identically_twice():
+    """A local fleet under the predictive controller takes tenants one
+    by one until it has scaled out; the window it recorded, replayed
+    through two fresh predictive controllers, gives the recorded actions
+    both times, and over the same rows the predictive controller scales
+    out no later than the reactive one."""
+    import time
 
+    from dvf_tpu.control.fleet_elastic import (
+        ElasticConfig,
+        make_elasticity_controller,
+    )
+    from dvf_tpu.fleet import FleetConfig, FleetFrontend
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.serve import AdmissionError, ServeConfig
 
-def test_plan_bench_committed_doc_schema_and_gates():
-    doc = _committed_doc()
-    assert doc["schema"] == "dvf.plan_bench.v1"
-    assert doc["quick"] is False   # the committed artifact is full-mode
-    pb = _load_plan_bench()
-    for metric, ok, detail in pb.check(doc):
-        assert ok, f"{metric}: {detail}"
-    # The searched winner was measured, cached, and the warm restart
-    # hit the cache with the same operating point.
-    s = doc["search"]
-    assert s["cold"]["ledger_cache"] == "miss"
-    assert s["warm"]["ledger_cache"] == "hit"
-    assert s["warm"]["source"] == "cache"
-    assert s["warm"]["matches_cold"]
+    elastic = ElasticConfig(
+        min_replicas=1, max_replicas=2, interval_s=0.1, out_after=2,
+        out_cooldown=4, in_after=30, in_cooldown=3, in_occupancy_frac=0.6,
+        predictive=True, predict_slope_window=3, predict_horizon=4)
+    fleet = FleetFrontend(get_filter("invert"), FleetConfig(
+        replicas=1, mode="local", autoscale=(1, 2), standby_warm=1,
+        elastic=elastic, health_poll_s=0.05,
+        serve=ServeConfig(batch_size=2, queue_size=256, slo_ms=60_000.0,
+                          max_sessions=4)))
+    with fleet:
+        deadline = time.time() + 60.0
+        while fleet.signals()["replicas_live"] < 2:
+            assert time.time() < deadline, fleet.stats()
+            try:
+                fleet.open_stream()     # the occupancy ramps
+            except AdmissionError:
+                pass
+            time.sleep(0.25)            # a few samples a tenant
+        window = fleet.elastic.replay_window()
+    recorded = [tuple(a) for a in window["actions"]]
+    assert "scale_out" in [a[0] for a in recorded]
 
+    def replay(config):
+        return _run(make_elasticity_controller(config), window["rows"])
 
-def test_plan_bench_replay_regression():
-    """Satellite (d): the predictive controller replayed offline over
-    the committed step-overload window scales out BEFORE the window's
-    first admission-refusal advance, byte-deterministically, and the
-    reactive replay reproduces the recorded action stream exactly."""
-    from dvf_tpu.control.fleet_elastic import ElasticConfig
-
-    doc = _committed_doc()
-    pb = _load_plan_bench()
-    w = doc["controller"]["window"]
-    rows = w["recorded_rows"]
-    assert len(rows) == w["rows"] and rows
-    elastic = ElasticConfig(**doc["controller"]["elastic"])
-
-    # Reactive replay == the recorded live action stream.
-    reactive = pb.replay_controller(
-        rows, dataclasses.replace(elastic, predictive=False))
-    assert [a[1:] for a in reactive] == [
-        list(a) for a in w["recorded_actions"]]
-
-    # Predictive replay: byte-deterministic, matches the committed
-    # stream, and its first spawn precedes the first refusal advance.
-    pred_cfg = dataclasses.replace(elastic, predictive=True)
-    pred = pb.replay_controller(rows, pred_cfg)
-    assert pred == pb.replay_controller(rows, pred_cfg)
-    assert pred == [list(a) for a in w["predictive_actions"]]
-
-    first_refusal = w["first_refusal_row"]
-    base = None
-    for i, row in enumerate(rows):
-        v = row.get("admission_refusals_total")
-        if v is None:
-            continue
-        if base is None:
-            base = float(v)
-        elif float(v) > base:
-            assert i == first_refusal
-            break
-    p_out = next(i for i, kind, *_ in pred if kind == "scale_out")
-    r_out = next((i for i, kind, *_ in reactive if kind == "scale_out"),
-                 None)
-    assert first_refusal is not None, "window recorded no refusal"
-    assert p_out < first_refusal
-    assert r_out is None or p_out <= r_out
+    first, second = replay(elastic), replay(elastic)
+    assert first == second
+    assert [a[1:] for a in first] == recorded
+    reactive = replay(dataclasses.replace(elastic, predictive=False))
+    first_out = next(i for i, kind, *_ in first if kind == "scale_out")
+    assert all(i >= first_out for i, kind, *_ in reactive
+               if kind == "scale_out")

@@ -22,14 +22,25 @@ pytest.importorskip("zmq")
 REF = "/root/reference/distributor.py"
 
 
-def _load_reference_distributor():
-    from benchtools import load_reference_module
+def load_reference_module(filename: str, ref_dir: str = os.path.dirname(REF)):
+    """Import one of the reference's modules from its read-only checkout
+    (never copied). Returns the loaded module."""
+    import importlib.util
 
+    spec = importlib.util.spec_from_file_location(
+        "ref_" + filename.removesuffix(".py"),
+        os.path.join(ref_dir, filename))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _load_reference_distributor():
     return load_reference_module("distributor.py").Distributor
 
 
 def _free_port():
-    from benchtools import free_port
+    from _util import free_port
 
     return free_port()
 
@@ -184,28 +195,107 @@ def test_reference_distributor_drives_tpu_worker_jpeg(rng):
         assert err < 8, (idx, err)  # two JPEG round-trips of loss
 
 
+# The reference's own InverterWorker, unmodified, as a process of its own
+# (its topology). It imports ``turbojpeg`` (PyTurboJPEG), which this image
+# does not have: an API-compatible stand-in over the in-repo libjpeg-turbo
+# codec goes into ``sys.modules`` before the reference is loaded.
+_REFERENCE_WORKER = """
+import os, sys, types
+tests_dir, ref_dir, p_dist, p_coll = sys.argv[1:3] + [int(a) for a in sys.argv[3:5]]
+sys.path.insert(0, tests_dir)
+from dvf_tpu.transport.codec import make_codec
+from test_reference_compat import load_reference_module
+
+codec = make_codec()
+
+class TurboJPEG:
+    def __init__(self, lib_path=None):
+        pass
+    def encode(self, frame, quality=90):
+        return codec.encode(frame)
+    def decode(self, data):
+        return codec.decode(data)
+
+shim = types.ModuleType("turbojpeg")
+shim.TurboJPEG = TurboJPEG
+sys.modules["turbojpeg"] = shim
+sys.path.insert(0, ref_dir)           # inverter.py: from worker import Worker
+sys.stdout = open(os.devnull, "w")    # its print a frame
+ref = load_reference_module("inverter.py", ref_dir)
+ref.InverterWorker("localhost", p_dist, p_coll).start()
+"""
+
+
 @pytest.mark.skipif(not os.path.exists(REF), reason="reference not present")
-def test_reference_headtohead_mechanics(tmp_path):
-    """The configs[0] parity-baseline bench runs end to end: reference's
-    unmodified Distributor + InverterWorker subprocess measured by its
-    own trace accounting, ours at the same geometry, speedups computed.
-    (Tiny duration — a mechanics check, not the committed numbers.)"""
-    import json as _json
+def test_reference_worker_and_tpu_worker_share_one_distributor():
+    """The parity baseline's mechanics without its clock: the reference's
+    Distributor fans JPEG frames out to its own InverterWorker (a process)
+    and to our worker at once. Every index that comes back is one that was
+    sent, comes back once (the reorder buffer is keyed by index and the
+    cursor never steps back), and decodes to the inverse of its frame,
+    whichever worker served it."""
     import subprocess
     import sys
 
-    out = tmp_path / "H2H"
-    p = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                      "reference_headtohead.py"),
-         "--seconds", "2", "--out", str(out)],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-        timeout=240, cwd=str(tmp_path),
-    )
-    assert p.returncode == 0, p.stderr[-800:]
-    doc = _json.loads((tmp_path / "H2H.json").read_text())
-    assert doc["reference"]["frames"] > 0
-    assert doc["dvf_tpu_cpu_jpeg_wire"]["fps"] > 0
-    assert doc["speedup_raw_wire"] is not None
-    assert os.path.exists(str(out) + ".md")
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.transport.codec import NativeJpegCodec
+    from dvf_tpu.transport.zmq_ingress import TpuZmqWorker
+
+    try:
+        codec = NativeJpegCodec(quality=95)
+    except RuntimeError as e:
+        pytest.skip(f"native jpeg shim unavailable: {e}")
+
+    Distributor = _load_reference_distributor()
+    p_dist, p_coll = _free_port(), _free_port()
+    dist = Distributor(distribute_port=p_dist, collect_port=p_coll, frame_delay=0)
+    dist.start()
+    tests_dir = os.path.dirname(os.path.abspath(__file__))
+    ref_worker = subprocess.Popen(
+        [sys.executable, "-c", _REFERENCE_WORKER, tests_dir,
+         os.path.dirname(REF), str(p_dist), str(p_coll)],
+        cwd=os.path.dirname(tests_dir), stdout=subprocess.DEVNULL,
+        stderr=subprocess.PIPE)
+    worker = TpuZmqWorker(
+        get_filter("invert"), host="127.0.0.1", distribute_port=p_dist,
+        collect_port=p_coll, batch_size=4, assemble_timeout_s=0.06,
+        use_jpeg=True)
+    wt = threading.Thread(target=worker.run, daemon=True)
+    wt.start()
+
+    n = 40
+    y, x = np.mgrid[0:32, 0:32]
+    frames, got, cursor = {}, {}, []
+    try:
+        for i in range(n):
+            f = np.stack([(x * 3 + i) % 256, (y * 3) % 256, (x + y) % 256],
+                         -1).astype(np.uint8)
+            frames[i] = f
+            dist.add_frame_for_distribution(codec.encode(f), time.time())
+            dist.update_display_frame()
+            if dist.current_display_frame is not None:
+                cursor.append(dist.current_display_frame)
+            time.sleep(0.015)
+        deadline = time.time() + 15
+        while time.time() < deadline and dist.latest_received_frame < n - 1:
+            time.sleep(0.01)
+        assert ref_worker.poll() is None, ref_worker.stderr.read()[-800:]
+        for idx, entry in list(dist.received_frames.items()):
+            got[idx] = codec.decode(entry["frame_data"])
+    finally:
+        worker.stop()
+        wt.join(timeout=5)
+        worker.close()
+        ref_worker.terminate()
+        try:
+            ref_worker.wait(timeout=5)
+        except subprocess.TimeoutExpired:
+            ref_worker.kill()
+        dist.cleanup()
+
+    assert len(got) >= n // 2, f"only {len(got)}/{n} frames came back"
+    assert set(got) <= set(frames)
+    assert cursor == sorted(cursor)
+    for idx, out in got.items():
+        err = np.abs(out.astype(int) - (255 - frames[idx]).astype(int)).mean()
+        assert err < 8, (idx, err)  # two JPEG round-trips of loss

@@ -519,7 +519,7 @@ def test_zmq_bridge_reference_framing():
     frame indices out, while the session rides the shared batcher."""
     zmq = pytest.importorskip("zmq")
 
-    from benchtools import free_port
+    from _util import free_port
     from dvf_tpu.serve import ZmqStreamBridge
 
     p_dist, p_coll = free_port(), free_port()

@@ -444,8 +444,8 @@ def test_equalize_space_sharded_matches_replicated():
 
 def test_pallas_tile_h_variants_numerically_identical(batch):
     """tile_h only changes the grid, never the numerics — the guarantee
-    the on-chip tile sweep (run_table COMPARISONS *_tile_1080p) relies on
-    to wire a measured winner as the default tile target."""
+    an on-chip tile sweep relies on to wire a measured winner as the
+    default tile target."""
     want = np.asarray(bilateral_nhwc_pallas(batch, interpret=True))
     h = batch.shape[1]
     for th in (8, 16, h):  # 8-aligned divisors of the test H, plus whole-H

@@ -305,7 +305,7 @@ def test_every_stencil_kernel_is_named(fn, name):
     (None, False, 25, False),      # the default window at the auto tile: Mosaic's 16 MiB
     (None, False, 81, True),       # d 9 at the auto tile: 26.33 MB needed (PR 43)
     (None, False, 49, True),
-    (24, False, 25, True),         # a pinned tile (the run_table sweeps; PR 21)
+    (24, False, 25, True),         # a pinned tile (chip_smoke.py's pins; PR 21)
     (24, False, 81, True),
     (None, True, 81, False),       # interpret mode has no VMEM
     (24, True, 81, False),

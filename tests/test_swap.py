@@ -330,6 +330,52 @@ class TestServeHotSwap:
         assert audit["replay_mismatches_total"] == 0
         assert audit["swap_guard_mismatches_total"] == 0
 
+    def test_n_resizes_n_swap_events_and_no_stall_window(self):
+        """Three resizes under paced traffic: three ``swap`` events
+        (cause resize, none aborted), each with its own batch size, no
+        stall window opened by any of them, and the session's frames
+        delivered complete and in order across all three cutovers."""
+        sizes = (6, 3, 2)
+        fe = ServeFrontend(get_filter("invert"),
+                           self._cfg(audit=False))
+        deliveries: dict = {}
+        with fe:
+            sid = fe.open_stream()
+            sent = 0
+
+            def step():
+                nonlocal sent
+                fe.submit(sid, tagged_frame(0, sent))
+                sent += 1
+                deliveries.setdefault(sid, []).extend(fe.poll(sid))
+                time.sleep(0.002)
+
+            for _ in range(8):
+                step()
+            for done, size in enumerate(sizes):
+                label = next(iter(fe.stats()["buckets"]))
+                assert fe.request_batch_size(label, size,
+                                             reason=f"resize {done}")
+                deadline = time.time() + 30.0
+                while fe.swaps <= done and time.time() < deadline:
+                    step()
+                assert fe.swaps == done + 1
+            drain(fe, [sid], deliveries, want=sent)
+            events = _swap_events(fe, cause=ledger_mod.CAUSE_RESIZE)
+            summary = fe.ledger.summary()
+            assert fe.swap_aborts == 0
+        assert [e["batch_size"] for e in events] == list(sizes)
+        assert [e["reason"] for e in events] == [
+            f"resize {i}" for i in range(len(sizes))]
+        assert not any(e.get("aborted") for e in events)
+        assert summary["stall_events_total"] == 0
+        assert summary["by_kind"]["swap"] == len(sizes)
+        got = deliveries[sid]
+        assert [d.index for d in got] == list(range(sent))
+        for d in got:
+            np.testing.assert_array_equal(
+                d.frame, 255 - tagged_frame(0, d.index))
+
     def test_chaos_aside_compile_failure_contained(self):
         """Chaos-armed resize: the aside compile fails on its
         background thread — the OLD program keeps serving every frame,
@@ -499,53 +545,3 @@ class TestMorphStream:
         fe = ServeFrontend(get_filter("invert"), self._cfg())
         with fe:
             assert fe.morph_stream("nope", "invert") is False
-
-
-# ------------------------------------------------- swap bench schema
-
-
-class TestSwapBenchQuick:
-    def test_swap_bench_writer_schema_and_committed_gates(self):
-        """The SWAP_BENCH.json writer is schema-conformant in quick
-        mode, and the COMMITTED artifact pins the headline: hot-swap
-        stall ≥ 10× lower than quiesce-rebind, zero ledger stall
-        events on the hot-swap AND dwell≈0 soak legs, interactive p99
-        held. (Quick mode on a noisy box is a smoke test; the gate
-        reads the committed run — sentinel.py re-checks it too.)"""
-        import json
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                        ".."))
-        from benchmarks.swap_bench import STALL_SPEEDUP_TARGET, run
-
-        doc = run(quick=True)
-        for leg in ("hot_swap", "quiesce"):
-            assert doc[leg]["reconfigs_applied"] > 0, leg
-            assert doc[leg]["stall_ms"], leg
-            assert doc[leg]["delivered"] > 0, leg
-        assert doc["hot_swap"]["ledger_stall_events_total"] == 0
-        assert doc["dwell0_soak"]["hard_failures_total"] == 0
-        assert doc["dwell0_soak"]["reconfig"][
-            "ledger_stall_events_total"] == 0
-        acc = doc["acceptance"]
-        assert acc["stall_speedup_target"] == STALL_SPEEDUP_TARGET
-        assert acc["measured_stall_speedup"] is not None
-        assert "sentinel" in doc
-
-        committed = os.path.join(os.path.dirname(__file__), "..",
-                                 "benchmarks", "SWAP_BENCH.json")
-        with open(committed) as f:
-            shipped = json.load(f)
-        acc = shipped["acceptance"]
-        assert acc["within_budget"] is True, acc
-        assert acc["measured_stall_speedup"] >= \
-            acc["stall_speedup_target"], acc
-        assert acc["hot_swap_stall_events_total"] == 0
-        assert acc["dwell0_soak_stall_events_total"] == 0
-        assert acc["hot_swap_p99_over_quiesce_p99"] <= 1.25, acc
-        # The committed dwell≈0 leg is only evidence when the
-        # controller actually actuated (rebinds or resizes fired).
-        rec = shipped["dwell0_soak"]["reconfig"]
-        assert (rec["quality_rebinds_total"] + rec["swaps_total"]) > 0

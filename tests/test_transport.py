@@ -177,6 +177,27 @@ def test_native_jpeg_corrupt_stream_rejected(native_codec):
         )
 
 
+@pytest.mark.parametrize("cores, width", [(1, 1), (4, 3), (16, 11)])
+def test_entropy_pool_width_is_a_share_of_the_cores(monkeypatch, cores,
+                                                    width):
+    """ceil(0.629 x cores), between 1 and the cores, from a constant of
+    the module: sizing the pool opens no file (it used to read a record
+    beside the checkout, and took another share where none was)."""
+    import builtins
+    import math
+
+    from dvf_tpu.transport import codec
+
+    def no_open(*a, **kw):
+        raise AssertionError(f"entropy_pool_size opened {a[0]!r}")
+
+    monkeypatch.setattr(builtins, "open", no_open)
+    n = codec.entropy_pool_size(cores)
+    monkeypatch.undo()
+    assert n == width == math.ceil(codec.ENTROPY_SHARE * cores)
+    assert 1 <= n <= cores
+
+
 def test_make_codec_prefers_native(native_codec):
     # (native_codec fixture = skip where the shim can't build; there
     # make_codec legitimately returns the cv2 fallback.)
