@@ -4,7 +4,7 @@
 One process (it holds the chip and starts no child that needs it) drives
 the production path once, end to end, and checks what comes out:
 
-1. **serve** — each of the eight ``cli.BENCH_CONFIGS`` through
+1. **serve** — each of the nine ``cli.BENCH_CONFIGS`` through
    ``ServeFrontend.open_stream/submit/poll`` at its own geometry, width and
    batch: delivery complete and in order, no fault, one compile, numerics
    against a reference (``Smoke.reference``), and which ingest/egress mode
@@ -44,9 +44,9 @@ import time
 import traceback
 
 # Riskiest first, so a short chip budget is spent where trouble is likeliest.
-SERVE_ORDER = ("invert_1080p", "sobel_bilateral_1080p", "style_720p",
-               "flow_720p", "invert_640x480", "gauss3_1080p", "gauss9_1080p",
-               "sr2x_540p")
+SERVE_ORDER = ("invert_1080p", "clahe_1080p", "sobel_bilateral_1080p",
+               "style_720p", "flow_720p", "invert_640x480", "gauss3_1080p",
+               "gauss9_1080p", "sr2x_540p")
 
 BATCHES_PER_CONFIG = 3   # frames submitted = this many device batches
 N_UNIQUE = 8             # distinct seeded frames per config (cycled)
@@ -225,6 +225,13 @@ class Smoke:
             ref = get_filter(fname, impl="chain", **kwargs)
             return (self._through_engine(ref, self.frames(name, N_UNIQUE), 2),
                     within(TOL_STEP))
+        if fname == "clahe":
+            # TPU default = the counted histograms and lane-gather lookups
+            # (ops/histogram.py, impl "pallas"); reference = the same op's
+            # sort + gather form on the same device: integers, bit for bit.
+            ref = get_filter(fname, impl="sort", **kwargs)
+            return (self._through_engine(ref, self.frames(name, N_UNIQUE), 2),
+                    within(TOL_EXACT))
         if fname == "flow_warp":
             # TPU default = the Pallas bounded warp; reference = the XLA
             # gather warp, each served session's frames (k, k+n, ...: two
@@ -445,7 +452,7 @@ def serve_config(s: Smoke, name: str) -> None:
                  ("h2d_block_ms", "d2h_block_ms", "step_block_ms")}
     _check_clean(s, stats, total)
     s.check("dvf_" in scrape, "metrics scrape rendered nothing")
-    if name in ("sobel_bilateral_1080p", "flow_720p") and not s.tiny:
+    if name in ("sobel_bilateral_1080p", "flow_720p", "clahe_1080p") and not s.tiny:
         s.check(mosaic, f"{name}: the served program has no "
                         f"tpu_custom_call — the Pallas kernel is not in it")
     mx = max(v[0] for v in worst.values())
@@ -744,6 +751,24 @@ def kernel_cases(s: Smoke):
               f"bitmaps {bitmaps.shape}")
 
     cases.append(("fused_delta_transform", fused_transform))
+
+    def histogram(name, **kwargs):
+        # The histogram family's two kernels (tile_hist_pallas,
+        # lut_apply_pallas) as the filter calls them, against the sort +
+        # gather form of the same filter: integers, so bit for bit.
+        def run():
+            h, w = hw1080
+            x = jnp.asarray(rng().integers(0, 256, (b, h, w, 3), dtype=np.uint8))
+            got, dt = mosaic_compiled(
+                lambda v: get_filter(name, impl="pallas", **kwargs).fn(v, None)[0], x)
+            want = get_filter(name, impl="sort", **kwargs).fn(x, None)[0]
+            close(name, got, want, 0)
+            s.log(f"{name} {h}x{w} {kwargs}: equals the sort form, {dt:.1f}s "
+                  f"compile + first run")
+        return f"{name}_forms", run
+
+    cases.append(histogram("clahe", clip_limit=2.0, grid=8))
+    cases.append(histogram("equalize"))
     return cases
 
 

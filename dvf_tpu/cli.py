@@ -42,6 +42,11 @@ BENCH_CONFIGS = {
     ),
     # 540p -> 1080p subpixel upscale; all conv FLOPs at the LOW resolution.
     "sr2x_540p": dict(filter=("super_resolution", {"scale": 2}), h=540, w=960, batch=8),
+    # cv2.createCLAHE(clipLimit=2.0, tileGridSize=(8, 8)) per RGB channel:
+    # clahe()'s defaults; on a TPU the counted form (MEASURED_DEFAULTS
+    # "clahe"), the program chipbench/configs/clahe_1080p.json pins by
+    # its factory's name (clahe_pallas).
+    "clahe_1080p": dict(filter=("clahe", {}), h=1080, w=1920, batch=16),
 }
 
 

@@ -1211,3 +1211,220 @@ def dct8x8_quant(plane: jnp.ndarray, qtable,
     if h % 8 == 0 and w % 8 == 0:
         return dct8x8_quant_pallas(plane, qtable, interpret=interpret)
     return dct8x8_quant_ref(plane, qtable)
+
+
+# ---------------------------------------------------------------------------
+# The histogram family (PR 49): tile histograms by counting on the VPU, and
+# the table lookup as a lane gather (ops/histogram.py: clahe, equalize)
+# ---------------------------------------------------------------------------
+
+HIST_BINS = 256
+_BIN_GROUP = 8      # bins counted a trip of the kernel's loop over a tile
+
+
+def tile_pad(th: int, tw: int) -> tuple:
+    """``(rows, lanes)`` of the whole (8, 128) vregs a ``th x tw`` tile fills."""
+    return _round_up(th, _SUBLANE), _round_up(tw, _LANE)
+
+
+def to_tiles(x: jnp.ndarray, gy: int, gx: int) -> jnp.ndarray:
+    """``(N, gy*th, gx*tw)`` → ``(N, gy, TH, gx*TW)``: each of the ``gy x
+    gx`` tiles padded to whole vregs with zeros (bottom and right), a row
+    of tiles side by side on the lane axis, so that tile ``(ty, tx)`` is
+    the aligned window ``[ty, :, tx*TW:(tx+1)*TW]``. Columns first, as
+    slices side by side, then rows: the lane axis stays the minor one all
+    the way (a ``(.., gx, tw)`` intermediate would put ``gx`` on the
+    sublanes, padded fourfold for bytes)."""
+    n, hp, wp = x.shape
+    th, tw = hp // gy, wp // gx
+    rows, lanes = tile_pad(th, tw)
+    if lanes != tw:
+        x = jnp.concatenate([jnp.pad(x[:, :, tx * tw:(tx + 1) * tw],
+                                     ((0, 0), (0, 0), (0, lanes - tw)))
+                             for tx in range(gx)], axis=2)
+    return jnp.pad(x.reshape(n, gy, th, gx * lanes),
+                   ((0, 0), (0, 0), (0, rows - th), (0, 0)))
+
+
+def from_tiles(t: jnp.ndarray, gy: int, gx: int, th: int, tw: int) -> jnp.ndarray:
+    """The inverse of :func:`to_tiles`, the filler dropped."""
+    n, _, _, width = t.shape
+    lanes = width // gx
+    x = t[:, :, :th].reshape(n, gy * th, width)
+    if lanes == tw:
+        return x
+    return jnp.concatenate([x[:, :, tx * lanes:tx * lanes + tw] for tx in range(gx)],
+                           axis=2)
+
+
+def _block_vmem_limit(block_bytes: int, interpret: bool) -> Optional[int]:
+    """Scoped-VMEM limit for a kernel whose windows Pallas double-buffers:
+    None (Mosaic's default 16 MiB) while two copies of every window and as
+    much again of temporaries fit under 12 MiB, else the stencils' raised
+    limit (CLAHE at 1080p, grid 8, holds 0.6 MB of uint8 windows a grid
+    step; an 8K frame would pass the default)."""
+    if interpret or 4 * block_bytes <= 12 * 1024 * 1024:
+        return None
+    return _VMEM_LIMIT_RAISED
+
+
+def _tile_hist_kernel(rows: int, lanes: int, gx: int):
+    """One grid step counts the ``gx`` tiles of one row of tiles: each
+    tile's bytes are widened once into an int32 scratch, then, eight bins a
+    pass over it, the tile is compared with the bin's value and the hits
+    are added up, vreg on vreg, then sublane on sublane, into row ``bin`` of
+    a (256, 128) scratch, whose lanes are summed once a tile into lane
+    ``tx`` of the (256, 128) result. 3 VPU operations (compare, select,
+    add) a pixel a bin: 768 a pixel, the whole cost of the kernel. Written
+    over the whole tile, an expression a bin: the compiler unrolls it into
+    vregs; unrolled here, vreg by vreg, the same schedule took 2 s to
+    trace (``setup_s``: the Engine traces a step twice)."""
+    row_tiles, lane_tiles = rows // _SUBLANE, lanes // _LANE
+
+    def kernel(x_ref, out_ref, tile_ref, rows_ref):
+        lane = lax.broadcasted_iota(jnp.int32, (HIST_BINS, _LANE), 1)
+        result = jnp.zeros((HIST_BINS, _LANE), jnp.int32)
+        for tx in range(gx):
+            tile_ref[...] = x_ref[0, 0, :, pl.ds(tx * lanes, lanes)].astype(jnp.int32)
+
+            def group(g, carry):
+                tile = tile_ref[...]
+                for j in range(_BIN_GROUP):
+                    v = g * _BIN_GROUP + j
+                    hits = jnp.where(tile == v, 1, 0)
+                    acc = hits.reshape(row_tiles, _SUBLANE, lanes).sum(axis=0)
+                    acc = sum(acc[:, c * _LANE:(c + 1) * _LANE] for c in range(lane_tiles))
+                    rows_ref[pl.ds(v, 1), :] = jnp.sum(acc, axis=0, keepdims=True)
+                return carry
+
+            lax.fori_loop(0, HIST_BINS // _BIN_GROUP, group, 0)
+            total = jnp.sum(rows_ref[...], axis=1, keepdims=True)
+            result = jnp.where(lane == tx, total, result)
+        out_ref[0, 0] = result
+
+    return kernel
+
+
+def tile_hist_pallas(tiles: jnp.ndarray, gx: int, pixels: int, name: str,
+                     interpret: bool = False) -> jnp.ndarray:
+    """``(N, gy, TH, gx*TW)`` uint8 tiles (:func:`to_tiles`) of ``pixels``
+    pixels each → ``(N, gy, gx, 256)`` int32 counts: how many pixels of
+    tile ``(ty, tx)`` hold each value. The filler is zeros, counted with
+    the tile and taken off bin 0 again (``TH*TW - pixels`` a tile). Exact
+    integer counting, so any sum of tiles is the histogram of their union."""
+    n, gy, rows, width = tiles.shape
+    lanes = width // gx
+    if gx > _LANE:
+        raise ValueError(f"at most {_LANE} tiles a row, got {gx}")
+    out = pl.pallas_call(
+        _tile_hist_kernel(rows, lanes, gx),
+        grid=(n, gy),
+        in_specs=[pl.BlockSpec((1, 1, rows, width), lambda b, t: (b, t, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, HIST_BINS, _LANE), lambda b, t: (b, t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, gy, HIST_BINS, _LANE), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((rows, lanes), jnp.int32),
+                        pltpu.VMEM((HIST_BINS, _LANE), jnp.int32)],
+        compiler_params=_vmem_params(_block_vmem_limit(rows * width, interpret)),
+        interpret=interpret,
+        name=name,
+    )(tiles)
+    counts = jnp.swapaxes(out[..., :gx], 2, 3)
+    return counts.at[..., 0].add(pixels - rows * lanes)
+
+
+def pack_luts(a, b, c, d) -> jnp.ndarray:
+    """Four uint8 tables → one int32 table, a byte each (``a`` lowest): one
+    lookup then fetches all four (:func:`lut_apply_pallas`)."""
+    a, b, c, d = (t.astype(jnp.int32) for t in (a, b, c, d))
+    return a | (b << 8) | (c << 16) | (d << 24)
+
+
+def blend_rounded(terms, as_xla_cpu_code: bool) -> jnp.ndarray:
+    """``((t0 + t1) + t2) + t3`` of four float32 products, each product
+    rounded before it is added. The VPU has no fused multiply-add, but
+    XLA's CPU code contracts ``a * b + c`` into one wherever both land in
+    one fusion (15,322 of 65,536 blends differ in the last bit, 9 of 2880
+    pixels a step, here on the CPU, PR 49; an ``optimization_barrier`` did
+    not stop it). ``as_xla_cpu_code``: give every product a second use (a
+    comparison with itself, never false), which keeps it rounded there, so
+    that every form of a filter blends to the same bits on every backend,
+    jitted or not."""
+    val = ((terms[0] + terms[1]) + terms[2]) + terms[3]
+    if as_xla_cpu_code:
+        val = jnp.where(functools.reduce(jnp.logical_and, [t == t for t in terms]),
+                        val, 0.0)
+    return val
+
+
+def _lut_apply_kernel(rows: int, lanes: int, gx: int, blend: bool,
+                      interpret: bool):
+    """One grid step maps the ``gx`` cells of one row of cells through
+    their own 256-entry tables. A table's two halves are two vregs (128
+    lanes each, every sublane alike); a pixel's entry is a lane gather from
+    each by its low seven bits and a select on the eighth: no compare a
+    bin. ``blend``: the entry is four byte tables (:func:`pack_luts`) and
+    the result their bilinear blend in float32, ``(wy0 wx0) a + (wy0 wx1) b
+    + (wy1 wx0) c + (wy1 wx1) d`` in that order, rounded half to even and
+    clipped (what ops/histogram.py's gather form computes, operation for
+    operation); else the entry is the result. ``interpret``: the kernel's
+    body then runs as XLA's CPU code (:func:`blend_rounded`)."""
+
+    def kernel(x_ref, lut_ref, *rest):
+        *weights, out_ref = rest
+        for cx in range(gx):
+            table = [jnp.broadcast_to(
+                lut_ref[0, 0, pl.ds(cx, 1), pl.ds(half * _LANE, _LANE)],
+                (rows, _LANE)) for half in (0, 1)]
+            for c in range(lanes // _LANE):
+                cols = pl.ds(cx * lanes + c * _LANE, _LANE)
+                x = x_ref[0, 0, :, cols].astype(jnp.int32)
+                low = x & (_LANE - 1)
+                entry = jnp.where(x >= _LANE,
+                                  jnp.take_along_axis(table[1], low, axis=1),
+                                  jnp.take_along_axis(table[0], low, axis=1))
+                if blend:
+                    wy0, wy1, wx0, wx1 = weights
+                    y0, y1 = wy0[0], wy1[0]
+                    x0 = jnp.broadcast_to(wx0[:, cols], (rows, _LANE))
+                    x1 = jnp.broadcast_to(wx1[:, cols], (rows, _LANE))
+                    a, b, c_, d = (((entry >> s) & 255).astype(jnp.float32)
+                                   for s in (0, 8, 16, 24))
+                    val = blend_rounded([(y0 * x0) * a, (y0 * x1) * b,
+                                         (y1 * x0) * c_, (y1 * x1) * d], interpret)
+                    entry = jnp.clip(jnp.round(val), 0.0, 255.0).astype(jnp.int32)
+                out_ref[0, 0, :, cols] = entry.astype(out_ref.dtype)
+
+    return kernel
+
+
+def lut_apply_pallas(cells: jnp.ndarray, luts: jnp.ndarray, name: str,
+                     weights=None, interpret: bool = False) -> jnp.ndarray:
+    """``(N, gy, CH, gx*CW)`` uint8 cells (:func:`to_tiles`) through ``(N,
+    gy, gx, 256)`` int32 tables, a cell its own → uint8 of the cells' shape
+    (the low byte of a table's entry, or of the blend). ``weights`` =
+    ``(wy0, wy1, wx0, wx1)`` turns the lookup into the four-table blend of
+    :func:`_lut_apply_kernel`: ``wy*`` float32 ``(gy, CH, 128)`` (a row's
+    weight on every lane), ``wx*`` float32 ``(1, gx*CW)`` (a column's
+    weight, laid out as the cells are)."""
+    n, gy, rows, width = cells.shape
+    gx = luts.shape[2]
+    lanes = width // gx
+    cell_spec = pl.BlockSpec((1, 1, rows, width), lambda b, t: (b, t, 0, 0))
+    in_specs = [cell_spec,
+                pl.BlockSpec((1, 1, gx, HIST_BINS), lambda b, t: (b, t, 0, 0))]
+    operands = [cells, luts]
+    if weights is not None:
+        in_specs += [pl.BlockSpec((1, rows, _LANE), lambda b, t: (t, 0, 0))] * 2
+        in_specs += [pl.BlockSpec((1, width), lambda b, t: (0, 0))] * 2
+        operands += list(weights)
+    return pl.pallas_call(
+        _lut_apply_kernel(rows, lanes, gx, weights is not None, interpret),
+        grid=(n, gy),
+        in_specs=in_specs,
+        out_specs=cell_spec,
+        out_shape=jax.ShapeDtypeStruct(cells.shape, jnp.uint8),
+        compiler_params=_vmem_params(
+            _block_vmem_limit(2 * rows * width, interpret)),
+        interpret=interpret,
+        name=name,
+    )(*operands)

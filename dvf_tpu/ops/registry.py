@@ -134,6 +134,21 @@ MEASURED_DEFAULTS = {
         "winners": {"cpu": "ref"},
         "fallback": "ref",
     },
+    # The histogram family's two forms (ops/histogram.py), bit-identical
+    # on both backends. TPU: "pallas", counted histograms and lane-gather
+    # lookups: PR 49's chip run, 8 frames of 1080p, wall: clahe 12.0 ms
+    # against the sort form's 2863.6 (its four image-sized XLA gathers),
+    # equalize 9.1 against 637.7 (PERF.md sections 4 and 5). CPU: "sort":
+    # the kernels run in interpret mode there (tests/test_histogram_forms.py
+    # runs both: the sort form is the faster at every size it uses).
+    "clahe": {
+        "winners": {"tpu": "pallas", "cpu": "sort"},
+        "fallback": "sort",
+    },
+    "equalize": {
+        "winners": {"tpu": "pallas", "cpu": "sort"},
+        "fallback": "sort",
+    },
 }
 
 

@@ -7,7 +7,11 @@ same way (``super_resolution(scale=2)`` at 16 x 540 x 960 in, 1080 x 1920 out; t
 ``--fast-convs`` probes the per-layer space-to-depth round trip); ``--model stencil`` the fused Sobel -> bilateral chain of
 ``chipbench/configs/sobel_bilateral_1080p.json`` (``sobel_bilateral(d=9, impl="pallas")`` at 1080 x 1920; the scopes of
 ``ops/pallas_kernels.py::sobel_bilateral_nhwc_pallas``: ``stencil_prep``, ``stencil_kernel``, ``stencil_finish``; ``--d 5`` and
-``--impl chain`` probe the real-time window and the two-op jnp chain, which carries no scope). Compiles the step program
+``--impl chain`` probe the real-time window and the two-op jnp chain, which carries no scope); ``--model clahe`` the
+counted CLAHE of ``chipbench/configs/clahe_1080p.json`` (``clahe(impl="pallas")`` at 1080 x 1920, uint8 in and out with
+no float conversion, as the Engine steps a ``uint8_ok`` filter; the scopes of ``ops/histogram.py::_clahe_planes_pallas``:
+``clahe_hist``, ``clahe_lut``, ``clahe_apply``; ``--impl sort`` probes the sort + gather form, at a batch it fits, and
+the first frames of either are compared with ``chipbench/refs/clahe_1080p.py``). Compiles the step program
 of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
 weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
@@ -21,6 +25,8 @@ relu and the residual add). Run on the chip:
     chiprun -- python scripts/style_step_probe.py --model espcn   # chiprun_out/espcn_step_probe.json
 
     chiprun -- python scripts/style_step_probe.py --model stencil --batch 64   # chiprun_out/stencil_step_probe.json
+
+    chiprun -- python scripts/style_step_probe.py --model clahe --batch 64     # chiprun_out/clahe_step_probe.json
 
 ``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
 nothing.
@@ -68,7 +74,20 @@ def _stencil_stages(kwargs, shape):
             "stencil_finish": "slice, broadcast to %d, NHWC" % shape[-1]}
 
 
+def _clahe_stages(kwargs, shape):
+    from dvf_tpu.ops.histogram import clahe_plan
+
+    if kwargs["impl"] != "pallas":      # the sort + gather form: XLA's own ops, no scope and no tiling
+        return {}
+    plan = clahe_plan(shape, kwargs["clip_limit"], kwargs["grid"], kwargs["on_gray"])
+    tile = "%d x %d as %d x %d" % (plan["tile_h"], plan["tile_w"], plan["tile_h_pad"], plan["tile_w_pad"])
+    return {"clahe_hist": "%d planes, %d^2 tiles of %s, counted" % (plan["planes"], plan["grid"], tile),
+            "clahe_lut": "clip %d, redistribution, cumulative tables, %d^2 cells packed" % (plan["clip_abs"], plan["cells"]),
+            "clahe_apply": "%d^2 cells of %s, lane-gather lookup, float32 blend" % (plan["cells"], tile)}
+
+
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))
+_CLAHE = {"clip_limit": 2.0, "grid": 8, "on_gray": False, "impl": "pallas"}     # as chipbench/configs/clahe_1080p.json
 _STENCIL = {"d": 9, "sigma_color": 0.1, "sigma_space": 2.0, "magnitude_scale": 1.0, "impl": "pallas"}   # as the cell's file
 _ESPCN = {"scale": 2, "fast_convs": False, "dtype": "bfloat16"}          # as chipbench/configs/sr2x_540p.json
 MODELS = {
@@ -76,6 +95,7 @@ MODELS = {
               {"base_channels": 8, "n_residual": 2}, _style_stages),
     "espcn": ("super_resolution", (540, 960), _ESPCN, _ESPCN, _espcn_stages),
     "stencil": ("sobel_bilateral", (1080, 1920), _STENCIL, _STENCIL, _stencil_stages),
+    "clahe": ("clahe", (1080, 1920), _CLAHE, _CLAHE, _clahe_stages),
 }
 
 
@@ -121,7 +141,8 @@ def main() -> int:
     ap.add_argument("--model", choices=sorted(MODELS), default="style")
     ap.add_argument("--fast-convs", action="store_true", help="the filter's fast_convs=True (espcn has it)")
     ap.add_argument("--d", type=int, default=None, help="stencil: the bilateral's window (the cell serves 9)")
-    ap.add_argument("--impl", choices=("pallas", "chain"), default=None, help="stencil: the fused kernel or the jnp chain")
+    ap.add_argument("--impl", choices=("pallas", "chain", "sort"), default=None,
+                    help="stencil: the fused kernel or the jnp chain; clahe: the counted kernels or the sort + gather form")
     ap.add_argument("--out", default=None, help="default chiprun_out/<model>_step_probe.json")
     args = ap.parse_args()
     args.out = args.out or f"chiprun_out/{args.model}_step_probe.json"
@@ -150,8 +171,8 @@ def main() -> int:
     filt = get_filter(name, **kwargs)
 
     def step(batch, state):            # the body of Engine._build_step
-        y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
-        return to_uint8(y), new_state
+        y, new_state = filt.fn(batch if filt.uint8_ok else to_float(batch, filt.compute_dtype), state)
+        return (y if y.dtype == jnp.uint8 else to_uint8(y)), new_state
 
     state = filt.init_state(shape, jnp.float32) if filt.init_state is not None else None
     batch = jnp.asarray(np.random.default_rng(0).integers(0, 256, shape, dtype=np.uint8))
@@ -211,6 +232,16 @@ def main() -> int:
     for part, stages in sorted(by_part.items()):
         print(f"[probe] {part} {sum(stages.values()):.2f}: "
               + ", ".join(f"{k} {v:.2f}" for k, v in sorted(stages.items(), key=lambda kv: -kv[1])))
+    if args.model == "clahe":           # integers: the step's first frames against the benchmark's plain reference
+        from chipbench import spec
+
+        ref = spec.load_module("refs/clahe_1080p.py")
+        got = np.asarray(compiled(batch, state)[0][:2])
+        want = ref.reference(list(np.asarray(batch[:2])), {"filter": {"kwargs": {k: kwargs[k] for k in
+                                                                                 ("clip_limit", "grid", "on_gray")}}})
+        diff = np.abs(got.astype(np.int16) - np.stack(want).astype(np.int16))
+        report["against_reference"] = {"max_abs_steps": int(diff.max()), "mean_abs_steps": float(diff.mean())}
+        print(f"[probe] against chipbench/refs/clahe_1080p.py, 2 frames: {report['against_reference']}")
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)

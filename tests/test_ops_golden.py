@@ -298,6 +298,8 @@ _DEFAULT_SITES = {
     "gaussian_blur_k9": ("gaussian_blur", {"ksize": 9}, "impl", "pallas"),
     "gaussian_blur_small": ("gaussian_blur", {"ksize": 3}, "impl", "shift"),
     "espcn_fast": ("super_resolution", {}, "fast_convs", "ref"),
+    "clahe": ("clahe", {}, "impl", "sort"),
+    "equalize": ("equalize", {}, "impl", "sort"),
 }
 
 

@@ -222,3 +222,37 @@ def test_ingest_join_is_a_copy_with_no_temporary(one_chip, shape):
     assert f"u8[{b},{h},{w},{c}]{{2,1,3,0" in root, root
     assert len(re.findall(rf"u8\[{h},{w},{c}\]{{1,0,2[^}}]*}} parameter\(", entry)) == b
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("name,calls", [
+    ("clahe", {"clahe_hist": "s32[6,8,256,128]", "clahe_apply": "u8[6,9,136,2304]"}),
+    ("equalize", {"equalize_hist": "s32[6,9,256,128]", "equalize_apply": "u8[6,9,128,1920]"})])
+def test_histogram_kernels_compile_through_mosaic_at_1080p(one_chip, name, calls):
+    """The counted form of the histogram family (ops/histogram.py, PR 49)
+    at 1080 x 1920 for the described v5e, as the Engine steps a
+    ``uint8_ok`` filter (uint8 in, uint8 out, no float conversion): both
+    kernels are in the step under their own names (the lane gather of
+    ``lut_apply_pallas`` lowers through Mosaic), inside their scopes for
+    CLAHE, under Mosaic's default scoped VMEM, and the step holds no sort
+    and no XLA gather (the sort + gather form's one sort and, for CLAHE,
+    four image-sized gathers: tests/test_histogram_forms.py reads the sort
+    in its lowering, PERF.md section 4 the chip's 2.9 s for 8 frames)."""
+    from dvf_tpu.ops import get_filter
+
+    filt = get_filter(name, impl="pallas", interpret=False)
+    batch = jax.ShapeDtypeStruct((2, 1080, 1920, 3), jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(lambda b: filt.fn(b, None)[0]).lower(batch).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    made = {m.group(1): m.group(2) for m in (
+        re.match(r"\s*%([a-z_]+)(?:\.\d+)? = (\w+\[[\d,]*\])\S* custom-call\(", ln)
+        for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln) if m}
+    assert made == calls
+    if name == "clahe":
+        for kernel in calls:
+            assert f'/{kernel}/{kernel}/pallas_call"' in text, kernel
+    assert not re.search(r'"scoped_memory_configs":\[\{', text)     # no raised limit
+    assert not re.findall(r" (sort|gather)\(", text)     # instructions, whatever their result type
