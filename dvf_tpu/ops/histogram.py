@@ -18,16 +18,21 @@ Two forms of the two stages, bit-identical (tests/test_histogram_forms.py):
   60 (PR 49's chip run,
   ``scripts/style_step_probe.py --model clahe --impl sort``).
 - ``impl="pallas"`` (PR 49): two kernels of the repo's own
-  (ops/pallas_kernels.py). ``tile_hist_pallas`` COUNTS on the VPU: every
-  vreg of a tile compared with each bin's value and accumulated (768
-  operations a pixel; the chip has no vector scatter-add, and no sort is
-  needed). ``lut_apply_pallas`` looks a pixel's entry up with two LANE
+  (ops/pallas_kernels.py). ``tile_hist_pallas`` COUNTS on the VPU (the
+  chip has no vector scatter-add, and no sort is needed): since PR 50 a
+  tile is cut into its 8 bit planes, 32 pixels a word, each bin's pixels
+  are an AND of the planes and their complements, and the chip's
+  population count (``vpcnt``) counts a vreg of them in one instruction:
+  about 70 operations a pixel at CLAHE's 1080p tile (the plan's
+  ``hist_ops_per_pixel``) where comparing every pixel with each of 256
+  bin values took 768. ``lut_apply_pallas`` looks a pixel's entry up with two LANE
   GATHERS (the table's halves, a vreg each) and a select, and fetches
   CLAHE's four neighbouring tile tables in one lookup, a byte each,
   before the float32 blend. On the v5e, 8 frames of 1080p: CLAHE 12.0 ms
   against the sort form's 2864, ``equalize`` 9.1 against 638, the same
-  bytes out (PR 49's chip run; PERF.md section 5 has the step by scope:
-  the counting is 65 of a 94 ms step of 64 frames).
+  bytes out (PR 49's chip run, while the counting still compared; PERF.md
+  section 5 has the step by scope: the counting was 65 of a 94 ms step of
+  64 frames then and is 7.6 of 37.6 since PR 50).
 
 ``impl=None`` picks the measured per-backend winner
 (``MEASURED_DEFAULTS["clahe"]`` / ``["equalize"]``).
@@ -398,8 +403,10 @@ def clahe_plan(shape, clip_limit: float = 2.0, grid: int = 8,
         "clip_abs": g["clip_abs"],
         "hist_grid": [planes, grid],
         "apply_grid": [planes, grid + 1],
-        # clahe_hist's: a tile widened to int32, and a row of lane sums a bin
-        "vmem_scratch_bytes": (rows * lanes + pk.HIST_BINS * pk._LANE) * 4,
+        "hist_form": "bitplane",
+        "hist_ops_per_pixel": pk.hist_ops_per_pixel(rows, lanes),
+        # clahe_hist's: a vreg of word counts a bin
+        "vmem_scratch_bytes": pk.HIST_BINS * pk._SUBLANE * pk._LANE * 4,
         "vmem_window_bytes": window,
         # None: Mosaic's default scoped-VMEM limit (16 MiB)
         "vmem_limit_bytes": pk._block_vmem_limit(2 * window, interpret),

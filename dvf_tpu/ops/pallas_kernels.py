@@ -1214,12 +1214,12 @@ def dct8x8_quant(plane: jnp.ndarray, qtable,
 
 
 # ---------------------------------------------------------------------------
-# The histogram family (PR 49): tile histograms by counting on the VPU, and
-# the table lookup as a lane gather (ops/histogram.py: clahe, equalize)
+# The histogram family (PR 49): tile histograms by counting on the VPU (since
+# PR 50 bit planes and the population count), and the table lookup as a lane
+# gather (ops/histogram.py: clahe, equalize)
 # ---------------------------------------------------------------------------
 
 HIST_BINS = 256
-_BIN_GROUP = 8      # bins counted a trip of the kernel's loop over a tile
 
 
 def tile_pad(th: int, tw: int) -> tuple:
@@ -1268,39 +1268,130 @@ def _block_vmem_limit(block_bytes: int, interpret: bool) -> Optional[int]:
     return _VMEM_LIMIT_RAISED
 
 
+_WORD_BITS = 32     # pixels a word of a bit plane: the row tiles one group of a strip holds
+
+
+def _hist_groups(rows: int) -> list:
+    """``(first row tile, row tiles)`` of the groups a strip of ``rows``
+    rows is walked in: at most a word's 32 row tiles each."""
+    row_tiles = rows // _SUBLANE
+    return [(r0, min(_WORD_BITS, row_tiles - r0)) for r0 in range(0, row_tiles, _WORD_BITS)]
+
+
+def _word_of(n: int) -> int:
+    """The int32 word with the bit of every row tile of a group of ``n``
+    set: row tile ``r`` sits at bit ``8 (r % 4) + r // 4`` (four row tiles
+    a byte lane of the packed word, :func:`_bit_planes`)."""
+    word = sum(1 << (8 * (r % 4) + r // 4) for r in range(n))
+    return word - (1 << 32) if word >> 31 else word
+
+
+def _bit_planes(t: jnp.ndarray) -> jnp.ndarray:
+    """``(n, 8, 128)`` int32 pixels (0..255), ``n <= 32`` row tiles of one
+    lane tile → ``(8, 8, 128)`` int32: plane ``k``'s word at a (sublane,
+    lane) holds bit ``k`` of the ``n`` pixels there, row tile ``r`` at bit
+    ``8 (r % 4) + r // 4``. Four row tiles are first packed a byte each
+    into a word, so that one shift and mask then moves a bit of all four;
+    the bits are disjoint, so the sums are ORs (and bit 31, the sign, is a
+    pixel like any other)."""
+    n = t.shape[0]
+    quads = -(-n // 4)
+    if 4 * quads != n:
+        t = jnp.concatenate([t, jnp.zeros((4 * quads - n, *t.shape[1:]), jnp.int32)], axis=0)
+    t = t.reshape(quads, 4, *t.shape[1:])
+    packed = jnp.sum(lax.shift_left(t, 8 * lax.broadcasted_iota(jnp.int32, t.shape, 1)), axis=1)
+    shape = (8, *packed.shape)
+    bits = lax.shift_right_logical(jnp.broadcast_to(packed[None], shape),
+                                   lax.broadcasted_iota(jnp.int32, shape, 0)) & 0x01010101
+    return jnp.sum(lax.shift_left(bits, lax.broadcasted_iota(jnp.int32, shape, 1)), axis=1)
+
+
+def hist_ops_per_pixel(rows: int, lanes: int) -> float:
+    """Vector operations :func:`_tile_hist_kernel`'s expressions state for a
+    ``rows x lanes`` tile, a vreg each, over the tile's vregs of pixels
+    (what the compare form's 768 counted). A group of ``n`` row tiles of a
+    strip: widen ``n``, pack ``7 q`` (``q`` quads of row tiles), the planes
+    ``8 (4 q - 1)``, both nibbles' masks 68, then AND, count and add for
+    each of 256 bins, whatever ``n``: a fuller word is a cheaper pixel. A
+    tile: 7 adds for each of the 32 vregs of the sublane sum, a lane sum
+    and a select for each."""
+    def group(n):
+        quads = -(-n // 4)
+        return n + 7 * quads + 8 * (4 * quads - 1) + 68 + 3 * HIST_BINS
+
+    strips = lanes // _LANE
+    ops = strips * sum(group(n) for _, n in _hist_groups(rows))
+    ops += (_SUBLANE - 1 + 2) * HIST_BINS // _SUBLANE
+    return round(ops / (rows // _SUBLANE * strips), 1)
+
+
+def _nibble_masks(root: jnp.ndarray, planes) -> jnp.ndarray:
+    """``(16, 8, 128)``: entry ``v`` has the bits of the pixels of ``root``
+    whose four ``planes`` (most significant first) spell ``v``."""
+    masks = root[None]
+    for plane in planes:
+        masks = jnp.stack([masks & ~plane, masks & plane], axis=1).reshape(-1, *plane.shape)
+    return masks
+
+
 def _tile_hist_kernel(rows: int, lanes: int, gx: int):
-    """One grid step counts the ``gx`` tiles of one row of tiles: each
-    tile's bytes are widened once into an int32 scratch, then, eight bins a
-    pass over it, the tile is compared with the bin's value and the hits
-    are added up, vreg on vreg, then sublane on sublane, into row ``bin`` of
-    a (256, 128) scratch, whose lanes are summed once a tile into lane
-    ``tx`` of the (256, 128) result. 3 VPU operations (compare, select,
-    add) a pixel a bin: 768 a pixel, the whole cost of the kernel. Written
-    over the whole tile, an expression a bin: the compiler unrolls it into
-    vregs; unrolled here, vreg by vreg, the same schedule took 2 s to
-    trace (``setup_s``: the Engine traces a step twice)."""
-    row_tiles, lane_tiles = rows // _SUBLANE, lanes // _LANE
+    """One grid step counts the ``gx`` tiles of one row of tiles, exactly,
+    by POPULATION COUNT over bit planes (PR 50; the compare, select and add
+    a pixel a bin that stood here cost 768 VPU operations a pixel). A tile
+    is walked a lane tile (a strip of 128 lanes) at a time, and a strip in
+    groups of at most 32 row tiles: the group's pixels are cut into their 8
+    bit planes, 32 pixels a word (:func:`_bit_planes`); the planes and
+    their complements are ANDed into 16 masks of the high nibble and 16 of
+    the low (:func:`_nibble_masks`); bin ``16 h + l``'s pixels are ``high[h]
+    & low[l]``, counted by ``lax.population_count`` (``vpcnt``, an
+    instruction a vreg) and added into row ``bin`` of a (256 x 8, 128)
+    scratch. Once a tile the scratch's sublanes are summed (8 strided
+    loads, a sublane of every bin each), then its lanes, into lane ``tx``
+    of the (256, 128) result. What a pixel costs is the plan's
+    ``hist_ops_per_pixel`` (:func:`hist_ops_per_pixel`). Whole-array
+    expressions and loops over tiles and strips: the body is traced once
+    (``setup_s``: the Engine traces a step twice). A tile's first strip
+    SETS the scratch, outside the loop over the others, which add to it:
+    by the compiler's schedule for a described v5e
+    (``scripts/stencil_kernel_probe.py --kernel clahe_hist``) CLAHE's
+    1080p tile, two strips, is then straight-line code, 6,252 bundles a
+    grid step (the loop's one trip is flattened, unrolled or not), where
+    zeroing the scratch and looping over both strips reads 10,660 (the
+    scratch's 256 vregs go through VMEM a group: ``vst`` 6,402 for
+    2,890); ``equalize``'s band of 15 strips reads the same either way
+    (5,644 / 5,622)."""
+    strips, groups = lanes // _LANE, _hist_groups(rows)
 
-    def kernel(x_ref, out_ref, tile_ref, rows_ref):
+    def kernel(x_ref, out_ref, acc_ref):
         lane = lax.broadcasted_iota(jnp.int32, (HIST_BINS, _LANE), 1)
-        result = jnp.zeros((HIST_BINS, _LANE), jnp.int32)
-        for tx in range(gx):
-            tile_ref[...] = x_ref[0, 0, :, pl.ds(tx * lanes, lanes)].astype(jnp.int32)
 
-            def group(g, carry):
-                tile = tile_ref[...]
-                for j in range(_BIN_GROUP):
-                    v = g * _BIN_GROUP + j
-                    hits = jnp.where(tile == v, 1, 0)
-                    acc = hits.reshape(row_tiles, _SUBLANE, lanes).sum(axis=0)
-                    acc = sum(acc[:, c * _LANE:(c + 1) * _LANE] for c in range(lane_tiles))
-                    rows_ref[pl.ds(v, 1), :] = jnp.sum(acc, axis=0, keepdims=True)
+        def strip(i, first):
+            c0 = pl.multiple_of(i * _LANE, _LANE)
+            for g, (r0, n) in enumerate(groups):
+                t = x_ref[0, 0, pl.ds(r0 * _SUBLANE, n * _SUBLANE), pl.ds(c0, _LANE)]
+                p = _bit_planes(t.astype(jnp.int32).reshape(n, _SUBLANE, _LANE))
+                high = _nibble_masks(jnp.full(p.shape[1:], _word_of(n), jnp.int32), [p[k] for k in (7, 6, 5, 4)])
+                low = _nibble_masks(jnp.full(p.shape[1:], -1, jnp.int32), [p[k] for k in (3, 2, 1, 0)])
+                counts = lax.population_count(high[:, None] & low[None]).reshape(acc_ref.shape)
+                acc_ref[...] = counts if first and g == 0 else acc_ref[...] + counts
+
+        def tile(tx, carry):
+            def later_strip(s, carry):
+                strip(tx * strips + s, False)
                 return carry
 
-            lax.fori_loop(0, HIST_BINS // _BIN_GROUP, group, 0)
-            total = jnp.sum(rows_ref[...], axis=1, keepdims=True)
-            result = jnp.where(lane == tx, total, result)
-        out_ref[0, 0] = result
+            strip(tx * strips, True)
+            if strips > 1:
+                lax.fori_loop(1, strips, later_strip, 0)
+            by_lane = acc_ref[pl.ds(0, HIST_BINS, stride=_SUBLANE), :]
+            for sub in range(1, _SUBLANE):
+                by_lane = by_lane + acc_ref[pl.ds(sub, HIST_BINS, stride=_SUBLANE), :]
+            total = jnp.sum(by_lane, axis=1, keepdims=True)
+            out_ref[0, 0] = jnp.where(lane == tx, total, out_ref[0, 0])
+            return carry
+
+        out_ref[0, 0] = jnp.zeros((HIST_BINS, _LANE), jnp.int32)
+        lax.fori_loop(0, gx, tile, 0)
 
     return kernel
 
@@ -1322,8 +1413,7 @@ def tile_hist_pallas(tiles: jnp.ndarray, gx: int, pixels: int, name: str,
         in_specs=[pl.BlockSpec((1, 1, rows, width), lambda b, t: (b, t, 0, 0))],
         out_specs=pl.BlockSpec((1, 1, HIST_BINS, _LANE), lambda b, t: (b, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, gy, HIST_BINS, _LANE), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((rows, lanes), jnp.int32),
-                        pltpu.VMEM((HIST_BINS, _LANE), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((HIST_BINS * _SUBLANE, _LANE), jnp.int32)],
         compiler_params=_vmem_params(_block_vmem_limit(rows * width, interpret)),
         interpret=interpret,
         name=name,

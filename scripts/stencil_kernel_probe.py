@@ -34,6 +34,14 @@ The variants patch names in ``dvf_tpu.ops.pallas_kernels`` for the length of one
 only timed. It also checks ``shipped`` against ``sobel_bilateral(impl="chain")`` on two frames. ``--tree DIR`` probes
 another checkout's ``dvf_tpu`` (the parent's) with this script. ``--toy``: a tiny shape on whatever backend jax has, in
 interpret mode off the TPU; it checks the script and its times mean nothing.
+
+``--kernel clahe_hist`` (PR 50) reads the histogram family's counting kernel the same two ways: without ``--chip`` the
+schedule of ``tile_hist_pallas`` at the cell's tiles (``u8[192, 8, 136, 2048]``: 64 frames' 192 planes, 8 rows of 8 tiles
+of 136 x 256) with ``vpcnt`` (the population count a bin a word vreg), ``vand``, ``vcmp`` (the compare form's) and the rest;
+with ``--chip`` the ``pallas_call`` alone on seeded noise, the least of ``--steps`` calls, and its counts against
+``np.bincount`` on one plane. Its record is ``chiprun_out/clahe_hist_kernel_probe.json``.
+
+    python scripts/stencil_kernel_probe.py --kernel clahe_hist [--tree chip_checkout/parent --out chiprun_out/clahe_hist_kernel_probe_parent.json]
 """
 
 from __future__ import annotations
@@ -54,7 +62,9 @@ from unittest import mock
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CLOCK_GHZ = 1.5     # TPU v5e core clock: one VLIW bundle a cycle
-COUNTED = ("vld", "vst", "vpow2", "vrot.lane", "vrot.slane", "vsel", "vmul", "vadd", "vsub", "vrcp", "vrsqrt")
+COUNTED = ("vld", "vst", "vpow2", "vrot.lane", "vrot.slane", "vsel", "vmul", "vadd", "vsub", "vrcp", "vrsqrt",
+           "vpcnt", "vand", "vor", "vcmp", "vshll", "vshrl")
+HIST_TILES = (8, 136, 2048)     # clahe_1080p: rows of tiles a plane, a row of 8 tiles of 136 x 256, side by side
 
 _BUNDLE = re.compile(r"^\s*(?:0x[0-9a-f]+|\d+)\s+(?:([A-Z]{2}):|:)\s*(?:> ?)*\{(.*)$")
 _EXIT_TEST = re.compile(r"%(p\w+) = scmp\.ge\.s32\.totalorder .*?, (\d+) /\* loop exit test \*/")
@@ -119,6 +129,11 @@ def _import_kernels(tree):
     return pallas_kernels
 
 
+def _hist_call(pk, tiles, interpret=False):
+    """``tile_hist_pallas`` on rows of 8 tiles side by side, every pixel of a padded tile counted (no filler)."""
+    return pk.tile_hist_pallas(tiles, 8, tiles.shape[2] * tiles.shape[3] // 8, "clahe_hist", interpret)
+
+
 def dump_child(args):
     """The child: compile for a described v5e with the dump on. May not return (see the module docstring)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -130,8 +145,12 @@ def dump_child(args):
     pk = _import_kernels(args.tree)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     jax.config.update("jax_enable_compilation_cache", False)   # a described device's entry cannot be read back
-    batch = jax.ShapeDtypeStruct((args.batch, args.height, args.width, 3), jnp.float32,
-                                 sharding=SingleDeviceSharding(topo.devices[0]))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    if args.kernel == "clahe_hist":
+        tiles = jax.ShapeDtypeStruct((3 * args.batch, *HIST_TILES), jnp.uint8, sharding=one_chip)
+        jax.jit(lambda t: _hist_call(pk, t)).lower(tiles).compile()
+        return
+    batch = jax.ShapeDtypeStruct((args.batch, args.height, args.width, 3), jnp.float32, sharding=one_chip)
     jax.jit(lambda x: pk.sobel_bilateral_nhwc_pallas(x, d=args.d, tile_h=args.tile_h)).lower(batch).compile()
 
 
@@ -140,17 +159,17 @@ def schedule(args):
         env = dict(os.environ, JAX_PLATFORMS="cpu", LIBTPU_INIT_ARGS=" ".join(filter(None, [
             os.environ.get("LIBTPU_INIT_ARGS"), f"--xla_jf_dump_to={dump}", "--xla_jf_dump_llo_text=true"])))
         child = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--dump-child", "--tree", args.tree, "--d", str(args.d),
+            [sys.executable, os.path.abspath(__file__), "--dump-child", "--kernel", args.kernel, "--tree", args.tree, "--d", str(args.d),
              "--tile-h", str(args.tile_h), "--batch", str(args.batch), "--height", str(args.height),
              "--width", str(args.width)], env=env, capture_output=True, text=True)
-        final = [p for p in glob.glob(os.path.join(dump, "*sobel_bilateral*final_bundles.txt"))
+        final = [p for p in glob.glob(os.path.join(dump, f"*{args.kernel}*final_bundles.txt"))
                  if "schedule-analysis" not in p]
         if len(final) != 1:
             raise SystemExit(f"the compile left {len(final)} final_bundles files of the kernel (exit code "
                              f"{child.returncode}):\n{child.stderr[-3000:]}")
         with open(final[0]) as f:
             counts, loops = read_bundles(f.read())
-        late = glob.glob(os.path.join(dump, "*sobel_bilateral*post-delay-converter.txt"))
+        late = glob.glob(os.path.join(dump, f"*{args.kernel}*post-delay-converter.txt"))
         vmem = None
         if late:
             with open(late[0]) as f:
@@ -248,10 +267,46 @@ def chip(args):
     return out
 
 
+def chip_hist(args):
+    """``--chip --kernel clahe_hist``: the counting kernel's ``pallas_call`` alone, and its counts."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    pk = _import_kernels(args.tree)
+    on_tpu = jax.default_backend() == "tpu"
+    if not (on_tpu or args.toy):
+        raise SystemExit("--chip times a TPU; there is none here (--toy checks the script on any backend)")
+    shape = (2, 2, 16, 8 * 128) if args.toy else (3 * args.batch, *HIST_TILES)
+    tiles = np.random.default_rng(50).integers(0, 256, shape, dtype=np.uint8)
+    x = jnp.asarray(tiles)
+    # the pallas_call's own eqn, apart from the slice, swap and filler correction behind it
+    (call,) = [e for e in jax.make_jaxpr(lambda t: _hist_call(pk, t, not on_tpu))(x).eqns
+               if e.primitive.name == "pallas_call"]
+    run = jax.jit(lambda t: call.primitive.bind(t, **call.params)[0])
+    got = np.asarray(jax.block_until_ready(run(x)))         # (planes, rows of tiles, 256, 128): lane tx is tile tx
+    times = []
+    for _ in range(args.steps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(x))
+        times.append((time.perf_counter() - t0) * 1e3)
+    lanes = shape[3] // 8
+    want = np.stack([np.bincount(tiles[0, ty, :, tx * lanes:(tx + 1) * lanes].ravel(), minlength=256)
+                     for ty in range(shape[1]) for tx in range(8)]).reshape(shape[1], 8, 256)
+    ms = {"min": round(min(times), 3), "median": round(sorted(times)[len(times) // 2], 3)}
+    print(f"[probe] clahe_hist: {ms} ms a call of u8{list(shape)}", flush=True)
+    return {"device": {"platform": jax.devices()[0].platform, "kind": jax.devices()[0].device_kind},
+            "kernel_ms": {"shipped": ms}, "tiles": list(shape),
+            "us_a_grid_step": {"shipped": round(ms["min"] * 1e3 / (shape[0] * shape[1]), 3)},
+            "against_bincount": {"tiles": int(want.shape[0] * 8),
+                                 "equal": bool(np.array_equal(got[0, :, :, :8].swapaxes(1, 2), want))}}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chip", action="store_true", help="time the kernel on the attached TPU (else: its schedule, no chip)")
     ap.add_argument("--toy", action="store_true", help="with --chip: a tiny shape on any backend; checks the script only")
+    ap.add_argument("--kernel", choices=("sobel_bilateral", "clahe_hist"), default="sobel_bilateral")
     ap.add_argument("--tree", default=os.path.join(HERE, ".."), help="the checkout whose dvf_tpu is probed")
     ap.add_argument("--d", type=int, default=9)
     ap.add_argument("--tile-h", type=int, default=24)
@@ -259,7 +314,7 @@ def main() -> int:
     ap.add_argument("--height", type=int, default=1080)
     ap.add_argument("--width", type=int, default=1920)
     ap.add_argument("--steps", type=int, default=10, help="--chip: timed calls a variant")
-    ap.add_argument("--out", default=None, help="default chiprun_out/stencil_kernel_probe.json")
+    ap.add_argument("--out", default=None, help="default chiprun_out/stencil_kernel_probe.json (clahe_hist_kernel_probe.json)")
     ap.add_argument("--dump-child", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.dump_child:
@@ -267,16 +322,20 @@ def main() -> int:
         return 0
     if args.toy:
         args.batch, args.height, args.width, args.tile_h, args.steps = 2, 32, 200, 16, 2
-    path = args.out or os.path.join(HERE, "..", "chiprun_out", "stencil_kernel_probe.json")
+    stem = "stencil" if args.kernel == "sobel_bilateral" else args.kernel
+    path = args.out or os.path.join(HERE, "..", "chiprun_out", f"{stem}_kernel_probe.json")
     result = {}
     if os.path.exists(path):            # the two halves run on two machines and share the file
         with open(path) as f:
             result = json.load(f)
-    result["shape"] = {"batch": args.batch, "height": args.height, "width": args.width, "d": args.d,
-                       "tile_h": args.tile_h}
+    if args.kernel == "clahe_hist":
+        result["shape"] = {"batch": args.batch, "tiles": [3 * args.batch, *HIST_TILES]}
+    else:
+        result["shape"] = {"batch": args.batch, "height": args.height, "width": args.width, "d": args.d,
+                           "tile_h": args.tile_h}
     result["tree"] = os.path.relpath(os.path.abspath(args.tree), os.path.join(HERE, ".."))
     if args.chip:
-        result["chip"] = chip(args)
+        result["chip"] = chip_hist(args) if args.kernel == "clahe_hist" else chip(args)
     else:
         result["schedule"] = schedule(args)
     os.makedirs(os.path.dirname(path), exist_ok=True)

@@ -33,6 +33,7 @@ from chipbench import check
 from dvf_tpu.cli import BENCH_CONFIGS
 from dvf_tpu.ops import get_filter
 from dvf_tpu.ops import histogram as hg
+from dvf_tpu.ops import pallas_kernels as pk
 from dvf_tpu.serve import ServeConfig, ServeFrontend
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -180,6 +181,9 @@ def test_bucket_row_states_the_kernels_and_their_tiling():
     assert block["kernels"] == ["clahe_hist", "clahe_apply"] and block["kernel"] in block["kernels"]
     assert (block["tile_h"], block["tile_w"], block["tile_h_pad"], block["tile_w_pad"]) == (5, 7, 8, 128)
     assert block["planes"] == BATCH * 3 and block["clip_abs"] == 1
+    # how clahe_hist counts (PR 50): bit planes and the population count, and what a pixel of this tile costs
+    assert block["hist_form"] == "bitplane"
+    assert block["hist_ops_per_pixel"] == pk.hist_ops_per_pixel(8, 128) and block["vmem_scratch_bytes"] == 256 * 8 * 128 * 4
     json.dumps(block)                                    # plain data: stats() is serialised
     spans = [e for e in fe.tracer._events if e["name"] == "dispatch:assemble_h2d"]
     assert spans and all(e["args"]["kernel"] == "clahe_hist" for e in spans)

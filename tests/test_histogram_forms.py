@@ -77,20 +77,61 @@ def test_float_batches_take_the_counted_form_too(name):
     assert np.array_equal(np.round(from_f32 * 255.0).astype(np.uint8), from_u8)
 
 
-def test_tile_histograms_are_counts():
-    """``tile_hist_pallas`` against numpy's bincount: the zeros a tile is
-    padded with are taken off bin 0 again."""
-    rng = np.random.default_rng(0)
-    x = rng.integers(0, 256, (2, 3 * 5, 2 * 130), dtype=np.uint8)
-    got = np.asarray(pk.tile_hist_pallas(pk.to_tiles(jnp.asarray(x), 3, 2), 2, 5 * 130,
-                                         "hist", interpret=True))
-    assert got.shape == (2, 3, 2, 256)
-    for n in range(2):
-        for ty in range(3):
-            for tx in range(2):
-                tile = x[n, ty * 5:(ty + 1) * 5, tx * 130:(tx + 1) * 130]
-                assert np.array_equal(got[n, ty, tx], np.bincount(tile.ravel(), minlength=256))
-    assert np.array_equal(np.asarray(pk.from_tiles(pk.to_tiles(jnp.asarray(x), 3, 2), 3, 2, 5, 130)), x)
+# (tile_h, tile_w, gy, gx): the walk of ``_tile_hist_kernel`` is groups of at most 32 row tiles by strips of one lane tile
+TILE_SHAPES = {
+    "one_vreg": (8, 128, 1, 1),                        # one row tile, one strip: a bit a word
+    "padded_rows_and_lanes": (5, 130, 3, 2),           # 8 x 256 walked for 5 x 130: filler in both directions
+    "cell_136x256": (135, 240, 1, 2),                  # the benchmark's tile: 17 row tiles x 2 strips = 34 vregs
+    "32_row_tiles": (256, 128, 1, 1),                  # a full word: row tile 31 is the sign bit
+    "over_32_row_tiles": (260, 100, 1, 2),             # 33 row tiles: a group of 32 and a group of 1
+    "equalize_band": (16, 1920, 2, 1),                 # 15 strips: the loop over strips, as equalize's bands
+    "three_strips_with_filler": (24, 300, 1, 3),       # 384 lanes for 300: the last strip mostly filler; a loop of two trips
+}
+
+
+def _plane(kind, shape):
+    """Planes on the edges of the bit arithmetic: every bit of a pixel
+    clear, every bit set, bit 7 alone, each value once among a constant,
+    and seeded noise."""
+    if kind in ("zeros", "all_255", "all_128"):
+        return np.full(shape, {"zeros": 0, "all_255": 255, "all_128": 128}[kind], np.uint8)
+    rng = np.random.default_rng(sum(shape))
+    if kind == "noise":
+        return rng.integers(0, 256, shape, dtype=np.uint8)
+    x = np.full(shape, 77, np.uint8).reshape(shape[0], -1)            # "each_value"
+    for n in range(shape[0]):
+        x[n, rng.choice(x.shape[1], 256, replace=False)] = np.arange(256)
+    return x.reshape(shape)
+
+
+@pytest.mark.parametrize("kind", ["zeros", "all_255", "all_128", "each_value", "noise"])
+@pytest.mark.parametrize("tiling", sorted(TILE_SHAPES))
+def test_tile_histograms_are_counts(tiling, kind):
+    """``tile_hist_pallas`` against numpy's bincount, a tile: exact over
+    every boundary of the walk (one vreg, the cell's 34, more than 32 row
+    tiles a strip, a loop over strips, filler rows and lanes) and every
+    edge of the bit planes; the zeros a tile is padded with are taken off
+    bin 0 again."""
+    th, tw, gy, gx = TILE_SHAPES[tiling]
+    x = _plane(kind, (2, gy * th, gx * tw))
+    tiles = pk.to_tiles(jnp.asarray(x), gy, gx)
+    rows, lanes = pk.tile_pad(th, tw)
+    assert tiles.shape == (2, gy, rows, gx * lanes)
+    got = np.asarray(jax.jit(lambda t: pk.tile_hist_pallas(t, gx, th * tw, "hist", interpret=True))(tiles))
+    assert got.shape == (2, gy, gx, 256) and got.dtype == np.int32
+    want = np.stack([np.bincount(x[n, ty * th:(ty + 1) * th, tx * tw:(tx + 1) * tw].ravel(), minlength=256)
+                     for n in range(2) for ty in range(gy) for tx in range(gx)]).reshape(got.shape)
+    assert np.array_equal(got, want), np.argwhere(got != want)[:4]
+    assert np.array_equal(np.asarray(pk.from_tiles(tiles, gy, gx, th, tw)), x)
+
+
+def test_walk_arithmetic_of_the_counting_kernel():
+    """``hist_ops_per_pixel``: what the bit-plane walk states for a tile,
+    against the compare form's 768: a fuller word is a cheaper pixel."""
+    assert pk.hist_ops_per_pixel(136, 256) == 69.6          # the cell's tile: 17 bits a word
+    assert pk.hist_ops_per_pixel(256, 128) < pk.hist_ops_per_pixel(128, 1920) < pk.hist_ops_per_pixel(136, 256)
+    assert pk.hist_ops_per_pixel(8, 128) > 768              # one bit a word: a vreg pays for a whole word's bins
+    assert pk._word_of(32) == -1 and pk._word_of(1) == 1 and pk._word_of(5) == 0x0101_0103
 
 
 def test_lookup_is_the_tables_entry():
@@ -126,7 +167,8 @@ def test_plan_states_the_tiling_at_the_cells_shape():
         "kernel": "clahe_hist", "kernels": ["clahe_hist", "clahe_apply"], "impl": "pallas",
         "grid": 8, "cells": 9, "bins": 256, "planes": 192, "tile_h": 135, "tile_w": 240,
         "tile_h_pad": 136, "tile_w_pad": 256, "clip_abs": 253, "hist_grid": [192, 8],
-        "apply_grid": [192, 9], "vmem_scratch_bytes": (136 * 256 + 256 * 128) * 4,
+        "apply_grid": [192, 9], "hist_form": "bitplane", "hist_ops_per_pixel": 69.6,
+        "vmem_scratch_bytes": 256 * 8 * 128 * 4,
         "vmem_window_bytes": 136 * 9 * 256, "vmem_limit_bytes": None, "io_dtype": "uint8",
         "compute_dtype": "int32"}
     json.dumps(plan)

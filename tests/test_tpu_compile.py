@@ -4,6 +4,8 @@ attached (on-chip-measurement guide, section 2). Nothing runs, so nothing
 here is a time. All such compiles live in this one file, behind a fixture:
 one worker loads the TPU library, and only once a test of this file runs."""
 
+import base64
+import collections
 import importlib.util
 import math
 import os
@@ -224,6 +226,16 @@ def test_ingest_join_is_a_copy_with_no_temporary(one_chip, shape):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def _eqns(jaxpr, trips):
+    """``(eqn, times it runs)`` of a jaxpr and every jaxpr under it, a
+    loop's body times its trip count."""
+    for eqn in jaxpr.eqns:
+        yield eqn, trips
+        inner = trips * eqn.params.get("length", 1) if eqn.primitive.name == "scan" else trips
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inner)
+
+
 @pytest.mark.parametrize("name,calls", [
     ("clahe", {"clahe_hist": "s32[6,8,256,128]", "clahe_apply": "u8[6,9,136,2304]"}),
     ("equalize", {"equalize_hist": "s32[6,9,256,128]", "equalize_apply": "u8[6,9,128,1920]"})])
@@ -244,9 +256,22 @@ def test_histogram_kernels_compile_through_mosaic_at_1080p(one_chip, name, calls
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        text = jax.jit(lambda b: filt.fn(b, None)[0]).lower(batch).compile().as_text()
+        traced = jax.jit(lambda b: filt.fn(b, None)[0]).trace(batch)
+        lowered = traced.lower()
+        text = lowered.compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+    # the counting kernel as Mosaic takes it (PR 50): a population count (``math.ctpop``: the op names of a
+    # kernel's serialized module are plain bytes), and no compare a bin: the compare form traced 8 ``eq`` a trip of
+    # 32, the bit-plane form compares a lane index with the tile's once a tile
+    bodies = [base64.b64decode(b) for b in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered.as_text())]
+    assert [b"ctpop" in body for body in bodies] == [True, False]
+    hist = next(eqn for eqn, _ in _eqns(traced.jaxpr.jaxpr, 1)
+                if eqn.primitive.name == "pallas_call" and eqn.params["name"].endswith("_hist"))
+    ran = collections.Counter()
+    for eqn, trips in _eqns(hist.params["jaxpr"], 1):
+        ran[eqn.primitive.name] += trips
+    assert ran["population_count"] >= 1 and ran["eq"] <= 8 and ran["select_n"] <= 8, ran
     made = {m.group(1): m.group(2) for m in (
         re.match(r"\s*%([a-z_]+)(?:\.\d+)? = (\w+\[[\d,]*\])\S* custom-call\(", ln)
         for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln) if m}
