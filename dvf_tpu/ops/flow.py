@@ -351,9 +351,20 @@ def _inner_warp_fn(inner_warp: str, max_disp: int):
     if inner_warp == "pallas":
         from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas
 
-        return lambda img, f: warp_bounded_pallas(img, f, max_disp=max_disp)
+        def inner_warp_pallas(img, f):
+            with jax.named_scope("flow_inner_warp"):
+                return warp_bounded_pallas(img, f, max_disp=max_disp)
+
+        return inner_warp_pallas
     raise ValueError(
         f"inner_warp must be 'gather' or 'pallas', got {inner_warp!r}")
+
+
+def _pyramid_shapes(h: int, w: int, levels: int, pyr_scale: float = 0.5):
+    """``(h, w)`` of each pyramid level of an ``h`` x ``w`` estimation grid,
+    finest first."""
+    return [(max(8, int(round(h * pyr_scale ** lvl))),
+             max(8, int(round(w * pyr_scale ** lvl)))) for lvl in range(levels)]
 
 
 def _coarse_to_fine(polys_at, b, h, w, dtype, levels, pyr_scale, win_size,
@@ -370,10 +381,7 @@ def _coarse_to_fine(polys_at, b, h, w, dtype, levels, pyr_scale, win_size,
     else:
         raise ValueError(
             f"win_type must be 'gaussian' or 'box', got {win_type!r}")
-    shapes = []
-    for lvl in range(levels):
-        scale = pyr_scale ** lvl
-        shapes.append((max(8, int(round(h * scale))), max(8, int(round(w * scale)))))
+    shapes = _pyramid_shapes(h, w, levels, pyr_scale)
 
     flow = None
     for lvl in range(levels - 1, -1, -1):
@@ -426,6 +434,11 @@ def flow_warp(
     "gather" on CPU (it imposes no displacement clip). Provenance: the
     TPU figures were captured 2026-07-31 through a shared chip that no
     longer exists (table removed in PR 21); not measured on this chip.
+    With a bounded warp anywhere in the step the filter states its
+    ``warp_bounded`` calls as data (``kernel_plan``: one
+    ``pallas_kernels.warp_plan`` a distinct shape, in step order), and the
+    two call sites carry the scopes ``flow_final_warp`` /
+    ``flow_inner_warp`` (``scripts/style_step_probe.py --model flow``).
 
     NOTE the TPU default is an APPROXIMATION, unlike the other measured
     winners (which are numerics-identical): the Pallas warp clips
@@ -464,12 +477,45 @@ def flow_warp(
         raise ValueError(
             f"win_size must be odd when win_type='box', got {win_size}")
 
+    # The inner warp runs at the 1/flow_scale estimation grid, so
+    # ±max_disp full-res px = ±max_disp/flow_scale grid px — scale
+    # the bound so pallas-inner carries the SAME |motion| ≤ max_disp
+    # full-res contract the final bounded warp documents.
+    inner_max_disp = max(1, -(-max_disp // max(1, flow_scale)))
+
     def init_state(batch_shape: Sequence[int], dtype: Any):
         _, h, w, c = batch_shape
         return {
             "prev": jnp.zeros((h, w, c), dtype=dtype),
             "initialized": jnp.zeros((), dtype=jnp.bool_),
         }
+
+    def kernel_plan(batch_shape) -> Optional[dict]:
+        """The step's ``warp_bounded`` calls as data (``Filter.kernel_plan``):
+        one entry a distinct shape in step order, each the
+        ``pallas_kernels.warp_plan`` its ``pallas_call`` is built from.
+        None where no warp is the kernel."""
+        from dvf_tpu.ops.pallas_kernels import _auto_interpret, warp_plan
+
+        bsz, h, w, c = (int(v) for v in batch_shape)
+        interpret = _auto_interpret(None)
+        calls = []
+        if inner_warp == "pallas":
+            grid = _pyramid_shapes(h // flow_scale, w // flow_scale, levels)
+            for lvl in range(levels - 1, -1, -1):
+                calls.append({"role": "inner", "level": lvl, "count": n_iters,
+                              **warp_plan((bsz, *grid[lvl], 5), inner_max_disp,
+                                          interpret=interpret)})
+        if warp_impl == "pallas":
+            calls.append({"role": "final", "level": None, "count": 1,
+                          **warp_plan((bsz, h, w, c), max_disp,
+                                      interpret=interpret)})
+        if not calls:
+            return None
+        return {"kernel": "warp_bounded",  # the pallas_calls' name in a trace
+                "kernels": ["warp_bounded"],
+                "impl": "pallas",
+                "calls": calls}
 
     def rows(batch: jnp.ndarray, prev_states, pred) -> Tuple[jnp.ndarray, Any]:
         bsz, h, w, c = batch.shape
@@ -486,15 +532,10 @@ def flow_warp(
             sh, sw = h // flow_scale, w // flow_scale
             sg = jax.image.resize(sg, (seq.shape[0], sh, sw, 1),
                                   method="linear")
-        # The inner warp runs at the 1/flow_scale estimation grid, so
-        # ±max_disp full-res px = ±max_disp/flow_scale grid px — scale
-        # the bound so pallas-inner carries the SAME |motion| ≤ max_disp
-        # full-res contract the final bounded warp documents.
         flow = farneback_flow_seq(
             sg, levels=levels, win_size=win_size, n_iters=n_iters,
             win_type=win_type, inner_warp=inner_warp,
-            inner_max_disp=max(1, -(-max_disp // max(1, flow_scale))),
-            pred=pred)
+            inner_max_disp=inner_max_disp, pred=pred)
         if flow_scale > 1:
             flow = jax.image.resize(flow, (bsz, h, w, 2), method="linear") * float(flow_scale)
         if warp_impl == "pallas":
@@ -502,7 +543,8 @@ def flow_warp(
 
             # interpret=None → the kernel's own backend policy
             # (compiled on TPU, interpret elsewhere).
-            warped = warp_bounded_pallas(prev, flow, max_disp=max_disp)
+            with jax.named_scope("flow_final_warp"):
+                warped = warp_bounded_pallas(prev, flow, max_disp=max_disp)
         else:
             warped = warp_by_flow(prev, flow)
         # Until a session's first real previous frame exists, pass the
@@ -514,7 +556,7 @@ def flow_warp(
         f"flow_warp(levels={levels},win={win_size},warp={warp_impl}"
         f"{',box' if win_type == 'box' else ''}"
         f"{',pallas-inner' if inner_warp == 'pallas' else ''})",
-        rows, init_state)
+        rows, init_state, kernel_plan=kernel_plan)
 
 
 def _started(prev_states, pred, bsz: int) -> jnp.ndarray:

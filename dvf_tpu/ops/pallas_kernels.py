@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the hot stencil ops.
 
-Three kernels, each with a jnp golden it must match:
+Three stencil kernels, each with a jnp golden it must match:
 
 - **bilateral** — XLA already fuses the unrolled shifted-window bilateral
   (:mod:`dvf_tpu.ops.bilateral`) well; this kernel exists for the cases
@@ -17,9 +17,12 @@ Three kernels, each with a jnp golden it must match:
   reflection, |·| restores it), so computing Sobel inside the halo'd tile
   reproduces the unfused chain's borders exactly.
 - **flow bilinear-warp** (:func:`warp_bounded_pallas`) — backward warp as
-  (2R+1)² statically-unrolled shifted-window select-sums instead of the 4
-  dynamic gathers in :func:`dvf_tpu.ops.flow.bilinear_sample`; TPU has no
-  fast vector gather, while bounded-displacement warps are pure VPU work.
+  (2R+2)² statically-unrolled hat-weighted shifted-window sums instead of
+  the 4 dynamic gathers in :func:`dvf_tpu.ops.flow.bilinear_sample`; TPU
+  has no fast vector gather, while bounded-displacement warps are pure VPU
+  work. Since PR 51 it walks its tile in register-sized strips
+  (:func:`_warp_kernel`; the tiling is :func:`warp_plan`'s, one form for
+  the flow step's final warp and its nine inner warps).
 
 Layout choices (see /opt/skills/guides/pallas_guide.md):
 - frames are transposed NHWC→NCHW before the kernel so W (1920 at 1080p)
@@ -31,7 +34,9 @@ Layout choices (see /opt/skills/guides/pallas_guide.md):
   whose second-to-last dim is a multiple of the f32 sublane tile — see
   :func:`_pick_tile_h`, which pads H when no aligned divisor exists;
 - all window shifts are static python-int slices — fully unrolled at trace
-  time, no data-dependent control flow;
+  time, no data-dependent control flow; the two kernels that walk their
+  tile in strips (the fused Sobel+bilateral, PR 46; the warp, PR 51) loop
+  over the strips and unroll a strip's taps;
 - accumulation in float32 regardless of I/O dtype.
 
 The jnp implementations are the numerics goldens; tests compare in
@@ -141,7 +146,7 @@ def _stencil_vmem_limit(tile_h: Optional[int], interpret: bool,
     not compile) until PR 46 ran its taps in strips (3.25 MB now:
     tests/test_tpu_compile.py compiles it under the default). So a
     caller-pinned ``tile_h`` (chip_smoke.py's tile pins) or a window over
-    5x5 taps gets the warp kernel's 64 MiB (the chip has 128 MiB of VMEM),
+    5x5 taps gets 64 MiB (the chip has 128 MiB of VMEM),
     which costs nothing where it goes unused; the auto-picked tile at up
     to 25 taps compiles under the default and keeps it."""
     if interpret or (tile_h is None and taps <= _TAPS_UNDER_DEFAULT_VMEM):
@@ -261,39 +266,181 @@ def bilateral_nhwc_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _warp_kernel(tile_h: int, R: int, w: int, c: int):
-    Rp = R + 1  # fy=R needs taps floor(R)..floor(R)+1 = R..R+1
-    slab = _slab_rows(tile_h, 2 * Rp)
+_WARP_VMEM_BUDGET = 14 * 1024 * 1024   # of Mosaic's default 16 MiB of scoped VMEM
+_WARP_STEP_ROWS = 8      # a grid step's fixed cost (slab wait, halo copies) in rows of taps
 
-    def kernel(img_ref, flow_ref, out_ref, scratch, fscratch, sem_i, sem_f):
+
+def _warp_vmem_bytes(th: int, halo: int, c: int, side: int, w_out: int, w_al: int) -> tuple:
+    """``(slab scratch, shifted copies, pipelined blocks)`` in bytes, of a
+    grid step over a tile of ``th`` rows."""
+    slab = th + halo
+    return (c * slab * w_al * 4, side * c * slab * w_out * 4,
+            2 * (2 + c) * th * w_out * 4)       # flow in, planes out, double-buffered
+
+
+def _warp_tile(h: int, tile_h: Optional[int], fits) -> tuple:
+    """``(tile_h, padded_h)`` of the warp's grid over H; the tile a
+    multiple of the sublane tile always (a strip is 8 rows). Unpinned, the
+    tile that costs least over the frame among those whose grid step
+    ``fits`` the VMEM budget, a grid step counted as its rows plus
+    ``_WARP_STEP_ROWS``. At flow_720p's shapes: 720 x 1280 x 3 at +-4 px ->
+    48 x 15; 360 x 640 x 5 at +-2 -> 72 x 5; 180 x 320 -> one tile of 184;
+    90 x 160 -> one of 96."""
+    if tile_h is not None:
+        if h % tile_h:
+            raise ValueError(f"tile_h {tile_h} must divide H {h}")
+        if tile_h % _SUBLANE and tile_h != h:
+            raise ValueError(f"the warp's tile_h must be a multiple of "
+                             f"{_SUBLANE} or the whole H; got {tile_h} (H={h})")
+        th = _round_up(tile_h, _SUBLANE)
+        return th, h // tile_h * th
+    tiles = [t for t in range(_SUBLANE, _round_up(h, _SUBLANE) + 1, _SUBLANE)
+             if t == _SUBLANE or fits(t)]
+    th = min(tiles, key=lambda t: (-(-h // t) * (t + _WARP_STEP_ROWS), -t))
+    return th, -(-h // th) * th
+
+
+def warp_plan(shape, max_disp: int = 4, tile_h: Optional[int] = None,
+              interpret: bool = False) -> dict:
+    """The tiling :func:`warp_bounded_pallas` resolves to for an NHWC image
+    of ``shape``, as data: the wrapper builds its ``pallas_call`` from this
+    dict, and ``flow_warp`` lists one for each distinct call of its step
+    (``ops/flow.py::flow_warp`` -> ``Filter.kernel_plan`` -> the bucket
+    row's ``kernel`` block). Everything follows from what the call observes
+    in its input: ``planes`` (c), ``max_disp`` (R), H and W."""
+    b, h, w, c = (int(v) for v in shape)
+    R = int(max_disp)
+    if R < 1:
+        raise ValueError("max_disp must be >= 1")
+    side = 2 * R + 2                    # taps an axis: dy, dx in [-R, R + 1]
+    halo = _round_up(side - 1, _SUBLANE)
+    w_out, w_al = _round_up(w, _LANE), _round_up(w + side - 1, _LANE)
+
+    def vmem(th):
+        return _warp_vmem_bytes(th, halo, c, side, w_out, w_al)
+
+    th, h_pad = _warp_tile(h, tile_h, lambda t: sum(vmem(t)) <= _WARP_VMEM_BUDGET)
+    scratch, shifted, blocks = vmem(th)
+    unaligned = range(1, min(side, _SUBLANE))
+    return {
+        "planes": c,
+        "max_disp": R,
+        "taps": side * side,
+        "tile_h": th,
+        "h_pad": h_pad,
+        "grid": [b, h_pad // th],
+        "slab_rows": th + halo,         # rows DMA'd a grid step (tile + halo, 8-aligned)
+        "w_aligned": w_al,              # columns DMA'd (W + halo, 128-aligned)
+        "w_out": w_out,                 # columns of the flow, the copies and the result
+        # rows x lanes of the tile whose taps run at a time: one vreg an array
+        "strip": [_SUBLANE, _LANE],
+        # the two row offsets of a window taken from its aligned row tiles in
+        # registers, not by a load (:func:`_warp_kernel`)
+        "rows_in_registers": sorted({unaligned[len(unaligned) // 4],
+                                     unaligned[3 * len(unaligned) // 4]}),
+        "vmem_scratch_bytes": scratch,
+        # the slab's `side` column-shifted copies, a plane a lane tile
+        "vmem_shifted_bytes": shifted,
+        # None: Mosaic's default scoped-VMEM limit (16 MiB); raised where a
+        # pinned tile, or a frame too wide for a tile of 8 rows, outgrows it
+        "vmem_limit_bytes": None if interpret or (
+            scratch + shifted + blocks <= _WARP_VMEM_BUDGET) else _VMEM_LIMIT_RAISED,
+        "compute_dtype": "float32",
+    }
+
+
+def _warp_kernel(tile_h: int, R: int, w_out: int, c: int, rows_in_registers):
+    """out(y,x) = sum_dy sum_dx relu(1-|fy-dy|) relu(1-|fx-dx|) img(y+dy, x+dx)
+    over dy, dx in [-R, R+1]: exactly bilinear interpolation at the clipped
+    flow, because the hat weights are nonzero only at floor(f) and
+    floor(f)+1. Every shift is static; no gather anywhere.
+
+    A grid step walks its ``(c, tile_h, w_out)`` tile in register-sized
+    strips (8 rows x 128 lanes, a vreg a plane; PR 51). As whole-tile
+    expressions the accumulator alone was 60 of the file's 64
+    vregs at 16 x 1280 x 3 and every tap went through VMEM: 22.7K of 24.2K
+    loads and stores a grid step were spills. Now, once the slab has
+    landed, its ``2R+2`` column-shifted copies go to the ``shifted`` scratch
+    a row tile at a time (a lane rotate a copy, paid once a grid step, not
+    once a tap), each lane tile of each plane an array of its own,
+    ``[rows, 128]``: there 8 rows at ANY row offset are one ``vld``. Then
+    strip by strip: the flow clipped and the ``2R+2`` wx and wy hats once; column
+    shift by column shift ``col = sum_dy wy * rows`` and ``acc += wx * col``
+    in registers, every plane at once (the arrays are ``[c, 8, 128]``: c
+    vregs each, which Mosaic unrolls; written a plane at a time the body is
+    c times the equations, and the serving host took 9 s more to trace and
+    lower the step's kernels beside a busy generator: PR 51's chip runs);
+    one store. The sum is the product form's, re-associated. The v5e issues
+    one unaligned ``vld`` a bundle (aligned ones pair up) and the loop has
+    VALU slots to spare, so ``rows_in_registers`` of a window's row offsets
+    are merged from its two aligned row tiles by a sublane select and a
+    rotate instead (271 -> 233 bundles a strip at c 3, R 4). ``jax.lax``
+    ops throughout: the step traces this body ten times, twice over."""
+    side = 2 * R + 2
+    halo = _round_up(side - 1, _SUBLANE)
+    slab = tile_h + halo
+    lane_tiles = w_out // _LANE
+
+    def hat(f, d):
+        return lax.max(lax.sub(1.0, lax.abs(lax.sub(f, float(d)))), 0.0)
+
+    def kernel(img_ref, flow_ref, out_ref, scratch, shifted, sem):
         b = pl.program_id(0)
         i = pl.program_id(1)
-        ci = pltpu.make_async_copy(
-            img_ref.at[b, :, pl.ds(i * tile_h, slab), :],
-            scratch, sem_i)
-        cf = pltpu.make_async_copy(
-            flow_ref.at[b, :, pl.ds(i * tile_h, _round_up(tile_h, _SUBLANE)), :],
-            fscratch, sem_f)
-        ci.start()
-        cf.start()
-        ci.wait()
-        cf.wait()
-        img = scratch[...].astype(jnp.float32)     # (c, slab, w_al)
-        fl = fscratch[...].astype(jnp.float32)[:, :tile_h, :w]  # (2, th, w)
-        fx = jnp.clip(fl[0], -R, R)
-        fy = jnp.clip(fl[1], -R, R)
-        acc = jnp.zeros((c, tile_h, w), jnp.float32)
-        # out(y,x) = Σ_dy Σ_dx relu(1-|fy-dy|)·relu(1-|fx-dx|)·img(y+dy,x+dx)
-        # — exactly bilinear interpolation, because the hat weights are
-        # nonzero only at floor(f) and floor(f)+1. Every shift is a static
-        # slice; no gather anywhere.
-        for dy in range(-R, R + 2):
-            wy = jnp.maximum(0.0, 1.0 - jnp.abs(fy - dy))
-            for dx in range(-R, R + 2):
-                wx = jnp.maximum(0.0, 1.0 - jnp.abs(fx - dx))
-                sh = img[:, Rp + dy: Rp + dy + tile_h, Rp + dx: Rp + dx + w]
-                acc = acc + (wy * wx)[None] * sh
-        out_ref[...] = acc[None].astype(out_ref.dtype)
+        copy = pltpu.make_async_copy(
+            img_ref.at[b, :, pl.ds(i * tile_h, slab), :], scratch, sem)
+        copy.start()
+        copy.wait()
+        w_al = scratch.shape[-1]
+
+        def shift_row_tile(t):
+            rows = pl.ds(_aligned(t, _SUBLANE), _SUBLANE)
+            x = scratch[:, rows, :]                 # (c, 8, w_al)
+            for kx in range(side):
+                y = pltpu.roll(x, w_al - kx, 2) if kx else x
+                for q in range(lane_tiles):
+                    shifted[kx, q, :, rows, :] = y[:, :, q * _LANE:(q + 1) * _LANE]
+
+        _loop(slab // _SUBLANE, shift_row_tile)
+
+        def strip_at(s):
+            q = s % lane_tiles
+            row0 = _aligned(s // lane_tiles, _SUBLANE)
+            rows = pl.ds(row0, _SUBLANE)
+            lanes = pl.ds(_aligned(q, _LANE), _LANE)
+            planes = (c, _SUBLANE, _LANE)
+
+            def hats(f):
+                f = lax.broadcast_in_dim(lax.clamp(-float(R), f, float(R)), planes, (1, 2))
+                return [hat(f, k - R) for k in range(side)]
+
+            wx, wy = hats(flow_ref[0, 0, rows, lanes]), hats(flow_ref[0, 1, rows, lanes])
+            sublane = lax.broadcasted_iota(jnp.int32, planes, 1)
+            wraps = {ky: lax.ge(sublane, ky) for ky in rows_in_registers}
+            acc = None
+            for kx in range(side):
+                win = shifted.at[kx, q, :, pl.ds(row0, _SUBLANE + halo), :]
+                tiles = [win[:, pl.ds(0, _SUBLANE), :],
+                         win[:, pl.ds(_SUBLANE, _SUBLANE), :]]
+
+                def window_rows(ky):
+                    """Rows [ky, ky + 8) of the window, every plane's."""
+                    if ky in (0, _SUBLANE):
+                        return tiles[ky // _SUBLANE]
+                    if ky in wraps:     # row i on sublane (i + ky) % 8, then home
+                        return pltpu.roll(lax.select(wraps[ky], *tiles),
+                                          _SUBLANE - ky, 1)
+                    return win[:, pl.ds(ky, _SUBLANE), :]
+
+                col = None
+                for ky in range(side):
+                    term = lax.mul(wy[ky], window_rows(ky))
+                    col = term if col is None else lax.add(col, term)
+                term = lax.mul(wx[kx], col)
+                acc = term if acc is None else lax.add(acc, term)
+            out_ref[0, :, rows, lanes] = acc.astype(out_ref.dtype)
+
+        _loop(tile_h // _SUBLANE * lane_tiles, strip_at)
 
     return kernel
 
@@ -314,52 +461,50 @@ def warp_bounded_pallas(
     weighted static shifts trade FLOPs for the dynamic gathers TPUs hate —
     worth it while max_disp stays small (Farneback flows at video rates
     are a few px). ``interpret=None`` auto-selects: compiled on TPU,
-    interpret mode elsewhere.
+    interpret mode elsewhere. The tiling is :func:`warp_plan`'s. A ``jit``
+    of its own: the flow step calls it ten times at four distinct shapes
+    and the Engine traces a step twice, and the kernel's unrolled taps
+    (hundreds of loads, each an indexer) are then traced once a shape.
     """
-    interpret = _auto_interpret(interpret)
-    R = int(max_disp)
-    if R < 1:
-        raise ValueError("max_disp must be >= 1")
-    Rp = R + 1
+    return _warp_bounded(img, flow, int(max_disp), tile_h,
+                         _auto_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _warp_bounded(img, flow, max_disp, tile_h, interpret):
+    plan = warp_plan(img.shape, max_disp, tile_h, interpret)
+    R, side = plan["max_disp"], 2 * plan["max_disp"] + 2
     b, h, w, c = img.shape
-    # Smaller tile than the stencils: the (2R+2)² unrolled hat taps give
-    # Mosaic ~per-tap temporaries, and at tile 24 / R=4 the scoped-VMEM
-    # stack hit 26 MB vs the default 16 MB limit on v5e. 16 rows halves
-    # the liveness; the raised vmem_limit_bytes below covers the rest
-    # (v5e has 128 MiB of VMEM; the default limit is a conservative 16).
-    th, h_pad = _resolve_tile_h(h, tile_h, target=16,
-                                compiled=not interpret)
-    w_al = _round_up(w + 2 * Rp, _LANE)
-    w_fl = _round_up(w, _LANE)  # the flow DMA copies full width too
+    th, h_pad, w_al, w_out = (plan[k] for k in ("tile_h", "h_pad", "w_aligned", "w_out"))
+    slab = plan["slab_rows"]
 
+    # Taps dy, dx in [-R, R+1]: R edge rows over the image and R+1 under
+    # it, then the filler that keeps the last slab in bounds and the width
+    # lane-aligned (never read for a valid output: ``_pad_rows``).
     x = jnp.transpose(img, (0, 3, 1, 2))                    # (b,c,h,w)
-    x = jnp.pad(x, ((0, 0), (0, 0), (Rp, Rp), (Rp, Rp)), mode="edge")
-    x = _pad_rows(x, _extra_rows(h, h_pad, th, 2 * Rp))
-    x = _pad_cols(x, w_al - (w + 2 * Rp))
+    x = jnp.pad(x, ((0, 0), (0, 0), (R, R + 1), (R, R + 1)), mode="edge")
+    x = _pad_rows(x, h_pad - th + slab - (h + side - 1))
+    x = _pad_cols(x, w_al - (w + side - 1))
     fl = jnp.transpose(flow, (0, 3, 1, 2))                  # (b,2,h,w)
-    fl = _pad_rows(fl, h_pad - h + _round_up(th, _SUBLANE) - th)
-    fl = _pad_cols(fl, w_fl - w)
+    fl = _pad_cols(_pad_rows(fl, h_pad - h), w_out - w)
 
-    kernel = _warp_kernel(th, R, w, c)
     out = pl.pallas_call(
-        kernel,
-        grid=(b, h_pad // th),
+        _warp_kernel(th, R, w_out, c, plan["rows_in_registers"]),
+        grid=tuple(plan["grid"]),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, c, th, w), lambda bb, ii: (bb, 0, ii, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c, h_pad, w), img.dtype),
+                  pl.BlockSpec((1, 2, th, w_out), lambda bb, ii: (bb, 0, ii, 0))],
+        out_specs=pl.BlockSpec((1, c, th, w_out), lambda bb, ii: (bb, 0, ii, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, c, h_pad, w_out), img.dtype),
         scratch_shapes=[
-            pltpu.VMEM((c, _slab_rows(th, 2 * Rp), w_al), jnp.float32),
-            pltpu.VMEM((2, _round_up(th, _SUBLANE), w_fl), jnp.float32),
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((c, slab, w_al), jnp.float32),
+            pltpu.VMEM((side, w_out // _LANE, c, slab, _LANE), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=_vmem_params(plan["vmem_limit_bytes"]),
         interpret=interpret,
         name="warp_bounded",
     )(x, fl)
-    return jnp.transpose(out[:, :, :h, :], (0, 2, 3, 1))
+    return jnp.transpose(out[:, :, :h, :w], (0, 2, 3, 1))
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,7 @@ CLOCK_GHZ = 1.5     # TPU v5e core clock: one VLIW bundle a cycle
 COUNTED = ("vld", "vst", "vpow2", "vrot.lane", "vrot.slane", "vsel", "vmul", "vadd", "vsub", "vrcp", "vrsqrt",
            "vpcnt", "vand", "vor", "vcmp", "vshll", "vshrl")
 HIST_TILES = (8, 136, 2048)     # clahe_1080p: rows of tiles a plane, a row of 8 tiles of 136 x 256, side by side
+FLOW_CONFIG = os.path.join(HERE, "..", "chipbench", "configs", "flow_720p.json")
 
 _BUNDLE = re.compile(r"^\s*(?:0x[0-9a-f]+|\d+)\s+(?:([A-Z]{2}):|:)\s*(?:> ?)*\{(.*)$")
 _EXIT_TEST = re.compile(r"%(p\w+) = scmp\.ge\.s32\.totalorder .*?, (\d+) /\* loop exit test \*/")
@@ -76,8 +77,9 @@ def read_bundles(text):
     """``(counts, loops)`` of one kernel's ``final_bundles`` text: dynamic counts a grid step (``bundles`` and each
     opcode of ``COUNTED``, spills apart) and the loops inside a grid step as first / last bundle (positions in the
     listing) and trips. A loop opens at an ``LB:`` bundle and closes at the next backward branch not yet paired
-    (``sbr.rel (!%p)``, ``%p`` the loop's exit test): the listing nests them properly. The branch's own ``target
-    bundleno`` counts in another numbering than the listing's and is not used."""
+    (``sbr.rel (!%p)``, ``%p`` a loop's exit test; on any other predicate it is a forward branch, a pipelined grid's
+    "skip this copy", and closes nothing): the listing nests them properly. The branch's own ``target bundleno`` counts
+    in another numbering than the listing's and is not used."""
     bundles, trips_of, open_loops, loops = [], {}, [], []      # loops: [first, last, trips]
     for line in text.splitlines():
         m = _BUNDLE.match(line)
@@ -89,6 +91,8 @@ def read_bundles(text):
             open_loops.append(here)
         trips_of.update((name, int(n)) for name, n in _EXIT_TEST.findall(line))
         for name in _BACK_EDGE.findall(line):
+            if name not in trips_of:            # a forward branch: a pipelined grid's "skip this copy"
+                continue
             if not open_loops:
                 raise ValueError(f"a backward branch with no open loop at bundle {here}")
             loops.append([open_loops.pop(), here, trips_of.get(name)])
@@ -134,6 +138,22 @@ def _hist_call(pk, tiles, interpret=False):
     return pk.tile_hist_pallas(tiles, 8, tiles.shape[2] * tiles.shape[3] // 8, "clahe_hist", interpret)
 
 
+def warp_call(role, level, toy=False):
+    """``((H, W, planes), max_disp)`` of one of the flow step's ten ``warp_bounded`` calls at the cell's geometry
+    (``chipbench/configs/flow_720p.json``): the final warp of the previous frame, or an inner warp of the polynomial
+    stack at pyramid ``level`` of the estimation grid (``ops/flow.py``: ``flow_warp``, ``_coarse_to_fine``)."""
+    with open(FLOW_CONFIG) as f:
+        config = json.load(f)
+    g, kw = config["geometry"], config["filter"]["kwargs"]
+    if toy:
+        g = config["toy"]["geometry"]
+    if role == "final":
+        return (g["height"], g["width"], g["channels"]), kw["max_disp"]
+    eh, ew = g["height"] // kw["flow_scale"], g["width"] // kw["flow_scale"]
+    return ((max(8, round(eh * 0.5 ** level)), max(8, round(ew * 0.5 ** level)), 5),
+            max(1, -(-kw["max_disp"] // kw["flow_scale"])))
+
+
 def dump_child(args):
     """The child: compile for a described v5e with the dump on. May not return (see the module docstring)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -150,6 +170,12 @@ def dump_child(args):
         tiles = jax.ShapeDtypeStruct((3 * args.batch, *HIST_TILES), jnp.uint8, sharding=one_chip)
         jax.jit(lambda t: _hist_call(pk, t)).lower(tiles).compile()
         return
+    if args.kernel == "warp_bounded":
+        (h, w, c), max_disp = warp_call(args.role, args.level)
+        img = jax.ShapeDtypeStruct((args.batch, h, w, c), jnp.float32, sharding=one_chip)
+        flow = jax.ShapeDtypeStruct((args.batch, h, w, 2), jnp.float32, sharding=one_chip)
+        jax.jit(lambda i, f: pk.warp_bounded_pallas(i, f, max_disp=max_disp, interpret=False)).lower(img, flow).compile()
+        return
     batch = jax.ShapeDtypeStruct((args.batch, args.height, args.width, 3), jnp.float32, sharding=one_chip)
     jax.jit(lambda x: pk.sobel_bilateral_nhwc_pallas(x, d=args.d, tile_h=args.tile_h)).lower(batch).compile()
 
@@ -161,7 +187,7 @@ def schedule(args):
         child = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--dump-child", "--kernel", args.kernel, "--tree", args.tree, "--d", str(args.d),
              "--tile-h", str(args.tile_h), "--batch", str(args.batch), "--height", str(args.height),
-             "--width", str(args.width)], env=env, capture_output=True, text=True)
+             "--width", str(args.width), "--role", args.role, "--level", str(args.level)], env=env, capture_output=True, text=True)
         final = [p for p in glob.glob(os.path.join(dump, f"*{args.kernel}*final_bundles.txt"))
                  if "schedule-analysis" not in p]
         if len(final) != 1:
@@ -302,11 +328,83 @@ def chip_hist(args):
                                  "equal": bool(np.array_equal(got[0, :, :, :8].swapaxes(1, 2), want))}}
 
 
+def _pallas_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr and of every jaxpr under it (the warp's wrapper is a jit of its own)."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def chip_warp(args):
+    """``--chip --kernel warp_bounded``: one of the flow step's calls, its ``pallas_call`` alone, three ways (``one_tap``
+    cuts every ``range`` as long as the taps' axis, ``2 max_disp + 2``, to one trip: in the strip form that is also
+    one column-shifted copy), and ``shipped`` against ``ops.flow.warp_by_flow`` on the clipped flow on two frames."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    pk = _import_kernels(args.tree)
+    from dvf_tpu.ops.flow import warp_by_flow
+
+    on_tpu = jax.default_backend() == "tpu"
+    if not (on_tpu or args.toy):
+        raise SystemExit("--chip times a TPU; there is none here (--toy checks the script on any backend)")
+    (h, w, c), max_disp = warp_call(args.role, args.level, args.toy)
+    side = 2 * max_disp + 2
+    rng = np.random.default_rng(51)
+    img = jnp.asarray(rng.random((args.batch, h, w, c), dtype=np.float32))
+    flow = jnp.asarray((rng.random((args.batch, h, w, 2), dtype=np.float32) - 0.5) * (2 * max_disp + 2))
+
+    def warp(i, f):
+        return pk.warp_bounded_pallas(i, f, max_disp=max_disp, interpret=not on_tpu)
+
+    def one_trip(*a):                   # the taps' ranges (dy, dx: `side` long, however they are spelled) -> one trip
+        r = builtins.range(*a)
+        return r[:1] if len(r) == side else r
+
+    variants = dict(_variants(pk), one_tap=mock.patch.object(pk, "range", one_trip, create=True))
+    out = {"device": {"platform": jax.devices()[0].platform, "kind": jax.devices()[0].device_kind}, "kernel_ms": {}}
+    for name in ("shipped", "one_tap", "no_slab_wait"):
+        jax.clear_caches()              # or a variant is answered with the trace of the one before it
+        with variants[name]:
+            (call,) = _pallas_calls(jax.make_jaxpr(warp)(img, flow).jaxpr)
+            run = jax.jit(lambda x, f, call=call: call.primitive.bind(x, f, **call.params)[0])
+            operands = [jnp.asarray(rng.random(v.aval.shape, dtype=np.float32)) for v in call.invars]
+            compiled = run.lower(*operands).compile()
+        jax.block_until_ready(compiled(*operands))
+        times = []
+        for _ in range(args.steps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(compiled(*operands))
+            times.append((time.perf_counter() - t0) * 1e3)
+        out["kernel_ms"][name] = {"min": round(min(times), 3), "median": round(sorted(times)[len(times) // 2], 3)}
+        out["grid"] = [int(v) for v in call.params["grid_mapping"].grid]
+        print(f"[probe] {name}: {out['kernel_ms'][name]} ms a call, grid {out['grid']}", flush=True)
+    ms = {k: v["min"] for k, v in out["kernel_ms"].items()}
+    steps = out["grid"][0] * out["grid"][1]
+    out["us_a_grid_step"] = {k: round(v * 1e3 / steps, 3) for k, v in ms.items()}
+    out["slab_wait_us_a_grid_step"] = round((ms["shipped"] - ms["no_slab_wait"]) * 1e3 / steps, 3)
+    out["taps_us_a_grid_step"] = round((ms["shipped"] - ms["one_tap"]) * 1e3 / steps, 3)
+    got = np.asarray(warp(img[:2], flow[:2]), np.float64)
+    want = np.asarray(warp_by_flow(img[:2], jnp.clip(flow[:2], -max_disp, max_disp)), np.float64)
+    out["against_gather"] = {"max_abs": float(np.abs(got - want).max()), "mean_abs": float(np.abs(got - want).mean()),
+                             "values": int(got.size)}
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chip", action="store_true", help="time the kernel on the attached TPU (else: its schedule, no chip)")
     ap.add_argument("--toy", action="store_true", help="with --chip: a tiny shape on any backend; checks the script only")
-    ap.add_argument("--kernel", choices=("sobel_bilateral", "clahe_hist"), default="sobel_bilateral")
+    ap.add_argument("--kernel", choices=("sobel_bilateral", "clahe_hist", "warp_bounded"), default="sobel_bilateral")
+    ap.add_argument("--role", choices=("final", "inner"), default="final", help="warp_bounded: which of the step's calls")
+    ap.add_argument("--level", type=int, default=0, help="warp_bounded: an inner warp's pyramid level (0 = 360 x 640)")
     ap.add_argument("--tree", default=os.path.join(HERE, ".."), help="the checkout whose dvf_tpu is probed")
     ap.add_argument("--d", type=int, default=9)
     ap.add_argument("--tile-h", type=int, default=24)
@@ -322,26 +420,33 @@ def main() -> int:
         return 0
     if args.toy:
         args.batch, args.height, args.width, args.tile_h, args.steps = 2, 32, 200, 16, 2
-    stem = "stencil" if args.kernel == "sobel_bilateral" else args.kernel
+    stem = {"sobel_bilateral": "stencil", "warp_bounded": "warp"}.get(args.kernel, args.kernel)
     path = args.out or os.path.join(HERE, "..", "chiprun_out", f"{stem}_kernel_probe.json")
     result = {}
     if os.path.exists(path):            # the two halves run on two machines and share the file
         with open(path) as f:
             result = json.load(f)
+    result["tree"] = os.path.relpath(os.path.abspath(args.tree), os.path.join(HERE, ".."))
+    record = result
     if args.kernel == "clahe_hist":
         result["shape"] = {"batch": args.batch, "tiles": [3 * args.batch, *HIST_TILES]}
+    elif args.kernel == "warp_bounded":     # a record a call of the step: final, inner_level0 ..
+        call = "final" if args.role == "final" else f"inner_level{args.level}"
+        record = result.setdefault("calls", {}).setdefault(call, {})
+        (h, w, c), max_disp = warp_call(args.role, args.level, args.toy)
+        record["shape"] = {"batch": args.batch, "height": h, "width": w, "planes": c, "max_disp": max_disp}
     else:
         result["shape"] = {"batch": args.batch, "height": args.height, "width": args.width, "d": args.d,
                            "tile_h": args.tile_h}
-    result["tree"] = os.path.relpath(os.path.abspath(args.tree), os.path.join(HERE, ".."))
+    half = "chip" if args.chip else "schedule"
     if args.chip:
-        result["chip"] = chip_hist(args) if args.kernel == "clahe_hist" else chip(args)
+        record["chip"] = {"clahe_hist": chip_hist, "warp_bounded": chip_warp}.get(args.kernel, chip)(args)
     else:
-        result["schedule"] = schedule(args)
+        record["schedule"] = schedule(args)
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(result, f, indent=1, sort_keys=True)
-    print(json.dumps(result["chip" if args.chip else "schedule"], indent=1, sort_keys=True))
+    print(json.dumps(record[half], indent=1, sort_keys=True))
     print(f"[probe] wrote {os.path.relpath(path)}")
     return 0
 

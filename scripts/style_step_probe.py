@@ -11,7 +11,11 @@ same way (``super_resolution(scale=2)`` at 16 x 540 x 960 in, 1080 x 1920 out; t
 counted CLAHE of ``chipbench/configs/clahe_1080p.json`` (``clahe(impl="pallas")`` at 1080 x 1920, uint8 in and out with
 no float conversion, as the Engine steps a ``uint8_ok`` filter; the scopes of ``ops/histogram.py::_clahe_planes_pallas``:
 ``clahe_hist``, ``clahe_lut``, ``clahe_apply``; ``--impl sort`` probes the sort + gather form, at a batch it fits, and
-the first frames of either are compared with ``chipbench/refs/clahe_1080p.py``). Compiles the step program
+the first frames of either are compared with ``chipbench/refs/clahe_1080p.py``); ``--model flow`` the Farneback flow
+warp of ``chipbench/configs/flow_720p.json`` (``flow_warp`` with the bounded kernel for its final warp and its nine
+inner warps, one session's pairs at 720 x 1280; the scopes of ``ops/flow.py``: ``flow_final_warp``, ``flow_inner_warp``,
+the rest by op, and the ``warp_bounded`` calls summed by name, which a tree from before the scopes (``--tree``) has
+too). Compiles the step program
 of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
 weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
@@ -27,6 +31,8 @@ relu and the residual add). Run on the chip:
     chiprun -- python scripts/style_step_probe.py --model stencil --batch 64   # chiprun_out/stencil_step_probe.json
 
     chiprun -- python scripts/style_step_probe.py --model clahe --batch 64     # chiprun_out/clahe_step_probe.json
+
+    chiprun -- python scripts/style_step_probe.py --model flow --batch 64      # chiprun_out/flow_step_probe.json
 
 ``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
 nothing.
@@ -86,11 +92,28 @@ def _clahe_stages(kwargs, shape):
             "clahe_apply": "%d^2 cells of %s, lane-gather lookup, float32 blend" % (plan["cells"], tile)}
 
 
+def _flow_stages(kwargs, shape):
+    from dvf_tpu.ops import get_filter
+
+    plan = getattr(get_filter("flow_warp", **kwargs), "kernel_plan", None)     # None: a tree from before PR 51
+    calls = (plan(shape) or {}).get("calls", []) if plan else []
+
+    def form(role):
+        return "; ".join("%d x %s planes %d taps %d tile %d grid %s" % (
+            k["count"], "level %s" % k["level"] if role == "inner" else "frame", k["planes"], k["taps"], k["tile_h"],
+            k["grid"]) for k in calls if k["role"] == role)
+
+    return {"flow_final_warp": form("final"), "flow_inner_warp": form("inner")}
+
+
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))
 _CLAHE = {"clip_limit": 2.0, "grid": 8, "on_gray": False, "impl": "pallas"}     # as chipbench/configs/clahe_1080p.json
 _STENCIL = {"d": 9, "sigma_color": 0.1, "sigma_space": 2.0, "magnitude_scale": 1.0, "impl": "pallas"}   # as the cell's file
 _ESPCN = {"scale": 2, "fast_convs": False, "dtype": "bfloat16"}          # as chipbench/configs/sr2x_540p.json
+_FLOW = {"levels": 3, "win_size": 15, "n_iters": 3, "flow_scale": 2, "warp_impl": "pallas", "max_disp": 4,
+         "win_type": "gaussian", "inner_warp": "pallas"}                 # as chipbench/configs/flow_720p.json
 MODELS = {
+    "flow": ("flow_warp", (720, 1280), _FLOW, _FLOW, _flow_stages),
     "style": ("style_transfer", (720, 1280), {"base_channels": 32, "n_residual": 5},
               {"base_channels": 8, "n_residual": 2}, _style_stages),
     "espcn": ("super_resolution", (540, 960), _ESPCN, _ESPCN, _espcn_stages),
@@ -144,7 +167,10 @@ def main() -> int:
     ap.add_argument("--impl", choices=("pallas", "chain", "sort"), default=None,
                     help="stencil: the fused kernel or the jnp chain; clahe: the counted kernels or the sort + gather form")
     ap.add_argument("--out", default=None, help="default chiprun_out/<model>_step_probe.json")
+    ap.add_argument("--tree", default=None, help="another checkout whose dvf_tpu is probed with this script")
     args = ap.parse_args()
+    if args.tree:
+        sys.path.insert(0, os.path.abspath(args.tree))
     args.out = args.out or f"chiprun_out/{args.model}_step_probe.json"
 
     import jax
@@ -219,6 +245,7 @@ def main() -> int:
               "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
               "step_wall_ms": {"min": min(wall), "median": sorted(wall)[len(wall) // 2], "max": max(wall)},
               "traced_ms_a_step": sum(ops.values()), "by_stage_ms": by_stage, "norm_ms": by_part,
+              "warp_bounded_ms": {name: ms for ms, name, *_ in rows if "warp_bounded" in name},
               "ops": [{"ms": ms, "op": name, "stage": stage, "norm": part, "result": result}
                       for ms, name, stage, result, part in rows]}
     print(f"[probe {report['device']}{' toy' if args.toy else ''}] shape {shape}: compile {compile_s:.1f} s, "
@@ -232,6 +259,8 @@ def main() -> int:
     for part, stages in sorted(by_part.items()):
         print(f"[probe] {part} {sum(stages.values()):.2f}: "
               + ", ".join(f"{k} {v:.2f}" for k, v in sorted(stages.items(), key=lambda kv: -kv[1])))
+    if report["warp_bounded_ms"]:
+        print(f"[probe] warp_bounded, {len(report['warp_bounded_ms'])} calls: {sum(report['warp_bounded_ms'].values()):.2f} ms a step")
     if args.model == "clahe":           # integers: the step's first frames against the benchmark's plain reference
         from chipbench import spec
 

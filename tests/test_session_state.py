@@ -154,6 +154,30 @@ def test_sessions_sharing_batches_equal_each_alone(name, alone):
     assert state["bytes"] > 0
 
 
+@pytest.mark.parametrize("name", sorted(FILTERS))
+def test_served_row_says_which_kernels_the_step_runs(name):
+    """The bucket row of a served flow session carries the step's
+    ``warp_bounded`` calls as data (``flow_warp``'s ``kernel_plan`` ->
+    ``Engine.kernel_plan`` -> the row's ``kernel`` block): here the final
+    warp alone, the inner warps being gathers; a filter of XLA's own ops
+    has none."""
+    from dvf_tpu.ops.pallas_kernels import warp_plan
+
+    _, stats = _serve(name, 2, [(0, 0), (0, 1), (1, 0), (1, 1)], streams=STREAMS[:2])
+    block = next(iter(stats["buckets"].values()))["kernel"]
+    if name != "flow_warp":
+        assert block is None
+        return
+    assert block["kernel"] == "warp_bounded" and block["kernels"] == ["warp_bounded"]
+    (call,) = block["calls"]
+    assert (call["role"], call["level"], call["count"]) == ("final", None, 1)
+    # the shape one device of the test mesh sees: the batch's share
+    shard = (call["grid"][0], H, W, 3)
+    assert {k: v for k, v in call.items() if k not in ("role", "level", "count")} == warp_plan(
+        shard, FLOW_KW["max_disp"], interpret=True)
+    assert call["taps"] == 100 and call["planes"] == 3 and call["strip"] == [8, 128]
+
+
 @pytest.mark.parametrize("inner", ["gather", "pallas"])
 def test_flow_matches_the_plain_reference(inner):
     """(a) The single-stream run the other tests are held to is itself the

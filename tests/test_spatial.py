@@ -318,45 +318,219 @@ def test_pallas_fused_sobel_bilateral_registered(batch):
     assert f.halo == 3  # bilateral r=2 + sobel support 1
 
 
-def test_pallas_warp_matches_gather_golden(rng):
+# The two forms the flow step serves: the final warp (3 planes, ±4 px, 100
+# taps) and the inner warps of the polynomial stack (5 planes, ±2 px, 36).
+WARP_FORMS = {"final": (3, 4), "inner": (5, 2)}
+# (H, W) -> (a pinned tile or None, what the strip walk meets there); interpret
+# mode runs the same 8 x 128 strips as the chip (``warp_plan``'s ``strip``).
+WARP_GEOMETRIES = {
+    (24, 32): (None, "one tile, one strip a row of strips"),
+    (36, 40): (None, "an H that is no multiple of 8 (padded to 40), a W that is no lane multiple"),
+    (56, 200): (8, "seven tiles of one row of strips, two strips across (W 200 in 256 lanes)"),
+    (100, 136): (None, "one tile of 104 rows (H padded), thirteen strips down, two across"),
+}
+
+
+def _warp_flow(rng, kind, shape, R):
+    """Flows that meet the hats' corners: ``random`` straddles the clip,
+    ``clip`` sits at and beyond it on both sides, ``zeros`` is +0.0 and
+    -0.0, ``integers`` are exact displacements (one hat 1, its neighbours
+    0). Multiples of 1/64: the golden adds the flow to the pixel's
+    coordinate in float32, and past column 128 a finer fraction is rounded
+    there (1.5e-5 a step) where the kernel, which never forms the
+    coordinate, keeps it."""
+    if kind == "random":
+        return np.round((rng.random(shape) - 0.5) * (2 * R + 3) * 64).astype(np.float32) / 64
+    if kind == "clip":
+        return rng.choice(np.float32([-R - 2.5, -R, R, R + 0.75]), shape)
+    if kind == "zeros":
+        return rng.choice(np.float32([0.0, -0.0]), shape)
+    return rng.integers(-R, R + 1, shape).astype(np.float32)
+
+
+def _assert_warp_matches(img, flow, R, tile_h=None):
     from dvf_tpu.ops.flow import warp_by_flow
     from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas
 
-    img = rng.random((2, 24, 32, 3)).astype(np.float32)
-    flow = (rng.random((2, 24, 32, 2)).astype(np.float32) - 0.5) * 7.0
-    want = warp_by_flow(jnp.asarray(img), jnp.clip(jnp.asarray(flow), -4, 4))
+    want = warp_by_flow(jnp.asarray(img), jnp.clip(jnp.asarray(flow), -R, R))
     got = warp_bounded_pallas(jnp.asarray(img), jnp.asarray(flow),
-                              max_disp=4, interpret=True)
+                              max_disp=R, tile_h=tile_h, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
 
 
-def test_pallas_warp_unaligned_height_and_width(rng):
+@pytest.mark.parametrize("hw", sorted(WARP_GEOMETRIES))
+@pytest.mark.parametrize("form", sorted(WARP_FORMS))
+def test_pallas_warp_matches_gather_golden(rng, form, hw):
+    c, R = WARP_FORMS[form]
+    img = rng.random((2, *hw, c)).astype(np.float32)
+    _assert_warp_matches(img, _warp_flow(rng, "random", (2, *hw, 2), R), R,
+                         tile_h=WARP_GEOMETRIES[hw][0])
+
+
+@pytest.mark.parametrize("form", sorted(WARP_FORMS))
+def test_pallas_warp_under_a_small_vmem_budget(rng, form, monkeypatch):
+    """The plan's own pick where a frame's copies outgrow the budget, as
+    720 rows do on the chip: several tiles and an H padded to whole ones
+    (100 rows as three tiles of 40)."""
+    from dvf_tpu.ops import pallas_kernels as pk
+
+    c, R = WARP_FORMS[form]
+    shape = (1, 100, 136, c)
+    monkeypatch.setattr(pk, "_WARP_VMEM_BUDGET", sum(pk._warp_vmem_bytes(
+        40, 16 if R == 4 else 8, c, 2 * R + 2, 256, 256)))
+    plan = pk.warp_plan(shape, R, interpret=True)
+    assert (plan["tile_h"], plan["h_pad"], plan["grid"]) == (40, 120, [1, 3])
+    jax.clear_caches()      # the wrapper's jit was traced under the other budget
+    img = rng.random(shape).astype(np.float32)
+    _assert_warp_matches(img, _warp_flow(rng, "random", (1, 100, 136, 2), R), R)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("hw", [(36, 40), (45, 130)])
+@pytest.mark.parametrize("form", sorted(WARP_FORMS))
+def test_pallas_warp_unaligned_height_and_width(rng, form, hw):
     """H with no 8-aligned divisor + W that is no lane multiple exercise
-    both alignment-padding paths (incl. the flow input's col pad — the
-    flow DMA copies full width, so its width must be lane-aligned on
-    TPU; round-4 code-review finding)."""
-    from dvf_tpu.ops.flow import warp_by_flow
-    from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas
-
-    img = rng.random((2, 36, 40, 3)).astype(np.float32)
-    flow = (rng.random((2, 36, 40, 2)).astype(np.float32) - 0.5) * 6.0
-    want = warp_by_flow(jnp.asarray(img), jnp.clip(jnp.asarray(flow), -4, 4))
-    got = warp_bounded_pallas(jnp.asarray(img), jnp.asarray(flow),
-                              max_disp=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+    both alignment-padding paths (incl. the flow input's col pad: the
+    flow block is the lane-aligned width, so the flow is padded to it)."""
+    c, R = WARP_FORMS[form]
+    img = rng.random((2, *hw, c)).astype(np.float32)
+    _assert_warp_matches(img, _warp_flow(rng, "random", (2, *hw, 2), R), R)
 
 
-def test_pallas_warp_border_clamp_matches(rng):
-    """Edge-padding reproduces the golden's coordinate clamping."""
-    from dvf_tpu.ops.flow import warp_by_flow
-    from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas
+@pytest.mark.parametrize("kind", ["constant", "clip", "zeros", "integers"])
+@pytest.mark.parametrize("form", sorted(WARP_FORMS))
+def test_pallas_warp_border_clamp_matches(rng, form, kind):
+    """Edge-padding reproduces the golden's coordinate clamping, also
+    where every weight sits on a hat's corner."""
+    c, R = WARP_FORMS[form]
+    img = rng.random((1, 16, 136, c)).astype(np.float32)
+    flow = (np.full((1, 16, 136, 2), R - 0.3125, np.float32) if kind == "constant"
+            else _warp_flow(rng, kind, (1, 16, 136, 2), R))
+    _assert_warp_matches(img, flow, R)
 
-    img = rng.random((1, 8, 16, 3)).astype(np.float32)
-    flow = np.full((1, 8, 16, 2), 3.7, np.float32)
-    want = warp_by_flow(jnp.asarray(img), jnp.asarray(flow))
-    got = warp_bounded_pallas(jnp.asarray(img), jnp.asarray(flow),
-                              max_disp=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+@pytest.mark.parametrize("c,R,hw", [(1, 1, (16, 32)), (4, 3, (20, 36)), (2, 8, (24, 40))],
+                         ids=["R1", "R3_window_of_one_halo_tile", "R8_window_of_four_row_tiles"])
+def test_pallas_warp_other_bounds(rng, c, R, hw):
+    """One walk whatever the bound: a window of 2 to 4 row tiles."""
+    img = rng.random((1, *hw, c)).astype(np.float32)
+    _assert_warp_matches(img, _warp_flow(rng, "random", (1, *hw, 2), R), R)
+
+
+def _pallas_calls(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("hw", [(36, 40), (100, 136)])
+@pytest.mark.parametrize("form", sorted(WARP_FORMS))
+def test_warp_plan_is_what_the_call_is_built_with(form, hw):
+    """The plan a served step states is the ``pallas_call`` that runs: its
+    grid, the slab and the copies it holds in VMEM, its operands, its
+    limit."""
+    from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas, warp_plan
+
+    c, R = WARP_FORMS[form]
+    img = jax.ShapeDtypeStruct((2, *hw, c), jnp.float32)
+    flow = jax.ShapeDtypeStruct((2, *hw, 2), jnp.float32)
+    plan = warp_plan(img.shape, R, interpret=True)
+    (call,) = _pallas_calls(jax.make_jaxpr(
+        lambda i, f: warp_bounded_pallas(i, f, max_disp=R, interpret=True))(img, flow).jaxpr, [])
+    assert call.params["name"] == "warp_bounded"
+    assert tuple(call.params["grid_mapping"].grid) == tuple(plan["grid"])
+    th, h_pad, slab = plan["tile_h"], plan["h_pad"], plan["slab_rows"]
+    assert th % 8 == 0 and h_pad % th == 0 and h_pad >= hw[0] and plan["grid"][1] == h_pad // th
+    assert plan["taps"] == (2 * R + 2) ** 2 and plan["planes"] == c
+    x, fl = (v.aval.shape for v in call.invars)
+    assert x == (2, c, h_pad - th + slab, plan["w_aligned"])
+    assert fl == (2, 2, h_pad, plan["w_out"])
+    assert call.outvars[0].aval.shape == (2, c, h_pad, plan["w_out"])
+    scratch = [a.shape for a in call.params["grid_mapping"].scratch_avals][:2]     # then the semaphore
+    assert [int(np.prod(shape)) * 4 for shape in scratch] == [
+        plan["vmem_scratch_bytes"], plan["vmem_shifted_bytes"]]
+    rows, lanes = plan["strip"]
+    assert scratch[1] == (2 * R + 2, plan["w_out"] // lanes, c, slab, lanes) and rows == 8
+    assert all(0 < ky < 8 for ky in plan["rows_in_registers"])
+    assert plan["vmem_limit_bytes"] is None and not dict(call.params["compiler_params"])
+
+
+def test_warp_plan_states_the_limit_the_call_compiles_under():
+    """Unpinned, the plan shrinks the tile until a grid step fits Mosaic's
+    default scoped VMEM (a 4K-wide frame: 8 rows); a pinned tile that
+    outgrows it (96 rows: 41 MB of copies) gets the raised limit, stated
+    in the plan and carried by the call."""
+    from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas, warp_plan
+
+    img = jax.ShapeDtypeStruct((1, 96, 4000, 3), jnp.float32)
+    flow = jax.ShapeDtypeStruct((1, 96, 4000, 2), jnp.float32)
+    auto = warp_plan(img.shape, 4, interpret=False)
+    assert auto["tile_h"] == 8 and auto["vmem_limit_bytes"] is None
+    plan = warp_plan(img.shape, 4, tile_h=96, interpret=False)
+    assert plan["vmem_shifted_bytes"] > 16 * 2 ** 20 and plan["vmem_limit_bytes"] == 64 * 2 ** 20
+    (call,) = _pallas_calls(jax.make_jaxpr(lambda i, f: warp_bounded_pallas(
+        i, f, max_disp=4, tile_h=96, interpret=False))(img, flow).jaxpr, [])
+    (params,) = dict(call.params["compiler_params"]).values()
+    assert params.vmem_limit_bytes == plan["vmem_limit_bytes"]
+    assert warp_plan(img.shape, 4, tile_h=96, interpret=True)["vmem_limit_bytes"] is None
+
+
+def test_warp_plan_at_the_cell_shapes():
+    """What the plan picks from the four shapes flow_720p's step calls the
+    kernel with: the tile that costs least among those whose grid step fits
+    Mosaic's default scoped VMEM (tests/test_tpu_compile.py compiles them),
+    the padded H, the strip."""
+    from dvf_tpu.ops.pallas_kernels import warp_plan
+
+    got = {hw: warp_plan((64, *hw, c), R) for hw, (c, R) in {
+        (720, 1280): (3, 4), (360, 640): (5, 2), (180, 320): (5, 2), (90, 160): (5, 2)}.items()}
+    assert [(p["tile_h"], p["h_pad"], p["grid"]) for p in got.values()] == [
+        (48, 720, [64, 15]), (72, 360, [64, 5]), (184, 184, [64, 1]), (96, 96, [64, 1])]
+    assert got[(720, 1280)]["rows_in_registers"] == [2, 6]
+    assert got[(360, 640)]["rows_in_registers"] == [2, 4]
+    for plan in got.values():
+        assert plan["strip"] == [8, 128] and plan["vmem_limit_bytes"] is None
+        assert plan["vmem_scratch_bytes"] + plan["vmem_shifted_bytes"] < 12 * 2 ** 20
+    # a 1080p frame's copies are 0.26 MB a row of the tile: 24 rows fit the budget, 48 would not
+    assert warp_plan((64, 1080, 1920, 3), 4)["tile_h"] == 24
+
+
+@pytest.mark.parametrize("tile_h,ok", [(8, True), (24, True), (12, False), (7, False)])
+def test_warp_tile_pins(tile_h, ok):
+    from dvf_tpu.ops.pallas_kernels import warp_plan
+
+    if ok:
+        assert warp_plan((1, 48, 32, 3), 2, tile_h=tile_h)["tile_h"] == tile_h
+    else:
+        with pytest.raises(ValueError):
+            warp_plan((1, 48, 32, 3), 2, tile_h=tile_h)
+
+
+def test_flow_warp_states_its_kernels():
+    """``flow_warp`` lists its step's ``warp_bounded`` calls in step order:
+    three inner shapes, coarsest first, then the final warp; none where
+    the warps are gathers."""
+    from dvf_tpu.ops.pallas_kernels import warp_plan
+
+    shape = (4, 64, 96, 3)
+    block = get_filter("flow_warp", warp_impl="pallas", inner_warp="pallas").kernel_plan(shape)
+    assert block["kernel"] == "warp_bounded" and block["kernels"] == ["warp_bounded"]
+    assert block["impl"] == "pallas"
+    assert [(k["role"], k["level"], k["count"], k["planes"], k["max_disp"], k["taps"])
+            for k in block["calls"]] == [
+        ("inner", 2, 3, 5, 2, 36), ("inner", 1, 3, 5, 2, 36), ("inner", 0, 3, 5, 2, 36),
+        ("final", None, 1, 3, 4, 100)]
+    interpret = jax.default_backend() != "tpu"
+    want = warp_plan((4, 32, 48, 5), 2, interpret=interpret)
+    assert {k: v for k, v in block["calls"][2].items() if k in want} == want
+    assert {k: v for k, v in block["calls"][3].items()
+            if k not in ("role", "level", "count")} == warp_plan(shape, 4, interpret=interpret)
+    only_final = get_filter("flow_warp", warp_impl="pallas", inner_warp="gather").kernel_plan(shape)
+    assert [k["role"] for k in only_final["calls"]] == ["final"]
+    assert get_filter("flow_warp", warp_impl="gather").kernel_plan(shape) is None
 
 
 def test_flow_warp_pallas_impl_delivers(rng):

@@ -281,3 +281,75 @@ def test_histogram_kernels_compile_through_mosaic_at_1080p(one_chip, name, calls
             assert f'/{kernel}/{kernel}/pallas_call"' in text, kernel
     assert not re.search(r'"scoped_memory_configs":\[\{', text)     # no raised limit
     assert not re.findall(r" (sort|gather)\(", text)     # instructions, whatever their result type
+
+
+_FLOW_KW = dict(levels=3, win_size=15, n_iters=3, flow_scale=2, warp_impl="pallas", max_disp=4,
+                win_type="gaussian", inner_warp="pallas")        # as chipbench/configs/flow_720p.json
+
+
+@pytest.mark.parametrize("hw,c,max_disp", [((720, 1280), 3, 4), ((360, 640), 5, 2), ((180, 320), 5, 2),
+                                           ((90, 160), 5, 2)], ids=["final", "level0", "level1", "level2"])
+def test_warp_kernel_compiles_through_mosaic_at_the_cells_shapes(one_chip, hw, c, max_disp):
+    """``warp_bounded`` in strips (PR 51) at the four shapes flow_720p's
+    step calls it with, for the described v5e: under its own name, its
+    result the padded planes ``warp_plan`` states, and under Mosaic's
+    DEFAULT scoped VMEM: the whole-tile form held 19.3 MB at the final
+    warp's 16-row tile, 18.1 of it spill slots, and ran under a raised
+    limit of 64 MiB; the strips spill nothing and the ten column-shifted
+    copies of a 48-row tile's slab are 9.8 MB (scripts/stencil_kernel_probe.py
+    --kernel warp_bounded reads the schedule)."""
+    from dvf_tpu.ops.pallas_kernels import warp_bounded_pallas, warp_plan
+
+    img = jax.ShapeDtypeStruct((2, *hw, c), jnp.float32, sharding=one_chip)
+    flow = jax.ShapeDtypeStruct((2, *hw, 2), jnp.float32, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(lambda i, f: warp_bounded_pallas(i, f, max_disp=max_disp, interpret=False)).lower(
+            img, flow).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    plan = warp_plan(img.shape, max_disp)
+    (call,) = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    made = re.match(r"\s*%warp_bounded(?:\.\d+)? = f32\[([\d,]+)\]", call)
+    assert made and [int(v) for v in made.group(1).split(",")] == [2, c, plan["h_pad"], plan["w_out"]], call[:120]
+    assert plan["vmem_limit_bytes"] is None and not re.search(r'"scoped_memory_configs":\[\{', call)
+    assert not re.findall(r" gather\(", text)
+
+
+def test_flow_step_holds_ten_warp_kernels_and_no_gather(one_chip, monkeypatch):
+    """The flow step of flow_720p as the Engine builds it (uint8 in, uint8
+    out, one session's pairs) at 2 x 720 x 1280 for the described v5e: the
+    final warp and the nine inner warps are ten ``warp_bounded`` calls
+    under their two scopes, and no XLA gather is left beside them (the
+    gathers of the 5-plane stacks were 3.5 s of a 3.78 s step, PR 27).
+    ``_auto_interpret`` is steered here: it asks ``jax.default_backend()``,
+    which is the CPU's in this process."""
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.ops import pallas_kernels as pk
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    monkeypatch.setattr(pk, "_auto_interpret", lambda interpret: False)
+    filt = get_filter("flow_warp", **_FLOW_KW)
+    shape = (2, 720, 1280, 3)
+
+    def step(batch, state):
+        y, new_state = filt.fn(to_float(batch, filt.compute_dtype), state)
+        return to_uint8(y), new_state
+
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                         jax.eval_shape(lambda: filt.init_state(shape, jnp.float32)))
+    batch = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(batch, state).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    calls = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 10 and all(re.match(r"\s*%warp_bounded(\.\d+)? = ", ln) for ln in calls)
+    scopes = collections.Counter(
+        next(s for s in ("flow_final_warp", "flow_inner_warp") if f"/{s}/" in ln) for ln in calls)
+    assert scopes == {"flow_final_warp": 1, "flow_inner_warp": 9}
+    assert not re.findall(r" gather\(", text)
+    assert filt.kernel_plan(shape)["calls"][-1]["grid"] == [2, 15]
