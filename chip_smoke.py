@@ -46,7 +46,7 @@ import traceback
 # Riskiest first, so a short chip budget is spent where trouble is likeliest.
 SERVE_ORDER = ("invert_1080p", "clahe_1080p", "sobel_bilateral_1080p",
                "style_720p", "flow_720p", "invert_640x480", "gauss3_1080p",
-               "gauss9_1080p", "sr2x_540p")
+               "gauss9_1080p", "sr2x_540p", "fastdvd_540p")
 
 BATCHES_PER_CONFIG = 3   # frames submitted = this many device batches
 N_UNIQUE = 8             # distinct seeded frames per config (cycled)
@@ -248,6 +248,39 @@ class Smoke:
                 for j, out in zip(mine, outs):
                     want[j] = out
             return want, within(TOL_STEP)
+        if fname == "video_denoise":
+            # The served form keeps two stage-1 results a session and runs
+            # two DenBlocks a frame; reference = the published window at
+            # once, four blocks, uncached, float32 at precision "highest"
+            # on the same device and the same seeded weights, each served
+            # session's frames (k, k+n, ...) alone: delivery i is the
+            # window of frames i-4 .. i (before the start: frame 0), whose
+            # centre is frame max(i - 2, 0). Held to the benchmark's own
+            # limits (chipbench/configs/fastdvd_540p.json).
+            import json
+
+            import jax.numpy as jnp
+
+            from dvf_tpu.models import fastdvdnet as net
+
+            params = net.init_fastdvdnet(jax.random.PRNGKey(0))
+            config = net.FastDvdConfig(compute_dtype=jnp.float32)
+            uncached = jax.jit(lambda win: jnp.round(jnp.clip(
+                net.apply_fastdvdnet(params, win.astype(jnp.float32) / 255.0, config),
+                0.0, 1.0) * 255.0).astype(jnp.uint8))
+            n = BATCHES_PER_CONFIG * batch
+            frames = self.frames(name, n)
+            want = [None] * n
+            with jax.default_matmul_precision("highest"):
+                for k in range(SERVE_SESSIONS):
+                    mine = list(range(k, n, SERVE_SESSIONS))
+                    for i, j in enumerate(mine):
+                        win = np.stack([frames[mine[max(i - 4 + t, 0)]] for t in range(5)])
+                        want[j] = np.asarray(uncached(win[None]))[0]
+            here = os.path.dirname(os.path.abspath(__file__))
+            with open(os.path.join(here, "chipbench", "configs", "fastdvd_540p.json")) as f:
+                limits = json.load(f)["limits"]
+            return want, within(limits["max_abs_steps"], limits["mean_abs_steps"])
         # style_transfer / super_resolution: the same weights (same seed),
         # float32 compute, matmul precision "highest".
         ref = get_filter(fname, dtype="float32", **kwargs)
@@ -404,7 +437,7 @@ def _bucket_modes(row: dict) -> dict:
 def _has_mosaic_call(engine) -> bool:
     """Does the engine's served program contain a Mosaic kernel? (A Pallas
     kernel that quietly gave way to jnp, or to interpret mode, does not.)"""
-    text = engine._step.lower(*engine.step_operands()).as_text()
+    text = engine.compiled_step().as_text()
     return "tpu_custom_call" in text
 
 

@@ -20,6 +20,9 @@ Reference counterpart: the abstract ``Worker.__call__(frame_bytes) -> bytes``
   over a batch whose rows belong to many streams, each row naming its own
   predecessor. The Engine holds one state per session in a device table
   and runs ``rows``, so which sessions share a batch is data, never shape.
+  A temporal filter that is also a net keeps its weights in the same
+  state dict under keys it names in ``shared_state``: stored once, handed
+  to ``rows`` as they are.
 """
 
 from __future__ import annotations
@@ -121,6 +124,24 @@ class Filter:
     # (``Engine.kernel_plan``) and the serve path's bucket row passes it on
     # as its ``kernel`` block. None: no such kernel (XLA's own ops only).
     kernel_plan: Optional[Callable[[Tuple[int, ...]], dict]] = None
+    # Temporal filters whose state also holds what is no session's: the
+    # top-level keys of the state dict that are read-only and stored once
+    # (a temporal net's weights beside its sessions' planes). ``rows``
+    # gets and returns them as they are (:func:`session_leaves`).
+    shared_state: Tuple[str, ...] = ()
+    # window: what a temporal filter's state holds of its session, as
+    # data, for a filter whose window is deeper than the two frames of
+    # the flow family: ``depth`` (predecessors a full window reads; a row
+    # served with fewer is warm-up), ``lag_frames`` (the result for a
+    # session's frame n answers its frame n - lag_frames: frames of
+    # lookahead) and ``leaves`` ({kind: planes a session}). None: depth
+    # 1, no lag. The serve path's bucket row passes it on in its
+    # ``state`` block and counts ``warm_rows_total`` by ``depth``.
+    window: Optional[dict] = None
+    # model: a learned filter's network as data (``name``, ``params``,
+    # and what its served form costs a frame); the bucket row's
+    # ``model`` block. None: the row has none.
+    model: Optional[dict] = None
 
     @property
     def stateful(self) -> bool:
@@ -131,6 +152,14 @@ class Filter:
         """State that one batch writes and the next reads: one session's
         (see ``rows``), never threaded across sessions."""
         return self.stateful and not self.constant_state
+
+    @property
+    def window_depth(self) -> int:
+        return int((self.window or {}).get("depth", 1))
+
+    @property
+    def lag_frames(self) -> int:
+        return int((self.window or {}).get("lag_frames", 0))
 
     @property
     def session_state(self) -> bool:
@@ -159,11 +188,15 @@ def temporal_filter(name: str, rows: Callable, init_state: Callable,
     import jax
 
     def fn(batch: jnp.ndarray, state: Any) -> Tuple[jnp.ndarray, Any]:
+        mine = session_leaves(filt, state)
         out, row_states = rows(
-            batch, jax.tree.map(lambda a: a[None], state), None)
-        return out, jax.tree.map(lambda a: a[-1], row_states)
+            batch, jax.tree.map(lambda a, m: a[None] if m else a, state, mine),
+            None)
+        return out, jax.tree.map(lambda a, m: a[-1] if m else a,
+                                 row_states, mine)
 
-    return Filter(name=name, fn=fn, rows=rows, init_state=init_state, **kw)
+    filt = Filter(name=name, fn=fn, rows=rows, init_state=init_state, **kw)
+    return filt
 
 
 def take_pred(seq: jnp.ndarray, pred: Any) -> jnp.ndarray:
@@ -176,13 +209,18 @@ def take_pred(seq: jnp.ndarray, pred: Any) -> jnp.ndarray:
 def session_leaves(filt: Filter, state: Any) -> Any:
     """Which leaves of ``filt``'s state tree are per-session (a pytree of
     bools shaped like ``state``): all of a temporal filter's, none of a
-    constant-state one's, member by member in a chain. The Engine gives
-    the marked leaves a row per session; the others are stored once."""
+    constant-state one's, member by member in a chain; none under a
+    temporal filter's ``shared_state`` keys. The Engine gives the marked
+    leaves a row per session; the others are stored once."""
     import jax
 
     if filt.members is not None:
         return tuple(session_leaves(f, s)
                      for f, s in zip(filt.members, state))
+    if filt.shared_state:
+        return {k: jax.tree.map(
+            lambda _: filt.temporal and k not in filt.shared_state, v)
+            for k, v in state.items()}
     return jax.tree.map(lambda _: filt.temporal, state)
 
 

@@ -47,6 +47,11 @@ BENCH_CONFIGS = {
     # "clahe"), the program chipbench/configs/clahe_1080p.json pins by
     # its factory's name (clahe_pallas).
     "clahe_1080p": dict(filter=("clahe", {}), h=1080, w=1920, batch=16),
+    # FastDVDnet (Tassano et al., CVPR 2020) at Set8's 960 x 540, streamed:
+    # the delivery for frame n is the denoised frame n - 2. Not pad-safe:
+    # the pipeline (--e2e) refuses it; the serve path is its home
+    # (chipbench/configs/fastdvd_540p.json: batch 32, 16 sessions).
+    "fastdvd_540p": dict(filter=("video_denoise", {}), h=540, w=960, batch=8),
 }
 
 

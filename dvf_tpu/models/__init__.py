@@ -5,7 +5,9 @@ The reference has no neural models — its one op is ``cv2.bitwise_not``
 transformer net (the flagship filter, BASELINE.json configs[4], with a
 small VGG encoder providing perceptual features for training) and an
 ESPCN sub-pixel super-resolution net (enhancement family; all FLOPs at
-low resolution — built for the MXU).
+low resolution — built for the MXU). A third, FastDVDnet video denoising
+(:mod:`dvf_tpu.models.fastdvdnet`: two stages of a three-scale U-Net over a
+five-frame window), is served streamed by ``ops/denoise.py``.
 
 Models are plain functional JAX: ``init(rng, ...) -> params`` pytrees and
 ``apply(params, batch) -> batch`` functions, with explicit
@@ -23,5 +25,10 @@ from dvf_tpu.models.espcn import (  # noqa: F401
     EspcnConfig,
     apply_espcn,
     init_espcn,
+)
+from dvf_tpu.models.fastdvdnet import (  # noqa: F401
+    FastDvdConfig,
+    apply_fastdvdnet,
+    init_fastdvdnet,
 )
 from dvf_tpu.models.vgg import VGGConfig, init_vgg, vgg_features  # noqa: F401

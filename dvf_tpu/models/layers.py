@@ -474,48 +474,52 @@ def instance_norm_phase(p: Params, x: jnp.ndarray, pivot=None,
 # fill its 128 lanes and no more, and no tensor goes back to plain.
 
 
-def zero_phase_taps(k: int, fi: int, fo: int):
+def zero_phase_taps(k: int, fi: int, fo: int, stride: int = 1):
     """One axis of :func:`zero_phase_kernel`: ``(idy, lo, hi)`` with
     ``idy[e, α, β]`` the tap ``dy`` of the k-tap kernel that output phase β
-    reads from input phase α of low-res position ``(fo // fi)·J + e − lo``
-    (output row ``fo·J + β`` reads input row ``fo·J + β + dy − r``), ``k``
-    where there is none: the zero tap. :func:`phase_kernel`'s table at
-    stride 1, with ``fo`` free of ``fi``."""
+    reads from input phase α of low-res position ``(stride·fo // fi)·J + e −
+    lo`` (output row ``fo·J + β`` reads input row ``stride·(fo·J + β) + dy −
+    r``), ``k`` where there is none: the zero tap. :func:`phase_kernel`'s
+    table with ``fo`` free of ``fi``: the conv strides ``stride·fo // fi``
+    over its low-res input."""
     r = k // 2
     lo = -((-r) // fi)
-    hi = (fo - 1 + k - 1 - r) // fi
+    hi = (stride * (fo - 1) + k - 1 - r) // fi
     idy = np.full((lo + hi + 1, fi, fo), k, dtype=np.int32)
     for e in range(lo + hi + 1):
         for a in range(fi):
             for b in range(fo):
-                dy = fi * (e - lo) + a - b + r
+                dy = fi * (e - lo) + a - stride * b + r
                 if 0 <= dy < k:
                     idy[e, a, b] = dy
     return idy, lo, hi
 
 
-def zero_phase_kernel(w: jnp.ndarray, fi=1, fo=2):
-    """Re-index a (k, k, Cin, Cout) kernel of a zero-SAME stride-1 conv
-    into the kernel of the same conv from ``space_to_depth(x, fi)`` to
-    ``space_to_depth(y, fo)``; ``fi``, ``fo``: a factor or an (H, W) pair,
-    ``fo`` a multiple of ``fi`` on each axis. Returns ``(kernel, pads,
-    strides)``: the (klh, klw, fih·fiw·Cin, foh·fow·Cout) kernel of a VALID
-    conv with window strides ``fo // fi``, and the ``(lo, hi)`` low-res
-    rows / columns of zeros its input needs. At ``fi = 1`` output phase
-    (β, β′) holds ``w`` at rows β…β+k−1, columns β′…β′+k−1 of a (k+f−1)²
-    kernel. One static gather, as :func:`_s2d_kernel`."""
+def zero_phase_kernel(w: jnp.ndarray, fi=1, fo=2, stride: int = 1):
+    """Re-index a (k, k, Cin, Cout) kernel of a zero-padded (k // 2) conv
+    of the given stride into the kernel of the same conv from
+    ``space_to_depth(x, fi)`` to ``space_to_depth(y, fo)``; ``fi``, ``fo``:
+    a factor or an (H, W) pair, ``stride·fo`` a multiple of ``fi`` on each
+    axis (at stride 1: a conv emits a multiple of the phases it reads).
+    Returns ``(kernel, pads, strides)``: the (klh, klw, fih·fiw·Cin,
+    foh·fow·Cout) kernel of a VALID conv with window strides ``stride·fo //
+    fi``, and the ``(lo, hi)`` low-res rows / columns of zeros its input
+    needs. At ``fi = 1`` and stride 1 output phase (β, β′) holds ``w`` at
+    rows β…β+k−1, columns β′…β′+k−1 of a (k+f−1)² kernel. One static
+    gather, as :func:`_s2d_kernel`."""
     fi, fo = _pair(fi), _pair(fo)
-    if fo[0] % fi[0] or fo[1] % fi[1]:
-        raise ValueError(f"phase factors {fi} -> {fo}: a conv emits a multiple of what it reads")
+    if (stride * fo[0]) % fi[0] or (stride * fo[1]) % fi[1]:
+        raise ValueError(f"phase factors {fi} -> {fo} at stride {stride}: a conv emits a "
+                         f"multiple of what it reads")
     k = w.shape[0]
-    (ih, loh, hih), (iw, low, hiw) = (zero_phase_taps(k, a, b) for a, b in zip(fi, fo))
+    (ih, loh, hih), (iw, low, hiw) = (zero_phase_taps(k, a, b, stride) for a, b in zip(fi, fo))
     wpad = jnp.pad(w, ((0, 1), (0, 1), (0, 0), (0, 0)))
     g = wpad[ih[:, :, :, None, None, None], iw[None, None, None, :, :, :]]
     # g[e, α, β, e', α', β', ci, co] → (e, e', α, α', ci, β, β', co)
     g = g.transpose(0, 3, 1, 4, 6, 2, 5, 7)
     cin, cout = w.shape[2], w.shape[3]
     kern = g.reshape(ih.shape[0], iw.shape[0], fi[0] * fi[1] * cin, fo[0] * fo[1] * cout)
-    sh, sw = fo[0] // fi[0], fo[1] // fi[1]
+    sh, sw = stride * fo[0] // fi[0], stride * fo[1] // fi[1]
     # The last window starts a stride short of the end: it stops that far short of ``hi``.
     return kern, ((loh, hih - (sh - 1)), (low, hiw - (sw - 1))), (sh, sw)
 
@@ -527,20 +531,26 @@ def conv2d_zero_phase(
     fo=2,
     cols=None,
     compute_dtype=jnp.bfloat16,
+    stride: int = 1,
+    out_dtype=None,
 ) -> jnp.ndarray:
-    """Zero-SAME k×k stride-1 conv (without bias) of the full-resolution
+    """Zero-padded (k // 2) k×k conv (without bias) of the full-resolution
     tensor whose ``space_to_depth(·, fi)`` image is ``x`` (``fi = 1``: the
     tensor itself), as ONE conv that emits the result's phases: returns
-    ``space_to_depth(conv2d_nb(p, X), fo)``. ``cols``: a static permutation
-    of the emitted columns (:func:`subpixel_order`), applied to the kernel.
-    On a v5e the stride costs nothing (PERF.md §6, PRs 28 and 41)."""
-    kern, (pad_h, pad_w), strides = zero_phase_kernel(p["w"], fi, fo)
+    ``space_to_depth(Y, fo)`` of the conv's result ``Y`` (at ``stride`` 1
+    ``conv2d_nb(p, X)``; at 2 PyTorch's ``Conv2d(stride=2, padding=k // 2)``
+    of an even H×W). ``cols``: a static permutation of the emitted columns
+    (:func:`subpixel_order`), applied to the kernel. ``out_dtype``: the
+    result's (``preferred_element_type``), None for ``compute_dtype``. On a
+    v5e the window stride costs nothing (PERF.md §6, PRs 28 and 41)."""
+    kern, (pad_h, pad_w), strides = zero_phase_kernel(p["w"], fi, fo, stride)
     if cols is not None:
         kern = kern[..., cols]
     xp = jnp.pad(x, ((0, 0), pad_h, pad_w, (0, 0)))
     return lax.conv_general_dilated(
         xp.astype(compute_dtype), kern.astype(compute_dtype),
         window_strides=strides, padding="VALID", dimension_numbers=_DN,
+        preferred_element_type=out_dtype,
     )
 
 

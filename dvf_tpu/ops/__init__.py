@@ -18,3 +18,4 @@ from dvf_tpu.ops import style  # noqa: F401,E402
 from dvf_tpu.ops import sr  # noqa: F401,E402
 from dvf_tpu.ops import histogram  # noqa: F401,E402
 from dvf_tpu.ops import pallas_kernels  # noqa: F401,E402
+from dvf_tpu.ops import denoise  # noqa: F401,E402

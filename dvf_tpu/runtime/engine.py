@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import os
+import re
 import sys
 import threading
 import time
@@ -216,6 +217,9 @@ class Engine:
         #   calibrations — the whole admission-visible cost): what the
         #   reconfiguration ledger's compile events and the
         #   dvf_compile_ms histogram record
+        self.step_conv_ops: Optional[List[str]] = None  # the compiled
+        #   step's convolutions by instruction name (conv_op_names), for
+        #   a filter that states its network; None otherwise
         self.state_bytes: int = 0  # measured device residency of the
         #   filter state (summed leaf nbytes at compile) — the per-
         #   engine half of the memory accounting; free() folds it into
@@ -402,18 +406,20 @@ class Engine:
 
             init = filt.init_state(batch_shape, state_dtype)
             tabled = session_leaves(filt, init)
-            prev = jax.tree.map(gathered, table, init, tabled)
+            with jax.named_scope("state_table"):
+                prev = jax.tree.map(gathered, table, init, tabled)
             y, row_states = filt.rows(x, prev, pred)
             # Unused entries point past the table, each at an index of
             # its own (the scatter is told they are unique): dropped.
-            dst = jnp.where(trow >= 0, trow,
-                            n_rows + jnp.arange(t_n, dtype=trow.dtype))
-            new_table = jax.tree.map(
-                lambda leaf, rs, per_session: leaf.at[dst].set(
-                    jnp.take(rs, tlast, axis=0).astype(leaf.dtype),
-                    mode="drop", unique_indices=True)
-                if per_session else leaf,
-                table, row_states, tabled)
+            with jax.named_scope("state_table"):
+                dst = jnp.where(trow >= 0, trow,
+                                n_rows + jnp.arange(t_n, dtype=trow.dtype))
+                new_table = jax.tree.map(
+                    lambda leaf, rs, per_session: leaf.at[dst].set(
+                        jnp.take(rs, tlast, axis=0).astype(leaf.dtype),
+                        mode="drop", unique_indices=True)
+                    if per_session else leaf,
+                    table, row_states, tabled)
             return y, new_table
 
         return body
@@ -427,6 +433,15 @@ class Engine:
         else:
             y, self._state = self._step(batch, self._state)
         return y
+
+    def compiled_step(self):
+        """The step's executable (its text, cost and memory analyses):
+        the one the engine calls where ``compile`` built it ahead of
+        time, else one more lowering beside the jit's own, which the
+        persistent cache answers for any program worth the wait."""
+        if isinstance(self._step, jax.stages.Compiled):
+            return self._step
+        return self._step.lower(*self.step_operands()).compile()
 
     def step_operands(self) -> Tuple:
         """Abstract operands of the compiled step, for ``lower()``."""
@@ -449,6 +464,19 @@ class Engine:
                     jnp.asarray(a)[None], (n,) + jnp.shape(a))
                 if per_session else a, state, session_leaves(ef, state))
         return jax.device_put(state, self._state_shardings())
+
+    def state_row_bytes(self) -> int:
+        """Bytes one session's row of the state table holds (its
+        per-session leaves, ``session_leaves``); 0 without a table. What
+        a step reads and writes back for each session in its batch."""
+        if not self._tabled or self._state is None:
+            return 0
+        mine = session_leaves(self._exec_filter, self._state)
+        return sum(
+            int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
+            for leaf, per_session in zip(jax.tree.leaves(self._state),
+                                         jax.tree.leaves(mine))
+            if per_session)
 
     def _state_shardings(self):
         """Sharding (tree or single) for the state pytree; also valid as a
@@ -500,6 +528,15 @@ class Engine:
         self._step = self._build_step(batch_shape, dtype)
         self._signature = sig
         self.stats.compile_count += 1
+        # A filter that states its network (Filter.model) has its step
+        # compiled ahead of time: the executable the engine calls is the
+        # one whose text names its convolutions for a trace's reader
+        # (the bucket row's ``model.conv_ops``), and nothing is lowered
+        # or compiled twice.
+        self.step_conv_ops = None
+        if self._exec_filter.model is not None:
+            self._step = self.compiled_step()
+            self.step_conv_ops = conv_op_names(self._step.as_text())
         # Warm the compile cache so the first real batch doesn't eat compile
         # time; the warmup consumes (donates) the state, so rebuild it —
         # stateful filters must still see a pristine first batch. A second
@@ -727,16 +764,11 @@ class Engine:
         (e.g. the cast folded into the filter) is accounted for. Returns
         None when the backend doesn't implement cost analysis.
 
-        Cost note: lower().compile() builds a second executable beside the
-        jit-cached one, but every bench entry point arms the persistent
-        compilation cache (enable_compilation_cache), so for any program
-        whose compile exceeded ~1 s this is a persistent-cache hit
-        (deserialize, not recompile)."""
+        Cost note: see :meth:`compiled_step`."""
         if self._step is None or self._signature is None:
             return None
         try:
-            lowered = self._step.lower(*self.step_operands())
-            ca = lowered.compile().cost_analysis()
+            ca = self.compiled_step().cost_analysis()
             flops = float(ca.get("flops", 0.0))
             byts = float(ca.get("bytes accessed", 0.0))
         except Exception:  # noqa: BLE001 — cost analysis is best-effort
@@ -893,7 +925,7 @@ class Engine:
                          "step_donates_input", "kernel_plan",
                          "_out_sharding", "h2d_block_ms", "d2h_block_ms",
                          "step_block_ms", "last_compile_ms",
-                         "state_bytes"):
+                         "step_conv_ops", "state_bytes"):
                 setattr(self, name, getattr(succ, name))
             self.stats.compile_count += succ.stats.compile_count
             # Neuter the successor shell: its device buffers now belong
@@ -1001,6 +1033,40 @@ def freed_device_bytes_total() -> int:
     the ``dvf_mem_engine_freed_bytes_total`` counter's source."""
     with _POOL_ENGINES_LOCK:
         return _FREED_DEVICE_BYTES
+
+
+def conv_op_names(hlo_text: str) -> List[str]:
+    """The instructions of a compiled module's entry computation that hold
+    a convolution (their own, or in a computation they call, a fusion
+    inside a fusion included), by name as a device trace lists them
+    (``fusion.47``): XLA names most such fusions ``fusion.N``, so a
+    reader cannot tell them by name."""
+    bodies: Dict[str, List[str]] = {}
+    body: Optional[List[str]] = None
+    entry: List[str] = []
+    for line in hlo_text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            body = entry if head.group(1) else bodies.setdefault(
+                head.group(2), [])
+        elif body is not None:
+            body.append(line)
+
+    def holds(lines) -> bool:
+        for ln in lines:
+            if " convolution(" in ln:
+                return True
+            called = re.search(r"calls=%([\w.\-]+)", ln)   # a fusion in a fusion
+            if called and holds(bodies.get(called.group(1), [])):
+                return True
+        return False
+
+    names = []
+    for line in entry:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if m and holds([line]):
+            names.append(m.group(1))
+    return names
 
 
 def _tree_device_bytes(state) -> int:

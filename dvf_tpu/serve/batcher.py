@@ -334,10 +334,19 @@ class ContinuousBatcher:
         return rows
 
     @staticmethod
-    def mark_reached_device(slots: Sequence[Slot]) -> None:
-        """The batch was submitted: its sessions' rows now hold state."""
+    def mark_reached_device(slots: Sequence[Slot], depth: int = 1) -> int:
+        """The batch was submitted: its sessions' rows now hold state.
+        Returns how many of the rows were warm-up: served with fewer
+        than ``depth`` predecessors since their session's row restarted
+        (``StreamSession.state_depth``, capped at ``depth``)."""
+        warm = 0
         for slot in slots:
-            slot.session.state_fresh = False
+            s = slot.session
+            if s.state_fresh:
+                s.state_fresh, s.state_depth = False, 0
+            warm += s.state_depth < depth
+            s.state_depth = min(s.state_depth + 1, depth)
+        return warm
 
     def _pool_staging(self, frame: np.ndarray) -> np.ndarray:
         shape = (self.batch_size, *frame.shape)

@@ -414,7 +414,7 @@ class _Bucket:
         self.state_rows = (engine.state_rows if filt.session_state else 0)
         self._state_free = list(range(self.state_rows - 1, -1, -1))
         self.state_counts = {"table_rows_total": 0, "chain_rows_total": 0,
-                             "fresh_rows_total": 0}
+                             "fresh_rows_total": 0, "warm_rows_total": 0}
         self.state_resets = {"admission": 0, "rebuild": 0, "migrate": 0}
         # What the batcher's "a short batch waits for the device" rule
         # did here (the dispatch thread's): batches by fill, those whose
@@ -443,6 +443,7 @@ class _Bucket:
         """Give ``s`` a row of this bucket's session-state table, marked
         fresh: its next frame to reach the device restarts the row."""
         s.state_row, s.state_fresh = None, False
+        s.output_lag_frames = self.filter.lag_frames
         if not self.state_rows:
             return
         if not self._state_free:
@@ -470,13 +471,17 @@ class _Bucket:
     def note_state_rows(self, plan: BatchPlan) -> None:
         """Dispatch thread, after the submit: the plan's frames reached
         the device. A session's first row in the batch took its
-        predecessor from the table, its later rows from the batch."""
-        ContinuousBatcher.mark_reached_device(plan.slots)
+        predecessor from the table, its later rows from the batch. A
+        row served with fewer predecessors than the filter's window
+        reads (``Filter.window_depth``) is warm-up."""
+        warm = ContinuousBatcher.mark_reached_device(
+            plan.slots, self.filter.window_depth)
         sessions = len(set(plan.rows[0, :plan.valid].tolist()))
         c = self.state_counts
         c["table_rows_total"] += sessions
         c["chain_rows_total"] += plan.valid - sessions
         c["fresh_rows_total"] += int(plan.rows[1].sum())
+        c["warm_rows_total"] += warm
 
     # -- scheduling ------------------------------------------------------
 
@@ -635,11 +640,24 @@ class _Bucket:
          row["xla_compile_s_total"]) = ledger_mod.XLA_COMPILES.totals()
         row.update(self.lane.stats())
         if self.state_rows:
+            window = self.filter.window or {}
             row["state"] = dict(
                 self.state_counts, rows=self.state_rows,
                 bound=self.state_rows - len(self._state_free),
                 bytes=getattr(self.engine, "state_bytes", 0),
-                resets_total=dict(self.state_resets))
+                row_bytes=self.engine.state_row_bytes(),
+                resets_total=dict(self.state_resets),
+                # The window the table holds of each session
+                # (Filter.window): predecessors a full one reads, frames
+                # of lookahead, planes a session by kind.
+                depth=self.filter.window_depth,
+                lag_frames=self.filter.lag_frames,
+                leaves=window.get("leaves"),
+                dtypes=window.get("dtypes"))
+        if self.filter.model is not None:
+            row["model"] = dict(
+                self.filter.model,
+                conv_ops=getattr(self.engine, "step_conv_ops", None))
         return row
 
 
@@ -3560,6 +3578,7 @@ class ServeFrontend:
                                     st.t_submit, TRACK_DISPATCH, seq=seq,
                                     sessions=n_sess, out_bytes=out_bytes,
                                     kernel=plan_k and plan_k["kernel"],
+                                    lag=bucket.filter.lag_frames,
                                     direct=builder.direct,
                                     stage_ms=stage_ms, put_ms=put_ms,
                                     wait_ms=wait_ms, join_ms=join_ms,

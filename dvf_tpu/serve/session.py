@@ -161,6 +161,11 @@ class StreamSession:
         #   initial state at this session's NEXT frame to reach the
         #   device (set at bind, cleared by the dispatch thread once a
         #   batch carrying the mark was submitted)
+        self.state_depth = 0  # frames of this session that reached the
+        #   device since its row last restarted, capped at the filter's
+        #   window depth (the dispatch thread's: mark_reached_device)
+        self.output_lag_frames = 0  # the delivery for frame n answers
+        #   frame n - this (Filter.window["lag_frames"]; set at bind)
         # -- load-adaptive quality state (dvf_tpu.control) --------------
         self.quality_level = 0   # 0 = full quality; level L frames are
         #   decimated ×2^L per axis at submit and served by a bucket
@@ -508,6 +513,7 @@ class StreamSession:
                 "tier": self.config.tier,
                 "quality_level": self.quality_level,
                 "quality_shifts": self.quality_shifts,
+                "output_lag_frames": self.output_lag_frames,
                 **self.latency.summary(),
             }
 

@@ -15,7 +15,9 @@ the first frames of either are compared with ``chipbench/refs/clahe_1080p.py``);
 warp of ``chipbench/configs/flow_720p.json`` (``flow_warp`` with the bounded kernel for its final warp and its nine
 inner warps, one session's pairs at 720 x 1280; the scopes of ``ops/flow.py``: ``flow_final_warp``, ``flow_inner_warp``,
 the rest by op, and the ``warp_bounded`` calls summed by name, which a tree from before the scopes (``--tree``) has
-too). Compiles the step program
+too); ``--model fastdvd`` the streamed FastDVDnet of ``chipbench/configs/fastdvd_540p.json`` (``video_denoise`` at
+540 x 960, one session's consecutive frames; the scopes of ``ops/denoise.py``: ``denoise_window`` (the four lag gathers),
+``denoise_stage1``, ``denoise_stage2``: one DenBlock each, two a frame). Compiles the step program
 of ``style_transfer(base_channels=32, n_residual=5)`` as the Engine builds it (uint8 batch in, uint8 batch out, the
 weights as state) at the cell's shape, times it, traces a few steps, and prints every device op's milliseconds a step beside the ``jax.named_scope`` of ``_forward`` it was compiled
 from (``stem``, ``down1``, ``down2``, ``trunk``, ``up1``, ``up2``, ``out``; the compiled HLO's ``op_name``) and its
@@ -33,6 +35,8 @@ relu and the residual add). Run on the chip:
     chiprun -- python scripts/style_step_probe.py --model clahe --batch 64     # chiprun_out/clahe_step_probe.json
 
     chiprun -- python scripts/style_step_probe.py --model flow --batch 64      # chiprun_out/flow_step_probe.json
+
+    chiprun -- python scripts/style_step_probe.py --model fastdvd --batch 32   # chiprun_out/fastdvd_step_probe.json
 
 ``--toy`` runs a tiny shape on whatever backend jax has (the CPU here): it checks the script, and its times mean
 nothing.
@@ -106,13 +110,24 @@ def _flow_stages(kwargs, shape):
     return {"flow_final_warp": form("final"), "flow_inner_warp": form("inner")}
 
 
+def _fastdvd_stages(kwargs, shape):
+    from dvf_tpu.models.fastdvdnet import FULL, HALF, PLAIN
+
+    block = "one DenBlock a row: 540p as phases %s, 270p as %s, 135p as %s" % (FULL, HALF, PLAIN)
+    return {"state_table": "Engine._table_body: four planes a session gathered, and scattered back",
+            "denoise_window": "four lags of a row's predecessor chain, planes float32",
+            "denoise_stage1": block, "denoise_stage2": block}
+
+
 # model -> (filter, the cell's (H, W), its kwargs, toy kwargs, {stage scope: form} of (kwargs, shape))
 _CLAHE = {"clip_limit": 2.0, "grid": 8, "on_gray": False, "impl": "pallas"}     # as chipbench/configs/clahe_1080p.json
 _STENCIL = {"d": 9, "sigma_color": 0.1, "sigma_space": 2.0, "magnitude_scale": 1.0, "impl": "pallas"}   # as the cell's file
 _ESPCN = {"scale": 2, "fast_convs": False, "dtype": "bfloat16"}          # as chipbench/configs/sr2x_540p.json
 _FLOW = {"levels": 3, "win_size": 15, "n_iters": 3, "flow_scale": 2, "warp_impl": "pallas", "max_disp": 4,
          "win_type": "gaussian", "inner_warp": "pallas"}                 # as chipbench/configs/flow_720p.json
+_FASTDVD = {"sigma": 25.0 / 255.0, "dtype": "bfloat16"}   # as chipbench/configs/fastdvd_540p.json
 MODELS = {
+    "fastdvd": ("video_denoise", (540, 960), _FASTDVD, _FASTDVD, _fastdvd_stages),
     "flow": ("flow_warp", (720, 1280), _FLOW, _FLOW, _flow_stages),
     "style": ("style_transfer", (720, 1280), {"base_channels": 32, "n_residual": 5},
               {"base_channels": 8, "n_residual": 2}, _style_stages),
@@ -167,6 +182,10 @@ def main() -> int:
     ap.add_argument("--impl", choices=("pallas", "chain", "sort"), default=None,
                     help="stencil: the fused kernel or the jnp chain; clahe: the counted kernels or the sort + gather form")
     ap.add_argument("--out", default=None, help="default chiprun_out/<model>_step_probe.json")
+    ap.add_argument("--sessions", type=int, default=None,
+                    help="a filter with per-session state: probe the step the service runs, the Engine's table body "
+                         "over this many session rows, the batch's rows dealt to them in turn (default: one session's "
+                         "consecutive frames through Filter.fn)")
     ap.add_argument("--tree", default=None, help="another checkout whose dvf_tpu is probed with this script")
     args = ap.parse_args()
     if args.tree:
@@ -203,25 +222,36 @@ def main() -> int:
     state = filt.init_state(shape, jnp.float32) if filt.init_state is not None else None
     batch = jnp.asarray(np.random.default_rng(0).integers(0, 256, shape, dtype=np.uint8))
     t = time.perf_counter()
-    compiled = jax.jit(step).lower(batch, state).compile()
+    if args.sessions:                   # the served step: Engine._table_body, its table and the batch's row map
+        from dvf_tpu.runtime.engine import Engine
+
+        engine = Engine(filt, state_rows=args.sessions)
+        engine.compile(shape, np.uint8)
+        rows = np.stack([np.arange(shape[0]) % args.sessions, np.zeros(shape[0])]).astype(np.int32)
+        host_batch = np.asarray(batch)
+        compiled = engine.compiled_step()
+        run = lambda: engine.submit(host_batch, rows)               # the same program
+    else:
+        compiled = jax.jit(step).lower(batch, state).compile()
+        run = lambda: compiled(batch, state)
     compile_s = time.perf_counter() - t
     forms = stages_of(kwargs, shape)
     table = op_table(compiled.as_text(), forms)
     mem = compiled.memory_analysis()
 
     for _ in range(2):
-        jax.block_until_ready(compiled(batch, state))
+        jax.block_until_ready(run())
     wall = []
     for _ in range(args.steps):
         t = time.perf_counter()
-        jax.block_until_ready(compiled(batch, state))
+        jax.block_until_ready(run())
         wall.append((time.perf_counter() - t) * 1e3)
 
     traced = 3
     with tempfile.TemporaryDirectory() as trace_dir:
         jax.profiler.start_trace(trace_dir)
         for _ in range(traced):
-            jax.block_until_ready(compiled(batch, state))
+            jax.block_until_ready(run())
         jax.profiler.stop_trace()
         planes = reduce.read_planes(reduce.find_xplane(trace_dir)) if dev.platform != "cpu" else {"devices": {}}
 
@@ -240,7 +270,7 @@ def main() -> int:
             stages[stage] = stages.get(stage, 0.0) + ms
 
     report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
-              "model": args.model, "filter": filt.name, "filter_kwargs": kwargs, "shape": list(shape),
+              "model": args.model, "sessions": args.sessions, "filter": filt.name, "filter_kwargs": kwargs, "shape": list(shape),
               "stage_forms": forms, "compile_s": compile_s,
               "temp_gib": mem.temp_size_in_bytes / 2 ** 30,
               "step_wall_ms": {"min": min(wall), "median": sorted(wall)[len(wall) // 2], "max": max(wall)},

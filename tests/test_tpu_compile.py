@@ -353,3 +353,63 @@ def test_flow_step_holds_ten_warp_kernels_and_no_gather(one_chip, monkeypatch):
     assert scopes == {"flow_final_warp": 1, "flow_inner_warp": 9}
     assert not re.findall(r" gather\(", text)
     assert filt.kernel_plan(shape)["calls"][-1]["grid"] == [2, 15]
+
+
+def test_denoise_step_at_the_cells_batch_fits_and_carries_phase_images(one_chip):
+    """The video denoiser's served step (uint8 in, uint8 out, the Engine's
+    table body over 16 session rows, the row map an operand) at the cell's
+    32 x 540 x 960 for the described v5e. It compiles, which the plain NHWC
+    form does not at this batch (20.3 GB of scratch: a
+    ``f32[32,540,960,32]`` is stored four times padded); its scratch leaves
+    the chip room for the table and the batches in flight; every
+    convolution's result is a phase image whose columns fill the lanes
+    (``models/fastdvdnet.py`` FULL / HALF / PLAIN) or the 12 columns of the
+    residual, never a plain (32, 540, 960, c > 12) or (32, 270, 480, 64);
+    and there are 32 convolutions, two DenBlocks of 16: the cached form."""
+    import numpy as np
+
+    from dvf_tpu.ops import get_filter
+    from dvf_tpu.runtime.engine import Engine
+    from dvf_tpu.utils.image import to_float, to_uint8
+
+    filt = get_filter("video_denoise")
+    shape = (32, 540, 960, 3)
+    engine = Engine(filt, state_rows=16)
+    engine._tabled = True                      # what compile() finds for such a filter
+    body = engine._table_body(shape, np.uint8)
+
+    def step(batch, state, row_map):
+        y, new_state = body(to_float(batch, filt.compute_dtype), state, row_map)
+        return to_uint8(y), new_state
+
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = on_chip(jax.eval_shape(lambda: engine._fresh_state(shape, np.uint8)))
+    batch = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+            batch, state, on_chip(engine._row_map_aval(shape[0]))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    assert compiled.memory_analysis().temp_size_in_bytes < 7 * 2 ** 30
+    text = compiled.as_text()
+    from dvf_tpu.runtime.engine import conv_op_names
+
+    entry = text[text.index("\nENTRY"):]
+    convs = []              # result dims of each instruction that holds a convolution
+    for name in conv_op_names(text):
+        m = re.search(r"%" + re.escape(name) + r" = \(?\w+\[([\d,]*)\]", entry)
+        convs.append(m.group(1))
+    # two DenBlocks of 16; an instruction may hold two (XLA fuses four of the
+    # 270p convolutions into their consumers' fusions at this batch)
+    assert sum(" convolution(" in line for line in text.splitlines()) == 32
+    assert 16 <= len(convs) <= 32, (len(convs), collections.Counter(convs))
+    allowed = {"32,270,480,360", "32,270,480,128", "32,270,240,128", "32,135,240,128",
+               "32,135,240,256", "32,270,240,256", "32,270,480,12"}
+    assert set(convs) <= allowed, set(convs) - allowed
+    scopes = collections.Counter(
+        s for line in text.splitlines() if " convolution(" in line
+        for s in ("denoise_stage1", "denoise_stage2") if f"/{s}/" in line)
+    assert scopes == {"denoise_stage1": 16, "denoise_stage2": 16}, scopes
