@@ -24,11 +24,81 @@ jax.config.update("jax_platforms", _platform)
 if _platform == "cpu":
     jax.config.update("jax_num_cpu_devices", 8)
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import tempfile  # noqa: E402
 import threading  # noqa: E402
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+# Every test's time limit (setup, call and teardown together), unless it
+# carries ``@pytest.mark.time_limit(seconds)``: five times the slowest
+# test on an idle machine, and small enough that a hang or two still let a
+# loaded run of the suite end inside the driver's 1470 s. A test that
+# waits for ever costs itself and this long, not the rest of its file and
+# the run's clock.
+TEST_LIMIT_S = 300
+
+_limit = {"stderr": None, "deadline": 0.0, "seconds": 0.0}
+
+
+def _time_limit_reached(signum, frame):
+    """SIGALRM in the main thread: fail the test that is running, with
+    every thread's stack in the failure (who waited, and on whom)."""
+    with tempfile.TemporaryFile("w+") as f:
+        faulthandler.dump_traceback(file=f, all_threads=True)
+        f.seek(0)
+        stacks = f.read()
+    pytest.fail(f"time limit of {_limit['seconds']:g} s reached "
+                f"(tests/conftest.py TEST_LIMIT_S, or the test's "
+                f"time_limit marker); every thread's stack:\n{stacks}",
+                pytrace=False)
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item):
+    """One deadline for the test's whole protocol, and behind it the
+    backstop for a main thread no signal reaches (stuck in XLA or other
+    native code): a fifth of the limit later, and never less than 5 s
+    later, the process writes its stacks to stderr and exits, and xdist
+    names the test its worker went down in and starts another worker."""
+    marker = item.get_closest_marker("time_limit")
+    seconds = float(marker.args[0]) if marker else TEST_LIMIT_S
+    _limit.update(seconds=seconds, deadline=time.monotonic() + seconds)
+    faulthandler.dump_traceback_later(
+        seconds + max(seconds / 5, 5.0), file=_limit["stderr"], exit=True)
+    previous = signal.signal(signal.SIGALRM, _time_limit_reached)
+    try:
+        return (yield)
+    finally:
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
+
+def _alarm_while_phase_runs():
+    """The alarm is armed only while setup, call or teardown runs: what it
+    raises there is the phase's own failure (pytest's CallInfo catches
+    it), never an error inside pytest's or xdist's reporting between
+    phases. A phase that starts past the deadline (teardown after the
+    limit was reached) runs under the backstop alone."""
+    left = _limit["deadline"] - time.monotonic()
+    if left > 0:
+        signal.setitimer(signal.ITIMER_REAL, left)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+
+
+pytest_runtest_setup = pytest.hookimpl(wrapper=True, tryfirst=True)(
+    _alarm_while_phase_runs)
+pytest_runtest_call = pytest.hookimpl(wrapper=True, tryfirst=True)(
+    _alarm_while_phase_runs)
+pytest_runtest_teardown = pytest.hookimpl(wrapper=True, tryfirst=True)(
+    _alarm_while_phase_runs)
 
 
 def _codec_threads():
@@ -64,9 +134,16 @@ def _codec_pools_joined_on_close():
 
 
 def pytest_configure(config):
+    # Output capture is suspended while plugins are configured, so this is
+    # the run's real stderr (a test's own fd 2 is the capture's file).
+    _limit["stderr"] = os.dup(2)
     config.addinivalue_line(
         "markers", "slow: long-running tests excluded from tier-1 "
                    "(-m 'not slow')")
+    config.addinivalue_line(
+        "markers", "time_limit(seconds): this test's time limit for "
+                   "setup, call and teardown together, in place of "
+                   "TEST_LIMIT_S (tests/conftest.py)")
     config.addinivalue_line(
         "markers", "chaos: deterministic fault-injection tests (seeded "
                    "FaultPlans, CPU backend, bounded wall time — run in "

@@ -577,7 +577,7 @@ def test_subscribe_dead_gate_exits_3():
     assert time.time() - t0 < 15.0, "exit 3 must beat the --timeout deadline"
 
 
-def test_worker_sigterm_graceful_stats_line():
+def test_worker_sigterm_graceful_stats_line(tmp_path):
     """SIGTERM on `dvf_tpu worker`: the run loop drains the egress
     plane and the final stats JSON lands on stdout with exit 0 — a
     supervisor's kill gets the same accounting as a max_frames exit."""
@@ -587,28 +587,29 @@ def test_worker_sigterm_graceful_stats_line():
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "dvf_tpu", "worker", "--filter", "invert",
-         "--platform", "cpu", "--distribute-port", str(free_port()),
-         "--collect-port", str(free_port())],
-        cwd=repo, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-        text=True)
+    # stderr goes to a file that is polled: the 90 s hold whatever the
+    # worker prints or does not print (a readline() would wait with it).
+    with open(tmp_path / "stderr.txt", "w") as err_file:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "dvf_tpu", "worker", "--filter", "invert",
+             "--platform", "cpu", "--distribute-port", str(free_port()),
+             "--collect-port", str(free_port())],
+            cwd=repo, env=env, stdout=subprocess.PIPE, stderr=err_file,
+            text=True)
+    read_err = (tmp_path / "stderr.txt").read_text
     try:
-        ready = False
         deadline = time.time() + 90.0
-        while time.time() < deadline:
-            line = proc.stderr.readline()
-            if not line:
-                break
-            if "serving" in line:
-                ready = True
-                break
-        assert ready, "worker never reached the serving banner"
+        while ("serving" not in read_err() and proc.poll() is None
+               and time.time() < deadline):
+            time.sleep(0.1)
+        assert "serving" in read_err(), (
+            f"worker never reached the serving banner: {read_err()}")
         proc.send_signal(signal.SIGTERM)
-        out, err = proc.communicate(timeout=60.0)
-    except Exception:
+        out, _ = proc.communicate(timeout=60.0)
+        err = read_err()
+    except BaseException:
         proc.kill()
-        proc.communicate()
+        proc.communicate(timeout=30.0)
         raise
     assert proc.returncode == 0, f"worker exit {proc.returncode}: {err}"
     stats_lines = [ln for ln in out.splitlines() if ln.strip()]

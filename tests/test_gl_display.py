@@ -129,7 +129,8 @@ def test_gl_blit_across_threads():
 
         t = threading.Thread(target=worker)
         t.start()
-        t.join()
+        t.join(timeout=60.0)
+        assert not t.is_alive(), "the worker's blit_pair never returned"
         results["main"] = r.blit_pair(live, proc)
         np.testing.assert_array_equal(results["worker"], results["main"])
         np.testing.assert_array_equal(results["main"][:, 24:], proc)

@@ -90,7 +90,8 @@ class TestMultiSessionCorrectness:
             for t in threads:
                 t.start()
             for t in threads:
-                t.join()
+                t.join(timeout=60.0)
+                assert not t.is_alive(), "a driver thread never finished"
             drain(fe, sids, deliveries)
             stats = fe.stats()
 

@@ -135,7 +135,8 @@ class TestEngineSwap:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=60.0)
+            assert not t.is_alive(), "a prepare_swap never returned"
         caches = sorted(r["cache"] for r in results)
         assert caches == ["miss", "staged"], results
         eng.commit_swap()

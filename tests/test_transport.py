@@ -3,6 +3,8 @@ shared memory), JPEG codec round-trip, and the ZMQ ingress speaking the
 reference wire protocol against a mini app-side harness."""
 
 import os
+import subprocess
+import sys
 import threading
 import time
 import uuid
@@ -73,28 +75,36 @@ def test_ring_spsc_threaded():
 
     t1 = threading.Thread(target=produce)
     t2 = threading.Thread(target=consume)
-    t1.start(); t2.start(); t1.join(); t2.join()
+    t1.start(); t2.start()
+    t1.join(timeout=60.0); t2.join(timeout=60.0)
+    assert not t1.is_alive() and not t2.is_alive()
     # Big ring: nothing dropped, strict FIFO.
     assert got == list(range(n))
     assert ring.dropped == 0
     ring.close()
 
 
+RING_READER = """
+import sys
+from dvf_tpu.transport.ring import FrameRing
+ring = FrameRing(capacity_bytes=1 << 16, shm_name=sys.argv[1], create=False)
+item = ring.pop()
+sys.exit(0 if item is not None and item[:2] == (b"hello", 42) else 1)
+"""
+
+
 def test_ring_shared_memory_cross_process():
     name = f"/dvf_test_{uuid.uuid4().hex[:8]}"
     ring = FrameRing(capacity_bytes=1 << 16, shm_name=name, create=True)
     ring.push(b"hello", 42, 1.5)
-    pid = os.fork()
-    if pid == 0:  # child: attach and read
-        try:
-            child = FrameRing(capacity_bytes=1 << 16, shm_name=name, create=False)
-            item = child.pop()
-            ok = item is not None and item[0] == b"hello" and item[1] == 42
-            os._exit(0 if ok else 1)
-        except BaseException:
-            os._exit(2)
-    _, status = os.waitpid(pid, 0)
-    assert os.waitstatus_to_exitcode(status) == 0
+    # The reader is a fresh interpreter: it shares nothing with this
+    # process but the named memory (and a fork of a process that holds
+    # JAX's threads may never return).
+    reader = subprocess.run(
+        [sys.executable, "-c", RING_READER, name],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=60)
+    assert reader.returncode == 0, reader.stderr
     assert ring.pop() is None  # consumed by the child through shm
     ring.close()
 
