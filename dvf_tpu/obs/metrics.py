@@ -511,11 +511,19 @@ class BatchStamps:
     (in-flight permit acquired) → ``t_submit`` (``Engine.submit``
     returned) → ``t_prefetched`` (``lane.prefetch`` returned: the way
     back is started) → ``t_taken`` (collect: popped off the in-flight
-    queue) → ``t_ready`` (``block_until_ready`` returned) →
-    ``t_fetched`` (``fetcher.fetch`` returned) → ``t_routed``
-    (``router.route`` returned). ``t_held``: where the hold that this
-    batch's binding ended had started (0.0: it was bound on the tick
-    that found its frames). Everything that times a batch is a view of
+    queue) → ``t_landed`` (the handle's ``wait_landed`` returned: the
+    batch's last frame is on the chip) → ``t_ready``
+    (``block_until_ready`` returned) → ``t_fetched`` (``fetcher.fetch``
+    returned) → ``t_routed`` (``router.route`` returned). ``t_held``:
+    where the hold that this batch's binding ended had started (0.0: it
+    was bound on the tick that found its frames). ``t_landed`` is
+    stamped only where the collect thread SAW the landing (the handle
+    read not landed at ``t_taken``); 0.0 where the bytes were there
+    before it looked, or the batch has no landing probe: the thread
+    observes, and its own lateness is not the link's. It cuts no frame
+    component (``device`` stays ``t_taken`` → ``t_ready``) and has no
+    cell in ``stages``: its one reader is the ``starved`` ledger
+    (:class:`StarvedStats`). Everything that times a batch is a view of
     these: the bucket's :class:`StageStats` and :class:`StarvedStats`,
     ``FrameLineage`` marks, the Tracer's dispatch/collect spans, the
     tick-cost sample. ``stages`` is the bucket's StageStats (None on
@@ -523,15 +531,15 @@ class BatchStamps:
     """
 
     __slots__ = ("stages", "t_held", "t_chosen", "t_permit", "t_submit",
-                 "t_prefetched", "t_taken", "t_ready", "t_fetched",
-                 "t_routed", "ms", "bins")
+                 "t_prefetched", "t_taken", "t_landed", "t_ready",
+                 "t_fetched", "t_routed", "ms", "bins")
 
     def __init__(self, stages: "Optional[StageStats]" = None,
                  t_chosen: float = 0.0):
         self.stages = stages
         self.t_chosen = t_chosen
         self.t_held = self.t_prefetched = 0.0
-        self.t_permit = self.t_submit = self.t_taken = 0.0
+        self.t_permit = self.t_submit = self.t_taken = self.t_landed = 0.0
         self.t_ready = self.t_fetched = self.t_routed = 0.0
         self.ms: Optional[tuple] = None    # the five batch-level intervals,
         self.bins: Optional[tuple] = None  # closed once by close_batch()
@@ -736,24 +744,63 @@ class StarvedStats:
     n. A collect thread's first batch (a generation's first, the first
     after a supervised recovery) opens none.
 
+    **The landing (PR 54).** The submit returns when the step is
+    dispatched, not when its input is on the chip: a batch's last bytes
+    may still be on the link (199 MB a batch in the invert cell). From
+    ``max(t_ready(n−1), t_submit(n))`` to ``t_landed(n)`` the chip holds
+    a dispatched step of ours and nothing it can run. Where the collect
+    thread saw the landing (``st.t_landed`` set) that interval, where
+    positive, is ``landing_ms_total``, a fifth state beside the four; the
+    four, ``gaps_total`` and ``max_gap_ms`` are what they were, and end
+    at the submit. Where the bytes were on the chip before the thread
+    looked (``t_landed`` 0.0 on a batch that had a probe), the landing
+    is COUNTED and never timed: ``[max(t_ready(n−1), t_submit(n)),
+    t_taken(n)]`` where positive goes to ``landing_unseen_ms_total``, an
+    upper bound that no share adds in. ``landed_seen_total`` /
+    ``landed_unseen_total`` count the batches of each, a collect
+    thread's first batch too (a batch without a probe, the slab and
+    monolithic paths', is neither); the two ``landing_*_ms_total`` need
+    a predecessor, as a gap does.
+
     Bias, as ``_Bucket.observe_device`` states its own: ``t_ready`` is
     read by the collect thread, so when that thread is behind the device
-    the gap reads too SHORT, never too long. And the gap ends at the
-    submit: an H2D that lands after it (a padded batch's, whose last
-    chunks are still on the link when the step is dispatched) is the
-    device's idle time and not this ledger's. That transfer, and the
-    dispatch-to-start latency of the step itself, is the difference to
-    the device trace's idle share.
+    the gap reads too SHORT, never too long; ``t_landed`` is read by the
+    same thread, so a seen landing reads LATE by at most the thread's
+    wake-up (and early by the rows that land behind the batch's last,
+    which is the probe: ``runtime/ingest.py::_finish_rows``). What is
+    left between this ledger and the device trace's idle share is the
+    step's own dispatch-to-start latency, and the landings nobody saw:
+    nearly all of them wherever the collect thread reaches a batch after
+    its bytes (PERF.md §6, PR 54: every cell but the live one).
     """
 
     def __init__(self):
         self.ms = dict.fromkeys(STARVED_STATES, 0.0)
         self.gaps = 0
         self.max_gap_ms = 0.0
+        self.landing_ms = self.landing_unseen_ms = 0.0
+        self.landed_seen = self.landed_unseen = 0
 
-    def note(self, last_ready: float, st: BatchStamps) -> None:
+    def note(self, last_ready: float, st: BatchStamps,
+             probed: bool = False) -> None:
         """Collect thread, once batch n is ready: ``last_ready`` is batch
-        n−1's ``t_ready`` (0.0: there was none on this thread)."""
+        n−1's ``t_ready`` (0.0: there was none on this thread),
+        ``probed`` the handle's (the batch had a landing probe)."""
+        if probed:
+            seen = st.t_landed > 0.0
+            # the chip had nothing else of ours from max(...) on, until
+            # the bytes were there (seen), or at the latest until the
+            # thread looked and found them there (unseen: a bound)
+            ms = ((st.t_landed if seen else st.t_taken)
+                  - max(last_ready, st.t_submit)) * 1e3
+            if not last_ready or ms < 0.0:
+                ms = 0.0
+            if seen:
+                self.landed_seen += 1
+                self.landing_ms += ms
+            else:
+                self.landed_unseen += 1
+                self.landing_unseen_ms += ms
         if not last_ready or st.t_submit <= last_ready:
             return
         cur = last_ready
@@ -771,7 +818,11 @@ class StarvedStats:
     def summary(self) -> dict:
         return {**{f"{k}_ms_total": round(v, 4) for k, v in self.ms.items()},
                 "gaps_total": self.gaps,
-                "max_gap_ms": round(self.max_gap_ms, 4)}
+                "max_gap_ms": round(self.max_gap_ms, 4),
+                "landing_ms_total": round(self.landing_ms, 4),
+                "landing_unseen_ms_total": round(self.landing_unseen_ms, 4),
+                "landed_seen_total": self.landed_seen,
+                "landed_unseen_total": self.landed_unseen}
 
 
 class ThreadClock:

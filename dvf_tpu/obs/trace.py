@@ -15,13 +15,10 @@ frame_delivered; the streamed ingest path (runtime/ingest.py) adds a
 transfer lane with per-shard spans:
 
 - ``ingest_h2d`` — one span per shard chunk's ``device_put`` issue
-  (args: the batch-row range and bytes shipped);
-- ``ingest_stage`` — the whole host-staging window of one batch (args:
-  the cumulative host-copy/decode time inside it);
-- ``ingest_overlap`` — first shard put → batch assembly complete: the
-  window in which transfers ran under decode of later shards and device
-  compute of the previous batch. Reading the lane against the device
-  lane in the merged export shows the stall the streaming removed.
+  (the row path: one for the batch's list of frames; args: the
+  batch-row range and bytes shipped): the CALL, which returns when the
+  runtime has copied the buffer, not when the bytes are on the chip
+  (that is ``BatchStamps.t_landed``, a counter's and no span's).
 
 The streamed egress path (runtime/egress.py) mirrors it on the delivery
 side:
@@ -42,11 +39,9 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-# Streamed-ingest span names (runtime/ingest.py emits these; one place
-# owns the strings so trace consumers can match on them).
+# Streamed-ingest span name (runtime/ingest.py emits it; one place owns
+# the string so trace consumers can match on it).
 INGEST_H2D = "ingest_h2d"
-INGEST_STAGE = "ingest_stage"
-INGEST_OVERLAP = "ingest_overlap"
 
 # Streamed-egress span names (runtime/egress.py — the delivery-side
 # mirror): one ``egress_d2h`` span per output-shard host copy, one

@@ -88,7 +88,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from dvf_tpu.obs.metrics import IngestStats
-from dvf_tpu.obs.trace import INGEST_H2D, INGEST_OVERLAP, INGEST_STAGE
+from dvf_tpu.obs.trace import INGEST_H2D
 from dvf_tpu.resilience.faults import FaultError, FaultKind
 
 INGEST_MODES = ("streamed", "monolithic")
@@ -401,9 +401,9 @@ class BatchBuilder:
         self._put_s = 0.0
         self._wait_s = 0.0
         self._join_s = 0.0
-        self._first_put_t: Optional[float] = None
-        self._t_begin = time.perf_counter()
         self.direct = False  # put_rows took the batch: it goes up as rows
+        self.landing = None  # _finish_rows leaves it: the row batch's
+        #   landing probe (its last device frame); None on every other path
 
     # -- the row path ----------------------------------------------------
 
@@ -437,7 +437,6 @@ class BatchBuilder:
             #   fires a chunk)
         dev = next(iter(asm.sharding.device_set))
         t0 = time.perf_counter()
-        self._first_put_t = t0
         try:
             rows = jax.device_put(list(frames), dev)
         except Exception as e:  # noqa: BLE001 — as _launch: containment
@@ -474,13 +473,17 @@ class BatchBuilder:
         batch = jax.make_array_from_single_device_arrays(
             asm.batch_shape, asm.sharding,
             [self._join(*rows, *(rows[-1],) * pad)])
-        t_end = time.perf_counter()
-        self._join_s = t_end - t_join
-        tracer = asm.tracer
-        if tracer is not None and tracer.enabled:
-            off = asm.wall_offset_s
-            tracer.complete(INGEST_OVERLAP, self._first_put_t + off,
-                            t_end + off, asm.track, valid=valid)
+        self._join_s = time.perf_counter() - t_join
+        # The landing probe: a transfer of the batch's own that no program
+        # donates (the join does not; the step donates the joined batch),
+        # so ``is_ready`` can be asked of it after the submit. ONE frame,
+        # the last: rows of one ``device_put(list)`` mostly land in order
+        # (``scripts/h2d_probe.py --landing-order``: a row behind the last
+        # in 35-58% of batches, by 0.2-0.4 ms in the median), so it reads
+        # early by that; holding every row costs this thread and the
+        # allocator more than that is worth (PERF.md §6, PR 54). Whoever
+        # takes it (``lane.prefetch``) lets it go once it has answered.
+        self.landing = rows[-1]
         self._record(valid)
         return batch, True
 
@@ -566,8 +569,6 @@ class BatchBuilder:
             # where a real transfer fault would surface.
             self.asm.chaos.fire("h2d")
         t0 = time.perf_counter()
-        if self._first_put_t is None:
-            self._first_put_t = t0
         arrs = []
         try:
             for dev, key in c.targets:
@@ -655,16 +656,7 @@ class BatchBuilder:
                         else jnp.concatenate(parts, axis=0))
         batch = jax.make_array_from_single_device_arrays(
             self.asm.batch_shape, self.asm.sharding, arrs)
-        t_end = time.perf_counter()
-        self._join_s = t_end - t_join
-        tracer = self.asm.tracer
-        if tracer is not None and tracer.enabled and self._first_put_t:
-            off = self.asm.wall_offset_s
-            tracer.complete(INGEST_OVERLAP, self._first_put_t + off,
-                            t_end + off, self.asm.track, valid=valid)
-            tracer.complete(INGEST_STAGE, self._t_begin + off, t_end + off,
-                            self.asm.track,
-                            stage_ms=round(self._stage_s * 1e3, 3))
+        self._join_s = time.perf_counter() - t_join
         self._record(valid)
         return batch, True
 

@@ -55,6 +55,15 @@ class InflightBatch:
     give the lane another one while this batch is in flight. ``layout``:
     its transfer layout, for the caller's d2h span.
 
+    It can also say where the batch's way UP ended, where the caller gave
+    ``prefetch`` the batch's builder and the batch went up on the row
+    path: ``landed()`` / ``wait_landed()`` ask the builder's landing probe
+    (the batch's last device frame, which no program donates) whether its
+    bytes have crossed the link, for the collector's ``t_landed`` stamp.
+    ``probed``: there was such a probe (else the batch reads landed: the
+    slab and monolithic paths, and every caller but the serve frontend).
+    The frame goes once it has answered.
+
     What ``fetch`` returns, and who may keep a row of it (``out[row]``,
     ``row < valid``, either way):
 
@@ -68,12 +77,15 @@ class InflightBatch:
       copy what may outlive the batch by long (the serve router does).
     """
 
-    __slots__ = ("_lane", "_fetcher", "_payload", "_device", "layout")
+    __slots__ = ("_lane", "_fetcher", "_payload", "_device", "layout",
+                 "_landing", "probed")
 
     def __init__(self, lane: "DeviceLane", fetcher: ShardedBatchFetcher,
-                 payload: Any):
+                 payload: Any, landing: Any = None):
         self._lane, self._fetcher, self._payload = lane, fetcher, payload
         self._device, self.layout = device_side(payload)
+        self._landing = landing
+        self.probed = landing is not None
 
     def wait(self) -> None:
         """Block until the device is done with this batch (the step, and
@@ -82,6 +94,21 @@ class InflightBatch:
 
     def is_ready(self) -> bool:
         return self._device.is_ready()
+
+    def landed(self) -> bool:
+        """True once the batch's bytes are on the chip (or there is no
+        probe to ask). The collecting thread's, like ``wait_landed``."""
+        if self._landing is not None and self._landing.is_ready():
+            self._landing = None
+        return self._landing is None
+
+    def wait_landed(self) -> None:
+        """Block until the batch's bytes are on the chip. The landing
+        precedes the step's end: this wait and ``wait`` in a row cost
+        the thread what ``wait`` alone did."""
+        landing, self._landing = self._landing, None
+        if landing is not None:
+            landing.block_until_ready()
 
     def fetch(self, seq: int):
         """The batch as host frames, once. ``seq`` is the caller's
@@ -198,12 +225,16 @@ class DeviceLane:
 
     # -- off the chip ------------------------------------------------------
 
-    def prefetch(self, result, valid: Optional[int] = None) -> InflightBatch:
+    def prefetch(self, result, valid: Optional[int] = None,
+                 builder: Optional[BatchBuilder] = None) -> InflightBatch:
         """Start ``result``'s way back now, under the tail of its compute
         and the next batch's staging; the caller keeps the handle in its
         place. ``valid``: the rows that carry a frame (None = all); on
         the packed layout the padding behind them never crosses the
-        link. A new output signature or mode rebuilds the fetcher."""
+        link. ``builder``: the batch's, from a caller whose collector
+        will ask the handle where the batch landed; its landing probe
+        changes hands here. A new output signature or mode rebuilds the
+        fetcher."""
         shape, dtype = self.engine.out_shape, self.engine.out_dtype
         mode, reason = self._mode(FaultKind.D2H)
         f = self._fetcher
@@ -219,7 +250,10 @@ class DeviceLane:
             self._swap_fetcher(f, park=True)
         with self._lock:
             self._pending[f] = self._pending.get(f, 0) + 1
-        return InflightBatch(self, f, f.prefetch(result, valid))
+        landing = None
+        if builder is not None:
+            landing, builder.landing = builder.landing, None
+        return InflightBatch(self, f, f.prefetch(result, valid), landing)
 
     def _swap_fetcher(self, new: Optional[ShardedBatchFetcher],
                       park: bool) -> None:

@@ -3541,8 +3541,9 @@ class ServeFrontend:
                     # Start the D2H now, so the collect side only waits,
                     # never initiates. What rides the in-flight queue is
                     # the lane's handle (the result itself is dropped
-                    # here); it pins the fetcher the D2H was issued on.
-                    result = lane.prefetch(result, plan.valid)
+                    # here); it pins the fetcher the D2H was issued on,
+                    # and takes the builder's landing probe with it.
+                    result = lane.prefetch(result, plan.valid, builder)
                     # Stamp: the pack is dispatched and the rows' D2H
                     # started; a thread state of its own, not idle.
                     st.t_prefetched = time.time()
@@ -3650,6 +3651,16 @@ class ServeFrontend:
                 st.t_taken = time.time()  # stamp: off the in-flight queue
                 bucket = plan.bucket
                 try:
+                    # Where the batch's H2D lands. This thread observes:
+                    # bytes that were there before it looked are counted
+                    # (the starved ledger's *_unseen), never timed, or
+                    # its own lateness would read as the link's. The
+                    # landing precedes the step's end, so the two waits
+                    # cost what the one did.
+                    seen = not result.landed()
+                    result.wait_landed()
+                    if seen:
+                        st.t_landed = time.time()  # stamp: bytes on chip
                     result.wait()
                 except Exception:  # noqa: BLE001 — a poisoned batch
                     pass  # raises again in fetch below, where the
@@ -3658,7 +3669,7 @@ class ServeFrontend:
                 if bucket is not None:
                     bucket.observe_device(
                         (st.t_ready - max(st.t_submit, last_ready)) * 1e3)
-                    bucket.starved.note(last_ready, st)
+                    bucket.starved.note(last_ready, st, result.probed)
                 last_ready = st.t_ready
                 try:
                     # Streamed egress: shard host copies into the slot's

@@ -41,6 +41,14 @@ Run on the chip:
 
 ``--toy`` runs tiny shapes on whatever backend jax has (the CPU here): it checks the script, and its rates mean
 nothing.
+
+``--landing-order`` (PR 54) asks instead what the serve path's landing probe may hold (``runtime/ingest.py::
+BatchBuilder._finish_rows``): for ``--batches`` (200) batches of the shipped row path's put (``R3`` as one list), alone and with a
+batch of D2H rows in flight beside it, once the LAST row's ``block_until_ready`` has returned, how many of the other
+rows are not yet ready and how long the wait for them is (0 and 0: transfers of one ``device_put(list)`` land in order
+and the last row proves the batch); then, the other way round, how long after the FIRST row the last one lands (the
+first-to-last spread); and what ``is_ready()`` + ``block_until_ready()`` cost on a probe that has landed (the stamp's
+cost when it has nothing to see). Writes ``chiprun_out/h2d_landing_order.json``.
 """
 
 from __future__ import annotations
@@ -103,6 +111,92 @@ def unpack_variant(form: str):
     return ingest_unpack_variant
 
 
+def landing_order(args, jax, dev) -> int:
+    """``--landing-order``: see the module docstring."""
+    import jax.numpy as jnp
+
+    from dvf_tpu.runtime.egress import egress_pack, pack_table
+
+    report = {"device": f"{dev.platform}:{dev.device_kind}", "jax": jax.__version__, "toy": args.toy,
+              "batches": args.batches, "results": {}}
+    for name, shape in (TOY if args.toy else BATCHES).items():
+        if args.shapes and name not in args.shapes.split(","):
+            continue
+        b, h, w, c = shape
+        rng = np.random.default_rng(54)
+        pool = [[rng.integers(0, 256, (h, w, c), dtype=np.uint8) for _ in range(b)] for _ in range(2)]
+        down = None
+        if (w * c) % 4 == 0:
+            ptable = jax.device_put(jnp.asarray(pack_table(w, c), jnp.bfloat16), dev)
+            resident = jax.device_put(np.stack(pool[0]), dev)
+            pack = jax.jit(egress_pack)
+            jax.block_until_ready(pack(resident, ptable))
+
+            def down():
+                out = pack(resident, ptable)
+                for r in out:
+                    r.copy_to_host_async()
+                return out
+
+        jax.block_until_ready(jax.device_put(list(pool[0]), dev))  # warm: the first put pays the allocator
+        rows_out = {}
+        for mode in ("alone", "duplex"):
+            if mode == "duplex" and down is None:
+                continue
+            late_rows, late_ms, flight_ms, spread_ms = [], [], [], []
+            for k in range(args.batches):
+                coming = down() if mode == "duplex" else None
+                t0 = time.perf_counter()
+                rows = jax.device_put(list(pool[k % 2]), dev)
+                if k % 10 == 9:     # every tenth batch the other way round: first row, then last
+                    rows[0].block_until_ready()
+                    t1 = time.perf_counter()
+                    rows[-1].block_until_ready()
+                    spread_ms.append((time.perf_counter() - t1) * 1e3)
+                else:
+                    rows[-1].block_until_ready()
+                    t1 = time.perf_counter()
+                    late_rows.append(sum(not r.is_ready() for r in rows[:-1]))
+                    jax.block_until_ready(rows[:-1])
+                    late_ms.append((time.perf_counter() - t1) * 1e3)
+                    flight_ms.append((t1 - t0) * 1e3)
+                if coming is not None:
+                    for r in coming:
+                        np.asarray(r)
+                del rows
+            rows_out[mode] = {
+                "batches_last_first": len(late_rows),
+                "batches_with_a_row_behind_the_last": sum(1 for n in late_rows if n),
+                "rows_behind_the_last_max": max(late_rows), "rows_behind_the_last_total": sum(late_rows),
+                "wait_for_them_ms_max": round(max(late_ms), 4),
+                "wait_for_them_ms_median": round(float(np.median(late_ms)), 4),
+                "put_to_last_row_ms_median": round(float(np.median(flight_ms)), 3),
+                "batches_first_then_last": len(spread_ms),
+                "first_to_last_ms_median": round(float(np.median(spread_ms)), 3),
+                "first_to_last_ms_max": round(max(spread_ms), 3)}
+            print(f"[{name}] landing order, {mode}: {json.dumps(rows_out[mode])}", flush=True)
+        # The stamp's cost where there is nothing to see: the two asks of a probe that has landed.
+        rows = jax.block_until_ready(jax.device_put(list(pool[0]), dev))
+        for what, arrays in (("last_row", rows[-1:]), ("every_row", rows)):
+            n = 2000
+            t0 = time.perf_counter()
+            for _ in range(n):
+                for a in arrays:
+                    a.is_ready()
+                for a in arrays:
+                    a.block_until_ready()
+            rows_out[f"landed_probe_us_{what}"] = round((time.perf_counter() - t0) / n * 1e6, 3)
+        print(f"[{name}] is_ready() + block_until_ready() on a landed probe, us: last row "
+              f"{rows_out['landed_probe_us_last_row']}, every row {rows_out['landed_probe_us_every_row']}", flush=True)
+        report["results"][name] = {"shape": list(shape), "modes": rows_out}
+    out = os.path.join(os.path.dirname(args.out) or ".", "h2d_landing_order.json")
+    os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+    with open(out, "w") as f:
+        json.dump(report, f, indent=1)
+    print("wrote", out)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--toy", action="store_true", help="tiny shapes, any backend: checks the script only")
@@ -111,6 +205,9 @@ def main() -> int:
     ap.add_argument("--only", default="", help="comma list of variants (S4,R,U,...) to run")
     ap.add_argument("--shapes", default="", help="comma list of batch names (invert_1080p,...) to run")
     ap.add_argument("--out", default="chiprun_out/h2d_probe.json")
+    ap.add_argument("--landing-order", action="store_true",
+                    help="whether the rows of one device_put(list) land in order (the landing probe's question)")
+    ap.add_argument("--batches", type=int, default=200, help="batches a mode of --landing-order")
     args = ap.parse_args()
 
     import jax
@@ -123,6 +220,8 @@ def main() -> int:
     if dev.platform == "cpu" and not args.toy:
         print("no accelerator: run through chiprun, or pass --toy", file=sys.stderr)
         return 3
+    if args.landing_order:
+        return landing_order(args, jax, dev)
     only = set(filter(None, args.only.split(",")))
     shapes = set(filter(None, args.shapes.split(",")))
     pool_t = ThreadPoolExecutor(4)

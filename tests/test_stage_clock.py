@@ -15,12 +15,17 @@ and the tick-cost sample are all views of them. Pinned here:
   own and not ``idle``; the ingest block's five clocks stay inside
   ``assemble_h2d``; the bucket row's ``starved`` block books each device
   gap under what the dispatch thread was doing, from the batch's stamps;
+- where a batch's H2D lands (PR 54): ``t_landed`` is stamped only where
+  the collect thread saw the landing, the ``starved`` block books that
+  wait beside the four states and leaves them as they were, and an
+  unseen landing is counted and never timed;
 - lineage and trace are views: same numbers, no clock of their own, and
   nothing is allocated for them when they are off;
 - XLA compilations are counted process-wide; a flight dump's device
   capture records its host-clock epoch.
 """
 
+import collections
 import json
 import threading
 import time
@@ -373,9 +378,9 @@ def test_prefetch_is_a_dispatch_state_and_not_idle(monkeypatch):
 
     real = lane_mod.DeviceLane.prefetch
 
-    def slow(self, result, valid=None):
+    def slow(self, *batch):
         time.sleep(0.02)
-        return real(self, result, valid)
+        return real(self, *batch)
 
     monkeypatch.setattr(lane_mod.DeviceLane, "prefetch", slow)
     fe = ServeFrontend(get_filter("invert"), ServeConfig(
@@ -422,12 +427,12 @@ def test_a_raising_prefetch_stays_under_idle(monkeypatch):
     real = lane_mod.DeviceLane.prefetch
     calls = []
 
-    def flaky(self, result, valid=None):
+    def flaky(self, *batch):
         calls.append(1)
         if len(calls) == 2:
             time.sleep(0.03)
             raise RuntimeError("prefetch: injected")
-        return real(self, result, valid)
+        return real(self, *batch)
 
     monkeypatch.setattr(lane_mod.DeviceLane, "prefetch", flaky)
     fe = ServeFrontend(get_filter("invert"), ServeConfig(
@@ -509,10 +514,16 @@ def test_ingest_split_stays_inside_assemble_h2d(mode, rows, monkeypatch):
             row1["ingest"][theirs], abs=0.001 * len(spans) + 0.001)
 
 
-def _stamps(t_chosen, t_permit, t_submit, t_held=0.0):
+def _stamps(t_chosen, t_permit, t_submit, t_held=0.0, t_taken=0.0, t_landed=0.0):
     st = M.BatchStamps(None, t_chosen)
     st.t_held, st.t_permit, st.t_submit = t_held, t_permit, t_submit
+    st.t_taken, st.t_landed = t_taken, t_landed
     return st
+
+
+OLD_KEYS = {f"{s}_ms_total" for s in M.STARVED_STATES} | {"gaps_total", "max_gap_ms"}
+LANDING_KEYS = {"landing_ms_total", "landing_unseen_ms_total", "landed_seen_total",
+                "landed_unseen_total"}
 
 
 @pytest.mark.parametrize("last_ready,stamps,want", [
@@ -538,8 +549,8 @@ def test_starved_block_cuts_a_gap_at_the_batchs_stamps(last_ready, stamps, want)
     starved = M.StarvedStats()
     starved.note(last_ready, _stamps(*stamps))
     doc = starved.summary()
-    assert set(doc) == {f"{s}_ms_total" for s in M.STARVED_STATES} | {
-        "gaps_total", "max_gap_ms"}
+    assert set(doc) == OLD_KEYS | LANDING_KEYS
+    assert not any(doc[k] for k in LANDING_KEYS)    # no probe: nothing of the landing's
     if want is None:
         assert doc["gaps_total"] == 0 and doc["max_gap_ms"] == 0.0
         assert all(doc[f"{s}_ms_total"] == 0.0 for s in M.STARVED_STATES)
@@ -617,9 +628,9 @@ def test_first_batch_after_a_recovery_opens_no_gap(monkeypatch):
     seen = []
     real = M.StarvedStats.note
 
-    def spy(self, last_ready, st):
+    def spy(self, last_ready, st, *probed):
         seen.append((threading.get_ident(), last_ready))
-        real(self, last_ready, st)
+        real(self, last_ready, st, *probed)
 
     monkeypatch.setattr(M.StarvedStats, "note", spy)
     chaos = FaultPlan().add("freeze", at=(3,), delay_s=1.5)
@@ -647,6 +658,268 @@ def test_first_batch_after_a_recovery_opens_no_gap(monkeypatch):
         first, *rest = [ready for who, ready in seen if who == t]
         assert first == 0.0 and all(r > 0.0 for r in rest)
 
+
+
+# ---------------------------------------------------------------------------
+# Where a batch's H2D lands (PR 54)
+# ---------------------------------------------------------------------------
+
+# (last_ready, (t_chosen, t_permit, t_submit, t_held, t_taken, t_landed),
+#  the batch had a probe, what moves). The batch: submitted at 10.4, taken
+# off the queue at 10.45.
+LANDING_CASES = {
+    # ran out at 10.3, the bytes landed at 10.5 under the thread's eyes:
+    # 100 ms before the submit are assemble_h2d's, 100 ms after it the link's
+    "seen": (10.3, (10.0, 10.1, 10.4, 0.0, 10.45, 10.5), True, dict(
+        landing_ms_total=100.0, landed_seen_total=1)),
+    # the step before was still running when they landed: a landing seen,
+    # and no ms of the chip's (from 10.6 it had this batch to run)
+    "seen_before_last_ready": (10.6, (10.0, 10.1, 10.4, 0.0, 10.45, 10.5), True, dict(
+        landed_seen_total=1)),
+    # there before the thread looked: counted, and bounded by its look
+    "unseen": (10.3, (10.0, 10.1, 10.4, 0.0, 10.45, 0.0), True, dict(
+        landing_unseen_ms_total=50.0, landed_unseen_total=1)),
+    "unseen_before_last_ready": (10.6, (10.0, 10.1, 10.4, 0.0, 10.45, 0.0), True, dict(
+        landed_unseen_total=1)),
+    # the slab and monolithic paths: nothing to ask
+    "no_probe": (10.3, (10.0, 10.1, 10.4, 0.0, 10.45, 0.0), False, {}),
+    # a collect thread's first batch: the landing is the batch's own and is
+    # counted; without a predecessor no ms of the chip's is booked
+    "first_seen": (0.0, (10.0, 10.1, 10.4, 0.0, 10.45, 10.5), True, dict(
+        landed_seen_total=1)),
+    "first_unseen": (0.0, (10.0, 10.1, 10.4, 0.0, 10.45, 0.0), True, dict(
+        landed_unseen_total=1)),
+}
+
+
+@pytest.mark.parametrize("case", LANDING_CASES)
+def test_starved_block_books_a_landing(case):
+    last_ready, stamps, probed, want = LANDING_CASES[case]
+    starved = M.StarvedStats()
+    starved.note(last_ready, _stamps(*stamps), probed)
+    doc = starved.summary()
+    assert set(doc) == OLD_KEYS | LANDING_KEYS
+    for key in LANDING_KEYS:
+        assert doc[key] == pytest.approx(want.get(key, 0), abs=1e-6), key
+    # cumulative: a second seen landing, 20 ms of the chip's
+    starved.note(20.0, _stamps(19.0, 19.5, 20.01, 0.0, 20.02, 20.03), True)
+    doc = starved.summary()
+    assert doc["landing_ms_total"] == pytest.approx(
+        want.get("landing_ms_total", 0.0) + 20.0, abs=1e-6)
+    assert doc["landed_seen_total"] == want.get("landed_seen_total", 0) + 1
+
+
+@pytest.mark.parametrize("case", LANDING_CASES)
+def test_a_landing_leaves_the_four_states_as_they_were(case):
+    """``device_starved_pct`` sums its own four names: whatever the landing
+    books, they, ``gaps_total`` and ``max_gap_ms`` read what they read."""
+    last_ready, stamps, probed, _ = LANDING_CASES[case]
+    with_landing, without = M.StarvedStats(), M.StarvedStats()
+    for ready, st, had in ((last_ready, stamps, probed),
+                           (20.0, (19.0, 19.5, 20.01, 0.0, 20.02, 20.03), True)):
+        with_landing.note(ready, _stamps(*st), had)
+        without.note(ready, _stamps(*st[:4]))
+    doc, old = with_landing.summary(), without.summary()
+    assert {k: doc[k] for k in OLD_KEYS} == {k: old[k] for k in OLD_KEYS}
+
+
+def _one_chip_rows(monkeypatch, seen, **cfg):
+    """A frontend on the row path at the tests' sizes (one shard on one
+    device, streamed on the CPU): every batch has a landing probe. The CPU's
+    transfers are over before anybody looks, so ``seen`` True makes every
+    handle read not landed once (the collect thread then waits and stamps)."""
+    from dvf_tpu.parallel import MeshConfig, make_mesh
+    from dvf_tpu.runtime import Engine
+    from dvf_tpu.runtime import ingest as ingest_mod
+    from dvf_tpu.runtime import lane as lane_mod
+
+    monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    if seen:
+        monkeypatch.setattr(lane_mod.InflightBatch, "landed",
+                            lambda self: self._landing is None)
+    return Engine(get_filter("invert"), mesh=make_mesh(MeshConfig(data=1)))
+
+
+@pytest.mark.parametrize("seen", [True, False], ids=["seen", "unseen"])
+def test_landing_through_a_served_run(monkeypatch, seen):
+    """The bucket row carries the new keys; every batch with a probe is seen
+    or unseen; the landing lies inside ``device`` and cuts nothing: the
+    eight components still sum to the delivered latency, and ``device`` is
+    ``t_taken`` -> ``t_ready`` as it was."""
+    noted = []
+    real = M.StarvedStats.note
+
+    def spy(self, last_ready, st, probed=False):
+        real(self, last_ready, st, probed)
+        noted.append((st, probed))
+
+    monkeypatch.setattr(M.StarvedStats, "note", spy)
+    got, stats, fe = serve(n_sessions=2, n_frames=12, pace_s=0.002,
+                           engine=_one_chip_rows(monkeypatch, seen))
+    stages, row = stages_of(stats)
+    doc = row["starved"]
+    assert row["ingest"]["row_path"] and set(doc) == OLD_KEYS | LANDING_KEYS
+    assert doc["landed_seen_total"] + doc["landed_unseen_total"] == row["batches"]
+    assert len(noted) == row["batches"] and all(probed for _, probed in noted)
+    for st, _ in noted:
+        if seen:
+            assert st.t_taken <= st.t_landed <= st.t_ready
+        else:   # ready at t_taken: landed before the thread looked, never timed
+            assert st.t_landed == 0.0
+    if seen:
+        assert doc["landed_seen_total"] == row["batches"]
+        assert doc["landing_unseen_ms_total"] == 0.0
+        # what the ledger booked lies inside the batches' device intervals
+        assert 0.0 < doc["landing_ms_total"] <= sum(
+            (st.t_landed - st.t_submit) * 1e3 for st, _ in noted) + 0.01
+    else:
+        assert doc["landed_unseen_total"] == row["batches"]
+        assert doc["landing_ms_total"] == 0.0
+    # nothing the landing touches: the stage cells, the frame components and
+    # their exact sum
+    assert set(stages) - {"components"} >= {"route", "prefetch"}
+    assert "landing" not in stages
+    assert stages["delivered"] == 24
+    total = sum(stages["components"][c]["ms_total"] for c in SERVE_COMPONENTS)
+    assert total == pytest.approx(stages["latency_ms_total"], abs=0.01)
+    assert stages["components"]["device"]["batch_ms_total"] == pytest.approx(
+        sum((st.t_ready - st.t_taken) * 1e3 for st, _ in noted), abs=0.01)
+    assert stats["threads"]["collect"]["device_ms"] == pytest.approx(
+        stages["components"]["device"]["batch_ms_total"], abs=0.01)
+
+
+def test_a_monolithic_batch_has_no_probe_and_books_no_landing():
+    got, stats, fe = serve(n_sessions=1, n_frames=8)
+    _, row = stages_of(stats)
+    assert row["ingest"]["mode"] == "monolithic"
+    assert not any(row["starved"][k] for k in LANDING_KEYS)
+
+
+@pytest.mark.parametrize("path,handed", [
+    ("rows", True), ("rows", False), ("slab", True), ("monolithic", True)],
+    ids=["rows", "rows_not_handed", "slab", "monolithic"])
+def test_the_handle_drops_its_probe_once_it_has_answered(monkeypatch, path, handed):
+    """``lane.prefetch(result, valid, builder)``: a row batch's landing probe
+    (its last device frame) changes hands there, answers once, and is held
+    by nobody afterwards; a caller that hands no builder over (the Pipeline,
+    the worker) gets a handle that reads landed, and so does every batch of
+    the slab and monolithic paths."""
+    from dvf_tpu.parallel import MeshConfig, make_mesh
+    from dvf_tpu.runtime import Engine, PipelineConfig
+    from dvf_tpu.runtime import ingest as ingest_mod
+    from dvf_tpu.runtime.lane import DeviceLane
+
+    monkeypatch.setattr(ingest_mod, "MIN_STREAM_H2D_MS", 0.0)
+    filt = get_filter("invert")
+    engine = Engine(filt, mesh=make_mesh(MeshConfig(data=1)))
+    options = PipelineConfig(
+        ingest="monolithic" if path == "monolithic" else "streamed")
+    lane = DeviceLane(engine, options, inflight=2)
+    frames = [frame_u8(0, j) for j in range(4)]
+    builder = lane.begin((4, H, W, 3), np.uint8, 0)
+    if path != "rows" or not builder.put_rows(frames[:3]):
+        assert path != "rows"
+        for i, f in enumerate(frames[:3]):
+            builder.write_row(i, f)
+    result = lane.submit(builder, 3)
+    assert (builder.landing is not None) is (path == "rows")
+    handle = lane.prefetch(result, 3, builder if handed else None)
+    probed = path == "rows" and handed
+    assert handle.probed is probed and (handle._landing is not None) is probed
+    if probed:      # one frame, and the builder's no more
+        assert handle._landing.shape == (H, W, 3) and builder.landing is None
+    handle.wait_landed()
+    assert handle.landed() and handle._landing is None
+    handle.wait()
+    out = handle.fetch(0)
+    for i in range(3):
+        np.testing.assert_array_equal(np.asarray(out[i]), 255 - frames[i])
+    lane.release()
+
+
+@pytest.mark.parametrize("ready", [True, False], ids=["landed", "on_the_link"])
+def test_a_probe_ready_when_the_thread_looks_is_never_stamped(ready):
+    """The collect thread's three lines, on a handle with a fake probe: one
+    that is ready at ``t_taken`` says only "landed before I looked", costs
+    no wait, and is let go either way."""
+    from dvf_tpu.runtime.lane import InflightBatch
+
+    class _Array:
+        def __init__(self):
+            self.ready, self.waited = ready, 0
+
+        def is_ready(self):
+            return self.ready
+
+        def block_until_ready(self):
+            self.waited += 1
+            self.ready = True
+
+    arr = _Array()
+    handle = InflightBatch.__new__(InflightBatch)
+    handle._landing, handle.probed = arr, True
+    st = M.BatchStamps(None, 9.0)
+    seen = not handle.landed()
+    handle.wait_landed()
+    if seen:
+        st.t_landed = time.time()
+    assert seen is (not ready) and (st.t_landed == 0.0) is ready
+    assert arr.waited == (0 if ready else 1)
+    assert handle._landing is None and handle.landed()
+
+
+@pytest.mark.parametrize("seen", [True, False], ids=["seen", "unseen"])
+def test_the_landing_adds_no_span(monkeypatch, seen):
+    """With ``trace`` on the transfer lane carries the put calls and nothing
+    of the landing: ``t_landed`` has one reader, the ``starved`` ledger
+    (a span that no reader takes is not emitted); ``ingest_overlap`` /
+    ``ingest_stage`` went with PR 54."""
+    got, stats, fe = serve(n_sessions=2, n_frames=12, trace=True,
+                           engine=_one_chip_rows(monkeypatch, seen))
+    _, row = stages_of(stats)
+    names = collections.Counter(e["name"] for e in fe.tracer.snapshot()["events"])
+    assert names["ingest_h2d"] == names["collect:device"] == row["batches"]
+    assert not {"ingest_overlap", "ingest_stage", "h2d_flight",
+                "collect:landing"} & set(names)
+    assert row["starved"]["landed_seen_total"] == (row["batches"] if seen else 0)
+
+
+@pytest.mark.parametrize("metric", ["device_landing_pct", "landing_seen_pct"])
+def test_link_readers_find_the_programs_block(monkeypatch, metric):
+    """The two readers of PR 54 (chipbench/layer_metrics) on the program's
+    own rows: every key they take is there, and the window's delta is the
+    rows' difference."""
+    from chipbench import spec
+
+    fe = ServeFrontend(get_filter("invert"), ServeConfig(
+        batch_size=4, queue_size=500, slo_ms=60_000.0, telemetry_sample_s=0.0),
+        engine=_one_chip_rows(monkeypatch, seen=True))
+    reads = []
+    with fe:
+        sid = fe.open_stream()
+        for burst in range(2):
+            for j in range(24):
+                fe.submit(sid, frame_u8(0, burst * 24 + j))
+                if j % 4 == 3:
+                    time.sleep(0.01)
+            assert len(drain(fe, sid, 24)) == 24
+            reads.append({"buckets": [r for r in fe.stats()["buckets"].values()
+                                      if r["batches"]]})
+    before, after = reads
+    logs = []
+    value = spec.load_module(f"layer_metrics/{metric}.py").read(
+        {"before": before, "after": after, "log": logs.append})
+    (b,), (a,) = before["buckets"], after["buckets"]
+    wall_ms = (a["stages"]["t"] - b["stages"]["t"]) * 1e3
+
+    def delta(key):
+        return a["starved"][key] - b["starved"][key]
+
+    assert delta("landed_seen_total") >= 6
+    want = {"device_landing_pct": 100.0 * delta("landing_ms_total") / wall_ms,
+            "landing_seen_pct": 100.0}[metric]
+    assert value == pytest.approx(want, rel=1e-9, abs=1e-9)
+    assert any(line.startswith(f"[layer] {metric}: ") for line in logs)
 
 
 @pytest.fixture(scope="module")
